@@ -7,11 +7,6 @@ named device mesh, fused transformer kernels in Pallas, bf16-first mixed
 precision, block-sparse attention, and a multi-host launcher.
 """
 
-# version shims first: the runtime below (and user scripts) use the
-# modern jax.shard_map spelling, which older jax lacks
-from deepspeed_tpu.utils.jax_compat import install as _install_jax_compat
-_install_jax_compat()
-
 from deepspeed_tpu.runtime.engine import DeepSpeedEngine, TrainState
 from deepspeed_tpu.runtime.config import DeepSpeedConfig
 from deepspeed_tpu.runtime.pipe import (

@@ -121,6 +121,7 @@ from deepspeed_tpu.runtime.quantized_params import (QuantizedParam,
                                                     quantized_tree_bytes)
 from deepspeed_tpu.utils.logging import logger
 from deepspeed_tpu.utils.monitor import TensorBoardMonitor, _JsonlWriter
+from deepspeed_tpu.utils.platform import enable_compile_cache
 
 __all__ = ["InferenceEngine"]
 
@@ -267,6 +268,9 @@ class InferenceEngine:
         (self.family, self._forward, _,
          self._param_specs_fn) = _family_of(model_config)
         self.dtype = dtype
+        # same persistent compile cache as the trainer: a restarted
+        # server reloads its warmup programs instead of recompiling
+        enable_compile_cache()
         cfg = _normalize_inference_config(inference_config)
         self.config = cfg
         from deepspeed_tpu.runtime.config import get_observability_config

@@ -22,7 +22,7 @@ Requests are ``{"method": str, "params": {...}}``; replies are
 "message"}}``. Calls are synchronous and in-order — the fleet router
 is single-threaded by design, so one outstanding call per replica.
 
-Error taxonomy (pinned — the router's failure handling branches on
+Error classes (pinned — the router's failure handling branches on
 exactly these, and each is a distinct ``runtime/fault.py`` injection
 point):
 
@@ -40,7 +40,7 @@ point):
 
 This module is jax-free (source-level ast pin in
 tests/unit/test_inference.py, alongside scheduler/paging/fleet):
-framing, retry policy, and the error taxonomy are unit-testable over a
+framing, retry policy, and the error classes are unit-testable over a
 ``socket.socketpair()`` in microseconds, no device, no child process.
 """
 
@@ -72,7 +72,7 @@ MAX_FRAME_BYTES = 1 << 30
 
 # --------------------------------------------------------------- errors
 class RpcError(Exception):
-    """Base of the pinned taxonomy; ``kind`` is the wire/router key."""
+    """Base of the pinned classification; ``kind`` is the wire/router key."""
     kind = "transport"
 
     def __init__(self, message: str, method: Optional[str] = None):
@@ -106,7 +106,7 @@ class ReplicaDeadError(RpcError):
 
 class RpcRemoteError(RpcError):
     """The replica's handler raised: the engine survived, the call
-    failed. Application-level, outside the transport taxonomy."""
+    failed. Application-level, outside the transport classification."""
     kind = "remote"
 
 
@@ -267,7 +267,7 @@ class RpcClient:
         self.retried = 0
 
     def _inject(self, method: str) -> None:
-        # the taxonomy's three fault hooks, each its own point so a
+        # the classification's three fault hooks, each its own point so a
         # test (or DSTPU_FAULT_ARM) targets exactly one failure mode
         try:
             fault.fire("rpc.transport", method=method, name=self.name)
@@ -315,7 +315,8 @@ class RpcClient:
     def call(self, method: str, params: Optional[Dict] = None,
              payload: bytes = b"", timeout_s: Optional[float] = None
              ) -> Tuple[Any, bytes]:
-        """Returns ``(result, reply_payload)``; raises the taxonomy."""
+        """Returns ``(result, reply_payload)``; raises the pinned error
+        classes."""
         self.calls += 1
         for attempt in range(self.retries + 1):
             try:

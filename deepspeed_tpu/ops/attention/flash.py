@@ -604,10 +604,7 @@ def _load_block_entries():
 
 
 def _device_kind():
-    try:
-        return jax.devices()[0].device_kind
-    except Exception:
-        return None
+    return jax.devices()[0].device_kind
 
 
 def _table_lookup(match):
@@ -618,8 +615,7 @@ def _table_lookup(match):
     fallback = None
     for e in _load_block_entries():
         try:
-            # ms <= 0 is an RTT-subtraction artifact from an old sweep
-            # harness, never a real measurement — skip it
+            # ms <= 0 is never a real measurement — skip it
             if e.get("ms", 1.0) <= 0.0 or not match(e):
                 continue
         except (KeyError, TypeError):
@@ -945,10 +941,9 @@ def _flash_bwd(res, g, causal, sm_scale, interpret,
 # public API
 # --------------------------------------------------------------------- #
 def _use_pallas():
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    # a backend that cannot be queried raises: it is never a reason to
+    # run a TPU kernel in the interpreter
+    return jax.default_backend() == "tpu"
 
 
 # seed rides as a traced (1,1) int32 arg (not static — a per-step seed must
@@ -1026,7 +1021,28 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
     dropout_rate: attention-probability dropout (reference
     attn_dropout_ratio); requires dropout_rng (a jax PRNG key) — pass
     rate 0.0 / rng None for eval.
+
+    Traced inside an engine's GSPMD program
+    (``parallel/pallas_shard.pallas_kernel_mesh``) the kernel runs
+    shard_mapped over the engine's mesh — batch over data, heads over
+    model — because a pallas_call cannot be auto-partitioned; inside a
+    shard_map the operands are already local.
     """
+    from deepspeed_tpu.parallel.pallas_shard import (
+        current_kernel_mesh, sharded_flash_attention)
+    km = current_kernel_mesh()
+    kwargs = dict(mask=mask, causal=causal, sm_scale=sm_scale,
+                  dropout_rate=dropout_rate, dropout_rng=dropout_rng,
+                  interpret=interpret, force_reference=force_reference)
+    if km is not None and not jax.sharding.get_abstract_mesh().manual_axes:
+        return sharded_flash_attention(km, q, k, v, **kwargs)
+    return _local_flash_attention(q, k, v, **kwargs)
+
+
+def _local_flash_attention(q, k, v, mask, causal, sm_scale, dropout_rate,
+                           dropout_rng, interpret, force_reference):
+    """:func:`flash_attention` on operands that are local to this device
+    (or replicated): picks the kernel and calls it."""
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(q.shape[-1])
     assert q.shape[1] % k.shape[1] == 0 and k.shape[1] == v.shape[1], (
@@ -1095,11 +1111,10 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
             mp = key_pad if mask is None else (
                 jnp.pad(mask.astype(jnp.float32),
                         ((0, 0), (0, 0), (0, 0), (0, pk))) + key_pad)
-        out = flash_attention(qp, kp, vp, mask=mp, causal=causal,
-                              sm_scale=sm_scale,
-                              dropout_rate=dropout_rate,
-                              dropout_rng=dropout_rng,
-                              interpret=interpret)
+        out = _local_flash_attention(
+            qp, kp, vp, mask=mp, causal=causal, sm_scale=sm_scale,
+            dropout_rate=dropout_rate, dropout_rng=dropout_rng,
+            interpret=interpret, force_reference=False)
         return out[:, :, :sq, :]
     if mask is not None:
         assert mask.ndim == 4 and mask.shape[1] == 1 and \

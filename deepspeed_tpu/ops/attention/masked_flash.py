@@ -414,8 +414,12 @@ def _partial_keep(kind, q_idx, k_idx, band):
     """Elementwise keep for a walked tile: FULL items (kind == 0) keep
     everything; the causal bit clips to q_idx >= k_idx; the band bit
     applies the fine-block structure (global prefix | window, plus the
-    layout's own block-level causal clip)."""
-    keep = jnp.where(kind & KIND_CAUSAL, q_idx >= k_idx, True)
+    layout's own block-level causal clip).
+
+    Plain boolean algebra on the kind bit: Mosaic cannot legalize a
+    select between two i1 vectors, which ``jnp.where(bit, pred, True)``
+    lowers to."""
+    keep = (q_idx >= k_idx) | ((kind & KIND_CAUSAL) == 0)
     if band is not None:
         fb, w, g_r, g_c, clip = band
         qf = q_idx // fb
@@ -423,7 +427,7 @@ def _partial_keep(kind, q_idx, k_idx, band):
         ok = (qf < g_r) | (kf < g_c) | (jnp.abs(qf - kf) <= w)
         if clip:
             ok &= kf <= qf
-        keep = keep & jnp.where(kind & KIND_BAND, ok, True)
+        keep = keep & (ok | ((kind & KIND_BAND) == 0))
     return keep
 
 

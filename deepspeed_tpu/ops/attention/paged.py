@@ -47,10 +47,14 @@ gather path tier-1-testable without hardware
 Compiled-TPU legality: Mosaic requires the DMA tile's lane (minor) dim
 to be 128-aligned; the streamed tile is ``(page_size, head_dim)``, so
 the compiled path needs ``head_dim % 128 == 0`` (plus a sublane-tile
-page size). :func:`paged_decode_supported` is the one predicate the
-serving engine consults; unsupported geometries fall back to the
-gather path with a one-line log (see docs/inference.md's fallback
-matrix) — the gather path remains the numerics oracle either way.
+page size). The int8 pool's per-page scale tile is
+``(page_size, scale_blocks)`` fp32 — its lane dim is 1..4, so the int8
+arity is refused by the compiler at every page geometry and runs in
+interpret mode only. :func:`paged_decode_supported` is the one
+predicate the serving engine consults; unsupported geometries fall back
+to the gather path with a one-line log (see docs/inference.md's
+fallback matrix) — the gather path remains the numerics oracle either
+way.
 """
 
 import functools
@@ -66,7 +70,7 @@ try:
 except ImportError:  # pragma: no cover
     pltpu = None
 
-from deepspeed_tpu.ops.attention.flash import NEG_INF
+from deepspeed_tpu.ops.attention.flash import NEG_INF, _use_pallas
 
 __all__ = ["paged_decode_attention", "paged_decode_reference",
            "paged_decode_supported", "decode_read_bytes",
@@ -93,23 +97,27 @@ def paged_decode_supported(page_size: int, head_dim: int,
     layout constraints) — always supported. On TPU the DMA tile is
     ``(page_size, head_dim)``: Mosaic needs the lane dim 128-aligned
     (``head_dim % 128``) and the sublane dim a full tile
-    (8 fp32 / 16 bf16 / 32 int8 rows), so small pages or narrow heads
-    fall back to the gather path.
+    (8 fp32 / 16 bf16 rows), so small pages or narrow heads fall back
+    to the gather path. ``dtype`` is the POOL dtype: an int8 pool also
+    streams a ``(page_size, scale_blocks)`` fp32 scale tile per page,
+    whose lane dim Mosaic refuses whatever the page geometry, so int8
+    always gathers on TPU (tests/unit/test_tpu_compile.py holds this
+    predicate to the compiler).
     """
     if pltpu is None:
         return False, "pallas tpu backend unavailable"
     if backend is None:
-        try:
-            backend = jax.default_backend()
-        except Exception:
-            backend = "cpu"
+        backend = jax.default_backend()
     if backend != "tpu":
         return True, "interpret mode (CPU oracle path)"
     if head_dim % 128 != 0:
         return False, (f"head_dim {head_dim} not a multiple of 128 "
                        "(DMA lane dim)")
     itemsize = jnp.dtype(dtype).itemsize
-    sublane = {1: 32, 2: 16}.get(itemsize, 8)
+    if itemsize == 1:
+        return False, ("int8 pool: the (page_size, scale_blocks) fp32 "
+                       "scale tile's lane dim is not 128-aligned")
+    sublane = 16 if itemsize == 2 else 8
     if page_size % sublane != 0:
         return False, (f"page_size {page_size} not a multiple of the "
                        f"{sublane}-row sublane tile for "
@@ -317,24 +325,13 @@ def _decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     o_ref[0, 0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
 
 
-def _use_pallas():
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
 def _compiler_params(interpret):
     if pltpu is None or interpret:
         return None
-    # 0.4.x spells it TPUCompilerParams; newer releases CompilerParams
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:                                   # pragma: no cover
-        return None
     # batch programs are independent; the kv-head dim drives the DMA
     # sequence and stays arbitrary
-    return cls(dimension_semantics=("parallel", "arbitrary"))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"))
 
 
 def _paged_decode_pallas(q, kpool, vpool, scales, block_tables,

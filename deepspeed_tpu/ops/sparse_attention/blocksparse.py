@@ -30,6 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from deepspeed_tpu.ops.attention.flash import _use_pallas
+
 try:
     from jax.experimental.pallas import tpu as pltpu
 except ImportError:  # pragma: no cover
@@ -434,13 +436,6 @@ def planned_kernel(layout, block, has_am=False, interpret=False) -> str:
         # runs, O(S^2) included
         return "reference-fallback" if has_am else "masked-fallback"
     return "v1"
-
-
-def _use_pallas():
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 def _build_masked_fn(layout: np.ndarray, block: int, sm_scale: float,

@@ -24,11 +24,17 @@ wraps:
   per-head metadata cannot differ across shards. NOTE: the in-kernel
   dropout hash is keyed on the *local* head index, so a sharded run
   draws a different (equally valid) keep-mask than an unsharded one.
+- :func:`sharded_flash_attention` — the ``flash_attention`` dispatcher
+  itself (whichever kernel it selects) over q/k/v sharded by batch over
+  the data axes and by head over the model axis: what the training
+  engine's GSPMD step needs on more than one chip, where Mosaic
+  refuses "Mosaic kernels cannot be automatically partitioned".
 - :func:`pallas_kernel_mesh` / :func:`current_kernel_mesh` — a
-  trace-time context the serving engine uses to thread its mesh down to
-  the models' kernel call sites without widening every forward
-  signature: the engine traces its compiled programs under the context,
-  ``models/gpt2.paged_decode_ctx`` consults it.
+  trace-time context the engines use to thread their mesh down to the
+  models' kernel call sites without widening every forward signature:
+  an engine traces its compiled programs under the context,
+  ``ops/attention/flash.flash_attention`` and
+  ``models/gpt2.paged_decode_ctx`` consult it.
 
 Head-axis legality mirrors the PR 7 cache sharding: the mesh axis must
 divide q heads AND kv heads (each shard then owns whole GQA groups, so
@@ -37,7 +43,8 @@ group g of q head h lands on the same shard as kv head h // G).
 
 import contextlib
 import functools
-from typing import NamedTuple, Optional
+import math
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
@@ -47,12 +54,13 @@ from deepspeed_tpu.parallel.mesh import axis_size
 __all__ = ["sharded_paged_decode", "sharded_masked_flash",
            "pallas_kernel_mesh", "current_kernel_mesh", "KernelMesh",
            "head_shard_supported", "context_prefill_mesh",
-           "current_cp_mesh"]
+           "current_cp_mesh", "sharded_flash_attention"]
 
 
 class KernelMesh(NamedTuple):
     mesh: Mesh
-    axis: str
+    axis: str                           # heads shard over this axis
+    batch_axes: Tuple[str, ...] = ()    # batch shards over these
 
 
 _ACTIVE: list = []          # stack; trace-time only
@@ -60,15 +68,21 @@ _CP_ACTIVE: list = []       # context-parallel prefill stack (ISSUE 19)
 
 
 @contextlib.contextmanager
-def pallas_kernel_mesh(mesh: Optional[Mesh], axis: str = "model"):
+def pallas_kernel_mesh(mesh: Optional[Mesh], axis: str = "model",
+                       batch_axes: Tuple[str, ...] = ()):
     """Trace-time context: while active, mesh-aware kernel call sites
-    (``models/gpt2.paged_decode_ctx``) wrap their Pallas kernels in
-    shard_map over ``(mesh, axis)``. ``mesh=None`` (or an absent/size-1
-    axis) is a no-op, so callers can wrap unconditionally."""
-    if mesh is None or axis_size(mesh, axis) <= 1:
+    (``flash_attention``, ``models/gpt2.paged_decode_ctx``) wrap their
+    Pallas kernels in shard_map over the mesh — heads over ``axis``,
+    batch over ``batch_axes`` (the training engine's data axes; the
+    serving engines shard heads only). ``mesh=None`` (or axes that are
+    all absent/size-1) is a no-op, so callers can wrap
+    unconditionally."""
+    batch_axes = tuple(a for a in batch_axes if axis_size(mesh, a) > 1) \
+        if mesh is not None else ()
+    if mesh is None or (axis_size(mesh, axis) <= 1 and not batch_axes):
         yield
         return
-    _ACTIVE.append(KernelMesh(mesh, axis))
+    _ACTIVE.append(KernelMesh(mesh, axis, batch_axes))
     try:
         yield
     finally:
@@ -109,6 +123,57 @@ def head_shard_supported(n: int, *head_counts) -> bool:
     these head counts? Every count must divide (whole GQA groups per
     shard)."""
     return all(h % n == 0 for h in head_counts)
+
+
+def sharded_flash_attention(km: KernelMesh, q, k, v, mask=None,
+                            dropout_rng=None, **kwargs):
+    """``flash_attention`` with the kernel wrapped in shard_map over
+    ``km``: batch over ``km.batch_axes``, heads over ``km.axis`` —
+    attention needs no collective across either. A dim its axes do not
+    divide stays replicated (every shard then computes all of it, which
+    is what GSPMD does with such a dim anyway). With dropout each shard
+    folds its index into the rng: the in-kernel hash is keyed on the
+    LOCAL (batch, head) index, and shards must not draw the same mask.
+    ``flash_attention`` calls this when a kernel mesh is active and the
+    trace is not already inside a shard_map."""
+    from deepspeed_tpu.ops.attention.flash import _local_flash_attention
+    mesh = km.mesh
+    b, h = q.shape[:2]
+    batch_axes = km.batch_axes
+    if b % math.prod(axis_size(mesh, a) for a in batch_axes):
+        batch_axes = ()
+    nh = axis_size(mesh, km.axis)
+    head = km.axis if nh > 1 and head_shard_supported(
+        nh, h, k.shape[1]) else None
+    manual = batch_axes + ((head,) if head else ())
+    if not manual:
+        return _local_flash_attention(q, k, v, mask=mask,
+                                      dropout_rng=dropout_rng, **kwargs)
+    batch = batch_axes if len(batch_axes) > 1 else \
+        (batch_axes[0] if batch_axes else None)
+    qkv_spec = P(batch, head)
+    operands, specs = [q, k, v], [qkv_spec] * 3
+    if mask is not None:                      # (B, 1, 1, Sk) key mask
+        operands.append(mask)
+        specs.append(P(batch))
+    if dropout_rng is not None:
+        operands.append(dropout_rng)
+        specs.append(P())
+
+    def inner(q, k, v, *rest):
+        rest = list(rest)
+        m = rest.pop(0) if mask is not None else None
+        rng = rest.pop(0) if dropout_rng is not None else None
+        if rng is not None:
+            idx = 0
+            for a in manual:
+                idx = idx * axis_size(mesh, a) + jax.lax.axis_index(a)
+            rng = jax.random.fold_in(rng, idx)
+        return _local_flash_attention(q, k, v, mask=m, dropout_rng=rng,
+                                      **kwargs)
+
+    return jax.shard_map(inner, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=qkv_spec, check_vma=False)(*operands)
 
 
 def sharded_paged_decode(q, kpool, vpool, block_tables, cache_position,

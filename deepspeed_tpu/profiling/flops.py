@@ -9,8 +9,9 @@ actually runs (fused backward, remat re-computation, quantized
 collectives included), not an eager-mode estimate.
 
 MFU is reported against a small peak-FLOPs device registry (bf16 MXU
-peaks for the TPU generations this repo targets, plus a nominal CPU
-fallback so CPU smoke runs still produce a well-defined fraction).
+peaks for the TPU generations this repo targets). The CPU platform gets
+a nominal figure so CPU test runs still produce a well-defined
+fraction; any other device that is not in the table is an error.
 """
 
 import time
@@ -35,11 +36,11 @@ PEAK_FLOPS_REGISTRY = (
     ("tpu v5", 459e12),
     ("tpu v4", 275e12),
 )
-# Nominal placeholder so MFU stays a well-defined positive fraction in
-# CPU smoke runs (tests, forced-CPU bench children). Deliberately NOT a
-# measured CPU peak: CPU MFU values are only meaningful relative to
-# each other within one run.
-CPU_FALLBACK_PEAK_FLOPS = 1e11
+# Nominal placeholder so MFU stays a well-defined positive fraction on
+# the CPU platform (tests, forced-CPU bench children). Deliberately NOT
+# a measured CPU peak: CPU MFU values are only meaningful relative to
+# each other within one run. Never applied to an accelerator.
+CPU_NOMINAL_PEAK_FLOPS = 1e11
 
 
 class FlopsProfile(NamedTuple):
@@ -72,28 +73,32 @@ class FlopsProfile(NamedTuple):
 
 def peak_flops_per_device(device=None):
     """``(peak_flops, label)`` for a jax device (first local device when
-    None). Unknown accelerators fall back to the CPU placeholder with a
-    ``+nominal-peak`` label so reports can't silently claim real MFU."""
+    None). The CPU platform gets the nominal placeholder with a
+    ``+nominal-peak`` label so reports can't silently claim real MFU;
+    an accelerator whose ``device_kind`` is not in the registry raises
+    — an MFU against a made-up peak is worse than none."""
     if device is None:
         import jax
         device = jax.local_devices()[0]
-    kind = str(getattr(device, "device_kind", "cpu"))
+    kind = str(device.device_kind)
     low = kind.lower()
     for needle, peak in PEAK_FLOPS_REGISTRY:
         if needle in low:
             return peak, kind
-    return CPU_FALLBACK_PEAK_FLOPS, f"{kind}+nominal-peak"
+    if device.platform == "cpu":
+        return CPU_NOMINAL_PEAK_FLOPS, f"{kind}+nominal-peak"
+    raise ValueError(
+        f"no peak FLOP/s entry for device kind {kind!r} (platform "
+        f"{device.platform!r}); add it to PEAK_FLOPS_REGISTRY with its "
+        "source")
 
 
 def normalize_cost_analysis(cost: Any) -> dict:
-    """``compiled.cost_analysis()`` returns a list of per-module dicts on
-    jax 0.4.x and a plain dict on newer jax; normalize to
-    ``{"flops": float, "bytes_accessed": float}`` (0.0 when the backend
-    reports nothing — cost analysis is best-effort on some platforms)."""
+    """Normalize ``compiled.cost_analysis()`` (a dict, or None when the
+    backend reports nothing — cost analysis is best-effort on some
+    platforms) to ``{"flops": float, "bytes_accessed": float}``."""
     if cost is None:
         cost = {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0) or 0.0)
     nbytes = float(cost.get("bytes accessed", 0.0) or 0.0)
     return {"flops": max(flops, 0.0), "bytes_accessed": max(nbytes, 0.0)}
@@ -114,11 +119,8 @@ def _shape_specs(args):
         if hasattr(x, "shape") and hasattr(x, "dtype"):
             shd = getattr(x, "sharding", None)
             if isinstance(shd, Sharding):
-                try:
-                    return jax.ShapeDtypeStruct(np.shape(x), x.dtype,
-                                                sharding=shd)
-                except TypeError:
-                    pass  # older jax: positional-only struct
+                return jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                            sharding=shd)
             return jax.ShapeDtypeStruct(np.shape(x), x.dtype)
         return x
     return jax.tree_util.tree_map(spec, args)

@@ -5,7 +5,6 @@ Key names intentionally match the reference JSON schema
 unchanged; defaults re-tuned for TPU where noted (bf16 on by default is new).
 """
 
-import os
 
 #############################################
 # Routes
@@ -400,16 +399,18 @@ ASYNC_SYNC_LOSS_EVERY_STEP = "sync_loss_every_step"
 ASYNC_SYNC_LOSS_EVERY_STEP_DEFAULT = False
 
 #############################################
-# Persistent XLA compilation cache (TPU-native: first jit of a large
-# model costs tens of seconds — and minutes through a remote-compile
-# tunnel; caching the compiled executable on disk makes re-runs,
-# bench children, and resumed jobs start hot. No reference analog:
-# CUDA kernels there are AOT-built at install time via DS_BUILD_*
-# env flags, setup.py:47-68 — this knob is the JIT-world equivalent.)
+# Persistent XLA compilation cache (TPU-native: the first jit of a
+# large model costs a minute or more; caching the compiled executable
+# on disk makes re-runs, bench children, and resumed jobs start hot.
+# No reference analog: CUDA kernels there are AOT-built at install time
+# via DS_BUILD_* env flags, setup.py:47-68 — this knob is the JIT-world
+# equivalent.)
 #
 # "compile_cache": {
 #   "enabled": true,
-#   "dir": "~/.cache/deepspeed_tpu/xla_cache",   # the computed default
+#   "dir": null,               # null = <checkout>/.jax_cache
+#                              # (utils/platform.py); the environment's
+#                              # JAX_COMPILATION_CACHE_DIR wins over both
 #   "min_compile_secs": 1.0    # don't cache trivial programs
 # }
 #############################################
@@ -417,10 +418,7 @@ COMPILE_CACHE = "compile_cache"
 COMPILE_CACHE_ENABLED = "enabled"
 COMPILE_CACHE_ENABLED_DEFAULT = True
 COMPILE_CACHE_DIR = "dir"
-# per-user default (a world-shared /tmp path would let another local
-# user pre-own the dir — permission collisions at best)
-COMPILE_CACHE_DIR_DEFAULT = os.path.join(
-    os.path.expanduser("~"), ".cache", "deepspeed_tpu", "xla_cache")
+COMPILE_CACHE_DIR_DEFAULT = None
 COMPILE_CACHE_MIN_COMPILE_SECS = "min_compile_secs"
 COMPILE_CACHE_MIN_COMPILE_SECS_DEFAULT = 1.0
 

@@ -56,7 +56,7 @@ grammar):
                                  replica, never drop it
 
 RPC-plane points (ISSUE 16 — ``inference/rpc.py`` client and the
-``replica_worker`` child; one point per pinned error-taxonomy kind so a
+``replica_worker`` child; one point per pinned error-classification kind so a
 test targets exactly one failure mode):
 
 - ``rpc.transport``            : at the top of every RPC call attempt

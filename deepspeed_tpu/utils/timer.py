@@ -140,7 +140,7 @@ class ThroughputTimer:
         self.started = True
         if self.total_step_count >= self.start_step:
             # NO device sync here: a per-step barrier serializes the async
-            # dispatch pipeline (ruinous over a network-tunneled device).
+            # dispatch pipeline.
             # We sync only at reporting boundaries, which makes the
             # *cumulative* time — and therefore avg samples/sec — honest.
             self.start_time = time.perf_counter()

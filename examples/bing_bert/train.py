@@ -14,10 +14,6 @@ import json
 import os
 
 import jax
-
-from deepspeed_tpu.utils.platform import apply_platform_env
-
-apply_platform_env()  # honor DSTPU_PLATFORM/DSTPU_HOST_DEVICES (CLI tests)
 import numpy as np
 
 import deepspeed_tpu as ds

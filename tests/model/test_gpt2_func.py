@@ -20,7 +20,8 @@ _TRAIN = os.path.join(_ROOT, "examples", "megatron_gpt2", "train.py")
 def _launch(*args, timeout=900):
     """Run the training CLI on a forced 8-device CPU mesh; return stdout."""
     env = dict(os.environ)
-    env.update({"DSTPU_PLATFORM": "cpu", "DSTPU_HOST_DEVICES": "8",
+    env.update({"JAX_PLATFORMS": "cpu",
+                "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
                 "PYTHONPATH": _ROOT + os.pathsep + env.get("PYTHONPATH", "")})
     proc = subprocess.run(
         [sys.executable, _TRAIN, *args], env=env, cwd=_ROOT,

@@ -1,6 +1,7 @@
 """Config system tests (mirrors reference tests/unit/test_config.py)."""
 
 import json
+import os
 
 import pytest
 
@@ -212,7 +213,8 @@ class TestCompileCache:
     def test_defaults_and_override(self):
         cfg = DeepSpeedConfig(base_dict(), world_size=1)
         assert cfg.compile_cache_config["enabled"] is True
-        assert cfg.compile_cache_config["dir"].endswith("xla_cache")
+        # no dir in the JSON: the helper picks the in-checkout default
+        assert cfg.compile_cache_config["dir"] is None
         cfg = DeepSpeedConfig(
             base_dict(compile_cache={"enabled": False, "dir": "/tmp/x",
                                      "min_compile_secs": 0.0}),
@@ -220,36 +222,56 @@ class TestCompileCache:
         assert cfg.compile_cache_config == {
             "enabled": False, "dir": "/tmp/x", "min_compile_secs": 0.0}
 
-    def test_enable_populates_cache_dir(self, tmp_path, monkeypatch):
+    @pytest.fixture
+    def platform(self, monkeypatch):
+        """utils/platform with no env override, no active dir, and
+        jax's once-initialized cache object dropped; restored after."""
         import jax
-        import jax.numpy as jnp
+        from jax.experimental.compilation_cache import compilation_cache
         from deepspeed_tpu.utils import platform as P
-        monkeypatch.setattr(P, "_CACHE_ENABLED_DIR", None)
+        monkeypatch.delenv(P.COMPILE_CACHE_ENV, raising=False)
         prev = jax.config.jax_compilation_cache_dir
         prev_secs = jax.config.jax_persistent_cache_min_compile_time_secs
+        jax.config.update("jax_compilation_cache_dir", None)
+        compilation_cache.reset_cache()
+        yield P
+        jax.config.update("jax_compilation_cache_dir", prev)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          prev_secs)
+        compilation_cache.reset_cache()
 
-        def _reset_jax_cache():
-            # jax initializes its cache object once; a dir change after
-            # another test compiled (e.g. engine default cache) would be
-            # ignored without this
-            try:
-                from jax._src import compilation_cache
-                compilation_cache.reset_cache()
-            except (ImportError, AttributeError):
-                pass
+    def test_enable_populates_cache_dir(self, tmp_path, platform):
+        import jax
+        import jax.numpy as jnp
+        assert platform.enable_compile_cache(
+            str(tmp_path), min_compile_secs=0.0) == str(tmp_path)
+        # second call, different dir: the active one is reported
+        # (jax's cache dir is global — first caller wins)
+        assert platform.enable_compile_cache(
+            str(tmp_path / "other")) == str(tmp_path)
+        assert not (tmp_path / "other").exists()
+        jax.jit(lambda x: jnp.sin(x) * 41.2512)(jnp.ones((8, 8)))
+        assert os.listdir(str(tmp_path)), "no cache entry written"
 
-        _reset_jax_cache()
-        try:
-            assert P.enable_compile_cache(str(tmp_path),
-                                          min_compile_secs=0.0)
-            # second call, different dir: refused (global setting)
-            assert not P.enable_compile_cache(str(tmp_path / "other"))
-            assert P.enable_compile_cache(str(tmp_path))
-            jax.jit(lambda x: jnp.sin(x) * 41.2512)(jnp.ones((8, 8)))
-            import os
-            assert os.listdir(str(tmp_path)), "no cache entry written"
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              prev_secs)
-            _reset_jax_cache()
+    def test_default_dir_is_fixed_inside_the_checkout(self, platform):
+        import deepspeed_tpu
+        repo = os.path.dirname(os.path.dirname(
+            os.path.abspath(deepspeed_tpu.__file__)))
+        assert platform.DEFAULT_COMPILE_CACHE_DIR == \
+            os.path.join(repo, ".jax_cache")
+        assert platform.enable_compile_cache() == \
+            platform.DEFAULT_COMPILE_CACHE_DIR
+
+    def test_environment_wins_and_nothing_is_set_in_code(
+            self, tmp_path, monkeypatch, platform):
+        import jax
+        secs = jax.config.jax_persistent_cache_min_compile_time_secs
+        monkeypatch.setenv(platform.COMPILE_CACHE_ENV,
+                           str(tmp_path / "env"))
+        # a user's compile_cache.dir loses to the environment
+        assert platform.enable_compile_cache(
+            str(tmp_path / "json"), min_compile_secs=secs + 1) == \
+            str(tmp_path / "env")
+        assert jax.config.jax_compilation_cache_dir is None
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == secs
+        assert not (tmp_path / "json").exists()

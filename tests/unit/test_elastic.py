@@ -662,7 +662,7 @@ class TestSupervisor:
                                 backoff=0.0) == 0
 
     def test_restart_decision_matrix(self):
-        """The restart taxonomy is API: the preemption drain (85) and
+        """The restart classification is API: the preemption drain (85) and
         the hang watchdog's distinguished kill (87) are the ONLY exit
         codes worth another life — both certify a committed checkpoint
         chain. Everything else is a genuine failure."""
@@ -699,9 +699,6 @@ CHILD_SCRIPT = textwrap.dedent("""
     import os, sys
     sys.path.insert(0, {repo!r})
     import jax
-    jax.config.update("jax_platforms", "cpu")
-    from deepspeed_tpu.utils.jax_compat import install
-    install()
     import deepspeed_tpu
     from tests.unit.simple_model import (
         base_config, init_simple_params, random_batches, simple_loss_fn)

@@ -11,7 +11,7 @@ Tier-1 acceptance pins:
   steady-state recompiles on survivors, the dead child's flight
   recorder salvaged into the router's event trail, and the child
   relaunched under the launcher's 85/87 restart policy;
-- the RPC framing / pinned error taxonomy / bounded-backoff retry
+- the RPC framing / pinned error classification / bounded-backoff retry
   policy is testable jax-free over a socketpair in microseconds;
 - ``FleetRouter.drain()`` is idempotent — a double drain is ONE
   episode, exactly one FinishedRequest per uid;
@@ -151,7 +151,7 @@ class TestRpcWire:
 
 
 # ===================================================================== #
-# client policy: timeout, retry/backoff, taxonomy fault points
+# client policy: timeout, retry/backoff, per-kind fault points
 # ===================================================================== #
 
 def _serve_in_thread(dispatch):

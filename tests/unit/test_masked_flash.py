@@ -515,6 +515,79 @@ class TestShardedMaskedFlash:
                                  interpret=True)
 
 
+class TestFlashAttentionUnderKernelMesh:
+    """The training engine's GSPMD path: ``flash_attention`` traced
+    inside ``pallas_kernel_mesh`` runs shard_mapped — batch over the
+    data axes, heads over the model axis. (That the chip's compiler
+    needs this wrap is tests/unit/test_tpu_compile.py's to show.)"""
+
+    def _mesh(self):
+        from deepspeed_tpu.parallel.mesh import build_mesh
+        return build_mesh({"data": 2, "model": 2})
+
+    @pytest.mark.parametrize("batch_axes,axis", [
+        (("data",), "model"),       # batch and heads sharded
+        (("data",), "absent"),      # data parallel only
+        ((), "model"),              # heads only (the serving engines)
+    ])
+    def test_parity_and_grads(self, batch_axes, axis):
+        from deepspeed_tpu.parallel.pallas_shard import (
+            current_kernel_mesh, pallas_kernel_mesh)
+        mesh = self._mesh()
+        q, k, v = _qkv(hkv=2, seed=21)
+
+        def loss(q, k, v, wrapped):
+            ctx = pallas_kernel_mesh(mesh if wrapped else None, axis,
+                                     batch_axes=batch_axes)
+            with ctx:
+                assert (current_kernel_mesh() is not None) == wrapped
+                o = F.flash_attention(q, k, v, causal=True,
+                                      interpret=True)
+            return jnp.sum(o ** 2), o
+
+        grad = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                          has_aux=True),
+                       static_argnums=3)
+        (_, o_sh), g_sh = grad(q, k, v, True)
+        (_, o_ref), g_ref = grad(q, k, v, False)
+        np.testing.assert_allclose(np.asarray(o_sh), np.asarray(o_ref),
+                                   atol=1e-6)
+        for a, b, n in zip(g_sh, g_ref, "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-5, err_msg=f"d{n}")
+
+    def test_shards_draw_different_dropout_masks(self):
+        """The in-kernel hash is keyed on the LOCAL (batch, head) index:
+        without the per-shard rng fold, two data shards fed the same
+        rows would drop the same cells."""
+        from deepspeed_tpu.parallel.pallas_shard import pallas_kernel_mesh
+        mesh = self._mesh()
+        q, k, v = _qkv(B=1, seed=22)
+        q, k, v = (jnp.concatenate([x, x]) for x in (q, k, v))
+
+        @jax.jit
+        def attend(q, k, v):
+            with pallas_kernel_mesh(mesh, batch_axes=("data",)):
+                return F.flash_attention(
+                    q, k, v, causal=True, dropout_rate=0.5,
+                    dropout_rng=jax.random.PRNGKey(3), interpret=True)
+
+        o = np.asarray(attend(q, k, v))
+        assert np.isfinite(o).all()
+        assert not np.allclose(o[0], o[1])
+
+    def test_indivisible_batch_stays_replicated(self):
+        from deepspeed_tpu.parallel.pallas_shard import pallas_kernel_mesh
+        mesh = self._mesh()
+        q, k, v = _qkv(B=3, seed=23)
+        with pallas_kernel_mesh(mesh, "absent", batch_axes=("data",)):
+            o = F.flash_attention(q, k, v, causal=True, interpret=True)
+        np.testing.assert_allclose(
+            np.asarray(o),
+            np.asarray(F.flash_attention(q, k, v, causal=True,
+                                         interpret=True)), atol=1e-6)
+
+
 # --------------------------------------------------------------------- #
 # cost model (the masked_flash_flops_bytes bench row's engine)
 # --------------------------------------------------------------------- #

@@ -213,19 +213,23 @@ def test_flops_profiler_counts_matmul_flops():
 
 
 def test_peak_flops_registry():
-    from deepspeed_tpu.profiling.flops import (CPU_FALLBACK_PEAK_FLOPS,
+    from deepspeed_tpu.profiling.flops import (CPU_NOMINAL_PEAK_FLOPS,
                                                peak_flops_per_device)
 
     class FakeDev:
-        def __init__(self, kind):
+        def __init__(self, kind, platform="tpu"):
             self.device_kind = kind
+            self.platform = platform
 
     assert peak_flops_per_device(FakeDev("TPU v4"))[0] == 275e12
     assert peak_flops_per_device(FakeDev("TPU v5 lite"))[0] == 197e12
     assert peak_flops_per_device(FakeDev("TPU v5p"))[0] == 459e12
-    peak, label = peak_flops_per_device(FakeDev("cpu"))
-    assert peak == CPU_FALLBACK_PEAK_FLOPS
-    assert "nominal-peak" in label  # unknown devices can't fake real MFU
+    peak, label = peak_flops_per_device(FakeDev("cpu", platform="cpu"))
+    assert peak == CPU_NOMINAL_PEAK_FLOPS
+    assert "nominal-peak" in label  # the CPU can't fake real MFU
+    # an accelerator that is not in the table is an error, not 1e11
+    with pytest.raises(ValueError, match="TPU v9"):
+        peak_flops_per_device(FakeDev("TPU v9"))
 
 
 def test_compute_mfu():

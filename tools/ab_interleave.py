@@ -31,7 +31,7 @@ from deepspeed_tpu.models.gpt2 import (GPT2Config, gpt2_block,
 
 
 def main():
-    enable_compile_cache(None)
+    enable_compile_cache()
     # flagship block shape (GPT-2 1.5B: hidden 1600, 20 heads), seq and
     # micro-batch from the 3D bench config; 12 layers = one device's
     # stage depth at pipe=2 x V=2 for 48 layers

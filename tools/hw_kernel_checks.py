@@ -1,9 +1,12 @@
-"""On-chip kernel parity sweep: run each Pallas kernel path on the REAL
-TPU against its jnp oracle and print PASS/FAIL per check (the unit suite
-runs these in interpret mode on CPU; this is the hardware evidence).
+"""On-chip parity sweep of the SPARSE-attention kernels: run each path
+on the REAL TPU against its jnp oracle and print PASS/FAIL per check
+(the unit suite runs these in interpret mode on CPU; this is the
+hardware evidence). The kernels of the main path — masked flash causal
+with and without dropout, paged decode — are checked at real widths by
+``chip_smoke.py``, whose helpers this sweep shares.
 
 Run on hardware:  PYTHONPATH=/root/repo python tools/hw_kernel_checks.py
-(~5 min; each check pays at most one compile, shared via the persistent
+(each check pays at most one compile, shared via the persistent
 compile cache). Exits nonzero if any check fails.
 """
 
@@ -12,6 +15,9 @@ import traceback
 
 import numpy as np
 
+from chip_smoke import assert_close as _close
+from chip_smoke import grad_pair as _grad_pair
+from chip_smoke import random_qkv as _qkv
 
 CHECKS = []
 
@@ -21,101 +27,6 @@ def check(name):
         CHECKS.append((name, fn))
         return fn
     return deco
-
-
-def _qkv(B, H, S, D, kv_heads=None, seed=0):
-    import jax
-    import jax.numpy as jnp
-    key = jax.random.PRNGKey(seed)
-    kvh = kv_heads or H
-    q = jax.random.normal(jax.random.fold_in(key, 0), (B, H, S, D),
-                          jnp.bfloat16)
-    k = jax.random.normal(jax.random.fold_in(key, 1), (B, kvh, S, D),
-                          jnp.bfloat16)
-    v = jax.random.normal(jax.random.fold_in(key, 2), (B, kvh, S, D),
-                          jnp.bfloat16)
-    return q, k, v
-
-
-def _close(a, b, atol=2e-2, rtol=2e-2, msg=""):
-    np.testing.assert_allclose(np.asarray(a, np.float32),
-                               np.asarray(b, np.float32),
-                               atol=atol, rtol=rtol, err_msg=msg)
-
-
-def _grad_pair(fn_a, fn_b, args):
-    import jax
-    import jax.numpy as jnp
-    la = jax.jit(jax.grad(lambda *xs: jnp.sum(fn_a(*xs)
-                                              .astype(jnp.float32)),
-                          argnums=tuple(range(len(args)))))
-    lb = jax.jit(jax.grad(lambda *xs: jnp.sum(fn_b(*xs)
-                                              .astype(jnp.float32)),
-                          argnums=tuple(range(len(args)))))
-    return la(*args), lb(*args)
-
-
-@check("flash causal fwd+grad vs oracle (S=512)")
-def _flash_causal():
-    import functools
-    from deepspeed_tpu.ops.attention import flash as F
-    q, k, v = _qkv(2, 4, 512, 64)
-    kern = functools.partial(F.flash_attention, causal=True)
-    orac = functools.partial(F.flash_attention, causal=True,
-                             force_reference=True)
-    _close(kern(q, k, v), orac(q, k, v), msg="fwd")
-    ga, gb = _grad_pair(kern, orac, (q, k, v))
-    for a, b, n in zip(ga, gb, "qkv"):
-        _close(a, b, msg=f"d{n}")
-
-
-@check("flash GQA kv_heads=2 vs oracle (S=512)")
-def _flash_gqa():
-    import functools
-    from deepspeed_tpu.ops.attention import flash as F
-    q, k, v = _qkv(1, 8, 512, 64, kv_heads=2)
-    kern = functools.partial(F.flash_attention, causal=True)
-    orac = functools.partial(F.flash_attention, causal=True,
-                             force_reference=True)
-    _close(kern(q, k, v), orac(q, k, v), msg="fwd")
-    ga, gb = _grad_pair(kern, orac, (q, k, v))
-    for a, b, n in zip(ga, gb, "qkv"):
-        _close(a, b, msg=f"d{n}")
-
-
-@check("flash in-kernel dropout fwd/bwd consistency (S=512)")
-def _flash_dropout():
-    import jax
-    import jax.numpy as jnp
-    from deepspeed_tpu.ops.attention import flash as F
-    q, k, v = _qkv(1, 4, 512, 64)
-    rng = jax.random.PRNGKey(7)
-
-    def loss(q, k, v):
-        return jnp.sum(F.flash_attention(q, k, v, causal=True,
-                                         dropout_rate=0.1, dropout_rng=rng)
-                       .astype(jnp.float32))
-    # same seed twice -> identical loss and grads (mask regenerated
-    # identically in fwd + both bwd kernels)
-    l1 = jax.jit(loss)(q, k, v)
-    l2 = jax.jit(loss)(q, k, v)
-    assert float(l1) == float(l2), (float(l1), float(l2))
-    g1 = jax.jit(jax.grad(loss, argnums=(0,)))(q, k, v)[0]
-    g2 = jax.jit(jax.grad(loss, argnums=(0,)))(q, k, v)[0]
-    assert np.array_equal(np.asarray(g1, np.float32),
-                          np.asarray(g2, np.float32))
-
-
-@check("streamed flash (S=8192) vs oracle")
-def _flash_streamed():
-    import functools
-    from deepspeed_tpu.ops.attention import flash as F
-    assert F._use_stream(8192, 8192), "streaming not engaged at S=8192"
-    q, k, v = _qkv(1, 2, 8192, 64)
-    kern = functools.partial(F.flash_attention, causal=True)
-    orac = functools.partial(F.flash_attention, causal=True,
-                             force_reference=True)
-    _close(kern(q, k, v), orac(q, k, v), msg="fwd")
 
 
 def _sparse_vs_oracle(layout, seed, expect_kernel=None):
@@ -249,7 +160,7 @@ def main():
               "harness itself)", flush=True)
         sys.exit(3)
     from deepspeed_tpu.utils.platform import enable_compile_cache
-    enable_compile_cache(None)
+    enable_compile_cache()
     failed = 0
     for name, fn in CHECKS:
         try:
