@@ -113,7 +113,8 @@ from deepspeed_tpu.models.llama import (LlamaConfig, init_llama_params,
 from deepspeed_tpu.ops.attention.flash import NEG_INF
 from deepspeed_tpu.parallel.mesh import axis_size, build_mesh
 from deepspeed_tpu.profiling.recompile import CompileTracker
-from deepspeed_tpu.profiling.spans import ChromeTraceRecorder, trace_span
+from deepspeed_tpu.profiling.spans import (ChromeTraceRecorder, scope,
+                                           trace_span)
 from deepspeed_tpu.runtime.quantized_params import (QuantizedParam,
                                                     dequantize_param_tree,
                                                     is_quantized_tree,
@@ -812,14 +813,15 @@ class InferenceEngine:
         """Per-request sampling: greedy rows (temp <= 0) take argmax;
         the rest sample ``categorical(logits / temp)`` under the
         engine-global top-k filter with each row's own PRNG key."""
-        logits = logits.astype(jnp.float32)
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-        if self._top_k > 0:
-            kth = jax.lax.top_k(scaled, self._top_k)[0][:, -1][:, None]
-            scaled = jnp.where(scaled < kth, NEG_INF, scaled)
-        sampled = jax.vmap(jax.random.categorical)(keys, scaled)
-        return jnp.where(temps > 0, sampled.astype(jnp.int32), greedy)
+        with scope("sample"):
+            logits = logits.astype(jnp.float32)
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+            if self._top_k > 0:
+                kth = jax.lax.top_k(scaled, self._top_k)[0][:, -1][:, None]
+                scaled = jnp.where(scaled < kth, NEG_INF, scaled)
+            sampled = jax.vmap(jax.random.categorical)(keys, scaled)
+            return jnp.where(temps > 0, sampled.astype(jnp.int32), greedy)
 
     def _prefill_impl(self, params, cache, ids, lengths, slots, keys,
                       temps):
@@ -1389,28 +1391,44 @@ class InferenceEngine:
             }
         return state
 
+    def _span(self, name: str, **args):
+        """A registered host phase span (profiling/spans.HOST_SPANS):
+        its counters ride as the annotation's arguments."""
+        return trace_span(name, recorder=self._recorder, **args)
+
     def _run_prefill(self, batch) -> np.ndarray:
-        keys = np.zeros((batch.batch_bucket, 2), np.uint32)
-        temps = np.zeros((batch.batch_bucket,), np.float32)
-        for i, req in enumerate(batch.requests):
-            keys[i] = self._key_for(req.seed)
-            temps[i] = req.temperature
-        with trace_span("serve/prefill", recorder=self._recorder,
-                        batch=batch.batch_bucket,
-                        prompt=batch.prompt_bucket):
-            if self.paged:
-                suffixes = [r.prompt[pl:] for r, pl in
-                            zip(batch.requests, batch.prefix_lens)]
-                ids, lengths = pad_prompts(suffixes, batch.prompt_bucket,
-                                           batch.batch_bucket)
-                positions = np.zeros((batch.batch_bucket,), np.int32)
-                tables = np.zeros(
-                    (batch.batch_bucket, self._prefill_pps), np.int32)
-                for i, (pl, pages) in enumerate(
-                        zip(batch.prefix_lens, batch.page_tables)):
-                    positions[i] = pl
-                    tables[i, :len(pages)] = pages
-                if self._separate_pools:
+        bb, pb = batch.batch_bucket, batch.prompt_bucket
+        if self.paged:
+            prompts = [r.prompt[pl:] for r, pl in
+                       zip(batch.requests, batch.prefix_lens)]
+        else:
+            prompts = [r.prompt for r in batch.requests]
+        with self._span("serve/prefill", batch=bb, prompt=pb,
+                        real_tokens=sum(len(p) for p in prompts)):
+            with self._span("serve/prefill/build"):
+                keys = np.zeros((bb, 2), np.uint32)
+                temps = np.zeros((bb,), np.float32)
+                for i, req in enumerate(batch.requests):
+                    keys[i] = self._key_for(req.seed)
+                    temps[i] = req.temperature
+                ids, lengths = pad_prompts(prompts, pb, bb)
+                if self.paged:
+                    positions = np.zeros((bb,), np.int32)
+                    tables = np.zeros((bb, self._prefill_pps), np.int32)
+                    for i, (pl, pages) in enumerate(
+                            zip(batch.prefix_lens, batch.page_tables)):
+                        positions[i] = pl
+                        tables[i, :len(pages)] = pages
+                else:
+                    slots = np.full((bb,), self._scratch, np.int32)
+                    slots[:len(batch.slot_ids)] = batch.slot_ids
+            with self._span("serve/prefill/dispatch"):
+                if not self.paged:
+                    first, self._cache = self._prefill(
+                        self.params, self._cache, jnp.asarray(ids),
+                        jnp.asarray(lengths), jnp.asarray(slots),
+                        jnp.asarray(keys), jnp.asarray(temps))
+                elif self._separate_pools:
                     first, self._cache_prefill = self._prefill(
                         self.params, self._cache_prefill,
                         jnp.asarray(ids), jnp.asarray(lengths),
@@ -1422,18 +1440,8 @@ class InferenceEngine:
                         jnp.asarray(lengths), jnp.asarray(positions),
                         jnp.asarray(tables), jnp.asarray(keys),
                         jnp.asarray(temps))
-            else:
-                ids, lengths = pad_prompts(
-                    [r.prompt for r in batch.requests],
-                    batch.prompt_bucket, batch.batch_bucket)
-                slots = np.full((batch.batch_bucket,), self._scratch,
-                                np.int32)
-                slots[:len(batch.slot_ids)] = batch.slot_ids
-                first, self._cache = self._prefill(
-                    self.params, self._cache, jnp.asarray(ids),
-                    jnp.asarray(lengths), jnp.asarray(slots),
-                    jnp.asarray(keys), jnp.asarray(temps))
-            return np.asarray(first)
+            with self._span("serve/prefill/wait"):
+                return np.asarray(first)
 
     def _drain_request_metrics(self):
         """Per-admitted-request scalar writes (TTFT / queue wait)
@@ -1456,31 +1464,35 @@ class InferenceEngine:
         sched = self.scheduler
         self.health.heartbeat("prefill")
         t0 = time.perf_counter()
-        for batch in sched.admit():
+        with self._span("serve/admit"):
+            batches = sched.admit()
+        for batch in batches:
             t_p = time.perf_counter()
             first = self._run_prefill(batch)
             prefill_ms = (time.perf_counter() - t_p) * 1e3
-            if self._dispatch_trace is not None:
-                self._dispatch_trace.record(self._steps, "prefill")
-            for sid, req in zip(batch.slot_ids, batch.requests):
-                self._tracer.on_prefill(
-                    req.uid, sid, prefill_ms, batch.prompt_bucket,
-                    batch.batch_bucket, len(batch.requests))
-            if self.disagg:
-                now = time.perf_counter()
-                ps = self.paged_spec.page_size
-                for i, (sid, req) in enumerate(zip(batch.slot_ids,
-                                                   batch.requests)):
-                    self._handoff_q.push(HandoffRecord(
-                        uid=req.uid, slot=sid,
-                        first_token=int(first[i]),
-                        live_pages=pages_for(len(req.prompt), ps),
-                        prompt_tokens=len(req.prompt), t_ready=now))
-            else:
-                finished.extend(sched.record_tokens(
-                    {sid: int(first[i])
-                     for i, sid in enumerate(batch.slot_ids)}))
-            self._drain_request_metrics()
+            with self._span("serve/record"):
+                if self._dispatch_trace is not None:
+                    self._dispatch_trace.record(self._steps, "prefill")
+                for sid, req in zip(batch.slot_ids, batch.requests):
+                    self._tracer.on_prefill(
+                        req.uid, sid, prefill_ms, batch.prompt_bucket,
+                        batch.batch_bucket, len(batch.requests))
+                if self.disagg:
+                    now = time.perf_counter()
+                    ps = self.paged_spec.page_size
+                    for i, (sid, req) in enumerate(zip(batch.slot_ids,
+                                                       batch.requests)):
+                        self._handoff_q.push(HandoffRecord(
+                            uid=req.uid, slot=sid,
+                            first_token=int(first[i]),
+                            live_pages=pages_for(len(req.prompt), ps),
+                            prompt_tokens=len(req.prompt), t_ready=now))
+                else:
+                    finished.extend(sched.record_tokens(
+                        {sid: int(first[i])
+                         for i, sid in enumerate(batch.slot_ids)}))
+            with self._span("serve/metrics"):
+                self._drain_request_metrics()
         self._serve_secs += time.perf_counter() - t0
 
     def _chunk_phase(self, finished: List[FinishedRequest]) -> None:
@@ -1516,70 +1528,74 @@ class InferenceEngine:
             cand = [sid for sid in cand if _cp(sid) == use_cp]
         bb = pick_bucket(len(cand), self.config["batch_buckets"])
         ct = self._chunk_tokens
-        ids = np.zeros((bb, ct), np.int32)
-        lengths = np.ones((bb,), np.int32)
-        positions = np.zeros((bb,), np.int32)
-        tables = np.zeros((bb, self._prefill_pps), np.int32)
-        keys = np.zeros((bb, 2), np.uint32)
-        temps = np.zeros((bb,), np.float32)
-        spans = []
-        for i, sid in enumerate(cand):
-            slot = sched.slots[sid]
-            req = slot.request
-            start, n = sched.chunk_span(sid)
-            spans.append((sid, req, start, n,
-                          (start - slot.prefix_len) // ct))
-            ids[i, :n] = req.prompt[start:start + n]
-            lengths[i] = n
-            positions[i] = start
-            tables[i, :len(slot.pages)] = slot.pages
-            keys[i] = self._key_for(req.seed)
-            temps[i] = req.temperature
-        prog = self._chunk_cp if use_cp else self._prefill
-        t_c = time.perf_counter()
-        with trace_span("serve/chunk", recorder=self._recorder,
-                        batch=bb, chunk=ct,
-                        cp_shards=self._cp_shards if use_cp else 1):
-            if self._separate_pools:
-                first, self._cache_prefill = prog(
-                    self.params, self._cache_prefill, jnp.asarray(ids),
-                    jnp.asarray(lengths), jnp.asarray(positions),
-                    jnp.asarray(tables), jnp.asarray(keys),
-                    jnp.asarray(temps))
-            else:
-                first, self._cache = prog(
-                    self.params, self._cache, jnp.asarray(ids),
-                    jnp.asarray(lengths), jnp.asarray(positions),
-                    jnp.asarray(tables), jnp.asarray(keys),
-                    jnp.asarray(temps))
-            # host sync: final chunks release their first token
-            first = np.asarray(first)
-        wall_ms = (time.perf_counter() - t_c) * 1e3
-        if self._dispatch_trace is not None:
-            self._dispatch_trace.record(self._steps, "chunk")
-        self._chunk_dispatches += 1
         shards = self._cp_shards if use_cp else 1
-        now = time.perf_counter()
-        released: Dict[int, int] = {}
-        for i, (sid, req, start, n, k) in enumerate(spans):
-            self._tracer.on_prefill_chunk(req.uid, sid, k, n, wall_ms,
-                                          cp_shards=shards)
-            if not sched.record_chunk(sid, n):
-                continue                    # mid-prompt, keep chunking
-            if self.disagg:
-                ps = self.paged_spec.page_size
-                self._handoff_q.push(HandoffRecord(
-                    uid=req.uid, slot=sid, first_token=int(first[i]),
-                    live_pages=pages_for(len(req.prompt), ps),
-                    prompt_tokens=len(req.prompt), t_ready=now))
-            else:
-                released[sid] = int(first[i])
-        if released:
-            finished.extend(sched.record_tokens(released))
-        self.monitor.write_serving_metrics(
-            chunk_dispatches=self._chunk_dispatches,
-            tokens=sched.total_tokens, flush=False)
-        self._drain_request_metrics()
+        prog = self._chunk_cp if use_cp else self._prefill
+        with self._span("serve/chunk", batch=bb, chunk=ct,
+                        cp_shards=shards):
+            with self._span("serve/chunk/build"):
+                ids = np.zeros((bb, ct), np.int32)
+                lengths = np.ones((bb,), np.int32)
+                positions = np.zeros((bb,), np.int32)
+                tables = np.zeros((bb, self._prefill_pps), np.int32)
+                keys = np.zeros((bb, 2), np.uint32)
+                temps = np.zeros((bb,), np.float32)
+                spans = []
+                for i, sid in enumerate(cand):
+                    slot = sched.slots[sid]
+                    req = slot.request
+                    start, n = sched.chunk_span(sid)
+                    spans.append((sid, req, start, n,
+                                  (start - slot.prefix_len) // ct))
+                    ids[i, :n] = req.prompt[start:start + n]
+                    lengths[i] = n
+                    positions[i] = start
+                    tables[i, :len(slot.pages)] = slot.pages
+                    keys[i] = self._key_for(req.seed)
+                    temps[i] = req.temperature
+            t_c = time.perf_counter()
+            with self._span("serve/chunk/dispatch"):
+                if self._separate_pools:
+                    first, self._cache_prefill = prog(
+                        self.params, self._cache_prefill,
+                        jnp.asarray(ids), jnp.asarray(lengths),
+                        jnp.asarray(positions), jnp.asarray(tables),
+                        jnp.asarray(keys), jnp.asarray(temps))
+                else:
+                    first, self._cache = prog(
+                        self.params, self._cache, jnp.asarray(ids),
+                        jnp.asarray(lengths), jnp.asarray(positions),
+                        jnp.asarray(tables), jnp.asarray(keys),
+                        jnp.asarray(temps))
+            with self._span("serve/chunk/wait"):
+                # host sync: final chunks release their first token
+                first = np.asarray(first)
+        wall_ms = (time.perf_counter() - t_c) * 1e3
+        with self._span("serve/record"):
+            if self._dispatch_trace is not None:
+                self._dispatch_trace.record(self._steps, "chunk")
+            self._chunk_dispatches += 1
+            now = time.perf_counter()
+            released: Dict[int, int] = {}
+            for i, (sid, req, start, n, k) in enumerate(spans):
+                self._tracer.on_prefill_chunk(req.uid, sid, k, n, wall_ms,
+                                              cp_shards=shards)
+                if not sched.record_chunk(sid, n):
+                    continue                # mid-prompt, keep chunking
+                if self.disagg:
+                    ps = self.paged_spec.page_size
+                    self._handoff_q.push(HandoffRecord(
+                        uid=req.uid, slot=sid, first_token=int(first[i]),
+                        live_pages=pages_for(len(req.prompt), ps),
+                        prompt_tokens=len(req.prompt), t_ready=now))
+                else:
+                    released[sid] = int(first[i])
+            if released:
+                finished.extend(sched.record_tokens(released))
+        with self._span("serve/metrics"):
+            self.monitor.write_serving_metrics(
+                chunk_dispatches=self._chunk_dispatches,
+                tokens=sched.total_tokens, flush=False)
+            self._drain_request_metrics()
         self._serve_secs += time.perf_counter() - t0
 
     def _claim_phase(self, finished: List[FinishedRequest]) -> None:
@@ -1670,16 +1686,6 @@ class InferenceEngine:
             return False
         t0 = time.perf_counter()
         occupancy = len(sids) / self.num_slots
-        toks_a = np.zeros((self._rows,), np.int32)
-        poss_a = np.zeros((self._rows,), np.int32)
-        temps_a = np.zeros((self._rows,), np.float32)
-        keys_a = np.zeros((self._rows, 2), np.uint32)
-        for sid, tok, pos, temp, seed in zip(sids, toks, poss, temps,
-                                             seeds):
-            toks_a[sid] = tok
-            poss_a[sid] = pos
-            temps_a[sid] = temp
-            keys_a[sid] = self._key_for(seed)
         props: Dict[int, List[int]] = {}
         if self.spec and self.paged:
             props = sched.draft_proposals(
@@ -1687,26 +1693,36 @@ class InferenceEngine:
         spec_kw = {}
         runs: Dict[int, List[int]] = {}
         draft_stats = None
-        t_d = time.perf_counter()
+        # what this dispatch reads, as the span's counters
+        live_tokens = sched.tokens_in_flight
+        counters = dict(rows=self._rows, live_tokens=live_tokens)
+        if self.paged:
+            counters["page_size"] = self.paged_spec.page_size
         if props:
             dmax = max(len(p) for p in props.values())
             v = pick_bucket(dmax + 1, self._verify_widths)
-            vt = np.zeros((self._rows, v), np.int32)
-            vt[:, 0] = toks_a
-            for sid, p in props.items():
-                vt[sid, 1:1 + len(p)] = p
             # verify tables ride at FULL width: one compiled program
             # per verify width, not per width x page bucket
-            tables = sched.block_table_rows(
-                self._rows, self.paged_spec.pages_per_seq)
-            with trace_span("serve/verify", recorder=self._recorder,
-                            active=len(sids), width=v):
-                out, self._cache = self._verify(
-                    self.params_decode, self._cache, jnp.asarray(vt),
-                    jnp.asarray(poss_a), jnp.asarray(tables),
-                    jnp.asarray(keys_a), jnp.asarray(temps_a))
-                # host sync: the scheduler needs the token values
-                out = np.asarray(out)
+            width = self.paged_spec.pages_per_seq
+            with self._span("serve/verify", width=v, table_pages=width,
+                            **counters):
+                with self._span("serve/verify/build"):
+                    toks_a, poss_a, temps_a, keys_a = self._decode_arrays(
+                        sids, toks, poss, temps, seeds)
+                    t_d = time.perf_counter()
+                    vt = np.zeros((self._rows, v), np.int32)
+                    vt[:, 0] = toks_a
+                    for sid, p in props.items():
+                        vt[sid, 1:1 + len(p)] = p
+                    tables = sched.block_table_rows(self._rows, width)
+                with self._span("serve/verify/dispatch"):
+                    out, self._cache = self._verify(
+                        self.params_decode, self._cache, jnp.asarray(vt),
+                        jnp.asarray(poss_a), jnp.asarray(tables),
+                        jnp.asarray(keys_a), jnp.asarray(temps_a))
+                with self._span("serve/verify/wait"):
+                    # host sync: the scheduler needs the token values
+                    out = np.asarray(out)
             if self._dispatch_trace is not None:
                 self._dispatch_trace.record(self._steps, "verify")
             draft_stats = {}
@@ -1733,30 +1749,40 @@ class InferenceEngine:
                 spec_kw["spec_accept_rate"] = (accepted_total
                                                / proposed_total)
         else:
-            with trace_span("serve/decode", recorder=self._recorder,
-                            active=len(sids)):
-                if self.paged:
-                    # clamp the dispatch's table width to the batch's
-                    # live-page bucket: reads (kernel walk or gather
-                    # stripe) scale with tokens in flight, and every
-                    # width was compiled at warmup
-                    width = pick_bucket(
-                        min(sched.max_live_pages(),
-                            self.paged_spec.pages_per_seq),
-                        self._decode_page_buckets)
-                    tables = sched.block_table_rows(self._rows, width)
-                    nxt, self._cache = self._decode(
-                        self.params_decode, self._cache,
-                        jnp.asarray(toks_a), jnp.asarray(poss_a),
-                        jnp.asarray(tables), jnp.asarray(keys_a),
-                        jnp.asarray(temps_a))
-                else:
-                    nxt, self._cache = self._decode(
-                        self.params_decode, self._cache,
-                        jnp.asarray(toks_a), jnp.asarray(poss_a),
-                        jnp.asarray(keys_a), jnp.asarray(temps_a))
-                # host sync: the scheduler needs the token values
-                nxt = np.asarray(nxt)
+            if self.paged:
+                # clamp the dispatch's table width to the batch's
+                # live-page bucket: reads (kernel walk or gather
+                # stripe) scale with tokens in flight, and every
+                # width was compiled at warmup
+                width = pick_bucket(
+                    min(sched.max_live_pages(),
+                        self.paged_spec.pages_per_seq),
+                    self._decode_page_buckets)
+                counters["table_pages"] = width
+            with self._span("serve/decode", **counters):
+                with self._span("serve/decode/build"):
+                    toks_a, poss_a, temps_a, keys_a = self._decode_arrays(
+                        sids, toks, poss, temps, seeds)
+                    # Serve/token_latency_ms runs from the end of the
+                    # slot loop (verify's too), block tables included
+                    t_d = time.perf_counter()
+                    if self.paged:
+                        tables = sched.block_table_rows(self._rows, width)
+                with self._span("serve/decode/dispatch"):
+                    if self.paged:
+                        nxt, self._cache = self._decode(
+                            self.params_decode, self._cache,
+                            jnp.asarray(toks_a), jnp.asarray(poss_a),
+                            jnp.asarray(tables), jnp.asarray(keys_a),
+                            jnp.asarray(temps_a))
+                    else:
+                        nxt, self._cache = self._decode(
+                            self.params_decode, self._cache,
+                            jnp.asarray(toks_a), jnp.asarray(poss_a),
+                            jnp.asarray(keys_a), jnp.asarray(temps_a))
+                with self._span("serve/decode/wait"):
+                    # host sync: the scheduler needs the token values
+                    nxt = np.asarray(nxt)
             if self._dispatch_trace is not None:
                 self._dispatch_trace.record(self._steps, "decode")
             runs = {sid: [int(nxt[sid])] for sid in sids}
@@ -1767,8 +1793,36 @@ class InferenceEngine:
                     self._tracer.on_defer(
                         sched.slots[sid].request.uid, "draft_stall")
         tok_ms = (time.perf_counter() - t_d) * 1e3
-        finished.extend(sched.record_token_runs(runs, draft_stats))
+        with self._span("serve/record"):
+            finished.extend(sched.record_token_runs(runs, draft_stats))
         self._serve_secs += time.perf_counter() - t0
+        with self._span("serve/metrics"):
+            self._write_decode_metrics(tok_ms, occupancy, live_tokens,
+                                       spec_kw)
+        return True
+
+    def _decode_arrays(self, sids, toks, poss, temps, seeds):
+        """The decode dispatch's per-row host arrays over the full slot
+        table (inactive rows stay zero)."""
+        toks_a = np.zeros((self._rows,), np.int32)
+        poss_a = np.zeros((self._rows,), np.int32)
+        temps_a = np.zeros((self._rows,), np.float32)
+        keys_a = np.zeros((self._rows, 2), np.uint32)
+        for sid, tok, pos, temp, seed in zip(sids, toks, poss, temps,
+                                             seeds):
+            toks_a[sid] = tok
+            poss_a[sid] = pos
+            temps_a[sid] = temp
+            keys_a[sid] = self._key_for(seed)
+        return toks_a, poss_a, temps_a, keys_a
+
+    def _write_decode_metrics(self, tok_ms, occupancy, live_tokens,
+                              spec_kw) -> None:
+        """The per-dispatch ``Serve/*`` scalars (buffered; ``step``
+        flushes). ``live_tokens`` is what the dispatch read: the
+        scheduler's ``tokens_in_flight`` before it, walked once for the
+        ``serve/decode`` span and ``Serve/tokens_in_flight`` both."""
+        sched = self.scheduler
         tps = (sched.total_tokens / self._serve_secs
                if self._serve_secs > 0 else 0.0)
         paged_kw = {}
@@ -1779,7 +1833,7 @@ class InferenceEngine:
                     + hit_alloc.prefix_miss_tokens)
             paged_kw = dict(
                 kv_pages_in_use=alloc.pages_in_use,
-                tokens_in_flight=sched.tokens_in_flight,
+                tokens_in_flight=live_tokens,
                 prefix_hit_rate=(hit_alloc.prefix_hit_tokens / seen
                                  if seen else 0.0),
                 decode_attn_path=(
@@ -1806,7 +1860,6 @@ class InferenceEngine:
             queue_depth=sched.queue_depth, batch_occupancy=occupancy,
             tokens=sched.total_tokens, flush=False, **paged_kw,
             **slo_kw, **spec_kw)
-        return True
 
     def step(self) -> List[FinishedRequest]:
         """One serving iteration. Default: admit waiting requests into
@@ -1841,12 +1894,13 @@ class InferenceEngine:
 
         # serve_finish / serve_evict rows are emitted by the tracer as
         # the scheduler retires each request (sync-free host appends)
-        self.monitor.flush()
         self._steps += 1
-        if self._log is not None and self._state_event_every and \
-                self._steps % self._state_event_every == 0:
-            self._log.add_event("serve_state", step=self._steps,
-                                **self.debug_state())
+        with self._span("serve/metrics"):
+            self.monitor.flush()
+            if self._log is not None and self._state_event_every and \
+                    self._steps % self._state_event_every == 0:
+                self._log.add_event("serve_state", step=self._steps,
+                                    **self.debug_state())
         return finished
 
     def run(self) -> List[FinishedRequest]:

@@ -17,6 +17,7 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.ops.attention.flash import (NEG_INF,
                                                flash_attention)
+from deepspeed_tpu.profiling.spans import scope
 
 
 class GPT2Config(NamedTuple):
@@ -139,7 +140,8 @@ from deepspeed_tpu.ops.functional import layer_norm as _ln_wb
 
 
 def _layer_norm(x, p, eps):
-    return _ln_wb(x, p["w"], p["b"], eps)
+    with scope("ln"):
+        return _ln_wb(x, p["w"], p["b"], eps)
 
 
 def _wd(leaf, dtype):
@@ -151,9 +153,10 @@ def _wd(leaf, dtype):
     QuantizedParam leaves, so the training path compiles unchanged."""
     from deepspeed_tpu.runtime.quantized_params import (QuantizedParam,
                                                         dequantize_param)
-    if isinstance(leaf, QuantizedParam):
-        return dequantize_param(leaf, dtype)
-    return leaf.astype(dtype)
+    with scope("weight_cast"):
+        if isinstance(leaf, QuantizedParam):
+            return dequantize_param(leaf, dtype)
+        return leaf.astype(dtype)
 
 
 def _emb_rows(leaf, ids, dtype):
@@ -174,25 +177,29 @@ def _embed(wte, wpe, ids, dtype):
     """Token + position embedding (shared by flat and pipelined forms)."""
     from deepspeed_tpu.runtime.quantized_params import QuantizedParam
     pos = jnp.arange(ids.shape[1])[None, :]
-    if isinstance(wte, QuantizedParam) or isinstance(wpe, QuantizedParam):
-        return (_emb_rows(wte, ids, jnp.float32)
-                + _emb_rows(wpe, pos, jnp.float32)).astype(dtype)
-    return (wte[ids] + wpe[pos]).astype(dtype)
+    with scope("embed"):
+        if isinstance(wte, QuantizedParam) or \
+                isinstance(wpe, QuantizedParam):
+            return (_emb_rows(wte, ids, jnp.float32)
+                    + _emb_rows(wpe, pos, jnp.float32)).astype(dtype)
+        return (wte[ids] + wpe[pos]).astype(dtype)
 
 
 def _tied_logits(x, wte, dtype):
     """LM head tied to the embedding: bf16 operands, fp32 accumulation —
     keeps the vocab GEMM on the MXU's fast path while the downstream
     softmax stays fp32."""
-    return jax.lax.dot_general(
-        x.astype(dtype), _wd(wte, dtype),
-        (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    with scope("lm_head"):
+        return jax.lax.dot_general(
+            x.astype(dtype), _wd(wte, dtype),
+            (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
 
 def _next_token_xent(logits, targets):
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return -jnp.mean(ll)
+    with scope("loss_head"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        return -jnp.mean(ll)
 
 
 def _tied_xent_chunked(x, wte, targets, dtype, chunk_tokens: int = 2048,
@@ -207,6 +214,12 @@ def _tied_xent_chunked(x, wte, targets, dtype, chunk_tokens: int = 2048,
     ~10% more MXU flops for a large cut in HBM traffic. The scan carries
     only the scalar loss.
     """
+    with scope("loss_head"):
+        return _tied_xent_scan(x, wte, targets, dtype, chunk_tokens, mean,
+                               weights)
+
+
+def _tied_xent_scan(x, wte, targets, dtype, chunk_tokens, mean, weights):
     B, S, H = x.shape
     n = B * S
     xf = x.reshape(n, H)
@@ -264,41 +277,49 @@ def gpt2_block(block_params, config: GPT2Config, x, rng, deterministic,
     # attention (pre-LN)
     a_in = _layer_norm(x, block_params["ln_1"], config.layer_norm_eps)
     ap = block_params["attn"]
-    qkv = a_in @ _wd(ap["qkvw"], dtype) + _wd(ap["qkvb"], dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(B, S, heads, hd).transpose(0, 2, 1, 3)
-    k = k.reshape(B, S, heads, hd).transpose(0, 2, 1, 3)
-    v = v.reshape(B, S, heads, hd).transpose(0, 2, 1, 3)
+    with scope("attn_proj"):
+        qkv = a_in @ _wd(ap["qkvw"], dtype) + _wd(ap["qkvb"], dtype)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q = q.reshape(B, S, heads, hd).transpose(0, 2, 1, 3)
+        k = k.reshape(B, S, heads, hd).transpose(0, 2, 1, 3)
+        v = v.reshape(B, S, heads, hd).transpose(0, 2, 1, 3)
     drop = (config.attn_dropout
             if not deterministic and rng is not None else 0.0)
     if drop > 0.0:
         r1, r_attn = jax.random.split(r1)
     else:
         r_attn = None
-    if attention_fn is not None:
-        ctx = attention_fn(q, k, v, drop, r_attn)
-    elif drop > 0.0:
-        # attention dropout runs inside the Pallas kernel (counter-based
-        # hash mask regenerated in fwd and bwd — no (S, S) mask in HBM)
-        ctx = flash_attention(q, k, v, causal=True, dropout_rate=drop,
-                              dropout_rng=r_attn)
-    else:
-        ctx = flash_attention(q, k, v, causal=True)
-    ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, h)
-    attn_out = ctx @ _wd(ap["ow"], dtype) + _wd(ap["ob"], dtype)
+    # the cached attention_fns open their own scopes inside this one
+    # (kv_write, kv_gather, attn_cached): innermost wins
+    with scope("attn_core"):
+        if attention_fn is not None:
+            ctx = attention_fn(q, k, v, drop, r_attn)
+        elif drop > 0.0:
+            # attention dropout runs inside the Pallas kernel
+            # (counter-based hash mask regenerated in fwd and bwd — no
+            # (S, S) mask in HBM)
+            ctx = flash_attention(q, k, v, causal=True, dropout_rate=drop,
+                                  dropout_rng=r_attn)
+        else:
+            ctx = flash_attention(q, k, v, causal=True)
+    with scope("attn_proj"):
+        ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, h)
+        attn_out = ctx @ _wd(ap["ow"], dtype) + _wd(ap["ob"], dtype)
     x = x + _dropout(attn_out, config.resid_dropout, r1, deterministic)
 
     # mlp
     m_in = _layer_norm(x, block_params["ln_2"], config.layer_norm_eps)
     mp = block_params["mlp"]
     if mlp_fn is not None:
-        m_out, aux = mlp_fn(mp, m_in)
+        with scope("mlp"):
+            m_out, aux = mlp_fn(mp, m_in)
         x = x + _dropout(m_out.astype(dtype), config.resid_dropout, r2,
                          deterministic)
         return x, aux
-    hmid = m_in @ _wd(mp["fc_w"], dtype) + _wd(mp["fc_b"], dtype)
-    hmid = jax.nn.gelu(hmid, approximate=True)
-    m_out = hmid @ _wd(mp["proj_w"], dtype) + _wd(mp["proj_b"], dtype)
+    with scope("mlp"):
+        hmid = m_in @ _wd(mp["fc_w"], dtype) + _wd(mp["fc_b"], dtype)
+        hmid = jax.nn.gelu(hmid, approximate=True)
+        m_out = hmid @ _wd(mp["proj_w"], dtype) + _wd(mp["proj_b"], dtype)
     x = x + _dropout(m_out, config.resid_dropout, r2, deterministic)
     return x
 
@@ -380,30 +401,32 @@ def _gpt2_trunk_cached(params, config: GPT2Config, input_ids, kv_cache,
     ``(kc, vc, kscale, vscale)`` (scale pools
     (layers, num_pages, heads, page_size, nb) fp32) — writes quantize
     per token row, reads dequantize at the attention site."""
-    kc, vc = kv_cache[0], kv_cache[1]
-    kscale, vscale = (kv_cache[2], kv_cache[3]) if len(kv_cache) == 4 \
-        else (None, None)
+    quantized = len(kv_cache) == 4
     B, S = input_ids.shape
     pos = cache_position[:, None] + jnp.arange(S)[None, :]
-    x = (_emb_rows(params["wte"], input_ids, jnp.float32)
-         + _emb_rows(params["wpe"], pos, jnp.float32)).astype(dtype)
+    with scope("embed"):
+        x = (_emb_rows(params["wte"], input_ids, jnp.float32)
+             + _emb_rows(params["wpe"], pos, jnp.float32)).astype(dtype)
     new_caches = []
     for i in range(config.num_layers):
         box = []
+        with scope("kv_write"):        # the layer's slice of the cache
+            layer = tuple(leaf[i] for leaf in kv_cache)
         if block_tables is not None:
             attn = _paged_cache_attention(
-                kc[i], vc[i], block_tables, cache_position, box,
+                layer[0], layer[1], block_tables, cache_position, box,
                 attn_kernel=paged_attn_kernel,
-                kscale_pool=None if kscale is None else kscale[i],
-                vscale_pool=None if vscale is None else vscale[i])
+                kscale_pool=layer[2] if quantized else None,
+                vscale_pool=layer[3] if quantized else None)
         else:
-            attn = _offset_cache_attention(kc[i], vc[i], cache_position,
-                                           box)
+            attn = _offset_cache_attention(layer[0], layer[1],
+                                           cache_position, box)
         x = gpt2_block(layer_params(params, config, i), config, x, None,
                        True, dtype, attention_fn=attn)
         new_caches.append(box[0])
     x = _layer_norm(x, params["ln_f"], config.layer_norm_eps)
-    return x, tuple(jnp.stack(leaf) for leaf in zip(*new_caches))
+    with scope("kv_write"):            # the layers stacked back
+        return x, tuple(jnp.stack(leaf) for leaf in zip(*new_caches))
 
 
 def gpt2_forward(params, config: GPT2Config, input_ids, rng=None,
@@ -527,9 +550,10 @@ def write_kv_cache(cache, new, cache_position):
     ``lax.dynamic_update_slice`` vmapped over the batch so every serving
     slot advances at its own offset (continuous batching: slots are at
     different sequence lengths)."""
-    return jax.vmap(
-        lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (0, s, 0))
-    )(cache, new.astype(cache.dtype), cache_position)
+    with scope("kv_write"):
+        return jax.vmap(
+            lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (0, s, 0))
+        )(cache, new.astype(cache.dtype), cache_position)
 
 
 def write_paged_kv_cache(pool, new, block_table, cache_position):
@@ -545,15 +569,18 @@ def write_paged_kv_cache(pool, new, block_table, cache_position):
     B, H, S, hd = new.shape
     P = block_table.shape[1]
     ps = pool.shape[2]
-    pos = cache_position[:, None] + jnp.arange(S)[None, :]       # (B, S)
-    slot = pos // ps
-    page = jnp.where(
-        slot < P,
-        jnp.take_along_axis(block_table, jnp.minimum(slot, P - 1), axis=1),
-        0)
-    vals = new.astype(pool.dtype).transpose(0, 2, 1, 3).reshape(
-        B * S, H, hd)
-    return pool.at[page.reshape(-1), :, (pos % ps).reshape(-1)].set(vals)
+    with scope("kv_write"):
+        pos = cache_position[:, None] + jnp.arange(S)[None, :]   # (B, S)
+        slot = pos // ps
+        page = jnp.where(
+            slot < P,
+            jnp.take_along_axis(block_table, jnp.minimum(slot, P - 1),
+                                axis=1),
+            0)
+        vals = new.astype(pool.dtype).transpose(0, 2, 1, 3).reshape(
+            B * S, H, hd)
+        return pool.at[page.reshape(-1), :, (pos % ps).reshape(-1)].set(
+            vals)
 
 
 def gather_paged_kv(pool, block_table):
@@ -575,8 +602,9 @@ def gather_paged_kv(pool, block_table):
     (``inference.paged_kv.decode_page_buckets``)."""
     B, P = block_table.shape
     _, H, ps, hd = pool.shape
-    return pool[block_table].transpose(0, 2, 1, 3, 4).reshape(
-        B, H, P * ps, hd)
+    with scope("kv_gather"):
+        return pool[block_table].transpose(0, 2, 1, 3, 4).reshape(
+            B, H, P * ps, hd)
 
 
 def paged_decode_ctx(q, kpool, vpool, block_table, cache_position,
@@ -598,17 +626,19 @@ def paged_decode_ctx(q, kpool, vpool, block_table, cache_position,
     from deepspeed_tpu.parallel.pallas_shard import (current_kernel_mesh,
                                                      sharded_paged_decode)
     km = current_kernel_mesh()
-    if km is not None:
-        out = sharded_paged_decode(q[:, :, 0], kpool, vpool, block_table,
-                                   cache_position, mesh=km.mesh,
-                                   axis=km.axis, k_scales=k_scales,
-                                   v_scales=v_scales)
-    else:
-        out = paged_decode_attention(q[:, :, 0], kpool, vpool,
-                                     block_table, cache_position,
-                                     k_scales=k_scales,
-                                     v_scales=v_scales)
-    return out[:, :, None, :]
+    with scope("attn_cached"):
+        if km is not None:
+            out = sharded_paged_decode(q[:, :, 0], kpool, vpool,
+                                       block_table, cache_position,
+                                       mesh=km.mesh, axis=km.axis,
+                                       k_scales=k_scales,
+                                       v_scales=v_scales)
+        else:
+            out = paged_decode_attention(q[:, :, 0], kpool, vpool,
+                                         block_table, cache_position,
+                                         k_scales=k_scales,
+                                         v_scales=v_scales)
+        return out[:, :, None, :]
 
 
 def _paged_cache_attention(kpool, vpool, block_table, cache_position,
@@ -638,8 +668,9 @@ def _paged_cache_attention(kpool, vpool, block_table, cache_position,
             from deepspeed_tpu.ops.attention.paged import (dequantize_pool,
                                                            quantize_kv)
             nb = kscale_pool.shape[-1]
-            k_q, k_s = quantize_kv(k, nb)
-            v_q, v_s = quantize_kv(v, nb)
+            with scope("kv_write"):
+                k_q, k_s = quantize_kv(k, nb)
+                v_q, v_s = quantize_kv(v, nb)
             kp = write_paged_kv_cache(kpool, k_q, block_table,
                                       cache_position)
             vp = write_paged_kv_cache(vpool, v_q, block_table,
@@ -663,8 +694,9 @@ def _paged_cache_attention(kpool, vpool, block_table, cache_position,
         kc = gather_paged_kv(kp, block_table)
         vc = gather_paged_kv(vp, block_table)
         if quantized:
-            kc = dequantize_pool(kc, gather_paged_kv(ksp, block_table))
-            vc = dequantize_pool(vc, gather_paged_kv(vsp, block_table))
+            with scope("kv_gather"):
+                kc = dequantize_pool(kc, gather_paged_kv(ksp, block_table))
+                vc = dequantize_pool(vc, gather_paged_kv(vsp, block_table))
         if q.shape[2] > 1:
             # context-parallel chunked prefill (ISSUE 19): under the
             # engine's CP trace context, the chunk's sequence axis runs
@@ -678,6 +710,18 @@ def _paged_cache_attention(kpool, vpool, block_table, cache_position,
                     ring_prefill_attention
                 return ring_prefill_attention(q, kc, vc, cache_position,
                                               cp.mesh, cp.axis)
+        with scope("kv_gather"):
+            # the gathered stripe's cast to the attention math's float32
+            # is the reader's cost, not the attention's
+            kc, vc = kc.astype(jnp.float32), vc.astype(jnp.float32)
+        return _stripe_attention(q, kc, vc, cache_position)
+    return attn
+
+
+def _stripe_attention(q, kc, vc, cache_position):
+    """Scores, offset-causal mask, softmax and context of ``q`` over a
+    whole key/value stripe (B, heads, kv_len, hd), in float32."""
+    with scope("attn_cached"):
         hd = q.shape[-1]
         scores = jnp.einsum("bhqd,bhld->bhql", q.astype(jnp.float32),
                             kc.astype(jnp.float32)) / np.sqrt(hd)
@@ -686,7 +730,6 @@ def _paged_cache_attention(kpool, vpool, block_table, cache_position,
         probs = jax.nn.softmax(scores, axis=-1)
         return jnp.einsum("bhql,bhld->bhqd", probs,
                           vc.astype(jnp.float32)).astype(q.dtype)
-    return attn
 
 
 def _offset_cache_attention(kcache, vcache, cache_position, out_box):
@@ -700,14 +743,7 @@ def _offset_cache_attention(kcache, vcache, cache_position, out_box):
         kc = write_kv_cache(kcache, k, cache_position)
         vc = write_kv_cache(vcache, v, cache_position)
         out_box.append((kc, vc))
-        hd = q.shape[-1]
-        scores = jnp.einsum("bhqd,bhld->bhql", q.astype(jnp.float32),
-                            kc.astype(jnp.float32)) / np.sqrt(hd)
-        mask = causal_cache_mask(cache_position, q.shape[2], kc.shape[2])
-        scores = jnp.where(mask, scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
-        return jnp.einsum("bhql,bhld->bhqd", probs,
-                          vc.astype(jnp.float32)).astype(q.dtype)
+        return _stripe_attention(q, kc, vc, cache_position)
     return attn
 
 
@@ -786,8 +822,9 @@ def gpt2_generate(params, config: GPT2Config, prompt_ids, max_new_tokens,
     def step_logits(tok, t, caches):
         kc, vc = caches
         pos = P + t                       # position of `tok` in the stream
-        x = (params["wte"][tok[:, None]]
-             + params["wpe"][pos][None, None]).astype(dtype)
+        with scope("embed"):
+            x = (params["wte"][tok[:, None]]
+                 + params["wpe"][pos][None, None]).astype(dtype)
         new_kc, new_vc = [], []
         for i in range(nl):
             box = []
@@ -946,7 +983,8 @@ def gpt2_sp_loss_fn(config: GPT2Config, mesh, dtype=jnp.bfloat16,
             inputs, targets = win[:, :-1], win[:, 1:]
             pos_emb = jax.lax.dynamic_slice_in_dim(params["wpe"],
                                                    idx * sl, sl, axis=0)
-        x = (params["wte"][inputs] + pos_emb[None]).astype(dtype)
+        with scope("embed"):
+            x = (params["wte"][inputs] + pos_emb[None]).astype(dtype)
         if rng is not None and not deterministic:
             rng = jax.random.fold_in(rng, 0)
             rng, r_emb = jax.random.split(rng)

@@ -191,7 +191,7 @@ class Observer:
         """Phase span: XLA TraceAnnotation always (near-free, shows in
         captured traces even with observability off), Chrome-trace event
         when enabled. trace_span itself never raises from
-        instrumentation (annotation enter/exit are guarded in-body)."""
+        instrumentation (one guard, at import)."""
         return trace_span(name, recorder=self.recorder, **extra)
 
     def wants_flops_profile(self, name: str) -> bool:

@@ -1,27 +1,81 @@
-"""Structured trace spans: one context manager, two sinks.
+"""Structured trace spans and named scopes: the program's own names in
+the one ``jax.profiler`` trace.
 
-``trace_span("forward")`` emits
-- a ``jax.profiler.TraceAnnotation`` — the span shows up inside captured
-  XLA traces (the ``profiler``/``observability.trace`` window), nested
-  under the device timeline exactly where it ran; and
+Host side, ``trace_span("serve/decode", active=3)`` is one context
+manager with two sinks:
+- a ``jax.profiler.TraceAnnotation`` carrying the keyword arguments —
+  the span shows up inside a captured trace on the profiler's clock (the
+  device planes' clock), and ``ProfileData`` gives the arguments back as
+  the event's ``stats``; it costs about a microsecond when no trace is
+  being taken; and
 - a Chrome-trace JSON "complete" event into a
-  :class:`ChromeTraceRecorder` — loadable in ``chrome://tracing`` /
-  Perfetto without capturing a full XLA trace.
+  :class:`ChromeTraceRecorder`, when one is attached — loadable in
+  ``chrome://tracing`` / Perfetto without capturing a full XLA trace
+  (host wall-clock only, no device sync: dispatch-side phase structure).
 
-The recorder is deliberately tiny (host wall-clock only, no device
-sync): spans measure *dispatch-side* phase structure. Device-honest
-timing stays with SynchronizedWallClockTimer / the XLA trace.
+Device side, ``scope("mlp")`` is ``jax.named_scope`` held to the
+registry below: the name reaches each HLO operation's ``op_name``
+metadata (``jit(_micro_step)/.../transpose(jvp(mlp))/dot_general``) and
+costs nothing at run time. Innermost wins; forward and backward are
+told apart by the ``jvp(...)`` / ``transpose(jvp(...))`` wrappers
+autodiff puts into the path.
+
+``DEVICE_SCOPES`` and ``HOST_SPANS`` are the one registry of both kinds
+of name: the program opens them, and the benchmark's reader
+(``benchmarks/core/program_trace.py``) imports them to know what to
+keep. docs/observability.md "Trace spans" says where each is opened.
 """
 
 import json
 import os
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import List, Optional
 
+import jax
+
+try:
+    from jax.profiler import TraceAnnotation as _annotation
+except Exception:        # an odd jax profiler degrades to timing-only
+    def _annotation(name, **kwargs):
+        return nullcontext()
+
 __all__ = ["ChromeTraceRecorder", "trace_span", "set_default_recorder",
-           "get_default_recorder"]
+           "get_default_recorder", "scope", "DEVICE_SCOPES", "HOST_SPANS"]
+
+# scopes inside the compiled programs (jax.named_scope)
+DEVICE_SCOPES = (
+    "embed", "ln", "attn_proj", "attn_core", "kv_write", "kv_gather",
+    "attn_cached", "mlp", "weight_cast", "loss_head", "lm_head", "sample",
+    "grad_clip", "loss_scale", "opt_update",
+)
+
+# host phase spans (TraceAnnotation), each parent before its children
+HOST_SPANS = (
+    "train_batch", "data", "train/dispatch", "train/tail",
+    "forward", "backward", "step", "eval",
+    "pipe/stack_batch", "pipe/train_batch", "pipe/eval_batch",
+    "serve/admit",
+    "serve/prefill", "serve/prefill/build", "serve/prefill/dispatch",
+    "serve/prefill/wait",
+    "serve/chunk", "serve/chunk/build", "serve/chunk/dispatch",
+    "serve/chunk/wait",
+    "serve/verify", "serve/verify/build", "serve/verify/dispatch",
+    "serve/verify/wait",
+    "serve/decode", "serve/decode/build", "serve/decode/dispatch",
+    "serve/decode/wait",
+    "serve/record", "serve/metrics",
+)
+
+
+def scope(name: str):
+    """``jax.named_scope`` for a registered device scope; an unknown
+    name is refused when the program is traced."""
+    if name not in DEVICE_SCOPES:
+        raise ValueError(f"device scope {name!r} is not in "
+                         f"profiling.spans.DEVICE_SCOPES")
+    return jax.named_scope(name)
 
 
 class ChromeTraceRecorder:
@@ -126,27 +180,14 @@ def get_default_recorder() -> Optional[ChromeTraceRecorder]:
 @contextmanager
 def trace_span(name: str, recorder: Optional[ChromeTraceRecorder] = None,
                **extra):
-    """Context manager wrapping a phase in both sinks. Never raises from
-    instrumentation: a missing/odd jax profiler degrades to timing-only."""
+    """Context manager wrapping a phase in both sinks: the annotation
+    (with ``extra`` as its arguments) always, the recorder's event only
+    when one is attached."""
     rec = recorder if recorder is not None else _default_recorder
+    t0 = time.perf_counter() if rec is not None else 0.0
     try:
-        import jax.profiler as _jp
-        annotation = _jp.TraceAnnotation(name)
-    except Exception:
-        annotation = None
-    t0 = time.perf_counter()
-    if annotation is not None:
-        try:
-            annotation.__enter__()
-        except Exception:
-            annotation = None  # profiler refused to start: timing-only
-    try:
-        yield
+        with _annotation(name, **extra):
+            yield
     finally:
-        if annotation is not None:
-            try:
-                annotation.__exit__(None, None, None)
-            except Exception:
-                pass
         if rec is not None:
             rec.add(name, t0, time.perf_counter(), **extra)

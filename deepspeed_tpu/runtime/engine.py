@@ -41,6 +41,7 @@ from deepspeed_tpu.parallel.mesh import (axis_size, build_mesh,
                                          data_axis_names, data_axis_size,
                                          split_data_axis)
 from deepspeed_tpu.parallel.topology import ParallelGrid
+from deepspeed_tpu.profiling.spans import scope
 from deepspeed_tpu.runtime import checkpoint as ckpt
 from deepspeed_tpu.runtime import elastic
 from deepspeed_tpu.runtime import fault
@@ -1183,7 +1184,8 @@ class DeepSpeedEngine:
             # scales instead of bf16 (ZeRO++ arXiv:2306.10209 §quantized
             # weights) — see _quantized_weight_cast
             return self._quantized_weight_cast(params)
-        cast = _tree_cast(params, self.compute_dtype)
+        with scope("weight_cast"):
+            cast = _tree_cast(params, self.compute_dtype)
         if constrain and self.compute_dtype is not None \
                 and self.zero_stage >= 1:
             # Pin the compute-dtype copy to the MASTER's sharded layout so
@@ -1587,25 +1589,27 @@ class DeepSpeedEngine:
     def _apply_update(self, state: TrainState, grads) -> TrainState:
         """Optimizer boundary: unscale, clip, update, loss-scale bookkeeping.
         (reference stage2.py:1331 step / engine.py:865 _take_model_step)"""
-        inv_scale = 1.0 / state.loss_scale.scale
-        grads = jax.tree_util.tree_map(lambda g: g * inv_scale, grads)
+        with scope("loss_scale"):
+            inv_scale = 1.0 / state.loss_scale.scale
+            grads = jax.tree_util.tree_map(lambda g: g * inv_scale, grads)
 
-        if self.fp16_enabled:
-            overflow = has_overflow(grads)
-        else:
-            overflow = jnp.zeros((), bool)
+            if self.fp16_enabled:
+                overflow = has_overflow(grads)
+            else:
+                overflow = jnp.zeros((), bool)
 
         if self.gradient_clipping > 0:
-            if self._onebit_dist:
-                # stacked local grads: clip by the norm of the averaged
-                # gradient (what the dense path would see)
-                norm = _global_norm(jax.tree_util.tree_map(
-                    lambda g: g.mean(axis=0), grads))
-            else:
-                norm = _global_norm(grads)
-            clip = jnp.minimum(1.0, self.gradient_clipping /
-                               (norm + 1e-6))
-            grads = jax.tree_util.tree_map(lambda g: g * clip, grads)
+            with scope("grad_clip"):
+                if self._onebit_dist:
+                    # stacked local grads: clip by the norm of the
+                    # averaged gradient (what the dense path would see)
+                    norm = _global_norm(jax.tree_util.tree_map(
+                        lambda g: g.mean(axis=0), grads))
+                else:
+                    norm = _global_norm(grads)
+                clip = jnp.minimum(1.0, self.gradient_clipping /
+                                   (norm + 1e-6))
+                grads = jax.tree_util.tree_map(lambda g: g * clip, grads)
 
         lr = self._lr_at(state.global_step)
         mom = self._mom_at(state.global_step)
@@ -1635,19 +1639,22 @@ class DeepSpeedEngine:
             params, opt_state, _ = operand
             return params, opt_state
 
-        if self.fp16_enabled:
-            new_params, new_opt = jax.lax.cond(
-                overflow, skip_update, do_update,
-                (state.params, state.opt_state, grads))
-        else:
-            # overflow is statically False (bf16/fp32): no cond — keeps
-            # collectives (1-bit allreduce) out of conditional branches
-            new_params, new_opt = do_update(
-                (state.params, state.opt_state, grads))
+        with scope("opt_update"):
+            if self.fp16_enabled:
+                new_params, new_opt = jax.lax.cond(
+                    overflow, skip_update, do_update,
+                    (state.params, state.opt_state, grads))
+            else:
+                # overflow is statically False (bf16/fp32): no cond —
+                # keeps collectives (1-bit allreduce) out of conditional
+                # branches
+                new_params, new_opt = do_update(
+                    (state.params, state.opt_state, grads))
+            zero_accum = jax.tree_util.tree_map(jnp.zeros_like,
+                                                state.accum_grads)
 
-        new_scale = self.loss_scaler.update(state.loss_scale, overflow)
-        zero_accum = jax.tree_util.tree_map(jnp.zeros_like,
-                                            state.accum_grads)
+        with scope("loss_scale"):
+            new_scale = self.loss_scaler.update(state.loss_scale, overflow)
         return state._replace(
             params=new_params,
             opt_state=new_opt,
@@ -2411,7 +2418,8 @@ class DeepSpeedEngine:
                 with self.observability.span("data"):
                     batch = self._next_stacked_batch(data_iter)
                 _t0 = time.perf_counter()
-                self.state, mean_loss = step_fn(self.state, batch)
+                with self.observability.span("train/dispatch"):
+                    self.state, mean_loss = step_fn(self.state, batch)
                 _t_dispatch = time.perf_counter() - _t0
         else:
             step_fn = self._get_compiled_micro_step()
@@ -2423,7 +2431,8 @@ class DeepSpeedEngine:
                     with self.observability.span("data"):
                         batch = next(data_iter)
                     _t0 = time.perf_counter()
-                    self.state, out = step_fn(self.state, batch)
+                    with self.observability.span("train/dispatch"):
+                        self.state, out = step_fn(self.state, batch)
                     _t_dispatch += time.perf_counter() - _t0
                     if offload_direct:
                         out, self._offload_grads_device = out
@@ -2454,10 +2463,11 @@ class DeepSpeedEngine:
             self.observability.maybe_profile_flops(
                 prog, step_fn, (self.state, batch),
                 samples=self._host_global_step * self.train_batch_size())
-        self._check_csr_overflow()
-        self._report_progress()
-        self._write_monitor(mean_loss)
-        self._elastic_boundary()
+        with self.observability.span("train/tail"):
+            self._check_csr_overflow()
+            self._report_progress()
+            self._write_monitor(mean_loss)
+            self._elastic_boundary()
         return mean_loss
 
     def last_loss(self):

@@ -1,0 +1,307 @@
+"""The program's own names in a trace (profiling/spans.py registry):
+device scopes inside the compiled programs, host phase spans with their
+counters as arguments inside the two step loops.
+
+Pins, on the CPU at a tiny size:
+- the registry refuses an unknown scope, and every span's parent is
+  registered before it;
+- the GPT-2 train step and the paged prefill and decode programs carry
+  the scopes they should in their lowered text, and open no
+  ``jax.named_scope`` outside the registry;
+- a short ``jax.profiler`` trace of three ``engine.step()`` and two
+  ``train_batch`` calls holds the spans in their nesting and order, with
+  arguments equal to what the scheduler reports;
+- the scopes change neither the compiled program set nor the
+  zero-recompile contract.
+"""
+
+import contextlib
+import glob
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from deepspeed_tpu.inference import InferenceEngine
+from deepspeed_tpu.inference.scheduler import Request
+from deepspeed_tpu.models.gpt2 import (GPT2Config, gpt2_loss_fn,
+                                       init_gpt2_params)
+from deepspeed_tpu.profiling import spans
+from deepspeed_tpu.profiling.spans import (DEVICE_SCOPES, HOST_SPANS,
+                                           ChromeTraceRecorder, scope,
+                                           trace_span)
+
+CFG = GPT2Config(vocab_size=61, max_position_embeddings=32, hidden_size=32,
+                 num_layers=2, num_heads=4, embd_dropout=0.0,
+                 attn_dropout=0.0, resid_dropout=0.0)
+INF = {"max_batch_size": 3, "prompt_buckets": [4, 8],
+       "batch_buckets": [1, 2], "max_seq_len": 32, "max_new_tokens": 4,
+       "paged_kv": {"attn_kernel": "gather"}}
+PROMPTS = [[5, 6, 7], [8, 9, 10, 11, 12], [13, 14]]
+
+
+def _params():
+    return init_gpt2_params(CFG, jax.random.PRNGKey(3))
+
+
+def _train_engine():
+    ds = {"train_micro_batch_size_per_gpu": 2,
+          "gradient_accumulation_steps": 1,
+          "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
+          "bf16": {"enabled": True}, "zero_optimization": {"stage": 2},
+          "gradient_clipping": 1.0, "steps_per_print": 1000,
+          "mesh": {"axes": {"data": 1}}}
+    engine, *_ = deepspeed_tpu.initialize(
+        model=gpt2_loss_fn(CFG, deterministic=True),
+        model_parameters=_params(), config=ds)
+    return engine
+
+
+def _batches(rows=2):
+    rs = np.random.RandomState(0)
+    while True:
+        yield {"input_ids": rs.randint(0, 61, (rows, 17)).astype(np.int32)}
+
+
+def _serve_engine(dtype=jnp.float32):
+    return InferenceEngine(CFG, _params(), INF, dtype=dtype)
+
+
+def _scopes_in(text):
+    """Registered scopes among the name-stack components of a lowered
+    program's locations (the last component is the primitive)."""
+    found = set()
+    for path in re.findall(r'loc\("([^"]+)"', text):
+        for part in path.split("/")[:-1]:
+            found.update(w for w in re.findall(r"[A-Za-z0-9_]+", part)
+                         if w in DEVICE_SCOPES)
+    return found
+
+
+@contextlib.contextmanager
+def _opened_scopes(monkeypatch):
+    """Every name handed to ``jax.named_scope`` while the body traces."""
+    opened, real = [], jax.named_scope
+
+    def spy(name):
+        opened.append(name)
+        return real(name)
+
+    monkeypatch.setattr(jax, "named_scope", spy)
+    yield opened
+    monkeypatch.setattr(jax, "named_scope", real)
+
+
+# ------------------------------------------------------------- registry
+def test_registry_refuses_unknown_scope_and_orders_spans():
+    with pytest.raises(ValueError, match="attn_kernel"):
+        scope("attn_kernel")
+    with scope("mlp"):
+        pass
+    assert len(set(DEVICE_SCOPES)) == len(DEVICE_SCOPES)
+    assert len(set(HOST_SPANS)) == len(HOST_SPANS)
+    for i, name in enumerate(HOST_SPANS):
+        parent = name.rsplit("/", 1)[0]
+        if parent.startswith("serve/") and parent != name:
+            assert parent in HOST_SPANS[:i], name
+    # the names tests and docs have pinned since PR 18
+    for name in ("train_batch", "data", "serve/prefill", "serve/chunk",
+                 "serve/verify", "serve/decode"):
+        assert name in HOST_SPANS
+
+
+def test_trace_span_hands_arguments_to_both_sinks(monkeypatch):
+    seen = []
+
+    class Annotation(contextlib.nullcontext):
+        def __init__(self, name, **kwargs):
+            super().__init__()
+            seen.append((name, kwargs))
+
+    monkeypatch.setattr(spans, "_annotation", Annotation)
+    with trace_span("serve/decode", live_tokens=3, rows=5):
+        pass
+    assert seen == [("serve/decode", {"live_tokens": 3, "rows": 5})]
+    rec = ChromeTraceRecorder()
+    with trace_span("serve/prefill", recorder=rec, batch=2):
+        pass
+    assert seen[-1] == ("serve/prefill", {"batch": 2})
+    assert [(e["name"], e["args"]) for e in rec.events] == \
+        [("serve/prefill", {"batch": 2})]
+
+
+# -------------------------------------------------------- device scopes
+def test_train_step_carries_its_scopes_and_none_outside(monkeypatch):
+    engine = _train_engine()
+    batch = engine._put_micro_batch(next(_batches()))
+    with _opened_scopes(monkeypatch) as opened:
+        text = engine._get_compiled_micro_step().lower(
+            engine.state, batch).as_text(debug_info=True)
+    assert set(opened) <= set(DEVICE_SCOPES), set(opened) - set(DEVICE_SCOPES)
+    want = {"embed", "ln", "attn_proj", "attn_core", "mlp", "weight_cast",
+            "loss_head", "loss_scale", "grad_clip", "opt_update"}
+    assert want <= _scopes_in(text), want - _scopes_in(text)
+    # forward and backward of one scope are told apart by autodiff's own
+    # wrappers in the path
+    assert re.search(r'loc\("[^"]*/jvp\(mlp\)/', text)
+    assert re.search(r'loc\("[^"]*/transpose\(jvp\(mlp\)\)/', text)
+    # nothing of serving is traced into the train step
+    assert not {"kv_write", "kv_gather", "attn_cached", "lm_head",
+                "sample"} & _scopes_in(text)
+    engine.close()
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_paged_serving_programs_carry_their_scopes(monkeypatch, program):
+    engine = _serve_engine(jnp.bfloat16)      # fp32 weights really cast
+    assert engine.paged and engine._decode_attn_path == "gather"
+    rows, pps = engine._rows, engine.paged_spec.pages_per_seq
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)      # noqa: E731
+    keys = jnp.zeros((rows, 2), jnp.uint32)
+    temps = jnp.zeros((rows,), jnp.float32)
+    if program == "decode":
+        fn, args = engine._decode_paged_impl, (
+            i32(rows), i32(rows), i32(rows, pps), keys, temps)
+    else:
+        fn, args = engine._prefill_paged_impl, (
+            i32(rows, 8), i32(rows) + 1, i32(rows),
+            i32(rows, engine._prefill_pps), keys, temps)
+    with _opened_scopes(monkeypatch) as opened:
+        text = jax.jit(fn).lower(engine.params, engine._cache,
+                                 *args).as_text(debug_info=True)
+    assert set(opened) <= set(DEVICE_SCOPES)
+    want = {"embed", "ln", "attn_proj", "kv_write", "kv_gather",
+            "attn_cached", "mlp", "weight_cast", "lm_head", "sample"}
+    assert want <= _scopes_in(text), want - _scopes_in(text)
+    # innermost wins: the gather sits inside the block's attention scope
+    assert re.search(r'loc\("[^"]*/attn_core/kv_gather/', text)
+    assert not {"loss_head", "opt_update", "grad_clip"} & _scopes_in(text)
+    engine.close()
+
+
+def test_scopes_leave_the_program_set_and_recompiles_alone(monkeypatch):
+    def programs():
+        engine = _serve_engine()
+        warm = engine.warmup()
+        out = engine.generate(PROMPTS, max_new_tokens=4)
+        counts = dict(engine.compile_tracker.counts)
+        recompiles = engine.steady_state_recompiles
+        engine.close()
+        return warm, counts, recompiles, out
+
+    scoped = programs()
+    from deepspeed_tpu.inference import engine as serve_engine
+    from deepspeed_tpu.models import gpt2
+    for module in (gpt2, serve_engine):
+        monkeypatch.setattr(module, "scope",
+                            lambda name: contextlib.nullcontext())
+    bare = programs()
+    assert scoped[:3] == bare[:3]
+    assert scoped[2] == 0
+    assert scoped[3] == bare[3]           # and the same tokens
+
+
+# ----------------------------------------------------------- host spans
+def _traced(tmp_path, body):
+    """Run `body` under a CPU profiler trace; the host events whose name
+    is registered, as (name, start, end, arguments), by start."""
+    from jax.profiler import ProfileData
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                         / "*.xplane.pb"))[-1]
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in HOST_SPANS:
+                    events.append((e.name, e.start_ns,
+                                   e.start_ns + e.duration_ns,
+                                   dict(e.stats)))
+    return sorted(events, key=lambda ev: (ev[1], -ev[2]))
+
+
+def _children(events, parent):
+    return [ev[0] for ev in events
+            if ev is not parent and parent[1] <= ev[1] and ev[2] <= parent[2]]
+
+
+def test_serving_trace_holds_spans_in_order_with_scheduler_counters(
+        tmp_path):
+    engine = _serve_engine()
+    engine.warmup()
+    sched = engine.scheduler
+    for i, p in enumerate(PROMPTS):
+        engine.submit(Request(prompt=p, max_new_tokens=6, temperature=0.0,
+                              seed=i, eos_id=None))
+    reported = []                   # what the scheduler says at each open
+    real_span = engine._span
+
+    def spy(name, **args):
+        if name == "serve/decode":
+            reported.append(sched.tokens_in_flight)
+        return real_span(name, **args)
+
+    engine._span = spy
+    events = _traced(tmp_path, lambda: [engine.step() for _ in range(3)])
+    engine.close()
+
+    decodes = [ev for ev in events if ev[0] == "serve/decode"]
+    prefills = [ev for ev in events if ev[0] == "serve/prefill"]
+    assert len(decodes) == 3 and len(prefills) >= 1
+    for ev, live in zip(decodes, reported):
+        # every argument has a reader (decode_stripe_live_share.sat)
+        assert set(ev[3]) == {"rows", "table_pages", "page_size",
+                              "live_tokens"}
+        assert ev[3]["live_tokens"] == live
+        assert ev[3]["rows"] == engine._rows
+        assert ev[3]["page_size"] == engine.paged_spec.page_size
+        assert ev[3]["table_pages"] == engine.paged_spec.pages_per_seq
+        assert _children(events, ev) == [
+            "serve/decode/build", "serve/decode/dispatch",
+            "serve/decode/wait"]
+    # every prompt token is real, the rest of the buckets is padding
+    assert sum(ev[3]["real_tokens"] for ev in prefills) == \
+        sum(len(p) for p in PROMPTS)
+    for ev in prefills:
+        assert set(ev[3]) == {"batch", "prompt", "real_tokens"}
+        assert ev[3]["batch"] in INF["batch_buckets"]
+        assert ev[3]["prompt"] in INF["prompt_buckets"]
+        assert ev[3]["real_tokens"] <= ev[3]["batch"] * ev[3]["prompt"]
+        assert _children(events, ev) == [
+            "serve/prefill/build", "serve/prefill/dispatch",
+            "serve/prefill/wait"]
+    # one step, in order: admission, its prefills with their bookkeeping,
+    # the decode dispatch, tokens recorded, metrics
+    first = [ev[0] for ev in events if ev[1] < decodes[1][1]
+             and ev[0].count("/") == 1]
+    assert first[0] == "serve/admit"
+    assert first[1:4] == ["serve/prefill", "serve/record", "serve/metrics"]
+    i = first.index("serve/decode")
+    assert first[i:i + 4] == ["serve/decode", "serve/record",
+                              "serve/metrics", "serve/metrics"]
+
+
+def test_training_trace_holds_dispatch_and_tail_spans(tmp_path):
+    engine = _train_engine()
+    it = _batches()
+    engine.train_batch(it)                            # compiles
+    events = _traced(
+        tmp_path, lambda: [engine.train_batch(it) for _ in range(2)])
+    engine.close()
+    steps = [ev for ev in events if ev[0] == "train_batch"]
+    tails = [ev for ev in events if ev[0] == "train/tail"]
+    assert len(steps) == 2 and len(tails) == 2
+    for step, tail in zip(steps, tails):
+        assert _children(events, step) == ["data", "train/dispatch"]
+        assert tail[1] >= step[2]                     # after, not inside
+    assert tails[0][2] <= steps[1][1]
