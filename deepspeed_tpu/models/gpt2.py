@@ -710,26 +710,56 @@ def _paged_cache_attention(kpool, vpool, block_table, cache_position,
                     ring_prefill_attention
                 return ring_prefill_attention(q, kc, vc, cache_position,
                                               cp.mesh, cp.axis)
-        with scope("kv_gather"):
-            # the gathered stripe's cast to the attention math's float32
-            # is the reader's cost, not the attention's
-            kc, vc = kc.astype(jnp.float32), vc.astype(jnp.float32)
         return _stripe_attention(q, kc, vc, cache_position)
     return attn
 
 
+# rows of one sublane tile: with fewer query rows the TPU's compiler does
+# not keep a dot a matmul (see _stripe_attention)
+_MXU_QUERY_ROWS = 8
+
+
 def _stripe_attention(q, kc, vc, cache_position):
     """Scores, offset-causal mask, softmax and context of ``q`` over a
-    whole key/value stripe (B, heads, kv_len, hd), in float32."""
+    whole key/value stripe (B, heads, kv_len, hd); mask and softmax in
+    float32, both dots accumulating in float32.
+
+    The operands are chosen from what the inputs show, no option. A
+    query of ``_MXU_QUERY_ROWS`` rows or more (every prefill and chunk
+    program) and any float32 stripe (a float32 cache, an int8 pool
+    after ``dequantize_pool``) contract float32 operands. Fewer rows
+    over a narrower stripe (decode over a bf16 cache) XLA:TPU rewrites
+    from a dot into a VPU multiply-and-reduce; the v5e's VPU has no
+    bf16, so the whole stripe was first written out in float32, and
+    that copy cost more than the attention (ISSUE 25). There the query
+    is zero-PADDED to the tile's rows, the stripe goes to the MXU in
+    the dtype it arrives in, and the padded rows are dropped after the
+    context. Zero rows, not copies of the one row: the installed
+    compiler keeps either as a matmul, but a dot of a broadcast is a
+    broadcast of the one-row dot, an identity a simplifier may come to
+    use; ``tests/unit/test_tpu_compile.py`` pins that no float32 stripe
+    comes back. Products of two bf16 values are exact in float32, so
+    these scores differ from the float32 operands' only by the order
+    of the sum; the probabilities stay float32 and the context dot runs
+    at ``Precision.HIGHEST`` so that they are not rounded to the
+    stripe's dtype (as fast on the chip as casting them)."""
     with scope("attn_cached"):
-        hd = q.shape[-1]
-        scores = jnp.einsum("bhqd,bhld->bhql", q.astype(jnp.float32),
-                            kc.astype(jnp.float32)) / np.sqrt(hd)
+        rows, hd, out_dtype = q.shape[2], q.shape[-1], q.dtype
+        narrow = rows < _MXU_QUERY_ROWS and kc.dtype != jnp.float32
+        if narrow:
+            q = jnp.pad(q.astype(kc.dtype),
+                        ((0, 0), (0, 0), (0, _MXU_QUERY_ROWS - rows), (0, 0)))
+        else:
+            q, kc = q.astype(jnp.float32), kc.astype(jnp.float32)
+        scores = jnp.einsum("bhqd,bhld->bhql", q, kc,
+                            preferred_element_type=jnp.float32) / np.sqrt(hd)
         mask = causal_cache_mask(cache_position, q.shape[2], kc.shape[2])
         scores = jnp.where(mask, scores, NEG_INF)
         probs = jax.nn.softmax(scores, axis=-1)
-        return jnp.einsum("bhql,bhld->bhqd", probs,
-                          vc.astype(jnp.float32)).astype(q.dtype)
+        ctx = jnp.einsum(
+            "bhql,bhld->bhqd", probs, vc.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST if narrow else None)
+        return ctx[:, :, :rows].astype(out_dtype)
 
 
 def _offset_cache_attention(kcache, vcache, cache_position, out_box):

@@ -452,6 +452,97 @@ class TestEngineParity:
                 dtype=jnp.float32)
 
 
+def _plain_stripe_attention(q, kc, vc, cache_position):
+    """The gather path's stripe math as it was before ISSUE 25: every
+    operand upcast to float32, whatever the query's rows."""
+    from deepspeed_tpu.models.gpt2 import NEG_INF, causal_cache_mask
+    scores = jnp.einsum("bhqd,bhld->bhql", q.astype(jnp.float32),
+                        kc.astype(jnp.float32)) / np.sqrt(q.shape[-1])
+    mask = causal_cache_mask(cache_position, q.shape[2], kc.shape[2])
+    probs = jax.nn.softmax(jnp.where(mask, scores, NEG_INF), axis=-1)
+    return jnp.einsum("bhql,bhld->bhqd", probs,
+                      vc.astype(jnp.float32)).astype(q.dtype)
+
+
+class TestGatherSeq1Path:
+    """ISSUE 25: a one-row query over a stripe narrower than float32 is
+    padded to the sublane tile and contracts the stripe in the dtype it
+    arrives in (``_stripe_attention``); float32 stripes — a float32
+    pool, an int8 pool once dequantized — keep the float32 operands."""
+
+    # what each pool's path may differ from the float32 oracle by: one
+    # rounding of the context to bf16 (outputs stay under 4, where a
+    # bf16 step is 2^-6) and the order of float32 sums; float32 stripes
+    # only the latter
+    TOLERANCE = {"bf16": 2.0 ** -6, "float32": 2e-5, "int8": 2e-5}
+
+    @pytest.mark.parametrize("pool", ["bf16", "float32", "int8"])
+    def test_write_gather_attend_matches_the_oracle(self, pool):
+        """Ragged positions, a one-token cache, a table mapped only as
+        far as it is used, the last position of the table, and an
+        inactive slot whose table is all null page."""
+        from deepspeed_tpu.models.gpt2 import _paged_cache_attention
+        from deepspeed_tpu.ops.attention.paged import (
+            paged_decode_reference, quantize_kv)
+        rng = np.random.RandomState(25)
+        heads, hd, ps, pages = 4, 16, 4, 5
+        positions = np.asarray([0, 0, 6, 19, 7, 12], np.int32)
+        batch = len(positions)
+        _, kpool, vpool, tables = _pool_case(rng, heads, 1, ps, pages,
+                                             hd=hd, batch=batch)
+        tables[1] = 0                     # the inactive slot
+        tables[4, 2:] = 0                 # mapped as far as position 7
+        dtype = jnp.bfloat16 if pool == "bf16" else jnp.float32
+        q, k, v = (jnp.asarray(rng.randn(batch, heads, 1, hd), dtype)
+                   for _ in range(3))
+        scales = {}
+        if pool == "int8":
+            kpool, ks = quantize_kv(kpool)
+            vpool, vs = quantize_kv(vpool)
+            scales = dict(kscale_pool=ks, vscale_pool=vs)
+        else:
+            kpool, vpool = kpool.astype(dtype), vpool.astype(dtype)
+        box = []
+        got = _paged_cache_attention(
+            kpool, vpool, jnp.asarray(tables), jnp.asarray(positions),
+            box, attn_kernel="gather", **scales)(q, k, v, 0.0, None)
+        assert got.shape == (batch, heads, 1, hd) and got.dtype == dtype
+        kp, vp, *written_scales = box[0]
+        ref = paged_decode_reference(
+            q[:, :, 0].astype(jnp.float32), kp, vp, jnp.asarray(tables),
+            jnp.asarray(positions),
+            **dict(zip(("k_scales", "v_scales"), written_scales)))
+        np.testing.assert_allclose(
+            np.asarray(got[:, :, 0].astype(jnp.float32)), np.asarray(ref),
+            rtol=0, atol=self.TOLERANCE[pool])
+
+    @pytest.mark.parametrize("cache", ["paged", "contiguous"])
+    def test_bf16_greedy_tokens_equal_the_float32_formulation(
+            self, cache, monkeypatch):
+        """A short bf16 generation through the gather path (and through
+        the contiguous cache, the same stripe math) picks the tokens the
+        parent's all-float32 formulation picks."""
+        from deepspeed_tpu.inference import InferenceEngine
+        from deepspeed_tpu.models import gpt2
+        cfg, params = tiny_gpt2()
+        rng = np.random.RandomState(25)
+        prompts = [rng.randint(1, 61, (n,)).tolist()
+                   for n in (3, 5, 7, 2, 8, 4)]
+        inf = dict(TINY_INF, paged_kv=(PAGED_GATHER if cache == "paged"
+                                       else {"enabled": False}))
+
+        def generate():
+            engine = InferenceEngine(cfg, params, inf, dtype=jnp.bfloat16)
+            assert (engine.paged_spec is not None) == (cache == "paged")
+            return engine.generate(prompts, max_new_tokens=6,
+                                   temperature=0.0)
+
+        got = generate()
+        monkeypatch.setattr(gpt2, "_stripe_attention",
+                            _plain_stripe_attention)
+        assert got == generate()
+
+
 class TestDecodeWidthBuckets:
     """ISSUE 8 satellite: the gather fallback's decode reads are
     bounded by the batch's LIVE page bucket, not pages_per_seq."""

@@ -14,6 +14,7 @@ can be written to it but not read back without a chip.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else logs under /tmp
 # a described topology attaches no chip, so several test processes
@@ -174,3 +175,96 @@ def test_paged_decode_gate_agrees_with_the_compiler(head_dim, page_size,
         with pytest.raises(Exception, match="aligned to tiling"):
             _compile(_paged_decode, *specs)
         assert why
+
+
+# --------------------------------------------------------------------- #
+# serving's one-token attention over a gathered stripe (ISSUE 25)
+# --------------------------------------------------------------------- #
+# gpt2-345m.serve-saturated's decode program: 161 rows, 16 heads of 64, a
+# table of 40 pages of 16 tokens over a pool of 2,561 pages
+ROWS, HEADS, HEAD_DIM, TABLE_PAGES, PAGE, POOL_PAGES = 161, 16, 64, 40, 16, 2561
+STRIPE_ELEMS = ROWS * HEADS * TABLE_PAGES * PAGE * HEAD_DIM
+
+
+def _cached_reader(cache, q_rows, cache_dtype):
+    """Write, (gather,) and stripe math of one layer as the cached
+    forward runs them: ``(fn, specs)`` for the paged pool or the
+    contiguous cache at the cell's shapes."""
+    from deepspeed_tpu.models import gpt2
+
+    qkv = _spec((ROWS, HEADS, q_rows, HEAD_DIM))
+    positions = _spec((ROWS,), jnp.int32)
+    if cache == "paged":
+        pool = _spec((POOL_PAGES, HEADS, PAGE, HEAD_DIM), cache_dtype)
+
+        def fn(q, k, v, kpool, vpool, tables, pos):
+            box = []
+            out = gpt2._paged_cache_attention(kpool, vpool, tables, pos,
+                                              box)(q, k, v, 0.0, None)
+            return out, box[0]
+        return fn, (qkv, qkv, qkv, pool, pool,
+                    _spec((ROWS, TABLE_PAGES), jnp.int32), positions)
+    stripe = _spec((ROWS, HEADS, TABLE_PAGES * PAGE, HEAD_DIM), cache_dtype)
+
+    def fn(q, k, v, kcache, vcache, pos):
+        box = []
+        out = gpt2._offset_cache_attention(kcache, vcache, pos, box)(
+            q, k, v, 0.0, None)
+        return out, box[0]
+    return fn, (qkv, qkv, qkv, stripe, stripe, positions)
+
+
+def _entry_float32_results(compiled):
+    """Element counts of every float32 array an instruction of the
+    optimized HLO's ENTRY computation produces (tuple results included):
+    what the program really writes, not what a fusion holds in
+    registers."""
+    entry = re.search(r"^ENTRY .*?^}", compiled.as_text(),
+                      re.S | re.M).group(0)
+    counts = []
+    for line in entry.splitlines():
+        result = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) [\w\-]+\(", line)
+        if result:
+            counts += [int(np.prod([int(d) for d in dims.split(",")]))
+                       for dims in re.findall(r"f32\[([\d,]+)\]",
+                                              result.group(1))]
+    return counts
+
+
+@pytest.mark.parametrize("cache", ["paged", "contiguous"])
+def test_seq1_attention_writes_no_float32_stripe(cache):
+    """A one-row query over a bf16 stripe: the parent's decode program
+    wrote the gathered keys and values again in float32 (two stand-alone
+    ``convert``s a layer, 98 of its 289 ms on the chip), because a
+    one-row dot becomes a VPU multiply-and-reduce and the v5e's VPU has
+    no bf16. With the query padded to the sublane tile both dots stay on
+    the MXU and take the stripe as it is."""
+    fn, specs = _cached_reader(cache, 1, jnp.bfloat16)
+    written = _entry_float32_results(_compile(fn, *specs))
+    assert written, "the parse found no float32 result at all"
+    assert max(written) < STRIPE_ELEMS
+
+
+@pytest.mark.parametrize("q_rows,cache_dtype", [
+    (1, jnp.float32),       # an int8 pool after dequantize_pool, fp32 caches
+    (8, jnp.bfloat16),      # the row rule's edge
+    (128, jnp.bfloat16),    # a prefill bucket
+], ids=["float32_stripe", "rows8", "rows128"])
+def test_wide_queries_and_float32_stripes_lower_as_before(q_rows,
+                                                          cache_dtype):
+    """The new path is taken on the query's row count and the stripe's
+    dtype alone: everything else hands XLA the very program the plain
+    float32 formulation does (what ``_stripe_attention`` was before
+    ISSUE 25), so prefill, chunked prefill and float32 stripes cannot
+    have moved."""
+    from deepspeed_tpu.models import gpt2
+    from tests.unit.test_paged_attention import _plain_stripe_attention
+
+    stripe = _spec((ROWS, HEADS, TABLE_PAGES * PAGE, HEAD_DIM), cache_dtype)
+    specs = (_spec((ROWS, HEADS, q_rows, HEAD_DIM)), stripe, stripe,
+             _spec((ROWS,), jnp.int32))
+
+    def program(fn):           # the module's first line names the function
+        return jax.jit(fn).lower(*specs).as_text().split("\n", 1)[1]
+    assert (program(gpt2._stripe_attention)
+            == program(_plain_stripe_attention))
