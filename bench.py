@@ -1493,7 +1493,7 @@ def bench_paged_decode_bytes(on_tpu, rtt):
     interpret-mode kernel — the same jaxpr structure the TPU program
     partitions from) and walk both for ``gather`` instructions. The
     gather program materializes each layer's
-    (rows, pages_per_seq, kv_heads, page_size, hd) stripe — a
+    (rows, pages_per_seq, page_size, kv_heads * hd) stripe — a
     max_len-bounded tensor; the pallas program must contain NO gather
     that large (its pool reads are per-page dynamic slices).
 

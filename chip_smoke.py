@@ -202,9 +202,11 @@ def check_paged_decode(seed, head_dim=128, page_size=16):
     rs = np.random.RandomState(seed)
     key = jax.random.PRNGKey(seed)
     q = jax.random.normal(key, (batch, heads, head_dim), jnp.bfloat16)
+    # the stacked pool's layout: one token a row, heads side by side
+    layers, layer = 2, 1
     kpool, vpool = (
         jax.random.normal(jax.random.fold_in(key, i),
-                          (num_pages, heads, page_size, head_dim),
+                          (layers, num_pages, page_size, heads * head_dim),
                           jnp.bfloat16) for i in (1, 2))
     # page 0 is the null page: every row owns distinct pages >= 1
     tables = 1 + rs.permutation(num_pages - 1)[:batch * pages_per_seq]
@@ -212,8 +214,9 @@ def check_paged_decode(seed, head_dim=128, page_size=16):
     positions = jnp.asarray(
         rs.randint(0, page_size * pages_per_seq, (batch,)), jnp.int32)
     got = paged_decode_attention(q, kpool, vpool, tables, positions,
-                                 interpret=False)
-    want = paged_decode_reference(q, kpool, vpool, tables, positions)
+                                 interpret=False, layer=layer)
+    want = paged_decode_reference(q, kpool, vpool, tables, positions,
+                                  layer=layer)
     assert_close(got, want, f"paged decode hd={head_dim} ps={page_size}")
 
 

@@ -157,11 +157,12 @@ class MigrationRecord:
 
     ``kslab``/``vslab`` are the live pages' K/V contents gathered by
     the warmup-compiled export program, trimmed to ``live_pages``
-    (shape ``(layers, live_pages, kv_heads, page_size, head_dim)``,
-    host numpy — they ship as the raw binary segment of an RPC frame).
+    (shape ``(layers, live_pages, page_size, kv_heads * head_dim)``:
+    the pool's own row layout with the page axis trimmed, one token a
+    row and heads major within it; host numpy — they ship as the raw binary segment of an RPC frame).
     Quantized (int8) pools additionally carry
     ``kscale_slab``/``vscale_slab`` — the per-token-row fp32 scales,
-    shape ``(layers, live_pages, kv_heads, page_size, scale_blocks)``
+    shape ``(layers, live_pages, page_size, kv_heads * scale_blocks)``
     — so migrated pages stay int8 on the wire and the destination
     scatters payload + scales as one leaf-generic import. An fp-pool
     record leaves them None; the destination engine rejects any

@@ -10,20 +10,27 @@ whether its request is 6 tokens or 6000. ``batch_rows`` is
 rows of a partially-filled prefill bucket scatter their (garbage) K/V
 there instead of corrupting a live request's slot.
 
-**Paged (default)** — a fixed pool of ``num_pages`` pages, each
-``(kv_heads, page_size, head_dim)``, as one pair of arrays shaped
-``(layers, num_pages, kv_heads, page_size, head_dim)``, plus a
-host-side :class:`PageAllocator`. A request occupies
-``ceil(total_tokens / page_size)`` pages mapped through a static-shape
-per-slot *block table*; HBM occupancy is therefore bounded by the
-tokens actually reserved in flight, not ``slots x max_len``. Page 0 is
-reserved as the *null page*: unallocated block-table entries and
-padding-row writes all land there (its contents are garbage by design
-and never read unmasked — ``causal_cache_mask`` hides every position a
-query has not reached). The allocator also implements **prefix
-caching**: full, page-aligned prompt prefixes are chain-hashed and
-refcounted, so concurrent requests sharing a system prompt prefill the
-shared pages once.
+**Paged (default)** — a fixed pool of ``num_pages`` pages, as one pair
+of arrays shaped ``(layers, num_pages, page_size, kv_heads *
+head_dim)``, plus a host-side :class:`PageAllocator`. A pool ROW is one
+token of one layer: its ``kv_heads`` heads side by side, heads major
+(head ``h`` is lanes ``h * head_dim:(h + 1) * head_dim``), so a row is
+lane-dense at every model width (1,024 lanes for GPT-2 345M, 1,600 for
+GPT-2 XL) where a ``head_dim`` of 64 alone is half a lane tile. The
+three index dimensions lead, so a token is written in place at
+``[layer, page, offset]`` and a donated pool is never copied around a
+program's layer stack (ISSUE 28); a ``(page_size, head_dim)`` tile of
+head ``h`` is ``pool[layer, page, :, h * head_dim:(h + 1) * head_dim]``.
+A request occupies ``ceil(total_tokens / page_size)`` pages mapped
+through a static-shape per-slot *block table*; HBM occupancy is
+therefore bounded by the tokens actually reserved in flight, not
+``slots x max_len``. Page 0 is reserved as the *null page*: unallocated
+block-table entries and padding-row writes all land there (its contents
+are garbage by design and never read unmasked — ``causal_cache_mask``
+hides every position a query has not reached). The allocator also
+implements **prefix caching**: full, page-aligned prompt prefixes are
+chain-hashed and refcounted, so concurrent requests sharing a system
+prompt prefill the shared pages once.
 
 Writes happen inside the model forwards via
 :func:`deepspeed_tpu.models.gpt2.write_kv_cache` (dense) /
@@ -107,14 +114,17 @@ class PagedKVSpec(NamedTuple):
     """Static geometry of the paged serving KV cache. ``pages_per_seq``
     is the block-table width: every slot's table maps that many logical
     page positions (covering ``max_len`` tokens), entries beyond its
-    reservation pointing at the null page 0.
+    reservation pointing at the null page 0. ``shape`` is the pool
+    leaf's: ``(layers, num_pages, page_size, kv_heads * head_dim)``, one
+    token a row with its heads side by side (module docstring).
 
     **Quantized pool (PR 17)** — ``dtype=int8`` switches the pool to
     int8 payload with per-token-row fp32 absmax scales stored alongside
     (the EQuARX/qwZ recipe applied to the KV pool): the cache tree
     becomes the 4-tuple ``(kc, vc, kscale, vscale)`` where the scale
-    pools are shaped ``(layers, num_pages, kv_heads, page_size,
-    scale_blocks)``. ``quant_block`` is the scale granularity along
+    pools are shaped ``(layers, num_pages, page_size, kv_heads *
+    scale_blocks)``: a token's scales are one row, heads major, like its
+    payload. ``quant_block`` is the scale granularity along
     head_dim (0 = one scale per token row, i.e. the whole head_dim);
     scales are per token row because decode fills pages one token at a
     time — a page-wide scale would be rewritten (and degrade) on every
@@ -129,9 +139,9 @@ class PagedKVSpec(NamedTuple):
     quant_block: int = 0  # scale block over head_dim (0 = head_dim)
 
     @property
-    def shape(self) -> Tuple[int, int, int, int, int]:
-        return (self.num_layers, self.num_pages, self.kv_heads,
-                self.page_size, self.head_dim)
+    def shape(self) -> Tuple[int, int, int, int]:
+        return (self.num_layers, self.num_pages, self.page_size,
+                self.kv_heads * self.head_dim)
 
     @property
     def quantized(self) -> bool:
@@ -144,9 +154,9 @@ class PagedKVSpec(NamedTuple):
         return self.head_dim // block
 
     @property
-    def scale_shape(self) -> Tuple[int, int, int, int, int]:
-        return (self.num_layers, self.num_pages, self.kv_heads,
-                self.page_size, self.scale_blocks)
+    def scale_shape(self) -> Tuple[int, int, int, int]:
+        return (self.num_layers, self.num_pages, self.page_size,
+                self.kv_heads * self.scale_blocks)
 
 
 def paged_spec_for(model_config, num_pages: int, page_size: int,

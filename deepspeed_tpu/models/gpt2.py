@@ -393,40 +393,47 @@ def _gpt2_trunk_cached(params, config: GPT2Config, input_ids, kv_cache,
     block math to drift.
 
     With ``block_tables`` ((B, pages_per_seq) int32) the cache is the
-    PAGED pool pair (each (layers, num_pages, heads, page_size, hd)) and
-    attention runs the paged path (:func:`_paged_cache_attention`) —
-    same block, same mask; ``paged_attn_kernel`` picks the fused Pallas
-    decode kernel ("pallas") or the gather oracle ("gather") for seq-1
-    queries. An int8-quantized pool arrives as the 4-tuple
+    PAGED pool pair (each (layers, num_pages, page_size, heads * hd):
+    one token a row, heads major within it) and attention runs the
+    paged path (:func:`_paged_cache_attention`) — same block, same
+    mask; ``paged_attn_kernel`` picks the fused Pallas decode kernel
+    ("pallas") or the gather oracle ("gather") for seq-1 queries. An
+    int8-quantized pool arrives as the 4-tuple
     ``(kc, vc, kscale, vscale)`` (scale pools
-    (layers, num_pages, heads, page_size, nb) fp32) — writes quantize
+    (layers, num_pages, page_size, heads * nb) fp32) — writes quantize
     per token row, reads dequantize at the attention site."""
-    quantized = len(kv_cache) == 4
     B, S = input_ids.shape
     pos = cache_position[:, None] + jnp.arange(S)[None, :]
     with scope("embed"):
         x = (_emb_rows(params["wte"], input_ids, jnp.float32)
              + _emb_rows(params["wpe"], pos, jnp.float32)).astype(dtype)
-    new_caches = []
-    for i in range(config.num_layers):
-        box = []
-        with scope("kv_write"):        # the layer's slice of the cache
-            layer = tuple(leaf[i] for leaf in kv_cache)
-        if block_tables is not None:
+    if block_tables is not None:
+        # the stacked pool is carried through the layers and written in
+        # place at [layer, page, offset]: no layer is sliced out of it
+        # and nothing is stacked back (ISSUE 28)
+        page, offset = paged_write_index(block_tables, cache_position, S,
+                                         kv_cache[0].shape[2])
+        for i in range(config.num_layers):
+            box = []
             attn = _paged_cache_attention(
-                layer[0], layer[1], block_tables, cache_position, box,
-                attn_kernel=paged_attn_kernel,
-                kscale_pool=layer[2] if quantized else None,
-                vscale_pool=layer[3] if quantized else None)
-        else:
-            attn = _offset_cache_attention(layer[0], layer[1],
-                                           cache_position, box)
-        x = gpt2_block(layer_params(params, config, i), config, x, None,
-                       True, dtype, attention_fn=attn)
-        new_caches.append(box[0])
-    x = _layer_norm(x, params["ln_f"], config.layer_norm_eps)
-    with scope("kv_write"):            # the layers stacked back
-        return x, tuple(jnp.stack(leaf) for leaf in zip(*new_caches))
+                kv_cache, i, block_tables, cache_position, page, offset,
+                box, attn_kernel=paged_attn_kernel)
+            x = gpt2_block(layer_params(params, config, i), config, x,
+                           None, True, dtype, attention_fn=attn)
+            kv_cache = box[0]
+    else:
+        new_caches = []
+        for i in range(config.num_layers):
+            box = []
+            with scope("kv_write"):    # the layer's slice of the cache
+                kc, vc = (leaf[i] for leaf in kv_cache)
+            attn = _offset_cache_attention(kc, vc, cache_position, box)
+            x = gpt2_block(layer_params(params, config, i), config, x,
+                           None, True, dtype, attention_fn=attn)
+            new_caches.append(box[0])
+        with scope("kv_write"):        # the layers stacked back
+            kv_cache = tuple(jnp.stack(leaf) for leaf in zip(*new_caches))
+    return _layer_norm(x, params["ln_f"], config.layer_norm_eps), kv_cache
 
 
 def gpt2_forward(params, config: GPT2Config, input_ids, rng=None,
@@ -442,7 +449,7 @@ def gpt2_forward(params, config: GPT2Config, input_ids, rng=None,
     :func:`causal_cache_mask`, and returns ``(logits, updated_cache)``
     instead of bare logits. ``block_tables`` ((B, pages_per_seq) int32)
     switches the cache interpretation to the paged pool pair (each
-    ``(layers, num_pages, heads, page_size, hd)``);
+    ``(layers, num_pages, page_size, heads * hd)``);
     ``paged_attn_kernel="pallas"`` routes seq-1 queries through the
     fused Pallas paged-decode kernel instead of the stripe gather. The
     training call signature is unchanged (the serving arguments all
@@ -556,40 +563,55 @@ def write_kv_cache(cache, new, cache_position):
         )(cache, new.astype(cache.dtype), cache_position)
 
 
-def write_paged_kv_cache(pool, new, block_table, cache_position):
-    """Scatter ``new`` (B, heads, S, hd) into a paged pool
-    ``(num_pages, heads, page_size, hd)``: row b's token j lands in page
+def paged_write_index(block_table, cache_position, num_tokens: int,
+                      page_size: int):
+    """Where this call's tokens go in a paged pool, computed once a call
+    and shared by every layer's write: ``(page, offset)``, each
+    ``(B * num_tokens,)`` int32, row-major over (row, token). Row b's
+    token j lands in page
     ``block_table[b, (cache_position[b]+j) // page_size]`` at offset
     ``(cache_position[b]+j) % page_size``. Positions past the table's
     logical extent — and unreserved table entries, which the host
     allocator leaves at 0 — land in the reserved null page 0, whose
-    garbage ``causal_cache_mask`` keeps unread. One scatter per call,
-    static shapes throughout: the serving paged programs never reshape.
-    """
-    B, H, S, hd = new.shape
+    garbage ``causal_cache_mask`` keeps unread."""
     P = block_table.shape[1]
-    ps = pool.shape[2]
     with scope("kv_write"):
-        pos = cache_position[:, None] + jnp.arange(S)[None, :]   # (B, S)
-        slot = pos // ps
+        pos = cache_position[:, None] + jnp.arange(num_tokens)[None, :]
+        slot = pos // page_size
         page = jnp.where(
             slot < P,
             jnp.take_along_axis(block_table, jnp.minimum(slot, P - 1),
                                 axis=1),
             0)
+        return page.reshape(-1), (pos % page_size).reshape(-1)
+
+
+def write_paged_kv_cache(pool, layer: int, new, page, offset):
+    """Write ``new`` (B, heads, S, w) into the stacked paged pool
+    ``(layers, num_pages, page_size, heads * w)`` in place: token (b, j)
+    becomes the pool row ``[layer, page[b*S+j], offset[b*S+j]]``, heads
+    major within the row (``page``/``offset`` from
+    :func:`paged_write_index`). The index dimensions lead and a whole
+    row is written, so under a donated pool the scatter aliases its
+    operand: no layer is sliced out and nothing of the pool's size is
+    copied (ISSUE 28). ``w`` is head_dim for the payload pools,
+    scale_blocks for an int8 pool's scale leaves."""
+    B, H, S, w = new.shape
+    with scope("kv_write"):
         vals = new.astype(pool.dtype).transpose(0, 2, 1, 3).reshape(
-            B * S, H, hd)
-        return pool.at[page.reshape(-1), :, (pos % ps).reshape(-1)].set(
-            vals)
+            B * S, H * w)
+        return pool.at[layer, page, offset].set(vals)
 
 
-def gather_paged_kv(pool, block_table):
-    """Assemble each row's logical K or V stripe from the paged pool:
-    ``(B, pages_per_seq)`` block table over ``(num_pages, heads,
-    page_size, hd)`` -> ``(B, heads, pages_per_seq * page_size, hd)``.
-    Gathered position ``t * page_size + o`` is the row's absolute cache
-    position, so :func:`causal_cache_mask` applies unchanged — unmapped
-    table entries surface the null page, always masked.
+def gather_paged_kv(pool, layer: int, block_table, kv_heads: int):
+    """Assemble each row's logical K or V stripe of one layer from the
+    stacked paged pool: ``(B, pages_per_seq)`` block table over
+    ``(layers, num_pages, page_size, kv_heads * w)`` ->
+    ``(B, kv_heads, pages_per_seq * page_size, w)``. Gathered position
+    ``t * page_size + o`` is the row's absolute cache position, so
+    :func:`causal_cache_mask` applies unchanged — unmapped table entries
+    surface the null page, always masked. The gather moves whole
+    lane-dense rows; the split of a row into its heads comes after it.
 
     NB: this materializes each row's full logical stripe (every table
     entry it is handed) each call — per-step decode reads are bounded
@@ -601,21 +623,57 @@ def gather_paged_kv(pool, block_table):
     even this fallback stops paying full ``max_len`` bandwidth
     (``inference.paged_kv.decode_page_buckets``)."""
     B, P = block_table.shape
-    _, H, ps, hd = pool.shape
+    ps, width = pool.shape[2:]
     with scope("kv_gather"):
-        return pool[block_table].transpose(0, 2, 1, 3, 4).reshape(
-            B, H, P * ps, hd)
+        return pool[layer, block_table].reshape(
+            B, P * ps, kv_heads, width // kv_heads).transpose(0, 2, 1, 3)
 
 
-def paged_decode_ctx(q, kpool, vpool, block_table, cache_position,
-                     k_scales=None, v_scales=None):
+def write_paged_layer(pools, layer: int, k, v, page, offset):
+    """One layer's new K/V (each (B, kv_heads, S, hd)) into the stacked
+    pool tree, in place; returns the updated tree. The pair
+    ``(kpool, vpool)`` stores them as they come; the int8 4-tuple
+    ``(kpool, vpool, kscale, vscale)`` quantizes per token row
+    (``ops.attention.paged.quantize_kv``), payload and scales landing
+    through the same ``[layer, page, offset]`` write."""
+    if len(pools) == 4:
+        from deepspeed_tpu.ops.attention.paged import quantize_kv
+        nb = pools[2].shape[-1] // k.shape[1]
+        with scope("kv_write"):
+            k, k_s = quantize_kv(k, nb)
+            v, v_s = quantize_kv(v, nb)
+        new = (k, v, k_s, v_s)
+    else:
+        new = (k, v)
+    return tuple(write_paged_kv_cache(pool, layer, x, page, offset)
+                 for pool, x in zip(pools, new))
+
+
+def gather_paged_layer(pools, layer: int, block_table, kv_heads: int):
+    """One layer's ``(kc, vc)`` stripes, each
+    (B, kv_heads, pages_per_seq * page_size, hd), gathered from the
+    stacked pool tree — float32 after ``dequantize_pool`` where the tree
+    is the int8 4-tuple, else in the pool's dtype."""
+    kc, vc = (gather_paged_kv(pool, layer, block_table, kv_heads)
+              for pool in pools[:2])
+    if len(pools) == 4:
+        from deepspeed_tpu.ops.attention.paged import dequantize_pool
+        with scope("kv_gather"):
+            kc = dequantize_pool(kc, gather_paged_kv(
+                pools[2], layer, block_table, kv_heads))
+            vc = dequantize_pool(vc, gather_paged_kv(
+                pools[3], layer, block_table, kv_heads))
+    return kc, vc
+
+
+def paged_decode_ctx(q, pools, layer: int, block_table, cache_position):
     """The seq-1 fused-kernel dispatch both families share: run
     :func:`deepspeed_tpu.ops.attention.paged.paged_decode_attention`
-    against the (already-written) pool and restore the (B, H, 1, hd)
-    context layout. One home so the kernel call contract cannot drift
-    between gpt2 and llama. ``k_scales``/``v_scales`` select the int8
-    pool arity — the per-page scale tiles stream into the kernel and
-    dequant happens in VMEM.
+    against layer ``layer`` of the (already-written) stacked pool tree
+    and restore the (B, H, 1, hd) context layout. One home so the kernel
+    call contract cannot drift between gpt2 and llama. The int8 4-tuple
+    selects the kernel's scale arity — the per-page scale tiles stream
+    into the kernel and dequant happens in VMEM.
 
     Under a serving mesh the engine traces its compiled programs inside
     ``parallel/pallas_shard.pallas_kernel_mesh``; consulting that
@@ -625,6 +683,8 @@ def paged_decode_ctx(q, kpool, vpool, block_table, cache_position,
     from deepspeed_tpu.ops.attention.paged import paged_decode_attention
     from deepspeed_tpu.parallel.pallas_shard import (current_kernel_mesh,
                                                      sharded_paged_decode)
+    kpool, vpool = pools[:2]
+    k_scales, v_scales = pools[2:] if len(pools) == 4 else (None, None)
     km = current_kernel_mesh()
     with scope("attn_cached"):
         if km is not None:
@@ -632,85 +692,62 @@ def paged_decode_ctx(q, kpool, vpool, block_table, cache_position,
                                        block_table, cache_position,
                                        mesh=km.mesh, axis=km.axis,
                                        k_scales=k_scales,
-                                       v_scales=v_scales)
+                                       v_scales=v_scales, layer=layer)
         else:
             out = paged_decode_attention(q[:, :, 0], kpool, vpool,
                                          block_table, cache_position,
                                          k_scales=k_scales,
-                                         v_scales=v_scales)
+                                         v_scales=v_scales, layer=layer)
         return out[:, :, None, :]
 
 
-def _paged_cache_attention(kpool, vpool, block_table, cache_position,
-                           out_box, attn_kernel: str = "gather",
-                           kscale_pool=None, vscale_pool=None):
-    """attention_fn for the paged cached forward (prefill-into-pages and
-    paged decode alike): scatter this call's K/V into the page pool via
-    the block table, then attend. Single-query calls (decode — and any
-    seq-1 prefill bucket) with ``attn_kernel="pallas"`` run the fused
-    paged-attention kernel straight against the pool
-    (:func:`paged_decode_ctx` — only live pages are read); everything
-    else gathers each row's logical stripe back and attends under the
-    shared ``causal_cache_mask`` (the numerics oracle / fallback).
-    Updated pools return through ``out_box``.
+def paged_attend(q, k, v, pools, layer: int, block_table, cache_position,
+                 page, offset, out_box, attn_kernel: str, stripe_attention):
+    """Layer ``layer`` of the paged cached forward, for both families
+    (prefill-into-pages and paged decode alike): write this call's K/V
+    into the stacked pool tree at ``[layer, page, offset]``
+    (:func:`write_paged_layer`), then attend. Single-query calls
+    (decode — and any seq-1 prefill bucket) with
+    ``attn_kernel="pallas"`` run the fused paged-attention kernel
+    straight against the pool (:func:`paged_decode_ctx` — only live
+    pages are read); everything else gathers each row's logical stripe
+    back and hands it to the family's
+    ``stripe_attention(q, kc, vc, cache_position)`` (the numerics oracle
+    / fallback). The updated tree — the pair, or the int8 4-tuple —
+    returns through ``out_box``."""
+    written = write_paged_layer(pools, layer, k, v, page, offset)
+    out_box.append(written)
+    if attn_kernel == "pallas" and q.shape[2] == 1:
+        return paged_decode_ctx(q, written, layer, block_table,
+                                cache_position)
+    kc, vc = gather_paged_layer(written, layer, block_table, k.shape[1])
+    if q.shape[2] > 1:
+        # context-parallel chunked prefill (ISSUE 19): under the
+        # engine's CP trace context, the chunk's sequence axis runs
+        # ring-sharded over the serving mesh — same stripe, same
+        # absolute-position causal rule (GQA folds group-wise inside
+        # the ring)
+        from deepspeed_tpu.parallel.pallas_shard import current_cp_mesh
+        cp = current_cp_mesh()
+        if cp is not None:
+            from deepspeed_tpu.ops.attention.ring import \
+                ring_prefill_attention
+            return ring_prefill_attention(q, kc, vc, cache_position,
+                                          cp.mesh, cp.axis)
+    return stripe_attention(q, kc, vc, cache_position)
 
-    With ``kscale_pool``/``vscale_pool`` the pool is int8: this call's
-    K/V quantize per token row (``ops.attention.paged.quantize_kv``)
-    before the scatter — payload and scales land through the SAME
-    block-table scatter — and every read path dequantizes (in-kernel
-    for pallas, post-gather for the oracle). ``out_box`` then carries
-    the 4-tuple ``(kp, vp, ksp, vsp)``."""
-    quantized = kscale_pool is not None
 
+def _paged_cache_attention(pools, layer: int, block_table, cache_position,
+                           page, offset, out_box,
+                           attn_kernel: str = "gather"):
+    """attention_fn for layer ``layer`` of the paged cached forward:
+    :func:`paged_attend` with GPT-2's stripe math under the shared
+    ``causal_cache_mask``."""
     def attn(q, k, v, rate, rng):
         del rate, rng                  # cached forward is deterministic
-        if quantized:
-            from deepspeed_tpu.ops.attention.paged import (dequantize_pool,
-                                                           quantize_kv)
-            nb = kscale_pool.shape[-1]
-            with scope("kv_write"):
-                k_q, k_s = quantize_kv(k, nb)
-                v_q, v_s = quantize_kv(v, nb)
-            kp = write_paged_kv_cache(kpool, k_q, block_table,
-                                      cache_position)
-            vp = write_paged_kv_cache(vpool, v_q, block_table,
-                                      cache_position)
-            ksp = write_paged_kv_cache(kscale_pool, k_s, block_table,
-                                       cache_position)
-            vsp = write_paged_kv_cache(vscale_pool, v_s, block_table,
-                                       cache_position)
-            out_box.append((kp, vp, ksp, vsp))
-        else:
-            kp = write_paged_kv_cache(kpool, k, block_table,
-                                      cache_position)
-            vp = write_paged_kv_cache(vpool, v, block_table,
-                                      cache_position)
-            ksp = vsp = None
-            out_box.append((kp, vp))
-        if attn_kernel == "pallas" and q.shape[2] == 1:
-            return paged_decode_ctx(q, kp, vp, block_table,
-                                    cache_position, k_scales=ksp,
-                                    v_scales=vsp)
-        kc = gather_paged_kv(kp, block_table)
-        vc = gather_paged_kv(vp, block_table)
-        if quantized:
-            with scope("kv_gather"):
-                kc = dequantize_pool(kc, gather_paged_kv(ksp, block_table))
-                vc = dequantize_pool(vc, gather_paged_kv(vsp, block_table))
-        if q.shape[2] > 1:
-            # context-parallel chunked prefill (ISSUE 19): under the
-            # engine's CP trace context, the chunk's sequence axis runs
-            # ring-sharded over the serving mesh — same stripe, same
-            # absolute-position causal rule
-            from deepspeed_tpu.parallel.pallas_shard import \
-                current_cp_mesh
-            cp = current_cp_mesh()
-            if cp is not None:
-                from deepspeed_tpu.ops.attention.ring import \
-                    ring_prefill_attention
-                return ring_prefill_attention(q, kc, vc, cache_position,
-                                              cp.mesh, cp.axis)
-        return _stripe_attention(q, kc, vc, cache_position)
+        return paged_attend(q, k, v, pools, layer, block_table,
+                            cache_position, page, offset, out_box,
+                            attn_kernel, _stripe_attention)
     return attn
 
 

@@ -214,6 +214,23 @@ def _llama_trunk(params, config: LlamaConfig, input_ids,
     return rms_norm(x, params["ln_f"]["w"], config.rms_norm_eps)
 
 
+def _gqa_stripe_attention(q, kc, vc, cache_position):
+    """Group-wise attention of ``q`` (B, heads, S, hd) over a whole
+    kv_heads-sized key/value stripe (B, kv_heads, kv_len, hd) under the
+    shared ``causal_cache_mask``, in float32: no head is replicated."""
+    from deepspeed_tpu.models.gpt2 import causal_cache_mask
+    B, H, S, hd = q.shape
+    hkv = kc.shape[1]
+    qg = q.reshape(B, hkv, H // hkv, S, hd)
+    scores = jnp.einsum("bkgsd,bkld->bkgsl", qg.astype(jnp.float32),
+                        kc.astype(jnp.float32)) / np.sqrt(hd)
+    mask = causal_cache_mask(cache_position, S, kc.shape[2])
+    scores = jnp.where(mask[:, :, None], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    ctx = jnp.einsum("bkgsl,bkld->bkgsd", probs, vc.astype(jnp.float32))
+    return ctx.reshape(B, H, S, hd).astype(q.dtype)
+
+
 def _gqa_offset_cache_attention(kcache, vcache, cache_position, out_box):
     """attention_fn for the cached llama forward (prefill-into-cache and
     decode alike): write this call's post-RoPE K/V into the hkv-head
@@ -221,102 +238,33 @@ def _gqa_offset_cache_attention(kcache, vcache, cache_position, out_box):
     slots <= each query's absolute position (the shared
     ``causal_cache_mask``). The cache stays kv_heads-sized — GQA's
     serving payoff. Updated caches return through ``out_box``."""
-    from deepspeed_tpu.models.gpt2 import causal_cache_mask, write_kv_cache
+    from deepspeed_tpu.models.gpt2 import write_kv_cache
 
     def attn(q, k, v):
         kc = write_kv_cache(kcache, k, cache_position)
         vc = write_kv_cache(vcache, v, cache_position)
         out_box.append((kc, vc))
-        B, H, S, hd = q.shape
-        hkv = kc.shape[1]
-        qg = q.reshape(B, hkv, H // hkv, S, hd)
-        scores = jnp.einsum("bkgsd,bkld->bkgsl", qg.astype(jnp.float32),
-                            kc.astype(jnp.float32)) / np.sqrt(hd)
-        mask = causal_cache_mask(cache_position, S, kc.shape[2])
-        scores = jnp.where(mask[:, :, None], scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
-        ctx = jnp.einsum("bkgsl,bkld->bkgsd", probs,
-                         vc.astype(jnp.float32))
-        return ctx.reshape(B, H, S, hd).astype(q.dtype)
+        return _gqa_stripe_attention(q, kc, vc, cache_position)
     return attn
 
 
-def _gqa_paged_cache_attention(kpool, vpool, block_table, cache_position,
-                               out_box, attn_kernel: str = "gather",
-                               kscale_pool=None, vscale_pool=None):
-    """Paged attention_fn for the cached llama forward: scatter this
-    call's post-RoPE K/V into the kv_heads-sized page pool via the block
-    table (``gpt2.write_paged_kv_cache``), then attend. Single-query
-    calls with ``attn_kernel="pallas"`` run the fused paged-decode
-    kernel, which serves GQA natively — the q_heads/kv_heads query rows
-    of each group share their kv head's page stream inside the kernel,
-    so no head replication ever materializes. Otherwise gather each
-    row's logical stripe back and attend group-wise under the shared
-    ``causal_cache_mask`` (the oracle/fallback). Updated pools return
-    through ``out_box``. ``kscale_pool``/``vscale_pool`` select the int8
-    pool (see ``gpt2._paged_cache_attention``): writes quantize per
-    token row, reads dequantize, ``out_box`` carries the 4-tuple."""
-    from deepspeed_tpu.models.gpt2 import (causal_cache_mask,
-                                           gather_paged_kv,
-                                           paged_decode_ctx,
-                                           write_paged_kv_cache)
-    quantized = kscale_pool is not None
+def _gqa_paged_cache_attention(pools, layer: int, block_table,
+                               cache_position, page, offset, out_box,
+                               attn_kernel: str = "gather"):
+    """Paged attention_fn for layer ``layer`` of the cached llama
+    forward: ``gpt2.paged_attend`` over the kv_heads-sized stacked pool
+    tree with this call's post-RoPE K/V. Single-query calls with
+    ``attn_kernel="pallas"`` run the fused paged-decode kernel, which
+    serves GQA natively — the q_heads/kv_heads query rows of each group
+    share their kv head's page stream inside the kernel, so no head
+    replication ever materializes; otherwise the gathered stripe is
+    attended group-wise (:func:`_gqa_stripe_attention`)."""
+    from deepspeed_tpu.models.gpt2 import paged_attend
 
     def attn(q, k, v):
-        if quantized:
-            from deepspeed_tpu.ops.attention.paged import (dequantize_pool,
-                                                           quantize_kv)
-            nb = kscale_pool.shape[-1]
-            k_q, k_s = quantize_kv(k, nb)
-            v_q, v_s = quantize_kv(v, nb)
-            kp = write_paged_kv_cache(kpool, k_q, block_table,
-                                      cache_position)
-            vp = write_paged_kv_cache(vpool, v_q, block_table,
-                                      cache_position)
-            ksp = write_paged_kv_cache(kscale_pool, k_s, block_table,
-                                       cache_position)
-            vsp = write_paged_kv_cache(vscale_pool, v_s, block_table,
-                                       cache_position)
-            out_box.append((kp, vp, ksp, vsp))
-        else:
-            kp = write_paged_kv_cache(kpool, k, block_table,
-                                      cache_position)
-            vp = write_paged_kv_cache(vpool, v, block_table,
-                                      cache_position)
-            ksp = vsp = None
-            out_box.append((kp, vp))
-        if attn_kernel == "pallas" and q.shape[2] == 1:
-            return paged_decode_ctx(q, kp, vp, block_table,
-                                    cache_position, k_scales=ksp,
-                                    v_scales=vsp)
-        kc = gather_paged_kv(kp, block_table)
-        vc = gather_paged_kv(vp, block_table)
-        if quantized:
-            kc = dequantize_pool(kc, gather_paged_kv(ksp, block_table))
-            vc = dequantize_pool(vc, gather_paged_kv(vsp, block_table))
-        if q.shape[2] > 1:
-            # context-parallel chunked prefill (ISSUE 19): ring over
-            # the serving mesh; GQA folds group-wise inside the ring
-            # exactly like the dense fallback below
-            from deepspeed_tpu.parallel.pallas_shard import \
-                current_cp_mesh
-            cp = current_cp_mesh()
-            if cp is not None:
-                from deepspeed_tpu.ops.attention.ring import \
-                    ring_prefill_attention
-                return ring_prefill_attention(q, kc, vc, cache_position,
-                                              cp.mesh, cp.axis)
-        B, H, S, hd = q.shape
-        hkv = kc.shape[1]
-        qg = q.reshape(B, hkv, H // hkv, S, hd)
-        scores = jnp.einsum("bkgsd,bkld->bkgsl", qg.astype(jnp.float32),
-                            kc.astype(jnp.float32)) / np.sqrt(hd)
-        mask = causal_cache_mask(cache_position, S, kc.shape[2])
-        scores = jnp.where(mask[:, :, None], scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
-        ctx = jnp.einsum("bkgsl,bkld->bkgsd", probs,
-                         vc.astype(jnp.float32))
-        return ctx.reshape(B, H, S, hd).astype(q.dtype)
+        return paged_attend(q, k, v, pools, layer, block_table,
+                            cache_position, page, offset, out_box,
+                            attn_kernel, _gqa_stripe_attention)
     return attn
 
 
@@ -328,41 +276,47 @@ def _llama_trunk_cached(params, config: LlamaConfig, input_ids, kv_cache,
     training. RoPE angles are gathered per row at each token's absolute
     position. Returns (hidden states after ln_f, updated kv_cache).
     ``block_tables`` switches to the paged pool pair (each
-    (layers, num_pages, kv_heads, page_size, hd)); an int8-quantized
-    pool arrives as the 4-tuple ``(kc, vc, kscale, vscale)``;
-    ``paged_attn_kernel`` picks the fused Pallas decode kernel or the
-    gather oracle for seq-1 queries."""
-    from deepspeed_tpu.models.gpt2 import _emb_rows, layer_params
-    kc, vc = kv_cache[0], kv_cache[1]
-    kscale, vscale = (kv_cache[2], kv_cache[3]) if len(kv_cache) == 4 \
-        else (None, None)
+    (layers, num_pages, page_size, kv_heads * hd), carried through the
+    layers and written in place); an int8-quantized pool arrives as the
+    4-tuple ``(kc, vc, kscale, vscale)``; ``paged_attn_kernel`` picks
+    the fused Pallas decode kernel or the gather oracle for seq-1
+    queries."""
+    from deepspeed_tpu.models.gpt2 import (_emb_rows, layer_params,
+                                           paged_write_index)
     B, S = input_ids.shape
-    if block_tables is not None:
-        max_len = block_tables.shape[1] * kc.shape[3]  # pages x page_size
+    paged = block_tables is not None
+    if paged:
+        page_size = kv_cache[0].shape[2]
+        max_len = block_tables.shape[1] * page_size
+        page, offset = paged_write_index(block_tables, cache_position, S,
+                                         page_size)
     else:
-        max_len = kc.shape[3]
+        max_len = kv_cache[0].shape[3]
     pos = cache_position[:, None] + jnp.arange(S)[None, :]
     cos_full, sin_full = rope_cos_sin(max_len, config.head_dim,
                                       config.rope_theta)
     cos_b, sin_b = cos_full[pos], sin_full[pos]        # (B, S, hd/2)
     x = _emb_rows(params["tok_emb"], input_ids, dtype)
-    new_caches = []
-    for i in range(config.num_layers):
-        box = []
-        if block_tables is not None:
+    if paged:
+        for i in range(config.num_layers):
+            box = []
             attn = _gqa_paged_cache_attention(
-                kc[i], vc[i], block_tables, cache_position, box,
-                attn_kernel=paged_attn_kernel,
-                kscale_pool=None if kscale is None else kscale[i],
-                vscale_pool=None if vscale is None else vscale[i])
-        else:
-            attn = _gqa_offset_cache_attention(kc[i], vc[i],
-                                               cache_position, box)
-        x = llama_block(layer_params(params, config, i), config, x,
-                        cos_b, sin_b, dtype, attention_fn=attn)
-        new_caches.append(box[0])
-    x = rms_norm(x, params["ln_f"]["w"], config.rms_norm_eps)
-    return x, tuple(jnp.stack(leaf) for leaf in zip(*new_caches))
+                kv_cache, i, block_tables, cache_position, page, offset,
+                box, attn_kernel=paged_attn_kernel)
+            x = llama_block(layer_params(params, config, i), config, x,
+                            cos_b, sin_b, dtype, attention_fn=attn)
+            kv_cache = box[0]
+    else:
+        new_caches = []
+        for i in range(config.num_layers):
+            box = []
+            attn = _gqa_offset_cache_attention(
+                kv_cache[0][i], kv_cache[1][i], cache_position, box)
+            x = llama_block(layer_params(params, config, i), config, x,
+                            cos_b, sin_b, dtype, attention_fn=attn)
+            new_caches.append(box[0])
+        kv_cache = tuple(jnp.stack(leaf) for leaf in zip(*new_caches))
+    return rms_norm(x, params["ln_f"]["w"], config.rms_norm_eps), kv_cache
 
 
 def llama_forward(params, config: LlamaConfig, input_ids,

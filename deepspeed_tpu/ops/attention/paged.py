@@ -26,7 +26,9 @@ Design:
   The page walk is bounded by each row's OWN live page count — the
   kernel never touches reserved-but-unwritten pages.
 - **Double-buffered DMA.** K and V page tiles stream
-  ``pool[page_id, kv_head]`` → VMEM through 2-deep async-copy buffers
+  ``pool[layer, page_id, :, kv_head * hd:(kv_head + 1) * hd]`` (one
+  token a pool row, heads major within it) → VMEM through 2-deep
+  async-copy buffers
   (``flash.py``'s streaming idiom): page ``i+1``'s copy is issued
   before page ``i`` is consumed — 2 tiles of VMEM per stream at any
   pool size.
@@ -45,9 +47,10 @@ gather path tier-1-testable without hardware
 (tests/unit/test_paged_attention.py).
 
 Compiled-TPU legality: Mosaic requires the DMA tile's lane (minor) dim
-to be 128-aligned; the streamed tile is ``(page_size, head_dim)``, so
-the compiled path needs ``head_dim % 128 == 0`` (plus a sublane-tile
-page size). The int8 pool's per-page scale tile is
+to be 128-aligned; the streamed tile is ``(page_size, head_dim)``, cut
+out of the pool's ``kv_heads * head_dim`` lanes at a multiple of
+``head_dim``, so the compiled path needs ``head_dim % 128 == 0`` (plus
+a sublane-tile page size). The int8 pool's per-page scale tile is
 ``(page_size, scale_blocks)`` fp32 — its lane dim is 1..4, so the int8
 arity is refused by the compiler at every page geometry and runs in
 interpret mode only. :func:`paged_decode_supported` is the one
@@ -176,10 +179,11 @@ def quantize_kv(x, scale_blocks: int = 1):
 
 
 def dequantize_pool(pool, scales):
-    """fp32 view of an int8 page pool: ``pool`` (..., page_size, hd)
-    int8, ``scales`` (..., page_size, nb) fp32 per-token-row absmax
-    scales with nb dividing hd. The gather/oracle-path dequant — the
-    Pallas kernel applies the same math per streamed tile in VMEM."""
+    """fp32 view of int8 keys or values with the head width last:
+    ``pool`` (..., hd) int8 (a gathered stripe, a page tile), ``scales``
+    (..., nb) fp32 per-token-row absmax scales with nb dividing hd. The
+    gather/oracle-path dequant — the Pallas kernel applies the same math
+    per streamed tile in VMEM."""
     hd = pool.shape[-1]
     nb = scales.shape[-1]
     s = jnp.repeat(scales, hd // nb, axis=-1)
@@ -188,26 +192,30 @@ def dequantize_pool(pool, scales):
 
 def paged_decode_reference(q, kpool, vpool, block_tables, cache_position,
                            sm_scale: Optional[float] = None,
-                           k_scales=None, v_scales=None):
-    """Dense oracle: gather each row's full logical stripe from the
-    pool, mask positions past ``cache_position``, softmax in fp32 —
-    exactly what the models' gather fallback computes for a seq-1
-    query. q: (B, H, hd); pools: (num_pages, kv_heads, page_size, hd);
-    block_tables: (B, P) int32; cache_position: (B,) int32 (position of
-    the already-written current token). With ``k_scales``/``v_scales``
-    ((num_pages, kv_heads, page_size, nb) fp32) the pools are int8 and
-    dequantized before the gather. Returns (B, H, hd)."""
+                           k_scales=None, v_scales=None, layer: int = 0):
+    """Dense oracle: gather each row's full logical stripe of layer
+    ``layer`` from the pool, mask positions past ``cache_position``,
+    softmax in fp32 — exactly what the models' gather fallback computes
+    for a seq-1 query. q: (B, H, hd); pools:
+    (layers, num_pages, page_size, kv_heads * hd), one token a row and
+    heads major within it; block_tables: (B, P) int32; cache_position:
+    (B,) int32 (position of the already-written current token). With
+    ``k_scales``/``v_scales``
+    ((layers, num_pages, page_size, kv_heads * nb) fp32) the pools are
+    int8 and dequantized after the gather. Returns (B, H, hd)."""
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(q.shape[-1])
-    if k_scales is not None:
-        kpool = dequantize_pool(kpool, k_scales)
-        vpool = dequantize_pool(vpool, v_scales)
     B, H, hd = q.shape
-    _, KH, ps, _ = kpool.shape
-    kc = kpool[block_tables].transpose(0, 2, 1, 3, 4).reshape(
-        B, KH, -1, hd)
-    vc = vpool[block_tables].transpose(0, 2, 1, 3, 4).reshape(
-        B, KH, -1, hd)
+    KH = kpool.shape[-1] // hd
+
+    def stripe(pool):                      # -> (B, KH, P * ps, w)
+        rows = pool[layer, block_tables]
+        return rows.reshape(B, -1, KH, rows.shape[-1] // KH).transpose(
+            0, 2, 1, 3)
+    kc, vc = stripe(kpool), stripe(vpool)
+    if k_scales is not None:
+        kc = dequantize_pool(kc, stripe(k_scales))
+        vc = dequantize_pool(vc, stripe(v_scales))
     qg = q.reshape(B, KH, H // KH, hd)
     s = jnp.einsum("bkgd,bkld->bkgl", qg.astype(jnp.float32),
                    kc.astype(jnp.float32)) * sm_scale
@@ -223,7 +231,7 @@ def paged_decode_reference(q, kpool, vpool, block_tables, cache_position,
 # the kernel
 # --------------------------------------------------------------------- #
 def _decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                   sm_scale, page_size, quantized):
+                   sm_scale, page_size, quantized, layer):
     """One (sequence, kv head) program: walk the row's live pages from
     the pool via double-buffered DMA, online-softmax the GQA group's
     queries against each streamed page tile.
@@ -250,18 +258,33 @@ def _decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     if quantized:
         q = q.astype(jnp.float32)   # dequantized tiles are fp32
 
-    def _start(i):
+    def _tile(ref, page, width):
+        # head kh's (page_size, width) tile of one page: a pool row is a
+        # token with its heads side by side, so the head is a lane range
+        lanes = pl.ds(pl.multiple_of(kh * width, width), width)
+        return ref.at[layer, page, :, lanes]
+
+    def _copies(i):
         page = tables_ref[b, i]
         slot = jax.lax.rem(i, 2)
-        pltpu.make_async_copy(k_ref.at[page, kh], kbuf.at[slot],
-                              ksem.at[slot]).start()
-        pltpu.make_async_copy(v_ref.at[page, kh], vbuf.at[slot],
-                              vsem.at[slot]).start()
+        hd = kbuf.shape[-1]
+        copies = [
+            pltpu.make_async_copy(_tile(k_ref, page, hd), kbuf.at[slot],
+                                  ksem.at[slot]),
+            pltpu.make_async_copy(_tile(v_ref, page, hd), vbuf.at[slot],
+                                  vsem.at[slot])]
         if quantized:
-            pltpu.make_async_copy(ks_ref.at[page, kh], ksbuf.at[slot],
-                                  kssem.at[slot]).start()
-            pltpu.make_async_copy(vs_ref.at[page, kh], vsbuf.at[slot],
-                                  vssem.at[slot]).start()
+            nb = ksbuf.shape[-1]
+            copies += [
+                pltpu.make_async_copy(_tile(ks_ref, page, nb),
+                                      ksbuf.at[slot], kssem.at[slot]),
+                pltpu.make_async_copy(_tile(vs_ref, page, nb),
+                                      vsbuf.at[slot], vssem.at[slot])]
+        return copies
+
+    def _start(i):
+        for c in _copies(i):
+            c.start()
 
     _start(0)                                         # num_pg >= 1 always
 
@@ -273,17 +296,11 @@ def _decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
             _start(i + 1)
         page = tables_ref[b, i]
         slot = jax.lax.rem(i, 2)
-        pltpu.make_async_copy(k_ref.at[page, kh], kbuf.at[slot],
-                              ksem.at[slot]).wait()
-        pltpu.make_async_copy(v_ref.at[page, kh], vbuf.at[slot],
-                              vsem.at[slot]).wait()
+        for c in _copies(i):
+            c.wait()
         kt = kbuf[slot]                               # (page_size, hd)
         vt = vbuf[slot]
         if quantized:
-            pltpu.make_async_copy(ks_ref.at[page, kh], ksbuf.at[slot],
-                                  kssem.at[slot]).wait()
-            pltpu.make_async_copy(vs_ref.at[page, kh], vsbuf.at[slot],
-                                  vssem.at[slot]).wait()
             hd = kt.shape[-1]
             nb = ksbuf.shape[-1]
             blk = hd // nb
@@ -335,16 +352,21 @@ def _compiler_params(interpret):
 
 
 def _paged_decode_pallas(q, kpool, vpool, scales, block_tables,
-                         cache_position, sm_scale, interpret):
+                         cache_position, sm_scale, interpret, layer):
     """Shared pallas_call builder for the dense-pool and int8-pool
-    arities; ``scales`` is None or the (k_scales, v_scales) pair."""
+    arities; ``scales`` is None or the (k_scales, v_scales) pair. The
+    kernel is handed the whole stacked pool, pinned in HBM, and indexes
+    ``layer`` itself: a ``pool[layer]`` operand would be a copy of the
+    layer."""
     B, H, hd = q.shape
-    num_pages, KH, ps, _ = kpool.shape
+    ps, width = kpool.shape[2:]
+    KH = width // hd
     G = H // KH
     qg = q.reshape(B, KH, G, hd)
     quantized = scales is not None
     kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
-                               page_size=ps, quantized=quantized)
+                               page_size=ps, quantized=quantized,
+                               layer=layer)
     in_specs = [
         pl.BlockSpec((1, 1, G, hd), lambda b, k, *_: (b, k, 0, 0)),
         # pools stay pinned in HBM; the kernel DMAs one
@@ -358,7 +380,7 @@ def _paged_decode_pallas(q, kpool, vpool, scales, block_tables,
     ]
     operands = [block_tables, cache_position, qg, kpool, vpool]
     if quantized:
-        nb = scales[0].shape[-1]
+        nb = scales[0].shape[-1] // KH
         # scale pools ride in HBM too: one (page_size, nb) fp32 tile
         # DMAs alongside each int8 page tile
         in_specs += [pl.BlockSpec(memory_space=pltpu.HBM),
@@ -391,33 +413,37 @@ def _paged_decode_pallas(q, kpool, vpool, scales, block_tables,
     return out.reshape(B, H, hd)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "interpret", "layer"))
 def _paged_decode_call(q, kpool, vpool, block_tables, cache_position,
-                       sm_scale, interpret):
+                       sm_scale, interpret, layer):
     return _paged_decode_pallas(q, kpool, vpool, None, block_tables,
-                                cache_position, sm_scale, interpret)
+                                cache_position, sm_scale, interpret, layer)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "interpret", "layer"))
 def _paged_decode_call_quant(q, kpool, vpool, k_scales, v_scales,
                              block_tables, cache_position, sm_scale,
-                             interpret):
+                             interpret, layer):
     return _paged_decode_pallas(q, kpool, vpool, (k_scales, v_scales),
                                 block_tables, cache_position, sm_scale,
-                                interpret)
+                                interpret, layer)
 
 
 def paged_decode_attention(q, kpool, vpool, block_tables, cache_position,
                            sm_scale: Optional[float] = None,
                            interpret: Optional[bool] = None,
-                           k_scales=None, v_scales=None):
+                           k_scales=None, v_scales=None, layer: int = 0):
     """Decode attention straight from the page pool — O(live tokens).
 
     q: ``(B, q_heads, head_dim)`` — ONE query token per row (the seq-1
-    decode specialization; q post-RoPE for llama). kpool/vpool:
-    ``(num_pages, kv_heads, page_size, head_dim)`` with
-    ``q_heads % kv_heads == 0`` (GQA served natively — each group of
-    ``q_heads/kv_heads`` query rows shares its kv head's page stream).
+    decode specialization; q post-RoPE for llama). kpool/vpool: the
+    stacked pool ``(layers, num_pages, page_size, kv_heads * head_dim)``
+    — one token a row, heads major within it — of which the static
+    ``layer`` is read, with ``q_heads % kv_heads == 0`` (GQA served
+    natively — each group of ``q_heads/kv_heads`` query rows shares its
+    kv head's page stream).
     block_tables: ``(B, pages_per_seq)`` int32 (entries past a row's
     reservation = the null page 0). cache_position: ``(B,)`` int32 —
     the position of this call's ALREADY-WRITTEN token; the row attends
@@ -426,8 +452,9 @@ def paged_decode_attention(q, kpool, vpool, block_tables, cache_position,
     read from HBM. Returns ``(B, q_heads, head_dim)`` in q's dtype,
     matching the gather path's math (fp32 softmax, masked identically).
 
-    ``k_scales``/``v_scales`` ((num_pages, kv_heads, page_size, nb)
-    fp32, both or neither) select the int8-pool arity: the pools are
+    ``k_scales``/``v_scales``
+    ((layers, num_pages, page_size, kv_heads * nb) fp32, both or
+    neither) select the int8-pool arity: the pools are
     int8 payload and each walked page's scale tile streams alongside,
     dequantized in VMEM after the DMA lands (PR 17 — the decode step
     moves ~half the bytes per live token).
@@ -439,10 +466,10 @@ def paged_decode_attention(q, kpool, vpool, block_tables, cache_position,
     assert q.ndim == 3, f"paged decode takes (B, H, hd) queries, got " \
         f"{q.shape}"
     B, H, hd = q.shape
-    KH = kpool.shape[1]
-    assert H % KH == 0 and kpool.shape == vpool.shape, (q.shape,
-                                                        kpool.shape,
-                                                        vpool.shape)
+    assert kpool.ndim == 4 and kpool.shape[-1] % hd == 0 and \
+        kpool.shape == vpool.shape, (q.shape, kpool.shape, vpool.shape)
+    KH = kpool.shape[-1] // hd
+    assert H % KH == 0, (q.shape, kpool.shape)
     assert block_tables.shape[0] == B and cache_position.shape == (B,), (
         block_tables.shape, cache_position.shape)
     assert (k_scales is None) == (v_scales is None), \
@@ -452,14 +479,15 @@ def paged_decode_attention(q, kpool, vpool, block_tables, cache_position,
     if interpret is None:
         interpret = not _use_pallas()
     if k_scales is not None:
-        assert k_scales.shape[:3] == kpool.shape[:3] and \
-            hd % k_scales.shape[-1] == 0, (k_scales.shape, kpool.shape)
+        nb, rem = divmod(k_scales.shape[-1], KH)
+        assert k_scales.shape[:3] == kpool.shape[:3] and rem == 0 and \
+            hd % nb == 0, (k_scales.shape, kpool.shape)
         return _paged_decode_call_quant(
             q, kpool, vpool, k_scales, v_scales,
             block_tables.astype(jnp.int32),
             cache_position.astype(jnp.int32), float(sm_scale),
-            bool(interpret))
+            bool(interpret), int(layer))
     return _paged_decode_call(q, kpool, vpool,
                               block_tables.astype(jnp.int32),
                               cache_position.astype(jnp.int32),
-                              float(sm_scale), bool(interpret))
+                              float(sm_scale), bool(interpret), int(layer))

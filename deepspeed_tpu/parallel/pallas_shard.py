@@ -180,45 +180,40 @@ def sharded_paged_decode(q, kpool, vpool, block_tables, cache_position,
                          mesh: Mesh, axis: str = "model",
                          sm_scale: Optional[float] = None,
                          interpret: Optional[bool] = None,
-                         k_scales=None, v_scales=None):
+                         k_scales=None, v_scales=None, layer: int = 0):
     """PR 8 ``paged_decode_attention`` under a GSPMD mesh: q sharded
-    over heads, pools over kv heads (the engine's
-    ``P(None, None, 'model')`` cache split, per layer), block tables and
+    over heads, the stacked pools over their row dimension (the engine's
+    ``P(None, None, None, 'model')`` pool split: heads are major within
+    a row, so each shard holds whole kv heads), block tables and
     positions replicated. The int8-pool arity (``k_scales``/``v_scales``,
-    PR 17) shards the fp32 scale pools over the same kv-head dim as the
+    PR 17) shards the fp32 scale pools over the same dimension as the
     payload pools — each shard dequantizes its own head's tiles in
     VMEM, no collectives. Falls through to the plain kernel when the
     axis is absent or size 1."""
     from deepspeed_tpu.ops.attention.paged import paged_decode_attention
     n = axis_size(mesh, axis)
     kernel = functools.partial(paged_decode_attention, sm_scale=sm_scale,
-                               interpret=interpret)
-    quantized = k_scales is not None
+                               interpret=interpret, layer=layer)
+    pools = (kpool, vpool)
+    if k_scales is not None:
+        pools += (k_scales, v_scales)
+
+    def inner(q, block_tables, cache_position, kpool, vpool, ks=None,
+              vs=None):
+        return kernel(q, kpool, vpool, block_tables, cache_position,
+                      k_scales=ks, v_scales=vs)
     if n <= 1:
-        if quantized:
-            return kernel(q, kpool, vpool, block_tables, cache_position,
-                          k_scales=k_scales, v_scales=v_scales)
-        return kernel(q, kpool, vpool, block_tables, cache_position)
-    H, KH = q.shape[1], kpool.shape[1]
+        return inner(q, block_tables, cache_position, *pools)
+    H, KH = q.shape[1], kpool.shape[-1] // q.shape[-1]
     assert head_shard_supported(n, H, KH), (
         f"paged decode: mesh axis {axis!r} ({n}-way) must divide "
         f"q heads ({H}) and kv heads ({KH})")
-    pool_specs = (P(None, axis), P(None, axis), P(None, axis), P(), P())
-    if quantized:
-        def inner(q, kpool, vpool, block_tables, cache_position, ks, vs):
-            return kernel(q, kpool, vpool, block_tables, cache_position,
-                          k_scales=ks, v_scales=vs)
-        f = jax.shard_map(
-            inner, mesh=mesh,
-            in_specs=pool_specs + (P(None, axis), P(None, axis)),
-            out_specs=P(None, axis), check_vma=False)
-        return f(q, kpool, vpool, block_tables, cache_position,
-                 k_scales, v_scales)
     f = jax.shard_map(
-        kernel, mesh=mesh,
-        in_specs=pool_specs,
+        inner, mesh=mesh,
+        in_specs=(P(None, axis), P(), P())
+        + (P(None, None, None, axis),) * len(pools),
         out_specs=P(None, axis), check_vma=False)
-    return f(q, kpool, vpool, block_tables, cache_position)
+    return f(q, block_tables, cache_position, *pools)
 
 
 def sharded_masked_flash(q, k, v, mask, key_mask=None,

@@ -43,16 +43,25 @@ def _load_tool(name):
     return mod
 
 
+# every kernel case reads this layer of a two-layer pool whose other
+# layer is NaN: a reader that strays off its layer shows at once
+LAYER = 1
+
+
 def _pool_case(rng, kv_heads, gqa, page_size, pages_per_seq, hd=16,
                num_pages=None, batch=5):
-    """One kernel test case: random pool + per-row tables of distinct
-    non-null pages + queries."""
+    """One kernel test case: random stacked pools in the pool's layout
+    ``(layers, num_pages, page_size, kv_heads * hd)`` (one token a row,
+    heads major within it; layer ``LAYER`` holds the data, the other is
+    NaN) + per-row tables of distinct non-null pages + queries."""
     H = kv_heads * gqa
     num_pages = num_pages or (batch * pages_per_seq + 1)
-    kpool = jnp.asarray(rng.randn(num_pages, kv_heads, page_size, hd),
-                        jnp.float32)
-    vpool = jnp.asarray(rng.randn(num_pages, kv_heads, page_size, hd),
-                        jnp.float32)
+
+    def pool():
+        data = np.full((2, num_pages, page_size, kv_heads * hd), np.nan)
+        data[LAYER] = rng.randn(num_pages, page_size, kv_heads * hd)
+        return jnp.asarray(data, jnp.float32)
+    kpool, vpool = pool(), pool()
     q = jnp.asarray(rng.randn(batch, H, hd), jnp.float32)
     tables = np.zeros((batch, pages_per_seq), np.int32)
     avail = list(range(1, num_pages))
@@ -82,8 +91,9 @@ class TestKernelParity:
                            P * page_size - 1], jnp.int32)
         tables = jnp.asarray(tables)
         out = paged_decode_attention(q, kpool, vpool, tables, pos,
-                                     interpret=True)
-        ref = paged_decode_reference(q, kpool, vpool, tables, pos)
+                                     interpret=True, layer=LAYER)
+        ref = paged_decode_reference(q, kpool, vpool, tables, pos,
+                                     layer=LAYER)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5)
 
@@ -104,8 +114,9 @@ class TestKernelParity:
         pos = jnp.asarray([17, 17, 5], jnp.int32)
         tables = jnp.asarray(tables)
         out = paged_decode_attention(q, kpool, vpool, tables, pos,
-                                     interpret=True)
-        ref = paged_decode_reference(q, kpool, vpool, tables, pos)
+                                     interpret=True, layer=LAYER)
+        ref = paged_decode_reference(q, kpool, vpool, tables, pos,
+                                     layer=LAYER)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5)
         # divergence only past the shared pages: rows 0/1 differ (their
@@ -124,7 +135,7 @@ class TestKernelParity:
                                         batch=2)
         out = paged_decode_attention(
             q, kpool, vpool, jnp.zeros((2, 2), jnp.int32),
-            jnp.zeros((2,), jnp.int32), interpret=True)
+            jnp.zeros((2,), jnp.int32), interpret=True, layer=LAYER)
         assert bool(jnp.all(jnp.isfinite(out)))
 
     def test_reads_only_live_pages(self):
@@ -139,27 +150,35 @@ class TestKernelParity:
                                              batch=2)
         pos = jnp.asarray([9, 3], jnp.int32)    # live pages: 2 and 1
         ref = paged_decode_reference(q, kpool, vpool,
-                                     jnp.asarray(tables), pos)
+                                     jnp.asarray(tables), pos, layer=LAYER)
         kpool_n, vpool_n = np.array(kpool), np.array(vpool)
-        kpool_n[tables[0, 2:]] = np.nan          # row 0: pages 2,3 dead
-        kpool_n[tables[1, 1:]] = np.nan          # row 1: pages 1..3 dead
-        vpool_n[tables[0, 2:]] = np.nan
-        vpool_n[tables[1, 1:]] = np.nan
+        kpool_n[:, tables[0, 2:]] = np.nan          # row 0: pages 2,3 dead
+        kpool_n[:, tables[1, 1:]] = np.nan          # row 1: pages 1..3 dead
+        vpool_n[:, tables[0, 2:]] = np.nan
+        vpool_n[:, tables[1, 1:]] = np.nan
         out = paged_decode_attention(q, jnp.asarray(kpool_n),
                                      jnp.asarray(vpool_n),
                                      jnp.asarray(tables), pos,
-                                     interpret=True)
+                                     interpret=True, layer=LAYER)
         assert bool(jnp.all(jnp.isfinite(out)))
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5)
 
 
-def _quantize_pools(kpool, vpool, scale_blocks=1):
-    """int8 pools + per-token-row fp32 scales from fp pools, via the
-    same quantize_kv the models' paged write path uses."""
+def _quantize_rows(pool, kv_heads, scale_blocks=1):
+    """One fp pool (or any array of pool rows, ``kv_heads * hd`` last) as
+    int8 rows + per-token-row fp32 scale rows ``kv_heads * scale_blocks``
+    wide, via the same per-head quantize_kv the models' paged write path
+    uses."""
     from deepspeed_tpu.ops.attention.paged import quantize_kv
-    kq, ks = quantize_kv(kpool, scale_blocks)
-    vq, vs = quantize_kv(vpool, scale_blocks)
+    lead = pool.shape[:-1]
+    q, s = quantize_kv(pool.reshape(lead + (kv_heads, -1)), scale_blocks)
+    return q.reshape(pool.shape), s.reshape(lead + (-1,))
+
+
+def _quantize_pools(kpool, vpool, scale_blocks=1, kv_heads=2):
+    kq, ks = _quantize_rows(kpool, kv_heads, scale_blocks)
+    vq, vs = _quantize_rows(vpool, kv_heads, scale_blocks)
     return kq, vq, ks, vs
 
 
@@ -191,16 +210,18 @@ class TestQuantizedPoolParity:
         tables = jnp.asarray(tables)
         kq, vq, ks, vs = _quantize_pools(kpool, vpool, scale_blocks)
         out = paged_decode_attention(q, kq, vq, tables, pos,
-                                     interpret=True,
+                                     interpret=True, layer=LAYER,
                                      k_scales=ks, v_scales=vs)
         # oracle 1: gather reference over the SAME int8 pool — pins the
         # kernel's in-VMEM dequant against the host-side dequant math
         ref_q = paged_decode_reference(q, kq, vq, tables, pos,
-                                       k_scales=ks, v_scales=vs)
+                                       k_scales=ks, v_scales=vs,
+                                       layer=LAYER)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref_q),
                                    atol=2e-5)
         # oracle 2: the original fp pool — the quantization-error budget
-        ref_fp = paged_decode_reference(q, kpool, vpool, tables, pos)
+        ref_fp = paged_decode_reference(q, kpool, vpool, tables, pos,
+                                        layer=LAYER)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref_fp),
                                    atol=self.QUANT_ATOL)
 
@@ -219,14 +240,15 @@ class TestQuantizedPoolParity:
         pos = jnp.asarray([9, 3], jnp.int32)    # live pages: 2 and 1
         kq, vq, ks, vs = _quantize_pools(kpool, vpool)
         ref = paged_decode_reference(q, kq, vq, jnp.asarray(tables),
-                                     pos, k_scales=ks, v_scales=vs)
+                                     pos, k_scales=ks, v_scales=vs,
+                                     layer=LAYER)
         ks_n, vs_n = np.array(ks), np.array(vs)
-        ks_n[tables[0, 2:]] = np.nan             # row 0: pages 2,3 dead
-        ks_n[tables[1, 1:]] = np.nan             # row 1: pages 1..3 dead
-        vs_n[tables[0, 2:]] = np.nan
-        vs_n[tables[1, 1:]] = np.nan
+        ks_n[:, tables[0, 2:]] = np.nan             # row 0: pages 2,3 dead
+        ks_n[:, tables[1, 1:]] = np.nan             # row 1: pages 1..3 dead
+        vs_n[:, tables[0, 2:]] = np.nan
+        vs_n[:, tables[1, 1:]] = np.nan
         out = paged_decode_attention(q, kq, vq, jnp.asarray(tables),
-                                     pos, interpret=True,
+                                     pos, interpret=True, layer=LAYER,
                                      k_scales=jnp.asarray(ks_n),
                                      v_scales=jnp.asarray(vs_n))
         assert bool(jnp.all(jnp.isfinite(out)))
@@ -253,10 +275,10 @@ class TestQuantizedPoolParity:
         tables = jnp.asarray(tables)
         kq, vq, ks, vs = _quantize_pools(kpool, vpool)
         out = paged_decode_attention(q, kq, vq, tables, pos,
-                                     interpret=True,
+                                     interpret=True, layer=LAYER,
                                      k_scales=ks, v_scales=vs)
         ref = paged_decode_reference(q, kq, vq, tables, pos,
-                                     k_scales=ks, v_scales=vs)
+                                     k_scales=ks, v_scales=vs, layer=LAYER)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5)
         np.testing.assert_array_equal(np.asarray(out[0]),
@@ -481,9 +503,10 @@ class TestGatherSeq1Path:
         """Ragged positions, a one-token cache, a table mapped only as
         far as it is used, the last position of the table, and an
         inactive slot whose table is all null page."""
-        from deepspeed_tpu.models.gpt2 import _paged_cache_attention
-        from deepspeed_tpu.ops.attention.paged import (
-            paged_decode_reference, quantize_kv)
+        from deepspeed_tpu.models.gpt2 import (_paged_cache_attention,
+                                               paged_write_index)
+        from deepspeed_tpu.ops.attention.paged import \
+            paged_decode_reference
         rng = np.random.RandomState(25)
         heads, hd, ps, pages = 4, 16, 4, 5
         positions = np.asarray([0, 0, 6, 19, 7, 12], np.int32)
@@ -495,22 +518,21 @@ class TestGatherSeq1Path:
         dtype = jnp.bfloat16 if pool == "bf16" else jnp.float32
         q, k, v = (jnp.asarray(rng.randn(batch, heads, 1, hd), dtype)
                    for _ in range(3))
-        scales = {}
         if pool == "int8":
-            kpool, ks = quantize_kv(kpool)
-            vpool, vs = quantize_kv(vpool)
-            scales = dict(kscale_pool=ks, vscale_pool=vs)
+            pools = _quantize_pools(kpool, vpool, kv_heads=heads)
         else:
-            kpool, vpool = kpool.astype(dtype), vpool.astype(dtype)
+            pools = (kpool.astype(dtype), vpool.astype(dtype))
+        tables, positions = jnp.asarray(tables), jnp.asarray(positions)
+        page, offset = paged_write_index(tables, positions, 1, ps)
         box = []
         got = _paged_cache_attention(
-            kpool, vpool, jnp.asarray(tables), jnp.asarray(positions),
-            box, attn_kernel="gather", **scales)(q, k, v, 0.0, None)
+            pools, LAYER, tables, positions, page, offset, box,
+            attn_kernel="gather")(q, k, v, 0.0, None)
         assert got.shape == (batch, heads, 1, hd) and got.dtype == dtype
         kp, vp, *written_scales = box[0]
         ref = paged_decode_reference(
-            q[:, :, 0].astype(jnp.float32), kp, vp, jnp.asarray(tables),
-            jnp.asarray(positions),
+            q[:, :, 0].astype(jnp.float32), kp, vp, tables, positions,
+            layer=LAYER,
             **dict(zip(("k_scales", "v_scales"), written_scales)))
         np.testing.assert_allclose(
             np.asarray(got[:, :, 0].astype(jnp.float32)), np.asarray(ref),
@@ -541,6 +563,187 @@ class TestGatherSeq1Path:
         monkeypatch.setattr(gpt2, "_stripe_attention",
                             _plain_stripe_attention)
         assert got == generate()
+
+
+def _np_write(pool, layer, new, tables, positions):
+    """The paged write, plainly: token j of row b of ``new``
+    (B, heads, S, w) becomes the pool row ``[layer, page, offset]``, its
+    heads side by side; a position past the table lands in null page 0.
+    ``pool`` is (layers, num_pages, page_size, heads * w)."""
+    pool = np.array(pool)
+    ps = pool.shape[2]
+    for b, row in enumerate(np.asarray(new)):
+        for j in range(row.shape[1]):
+            pos = int(positions[b]) + j
+            slot = pos // ps
+            page = tables[b, slot] if slot < tables.shape[1] else 0
+            pool[layer, page, pos % ps] = row[:, j].reshape(-1)
+    return pool
+
+
+def _np_gather(pool, layer, tables, kv_heads):
+    """The paged gather, plainly: (B, kv_heads, P * page_size, w) with
+    position ``t * page_size + o`` of row b read from the pool row
+    ``[layer, tables[b, t], o]``."""
+    pool = np.asarray(pool)
+    ps, width = pool.shape[2:]
+    out = np.zeros((tables.shape[0], kv_heads, tables.shape[1] * ps,
+                    width // kv_heads), pool.dtype)
+    for b, row in enumerate(tables):
+        for t, page in enumerate(row):
+            for o in range(ps):
+                out[b, :, t * ps + o] = pool[layer, page, o].reshape(
+                    kv_heads, -1)
+    return out
+
+
+class TestPoolRoundTrip:
+    """ISSUE 28: the pool's row layout
+    ``(layers, num_pages, page_size, kv_heads * head_dim)`` and its
+    in-place write at ``[layer, page, offset]``, against plain numpy."""
+
+    HEADS, HD, PS, PAGES = 4, 8, 4, 3       # a table of 12 positions
+
+    def _case(self, seed, positions, tokens):
+        """Two-layer K/V pools of noise, a table a row of distinct
+        non-null pages, and new K/V of ``tokens`` tokens a row."""
+        rng = np.random.RandomState(seed)
+        batch = len(positions)
+        _, kpool, vpool, tables = _pool_case(rng, self.HEADS, 1, self.PS,
+                                             self.PAGES, hd=self.HD,
+                                             batch=batch)
+        kpool, vpool = (jnp.asarray(rng.randn(*pool.shape), jnp.float32)
+                        for pool in (kpool, vpool))
+        k, v = (jnp.asarray(rng.randn(batch, self.HEADS, tokens, self.HD),
+                            jnp.float32) for _ in range(2))
+        return kpool, vpool, tables, np.asarray(positions, np.int32), k, v
+
+    @pytest.mark.parametrize("positions,tokens", [
+        ([0, 3, 4, 7, 11], 1),      # decode: both sides of each page edge
+        ([0, 2, 3, 5], 6),          # prefill: rows that cross one or two
+        ([0, 0, 0], 12),            # prefill: the whole table
+    ], ids=["ragged_decode", "ragged_prefill", "full_table"])
+    def test_write_then_gather_match_numpy(self, positions, tokens):
+        """Every written row is where the plain write puts it, every
+        other row of the pool — the other layer's too — is bit for bit
+        what it was, and the gather returns the plain gather's
+        stripe."""
+        from deepspeed_tpu.models.gpt2 import (gather_paged_kv,
+                                               paged_write_index,
+                                               write_paged_kv_cache)
+        kpool, _, tables, positions, k, _ = self._case(28, positions,
+                                                       tokens)
+        page, offset = paged_write_index(jnp.asarray(tables),
+                                         jnp.asarray(positions), tokens,
+                                         self.PS)
+        got = write_paged_kv_cache(kpool, LAYER, k, page, offset)
+        want = _np_write(kpool, LAYER, k, tables, positions)
+        assert got.shape == kpool.shape and got.dtype == kpool.dtype
+        np.testing.assert_array_equal(np.asarray(got), want)
+        np.testing.assert_array_equal(
+            np.asarray(gather_paged_kv(got, LAYER, jnp.asarray(tables),
+                                       self.HEADS)),
+            _np_gather(want, LAYER, tables, self.HEADS))
+
+    def test_write_past_the_table_lands_in_the_null_page(self):
+        """A decode position at the table's extent, a prefill that runs
+        over it, and a row whose table is unreserved (all 0): what has
+        no page goes to null page 0 and no live page is touched."""
+        from deepspeed_tpu.models.gpt2 import (paged_write_index,
+                                               write_paged_kv_cache)
+        extent = self.PS * self.PAGES
+        kpool, _, tables, positions, k, _ = self._case(
+            29, [extent - 2, extent, 1], 3)
+        tables[2] = 0
+        page, offset = paged_write_index(jnp.asarray(tables),
+                                         jnp.asarray(positions), 3,
+                                         self.PS)
+        np.testing.assert_array_equal(
+            np.asarray(page).reshape(3, 3),
+            [[tables[0, -1], tables[0, -1], 0], [0, 0, 0], [0, 0, 0]])
+        got = np.asarray(write_paged_kv_cache(kpool, LAYER, k, page,
+                                              offset))
+        want = _np_write(kpool, LAYER, k, tables, positions)
+        # several rows land on one null-page row: which of them stays
+        # is not defined, and nothing reads it unmasked
+        np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
+        np.testing.assert_array_equal(got[1 - LAYER], np.asarray(kpool)[0])
+
+    @pytest.mark.parametrize("scale_blocks", [1, 2])
+    def test_int8_tuple_write_then_gather(self, scale_blocks):
+        """The int8 4-tuple: payload rows and scale rows
+        (``kv_heads * scale_blocks`` wide) land through the same index,
+        and the gathered stripe is the dequantized plain gather."""
+        from deepspeed_tpu.models.gpt2 import (gather_paged_layer,
+                                               paged_write_index,
+                                               write_paged_layer)
+        from deepspeed_tpu.ops.attention.paged import (dequantize_pool,
+                                                       quantize_kv)
+        kpool, vpool, tables, positions, k, v = self._case(
+            30, [0, 3, 6, 2], 5)
+        pools = _quantize_pools(kpool, vpool, scale_blocks,
+                                kv_heads=self.HEADS)
+        assert pools[2].shape == kpool.shape[:3] + (
+            self.HEADS * scale_blocks,)
+        page, offset = paged_write_index(jnp.asarray(tables),
+                                         jnp.asarray(positions), 5,
+                                         self.PS)
+        got = write_paged_layer(pools, LAYER, k, v, page, offset)
+        assert [g.dtype for g in got] == [jnp.int8, jnp.int8,
+                                          jnp.float32, jnp.float32]
+        new = quantize_kv(k, scale_blocks) + quantize_kv(v, scale_blocks)
+        want = [_np_write(pool, LAYER, x, tables, positions)
+                for pool, x in zip(pools, (new[0], new[2], new[1],
+                                           new[3]))]
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), w)
+        kc, vc = gather_paged_layer(got, LAYER, jnp.asarray(tables),
+                                    self.HEADS)
+        for stripe, payload, scales in ((kc, want[0], want[2]),
+                                        (vc, want[1], want[3])):
+            np.testing.assert_array_equal(
+                np.asarray(stripe),
+                np.asarray(dequantize_pool(
+                    _np_gather(payload, LAYER, tables, self.HEADS),
+                    _np_gather(scales, LAYER, tables, self.HEADS))))
+
+    @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+    def test_export_then_import_returns_the_same_rows(self, kv_dtype):
+        """Pages exported from one pool (the page axis is dimension 1 of
+        every leaf, whatever a row holds) and imported into another at
+        other page numbers gather back, layer by layer, as the rows that
+        left."""
+        from deepspeed_tpu.inference import InferenceEngine
+        from deepspeed_tpu.inference.kv_cache import (init_paged_kv_cache,
+                                                      paged_spec_for)
+        from deepspeed_tpu.models.gpt2 import gather_paged_kv
+        cfg, _ = tiny_gpt2()
+        dtype = jnp.int8 if kv_dtype == "int8" else jnp.bfloat16
+        spec = paged_spec_for(cfg, 9, 4, 16, dtype=dtype)
+        assert spec.shape == (cfg.num_layers, 9, 4,
+                              spec.kv_heads * spec.head_dim)
+        rng = np.random.RandomState(31)
+        source = tuple(
+            jnp.asarray(rng.randint(-100, 100, leaf.shape), leaf.dtype)
+            for leaf in init_paged_kv_cache(spec))
+        src_pages, dst_pages = np.asarray([5, 2, 7]), np.asarray([1, 8, 3])
+        slab = InferenceEngine._export_pages_impl(None, source,
+                                                  jnp.asarray(src_pages))
+        assert all(s.shape == (leaf.shape[0], 3) + leaf.shape[2:]
+                   for s, leaf in zip(slab, source))
+        dest = InferenceEngine._import_pages_impl(
+            None, init_paged_kv_cache(spec), slab, jnp.asarray(dst_pages))
+        for layer in range(spec.num_layers):
+            for src, dst in zip(source, dest):
+                np.testing.assert_array_equal(
+                    np.asarray(gather_paged_kv(
+                        dst, layer, jnp.asarray(dst_pages[None]),
+                        spec.kv_heads)),
+                    np.asarray(gather_paged_kv(
+                        src, layer, jnp.asarray(src_pages[None]),
+                        spec.kv_heads)))
+        untouched = np.setdiff1d(np.arange(spec.num_pages), dst_pages)
+        assert not np.asarray(dest[0])[:, untouched].any()
 
 
 class TestDecodeWidthBuckets:

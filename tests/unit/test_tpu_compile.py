@@ -129,14 +129,17 @@ def test_flash_attention_under_a_data_mesh_needs_the_shard_wrap():
 
 
 def _paged_decode_specs(head_dim, page_size, pool_dtype):
-    batch, heads, num_pages, pages_per_seq = 8, 16, 128, 16
-    pool = _spec((num_pages, heads, page_size, head_dim), pool_dtype)
+    """The kernel's operands over a stacked pool in its row layout,
+    ``(layers, num_pages, page_size, heads * head_dim)``."""
+    batch, heads, layers, num_pages, pages_per_seq = 8, 16, 2, 128, 16
+    pool = _spec((layers, num_pages, page_size, heads * head_dim),
+                 pool_dtype)
     specs = [_spec((batch, heads, head_dim)), pool, pool,
              _spec((batch, pages_per_seq), jnp.int32),
              _spec((batch,), jnp.int32)]
     if pool_dtype == jnp.int8:
-        # the engine's scale leaves at kv_quant_block 0: one per row
-        scale = _spec((num_pages, heads, page_size, 1), jnp.float32)
+        # the engine's scale leaves at kv_quant_block 0: one per head
+        scale = _spec((layers, num_pages, page_size, heads), jnp.float32)
         specs += [scale, scale]
     return specs
 
@@ -144,7 +147,7 @@ def _paged_decode_specs(head_dim, page_size, pool_dtype):
 def _paged_decode(q, kpool, vpool, tables, positions, *scales):
     kw = dict(zip(("k_scales", "v_scales"), scales))
     return paged_decode_attention(q, kpool, vpool, tables, positions,
-                                  interpret=False, **kw)
+                                  interpret=False, layer=1, **kw)
 
 
 def test_paged_decode_bf16_head128_compiles():
@@ -195,12 +198,14 @@ def _cached_reader(cache, q_rows, cache_dtype):
     qkv = _spec((ROWS, HEADS, q_rows, HEAD_DIM))
     positions = _spec((ROWS,), jnp.int32)
     if cache == "paged":
-        pool = _spec((POOL_PAGES, HEADS, PAGE, HEAD_DIM), cache_dtype)
+        pool = _spec((1, POOL_PAGES, PAGE, HEADS * HEAD_DIM), cache_dtype)
 
         def fn(q, k, v, kpool, vpool, tables, pos):
             box = []
-            out = gpt2._paged_cache_attention(kpool, vpool, tables, pos,
-                                              box)(q, k, v, 0.0, None)
+            page, offset = gpt2.paged_write_index(tables, pos, q_rows, PAGE)
+            out = gpt2._paged_cache_attention(
+                (kpool, vpool), 0, tables, pos, page, offset, box)(
+                    q, k, v, 0.0, None)
             return out, box[0]
         return fn, (qkv, qkv, qkv, pool, pool,
                     _spec((ROWS, TABLE_PAGES), jnp.int32), positions)
@@ -214,21 +219,33 @@ def _cached_reader(cache, q_rows, cache_dtype):
     return fn, (qkv, qkv, qkv, stripe, stripe, positions)
 
 
+def _entry(compiled):
+    """The optimized HLO's ENTRY computation, as text."""
+    return re.search(r"^ENTRY .*?^}", compiled.as_text(),
+                     re.S | re.M).group(0)
+
+
+def _entry_results(compiled):
+    """``(opcode, dtype, elements, called computation)`` of every array
+    an instruction of the optimized HLO's ENTRY computation produces
+    (tuple results included): what the program really writes, not what
+    a fusion holds in registers."""
+    results = []
+    for line in _entry(compiled).splitlines():
+        inst = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if inst:
+            calls = re.search(r"calls=%?([\w.\-]+)", line)
+            results += [(inst.group(2), dtype,
+                         int(np.prod([int(d) for d in dims.split(",")])),
+                         calls and calls.group(1))
+                        for dtype, dims in re.findall(r"(\w+)\[([\d,]+)\]",
+                                                      inst.group(1))]
+    return results
+
+
 def _entry_float32_results(compiled):
-    """Element counts of every float32 array an instruction of the
-    optimized HLO's ENTRY computation produces (tuple results included):
-    what the program really writes, not what a fusion holds in
-    registers."""
-    entry = re.search(r"^ENTRY .*?^}", compiled.as_text(),
-                      re.S | re.M).group(0)
-    counts = []
-    for line in entry.splitlines():
-        result = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) [\w\-]+\(", line)
-        if result:
-            counts += [int(np.prod([int(d) for d in dims.split(",")]))
-                       for dims in re.findall(r"f32\[([\d,]+)\]",
-                                              result.group(1))]
-    return counts
+    return [elems for _, dtype, elems, _ in _entry_results(compiled)
+            if dtype == "f32"]
 
 
 @pytest.mark.parametrize("cache", ["paged", "contiguous"])
@@ -268,3 +285,129 @@ def test_wide_queries_and_float32_stripes_lower_as_before(q_rows,
         return jax.jit(fn).lower(*specs).as_text().split("\n", 1)[1]
     assert (program(gpt2._stripe_attention)
             == program(_plain_stripe_attention))
+
+
+# --------------------------------------------------------------------- #
+# the page pool written in place (ISSUE 28)
+# --------------------------------------------------------------------- #
+TRUNK_LAYERS = 4
+# opcodes that name or view an array and write none
+_NO_WRITE = {"parameter", "tuple", "get-tuple-element", "bitcast"}
+
+
+def _paged_trunk(family, heads, kv_heads, head_dim):
+    """A four-layer cached trunk over the paged pool, as the engine's
+    serving programs run it (gather reader, pool donated):
+    ``(jitted fn(params, cache, ids, positions, tables), param specs)``.
+    The vocabulary is small so that no embedding is of a layer slice's
+    size."""
+    from deepspeed_tpu.models import gpt2, llama
+    hidden = heads * head_dim
+    if family == "gpt2":
+        cfg = gpt2.GPT2Config(vocab_size=512, hidden_size=hidden,
+                              num_layers=TRUNK_LAYERS, num_heads=heads,
+                              max_position_embeddings=1024)
+        init, trunk = gpt2.init_gpt2_params, gpt2._gpt2_trunk_cached
+    else:
+        cfg = llama.LlamaConfig(vocab_size=512, hidden_size=hidden,
+                                num_layers=TRUNK_LAYERS, num_heads=heads,
+                                num_kv_heads=kv_heads,
+                                max_position_embeddings=1024)
+        init, trunk = llama.init_llama_params, llama._llama_trunk_cached
+    params = jax.tree_util.tree_map(
+        lambda x: _spec(x.shape, x.dtype),
+        jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0))))
+
+    def fn(params, cache, ids, positions, tables):
+        return trunk(params, cfg, ids, cache, positions, jnp.bfloat16,
+                     block_tables=tables)
+    return jax.jit(fn, donate_argnums=(1,)), params
+
+
+@pytest.mark.parametrize("rows,tokens", [(ROWS, 1), (8, 128)],
+                         ids=["decode", "prefill8x128"])
+@pytest.mark.parametrize("family,heads,kv_heads,head_dim", [
+    ("gpt2", 16, 16, 64),       # GPT-2 345M: rows of 1,024 lanes
+    ("gpt2", 25, 25, 64),       # GPT-2 XL: 1,600
+    ("llama", 32, 8, 128),      # Llama-sized GQA: 8 x 128
+], ids=["gpt2_345m", "gpt2_xl", "llama_gqa"])
+def test_paged_trunk_writes_the_pool_in_place(family, heads, kv_heads,
+                                              head_dim, rows, tokens):
+    """The pool leaf ``(layers, pages, page_size, kv_heads * head_dim)``
+    keeps its default layout as an entry parameter, so a serving program
+    that carries it through its layers (a) hands the donated pool back
+    in the buffers it came in, (b) writes nothing of a layer slice's
+    size or more but the 2 x layers scatters that alias it and, in
+    decode, the gathered stripes, and (c) needs temporaries of no more
+    than one layer's K and V stripes (their heads on whole lane tiles)
+    plus one layer slice. With a ``head_dim`` of 64 as the last
+    dimension the parameter's layout put the PAGES on the lanes, and
+    every program sliced, re-laid and re-stacked the whole pool
+    (2,354 MB of temporaries at these four layers)."""
+    width = kv_heads * head_dim
+    pool_shape = (TRUNK_LAYERS, POOL_PAGES, PAGE, width)
+    layer_elems = int(np.prod(pool_shape[1:]))
+    pool_elems = TRUNK_LAYERS * layer_elems
+    stripe_elems = rows * TABLE_PAGES * PAGE * width
+    fn, params = _paged_trunk(family, heads, kv_heads, head_dim)
+    pool = _spec(pool_shape)
+    compiled = fn.lower(params, (pool, pool),
+                        _spec((rows, tokens), jnp.int32),
+                        _spec((rows,), jnp.int32),
+                        _spec((rows, TABLE_PAGES), jnp.int32)).compile()
+    text = compiled.as_text()
+
+    # (a) results 1 and 2, the K and V pools, alias the pool parameters
+    aliases = dict(re.findall(r"\{(\d+)\}: \((\d+), \{\}, may-alias\)",
+                              text.split("\n", 1)[0]))
+    dims = ",".join(map(str, pool_shape))
+    pool_params = set(re.findall(
+        rf"= bf16\[{dims}\]\S* parameter\((\d+)\)", _entry(compiled)))
+    assert len(pool_params) == 2
+    assert {aliases.get("1"), aliases.get("2")} == pool_params
+
+    # (b) what is written at a layer slice's size or more
+    scatters = 0
+    for opcode, dtype, elems, calls in _entry_results(compiled):
+        if elems < layer_elems or opcode in _NO_WRITE:
+            continue
+        if elems == pool_elems:
+            root = re.search(
+                rf"^%?{re.escape(calls or '?')} .*?^\s*ROOT [^\n]*? "
+                r"(scatter|dynamic-update-slice)\(%?param_0", text,
+                re.S | re.M)
+            assert opcode == "fusion" and root, (opcode, dtype, calls)
+            scatters += 1
+        else:
+            assert elems == stripe_elems, (opcode, dtype, elems)
+    assert scatters == 2 * TRUNK_LAYERS
+
+    # (c) temporaries: K and V stripes of one layer, a head on whole
+    # lane tiles, and one layer slice
+    stripes = 2 * rows * kv_heads * TABLE_PAGES * PAGE * max(head_dim, 128)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 2 * (stripes + layer_elems)
+
+
+def test_serving_compiler_options_share_the_layers_code(monkeypatch):
+    """The in-place programs no longer run the compiler short of memory,
+    and it was only when short of memory that it generated the code of
+    like fusions once: without the engine's compiler option a 24-layer
+    decode program is 71 MB of code where the parent's was 10, the 13
+    serving programs hold 0.7 GB more of the chip and no longer fit the
+    compile cache they are loaded from (ISSUE 28). The option is the
+    TPU compiler's own: this holds its name and its effect to the
+    installed compiler."""
+    from deepspeed_tpu.inference import engine
+    assert engine._program_compiler_options() is None     # not a TPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    options = engine._program_compiler_options()
+    fn, params = _paged_trunk("gpt2", 16, 16, 64)
+    pool = _spec((TRUNK_LAYERS, POOL_PAGES, PAGE, 16 * 64))
+    lowered = fn.lower(params, (pool, pool), _spec((ROWS, 1), jnp.int32),
+                       _spec((ROWS,), jnp.int32),
+                       _spec((ROWS, TABLE_PAGES), jnp.int32))
+    plain, shared = (
+        lowered.compile(compiler_options=o).memory_analysis()
+        .generated_code_size_in_bytes for o in (None, options))
+    assert shared < plain / 2
