@@ -54,8 +54,7 @@ from deepspeed_tpu.models.gpt2 import (GPT2_MEDIUM, count_params,  # noqa
                                        gpt2_forward, gpt2_loss_fn,
                                        init_gpt2_params)
 from deepspeed_tpu.ops.attention.flash import (attention_reference,  # noqa
-                                               flash_attention,
-                                               get_attention_options)
+                                               flash_attention)
 from deepspeed_tpu.ops.attention.paged import (paged_decode_attention,  # noqa
                                                paged_decode_reference,
                                                paged_decode_supported)
@@ -221,8 +220,6 @@ def check_paged_decode(seed, head_dim=128, page_size=16):
 
 
 def phase_kernels(seed):
-    assert get_attention_options().kernel == "masked", \
-        "the default training attention kernel must be the masked one"
     checks = [(f"masked flash causal fwd+bwd {s}",
                lambda s=s: check_flash_causal(s, seed))
               for s in KERNEL_SHAPES]

@@ -19,7 +19,7 @@ TPU-first:
   hash-dedup so a fleet of requests on one system prompt prefills it
   once. Page allocation is host-side (scheduler) — the compiled
   programs never see it. ``paged_kv.enabled: false`` restores the dense
-  slot x max_len cache (the PR-5 layout, kept as the parity/bench
+  slot x max_len cache (the PR-5 layout, kept as the parity
   baseline).
 - **Fused paged-decode attention (default).** The decode step computes
   attention *directly against the page pool* through the Pallas
@@ -78,7 +78,7 @@ TPU-first:
   all host-side and sync-free (``inference/tracing.py``), so the
   compiled program set and the zero-recompile contract are untouched
   with tracing on. ``tools/obs_report.py --serve`` renders the SLO
-  report; the ``serve_trace_overhead`` bench row pins the no-overhead
+  report; ``tests/unit/test_serve_trace.py`` pins the no-overhead
   claim.
 """
 
@@ -438,7 +438,7 @@ class InferenceEngine:
         self._handoff_stats = HandoffStats() if self.disagg else None
         # chunked engines keep the trace too: the TBT bound is the pure
         # ordering pin "at most one chunk dispatch per step, after every
-        # decode of that step" (bench chunked_prefill_tbt checks it)
+        # decode of that step" (tests/unit/test_chunked_prefill.py)
         self._dispatch_trace = DispatchTrace() \
             if (self.disagg or self.chunked) else None
         self._link = None
@@ -484,7 +484,7 @@ class InferenceEngine:
         self._serve_secs = 0.0
         # offline fp-oracle probe result (record_quant_logit_err):
         # serving can't afford an fp oracle per dispatch, so the error
-        # rides telemetry only when a test/bench measures it
+        # rides telemetry only when a test measures it
         self.quant_logit_err: Optional[float] = None
         self._state_event_every = 64       # serve_state cadence (steps)
         self._key_cache: Dict[int, np.ndarray] = {}
@@ -1308,7 +1308,7 @@ class InferenceEngine:
 
     def record_quant_logit_err(self, err: float) -> None:
         """Record an offline quantized-vs-fp-oracle max-logit-error
-        probe (tests/bench compute it against a
+        probe (tests compute it against a
         :func:`~deepspeed_tpu.runtime.quantized_params.dequantize_param_tree`
         oracle — the serving path itself never pays for one). The next
         decode telemetry write carries it as ``Serve/quant_logit_err``

@@ -107,9 +107,8 @@ class ReplicaWorker:
             "weight_ordinal": eng.weight_ordinal,
             "steady_state_recompiles": eng.steady_state_recompiles,
             "can_migrate": getattr(eng, "can_migrate", False),
-            # cumulative device dispatches (CompileTracker) — the
-            # fleet_trace_overhead bench's dispatch_delta pin reads
-            # this through the router proxy; a host int, never a sync
+            # cumulative device dispatches (CompileTracker), read
+            # through the router proxy; a host int, never a sync
             "dispatches": getattr(getattr(eng, "compile_tracker", None),
                                   "total_dispatches", None),
         }

@@ -49,8 +49,8 @@ stamps are host wall-clock (``time.perf_counter``), events are
 line-buffered file appends, and nothing imports jax — the compiled
 program set, the warmup dispatch count, and the zero-per-dispatch-sync
 contract are untouched with tracing on (pinned source-level by the
-jax-free test in tests/unit/test_inference.py and end-to-end by the
-``serve_trace_overhead`` bench row).
+jax-free test in tests/unit/test_inference.py and end-to-end by
+tests/unit/test_serve_trace.py).
 
 Chrome-trace request lanes: with a recorder attached (the engine wires
 ``profiling/spans.py``'s :class:`ChromeTraceRecorder` when

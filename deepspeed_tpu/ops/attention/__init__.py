@@ -1,7 +1,5 @@
 from deepspeed_tpu.ops.attention.flash import (attention_reference,
-                                               flash_attention,
-                                               get_attention_options,
-                                               set_attention_options)
+                                               flash_attention)
 from deepspeed_tpu.ops.attention.masked_flash import (BlockMask,
                                                       masked_flash_attention,
                                                       masked_flash_cost)
@@ -11,5 +9,4 @@ from deepspeed_tpu.ops.attention.ring import ring_attention
 
 __all__ = ["attention_reference", "flash_attention", "ring_attention",
            "paged_decode_attention", "paged_decode_supported",
-           "BlockMask", "masked_flash_attention", "masked_flash_cost",
-           "get_attention_options", "set_attention_options"]
+           "BlockMask", "masked_flash_attention", "masked_flash_cost"]

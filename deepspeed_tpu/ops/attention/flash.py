@@ -1,13 +1,13 @@
 """Pallas flash attention — the MXU-native core of the transformer stack.
 
-Since PR 11 :func:`flash_attention` is a DISPATCHER: the default path
-compiles the ONE mask-parameterized kernel in ``masked_flash.py`` with
-a dense/causal BlockMask (same math, same dropout hash, one code path
-with the sparse layouts — docs/attention.md). The per-path kernels in
-this module remain the numerics oracles behind
-``set_attention_options(kernel="flash")``, and this module still owns
-the shared machinery (dropout hash, streaming layout, block autotune
-table, reference oracle, once-logging).
+:func:`flash_attention` compiles the ONE mask-parameterized kernel in
+``masked_flash.py`` with a dense or causal BlockMask (one code path with
+the sparse layouts — docs/attention.md). The kernels in this module
+(``_flash_fwd`` / ``_flash_bwd``) serve what that kernel does not:
+``ring.py`` builds ring attention on them chunk by chunk (it needs
+``(o, lse)`` per chunk), and a causal call with ``sq != sk`` has no
+square-block mask. This module also owns the shared machinery (dropout
+hash, streaming layout, block table, reference oracle).
 
 TPU-native replacement for the reference's fused CUDA attention pipeline
 (csrc/transformer/ds_transformer_cuda.cpp Forward :153: QK^T strided GEMM →
@@ -41,9 +41,7 @@ Falls back to a jnp reference implementation off-TPU (same math incl. the
 same hash mask, used as the numerics oracle in tests).
 """
 
-import dataclasses
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -59,54 +57,10 @@ except ImportError:  # pragma: no cover
     pltpu = None
 
 NEG_INF = -1e30
-# dropout-hash finalizer rounds: 2 = lowbias32-quality (default), 1 =
-# single multiply-xorshift round (A/B knob BENCH_DROPOUT_HASH1=1 via
-# bench.py; same keep statistics, cheaper tile-wide VPU work)
-_HASH_FINAL_ROUNDS = 2
-
-
-@dataclasses.dataclass
-class AttentionOptions:
-    """Process-wide attention-kernel selection (replaces the old
-    ``_FORCE_REFERENCE`` / ``_WARNED_*`` mutable module globals, whose
-    state leaked across tests and configs).
-
-    kernel: which implementation :func:`flash_attention` compiles —
-      ``"masked"`` (default): the unified mask-parameterized kernel
-      (``masked_flash.py``) with a dense/causal BlockMask;
-      ``"flash"``: the legacy per-path kernels in this module (kept as
-      numerics oracles);
-      ``"reference"``: the XLA-fused O(S^2) ``attention_reference``
-      path with MXU bf16 operands (A/B knob — at short sequences XLA's
-      batched fused attention may beat a Pallas launch grid). Ignored
-      (loudly, once) above STREAM_THRESHOLD where O(S^2) is not
-      meaningful.
-    """
-    kernel: str = os.environ.get("DSTPU_ATTENTION_KERNEL", "masked")
-
-    def __post_init__(self):
-        assert self.kernel in ("masked", "flash", "reference"), self.kernel
-
-
-_OPTIONS = AttentionOptions()
-
-
-def get_attention_options() -> AttentionOptions:
-    return _OPTIONS
-
-
-def set_attention_options(**kw) -> AttentionOptions:
-    """Update kernel-selection knobs; returns the PREVIOUS options so
-    callers (tests, bench A/B) can restore them."""
-    global _OPTIONS
-    old = _OPTIONS
-    _OPTIONS = dataclasses.replace(_OPTIONS, **kw)
-    return old
-
 
 # once-per-(reason, shape) which-path logging lives in utils/logging
 # (shared infrastructure); re-exported here because every attention
-# fallback logs through it and tests/benches reach it via this module
+# fallback logs through it and tests reach it via this module
 from deepspeed_tpu.utils.logging import (_ONCE_KEYS, log_once,  # noqa
                                          reset_once_logging)
 
@@ -144,17 +98,7 @@ def dropout_keep_mask(seed, bh, q_idx, k_idx, seq_k, rate):
               ^ (jnp.uint32(bh) * jnp.uint32(0x9E3779B9))
               ^ seed.astype(jnp.uint32))
     x = row ^ k_idx.astype(jnp.uint32)
-    if _HASH_FINAL_ROUNDS == 1:
-        # cheaper tile-wide finalizer (half the multiplies): one
-        # multiply-xorshift round on top of an already-mixed row hash.
-        # Keep-rate statistics and fwd/bwd bit-consistency are unchanged
-        # (tests pin both); only the mask pattern differs. A/B knob —
-        # promote to default if the hardware ladder shows dropout-MFU
-        # gains without convergence drift.
-        x = (x ^ (x >> 16)) * jnp.uint32(0x7FEB352D)
-        x = x ^ (x >> 15)
-    else:
-        x = mix(x)
+    x = mix(x)
     keep_thresh = min(int(round((1.0 - rate) * 2.0**32)), 2**32 - 1)
     return x < jnp.uint32(keep_thresh)
 
@@ -175,27 +119,18 @@ def dropout_mask_reference(seed, b, h, sq, sk, rate):
 # --------------------------------------------------------------------- #
 def attention_reference(q, k, v, mask=None, causal=False,
                         sm_scale: Optional[float] = None,
-                        dropout_rate: float = 0.0, dropout_seed=None,
-                        mxu_bf16: bool = False):
+                        dropout_rate: float = 0.0, dropout_seed=None):
     """Plain jnp attention. q,k,v: (B, H, S, D); mask: additive, broadcastable
     to (B, H, Sq, Sk). With dropout_rate > 0 applies the same hash keep-mask
-    the Pallas kernels use (seed: scalar). GQA: k/v may carry H/G heads.
-    mxu_bf16: keep MXU operands in the input dtype with fp32 accumulation
-    (the Pallas kernels' precision) instead of the oracle's fp32 operands
-    — used when this path serves as a PERFORMANCE alternative
-    (kernel="reference"), not as the accuracy oracle."""
+    the Pallas kernels use (seed: scalar). GQA: k/v may carry H/G heads."""
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(q.shape[-1])
     if k.shape[1] != q.shape[1]:
         rep = q.shape[1] // k.shape[1]
         k = jnp.repeat(k, rep, axis=1)
         v = jnp.repeat(v, rep, axis=1)
-    if mxu_bf16:
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                       preferred_element_type=jnp.float32) * sm_scale
-    else:
-        s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                       k.astype(jnp.float32)) * sm_scale
+    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) * sm_scale
     if mask is not None:
         s = s + mask.astype(jnp.float32)
     if causal:
@@ -209,10 +144,6 @@ def attention_reference(q, k, v, mask=None, causal=False,
         keep = dropout_mask_reference(dropout_seed, b_, h_, sq_, sk_,
                                       dropout_rate)
         p = jnp.where(keep, p, 0.0) / (1.0 - dropout_rate)
-    if mxu_bf16:
-        return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
-                          preferred_element_type=jnp.float32
-                          ).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)
                       ).astype(q.dtype)
 
@@ -572,19 +503,18 @@ def _block_cap(seq, stream):
 
 
 # measured block-size table (VERDICT r2 #6: the reference ships a GemmTest
-# autotuner, csrc/includes/gemm_test.h:27). tools/autotune_blocks.py sweeps
-# (bq, bk) combinations per shape class on the real chip and writes
-# block_table.json next to this module; unknown shapes fall back to the
-# hand-measured heuristic below. Entries carry:
+# autotuner, csrc/includes/gemm_test.h:27): block_table.json next to this
+# module holds (bq, bk) per shape class as measured on a chip; unknown
+# shapes fall back to the hand-measured heuristic below. Entries carry:
 #   kind: "flash" (default) keyed (seq_q, seq_k, d, stream, gqa)
-#         "banded" keyed (seq, fine_block, band_w, causal)
+#         "masked" keyed (seq_q, seq_k, d, stream), one square tile ``b``
 #   device_kind: jax device_kind the entry was measured on. An entry with
 #         device_kind applies ONLY on that exact chip generation (a v5p
 #         must never consume v5e-tuned blocks); entries without it are a
 #         legacy global fallback, used when no exact-device entry matches.
 _BLOCK_ENTRIES = None
 _BLOCK_TABLE = None      # test hook: when set, overrides entry matching
-_FORCE_BLOCKS = None     # (bq, bk) override used by the autotune sweep
+_FORCE_BLOCKS = None     # test hook: (bq, bk) override of every lookup
 
 
 def _load_block_entries():
@@ -650,25 +580,6 @@ def _pick_blocks(seq_q, seq_k, d=None, gqa=1):
     cap = _block_cap(max(seq_q, seq_k), stream)
     return (_largest_divisor_block(seq_q, cap),
             _largest_divisor_block(seq_k, cap))
-
-
-def lookup_banded_blocks(seq, fine_block, band_w=None, causal=None):
-    """Measured walk-tile sizes for the banded sparse kernels
-    (ops/sparse_attention/banded.py), or None. band_w/causal narrow the
-    match when given; an entry without those fields matches any."""
-    def m(e):
-        if e.get("kind") != "banded" or e["seq"] != seq or \
-                e["fine_block"] != fine_block:
-            return False
-        if band_w is not None and e.get("band_w") is not None and \
-                e["band_w"] != band_w:
-            return False
-        if causal is not None and e.get("causal") is not None and \
-                bool(e["causal"]) != causal:
-            return False
-        return seq % e["bq"] == 0 and seq % e["bk"] == 0
-    e = _table_lookup(m)
-    return (e["bq"], e["bk"]) if e is not None else None
 
 
 def lookup_masked_blocks(seq_q, seq_k, d, stream) -> Optional[int]:
@@ -1059,21 +970,8 @@ def _local_flash_attention(q, k, v, mask, causal, sm_scale, dropout_rate,
     else:
         seed = jnp.zeros((1, 1), jnp.int32)
     sq, sk = q.shape[2], k.shape[2]
-    force_ref = _OPTIONS.kernel == "reference"
-    if force_ref and max(sq, sk) >= STREAM_THRESHOLD:
-        # the A/B knob must never silently re-route a long-context
-        # measurement onto the O(S^2) path (it would OOM or be
-        # mis-attributed as the flash baseline — ADVICE r3 #2): above
-        # the streaming threshold the knob is ignored, loudly
-        log_once(("ref-stream", sq, sk),
-                 f"flash_attention: kernel='reference' ignored at seq "
-                 f"({sq}, {sk}) >= {STREAM_THRESHOLD} — the O(S^2) "
-                 "reference path is not meaningful (or feasible) in the "
-                 "DMA-streaming regime.", warn=True)
-        force_ref = False
-    if force_reference or force_ref or sq % 16 != 0 or sk % 16 != 0:
-        if not force_reference and not force_ref \
-                and max(sq, sk) > 2048:
+    if force_reference or sq % 16 != 0 or sk % 16 != 0:
+        if not force_reference and max(sq, sk) > 2048:
             log_once(("irregular-fallback", sq, sk),
                      f"flash_attention: seq ({sq}, {sk}) not divisible "
                      "by 16 — falling back to the O(S^2)-memory dense "
@@ -1083,12 +981,7 @@ def _local_flash_attention(q, k, v, mask, causal, sm_scale, dropout_rate,
                                    sm_scale=sm_scale,
                                    dropout_rate=dropout_rate,
                                    dropout_seed=seed.reshape(())
-                                   if dropout_rate > 0.0 else None,
-                                   # perf knob only: an explicit
-                                   # force_reference caller gets the
-                                   # fp32 accuracy oracle
-                                   mxu_bf16=force_ref
-                                   and not force_reference)
+                                   if dropout_rate > 0.0 else None)
     if (max(sq, sk) >= STREAM_THRESHOLD
             and (sq % 128 != 0 or sk % 128 != 0)):
         # long irregular sequences: the resident path may fail to compile
@@ -1120,12 +1013,11 @@ def _local_flash_attention(q, k, v, mask, causal, sm_scale, dropout_rate,
         assert mask.ndim == 4 and mask.shape[1] == 1 and \
             mask.shape[2] == 1, \
             f"flash path expects (B,1,1,Sk) additive mask, got {mask.shape}"
-    if _OPTIONS.kernel == "masked" and (not causal or sq == sk):
-        # default path (PR 11): dense and causal are mask choices of the
-        # ONE unified kernel — same math, same dropout hash, one code
-        # path with the sparse layouts. (A causal cross-attention with
-        # sq != sk has no square-block mask; it stays on the legacy
-        # kernels below.)
+    if not causal or sq == sk:
+        # dense and causal are mask choices of the ONE unified kernel —
+        # one code path with the sparse layouts. (A causal
+        # cross-attention with sq != sk has no square-block mask; it
+        # runs this module's own kernels below.)
         return _masked_dense_attention(q, k, v, mask, seed, causal,
                                        float(sm_scale), interpret,
                                        dropout_rate)

@@ -1,31 +1,27 @@
 """ONE mask-parameterized Pallas flash-attention kernel (training side).
 
-The repo grew four separate XLA/Pallas training attention paths — dense
-flash (``flash.py``), banded (``sparse_attention/banded.py``), generic
-block-sparse (``sparse_attention/blocksparse.py`` v1 +
-``blocksparse_v2.py``) and ring — each re-implementing the same
-online-softmax core with a different way of deciding *which K/V tiles a
-query block touches*. This module collapses the mask-shaped ones into a
-single kernel parameterized by a static :class:`BlockMask`: dense,
-causal, banded (Longformer-class) and BigBird block-sparse are just mask
-choices.
+Dense, causal, banded (Longformer-class) and BigBird block-sparse
+attention differ only in *which K/V tiles a query block touches*. This
+module is the one online-softmax kernel for all of them, parameterized
+by a static :class:`BlockMask`: ``flash_attention`` compiles it with a
+dense or causal mask, ``block_sparse_attention`` with a SparsityConfig
+layout. (``flash.py`` keeps its own chunk kernels for ring attention
+and for a causal call with ``sq != sk``.)
 
 Design (the PR 8 paged-decode recipe applied to training):
 
 - **Scalar-prefetched CSR walk.** The mask compiles to a per-(head,
   query-block) column list delivered through
-  ``pltpu.PrefetchScalarGridSpec`` (SMEM), the walk ``blocksparse_v2.py``
-  proved: each program walks only its row's nonzero K/V tiles with an
-  inner ``fori_loop``, so FLOPs and HBM bytes scale with nonzero blocks,
-  not S².
+  ``pltpu.PrefetchScalarGridSpec`` (SMEM): each program walks only its
+  row's nonzero K/V tiles with an inner ``fori_loop``, so FLOPs and HBM
+  bytes scale with nonzero blocks, not S².
 - **Partial tiles mask in registers.** A mask item is FULL (every cell
   computed — the reference's block-level mask semantics) or PARTIAL: an
   elementwise predicate evaluated from iota arithmetic in registers —
   the causal diagonal (``q_idx >= k_idx``) and/or the banded fine
   structure (global prefix + sliding window at the layout's fine block
   granularity). That is what lets a 128-fine-block Longformer layout
-  *walk 512-wide MXU tiles* with zero mask bytes from HBM — the banded
-  kernel's efficiency with the generic walk's generality.
+  *walk 512-wide MXU tiles* with zero mask bytes from HBM.
 - **Stream vs resident.** Below ``flash.STREAM_THRESHOLD`` the per-head
   K/V arrays ride as VMEM-resident blocked refs sliced at
   ``cols[i] * block``; at/above it they stay in HBM pre-tiled TRANSPOSED
@@ -55,7 +51,7 @@ with ``parallel/pallas_shard.sharded_masked_flash`` to run under a mesh
 """
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -91,11 +87,62 @@ _FORCE_STREAM: Optional[bool] = None
 
 
 def _iter_cost_us(blk: int) -> float:
-    # same shape as blocksparse._iter_cost_us: a fixed per-iteration
-    # floor (loop + DMA re-arm) plus MXU work linear in tile width.
-    # Only ratios matter — it picks between walking many fine tiles and
-    # fewer coarse tiles whose masked lanes ride register predicates.
+    # per-inner-iteration cost: a fixed floor (loop + DMA re-arm) plus
+    # MXU work linear in tile width. Only ratios matter — it picks
+    # between walking many fine tiles and fewer coarse tiles whose masked
+    # lanes ride register predicates.
     return 2.0 + 22.0 * (blk / 512.0)
+
+
+class BandedParams(NamedTuple):
+    g_r: int      # global ROW prefix, in fine blocks (rows that see all)
+    g_c: int      # global COL prefix, in fine blocks (cols all rows see)
+    w: int        # band half-width, in fine blocks
+    causal: bool  # block-level lower-triangular clip
+
+
+def detect_banded(layout: np.ndarray) -> Optional[BandedParams]:
+    """Match a (H, nb, nb) 0/1 layout against the global-prefix + band
+    predicate. Returns params or None (per-head layouts, non-prefix
+    globals, random blocks, fully dense all decline)."""
+    L = np.asarray(layout).astype(bool)
+    if L.ndim != 3 or L.shape[1] != L.shape[2] or L.shape[1] == 0:
+        return None
+    l = L[0]
+    if not (L == l[None]).all():
+        return None
+    n = l.shape[0]
+    idx = np.arange(n)
+    rb, cb = idx[:, None], idx[None, :]
+    for causal in (False, True):
+        clip = (cb <= rb) if causal else np.ones((n, n), bool)
+        # global prefixes: leading rows/cols equal to their clip pattern
+        row_full = (l == clip).all(axis=1)
+        col_full = (l == clip).all(axis=0)
+        g_r = 0
+        while g_r < n and row_full[g_r]:
+            g_r += 1
+        g_c = 0
+        while g_c < n and col_full[g_c]:
+            g_c += 1
+        if g_r >= n:          # fully dense: nothing to coarsen
+            continue
+        # infer w from the last row (never a global row here): its
+        # non-global cols must be a contiguous run ending at the diagonal
+        last = np.nonzero(l[n - 1, g_c:])[0] + g_c
+        if len(last) == 0:
+            # pure-global layout (no band): the band predicate would need
+            # a w=-1 "empty band" special case — leave it to the fine
+            # walk (rare, and tiny at any realistic density)
+            continue
+        run = np.arange(int(last.min()), n)
+        if len(last) != len(run) or not (last == run).all():
+            continue
+        w = (n - 1) - int(last.min())
+        pred = ((rb < g_r) | (cb < g_c) | (np.abs(rb - cb) <= w)) & clip
+        if (pred == l).all():
+            return BandedParams(g_r, g_c, w, bool(causal))
+    return None
 
 
 class BlockMask:
@@ -133,7 +180,7 @@ class BlockMask:
         self.heads = Hm
         self.band = tuple(band) if band is not None else None
         # the layout's original block granularity (== block unless the
-        # walk was coarsened); reporting/bench only
+        # walk was coarsened); reporting only
         self.fine_block = int(fine_block or block)
         self._key = (self.block, self.seq_q, self.seq_k, self.band,
                      active.tobytes(), kinds.tobytes())
@@ -167,7 +214,7 @@ class BlockMask:
         Head-identical layouts collapse to one mask head (metadata
         shrinks by H and the head-sharded wrap becomes legal). When the
         realized layout matches the banded predicate
-        (``banded.detect_banded`` — BSLongformer-class), the walk is
+        (:func:`detect_banded` — BSLongformer-class), the walk is
         COARSENED to a larger MXU-friendly tile and the fine structure
         rides the in-register KIND_BAND predicate; tiles fully inside
         the band stay FULL. Non-banded layouts (BigBird random blocks,
@@ -184,8 +231,6 @@ class BlockMask:
 
         bp = None
         if H == 1:
-            from deepspeed_tpu.ops.sparse_attention.banded import \
-                detect_banded
             bp = detect_banded(layout)
         cb = cls._pick_walk_block(fine, fine_block, S, bp, walk_block)
         if cb is None:
@@ -325,16 +370,15 @@ class BlockMask:
 
 
 # --------------------------------------------------------------------- #
-# cost model (the masked_flash_flops_bytes bench row; mfu_cost_model
-# pattern — analytic accounting proportional to nonzero blocks)
+# cost model: analytic accounting proportional to nonzero blocks
 # --------------------------------------------------------------------- #
 def masked_flash_cost(mask: BlockMask, batch: int, heads: int,
                       head_dim: int, dtype_bytes: int = 2,
                       backward: bool = False):
     """Modeled MXU FLOPs and HBM bytes for one forward (optionally +
-    backward) pass — the ``masked_flash_flops_bytes`` bench row's
-    engine (mfu_cost_model pattern: analytic accounting cross-checked
-    structurally against the CSR metadata the kernel actually walks).
+    backward) pass: analytic accounting, cross-checked structurally
+    against the CSR metadata the kernel actually walks
+    (``test_masked_flash.py::TestCostModel``).
 
     The mask-proportional work is separated from the constant terms:
     ``flops`` (QK^T + PV dots per walked item; the dq/dkv recompute and

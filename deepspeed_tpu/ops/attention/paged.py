@@ -132,9 +132,8 @@ def decode_read_bytes(cache_positions: Sequence[int], page_size: int,
                       pages_per_seq: int, kv_heads: int, head_dim: int,
                       dtype_bytes: int = 2, scale_blocks: int = 0):
     """Modeled K+V bytes one decode step reads from the pool, paged
-    kernel vs gather stripe — the ``paged_decode_bytes`` bench row's
-    cost model (mfu_cost_model pattern: analytic accounting that the
-    compiled-HLO audit cross-checks structurally).
+    kernel vs gather stripe: analytic accounting that the compiled-HLO
+    audit cross-checks structurally (tests/unit/test_paged_attention.py).
 
     The kernel reads each row's live pages once per layer:
     ``live_pages * page_size * kv_heads * head_dim`` K plus the same V.

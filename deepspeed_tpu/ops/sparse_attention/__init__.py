@@ -1,6 +1,6 @@
 """Block-sparse attention subsystem (reference:
 deepspeed/ops/sparse_attention/__init__.py) — sparsity layout configs,
-the fused Pallas block-sparse kernel, and attention modules."""
+the fused block-sparse attention entry, and attention modules."""
 
 from deepspeed_tpu.ops.sparse_attention.sparsity_config import (  # noqa
     SparsityConfig, DenseSparsityConfig, FixedSparsityConfig,

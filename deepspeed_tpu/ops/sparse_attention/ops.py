@@ -12,7 +12,7 @@ then block-row, then block-col; np.nonzero order).
 Implementation is layout-driven jnp gather/einsum/scatter: the MXU
 executes the per-block GEMMs batched over the nonzero list and XLA
 fuses the rest. (The fused attention path — SparseSelfAttention — uses
-the splash Pallas kernels in blocksparse*.py instead; these classes
+the Pallas kernel in ops/attention/masked_flash.py instead; these classes
 exist for composability parity, differentiable by construction.)
 
 Softmax normalizes each query row over the row's nonzero blocks only

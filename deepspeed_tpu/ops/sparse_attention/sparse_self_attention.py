@@ -6,13 +6,11 @@ Parity targets (reference):
 - SparseAttentionUtils           deepspeed/ops/sparse_attention/sparse_attention_utils.py:13
 
 Where the reference caches three Triton ops per sequence length
-(sparse_self_attention.py:44 get_ops), we cache one fused differentiable
-Pallas function per (layout, seq-len) via blocksparse._sparse_attention_fn;
-layout construction itself is cached here per seq len. Since PR 11 that
-dispatch resolves layouts to the ONE mask-parameterized flash kernel
+(sparse_self_attention.py:44 get_ops), ``block_sparse_attention`` caches
+one ``BlockMask`` per layout for the ONE mask-parameterized flash kernel
 (``ops/attention/masked_flash.py`` — the same kernel dense training
-attention compiles); the legacy banded/v2/v1 kernels stay behind
-``blocksparse.USE_MASKED_FLASH = False`` as numerics oracles.
+attention compiles); layout construction itself is cached here per seq
+len.
 
 Modules follow the repo's functional convention: configs are plain
 objects, parameters are pytrees created by ``init_*_params``, forward

@@ -7,8 +7,8 @@ VariableSparsityConfig:243, BigBirdSparsityConfig:421,
 BSLongformerSparsityConfig:544). Each config produces a block-level layout
 tensor of shape ``(num_heads, seq_len // block, seq_len // block)`` with 1
 marking an attended (query-block, key-block) pair. The layout is *static*
-numpy data consumed at trace time by the Pallas block-sparse attention
-kernel (blocksparse.py), which turns it into per-row look-up tables.
+numpy data consumed at trace time by ``block_sparse_attention``
+(blocksparse.py), which turns it into the masked kernel's ``BlockMask``.
 
 Deviations from the reference, on purpose:
 - layouts are numpy ``int32`` (not torch int64) — they are host-side trace

@@ -27,7 +27,7 @@ __all__ = [
 
 # Peak dense bf16 FLOP/s per chip. Sources: TPU v4 275 TFLOP/s,
 # v5e 197 TFLOP/s, v5p 459 TFLOP/s (cloud TPU system docs; v5e matches
-# the number bench.py's hardware MFU row already uses). Matching is by
+# benchmarks/peaks.json). Matching is by
 # substring on ``device.device_kind`` lowercased, most specific first.
 PEAK_FLOPS_REGISTRY = (
     ("tpu v5p", 459e12),

@@ -114,9 +114,8 @@ class CompileTracker:
         self.counts: Dict[str, int] = {}
         self.compile_ms: Dict[str, float] = {}
         # every CALL of a wrapped function, compiled or cached — the
-        # host-dispatch accounting the async-pipeline bench row and
-        # dispatch-count tests pin (one batch_step dispatch per
-        # train_batch on the fused path)
+        # host-dispatch accounting the dispatch-count tests pin (one
+        # batch_step dispatch per train_batch on the fused path)
         self.dispatch_counts: Dict[str, int] = {}
         self.events: List[CompileEvent] = []
         self._warned_fns = set()

@@ -401,7 +401,7 @@ ASYNC_SYNC_LOSS_EVERY_STEP_DEFAULT = False
 #############################################
 # Persistent XLA compilation cache (TPU-native: the first jit of a
 # large model costs a minute or more; caching the compiled executable
-# on disk makes re-runs, bench children, and resumed jobs start hot.
+# on disk makes re-runs, benchmark runs, and resumed jobs start hot.
 # No reference analog: CUDA kernels there are AOT-built at install time
 # via DS_BUILD_* env flags, setup.py:47-68 — this knob is the JIT-world
 # equivalent.)

@@ -21,8 +21,8 @@ Megatron TP layout as bf16-resident serving. Scales have shape
 
 HBM accounting: a (h, d) bf16 weight costs ``2*h*d`` bytes resident;
 int8-resident costs ``h*d + 4*h*nb`` — ~0.51x at the default block of
-256, i.e. the ~2x weight-HBM lever the bench row ``quant_serving_bytes``
-pins.
+256, i.e. the ~2x weight-HBM lever (pinned in
+tests/unit/test_inference.py).
 """
 
 from typing import Any, Tuple
@@ -162,7 +162,7 @@ def _leaf_bytes(x) -> int:
 
 def param_tree_bytes(params) -> int:
     """Resident HBM bytes of a param tree (quantized leaves count int8
-    payload + fp32 scales). The bench cost model's weight-HBM lever."""
+    payload + fp32 scales): the weight-HBM lever."""
     return sum(_leaf_bytes(leaf) for leaf in jax.tree_util.tree_leaves(
         params, is_leaf=_is_qp))
 

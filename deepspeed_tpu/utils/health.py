@@ -30,13 +30,12 @@ pieces, all host-side:
   reason from the pinned :data:`HEALTH_REASONS` vocabulary plus a
   cumulative ``Health/alerts`` scalar (monitor.TAG_HEALTH_ALERTS).
 - :class:`HealthPlane` — the engine-facing facade (train, pipe,
-  inference, fleet, bench all wire it); construction always succeeds
+  inference, fleet all wire it); construction always succeeds
   and every method no-ops when disabled, so callers wire it
   unconditionally like the profiling Observer.
 
 Deliberately stdlib-only (no jax import): the watchdog must be able to
-dump stacks while the process is wedged *inside* a device call, and
-``bench.py``'s ladder children arm it before any backend import.
+dump stacks while the process is wedged *inside* a device call.
 Config: ``observability.health:{}`` (runtime/config.py validates it;
 docs/config.md documents it). ``tools/obs_report.py --health`` renders
 the postmortem.
@@ -71,7 +70,6 @@ HEALTH_PHASES = (
     "chunk_prefill",      # chunked-prefill chunk dispatch (ISSUE 19)
     "checkpoint_commit",  # save snapshot/commit stages
     "fleet_step",         # FleetRouter scheduling round
-    "bench_metric",       # bench.py ladder child metric body
     "rpc_call",           # router-side blocking RPC wait on a replica
 )
 
@@ -86,7 +84,7 @@ HEALTH_REASONS = (
 
 # Distinguished "watchdog tripped and on_stall=exit" code: 85 is the
 # elastic resumable-preemption code, 143 an uncaught SIGTERM — a
-# supervisor (or bench parent) can tell a diagnosed stall from both.
+# supervisor can tell a diagnosed stall from both.
 STALL_EXIT_CODE = 87
 
 

@@ -1,10 +1,9 @@
-"""Compiled-HLO collective accounting (shared by tests and bench).
+"""Compiled-HLO collective accounting for the tests.
 
 The only multi-chip perf evidence a single-host rig can produce:
 compile the partitioned program on a virtual CPU mesh, walk the HLO,
 and pin communication volume to theory. Used by
-``tests/unit/test_hlo_collectives.py`` / ``test_hlo_quantized_comm.py``
-and by ``bench.py``'s hardware-free ``comm_wire_bytes_per_step`` row.
+``tests/unit/test_hlo_collectives.py`` / ``test_hlo_quantized_comm.py``.
 
 Counting rules:
 
@@ -323,8 +322,7 @@ def cone_reaches_compute(hlo_text, comp_name, root_pred):
 
 def overlap_structure(hlo_text, payload_pred=lambda line: "s8[" in line):
     """Structural overlap report of a compiled fused-step program, for
-    the hardware-free ``comm_overlap_structure`` bench row and the
-    tier-1 overlap audits.
+    the tier-1 overlap audits.
 
     Looks at every while-loop body that contains both compute
     (dot-general/matmul) and collectives whose line matches

@@ -1,7 +1,7 @@
 """Where this process keeps JAX's persistent compilation cache.
 
 One helper, :func:`enable_compile_cache`, used by the training engine,
-the inference engine, ``chip_smoke.py``, ``bench.py`` and the tools, so
+the inference engine, ``chip_smoke.py`` and ``benchmarks/run.py``, so
 that every entry point of a checkout shares one cache directory:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set in the environment: JAX reads it
@@ -27,7 +27,7 @@ DEFAULT_COMPILE_CACHE_DIR = os.path.join(
 def enable_compile_cache(cache_dir=None, min_compile_secs=1.0) -> str:
     """Make JAX's persistent compilation cache active for this process
     and return the directory it lives in, so re-runs (a second smoke,
-    bench children, resumed jobs, repeated CLI launches) load compiled
+    benchmark runs, resumed jobs, repeated CLI launches) load compiled
     executables from disk instead of compiling again.
 
     Precedence: the ``JAX_COMPILATION_CACHE_DIR`` environment variable

@@ -206,6 +206,42 @@ class TestSteadyState:
         eng.close()
 
 
+    def test_a_long_prompt_costs_a_decode_step_one_chunk(self):
+        """The inter-token bound as pure dispatch ordering: a long
+        prompt landing while short requests decode adds, to any one
+        engine step, at most ONE chunk dispatch, and every decode
+        dispatch of that step runs before it — so the worst gap between
+        two tokens is one decode plus one chunk, whatever the prompt's
+        length."""
+        from deepspeed_tpu.inference import InferenceEngine, Request
+        cfg, params = tiny_gpt2()
+        eng = InferenceEngine(cfg, params, CHUNKED_INF,
+                              dtype=jnp.float32)
+        eng.warmup()
+        assert eng._dispatch_trace is not None
+
+        def submit(p):
+            eng.submit(Request(prompt=p, max_new_tokens=6,
+                               temperature=0.0, seed=0))
+        for p in ([5, 6, 7], [8, 9]):
+            submit(p)
+        for _ in range(2):           # the shorts are decoding...
+            eng.step()
+        submit(LONG + [11, 12, 13, 14])   # ...when 24 tokens land
+        while not eng.scheduler.idle():
+            eng.step()
+        by_step = {}
+        for step, kind in eng._dispatch_trace.rows():
+            by_step.setdefault(step, []).append(kind)
+        chunk_steps = [k for k in by_step.values() if "chunk" in k]
+        assert len(chunk_steps) >= 3            # 24 tokens, 8 a chunk
+        assert any("decode" in k for k in chunk_steps)
+        for kinds in chunk_steps:
+            assert kinds.count("chunk") == 1, kinds
+            assert "decode" not in kinds[kinds.index("chunk"):], kinds
+        eng.close()
+
+
 class TestChunkTrail:
     def test_chunk_rows_and_ttft_decomposition(self, tmp_path):
         """One serve_prefill_chunk row per chunk (ceil(prompt/chunk)),

@@ -452,6 +452,18 @@ class TestSpecEngine:
         assert spec["proposed"] > 0
         assert 0 < spec["accepted"] <= spec["proposed"]
 
+    def test_verified_tokens_save_decode_dispatches(self, runs):
+        """What speculation is for, as a dispatch count: the same tokens
+        in fewer decode-phase device round-trips, so more kept tokens
+        per verify-or-decode dispatch than without it."""
+        def phase(name):
+            progs = runs[name]["state"]["programs"]
+            return sum(progs.get(k, {}).get("dispatches", 0)
+                       for k in ("decode", "verify"))
+        assert 0 < phase("spec") < phase("base")
+        assert runs["spec"]["total_tokens"] / phase("spec") > \
+            runs["base"]["total_tokens"] / phase("base")
+
     def test_goodput_counts_only_kept_tokens(self, runs):
         # rejected drafts must not inflate token accounting: the
         # scheduler's counter equals the tokens the requests got

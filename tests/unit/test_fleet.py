@@ -308,6 +308,53 @@ class TestFleetObservability:
         assert obs_report.main([fleet_runs["events_dir"],
                                 "--json"]) == 0
 
+    def test_fleet_tracing_adds_no_dispatch(self, tmp_path):
+        """Fleet tracing on both sides of the router (trace-id stamping
+        and ``fleet_dispatch`` rows in the router's log, the serve trail
+        in each replica's own events.jsonl) is host-side Python: the
+        replicas take exactly as many device dispatches, recompile
+        nothing and answer with the same tokens as an untraced fleet.
+        (The process fleet's ``dispatches`` state field reports this
+        same CompileTracker count.)"""
+        from deepspeed_tpu.inference import FleetRouter, InferenceEngine
+        from deepspeed_tpu.utils.monitor import _JsonlWriter
+        cfg, params = tiny_gpt2()
+
+        def serve(traced):
+            engines = []
+            for i in range(2):
+                ic = dict(INF)
+                if traced:
+                    ic["events_dir"] = str(tmp_path / f"r{i}")
+                eng = InferenceEngine(
+                    cfg, params, ic, dtype=jnp.float32,
+                    observability_config={"serve": {"enabled": traced}})
+                eng.warmup()
+                engines.append(eng)
+            writer = _JsonlWriter(str(tmp_path / "router")) \
+                if traced else None
+            router = FleetRouter(engines, {"replicas": 2}, writer=writer)
+            d0 = [e.compile_tracker.total_dispatches for e in engines]
+            uids = _submit_all(router)
+            by_uid = {f.uid: f.tokens for f in router.run()}
+            stats = ([e.compile_tracker.total_dispatches - d
+                      for e, d in zip(engines, d0)],
+                     [e.steady_state_recompiles for e in engines])
+            router.close()
+            if writer is not None:
+                writer.close()
+            return [by_uid[u] for u in uids], stats
+
+        outs_off, stats_off = serve(False)
+        outs_on, stats_on = serve(True)
+        assert stats_on == stats_off and stats_on[1] == [0, 0]
+        assert outs_on == outs_off
+        rows = [json.loads(l) for l in
+                open(tmp_path / "router" / "events.jsonl") if l.strip()]
+        assert sum(r.get("event") == "fleet_dispatch" for r in rows) \
+            == len(WORKLOAD)
+        assert os.path.getsize(tmp_path / "r0" / "events.jsonl") > 0
+
     def test_serve_ready_preflight(self, fleet_runs, capsys):
         """tools/verify_checkpoint.py --serve-ready: the fleet swap
         preflight — the tag must verify AND carry model_states."""

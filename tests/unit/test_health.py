@@ -233,12 +233,12 @@ def test_stall_exit_code_is_distinguishable():
 
 def test_heartbeat_phase_vocabulary_pinned(tmp_path):
     """The phase names ARE the stall-postmortem contract: renames break
-    every consumer (obs_report, bench salvage, docs), so the set is
+    every consumer (obs_report, docs), so the set is
     pinned and unknown phases raise even on an ENABLED plane."""
     assert HEALTH_PHASES == (
         "train_batch", "prefill", "decode", "handoff_claim",
         "chunk_prefill", "checkpoint_commit", "fleet_step",
-        "bench_metric", "rpc_call")
+        "rpc_call")
     hp = HealthPlane({"enabled": True, "stall_timeout_s": 60.0},
                      events_dir=str(tmp_path))
     try:
@@ -538,6 +538,47 @@ def test_health_plane_zero_perturbation(tmp_path):
     events = _events(tmp_path / "on" / "events.jsonl")
     assert [r for r in events if r.get("event") == "health"] == []
     assert [r for r in events if r.get("event") == "stall_detected"] == []
+
+
+def test_health_plane_adds_no_serving_dispatch(tmp_path):
+    """The serving side of the same contract: ring tap + armed watchdog
+    + detectors on an InferenceEngine change neither the warmup program
+    set nor the number of dispatches a workload takes, nor a token."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.inference import InferenceEngine
+    from deepspeed_tpu.models.gpt2 import GPT2Config, init_gpt2_params
+    cfg = GPT2Config(vocab_size=61, max_position_embeddings=32,
+                     hidden_size=32, num_layers=2, num_heads=4,
+                     embd_dropout=0.0, attn_dropout=0.0,
+                     resid_dropout=0.0)
+    params = init_gpt2_params(cfg, jax.random.PRNGKey(3))
+    prompts = [[1, 2, 3], [4, 5], [6, 7, 8, 9, 10], [11], [12, 13]]
+
+    def run(on):
+        eng = InferenceEngine(
+            cfg, params,
+            {"max_batch_size": 3, "prompt_buckets": [4, 8],
+             "batch_buckets": [1, 2], "max_seq_len": 32,
+             "max_new_tokens": 4,
+             "events_dir": str(tmp_path / ("on" if on else "off"))},
+            dtype=jnp.float32,
+            observability_config={"health": {
+                "enabled": on, "stall_timeout_s": 120.0,
+                "on_stall": "warn"}})
+        warm = eng.warmup()
+        outs = eng.generate(prompts, max_new_tokens=4, temperature=0.0)
+        stats = (warm, eng.compile_tracker.total_dispatches,
+                 eng.steady_state_recompiles)
+        alerts = eng.health.alerts_total
+        eng.close()
+        return outs, stats, alerts
+
+    outs_off, stats_off, _ = run(False)
+    outs_on, stats_on, alerts_on = run(True)
+    assert stats_on == stats_off and stats_on[2] == 0
+    assert outs_on == outs_off
+    assert alerts_on == 0
 
 
 # ================================================================== #

@@ -43,8 +43,7 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
-# shared HLO collective accounting (also feeds bench.py's hardware-free
-# comm_wire_bytes_per_step row and test_hlo_quantized_comm.py)
+# shared HLO collective accounting (also feeds test_hlo_quantized_comm.py)
 from deepspeed_tpu.utils.hlo_audit import (
     collect_collectives, wire_elements,
     conditional_branch_comps as _conditional_branch_comps,

@@ -743,6 +743,50 @@ class TestPagedServing:
         assert al.pages_in_use == 0 and al.free_pages == 11
         assert paged.scheduler.peak_tokens_in_flight > 0
 
+    @pytest.mark.parametrize("versus", ["dense", "int8_pool"])
+    def test_tokens_in_flight_per_cache_byte(self, versus):
+        """What the page pool buys, as scheduler counts at an EQUAL
+        cache budget: against the dense slot x max_len cache the pool
+        holds at least twice the live tokens per byte on a mixed-length
+        workload (the dense geometry charges every slot max_len up
+        front); against a float pool of the same page geometry the int8
+        pool packs the same peak concurrency into fewer bytes. Zero
+        steady-state recompiles under the churn either way."""
+        from deepspeed_tpu.inference import (InferenceEngine,
+                                             kv_cache_bytes,
+                                             paged_kv_bytes)
+        cfg, params = tiny_gpt2()
+        ps, max_len, dense_slots = 4, 32, 3
+        # equal budget: dense (slots + 1) rows x max_len == the pool
+        num_pages = (dense_slots + 1) * (max_len // ps)
+        rng = np.random.RandomState(0)
+        prompts = [rng.randint(1, 61, (n,)).tolist()
+                   for n in (5, 8, 3, 7, 2, 6, 4, 8, 3, 5, 7, 2)]
+        wide = dict(TINY_INF, max_batch_size=12, batch_buckets=[1, 4, 12])
+
+        def serve(icfg):
+            eng = InferenceEngine(cfg, params, icfg, dtype=jnp.float32)
+            eng.warmup()
+            outs = eng.generate(prompts, max_new_tokens=4,
+                                temperature=0.0)
+            assert eng.steady_state_recompiles == 0
+            nbytes = paged_kv_bytes(eng.paged_spec) if eng.paged \
+                else kv_cache_bytes(eng.cache_spec)
+            return outs, eng.scheduler.peak_tokens_in_flight, nbytes
+
+        pool = {"page_size": ps, "num_pages": num_pages}
+        outs, peak, nbytes = serve(dict(wide, paged_kv=pool))
+        if versus == "dense":
+            ref_outs, ref_peak, ref_bytes = serve(
+                dict(TINY_INF, paged_kv={"enabled": False}))
+            assert ref_outs == outs
+            assert nbytes <= ref_bytes
+            assert peak / nbytes >= 2.0 * ref_peak / ref_bytes
+        else:
+            _, q_peak, q_bytes = serve(dict(
+                wide, paged_kv=dict(pool, kv_dtype="int8")))
+            assert q_peak == peak and q_bytes < nbytes
+
     def test_paged_sampling_parity_with_dense(self):
         """Temperature sampling keys are position-based: the paged path
         must reproduce the dense stream exactly (same fold_in schedule
@@ -1230,6 +1274,42 @@ class TestQuantizedServing:
         st = spec.debug_state()
         assert st["quantization"]["weights_resident"] == "int8"
         assert st["quantization"]["kv_dtype"] == "int8"
+
+    def test_both_byte_levers_at_a_serving_width(self):
+        """Pure accounting at head_dim 128, against bf16 serving at the
+        same geometry: the int8-resident weight tree (block 256, 1-D
+        leaves left dense) and the int8 page pool with its per-row
+        scales each take under 1/1.8 of the bf16 bytes, and a decode
+        step's modeled K/V read shrinks by the pool's ratio."""
+        from deepspeed_tpu.inference.kv_cache import (paged_kv_bytes,
+                                                      paged_spec_for)
+        from deepspeed_tpu.models.gpt2 import (GPT2Config,
+                                               init_gpt2_params)
+        from deepspeed_tpu.ops.attention.paged import decode_read_bytes
+        from deepspeed_tpu.runtime.quantized_params import (
+            quantize_param_tree, quantized_tree_bytes)
+        cfg = GPT2Config(vocab_size=256, max_position_embeddings=512,
+                         hidden_size=512, num_layers=2, num_heads=4)
+        params = jax.tree_util.tree_map(
+            lambda x: x.astype(jnp.bfloat16),
+            init_gpt2_params(cfg, jax.random.PRNGKey(0)))
+        resident, dense = quantized_tree_bytes(
+            quantize_param_tree(params, 256))
+        assert dense / resident >= 1.8
+
+        ps = 16
+        bf16 = paged_spec_for(cfg, 144, ps, 256, dtype=jnp.bfloat16)
+        int8 = paged_spec_for(cfg, 144, ps, 256, dtype=jnp.int8,
+                              kv_quant_block=0)
+        assert paged_kv_bytes(bf16) / paged_kv_bytes(int8) >= 1.8
+        positions = [n + 8 for n in (5, 9, 14, 3, 16, 7, 12, 4)]
+        bf16_step, _ = decode_read_bytes(
+            positions, ps, bf16.pages_per_seq, bf16.kv_heads,
+            bf16.head_dim, dtype_bytes=2)
+        int8_step, _ = decode_read_bytes(
+            positions, ps, int8.pages_per_seq, int8.kv_heads,
+            int8.head_dim, dtype_bytes=1, scale_blocks=int8.scale_blocks)
+        assert bf16_step / int8_step >= 1.8
 
     def test_quant_config_normalization_and_validation(self):
         from deepspeed_tpu.runtime.config import (DeepSpeedConfigError,

@@ -847,10 +847,9 @@ class TestDecodeAttnTelemetry:
 
 class TestCompiledProgramAudit:
     def test_pallas_decode_program_free_of_stripe_gathers(self):
-        """ISSUE 8 acceptance (tier-1 half of the paged_decode_bytes
-        bench row): the compiled pallas decode program contains no
-        gather anywhere near the per-layer stripe size; the gather
-        program materializes it."""
+        """ISSUE 8 acceptance: the compiled pallas decode program
+        contains no gather anywhere near the per-layer stripe size; the
+        gather program materializes it."""
         from deepspeed_tpu.inference import InferenceEngine
         from deepspeed_tpu.utils.hlo_audit import max_gather_elems
         cfg, params = tiny_gpt2()

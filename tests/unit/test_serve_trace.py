@@ -16,8 +16,7 @@ Tier-1 pins:
 - ``engine.debug_state()`` live introspection (pool, prefix cache,
   slots, queue-by-bucket, per-program dispatches);
 - tracing is free at the dispatch level: warmup program set, dispatch
-  counts, and steady-state recompiles are IDENTICAL with tracing on
-  (the ``serve_trace_overhead`` bench row's tier-1 shadow);
+  counts, and steady-state recompiles are IDENTICAL with tracing on;
 - obs_report ``--serve`` CLI + the versioned ``--json`` schema.
 """
 
@@ -685,12 +684,6 @@ class TestTracingDispatchInvariants:
         assert disp_on == disp_off
         assert rc_on == rc_off == 0
         assert outs_on == outs_off
-
-    def test_bench_row_registered(self):
-        import bench
-        assert "serve_trace_overhead" in bench.METRICS
-        assert "serve_trace_overhead" in bench.HW_FREE
-        assert callable(bench.bench_serve_trace_overhead)
 
 
 # --------------------------------------------------------------------- #

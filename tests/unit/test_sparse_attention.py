@@ -197,19 +197,45 @@ def test_kernel_key_padding_mask_add():
                                atol=2e-5, rtol=2e-5)
 
 
-def test_kernel_attn_mask_mul():
-    B, H, S, D = 1, 2, 64, 16
-    cfg = BigBirdSparsityConfig(num_heads=H, block=16)
+@pytest.mark.parametrize("mode", ["add", "mul"])
+@pytest.mark.parametrize("block", [32, 128])
+def test_user_attn_mask_forward_and_gradient(block, mode):
+    """A user ``attn_mask`` has no kernel channel: the call runs the
+    differentiable dense reference, at every block size alike. Checked
+    against a dense computation written here, so that the reference is
+    not compared with itself."""
+    B, H, D = 1, 2, 16
+    S = 8 * block
+    cfg = BigBirdSparsityConfig(num_heads=H, block=block)
     layout = cfg.make_layout(S)
     q, k, v = _rand_qkv(B, H, S, D, seed=2)
-    am = np.tril(np.ones((S, S), np.float32))       # causal keep-mask
-    out = block_sparse_attention(q, k, v, layout,
-                                 attn_mask=jnp.asarray(am),
-                                 attn_mask_mode="mul")
-    ref = block_sparse_attention_reference(
-        q, k, v, layout, attn_mask=jnp.asarray(am), attn_mask_mode="mul")
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+    sm_scale = D ** -0.5
+    if mode == "mul":
+        am = np.tril(np.ones((S, S), np.float32))   # causal keep-mask
+        am_add = np.where(am == 0, -1e30, 0.0).astype(np.float32)
+    else:
+        am = np.random.RandomState(6).randn(S, S).astype(np.float32)
+        am_add = am
+    dense_mask = jnp.asarray(layout_additive_mask(layout, block))[None] \
+        + jnp.asarray(am_add)[None, None]
+
+    def attn(q, k, v):
+        return block_sparse_attention(q, k, v, layout, sm_scale=sm_scale,
+                                      attn_mask=jnp.asarray(am),
+                                      attn_mask_mode=mode)
+
+    def dense(q, k, v):
+        return _dense_guarded_attention(q, k, v, dense_mask, sm_scale)
+
+    np.testing.assert_allclose(np.asarray(attn(q, k, v)),
+                               np.asarray(dense(q, k, v)),
                                atol=2e-5, rtol=2e-5)
+    gk = jax.grad(lambda *a: jnp.sum(attn(*a) ** 2), (0, 1, 2))(q, k, v)
+    gd = jax.grad(lambda *a: jnp.sum(dense(*a) ** 2), (0, 1, 2))(q, k, v)
+    for a_, b_, name in zip(gk, gd, "qkv"):
+        np.testing.assert_allclose(np.asarray(a_), np.asarray(b_),
+                                   atol=5e-5, rtol=5e-4,
+                                   err_msg=f"d{name}")
 
 
 @pytest.mark.slow
@@ -263,46 +289,24 @@ def test_kernel_gradients_with_masks():
                                    rtol=5e-4, err_msg=f"d{name}")
 
 
-@pytest.mark.slow
-def test_masked_path_v2_matches_v1():
-    """VERDICT r2 #3: the blocked attn-mask variant now runs on the
-    row-run (splash v2) kernels — outputs and grads must match the v1
-    per-triple kernels bit-for-bit-ish on the same masked layout."""
-    from deepspeed_tpu.ops.sparse_attention import blocksparse as bs
-
-    B, H, S, D = 1, 2, 64, 16
-    cfg = BSLongformerSparsityConfig(num_heads=H, block=16)
-    layout = cfg.make_layout(S)
-    q, k, v = _rand_qkv(B, H, S, D, seed=7)
-    am = (np.random.RandomState(3).rand(S, S) > 0.2).astype(np.float32)
-
-    def run(use_v2):
-        old = bs.USE_SPLASH_V2
-        bs.USE_SPLASH_V2 = use_v2
-        bs._FN_CACHE.clear()
-        try:
-            def loss(q, k, v):
-                out = block_sparse_attention(
-                    q, k, v, layout, attn_mask=jnp.asarray(am),
-                    attn_mask_mode="mul")
-                return jnp.sum(out ** 2)
-            o = block_sparse_attention(q, k, v, layout,
-                                       attn_mask=jnp.asarray(am),
-                                       attn_mask_mode="mul")
-            g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-            return o, g
-        finally:
-            bs.USE_SPLASH_V2 = old
-            bs._FN_CACHE.clear()
-
-    o2, g2 = run(True)
-    o1, g1 = run(False)
-    np.testing.assert_allclose(np.asarray(o2), np.asarray(o1),
-                               atol=1e-5, rtol=1e-5)
-    for a, b, name in zip(g2, g1, "qkv"):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=5e-5, rtol=5e-4,
-                                   err_msg=f"d{name}")
+def test_rows_without_an_active_block_are_zero():
+    """A query-block row whose layout names no key block (and no
+    key-padding mask in the call, so the kernel gets no mask operand at
+    all) writes zeros, forward, and takes no gradient — the oracle's
+    convention for a row with nothing to attend to."""
+    B, H, S, D = 1, 2, 128, 16
+    layout = FixedSparsityConfig(num_heads=H, block=32,
+                                 num_local_blocks=2).make_layout(S)
+    layout[:, 2, :] = 0                      # rows 64..95 see nothing
+    q, k, v = _rand_qkv(B, H, S, D, seed=8)
+    out = block_sparse_attention(q, k, v, layout)
+    ref = block_sparse_attention_reference(q, k, v, layout)
+    assert float(jnp.abs(out[:, :, 64:96]).max()) == 0.0
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    dq = jax.grad(lambda q: jnp.sum(
+        block_sparse_attention(q, k, v, layout) ** 2))(q)
+    assert float(jnp.abs(dq[:, :, 64:96]).max()) == 0.0
 
 
 def test_kernel_bf16():
@@ -333,6 +337,22 @@ def test_sparse_self_attention_module():
                                rtol=2e-5)
     # layout cache hit
     assert attn.get_layout(S) is attn.get_layout(S)
+
+
+def test_sparse_self_attention_forwards_a_user_mask():
+    """``SparseSelfAttention`` only forwards ``attn_mask`` (no model or
+    engine path of the repo passes one): the module's mode reaches the
+    call, which runs the dense reference."""
+    B, H, S, D = 1, 2, 64, 16
+    cfg = BSLongformerSparsityConfig(num_heads=H, block=16)
+    q, k, v = _rand_qkv(B, H, S, D, seed=9)
+    am = jnp.asarray(np.tril(np.ones((S, S), np.float32)))
+    out = SparseSelfAttention(cfg, attn_mask_mode="mul")(q, k, v,
+                                                         attn_mask=am)
+    ref = block_sparse_attention_reference(
+        q, k, v, cfg.make_layout(S), sm_scale=D ** -0.5, attn_mask=am,
+        attn_mask_mode="mul")
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
 def test_bert_sparse_self_attention():
@@ -569,166 +589,6 @@ class TestComposableSparseOps:
             np.testing.assert_allclose(np.asarray(a_), np.asarray(b_),
                                        atol=5e-4, rtol=1e-3,
                                        err_msg=f"d{name}")
-
-
-# --------------------------------------------------------------------- #
-# coarse walk (layout coarsening through the streamed-mask channel)
-# --------------------------------------------------------------------- #
-def _run_coarse_case(S, fine_block, coarse, with_am, with_kpm, seed=11):
-    """Run block_sparse_attention with _FORCE_COARSE_BLOCK=coarse (0 =
-    off) and return (o, (dq, dk, dv)). Pins the LEGACY dispatch:
-    _FORCE_COARSE_BLOCK only exists on the v2 coarse walk, which the
-    unified masked kernel (the PR 11 default) would otherwise
-    short-circuit — these tests guard the oracle path the legacy bench
-    row still measures."""
-    from deepspeed_tpu.ops.sparse_attention import blocksparse as bs
-    B, H, D = 1, 2, 16
-    cfg = BSLongformerSparsityConfig(num_heads=H, block=fine_block)
-    layout = cfg.make_layout(S)
-    q, k, v = _rand_qkv(B, H, S, D, seed=seed)
-    kw = {}
-    if with_am:
-        kw["attn_mask"] = jnp.asarray(
-            (np.random.RandomState(5).rand(S, S) > 0.15).astype(np.float32))
-        kw["attn_mask_mode"] = "mul"
-    if with_kpm:
-        kpm = np.zeros((B, S), np.float32)
-        kpm[:, -fine_block:] = -1e9
-        kw["key_padding_mask"] = jnp.asarray(kpm)
-        kw["key_padding_mask_mode"] = "add"
-
-    old = bs._FORCE_COARSE_BLOCK
-    old_masked = bs.USE_MASKED_FLASH
-    bs._FORCE_COARSE_BLOCK = coarse
-    bs.USE_MASKED_FLASH = False
-    bs._FN_CACHE.clear()
-    try:
-        def loss(q, k, v):
-            return jnp.sum(block_sparse_attention(q, k, v, layout, **kw)
-                           .astype(jnp.float32) ** 2)
-        o = block_sparse_attention(q, k, v, layout, **kw)
-        g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        return o, g
-    finally:
-        bs._FORCE_COARSE_BLOCK = old
-        bs.USE_MASKED_FLASH = old_masked
-        bs._FN_CACHE.clear()
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("fine_block,coarse", [(128, 256), (64, 256),
-                                               (128, 512)])
-@pytest.mark.parametrize("with_am", [False, True])
-def test_coarse_walk_matches_fine(fine_block, coarse, with_am):
-    """The coarsened walk (fine structure as streamed NEG_INF mask
-    tiles) must reproduce the fine walk exactly: outputs and grads,
-    with and without a user attention mask, including fine blocks < 128
-    that previously had no streaming path at all."""
-    S = 512
-    o_c, g_c = _run_coarse_case(S, fine_block, coarse, with_am, True)
-    o_f, g_f = _run_coarse_case(S, fine_block, 0, with_am, True)
-    np.testing.assert_allclose(np.asarray(o_c), np.asarray(o_f),
-                               atol=1e-5, rtol=1e-5)
-    for a, b, name in zip(g_c, g_f, "qkv"):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=5e-5, rtol=5e-4,
-                                   err_msg=f"d{name}")
-
-
-@pytest.mark.slow
-def test_coarse_walk_matches_dense_oracle():
-    """Coarse walk vs the dense-masked oracle (not just the fine
-    kernel), so an error shared by both kernel paths would still show."""
-    from deepspeed_tpu.ops.sparse_attention import blocksparse as bs
-    B, H, S, D = 1, 2, 512, 16
-    cfg = BSLongformerSparsityConfig(num_heads=H, block=128)
-    layout = cfg.make_layout(S)
-    q, k, v = _rand_qkv(B, H, S, D, seed=3)
-    old = bs._FORCE_COARSE_BLOCK
-    old_masked = bs.USE_MASKED_FLASH
-    bs._FORCE_COARSE_BLOCK = 256
-    bs.USE_MASKED_FLASH = False          # the legacy coarse walk under test
-    bs._FN_CACHE.clear()
-    try:
-        o = block_sparse_attention(q, k, v, layout)
-    finally:
-        bs._FORCE_COARSE_BLOCK = old
-        bs.USE_MASKED_FLASH = old_masked
-        bs._FN_CACHE.clear()
-    ref = block_sparse_attention_reference(q, k, v, layout)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
-
-
-def test_coarse_index_structure():
-    """build_coarse_index: content dedup collapses a banded layout to a
-    handful of unique tiles; count_only matches the full build; per_coord
-    keys split identical patterns at different coordinates."""
-    from deepspeed_tpu.ops.sparse_attention.blocksparse_v2 import (
-        build_coarse_index)
-    H, fine_block, S = 2, 128, 4096
-    cfg = BSLongformerSparsityConfig(num_heads=H, block=fine_block)
-    layout = cfg.make_layout(S)
-
-    coarse, tiles, csr, csc, qrows, kcols = build_coarse_index(
-        layout, fine_block, 512, per_coord=False)
-    nnz_c, n_unique = build_coarse_index(layout, fine_block, 512,
-                                         per_coord=False, count_only=True)
-    assert coarse.shape == (H, 8, 8)
-    assert len(csr) == len(csc) == nnz_c == int(coarse.sum())
-    assert tiles.shape[0] == n_unique
-    # banded layout: content dedup far below one-tile-per-pair
-    assert n_unique < nnz_c / 2
-    # every fine nonzero is representable: expanding each unique tile's
-    # valid (non-NEG_INF) positions reproduces exactly the fine layout
-    f = 512 // fine_block
-    item = 0
-    for h in range(H):
-        for R in range(coarse.shape[1]):
-            for C in np.nonzero(coarse[h, R])[0]:
-                bits = tiles[csr[item]][::fine_block, ::fine_block] == 0.0
-                np.testing.assert_array_equal(
-                    bits, layout[h, R * f:(R + 1) * f,
-                                 C * f:(C + 1) * f].astype(bool))
-                item += 1
-    assert item == nnz_c
-    _, _, csr_pc, _, _, _ = build_coarse_index(layout, fine_block, 512,
-                                               per_coord=True)
-    n_unique_pc = len(np.unique(csr_pc))
-    assert n_unique_pc >= n_unique
-
-
-def test_pick_coarse_block_model():
-    """_pick_coarse_block: picks a coarse tile for a banded long-seq
-    layout, honors the force flag and the tile-memory budget, and
-    declines when the sequence does not divide."""
-    from deepspeed_tpu.ops.sparse_attention import blocksparse as bs
-    cfg = BSLongformerSparsityConfig(num_heads=2, block=128)
-    layout = cfg.make_layout(4096)
-    picked = bs._pick_coarse_block(layout, 128, has_am=False)
-    assert picked in (256, 512)
-
-    old = bs._FORCE_COARSE_BLOCK
-    try:
-        bs._FORCE_COARSE_BLOCK = 0
-        assert bs._pick_coarse_block(layout, 128, False) is None
-        bs._FORCE_COARSE_BLOCK = 512
-        assert bs._pick_coarse_block(layout, 128, False) == 512
-    finally:
-        bs._FORCE_COARSE_BLOCK = old
-
-    # S=192 divides by neither 256 nor 512 -> no candidate
-    small = cfg.make_layout(384)[:, :3, :3]   # (H, 3, 3) blocks, S=384
-    assert bs._pick_coarse_block(small, 128, False) is None
-
-    # budget: per-coord uniques at a huge budgetless layout would pass,
-    # but a zero budget must refuse
-    old_budget = bs._COARSE_TILE_BUDGET
-    try:
-        bs._COARSE_TILE_BUDGET = 0
-        assert bs._pick_coarse_block(layout, 128, False) is None
-    finally:
-        bs._COARSE_TILE_BUDGET = old_budget
 
 
 # --------------------------------------------------------------------- #

@@ -539,9 +539,9 @@ def test_shipped_block_table_resolves(monkeypatch):
 
 
 def test_block_table_lookup_and_fallback():
-    """Autotuned block table (tools/autotune_blocks.py): exact shape hits
-    override the heuristic; unknown shapes keep it; the sweep override
-    wins over both."""
+    """Measured block table (block_table.json): exact shape hits
+    override the heuristic; unknown shapes keep it; the forced-block
+    hook wins over both."""
     from deepspeed_tpu.ops.attention import flash as F
     old_table, old_force = F._BLOCK_TABLE, F._FORCE_BLOCKS
     try:
