@@ -196,7 +196,8 @@ def check_paged_decode(seed, head_dim=128, page_size=16):
     """The paged-decode kernel against the gather reader, rows at mixed
     cache positions over shuffled pages."""
     batch, heads, num_pages, pages_per_seq = 8, 16, 160, 16
-    ok, why = paged_decode_supported(page_size, head_dim, jnp.bfloat16)
+    ok, why = paged_decode_supported(page_size, head_dim, jnp.bfloat16,
+                                     kv_heads=heads)
     assert ok, why
     rs = np.random.RandomState(seed)
     key = jax.random.PRNGKey(seed)
@@ -228,6 +229,10 @@ def phase_kernels(seed):
          lambda: check_flash_dropout(KERNEL_SHAPES[0], seed)),
         ("paged decode bf16 head_dim=128 vs gather reader",
          lambda: check_paged_decode(seed)),
+        # GPT-2's head width: the kernel streams whole pool rows, so
+        # it is the row (16 x 64 lanes) that has to be whole lane tiles
+        ("paged decode bf16 head_dim=64 vs gather reader",
+         lambda: check_paged_decode(seed, head_dim=64)),
     ]
     for name, check in checks:
         t0 = time.perf_counter()

@@ -111,6 +111,8 @@ from deepspeed_tpu.models.gpt2 import (GPT2Config, gpt2_forward,
 from deepspeed_tpu.models.llama import (LlamaConfig, init_llama_params,
                                         llama_forward, llama_param_specs)
 from deepspeed_tpu.ops.attention.flash import NEG_INF
+from deepspeed_tpu.ops.attention.paged import (live_pages,
+                                               paged_decode_supported)
 from deepspeed_tpu.parallel.mesh import axis_size, build_mesh
 from deepspeed_tpu.profiling.recompile import CompileTracker
 from deepspeed_tpu.profiling.spans import (ChromeTraceRecorder, scope,
@@ -675,16 +677,19 @@ class InferenceEngine:
         with tokens in flight too (one compiled decode program per
         width; default = a single full-width program, preserving the
         PR 5/7 warmup program count)."""
-        from deepspeed_tpu.ops.attention.paged import \
-            paged_decode_supported
         requested = pk["attn_kernel"]
         if requested != "pallas":
             self._decode_attn_path = "gather"
             self._decode_attn_reason = "configured"
         else:
+            # the kernel streams whole pool rows: under a mesh, one
+            # shard's kv heads of them
+            shards = (axis_size(self.mesh, "model")
+                      if self.mesh is not None else 1)
             ok, why = paged_decode_supported(
                 self.paged_spec.page_size, self.paged_spec.head_dim,
-                dtype=self.paged_spec.dtype)
+                dtype=self.paged_spec.dtype,
+                kv_heads=self.paged_spec.kv_heads // shards)
             if ok and self.mesh is not None:
                 # a pallas_call can't be auto-partitioned by GSPMD —
                 # the kernel runs shard_mapped over the mesh's model
@@ -694,17 +699,16 @@ class InferenceEngine:
                 # always legal here: __init__'s cache-sharding check
                 # already rejected any model axis that does not divide
                 # num_heads AND kv_heads (whole GQA groups per shard).
-                from deepspeed_tpu.parallel.mesh import axis_size
                 from deepspeed_tpu.parallel.pallas_shard import \
                     head_shard_supported
-                n = axis_size(self.mesh, "model")
                 assert head_shard_supported(
-                    n, self.model_config.num_heads,
-                    self.paged_spec.kv_heads), (n, "unreachable: init "
-                                                "validates divisibility")
+                    shards, self.model_config.num_heads,
+                    self.paged_spec.kv_heads), (
+                        shards, "unreachable: init validates divisibility")
                 self._decode_attn_path = "pallas"
                 self._decode_attn_reason = (
-                    f"shard_map over mesh axis 'model' ({n}-way); {why}")
+                    f"shard_map over mesh axis 'model' ({shards}-way); "
+                    f"{why}")
             elif ok:
                 self._decode_attn_path = "pallas"
                 self._decode_attn_reason = why
@@ -1781,6 +1785,13 @@ class InferenceEngine:
                         self.paged_spec.pages_per_seq),
                     self._decode_page_buckets)
                 counters["table_pages"] = width
+                # what the Pallas kernel walks: each row's live pages,
+                # an inactive row's null page once; the gather reader
+                # walks none (it reads the table's whole width)
+                counters["read_pages"] = (
+                    sum(live_pages(p, self.paged_spec.page_size)
+                        for p in poss) + self._rows - len(sids)
+                    if self._decode_attn_path == "pallas" else 0)
             with self._span("serve/decode", **counters):
                 with self._span("serve/decode/build"):
                     toks_a, poss_a, temps_a, keys_a = self._decode_arrays(

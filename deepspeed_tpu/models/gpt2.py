@@ -615,12 +615,16 @@ def gather_paged_kv(pool, layer: int, block_table, kv_heads: int):
 
     NB: this materializes each row's full logical stripe (every table
     entry it is handed) each call — per-step decode reads are bounded
-    by the TABLE WIDTH, not the tokens actually live. It serves as the
-    paged paths' numerics oracle and as the fallback where the fused
-    Pallas decode kernel (``ops/attention/paged.py`` — reads only live
-    pages, O(live tokens)) can't run; the serving engine additionally
-    clamps the decode table width to the batch's live page bucket so
-    even this fallback stops paying full ``max_len`` bandwidth
+    by the TABLE WIDTH, not the tokens actually live. It is the reader
+    of every query of more than one row (prefill, chunked prefill,
+    spec-verify), the paged paths' numerics oracle, and decode's
+    fallback where the fused Pallas decode kernel
+    (``ops/attention/paged.py`` — streams whole rows of the live pages
+    only, at every head width whose pool row is whole 128-lane tiles)
+    can't run: an int8 pool, a row like GPT-2 XL's 1,600 lanes. The
+    serving engine additionally clamps the decode table width to the
+    batch's live page bucket so even this fallback stops paying full
+    ``max_len`` bandwidth
     (``inference.paged_kv.decode_page_buckets``)."""
     B, P = block_table.shape
     ps, width = pool.shape[2:]

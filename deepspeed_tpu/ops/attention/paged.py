@@ -15,30 +15,41 @@ tokens), not O(max_len).
 
 Design:
 
-- **Grid** ``(batch, kv_heads)``. Each program owns one sequence's
-  page walk for one kv head; the ``q_heads / kv_heads`` query rows of
-  that head's GQA group ride in the program's q block — K/V pages are
-  read once per group, never replicated per q head (llama serves with
-  no head expansion).
-- **Block tables in SMEM.** The per-slot block tables and cache
-  positions enter through ``PrefetchScalarGridSpec`` scalar prefetch,
-  so page ids are available to index DMAs before the kernel body runs.
-  The page walk is bounded by each row's OWN live page count — the
-  kernel never touches reserved-but-unwritten pages.
-- **Double-buffered DMA.** K and V page tiles stream
-  ``pool[layer, page_id, :, kv_head * hd:(kv_head + 1) * hd]`` (one
-  token a pool row, heads major within it) → VMEM through 2-deep
-  async-copy buffers
-  (``flash.py``'s streaming idiom): page ``i+1``'s copy is issued
-  before page ``i`` is consumed — 2 tiles of VMEM per stream at any
-  pool size.
-- **Online softmax in fp32.** Running (m, l, acc) across the page walk,
-  MXU dots take the pool dtype (bf16 in production) with fp32
-  accumulation — the flash kernels' precision. Positions past the
-  row's cache position AND anything mapped to the reserved null page 0
-  are masked *inside* the kernel, so the all-null tables of inactive
-  slots produce finite garbage (discarded by the host) rather than
-  NaN.
+- **Grid** ``(batch,)``. Each program owns one sequence's page walk for
+  ALL its heads: the DMA tile is a page of whole pool rows,
+  ``pool[layer, page]`` = ``(page_size, kv_heads * head_dim)`` — the
+  array's full last two dimensions, so no head is cut out of the lanes
+  and the kernel is one algorithm at every head width (ISSUE 30; it cut
+  head slices before, which Mosaic takes only at multiples of 128, and
+  every GPT-2 width gathered).
+- **Heads separated by the contraction.** The row's queries are spread
+  block-diagonally, head ``(kh, g)`` on row ``g * kv_rows + kh`` with
+  its values in kv head ``kh``'s lanes of the pool row: one dot
+  ``Qbd . Ktile^T`` is every head's scores, ``P . Vtile`` every head's
+  context in its own lanes of its row. The MXU does ``kv_heads`` times
+  the useful FLOPs; the program is bound by bytes. K/V pages are read
+  once for all heads and GQA falls out of the placement (llama serves
+  with no head expansion).
+- **Layer, block tables, positions in SMEM.** They enter through
+  ``PrefetchScalarGridSpec`` scalar prefetch, so page ids are available
+  to index DMAs before the kernel body runs, and every layer's call is
+  the same kernel. The page walk is bounded by each row's OWN live page
+  count — the kernel never touches reserved-but-unwritten pages.
+- **A block of pages a loop turn, double-buffered.** ``128 //
+  page_size`` pages (8 of 16 tokens: one MXU tile of keys) land in one
+  ``(block tokens, row width)`` VMEM buffer; block ``i+1``'s page
+  copies are issued before block ``i`` is consumed (``flash.py``'s
+  streaming idiom) — 2 blocks of VMEM per stream at any pool size. Of
+  a row's last block only the live pages are copied; the tail is
+  masked.
+- **Online softmax in fp32.** Running (m, l, acc) across the walk, keys
+  and values to the MXU in the pool dtype (bf16 in production) with
+  fp32 accumulation, and the probabilities NOT rounded to that dtype
+  (:func:`_probs_dot`) — no lower precision than the gather path it
+  replaces. Positions past the row's cache position AND anything
+  mapped to the reserved null page 0 are masked *inside* the kernel,
+  so the all-null tables of inactive slots produce finite zeros
+  (discarded by the host) rather than NaN.
 
 The same kernel runs under ``interpret=True`` on CPU — scalar
 prefetch, HBM refs, dynamic-index async copies and semaphores are all
@@ -46,18 +57,19 @@ interpretable — which is what makes exact greedy parity against the
 gather path tier-1-testable without hardware
 (tests/unit/test_paged_attention.py).
 
-Compiled-TPU legality: Mosaic requires the DMA tile's lane (minor) dim
-to be 128-aligned; the streamed tile is ``(page_size, head_dim)``, cut
-out of the pool's ``kv_heads * head_dim`` lanes at a multiple of
-``head_dim``, so the compiled path needs ``head_dim % 128 == 0`` (plus
-a sublane-tile page size). The int8 pool's per-page scale tile is
-``(page_size, scale_blocks)`` fp32 — its lane dim is 1..4, so the int8
-arity is refused by the compiler at every page geometry and runs in
-interpret mode only. :func:`paged_decode_supported` is the one
-predicate the serving engine consults; unsupported geometries fall back
-to the gather path with a one-line log (see docs/inference.md's
-fallback matrix) — the gather path remains the numerics oracle either
-way.
+Compiled-TPU legality: Mosaic needs the DMA tile's lane (minor) dim a
+whole number of 128-lane tiles and its sublane dim of 8-row tiles. The
+tile is ``(page_size, kv_heads * head_dim)``, so the compiled path
+needs ``kv_heads * head_dim % 128 == 0`` — 16 x 64 (GPT-2 345M) and
+8 x 128 (llama GQA) are, 25 x 64 = 1,600 (GPT-2 XL) is not — and
+``page_size % 8 == 0``. The int8 pool's scale rows are
+``(page_size, kv_heads * scale_blocks)`` fp32 — 16 lanes for GPT-2 —
+so the int8 arity is refused by the compiler and runs in interpret
+mode only. :func:`paged_decode_supported` is the one predicate the
+serving engine consults; unsupported geometries fall back to the
+gather path with a one-line log (see docs/inference.md's fallback
+matrix) — the gather path remains the numerics oracle either way, and
+the reader of everything with more than one query row.
 """
 
 import functools
@@ -90,22 +102,26 @@ def live_pages(cache_position, page_size: int):
 
 def paged_decode_supported(page_size: int, head_dim: int,
                            dtype=jnp.bfloat16,
-                           backend: Optional[str] = None
-                           ) -> Tuple[bool, str]:
+                           backend: Optional[str] = None,
+                           kv_heads: int = 1) -> Tuple[bool, str]:
     """Can the Pallas decode kernel run for this cache geometry on this
     backend? Returns ``(ok, reason)`` — the one predicate the serving
     engine consults before compiling the paged decode program.
 
     Off-TPU the kernel runs in interpret mode (pure jax semantics, no
-    layout constraints) — always supported. On TPU the DMA tile is
-    ``(page_size, head_dim)``: Mosaic needs the lane dim 128-aligned
-    (``head_dim % 128``) and the sublane dim a full tile
-    (8 fp32 / 16 bf16 rows), so small pages or narrow heads fall back
-    to the gather path. ``dtype`` is the POOL dtype: an int8 pool also
-    streams a ``(page_size, scale_blocks)`` fp32 scale tile per page,
-    whose lane dim Mosaic refuses whatever the page geometry, so int8
-    always gathers on TPU (tests/unit/test_tpu_compile.py holds this
-    predicate to the compiler).
+    layout constraints) — always supported. On TPU the DMA tile is one
+    page of whole pool rows, ``(page_size, kv_heads * head_dim)``, and
+    the head width itself is nothing Mosaic sees: it needs the ROW a
+    whole number of 128-lane tiles (16 x 64 and 8 x 128 are; GPT-2 XL's
+    25 x 64 = 1,600 is not: its HBM rows are padded to 1,664 and a
+    1,600-lane slice of them is refused) and the page a whole number of
+    8-row sublane tiles. ``kv_heads`` is the kv heads of the pool the
+    kernel is handed: under a serving mesh, one shard's. ``dtype`` is
+    the POOL dtype: an int8 pool also streams its
+    ``(page_size, kv_heads * scale_blocks)`` fp32 scale rows, 16 lanes
+    for GPT-2, so int8 gathers on TPU
+    (tests/unit/test_tpu_compile.py holds this predicate to the
+    compiler).
     """
     if pltpu is None:
         return False, "pallas tpu backend unavailable"
@@ -113,18 +129,18 @@ def paged_decode_supported(page_size: int, head_dim: int,
         backend = jax.default_backend()
     if backend != "tpu":
         return True, "interpret mode (CPU oracle path)"
-    if head_dim % 128 != 0:
-        return False, (f"head_dim {head_dim} not a multiple of 128 "
-                       "(DMA lane dim)")
-    itemsize = jnp.dtype(dtype).itemsize
-    if itemsize == 1:
-        return False, ("int8 pool: the (page_size, scale_blocks) fp32 "
-                       "scale tile's lane dim is not 128-aligned")
-    sublane = 16 if itemsize == 2 else 8
-    if page_size % sublane != 0:
+    if jnp.dtype(dtype).itemsize == 1:
+        return False, ("int8 pool: the (page_size, kv_heads * "
+                       "scale_blocks) fp32 scale rows are not a whole "
+                       "number of 128-lane tiles")
+    width = kv_heads * head_dim
+    if width % 128 != 0:
+        return False, (f"pool row of {kv_heads} x {head_dim} = {width} "
+                       "lanes is not a whole number of 128-lane tiles "
+                       "(the page DMA's lane dim)")
+    if page_size % 8 != 0:
         return False, (f"page_size {page_size} not a multiple of the "
-                       f"{sublane}-row sublane tile for "
-                       f"{jnp.dtype(dtype).name}")
+                       "8-row sublane tile")
     return True, "compiled pallas kernel"
 
 
@@ -229,205 +245,258 @@ def paged_decode_reference(q, kpool, vpool, block_tables, cache_position,
 # --------------------------------------------------------------------- #
 # the kernel
 # --------------------------------------------------------------------- #
-def _decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                   sm_scale, page_size, quantized, layer):
-    """One (sequence, kv head) program: walk the row's live pages from
-    the pool via double-buffered DMA, online-softmax the GQA group's
-    queries against each streamed page tile.
+# Tokens a loop turn: a block is this many tokens' worth of pages (at
+# least one page). 128, one MXU tile of keys, is the fast setting: the
+# decode call of ``gpt2-345m.serve-saturated`` spends 7.7 ms in the
+# kernel there and 18.0 ms at 16 (PERF.md §6, PR 30) — but the cell then
+# serves 3,900 tokens/s, and its backlog of 4,032 requests runs dry
+# above 3,180 (the run aborts, by design of the harness). Only a
+# ``benchmark`` PR may enlarge the backlog, so until one has (ROADMAP
+# A2) a turn is ONE page of 16 tokens, which the driver can measure
+# (3,016 tokens/s). Every block size is tested and compiled.
+_BLOCK_TOKENS = 16
+
+
+def _round_up(n: int, to: int) -> int:
+    return -(-n // to) * to
+
+
+def _probs_dot(p, vt):
+    """``p (rows, tokens) float32`` times a value tile
+    ``(tokens, width)``, accumulated in float32 with the probabilities
+    NOT rounded to the tile's dtype. A bf16 tile goes to the MXU as it
+    is: ``p`` is split into three bf16 terms that sum to it exactly
+    (8 + 8 + 8 mantissa bits), stacked on the rows of ONE dot, so the
+    tile is loaded once and every product is exact in float32. A
+    float32 tile (a float32 pool, a dequantized int8 one) contracts
+    float32 operands at ``Precision.HIGHEST``."""
+    if vt.dtype != jnp.bfloat16:
+        return jax.lax.dot_general(
+            p, vt.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+    rows = p.shape[0]
+    terms, rest = [], p
+    for _ in range(3):
+        t = rest.astype(jnp.bfloat16)
+        terms.append(t)
+        rest = rest - t.astype(jnp.float32)
+    out = jax.lax.dot_general(
+        jnp.concatenate(terms, axis=0), vt, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return out[:rows] + out[rows:2 * rows] + out[2 * rows:]
+
+
+def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref, v_ref,
+                   *rest, sm_scale, page_size, head_dim, quantized):
+    """One sequence's program: walk the row's live pages from the pool,
+    ``block_pages`` whole pool rows' pages a loop turn through
+    double-buffered DMA, and online-softmax EVERY head's query against
+    the streamed block with one pair of dots.
+
+    The heads are separated by the contraction, not by a lane slice.
+    ``q_ref`` is ``(1, groups, width)``: row ``g`` holds member ``g``
+    of every kv head's query group in that kv head's lanes of the pool
+    row. The program spreads it block-diagonally — query head
+    ``(g, kh)`` on row ``g * kv_rows + kh``, its ``head_dim`` values
+    where they were, zeros elsewhere — so ``q . Ktile^T`` is every
+    head's scores and ``p . Vtile`` holds head ``(g, kh)``'s context in
+    the same lanes of its row (the rest of the row is other heads'
+    values under this head's probabilities, dropped at the end).
+    ``kv_rows`` is the kv heads rounded up to whole sublane tiles (zero
+    rows: nothing of them is kept).
 
     ``quantized`` adds two operand refs (the per-token-row fp32 scale
     pools) and two scale scratch buffers: each walked page streams its
-    int8 K/V tile AND its (page_size, nb) scale tile, and the dequant
-    happens right after the DMA'd tile lands in VMEM — the int8 bytes
-    are what crossed HBM, the math below (scores, online softmax,
-    accumulation) stays fp32 exactly like the dense-pool path."""
+    int8 K/V rows AND its scale rows, and the dequant happens right
+    after the block lands in VMEM — the int8 bytes are what crossed
+    HBM, the math below (scores, online softmax, accumulation) stays
+    fp32 exactly like the dense-pool path."""
     if quantized:
         (ks_ref, vs_ref, o_ref, kbuf, vbuf, ksbuf, vsbuf,
          ksem, vsem, kssem, vssem) = rest
+        streams = ((k_ref, kbuf, ksem), (v_ref, vbuf, vsem),
+                   (ks_ref, ksbuf, kssem), (vs_ref, vsbuf, vssem))
     else:
         o_ref, kbuf, vbuf, ksem, vsem = rest
+        streams = ((k_ref, kbuf, ksem), (v_ref, vbuf, vsem))
     b = pl.program_id(0)
-    kh = pl.program_id(1)
+    layer = layer_ref[0]
     pos = pos_ref[b]
+    table_pages = tables_ref.shape[1]
+    tokens, width = kbuf.shape[1:]
+    block_pages = tokens // page_size
     # positions 0..pos are attended (this call's token was written
     # BEFORE attention — write_paged_kv_cache runs first), spanning
     # exactly pos // page_size + 1 pages: the O(live tokens) bound
     num_pg = pos // page_size + 1
-    q = q_ref[0, 0]                                   # (G, hd)
-    if quantized:
-        q = q.astype(jnp.float32)   # dequantized tiles are fp32
+    num_blk = (num_pg + block_pages - 1) // block_pages
+    groups = q_ref.shape[1]
+    kv_rows = _round_up(width // head_dim, 8)
+    if groups * kv_rows % 16:
+        kv_rows = _round_up(kv_rows, 16)
+    # row kh of a group keeps kv head kh's lanes
+    lane = jax.lax.broadcasted_iota(jnp.int32, (kv_rows, width), 1)
+    first = jax.lax.broadcasted_iota(
+        jnp.int32, (kv_rows, width), 0) * head_dim
+    own = (lane >= first) & (lane < first + head_dim)
+    q = jnp.concatenate(
+        [jnp.where(own, q_ref[0, g:g + 1, :].astype(jnp.float32), 0.0)
+         for g in range(groups)], axis=0).astype(q_ref.dtype)
 
-    def _tile(ref, page, width):
-        # head kh's (page_size, width) tile of one page: a pool row is a
-        # token with its heads side by side, so the head is a lane range
-        lanes = pl.ds(pl.multiple_of(kh * width, width), width)
-        return ref.at[layer, page, :, lanes]
+    def _page_id(blk, j):
+        # clamped: the last block's unwalked tail may lie past the table
+        return tables_ref[b, jnp.minimum(blk * block_pages + j,
+                                         table_pages - 1)]
 
-    def _copies(i):
-        page = tables_ref[b, i]
-        slot = jax.lax.rem(i, 2)
-        hd = kbuf.shape[-1]
-        copies = [
-            pltpu.make_async_copy(_tile(k_ref, page, hd), kbuf.at[slot],
-                                  ksem.at[slot]),
-            pltpu.make_async_copy(_tile(v_ref, page, hd), vbuf.at[slot],
-                                  vsem.at[slot])]
-        if quantized:
-            nb = ksbuf.shape[-1]
-            copies += [
-                pltpu.make_async_copy(_tile(ks_ref, page, nb),
-                                      ksbuf.at[slot], kssem.at[slot]),
-                pltpu.make_async_copy(_tile(vs_ref, page, nb),
-                                      vsbuf.at[slot], vssem.at[slot])]
-        return copies
+    def _for_live_pages(blk, fn):
+        """``fn(copy)`` for every copy of block ``blk``'s LIVE pages:
+        the pages past the row's count are never touched."""
+        slot = jax.lax.rem(blk, 2)
+        live = num_pg - blk * block_pages
+        for j in range(block_pages):
+            def _page(j=j):
+                page = _page_id(blk, j)
+                rows = pl.ds(j * page_size, page_size)
+                for ref, buf, sem in streams:
+                    fn(pltpu.make_async_copy(ref.at[layer, page],
+                                             buf.at[slot, rows],
+                                             sem.at[slot]))
+            if j == 0:
+                _page()                  # a walked block has a live page
+            else:
+                pl.when(j < live)(_page)
 
-    def _start(i):
-        for c in _copies(i):
-            c.start()
+    def _start(blk):
+        _for_live_pages(blk, lambda c: c.start())
 
-    _start(0)                                         # num_pg >= 1 always
+    if block_pages > 1:
+        # what a DMA never filled (the last block's tail; scratch keeps
+        # the previous program's rows) is masked out of the scores
+        # below, but a NaN value times a zero probability is NaN: the
+        # value side starts from zeros in every program
+        for buf in (vbuf, vsbuf) if quantized else (vbuf,):
+            buf[...] = jnp.zeros(buf.shape, buf.dtype)
+    _start(0)                                         # num_blk >= 1 always
 
-    def body(i, carry):
+    def body(blk, carry):
         m, l, acc = carry
 
-        @pl.when(i + 1 < num_pg)
+        @pl.when(blk + 1 < num_blk)
         def _prefetch_next():
-            _start(i + 1)
-        page = tables_ref[b, i]
-        slot = jax.lax.rem(i, 2)
-        for c in _copies(i):
-            c.wait()
-        kt = kbuf[slot]                               # (page_size, hd)
+            _start(blk + 1)
+        _for_live_pages(blk, lambda c: c.wait())
+        slot = jax.lax.rem(blk, 2)
+        kt = kbuf[slot]                               # (tokens, width)
         vt = vbuf[slot]
         if quantized:
-            hd = kt.shape[-1]
             nb = ksbuf.shape[-1]
-            blk = hd // nb
-            # per-token-row blockwise dequant of the landed tile:
-            # (ps, hd) int8 * (ps, nb) scales broadcast per block
-            kt = (kt.astype(jnp.float32).reshape(page_size, nb, blk)
-                  * ksbuf[slot][:, :, None]).reshape(page_size, hd)
-            vt = (vt.astype(jnp.float32).reshape(page_size, nb, blk)
-                  * vsbuf[slot][:, :, None]).reshape(page_size, hd)
+            lanes = width // nb
+            # per-token-row blockwise dequant of the landed block:
+            # (tokens, width) int8 * (tokens, nb) scales, a scale a
+            # run of ``lanes`` lanes
+            kt = (kt.astype(jnp.float32).reshape(tokens, nb, lanes)
+                  * ksbuf[slot][:, :, None]).reshape(tokens, width)
+            vt = (vt.astype(jnp.float32).reshape(tokens, nb, lanes)
+                  * vsbuf[slot][:, :, None]).reshape(tokens, width)
         s = jax.lax.dot_general(
             q, kt, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale   # (G, ps)
+            preferred_element_type=jnp.float32) * sm_scale  # (rows, tokens)
         # in-kernel masking: positions past the row's cache position,
         # and anything the table maps to the reserved null page 0 (the
         # all-null tables of inactive slots) — finite garbage out,
         # never NaN
-        offs = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        valid = (offs <= pos) & (page != 0)
+        local = jax.lax.broadcasted_iota(jnp.int32, (1, tokens), 1)
+        page_of = jnp.zeros((1, tokens), jnp.int32)
+        for j in range(block_pages):
+            page_of = jnp.where(local >= j * page_size, _page_id(blk, j),
+                                page_of)
+        valid = (blk * tokens + local <= pos) & (page_of != 0)
         s = jnp.where(valid, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        # a fully-masked tile leaves m_new at NEG_INF and p at
+        p = jnp.exp(s - m_new)
+        # a fully-masked block leaves m_new at NEG_INF and p at
         # exp(0) = 1 — re-mask so masked positions never reach l/acc
         p = jnp.where(valid, p, 0.0)
-        l_new = l * alpha + jnp.sum(p, axis=-1)
-        acc_new = acc * alpha[:, None] + jax.lax.dot_general(
-            p.astype(vt.dtype), vt, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = acc * alpha + _probs_dot(p, vt)
         return m_new, l_new, acc_new
 
-    G, hd = q.shape
-    m0 = jnp.full((G,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((G,), jnp.float32)
-    acc0 = jnp.zeros((G, hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, num_pg, body, (m0, l0, acc0))
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0, 0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
+    rows = groups * kv_rows
+    m0 = jnp.full((rows, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((rows, 1), jnp.float32)
+    acc0 = jnp.zeros((rows, width), jnp.float32)
+    m, l, acc = jax.lax.fori_loop(0, num_blk, body, (m0, l0, acc0))
+    ctx = acc / jnp.where(l == 0.0, 1.0, l)
+    # row (g, kh) keeps kv head kh's lanes; summed over kh that is
+    # group member g's context for every kv head, in the pool row's
+    # own layout
+    for g in range(groups):
+        part = ctx[g * kv_rows:(g + 1) * kv_rows]
+        o_ref[0, g:g + 1, :] = jnp.sum(
+            jnp.where(own, part, 0.0), axis=0,
+            keepdims=True).astype(o_ref.dtype)
 
 
 def _compiler_params(interpret):
     if pltpu is None or interpret:
         return None
-    # batch programs are independent; the kv-head dim drives the DMA
-    # sequence and stays arbitrary
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"))
+    # a program is one sequence's walk; the sequences are independent
+    return pltpu.CompilerParams(dimension_semantics=("parallel",))
 
 
-def _paged_decode_pallas(q, kpool, vpool, scales, block_tables,
-                         cache_position, sm_scale, interpret, layer):
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret",
+                                             "block_tokens"))
+def _paged_decode_call(q, kpool, vpool, scales, block_tables,
+                       cache_position, layer, sm_scale, interpret,
+                       block_tokens):
     """Shared pallas_call builder for the dense-pool and int8-pool
     arities; ``scales`` is None or the (k_scales, v_scales) pair. The
     kernel is handed the whole stacked pool, pinned in HBM, and indexes
     ``layer`` itself: a ``pool[layer]`` operand would be a copy of the
-    layer."""
+    layer. ``layer`` rides in SMEM beside the tables, so every layer's
+    call is the same kernel."""
     B, H, hd = q.shape
     ps, width = kpool.shape[2:]
     KH = width // hd
     G = H // KH
-    qg = q.reshape(B, KH, G, hd)
     quantized = scales is not None
+    # (B, G, width): member g of every kv head's group, in the kv
+    # head's lanes; the MXU takes the pool's dtype, dequantized int8
+    # tiles are fp32
+    qg = q.reshape(B, KH, G, hd).transpose(0, 2, 1, 3).reshape(
+        B, G, width).astype(jnp.float32 if quantized else kpool.dtype)
+    tokens = max(1, block_tokens // ps) * ps       # whole pages a turn
     kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
-                               page_size=ps, quantized=quantized,
-                               layer=layer)
-    in_specs = [
-        pl.BlockSpec((1, 1, G, hd), lambda b, k, *_: (b, k, 0, 0)),
-        # pools stay pinned in HBM; the kernel DMAs one
-        # (page_size, hd) tile per walked page — never the stripe
-        pl.BlockSpec(memory_space=pltpu.HBM),
-        pl.BlockSpec(memory_space=pltpu.HBM),
-    ]
-    scratch = [
-        pltpu.VMEM((2, ps, hd), kpool.dtype),
-        pltpu.VMEM((2, ps, hd), vpool.dtype),
-    ]
-    operands = [block_tables, cache_position, qg, kpool, vpool]
-    if quantized:
-        nb = scales[0].shape[-1] // KH
-        # scale pools ride in HBM too: one (page_size, nb) fp32 tile
-        # DMAs alongside each int8 page tile
-        in_specs += [pl.BlockSpec(memory_space=pltpu.HBM),
-                     pl.BlockSpec(memory_space=pltpu.HBM)]
-        scratch += [pltpu.VMEM((2, ps, nb), jnp.float32),
-                    pltpu.VMEM((2, ps, nb), jnp.float32)]
-        operands += [scales[0], scales[1]]
-    scratch += [pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SemaphoreType.DMA((2,))]
-    if quantized:
-        scratch += [pltpu.SemaphoreType.DMA((2,)),
-                    pltpu.SemaphoreType.DMA((2,))]
+                               page_size=ps, head_dim=hd,
+                               quantized=quantized)
+    # pools stay pinned in HBM; the kernel DMAs the walked pages' whole
+    # (page_size, width) tiles — never the stripe
+    pools = [kpool, vpool] + (list(scales) if quantized else [])
+    in_specs = [pl.BlockSpec((1, G, width), lambda b, *_: (b, 0, 0))]
+    in_specs += [pl.BlockSpec(memory_space=pltpu.HBM)] * len(pools)
+    scratch = [pltpu.VMEM((2, tokens, pool.shape[-1]), pool.dtype)
+               for pool in pools]
+    scratch += [pltpu.SemaphoreType.DMA((2,))] * len(pools)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        # tables + positions prefetch into SMEM: page ids must be
-        # available to index the DMAs before the body runs
-        num_scalar_prefetch=2,
-        grid=(B, KH),
+        # layer, tables and positions prefetch into SMEM: page ids must
+        # be available to index the DMAs before the body runs
+        num_scalar_prefetch=3,
+        grid=(B,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda b, k, *_: (b, k, 0, 0)),
+        out_specs=pl.BlockSpec((1, G, width), lambda b, *_: (b, 0, 0)),
         scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KH, G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, G, width), q.dtype),
         interpret=interpret,
         compiler_params=_compiler_params(interpret),
-    )(*operands)
-    return out.reshape(B, H, hd)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("sm_scale", "interpret", "layer"))
-def _paged_decode_call(q, kpool, vpool, block_tables, cache_position,
-                       sm_scale, interpret, layer):
-    return _paged_decode_pallas(q, kpool, vpool, None, block_tables,
-                                cache_position, sm_scale, interpret, layer)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("sm_scale", "interpret", "layer"))
-def _paged_decode_call_quant(q, kpool, vpool, k_scales, v_scales,
-                             block_tables, cache_position, sm_scale,
-                             interpret, layer):
-    return _paged_decode_pallas(q, kpool, vpool, (k_scales, v_scales),
-                                block_tables, cache_position, sm_scale,
-                                interpret, layer)
+    )(layer, block_tables, cache_position, qg, *pools)
+    # (B, G, KH * hd) -> heads in q's order, kh major
+    return out.reshape(B, G, KH, hd).transpose(0, 2, 1, 3).reshape(B, H, hd)
 
 
 def paged_decode_attention(q, kpool, vpool, block_tables, cache_position,
@@ -477,16 +546,15 @@ def paged_decode_attention(q, kpool, vpool, block_tables, cache_position,
         sm_scale = 1.0 / np.sqrt(hd)
     if interpret is None:
         interpret = not _use_pallas()
+    scales = None
     if k_scales is not None:
         nb, rem = divmod(k_scales.shape[-1], KH)
         assert k_scales.shape[:3] == kpool.shape[:3] and rem == 0 and \
             hd % nb == 0, (k_scales.shape, kpool.shape)
-        return _paged_decode_call_quant(
-            q, kpool, vpool, k_scales, v_scales,
-            block_tables.astype(jnp.int32),
-            cache_position.astype(jnp.int32), float(sm_scale),
-            bool(interpret), int(layer))
-    return _paged_decode_call(q, kpool, vpool,
+        scales = (k_scales, v_scales)
+    return _paged_decode_call(q, kpool, vpool, scales,
                               block_tables.astype(jnp.int32),
                               cache_position.astype(jnp.int32),
-                              float(sm_scale), bool(interpret), int(layer))
+                              jnp.full((1,), layer, jnp.int32),
+                              float(sm_scale), bool(interpret),
+                              _BLOCK_TOKENS)

@@ -97,6 +97,85 @@ class TestKernelParity:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5)
 
+    @pytest.mark.parametrize("block_tokens", [16, 128],
+                             ids=["page_a_turn", "block128"])
+    @pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bf16"])
+    @pytest.mark.parametrize("heads,kv_heads,hd", [
+        (16, 16, 64),       # GPT-2 345M: rows of 1,024 lanes
+        (25, 25, 64),       # GPT-2 XL: 1,600, heads not a sublane tile
+        (32, 8, 128),       # llama-sized GQA: 8 x 128
+    ], ids=["mha16x64", "mha25x64", "gqa32_8x128"])
+    def test_parity_at_served_widths_and_block_edges(self, monkeypatch,
+                                                     heads, kv_heads, hd,
+                                                     pool_dtype,
+                                                     block_tokens):
+        """ISSUE 30: the kernel streams whole pool rows, a block of
+        pages a loop turn, at every head width — at the shipped block
+        (one page of 16) and at 8 pages, the setting that waits for a
+        benchmark that can hold its speed. Pages of 16; rows at
+        ``cache_position`` 0, with fewer live pages than a block, with
+        exactly a block (its last token and one short of it), with one
+        page more, and two blocks and a tail; the last row is an
+        inactive slot (all-null table), which must come out finite.
+        bf16 pools take the MXU path whose probabilities are split, not
+        rounded: the float32 oracle over the same bf16 values is met to
+        float32 accuracy."""
+        from deepspeed_tpu.ops.attention import paged
+        from deepspeed_tpu.ops.attention.paged import (
+            paged_decode_attention, paged_decode_reference)
+        monkeypatch.setattr(paged, "_BLOCK_TOKENS", block_tokens)
+        ps = 16
+        blk = 128                   # the edges of the widest block tested
+        pos = [0, 3 * ps + 5, blk - 2, blk - 1, blk, blk + ps - 1,
+               2 * blk + 2 * ps + 7, 0]
+        P = max(pos) // ps + 2                      # one dead column
+        rng = np.random.RandomState(heads + hd)
+        q, kpool, vpool, tables = _pool_case(
+            rng, kv_heads=kv_heads, gqa=heads // kv_heads, page_size=ps,
+            pages_per_seq=P, hd=hd, batch=len(pos))
+        tables[-1] = 0                              # the inactive slot
+        for b, p in enumerate(pos[:-1]):            # reserved, unwritten
+            dead = tables[b, p // ps + 1:]
+            kpool = kpool.at[LAYER, dead].set(jnp.nan)
+            vpool = vpool.at[LAYER, dead].set(jnp.nan)
+        q, kpool, vpool = (x.astype(pool_dtype) for x in (q, kpool, vpool))
+        tables, pos = jnp.asarray(tables), jnp.asarray(pos, jnp.int32)
+        out = paged_decode_attention(q, kpool, vpool, tables, pos,
+                                     interpret=True, layer=LAYER)
+        assert out.shape == q.shape and out.dtype == q.dtype
+        assert bool(jnp.all(jnp.isfinite(out)))
+        live = slice(0, len(pos) - 1)
+        clean = [jnp.nan_to_num(x.astype(jnp.float32))
+                 for x in (q, kpool, vpool)]
+        ref = paged_decode_reference(clean[0][live], clean[1], clean[2],
+                                     tables[live], pos[live], layer=LAYER)
+        # the output is rounded to q's dtype once
+        atol = 2e-5 if pool_dtype == jnp.float32 else 2e-2
+        np.testing.assert_allclose(
+            np.asarray(out[live].astype(jnp.float32)), np.asarray(ref),
+            atol=atol)
+
+    def test_bf16_probabilities_are_not_rounded(self):
+        """Point 4 of ISSUE 30: over a bf16 pool the probabilities reach
+        the context product whole (three bf16 terms that sum to the
+        float32 value), so a float32 query's context equals the float32
+        oracle's over the same bf16 keys and values to float32 accuracy;
+        rounding them to bf16 would show at 1e-3."""
+        from deepspeed_tpu.ops.attention.paged import (
+            _probs_dot, paged_decode_attention, paged_decode_reference)
+        rng = np.random.RandomState(5)
+        p = jnp.asarray(rng.rand(16, 128), jnp.float32)
+        v = jnp.asarray(rng.randn(128, 256), jnp.bfloat16)
+        exact = np.asarray(p, np.float64) @ np.asarray(
+            v.astype(jnp.float32), np.float64)
+        np.testing.assert_allclose(np.asarray(_probs_dot(p, v)), exact,
+                                   rtol=0, atol=2e-5)
+        rounded = np.asarray(p.astype(jnp.bfloat16).astype(jnp.float32),
+                             np.float64) @ np.asarray(
+                                 v.astype(jnp.float32), np.float64)
+        assert np.abs(rounded - exact).max() > 1e-3
+
     def test_shared_prefix_pages_two_rows_one_batch(self):
         """Prefix-cache sharing at the kernel level: two rows whose
         tables point at the SAME physical pages (one prefilled prefix,
@@ -320,20 +399,30 @@ class TestSupportPredicate:
         assert ok and "interpret" in why
 
     def test_tpu_legality_matrix(self):
-        """Compiled-TPU DMA legality: head_dim must 128-align (lane
-        dim), page_size must fill the dtype's sublane tile."""
+        """Compiled-TPU DMA legality: the tile is a page of whole pool
+        rows, so the ROW (kv_heads * head_dim) must be whole 128-lane
+        tiles and the page whole 8-row sublane tiles; the head width
+        alone decides nothing (tests/unit/test_tpu_compile.py holds the
+        same cases to the compiler)."""
         from deepspeed_tpu.ops.attention.paged import \
             paged_decode_supported
-        assert paged_decode_supported(16, 128, jnp.bfloat16,
-                                      backend="tpu")[0]
-        assert paged_decode_supported(8, 128, jnp.float32,
-                                      backend="tpu")[0]
-        ok, why = paged_decode_supported(16, 64, jnp.bfloat16,
-                                         backend="tpu")
-        assert not ok and "head_dim" in why
-        ok, why = paged_decode_supported(8, 128, jnp.bfloat16,
-                                         backend="tpu")
+
+        def gate(page_size, head_dim, dtype, kv_heads):
+            return paged_decode_supported(page_size, head_dim, dtype,
+                                          backend="tpu", kv_heads=kv_heads)
+        assert gate(16, 64, jnp.bfloat16, 16)[0]        # GPT-2 345M
+        assert gate(16, 128, jnp.bfloat16, 8)[0]        # llama GQA
+        assert gate(8, 128, jnp.float32, 16)[0]
+        assert gate(8, 128, jnp.bfloat16, 16)[0]
+        assert gate(16, 128, jnp.bfloat16, 1)[0]
+        ok, why = gate(16, 64, jnp.bfloat16, 25)        # GPT-2 XL: 1,600
+        assert not ok and "1600" in why
+        ok, why = gate(16, 64, jnp.bfloat16, 1)
+        assert not ok and "128-lane" in why
+        ok, why = gate(4, 128, jnp.bfloat16, 16)
         assert not ok and "page_size" in why
+        ok, why = gate(32, 128, jnp.int8, 16)
+        assert not ok and "scale rows" in why
 
     def test_live_pages_and_bytes_model(self):
         from deepspeed_tpu.ops.attention.paged import (decode_read_bytes,
@@ -358,14 +447,24 @@ PAGED_GATHER = {"page_size": 4, "num_pages": 14, "attn_kernel": "gather"}
 
 
 class TestEngineParity:
-    @pytest.mark.parametrize("family", ["gpt2", "llama"])
+    @pytest.mark.parametrize("family", ["gpt2", "llama", "gpt2_head64"])
     def test_pallas_greedy_exactly_matches_gather(self, family):
         """ISSUE 8 acceptance: greedy outputs from the pallas decode
         path exactly match the gather path for both families under
         continuous batching with prefix reuse (shared system prompt),
-        mixed lengths, tiny pool."""
+        mixed lengths, tiny pool — and at GPT-2's head width of 64
+        (ISSUE 30: the width the kernel used to refuse on the chip)."""
         from deepspeed_tpu.inference import InferenceEngine
-        cfg, params = tiny_gpt2() if family == "gpt2" else tiny_llama()
+        if family == "gpt2_head64":
+            from deepspeed_tpu.models.gpt2 import (GPT2Config,
+                                                   init_gpt2_params)
+            cfg = GPT2Config(vocab_size=61, max_position_embeddings=32,
+                             hidden_size=128, num_layers=2, num_heads=2,
+                             embd_dropout=0.0, attn_dropout=0.0,
+                             resid_dropout=0.0)
+            params = init_gpt2_params(cfg, jax.random.PRNGKey(3))
+        else:
+            cfg, params = tiny_gpt2() if family == "gpt2" else tiny_llama()
         rng = np.random.RandomState(8)
         sys_prompt = rng.randint(1, 61, (4,)).tolist()   # one full page
         # the sys-prompt pair goes first so both are in flight together

@@ -235,18 +235,20 @@ def test_flash_attention_under_a_data_mesh_needs_the_shard_wrap():
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _paged_decode_specs(head_dim, page_size, pool_dtype):
+def _paged_decode_specs(head_dim, page_size, pool_dtype, heads=16,
+                        kv_heads=16):
     """The kernel's operands over a stacked pool in its row layout,
-    ``(layers, num_pages, page_size, heads * head_dim)``."""
-    batch, heads, layers, num_pages, pages_per_seq = 8, 16, 2, 128, 16
-    pool = _spec((layers, num_pages, page_size, heads * head_dim),
+    ``(layers, num_pages, page_size, kv_heads * head_dim)``."""
+    batch, layers, num_pages, pages_per_seq = 8, 2, 128, 16
+    pool = _spec((layers, num_pages, page_size, kv_heads * head_dim),
                  pool_dtype)
     specs = [_spec((batch, heads, head_dim)), pool, pool,
              _spec((batch, pages_per_seq), jnp.int32),
              _spec((batch,), jnp.int32)]
     if pool_dtype == jnp.int8:
         # the engine's scale leaves at kv_quant_block 0: one per head
-        scale = _spec((layers, num_pages, page_size, heads), jnp.float32)
+        scale = _spec((layers, num_pages, page_size, kv_heads),
+                      jnp.float32)
         specs += [scale, scale]
     return specs
 
@@ -263,22 +265,45 @@ def test_paged_decode_bf16_head128_compiles():
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("head_dim,page_size,pool_dtype", [
-    (128, 32, jnp.int8),        # a page geometry the old gate accepted
-    (128, 16, jnp.int8),
-    (64, 16, jnp.bfloat16),     # every GPT-2 width
-    (128, 16, jnp.bfloat16),
-    (128, 32, jnp.bfloat16),
-    (128, 8, jnp.float32),
+@pytest.mark.parametrize("block_tokens", [16, 64, 128, 256])
+@pytest.mark.parametrize("head_dim,heads,kv_heads", [
+    (64, 16, 16), (128, 32, 8)], ids=["gpt2_345m", "llama_gqa"])
+def test_paged_decode_compiles_at_every_block(monkeypatch, head_dim, heads,
+                                              kv_heads, block_tokens):
+    """The walk's block (tokens a loop turn) is one constant, shipped at
+    one page of 16 for what the benchmark can hold (ISSUE 30): the
+    settings a later PR may move it to compile today."""
+    from deepspeed_tpu.ops.attention import paged
+    monkeypatch.setattr(paged, "_BLOCK_TOKENS", block_tokens)
+    compiled = _compile(_paged_decode, *_paged_decode_specs(
+        head_dim, 16, jnp.bfloat16, heads, kv_heads))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("head_dim,page_size,pool_dtype,heads,kv_heads", [
+    (128, 32, jnp.int8, 16, 16),      # the scale rows: 16 lanes
+    (128, 16, jnp.int8, 16, 16),
+    (64, 16, jnp.bfloat16, 16, 16),   # GPT-2 345M: rows of 1,024 lanes
+    (128, 16, jnp.bfloat16, 16, 16),
+    (128, 32, jnp.bfloat16, 16, 16),
+    (128, 8, jnp.float32, 16, 16),
+    (64, 16, jnp.bfloat16, 25, 25),   # GPT-2 XL: 1,600, not whole tiles
+    (128, 16, jnp.bfloat16, 32, 8),   # llama-sized GQA: 8 x 128
+    (128, 8, jnp.bfloat16, 16, 16),   # a page of one sublane tile
+    (128, 4, jnp.bfloat16, 16, 16),   # and of half of one
+    (64, 16, jnp.bfloat16, 1, 1),     # one narrow head: a 64-lane row
 ], ids=lambda v: getattr(v, "__name__", str(v)))
 def test_paged_decode_gate_agrees_with_the_compiler(head_dim, page_size,
-                                                    pool_dtype):
+                                                    pool_dtype, heads,
+                                                    kv_heads):
     """``paged_decode_supported`` is what keeps the serving engine off a
-    kernel that cannot compile: it says no exactly where Mosaic
-    refuses."""
+    kernel that cannot compile: it says yes exactly where Mosaic
+    compiles the whole-row walk and no exactly where it refuses (ISSUE
+    30: the head width no longer decides; the pool row's width does)."""
     ok, why = paged_decode_supported(page_size, head_dim, pool_dtype,
-                                     backend="tpu")
-    specs = _paged_decode_specs(head_dim, page_size, pool_dtype)
+                                     backend="tpu", kv_heads=kv_heads)
+    specs = _paged_decode_specs(head_dim, page_size, pool_dtype, heads,
+                                kv_heads)
     if ok:
         _compile(_paged_decode, *specs)
     else:
@@ -402,9 +427,10 @@ TRUNK_LAYERS = 4
 _NO_WRITE = {"parameter", "tuple", "get-tuple-element", "bitcast"}
 
 
-def _paged_trunk(family, heads, kv_heads, head_dim):
+def _paged_trunk(family, heads, kv_heads, head_dim, attn_kernel="gather"):
     """A four-layer cached trunk over the paged pool, as the engine's
-    serving programs run it (gather reader, pool donated):
+    serving programs run it (pool donated; the gather reader, or the
+    Pallas decode kernel for one-token queries):
     ``(jitted fn(params, cache, ids, positions, tables), param specs)``.
     The vocabulary is small so that no embedding is of a layer slice's
     size."""
@@ -427,7 +453,7 @@ def _paged_trunk(family, heads, kv_heads, head_dim):
 
     def fn(params, cache, ids, positions, tables):
         return trunk(params, cfg, ids, cache, positions, jnp.bfloat16,
-                     block_tables=tables)
+                     block_tables=tables, paged_attn_kernel=attn_kernel)
     return jax.jit(fn, donate_argnums=(1,)), params
 
 
@@ -494,6 +520,54 @@ def test_paged_trunk_writes_the_pool_in_place(family, heads, kv_heads,
     stripes = 2 * rows * kv_heads * TABLE_PAGES * PAGE * max(head_dim, 128)
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 2 * (stripes + layer_elems)
+
+
+@pytest.mark.parametrize("family,heads,kv_heads,head_dim", [
+    ("gpt2", 16, 16, 64),       # the cell: gpt2-345m.serve-saturated
+    ("llama", 32, 8, 128),
+], ids=["gpt2_345m", "llama_gqa"])
+def test_pallas_decode_trunk_holds_no_gathered_stripe(monkeypatch, family,
+                                                      heads, kv_heads,
+                                                      head_dim):
+    """The decode program with the Pallas reader (ISSUE 30), at the
+    cell's 161 rows, table of 40 pages and pool of 2,561: the donated
+    pool comes back in its own buffers, the only results of half a
+    gathered stripe's size or more are the 2 x layers scatters that
+    alias it, and NOTHING like a stripe is produced: no ``gather``, no
+    ``reshape`` or ``copy`` of one, no fusion writing one. What reads
+    the keys and values is one ``tpu_custom_call`` a layer, and the
+    program's temporaries are a fraction of ONE stripe (the gather
+    reader's were four of them)."""
+    from deepspeed_tpu.ops.attention import paged
+    monkeypatch.setattr(paged, "_use_pallas", lambda: True)
+    width = kv_heads * head_dim
+    pool_shape = (TRUNK_LAYERS, POOL_PAGES, PAGE, width)
+    layer_elems = int(np.prod(pool_shape[1:]))
+    stripe_elems = ROWS * TABLE_PAGES * PAGE * width
+    fn, params = _paged_trunk(family, heads, kv_heads, head_dim, "pallas")
+    pool = _spec(pool_shape)
+    compiled = fn.lower(params, (pool, pool),
+                        _spec((ROWS, 1), jnp.int32),
+                        _spec((ROWS,), jnp.int32),
+                        _spec((ROWS, TABLE_PAGES), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"',
+                          _entry(compiled))) == TRUNK_LAYERS
+    aliases = dict(re.findall(r"\{(\d+)\}: \((\d+), \{\}, may-alias\)",
+                              text.split("\n", 1)[0]))
+    assert {"1", "2"} <= set(aliases)
+    written = [(opcode, elems)
+               for opcode, _, elems, _ in _entry_results(compiled)
+               if opcode not in _NO_WRITE and elems >= stripe_elems // 2]
+    assert written, "the parse found no result of the pool's size"
+    assert all(opcode == "fusion" and elems == TRUNK_LAYERS * layer_elems
+               for opcode, elems in written), written
+    assert len(written) == 2 * TRUNK_LAYERS
+    # the embedding lookup is the only gather left, of a row a token
+    from deepspeed_tpu.utils.hlo_audit import max_gather_elems
+    assert max_gather_elems(text) < stripe_elems // 2
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < stripe_elems * 2 // 4
 
 
 def test_serving_compiler_options_share_the_layers_code(monkeypatch):

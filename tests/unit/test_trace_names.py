@@ -66,8 +66,9 @@ def _batches(rows=2):
         yield {"input_ids": rs.randint(0, 61, (rows, 17)).astype(np.int32)}
 
 
-def _serve_engine(dtype=jnp.float32):
-    return InferenceEngine(CFG, _params(), INF, dtype=dtype)
+def _serve_engine(dtype=jnp.float32, attn_kernel="gather"):
+    inf = dict(INF, paged_kv=dict(INF["paged_kv"], attn_kernel=attn_kernel))
+    return InferenceEngine(CFG, _params(), inf, dtype=dtype)
 
 
 def _scopes_in(text):
@@ -235,9 +236,10 @@ def _children(events, parent):
             if ev is not parent and parent[1] <= ev[1] and ev[2] <= parent[2]]
 
 
+@pytest.mark.parametrize("attn_kernel", ["gather", "pallas"])
 def test_serving_trace_holds_spans_in_order_with_scheduler_counters(
-        tmp_path):
-    engine = _serve_engine()
+        tmp_path, attn_kernel):
+    engine = _serve_engine(attn_kernel=attn_kernel)
     engine.warmup()
     sched = engine.scheduler
     for i, p in enumerate(PROMPTS):
@@ -248,7 +250,14 @@ def test_serving_trace_holds_spans_in_order_with_scheduler_counters(
 
     def spy(name, **args):
         if name == "serve/decode":
-            reported.append(sched.tokens_in_flight)
+            # live tokens, and the pages the Pallas reader walks: each
+            # decoding row's live pages, the null page once for a row
+            # that is not decoding
+            ps = engine.paged_spec.page_size
+            walked = [s.position // ps + 1 for s in sched.slots
+                      if s is not None and s.pending_tok is not None]
+            reported.append((sched.tokens_in_flight,
+                             sum(walked) + engine._rows - len(walked)))
         return real_span(name, **args)
 
     engine._span = spy
@@ -258,11 +267,17 @@ def test_serving_trace_holds_spans_in_order_with_scheduler_counters(
     decodes = [ev for ev in events if ev[0] == "serve/decode"]
     prefills = [ev for ev in events if ev[0] == "serve/prefill"]
     assert len(decodes) == 3 and len(prefills) >= 1
-    for ev, live in zip(decodes, reported):
-        # every argument has a reader (decode_stripe_live_share.sat)
+    assert engine._decode_attn_path == attn_kernel
+    for ev, (live, walked) in zip(decodes, reported):
+        # every argument has a reader (decode_stripe_live_share.sat,
+        # decode_read_live_share.sat)
         assert set(ev[3]) == {"rows", "table_pages", "page_size",
-                              "live_tokens"}
+                              "live_tokens", "read_pages"}
         assert ev[3]["live_tokens"] == live
+        # the gather reader walks no page list: it reads the table
+        assert ev[3]["read_pages"] == \
+            (walked if attn_kernel == "pallas" else 0)
+        assert live <= walked * ev[3]["page_size"]
         assert ev[3]["rows"] == engine._rows
         assert ev[3]["page_size"] == engine.paged_spec.page_size
         assert ev[3]["table_pages"] == engine.paged_spec.pages_per_seq
