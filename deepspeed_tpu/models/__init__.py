@@ -9,3 +9,6 @@ from deepspeed_tpu.models.bert import (
 from deepspeed_tpu.models.llama import (
     LlamaConfig, init_llama_params, llama_forward, llama_generate,
     llama_loss_fn, llama_param_specs)
+from deepspeed_tpu.models.smallthinker import (
+    SmallThinkerConfig, init_smallthinker_params, smallthinker_logits,
+    smallthinker_loss_fn)

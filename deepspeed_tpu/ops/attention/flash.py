@@ -119,10 +119,13 @@ def dropout_mask_reference(seed, b, h, sq, sk, rate):
 # --------------------------------------------------------------------- #
 def attention_reference(q, k, v, mask=None, causal=False,
                         sm_scale: Optional[float] = None,
-                        dropout_rate: float = 0.0, dropout_seed=None):
+                        dropout_rate: float = 0.0, dropout_seed=None,
+                        window: Optional[int] = None):
     """Plain jnp attention. q,k,v: (B, H, S, D); mask: additive, broadcastable
     to (B, H, Sq, Sk). With dropout_rate > 0 applies the same hash keep-mask
-    the Pallas kernels use (seed: scalar). GQA: k/v may carry H/G heads."""
+    the Pallas kernels use (seed: scalar). GQA: k/v may carry H/G heads.
+    ``window`` (with ``causal``): a query sees only the ``window`` keys
+    that end at its own position."""
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(q.shape[-1])
     if k.shape[1] != q.shape[1]:
@@ -137,7 +140,10 @@ def attention_reference(q, k, v, mask=None, causal=False,
         sq, sk = s.shape[-2], s.shape[-1]
         idx_q = jnp.arange(sq)[:, None]
         idx_k = jnp.arange(sk)[None, :]
-        s = jnp.where(idx_q >= idx_k, s, NEG_INF)
+        keep = idx_q >= idx_k
+        if window is not None:
+            keep &= idx_q - idx_k < window
+        s = jnp.where(keep, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     if dropout_rate > 0.0:
         b_, h_, sq_, sk_ = p.shape
@@ -918,7 +924,8 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
                     dropout_rate: float = 0.0,
                     dropout_rng=None,
                     interpret: Optional[bool] = None,
-                    force_reference: bool = False):
+                    force_reference: bool = False,
+                    window: Optional[int] = None):
     """Flash attention with O(S) memory and in-kernel attention dropout.
 
     q: (batch, heads, seq, head_dim); k, v: (batch, kv_heads, seq_k,
@@ -932,6 +939,9 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
     dropout_rate: attention-probability dropout (reference
     attn_dropout_ratio); requires dropout_rng (a jax PRNG key) — pass
     rate 0.0 / rng None for eval.
+    window: with ``causal`` and ``seq == seq_k``, a query sees only the
+    ``window`` keys that end at its own position (sliding-window layers):
+    the banded causal BlockMask of the same kernel.
 
     Traced inside an engine's GSPMD program
     (``parallel/pallas_shard.pallas_kernel_mesh``) the kernel runs
@@ -944,14 +954,16 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
     km = current_kernel_mesh()
     kwargs = dict(mask=mask, causal=causal, sm_scale=sm_scale,
                   dropout_rate=dropout_rate, dropout_rng=dropout_rng,
-                  interpret=interpret, force_reference=force_reference)
+                  interpret=interpret, force_reference=force_reference,
+                  window=window)
     if km is not None and not jax.sharding.get_abstract_mesh().manual_axes:
         return sharded_flash_attention(km, q, k, v, **kwargs)
     return _local_flash_attention(q, k, v, **kwargs)
 
 
 def _local_flash_attention(q, k, v, mask, causal, sm_scale, dropout_rate,
-                           dropout_rng, interpret, force_reference):
+                           dropout_rng, interpret, force_reference,
+                           window=None):
     """:func:`flash_attention` on operands that are local to this device
     (or replicated): picks the kernel and calls it."""
     if sm_scale is None:
@@ -970,6 +982,11 @@ def _local_flash_attention(q, k, v, mask, causal, sm_scale, dropout_rate,
     else:
         seed = jnp.zeros((1, 1), jnp.int32)
     sq, sk = q.shape[2], k.shape[2]
+    if window is not None:
+        assert causal and sq == sk, \
+            "flash_attention: window needs causal self-attention"
+        if window >= sq:
+            window = None                     # the window holds every key
     if force_reference or sq % 16 != 0 or sk % 16 != 0:
         if not force_reference and max(sq, sk) > 2048:
             log_once(("irregular-fallback", sq, sk),
@@ -981,7 +998,11 @@ def _local_flash_attention(q, k, v, mask, causal, sm_scale, dropout_rate,
                                    sm_scale=sm_scale,
                                    dropout_rate=dropout_rate,
                                    dropout_seed=seed.reshape(())
-                                   if dropout_rate > 0.0 else None)
+                                   if dropout_rate > 0.0 else None,
+                                   window=window)
+    assert window is None or (sq % 128 == 0 or sq < STREAM_THRESHOLD), \
+        "flash_attention: a windowed sequence this long must be a " \
+        "multiple of 128"
     if (max(sq, sk) >= STREAM_THRESHOLD
             and (sq % 128 != 0 or sk % 128 != 0)):
         # long irregular sequences: the resident path may fail to compile
@@ -1020,7 +1041,7 @@ def _local_flash_attention(q, k, v, mask, causal, sm_scale, dropout_rate,
         # runs this module's own kernels below.)
         return _masked_dense_attention(q, k, v, mask, seed, causal,
                                        float(sm_scale), interpret,
-                                       dropout_rate)
+                                       dropout_rate, window)
     if mask is None:
         return _flash_attention(q, k, v, seed, causal, float(sm_scale),
                                 interpret, dropout_rate)
@@ -1034,26 +1055,29 @@ _DENSE_MASK_CACHE = {}
 _DENSE_MASK_CAP = 256
 
 
-def _dense_block_mask(sq, sk, d, causal):
-    key = (sq, sk, d, causal, _FORCE_BLOCKS)
+def _dense_block_mask(sq, sk, d, causal, window=None):
+    key = (sq, sk, d, causal, window, _FORCE_BLOCKS)
     bm = _DENSE_MASK_CACHE.get(key)
     if bm is None:
         from deepspeed_tpu.ops.attention.masked_flash import BlockMask
         block = pick_masked_block(sq, sk, d)
         if len(_DENSE_MASK_CACHE) >= _DENSE_MASK_CAP:
             _DENSE_MASK_CACHE.clear()
-        bm = BlockMask.causal(sq, block) if causal else \
-            BlockMask.dense(sq, sk, block)
+        if window is not None:
+            bm = BlockMask.causal_window(sq, window, block)
+        else:
+            bm = BlockMask.causal(sq, block) if causal else \
+                BlockMask.dense(sq, sk, block)
         _DENSE_MASK_CACHE[key] = bm
     return bm
 
 
 def _masked_dense_attention(q, k, v, mask, seed, causal, sm_scale,
-                            interpret, rate):
+                            interpret, rate, window=None):
     from deepspeed_tpu.ops.attention.masked_flash import masked_flash_call
     sq, sk = q.shape[2], k.shape[2]
     b = q.shape[0]
-    bm = _dense_block_mask(sq, sk, q.shape[-1], causal)
+    bm = _dense_block_mask(sq, sk, q.shape[-1], causal, window)
     # no mask: a dummy kpm + has_kpm=False keeps the hot path free of
     # an all-zero mask operand/add
     kpm = jnp.zeros((b, 1), jnp.float32) if mask is None else \

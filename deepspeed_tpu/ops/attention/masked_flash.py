@@ -207,6 +207,26 @@ class BlockMask:
         return cls(active, kinds * active, block, seq, seq)
 
     @classmethod
+    def causal_window(cls, seq: int, window: int,
+                      block: int) -> "BlockMask":
+        """Square causal mask inside a sliding window: query ``i`` sees
+        key ``j`` where ``j <= i`` and ``i - j < window``, to the
+        element. Tiles wholly inside the band are FULL, tiles the
+        diagonal or the window's far edge cuts carry the band predicate
+        at a fine block of ONE element (``|i - j| <= window - 1`` under
+        the causal clip), tiles outside are never walked."""
+        assert window >= 1, window
+        nb = seq // block
+        d = np.arange(nb)[:, None] - np.arange(nb)[None, :]
+        # a tile's pairs span i - j in [d*block - (block-1), d*block + block-1]
+        active = (d >= 0) & (d * block - (block - 1) <= window - 1)
+        full = (d >= 1) & (d * block + (block - 1) <= window - 1)
+        kinds = np.where(active & ~full, KIND_BAND, KIND_FULL
+                         ).astype(np.uint8)
+        return cls(active[None], kinds[None], block, seq, seq,
+                   band=(1, window - 1, 0, 0, True), fine_block=1)
+
+    @classmethod
     def from_layout(cls, layout: np.ndarray, fine_block: int,
                     walk_block: Optional[int] = None) -> "BlockMask":
         """A SparsityConfig layout (H, nb, nb) as a BlockMask.

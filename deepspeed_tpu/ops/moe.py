@@ -24,11 +24,14 @@ Design (GShard/Switch-style, XLA-first):
 """
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from deepspeed_tpu.profiling.spans import scope
 
 
 @dataclasses.dataclass
@@ -292,3 +295,210 @@ def moe_layer_sharded(params, config: MoEConfig, x, mesh,
         out_specs=(P(expert_axis, None, None), P()),
         check_vma=False)
     return fn(params["router"], params["wi"], params["wo"], x)
+
+
+# --------------------------------------------------------------------- #
+# dropless routed experts over the experts HELD HERE
+# --------------------------------------------------------------------- #
+# The one-hot path above costs ~2.5*T^2 elements a layer and drops what
+# overflows a capacity. This path drops nothing: every (token, choice)
+# assignment that names an expert this chip holds is sorted by expert,
+# the held experts run as ONE grouped matrix product per weight table
+# (rows of expert e contiguous, `group_sizes[e]` of them), and the rows
+# go back to their tokens weighted by the router. The layer routes over
+# ALL experts and computes its own experts' part of the result (what
+# expert parallelism asks of a chip); what the absent experts would add
+# is left out, and nothing here stands in for their chips.
+#
+# Shapes are static, counts are not, and the TIME IS STATIC TOO. The
+# sorted assignments are worked off in turns of `chunk_rows` rows (twice
+# this chip's even share, held / num_experts of the assignments; all of
+# them where that is less), as many turns as cover EVERY assignment, and
+# a turn works its whole buffer: the rows that no landed assignment
+# fills are given to the last group as rows of weight zero. So nothing
+# can overflow, whatever the imbalance, and a step costs the same
+# whatever the router does: with random weights under training the
+# landed share follows the trajectory (one layer of four at 75-98% of
+# its assignments on a quarter of the experts, another at 5%:
+# docs/smallthinker.md), and a layer whose time follows it cannot be
+# timed to a fraction of a percent. The price is the padding: the
+# grouped products run over every assignment's row, landed or not. A
+# chip that holds every expert has no padding and one turn. Each turn
+# is recomputed in the backward pass, so the layer keeps only its
+# inputs.
+
+
+def route_top_k(router_in, w_router, top_k: int):
+    """float32 router: ``r = router_in @ w_router`` (T, E) at full
+    precision, the ``top_k`` largest per token, softmax over THOSE only.
+    Returns (idx (T, k) int32, p (T, k) f32, r (T, E) f32)."""
+    r = jnp.dot(router_in.astype(jnp.float32),
+                w_router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+    top, idx = jax.lax.top_k(r, top_k)
+    return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1), r
+
+
+def chunk_rows(assignments: int, held: int, num_experts: int) -> int:
+    """Rows of a turn's buffer: twice this chip's even share of the
+    assignments, a multiple of 512 (a grouped product's row tile), at
+    most every assignment."""
+    up = lambda n: -(-n // 512) * 512
+    return min(up(-(-assignments * held * 2 // num_experts)),
+               up(assignments))
+
+
+# (rows, contraction, columns) of a grouped product's tile, each cut to
+# the operand: measured on a v5e at the 21B-A3B expert's shapes
+# (docs/smallthinker.md): 32,768 x 2,560 x 768 over 16 groups
+_GMM_TILE = (512, 1280, 768)
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs[rows of group g] @ rhs[g]`` for every group: lhs (M, K),
+    rhs (G, K, N), group_sizes (G,) int32 with sum <= M -> (M, N) in
+    lhs's dtype, float32 accumulation; M a multiple of 512. Only the row
+    tiles the groups cover are computed; rows past the groups' sum hold
+    nothing a caller may read. The Pallas grouped product that ships
+    with JAX (megablox: ``gmm`` forward, ``gmm`` against the transposed
+    table and ``tgmm`` backward), chosen over ``jax.lax.ragged_dot`` by a
+    chip measurement (docs/smallthinker.md)."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    tm, tk, tn = _GMM_TILE
+    tiling = (tm, min(tk, rhs.shape[1]), min(tn, rhs.shape[2]))
+    return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
+               tiling=tiling, interpret=jax.default_backend() != "tpu")
+
+
+def _weighted_rows(rows, w, pos, here, block=2048):
+    """(T, H) float32: token t sums ``w[t, j] * rows[pos[t, j]]`` over
+    its choices j that are ``here``, a block of tokens at a time so that
+    nothing of (T, k, H) is ever held."""
+    t, k = pos.shape
+    block = block if t % block == 0 else t
+
+    def one(args):
+        pos_b, w_b, here_b = args
+        picked = jnp.where(here_b[..., None], rows[pos_b], 0)
+        return jnp.sum(picked.astype(jnp.float32) * w_b[..., None], axis=1)
+
+    split = lambda a: a.reshape(t // block, block, k)
+    return jax.lax.map(one, (split(pos), split(w), split(here))
+                       ).reshape(t, rows.shape[1])
+
+
+@jax.custom_vjp
+def _take_rows(x, tok, pos, here):
+    """x (T, H) -> x[tok] (B, H). The backward pass is a gather too
+    (each token sums the rows its assignments sit in), never a
+    scatter-add."""
+    return x[tok]
+
+
+def _take_rows_fwd(x, tok, pos, here):
+    return x[tok], (pos, here)
+
+
+def _take_rows_bwd(res, g):
+    pos, here = res
+    ones = jnp.ones(pos.shape, jnp.float32)
+    return _weighted_rows(g, ones, pos, here).astype(g.dtype), \
+        None, None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine_rows(ys, w, tok, pos, here, w_sorted):
+    """ys (B, H) expert outputs in sorted order -> (T, H): token t sums
+    ``w[t, j] * ys[pos[t, j]]`` over its choices j that sit in this
+    buffer, in float32."""
+    return _weighted_rows(ys, w, pos, here)
+
+
+def _combine_rows_fwd(ys, w, tok, pos, here, w_sorted):
+    return _combine_rows(ys, w, tok, pos, here, w_sorted), \
+        (ys, tok, pos, here, w_sorted)
+
+
+def _combine_rows_bwd(res, g):
+    ys, tok, pos, here, w_sorted = res
+    g_rows = g[tok]                                    # (B, H) f32
+    d_ys = (g_rows * w_sorted[:, None]).astype(ys.dtype)
+    d_w_sorted = jnp.sum(g_rows * ys.astype(jnp.float32), axis=-1)
+    d_w = jnp.where(here, d_w_sorted[pos], 0.0)
+    return d_ys, d_w, None, None, None, None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
+def _reglu_chunk(rows, c, x, w, experts, plan):
+    """The held experts on chunk ``c`` of the sorted assignments: rows
+    [c * rows, (c + 1) * rows). Returns this chunk's part of y (T, H)."""
+    order, pos, held, ends = plan
+    top_k = w.shape[1]
+    lo = c * rows
+    with scope("moe_dispatch"):
+        # (the buffer is a multiple of the row tile: it may pass the end)
+        padded = jnp.pad(order, (0, (-order.shape[0]) % rows))
+        sorted_a = jax.lax.dynamic_slice(padded, (lo,), (rows,))
+        tok = sorted_a // top_k
+        valid = lo + jnp.arange(rows) < ends[-1]
+        here = held & (pos >= lo) & (pos < lo + rows)
+        posc = jnp.clip(pos - lo, 0, rows - 1)
+        w_sorted = jnp.where(valid, w.reshape(-1)[sorted_a], 0.0)
+        clipped = jnp.clip(ends, lo, lo + rows)
+        counts = jnp.diff(clipped, prepend=lo).astype(jnp.int32)
+        # the room left goes to the last group: its rows weigh nothing
+        counts = counts.at[-1].add(lo + rows - clipped[-1])
+        xs = _take_rows(x, tok, posc, here)
+    with scope("moe_experts"):
+        gate = grouped_matmul(xs, experts["w_gate"], counts)
+        up = grouped_matmul(xs, experts["w_up"], counts)
+        act = jnp.where(valid[:, None], jax.nn.relu(gate) * up, 0)
+        ys = grouped_matmul(act, experts["w_down"], counts)
+    with scope("moe_dispatch"):
+        return _combine_rows(ys, w, tok, posc, here, w_sorted)
+
+
+def dropless_reglu_experts(x, idx, p, experts, experts_held, num_experts):
+    """``y[t] = sum over the choices j of token t whose expert idx[t, j]
+    is held here of p[t, j] * W_down,e (relu(W_gate,e x[t]) * (W_up,e x[t]))``.
+
+    x (T, H) in the compute dtype; idx, p (T, k) from
+    :func:`route_top_k`; ``experts`` {"w_gate", "w_up": (held, H, F),
+    "w_down": (held, F, H)} in the compute dtype; ``experts_held``
+    (first, count) of the ``num_experts`` the router scores. Returns
+    (y (T, H) float32, counts (held,) int32: the assignments that landed
+    on each held expert). No assignment is dropped whatever the
+    imbalance, and the time does not follow it. Traced under the scopes ``moe_route`` (the sort),
+    ``moe_dispatch`` (the permutes, the combine) and ``moe_experts`` (the
+    grouped products).
+    """
+    first, count = experts_held
+    t, top_k = idx.shape
+    rows = chunk_rows(t * top_k, count, num_experts)
+    with scope("moe_route"):
+        local = idx - first
+        held = (local >= 0) & (local < count)
+        key = jnp.where(held, local, count).reshape(-1)
+        counts = jnp.sum(key[:, None] == jnp.arange(count)[None, :],
+                         axis=0, dtype=jnp.int32)
+        ends = jnp.cumsum(counts)
+        # sorted position -> assignment (stable: token order inside an
+        # expert), and assignment -> sorted position
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        pos = jnp.argsort(order).astype(jnp.int32).reshape(t, top_k)
+        w = jnp.where(held, p, 0.0).astype(jnp.float32)
+    chunk = jax.checkpoint(functools.partial(_reglu_chunk, rows))
+
+    def turn(y, c):
+        part = chunk(c, x, w, experts, (order, pos, held, ends))
+        with scope("moe_dispatch"):
+            return y + part, None
+
+    y, _ = jax.lax.scan(turn, jnp.zeros(x.shape, jnp.float32),
+                        jnp.arange(-(-t * top_k // rows)))
+    return y, counts

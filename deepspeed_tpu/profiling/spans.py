@@ -49,6 +49,11 @@ DEVICE_SCOPES = (
     "embed", "ln", "attn_proj", "attn_core", "kv_write", "kv_gather",
     "attn_cached", "mlp", "weight_cast", "loss_head", "lm_head", "sample",
     "grad_clip", "loss_scale", "opt_update",
+    # a stack of mixed layer kinds (models/smallthinker.py): the kind of
+    # the layer AROUND `attn_core`, which stays innermost and so still
+    # covers the kernels of both kinds; and the routed expert layer
+    "attn_window", "attn_global",
+    "moe_route", "moe_dispatch", "moe_experts",
 )
 
 # host phase spans (TraceAnnotation), each parent before its children

@@ -728,6 +728,11 @@ class DeepSpeedEngine:
         self._micro_shd = None
         self._monitor_ring = []          # deferred loss/lr/scale records
         self._last_loss_device = None    # device scalar; last_loss() syncs
+        # what the loss fn returned beside its loss in the last
+        # train_batch: one pytree of device arrays a micro batch, outputs
+        # of the step program that nothing has waited for; None where the
+        # loss fn returns a bare loss
+        self.last_aux = None
         self._host_sync_count = 0        # forced device syncs (telemetry)
         self._host_gap_ms = None         # per-step host time outside dispatch
         # only a dynamic fp16 scaler's per-step scale must be snapshot
@@ -1668,31 +1673,38 @@ class DeepSpeedEngine:
     def _grads_for_micro(self, state: TrainState, batch, sub):
         """One micro batch's fwd+bwd, dispatched to the configured
         gradient-exchange path. Returns ``(loss, csr_overflow|None,
-        grads)`` — shared by the per-micro step, the facade
-        ``forward()``, and the fused batch step's scan body."""
+        grads, aux|None)`` — shared by the per-micro step, the facade
+        ``forward()``, and the fused batch step's scan body. ``aux`` is
+        what the loss fn returned beside its loss (the plain path only)."""
         scale = state.loss_scale.scale
         if self._onebit_dist:
             loss, _aux, grads = self._compute_local_grads(
                 state.params, batch, sub, scale)
         elif self._sparse_grad_paths:
-            return self._compute_sparse_grads(state.params, batch, sub,
-                                              scale)
+            return (*self._compute_sparse_grads(state.params, batch, sub,
+                                                scale), None)
         elif self._quant_allreduce:
             loss, _aux, grads = self._compute_quantized_grads(
                 state.params, batch, sub, scale)
         else:
-            loss, _aux, grads = self._compute_loss_and_grads(
+            loss, aux, grads = self._compute_loss_and_grads(
                 state.params, batch, sub, scale)
-        return loss, None, grads
+            return loss, None, grads, aux
+        return loss, None, grads, None
 
     def _micro_step(self, state: TrainState, batch) -> Tuple[TrainState, Any]:
         """One fused micro-batch step: fwd + bwd + accumulate + maybe-apply.
         Returns ``(state, loss)`` — or ``(state, (loss, csr_overflow))``
-        when the CSR sparse-gradient path is active."""
+        when the CSR sparse-gradient path is active, or ``(state, (loss,
+        aux))`` when the loss fn returns an aux beside its loss: it
+        leaves the program as an output and nothing waits for it
+        (:attr:`last_aux`)."""
         rng, sub = jax.random.split(state.rng)
-        loss, csr_ovf, grads = self._grads_for_micro(state, batch, sub)
+        loss, csr_ovf, grads, aux = self._grads_for_micro(state, batch, sub)
 
         out = loss if csr_ovf is None else (loss, csr_ovf)
+        if aux is not None:
+            out = (out, aux)
         if self.zero_cpu_offload and self.gradient_accumulation_steps == 1:
             # no accumulator: the compute-dtype grads are an OUTPUT of
             # the dispatch (half the D2H bytes of fp32 — the
@@ -1752,12 +1764,20 @@ class DeepSpeedEngine:
         # cond + apply graph per iteration) — parity with the per-micro
         # loop is structural, not re-derived
         state, losses = jax.lax.scan(self._micro_step, state, stacked)
+        aux = None
+        if isinstance(losses, tuple):       # the loss fn gives an aux
+            losses, aux = losses
         # left-fold mean in the loss dtype, matching the per-micro
         # loop's python-side accumulation
         total = losses[0]
         for i in range(1, gas):
             total = total + losses[i]
-        return state, total / gas
+        if aux is None:
+            return state, total / gas
+        # one aux a micro batch, as the per-micro loop keeps them
+        return state, (total / gas, [
+            jax.tree_util.tree_map(lambda a, i=i: a[i], aux)
+            for i in range(gas)])
 
     # -- comm_autotune: compute/comm overlap inside the fused window ------
     #
@@ -2127,7 +2147,8 @@ class DeepSpeedEngine:
                 # quantization OUTSIDE autodiff — differentiating
                 # through round() would zero the master gradients)
                 rng, sub = jax.random.split(state.rng)
-                loss, ovf, grads = self._grads_for_micro(state, batch, sub)
+                loss, ovf, grads, _ = self._grads_for_micro(state, batch,
+                                                            sub)
                 if ovf is not None:
                     return loss, grads, rng, ovf
                 return loss, grads, rng
@@ -2421,9 +2442,11 @@ class DeepSpeedEngine:
                 with self.observability.span("train/dispatch"):
                     self.state, mean_loss = step_fn(self.state, batch)
                 _t_dispatch = time.perf_counter() - _t0
+                if isinstance(mean_loss, tuple):
+                    mean_loss, self.last_aux = mean_loss
         else:
             step_fn = self._get_compiled_micro_step()
-            total = None
+            total, auxes = None, []
             offload_direct = (self.zero_cpu_offload and
                               self.gradient_accumulation_steps == 1)
             with self.observability.span("train_batch"):
@@ -2438,6 +2461,9 @@ class DeepSpeedEngine:
                         out, self._offload_grads_device = out
                     if self._sparse_grad_paths and not self._onebit_dist:
                         loss, self._csr_overflow = out
+                    elif isinstance(out, tuple):
+                        loss, aux = out
+                        auxes.append(aux)
                     else:
                         loss = out
                     total = loss if total is None else total + loss
@@ -2447,6 +2473,8 @@ class DeepSpeedEngine:
                     else:
                         self._host_apply_update()
             mean_loss = total / self.gradient_accumulation_steps
+            if auxes:
+                self.last_aux = auxes
         self.tput_timer.stop()
         self._last_step_time_ms = (time.perf_counter() - _t_step0) * 1e3
         # host time NOT spent inside a dispatch call: data wait + python

@@ -592,3 +592,104 @@ def test_serving_compiler_options_share_the_layers_code(monkeypatch):
         lowered.compile(compiler_options=o).memory_analysis()
         .generated_code_size_in_bytes for o in (None, options))
     assert shared < plain / 2
+
+
+# ---------------------------------------------------------------------------
+# SmallThinker-21BA3B-Instruct as one chip's share (ISSUE 32): the banded
+# causal mask at its head shape, the experts' grouped product at its
+# widths, and the whole ZeRO-2 train step of the cut configuration.
+SMALLTHINKER_Q = (1, 28, 8192, 128)
+SMALLTHINKER_KV = (1, 4, 8192, 128)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_banded_causal_mask_compiles_at_28_over_4_heads_of_128(direction):
+    """First model use of the banded causal BlockMask: a 4,096 window at
+    8,192 positions, 28 query heads in groups of 7 over 4 key-value
+    heads of 128, streamed tiles."""
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=4096,
+                               interpret=False)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *qkv: jnp.sum(fwd(*qkv).astype(jnp.float32)),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    q, kv = _spec(SMALLTHINKER_Q), _spec(SMALLTHINKER_KV)
+    compiled = _compile({"fwd": fwd, "bwd": bwd}[direction], q, kv, kv)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("k,n", [(2560, 768), (768, 2560)],
+                         ids=["gate_up", "down"])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_grouped_product_compiles_at_the_expert_widths(monkeypatch, k, n,
+                                                       direction):
+    """The experts' grouped product over 16 held experts, a 32,768-row
+    buffer: forward, and the two backward products."""
+    from deepspeed_tpu.ops import moe
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def fwd(lhs, rhs, sizes):
+        return moe.grouped_matmul(lhs, rhs, sizes)
+
+    def bwd(lhs, rhs, sizes):
+        return jax.grad(lambda a, b: jnp.sum(
+            fwd(a, b, sizes).astype(jnp.float32)), argnums=(0, 1))(lhs, rhs)
+
+    compiled = _compile({"fwd": fwd, "bwd": bwd}[direction],
+                        _spec((32768, k)), _spec((16, k, n)),
+                        _spec((16,), jnp.int32))
+    calls = compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    assert calls >= {"fwd": 1, "bwd": 2}[direction]
+
+
+def test_smallthinker_train_step_compiles_at_the_cut_widths(monkeypatch):
+    """`deepspeed_tpu.initialize` + the ONE compiled `_micro_step`
+    (ZeRO-2, bf16, Adam, clipping) of the benchmark's configuration at
+    its published widths and its 8,192 positions, compiled for one
+    described v5e: a global and a window layer (the configuration holds
+    one global and three alike window layers), 16 of 64 experts held,
+    4,096 vocabulary rows (the configuration holds 38,016) so that the
+    state this host must hold stays at 3 GB."""
+    import deepspeed_tpu
+    from deepspeed_tpu.models import smallthinker as st
+    from deepspeed_tpu.ops.attention import flash
+
+    cfg = st.SmallThinkerConfig(
+        num_layers=2, rope_layout=(0, 1), sliding_window_layout=(0, 1),
+        experts_held=(0, 16), vocab_held=(0, 4096))
+    shapes = jax.eval_shape(
+        lambda key: st.init_smallthinker_params(cfg, key),
+        jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda s: np.zeros(s.shape, np.float32), shapes)
+    device = _DEVICES[:1]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: device)
+    monkeypatch.setattr(jax, "device_put", lambda x, *a, **k: x)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(flash, "_use_pallas", lambda: True)
+    engine, *_ = deepspeed_tpu.initialize(
+        model=st.smallthinker_loss_fn(cfg), model_parameters=params,
+        config={"train_micro_batch_size_per_gpu": 1,
+                "gradient_accumulation_steps": 1, "bf16": {"enabled": True},
+                "optimizer": {"type": "Adam", "params": {"lr": 1.5e-4}},
+                "zero_optimization": {"stage": 2},
+                "gradient_clipping": 1.0, "steps_per_print": 1000,
+                "mesh": {"axes": {"data": 1}}})
+    del params
+    on_chip = SingleDeviceSharding(device[0])
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip),
+        engine.state)
+    batch = {"input_ids": jax.ShapeDtypeStruct((1, 8193), jnp.int32,
+                                               sharding=on_chip)}
+    compiled = jax.jit(engine._micro_step, donate_argnums=(0,)).lower(
+        state, batch).compile()
+    text = compiled.as_text()
+    # 2 layers x 3 attention kernels, and the grouped products of the
+    # expert layer's forward, its second run and its backward
+    assert text.count('custom_call_target="tpu_custom_call"') >= 6 + 2 * 12
+    assert "ragged-dot" not in text
+    # the step's counters leave the program: (layers, held) int32
+    assert "s32[2,16]" in text
