@@ -1068,6 +1068,10 @@ def _dense_block_mask(sq, sk, d, causal, window=None):
         else:
             bm = BlockMask.causal(sq, block) if causal else \
                 BlockMask.dense(sq, sk, block)
+        # how much of the walk the FULL tiles' loop takes
+        log_once(("masked-walk",) + key,
+                 f"masked_flash: seq ({sq}, {sk}) d={d} causal={causal} "
+                 f"window={window} walks {bm.describe()}")
         _DENSE_MASK_CACHE[key] = bm
     return bm
 

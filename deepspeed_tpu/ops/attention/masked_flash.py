@@ -18,10 +18,18 @@ Design (the PR 8 paged-decode recipe applied to training):
 - **Partial tiles mask in registers.** A mask item is FULL (every cell
   computed — the reference's block-level mask semantics) or PARTIAL: an
   elementwise predicate evaluated from iota arithmetic in registers —
-  the causal diagonal (``q_idx >= k_idx``) and/or the banded fine
-  structure (global prefix + sliding window at the layout's fine block
-  granularity). That is what lets a 128-fine-block Longformer layout
-  *walk 512-wide MXU tiles* with zero mask bytes from HBM.
+  the causal diagonal (``q_idx >= k_idx``), a window's far edge
+  (``q_idx - k_idx <= w``) and/or the banded fine structure (global
+  prefix + sliding window at the layout's fine block granularity).
+  That is what lets a 128-fine-block Longformer layout *walk 512-wide
+  MXU tiles* with zero mask bytes from HBM.
+- **The mask costs only where it cuts.** A row's items are listed one
+  run a kind, FULL first, and each run is a loop of its own whose body
+  is traced for that STATIC kind: the FULL body computes no index, no
+  predicate and no select (without a key mask no validity select
+  either: its scores are finite), a partial body only the comparisons
+  of its kind's bits. ``sm_scale`` is folded once a program into the
+  resident operand instead of multiplied into every score.
 - **Stream vs resident.** Below ``flash.STREAM_THRESHOLD`` the per-head
   K/V arrays ride as VMEM-resident blocked refs sliced at
   ``cols[i] * block``; at/above it they stay in HBM pre-tiled TRANSPOSED
@@ -76,10 +84,13 @@ __all__ = ["BlockMask", "masked_flash_attention", "masked_flash_cost",
 # terms may stack; finite bf16 scores never approach it)
 VALID_THRESH = -1e28
 
-# partial-tile predicate bits (BlockMask.kinds cell values)
+# partial-tile predicate bits (BlockMask.kinds cell values); a tile's
+# kind is static, and its loop body evaluates only its kind's bits
 KIND_FULL = 0          # every cell computed (block-level mask semantics)
 KIND_CAUSAL = 1        # elementwise q_idx >= k_idx (diagonal tiles)
 KIND_BAND = 2          # banded fine structure (global prefix + window)
+KIND_WINDOW = 4        # elementwise q_idx - k_idx <= w (a window's far
+#                        edge; element-exact bands only, fine block 1)
 
 # test hooks: force the streamed / resident K-V path regardless of
 # sequence length (None = auto by STREAM_THRESHOLD)
@@ -150,12 +161,13 @@ class BlockMask:
 
     ``active``: (Hm, nq, nk) bool — which (q-block, k-block) tiles are
     walked; ``kinds``: (Hm, nq, nk) uint8 bitmask over active tiles
-    (KIND_CAUSAL / KIND_BAND; 0 = full). ``Hm`` is 1 for head-uniform
-    masks (dense, causal, propagated sparse layouts — the common case,
-    and the only one the shard_map head wrap accepts) or the full head
-    count for per-head layouts. ``band`` carries the static fine
+    (KIND_CAUSAL / KIND_BAND / KIND_WINDOW; 0 = full). ``Hm`` is 1 for
+    head-uniform masks (dense, causal, propagated sparse layouts — the
+    common case, and the only one the shard_map head wrap accepts) or
+    the full head count for per-head layouts. ``band`` carries the static fine
     structure for KIND_BAND tiles:
-    ``(fine_block, w, g_r, g_c, causal_clip)`` in fine-block units.
+    ``(fine_block, w, g_r, g_c, causal_clip)`` in fine-block units
+    (KIND_WINDOW tiles read its ``w`` alone).
 
     Instances are immutable, hashable (usable as a ``custom_vjp``
     static argument) and cache their CSR/CSC walk metadata.
@@ -182,6 +194,9 @@ class BlockMask:
         # the layout's original block granularity (== block unless the
         # walk was coarsened); reporting only
         self.fine_block = int(fine_block or block)
+        # the kinds that occur, ascending (FULL first): one loop each
+        self.run_kinds = tuple(
+            int(x) for x in np.unique(kinds[active])) or (KIND_FULL,)
         self._key = (self.block, self.seq_q, self.seq_k, self.band,
                      active.tobytes(), kinds.tobytes())
         self._csr = None
@@ -211,19 +226,20 @@ class BlockMask:
                       block: int) -> "BlockMask":
         """Square causal mask inside a sliding window: query ``i`` sees
         key ``j`` where ``j <= i`` and ``i - j < window``, to the
-        element. Tiles wholly inside the band are FULL, tiles the
-        diagonal or the window's far edge cuts carry the band predicate
-        at a fine block of ONE element (``|i - j| <= window - 1`` under
-        the causal clip), tiles outside are never walked."""
+        element. Tiles wholly inside the band are FULL and tiles
+        outside are never walked; a tile carries only the comparison
+        that can cut it: the diagonal's the causal one, one the window's
+        far edge crosses ``i - j <= window - 1`` (a diagonal tile wider
+        than the window both)."""
         assert window >= 1, window
         nb = seq // block
         d = np.arange(nb)[:, None] - np.arange(nb)[None, :]
         # a tile's pairs span i - j in [d*block - (block-1), d*block + block-1]
         active = (d >= 0) & (d * block - (block - 1) <= window - 1)
-        full = (d >= 1) & (d * block + (block - 1) <= window - 1)
-        kinds = np.where(active & ~full, KIND_BAND, KIND_FULL
-                         ).astype(np.uint8)
-        return cls(active[None], kinds[None], block, seq, seq,
+        kinds = (np.where(d == 0, KIND_CAUSAL, 0)
+                 | np.where(d * block + (block - 1) > window - 1,
+                            KIND_WINDOW, 0)).astype(np.uint8)
+        return cls(active[None], (kinds * active)[None], block, seq, seq,
                    band=(1, window - 1, 0, 0, True), fine_block=1)
 
     @classmethod
@@ -321,39 +337,43 @@ class BlockMask:
 
     @property
     def has_partials(self) -> bool:
-        return bool((self.kinds[self.active] != 0).any())
+        return self.run_kinds != (KIND_FULL,)
+
+    @property
+    def n_full(self) -> int:
+        return int((self.active & (self.kinds == KIND_FULL)).sum())
 
     def csr(self):
-        """(offs, cnts, cols, kinds) flattened over rows mh * nq + r."""
+        """(offs, ends, cols) over rows mh * nq + r. A row's items are
+        ``cols[offs[row]:offs[row] + ends[-1, row]]``, ordered one run a
+        kind of ``run_kinds`` (FULL first, ascending columns inside a
+        run); run ``i`` of the row ends at item ``ends[i, row]``."""
         if self._csr is None:
             self._csr = self._runs(self.active, self.kinds)
         return self._csr
 
     def csc(self):
-        """(offs, cnts, rows, kinds) flattened over cols mh * nk + c —
-        the column-major walk the dk/dv pass follows."""
+        """(offs, ends, rows) over cols mh * nk + c — the column-major
+        walk the dk/dv pass follows, in the same runs."""
         if self._csc is None:
             self._csc = self._runs(
                 np.ascontiguousarray(self.active.transpose(0, 2, 1)),
                 np.ascontiguousarray(self.kinds.transpose(0, 2, 1)))
         return self._csc
 
-    @staticmethod
-    def _runs(active, kinds):
-        offs, cnts, idxs, iks = [], [], [], []
-        off = 0
+    def _runs(self, active, kinds):
         H, nr, _ = active.shape
+        offs, idxs = [], []
+        ends = np.zeros((len(self.run_kinds), H * nr), np.int32)
         for h in range(H):
             for r in range(nr):
-                nz = np.nonzero(active[h, r])[0]
-                offs.append(off)
-                cnts.append(len(nz))
-                idxs.extend(int(c) for c in nz)
-                iks.extend(int(kinds[h, r, c]) for c in nz)
-                off += len(nz)
-        return (np.asarray(offs, np.int32), np.asarray(cnts, np.int32),
-                np.asarray(idxs if idxs else [0], np.int32),
-                np.asarray(iks if iks else [0], np.int32))
+                offs.append(len(idxs))
+                for i, kind in enumerate(self.run_kinds):
+                    idxs.extend(np.nonzero(
+                        active[h, r] & (kinds[h, r] == kind))[0].tolist())
+                    ends[i, h * nr + r] = len(idxs) - offs[-1]
+        return (np.asarray(offs, np.int32), ends,
+                np.asarray(idxs if idxs else [0], np.int32))
 
     def dense_additive(self) -> np.ndarray:
         """(Hm, Sq, Sk) additive 0 / NEG_INF expansion — the oracle view
@@ -372,11 +392,15 @@ class BlockMask:
             if clip:
                 ok &= kf <= qf
             keep &= ~((kinds & KIND_BAND).astype(bool)) | ok
+        if (kinds & KIND_WINDOW).any():
+            keep &= ~((kinds & KIND_WINDOW).astype(bool)) | (
+                qi - ki <= self.band[1])
         return np.where(keep, 0.0, NEG_INF).astype(np.float32)
 
     def describe(self) -> str:
         s = f"masked(block={self.block}, nnz={self.nnz}/" \
-            f"{self.heads * self.nq * self.nk}"
+            f"{self.heads * self.nq * self.nk}, full={self.n_full}, " \
+            f"partial={self.nnz - self.n_full}"
         if self.block != self.fine_block:
             s += f", coarsened from {self.fine_block}"
         return s + ")"
@@ -475,24 +499,75 @@ def _tile_idx(q0, k0, bq, bk):
 
 
 def _partial_keep(kind, q_idx, k_idx, band):
-    """Elementwise keep for a walked tile: FULL items (kind == 0) keep
-    everything; the causal bit clips to q_idx >= k_idx; the band bit
-    applies the fine-block structure (global prefix | window, plus the
-    layout's own block-level causal clip).
-
-    Plain boolean algebra on the kind bit: Mosaic cannot legalize a
-    select between two i1 vectors, which ``jnp.where(bit, pred, True)``
-    lowers to."""
-    keep = (q_idx >= k_idx) | ((kind & KIND_CAUSAL) == 0)
-    if band is not None:
+    """Elementwise keep of a walked tile of STATIC ``kind`` (never
+    FULL): only the comparisons of the kind's bits are traced. The
+    causal bit clips to q_idx >= k_idx; the window bit to
+    q_idx - k_idx <= w; the band bit applies the fine-block structure
+    (global prefix | window, plus the layout's own block-level causal
+    clip)."""
+    keep = None
+    if kind & KIND_CAUSAL:
+        keep = q_idx >= k_idx
+    if kind & KIND_WINDOW:
+        assert band[0] == 1, band      # element-exact bands only
+        ok = q_idx <= k_idx + band[1]
+        keep = ok if keep is None else keep & ok
+    if kind & KIND_BAND:
         fb, w, g_r, g_c, clip = band
         qf = q_idx // fb
         kf = k_idx // fb
         ok = (qf < g_r) | (kf < g_c) | (jnp.abs(qf - kf) <= w)
         if clip:
             ok &= kf <= qf
-        keep = keep & (ok | ((kind & KIND_BAND) == 0))
+        keep = ok if keep is None else keep & ok
     return keep
+
+
+def _cut_scores(s, kind, q0, k0, block, band, dropout_rate):
+    """A tile's scores with what its STATIC kind cuts set to NEG_INF,
+    and the tile's indices where a cut tile or dropout needs them: a
+    FULL tile without dropout computes neither."""
+    if kind == KIND_FULL and dropout_rate == 0.0:
+        return s, None, None
+    q_idx, k_idx = _tile_idx(q0, k0, block, block)
+    if kind != KIND_FULL:
+        s = jnp.where(_partial_keep(kind, q_idx, k_idx, band), s, NEG_INF)
+    return s, q_idx, k_idx
+
+
+def _walk(ends_ref, row, n_rows, run_kinds, make_body):
+    """A row's items in its runs: one ``fori_loop`` a kind, FULL first,
+    each with the body of its static kind (online softmax does not care
+    in which order the tiles come). The item index runs on through the
+    loops, so the streamed path's slot parity does too. The loops carry
+    nothing: the accumulators live in VMEM scratch, so a second loop
+    costs no copy of them at its boundaries (at 512 x 128 float32 they
+    are 64 vector registers' worth each, all there are)."""
+    lo = 0
+    for i, kind in enumerate(run_kinds):
+        hi = ends_ref[i * n_rows + row]
+        jax.lax.fori_loop(lo, hi, make_body(kind), None)
+        lo = hi
+
+
+# the running max and sum are kept replicated over a vector register's
+# lanes: a (rows, 1) column would be broadcast again at every use
+_LANES = 128
+
+
+def _lanes(x, n):
+    """A lane-replicated (rows, 128) statistic at ``n`` lanes."""
+    if n <= _LANES:
+        return x[:, :n]
+    if n % _LANES == 0:
+        return pltpu.repeat(x, n // _LANES, axis=1)
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _fold_scale(x, sm_scale):
+    # sm_scale once a program on the resident operand, not on every
+    # score: multiplied in float32, cast back to what the MXU takes
+    return (x.astype(jnp.float32) * sm_scale).astype(x.dtype)
 
 
 def _dma(src, row, c, buf, slot, sem):
@@ -515,20 +590,21 @@ def _drop_kpm(kernel, n_before):
 # --------------------------------------------------------------------- #
 # kernels
 # --------------------------------------------------------------------- #
-def _mf_fwd_kernel(offs_ref, cnts_ref, cols_ref, kinds_ref, seed_ref,
+def _mf_fwd_kernel(offs_ref, ends_ref, cols_ref, seed_ref,
                    q_ref, k_ref, v_ref, kpm_ref, o_ref, lse_ref,
                    *scratch, sm_scale, block, H, Hkv, Hm, nq, seq_k,
-                   band, has_partials, dropout_rate, stream):
+                   band, run_kinds, dropout_rate, stream):
+    m_ref, l_ref, acc_ref = scratch[:3]
     if stream:
-        kbuf, vbuf, ksem, vsem = scratch
+        kbuf, vbuf, ksem, vsem = scratch[3:]
     i = pl.program_id(0)                       # b * H + h
     j = pl.program_id(1)                       # q block
     h = jax.lax.rem(i, H)
     row = jax.lax.rem(h, Hm) * nq + j
-    n = cnts_ref[row]
+    n = ends_ref[(len(run_kinds) - 1) * Hm * nq + row]
     base = offs_ref[row]
     kv_row = (i // H) * Hkv + h // (H // Hkv)
-    q = q_ref[0]                               # (block, D)
+    q = _fold_scale(q_ref[0], sm_scale)        # (block, D)
     d = q.shape[-1]
 
     if stream:
@@ -538,80 +614,89 @@ def _mf_fwd_kernel(offs_ref, cnts_ref, cols_ref, kinds_ref, seed_ref,
             _dma(k_ref, kv_row, c0, kbuf, 0, ksem).start()
             _dma(v_ref, kv_row, c0, vbuf, 0, vsem).start()
 
-    def body(t, carry):
-        m, l, acc = carry
-        c = cols_ref[base + t]
-        kind = kinds_ref[base + t]
-        if stream:
-            @pl.when(t + 1 < n)
-            def _prefetch_next():
-                cn = cols_ref[base + t + 1]
-                slot = jax.lax.rem(t + 1, 2)
-                _dma(k_ref, kv_row, cn, kbuf, slot, ksem).start()
-                _dma(v_ref, kv_row, cn, vbuf, slot, vsem).start()
-            slot = jax.lax.rem(t, 2)
-            _dma(k_ref, kv_row, c, kbuf, slot, ksem).wait()
-            _dma(v_ref, kv_row, c, vbuf, slot, vsem).wait()
-            k, v = kbuf[slot], vbuf[slot]      # transposed: (D, block)
-        else:
-            k = k_ref[0, pl.ds(c * block, block), :]
-            v = v_ref[0, pl.ds(c * block, block), :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (0 if stream else 1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s = s * sm_scale
-        if kpm_ref is not None:
-            s += kpm_ref[0, 0, pl.ds(c * block, block)][None, :]
-        if has_partials or dropout_rate > 0.0:
-            q_idx, k_idx = _tile_idx(j * block, c * block, block, block)
-        if has_partials:
-            s = jnp.where(_partial_keep(kind, q_idx, k_idx, band), s,
-                          NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        m_safe = jnp.where(m_new <= VALID_THRESH, 0.0, m_new)
-        alpha = jnp.exp(m - m_new)
-        # exact-zero probability for structurally masked cells; rows
-        # with no valid entry keep l == 0 and fall out as zero output
-        p = jnp.where(s > VALID_THRESH, jnp.exp(s - m_safe[:, None]), 0.0)
-        l_new = l * alpha + jnp.sum(p, axis=-1)
-        if dropout_rate > 0.0:
-            keep = dropout_keep_mask(seed_ref[0], i, q_idx, k_idx,
-                                     seq_k, dropout_rate)
-            p = jnp.where(keep, p, 0.0)
-        acc_new = acc * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (1 if stream else 0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
+    def make_body(kind):
+        # a FULL tile's scores are finite unless a key mask empties a
+        # row, so only a cut tile or a key mask needs the validity select
+        guard = kind != KIND_FULL or kpm_ref is not None
 
-    m0 = jnp.full((block,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block,), jnp.float32)
-    acc0 = jnp.zeros((block, d), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n, body, (m0, l0, acc0))
+        def body(t, _):
+            m, l = m_ref[...], l_ref[...]
+            c = cols_ref[base + t]
+            if stream:
+                @pl.when(t + 1 < n)
+                def _prefetch_next():
+                    cn = cols_ref[base + t + 1]
+                    slot = jax.lax.rem(t + 1, 2)
+                    _dma(k_ref, kv_row, cn, kbuf, slot, ksem).start()
+                    _dma(v_ref, kv_row, cn, vbuf, slot, vsem).start()
+                slot = jax.lax.rem(t, 2)
+                _dma(k_ref, kv_row, c, kbuf, slot, ksem).wait()
+                _dma(v_ref, kv_row, c, vbuf, slot, vsem).wait()
+                k, v = kbuf[slot], vbuf[slot]  # transposed: (D, block)
+            else:
+                k = k_ref[0, pl.ds(c * block, block), :]
+                v = v_ref[0, pl.ds(c * block, block), :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (0 if stream else 1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if kpm_ref is not None:
+                s += kpm_ref[0, 0, pl.ds(c * block, block)][None, :]
+            s, q_idx, k_idx = _cut_scores(
+                s, kind, j * block, c * block, block, band, dropout_rate)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            if guard:
+                # exact-zero probability for structurally masked cells;
+                # rows with no valid entry keep l == 0 and fall out as
+                # zero output
+                m_safe = jnp.where(m_new <= VALID_THRESH, 0.0, m_new)
+                p = jnp.where(s > VALID_THRESH,
+                              jnp.exp(s - _lanes(m_safe, block)), 0.0)
+            else:
+                p = jnp.exp(s - _lanes(m_new, block))
+            m_ref[...] = m_new
+            l_ref[...] = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            if dropout_rate > 0.0:
+                keep = dropout_keep_mask(seed_ref[0], i, q_idx, k_idx,
+                                         seq_k, dropout_rate)
+                p = jnp.where(keep, p, 0.0)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v,
+                (((1,), (1 if stream else 0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc_ref[...] = acc_ref[...] * _lanes(alpha, d) + pv
+        return body
+
+    m_ref[...] = jnp.full((block, _LANES), NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros((block, _LANES), jnp.float32)
+    acc_ref[...] = jnp.zeros((block, d), jnp.float32)
+    _walk(ends_ref, row, Hm * nq, run_kinds, make_body)
+    m, l = m_ref[:, :1], l_ref[:, :1]
     l_safe = jnp.where(l == 0.0, 1.0, l)
-    out = acc / l_safe[:, None]
+    out = acc_ref[...] / l_safe
     if dropout_rate > 0.0:
         out = out * (1.0 / (1.0 - dropout_rate))
     o_ref[0] = out.astype(o_ref.dtype)
-    lse_ref[0, :, 0] = jnp.where(l == 0.0, NEG_INF,
-                                 jnp.where(m <= VALID_THRESH, 0.0, m)
-                                 + jnp.log(l_safe))
+    lse_ref[0] = jnp.where(l == 0.0, NEG_INF,
+                           jnp.where(m <= VALID_THRESH, 0.0, m)
+                           + jnp.log(l_safe))
 
 
-def _mf_dq_kernel(offs_ref, cnts_ref, cols_ref, kinds_ref, seed_ref,
+def _mf_dq_kernel(offs_ref, ends_ref, cols_ref, seed_ref,
                   q_ref, k_ref, v_ref, kpm_ref, do_ref, lse_ref,
                   delta_ref, dq_ref, *scratch, sm_scale, block, H, Hkv,
-                  Hm, nq, seq_k, band, has_partials, dropout_rate,
-                  stream):
+                  Hm, nq, seq_k, band, run_kinds, dropout_rate, stream):
+    acc_ref = scratch[0]
     if stream:
-        kbuf, vbuf, ksem, vsem = scratch
+        kbuf, vbuf, ksem, vsem = scratch[1:]
     i = pl.program_id(0)
     j = pl.program_id(1)
     h = jax.lax.rem(i, H)
     row = jax.lax.rem(h, Hm) * nq + j
-    n = cnts_ref[row]
+    n = ends_ref[(len(run_kinds) - 1) * Hm * nq + row]
     base = offs_ref[row]
     kv_row = (i // H) * Hkv + h // (H // Hkv)
-    q = q_ref[0]
+    q = _fold_scale(q_ref[0], sm_scale)
     do = do_ref[0]
     lse = lse_ref[0, :, 0]
     delta = delta_ref[0, :, 0]
@@ -624,65 +709,70 @@ def _mf_dq_kernel(offs_ref, cnts_ref, cols_ref, kinds_ref, seed_ref,
             _dma(k_ref, kv_row, c0, kbuf, 0, ksem).start()
             _dma(v_ref, kv_row, c0, vbuf, 0, vsem).start()
 
-    def body(t, dq):
-        c = cols_ref[base + t]
-        kind = kinds_ref[base + t]
-        if stream:
-            @pl.when(t + 1 < n)
-            def _prefetch_next():
-                cn = cols_ref[base + t + 1]
-                slot = jax.lax.rem(t + 1, 2)
-                _dma(k_ref, kv_row, cn, kbuf, slot, ksem).start()
-                _dma(v_ref, kv_row, cn, vbuf, slot, vsem).start()
-            slot = jax.lax.rem(t, 2)
-            _dma(k_ref, kv_row, c, kbuf, slot, ksem).wait()
-            _dma(v_ref, kv_row, c, vbuf, slot, vsem).wait()
-            k, v = kbuf[slot], vbuf[slot]      # transposed: (D, block)
-        else:
-            k = k_ref[0, pl.ds(c * block, block), :]
-            v = v_ref[0, pl.ds(c * block, block), :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (0 if stream else 1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s = s * sm_scale
-        if kpm_ref is not None:
-            s += kpm_ref[0, 0, pl.ds(c * block, block)][None, :]
-        if has_partials or dropout_rate > 0.0:
-            q_idx, k_idx = _tile_idx(j * block, c * block, block, block)
-        if has_partials:
-            s = jnp.where(_partial_keep(kind, q_idx, k_idx, band), s,
-                          NEG_INF)
-        p = jnp.where(s > VALID_THRESH, jnp.exp(s - lse[:, None]), 0.0)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (0 if stream else 1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if dropout_rate > 0.0:
-            keep = dropout_keep_mask(seed_ref[0], i, q_idx, k_idx,
-                                     seq_k, dropout_rate)
-            dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_rate)), 0.0)
-        ds = p * (dp - delta[:, None])
-        return dq + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (1 if stream else 0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def make_body(kind):
+        guard = kind != KIND_FULL or kpm_ref is not None
 
-    dq = jax.lax.fori_loop(0, n, body, jnp.zeros((block, d), jnp.float32))
-    dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
+        def body(t, _):
+            c = cols_ref[base + t]
+            if stream:
+                @pl.when(t + 1 < n)
+                def _prefetch_next():
+                    cn = cols_ref[base + t + 1]
+                    slot = jax.lax.rem(t + 1, 2)
+                    _dma(k_ref, kv_row, cn, kbuf, slot, ksem).start()
+                    _dma(v_ref, kv_row, cn, vbuf, slot, vsem).start()
+                slot = jax.lax.rem(t, 2)
+                _dma(k_ref, kv_row, c, kbuf, slot, ksem).wait()
+                _dma(v_ref, kv_row, c, vbuf, slot, vsem).wait()
+                k, v = kbuf[slot], vbuf[slot]  # transposed: (D, block)
+            else:
+                k = k_ref[0, pl.ds(c * block, block), :]
+                v = v_ref[0, pl.ds(c * block, block), :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (0 if stream else 1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if kpm_ref is not None:
+                s += kpm_ref[0, 0, pl.ds(c * block, block)][None, :]
+            s, q_idx, k_idx = _cut_scores(
+                s, kind, j * block, c * block, block, band, dropout_rate)
+            p = jnp.exp(s - lse[:, None])
+            if guard:
+                p = jnp.where(s > VALID_THRESH, p, 0.0)
+            dp = jax.lax.dot_general(
+                do, v, (((1,), (0 if stream else 1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if dropout_rate > 0.0:
+                keep = dropout_keep_mask(seed_ref[0], i, q_idx, k_idx,
+                                         seq_k, dropout_rate)
+                dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_rate)),
+                               0.0)
+            ds = p * (dp - delta[:, None])
+            acc_ref[...] += jax.lax.dot_general(
+                ds.astype(k.dtype), k,
+                (((1,), (1 if stream else 0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        return body
+
+    acc_ref[...] = jnp.zeros((block, d), jnp.float32)
+    _walk(ends_ref, row, Hm * nq, run_kinds, make_body)
+    dq_ref[0] = (acc_ref[...] * sm_scale).astype(dq_ref.dtype)
 
 
-def _mf_dkv_kernel(coffs_ref, ccnts_ref, crows_ref, ckinds_ref, seed_ref,
+def _mf_dkv_kernel(coffs_ref, cends_ref, crows_ref, seed_ref,
                    q_ref, k_ref, v_ref, kpm_ref, do_ref, lse_ref,
                    delta_ref, dk_ref, dv_ref, *scratch, sm_scale, block,
-                   H, Hm, nk, seq_k, band, has_partials, dropout_rate,
+                   H, Hm, nk, seq_k, band, run_kinds, dropout_rate,
                    stream):
+    dk_acc, dv_acc = scratch[:2]
     if stream:
-        qbuf, dobuf, qsem, dosem = scratch
+        qbuf, dobuf, qsem, dosem = scratch[2:]
     i = pl.program_id(0)                       # b * H + h (q heads)
     jb = pl.program_id(1)                      # k block
     h = jax.lax.rem(i, H)
     col = jax.lax.rem(h, Hm) * nk + jb
-    n = ccnts_ref[col]
+    n = cends_ref[(len(run_kinds) - 1) * Hm * nk + col]
     base = coffs_ref[col]
-    k = k_ref[0]                               # (block, D)
+    k = _fold_scale(k_ref[0], sm_scale)        # (block, D)
     v = v_ref[0]
     d = k.shape[-1]
     kpm_row = (kpm_ref[0, 0, pl.ds(jb * block, block)]
@@ -695,64 +785,64 @@ def _mf_dkv_kernel(coffs_ref, ccnts_ref, crows_ref, ckinds_ref, seed_ref,
             _dma(q_ref, i, r0, qbuf, 0, qsem).start()
             _dma(do_ref, i, r0, dobuf, 0, dosem).start()
 
-    def body(t, carry):
-        dk, dv = carry
-        rq = crows_ref[base + t]
-        kind = ckinds_ref[base + t]
-        if stream:
-            @pl.when(t + 1 < n)
-            def _prefetch_next():
-                rn = crows_ref[base + t + 1]
-                slot = jax.lax.rem(t + 1, 2)
-                _dma(q_ref, i, rn, qbuf, slot, qsem).start()
-                _dma(do_ref, i, rn, dobuf, slot, dosem).start()
-            slot = jax.lax.rem(t, 2)
-            _dma(q_ref, i, rq, qbuf, slot, qsem).wait()
-            _dma(do_ref, i, rq, dobuf, slot, dosem).wait()
-            q, do = qbuf[slot], dobuf[slot]    # transposed: (D, block)
-        else:
-            q = q_ref[0, pl.ds(rq * block, block), :]
-            do = do_ref[0, pl.ds(rq * block, block), :]
-        lse = lse_ref[0, 0, pl.ds(rq * block, block)]
-        delta = delta_ref[0, 0, pl.ds(rq * block, block)]
-        s = jax.lax.dot_general(
-            q, k, (((0 if stream else 1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (bq, bk)
-        s = s * sm_scale
-        if kpm_row is not None:
-            s += kpm_row[None, :]
-        if has_partials or dropout_rate > 0.0:
-            q_idx, k_idx = _tile_idx(rq * block, jb * block, block, block)
-        if has_partials:
-            s = jnp.where(_partial_keep(kind, q_idx, k_idx, band), s,
-                          NEG_INF)
-        p = jnp.where(s > VALID_THRESH, jnp.exp(s - lse[:, None]), 0.0)
-        dp = jax.lax.dot_general(
-            do, v, (((0 if stream else 1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (bq, bk)
-        if dropout_rate > 0.0:
-            keep = dropout_keep_mask(seed_ref[0], i, q_idx, k_idx,
-                                     seq_k, dropout_rate)
-            inv_kp = 1.0 / (1.0 - dropout_rate)
-            pd = jnp.where(keep, p * inv_kp, 0.0)
-            dp = jnp.where(keep, dp * inv_kp, 0.0)
-        else:
-            pd = p
-        dv_new = dv + jax.lax.dot_general(
-            pd.astype(do.dtype), do,
-            (((0,), (1 if stream else 0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (bk, D)
-        ds = p * (dp - delta[:, None])
-        dk_new = dk + jax.lax.dot_general(
-            ds.astype(q.dtype), q,
-            (((0,), (1 if stream else 0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (bk, D)
-        return dk_new, dv_new
+    def make_body(kind):
+        guard = kind != KIND_FULL or kpm_ref is not None
 
-    z = jnp.zeros((block, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(0, n, body, (z, z))
-    dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+        def body(t, _):
+            rq = crows_ref[base + t]
+            if stream:
+                @pl.when(t + 1 < n)
+                def _prefetch_next():
+                    rn = crows_ref[base + t + 1]
+                    slot = jax.lax.rem(t + 1, 2)
+                    _dma(q_ref, i, rn, qbuf, slot, qsem).start()
+                    _dma(do_ref, i, rn, dobuf, slot, dosem).start()
+                slot = jax.lax.rem(t, 2)
+                _dma(q_ref, i, rq, qbuf, slot, qsem).wait()
+                _dma(do_ref, i, rq, dobuf, slot, dosem).wait()
+                q, do = qbuf[slot], dobuf[slot]    # transposed: (D, block)
+            else:
+                q = q_ref[0, pl.ds(rq * block, block), :]
+                do = do_ref[0, pl.ds(rq * block, block), :]
+            lse = lse_ref[0, 0, pl.ds(rq * block, block)]
+            delta = delta_ref[0, 0, pl.ds(rq * block, block)]
+            s = jax.lax.dot_general(
+                q, k, (((0 if stream else 1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)        # (bq, bk)
+            if kpm_row is not None:
+                s += kpm_row[None, :]
+            s, q_idx, k_idx = _cut_scores(
+                s, kind, rq * block, jb * block, block, band, dropout_rate)
+            p = jnp.exp(s - lse[:, None])
+            if guard:
+                p = jnp.where(s > VALID_THRESH, p, 0.0)
+            dp = jax.lax.dot_general(
+                do, v, (((0 if stream else 1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)        # (bq, bk)
+            if dropout_rate > 0.0:
+                keep = dropout_keep_mask(seed_ref[0], i, q_idx, k_idx,
+                                         seq_k, dropout_rate)
+                inv_kp = 1.0 / (1.0 - dropout_rate)
+                pd = jnp.where(keep, p * inv_kp, 0.0)
+                dp = jnp.where(keep, dp * inv_kp, 0.0)
+            else:
+                pd = p
+            dv_acc[...] += jax.lax.dot_general(
+                pd.astype(do.dtype), do,
+                (((0,), (1 if stream else 0,)), ((), ())),
+                preferred_element_type=jnp.float32)        # (bk, D)
+            ds = p * (dp - delta[:, None])
+            dk_acc[...] += jax.lax.dot_general(
+                ds.astype(q.dtype), q,
+                (((0,), (1 if stream else 0,)), ((), ())),
+                preferred_element_type=jnp.float32)        # (bk, D)
+        return body
+
+    dk_acc[...] = jnp.zeros((block, d), jnp.float32)
+    dv_acc[...] = jnp.zeros((block, d), jnp.float32)
+    _walk(cends_ref, col, Hm * nk, run_kinds, make_body)
+    dk_ref[0] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+    dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 # --------------------------------------------------------------------- #
@@ -779,8 +869,20 @@ def _use_stream(mask: BlockMask, interpret: bool) -> bool:
 def _kernel_statics(mask: BlockMask, H, Hkv, sm_scale, rate, stream):
     return dict(sm_scale=sm_scale, block=mask.block, H=H, Hkv=Hkv,
                 Hm=mask.heads, nq=mask.nq, seq_k=mask.seq_k,
-                band=mask.band, has_partials=mask.has_partials,
+                band=mask.band, run_kinds=mask.run_kinds,
                 dropout_rate=rate, stream=stream)
+
+
+def _walk_scalars(runs, seed):
+    # the scalar-prefetched walk: a row's offset, its runs' ends
+    # (flattened (run, row)), the items; then the dropout seed
+    offs, ends, idxs = runs
+    return [jnp.asarray(offs), jnp.asarray(ends.reshape(-1)),
+            jnp.asarray(idxs), seed.reshape(1).astype(jnp.int32)]
+
+
+def _f32_scratch(*shapes):
+    return [pltpu.VMEM(shape, jnp.float32) for shape in shapes]
 
 
 def _stream_scratch(d, block, dt_a, dt_b):
@@ -807,7 +909,7 @@ def _masked_fwd(q, k, v, kpm, seed, mask, sm_scale, interpret, rate,
         _mf_fwd_kernel, **_kernel_statics(mask, h, hkv, sm_scale, rate,
                                           stream))
     if not has_kpm:
-        kernel = _drop_kpm(kernel, 8)       # 5 scalars + q, k, v
+        kernel = _drop_kpm(kernel, 7)       # 4 scalars + q, k, v
     if stream:
         kv_spec = pl.BlockSpec(memory_space=pltpu.HBM)
         kr = _stream_layout(kr, blk)
@@ -825,9 +927,7 @@ def _masked_fwd(q, k, v, kpm, seed, mask, sm_scale, interpret, rate,
         in_specs.append(
             pl.BlockSpec((1, 1, sk), lambda i, j, *_: (i // h, 0, 0)))
         args.append(kpm.reshape(b, 1, sk))
-    offs, cnts, cols, kinds = mask.csr()
-    scalars = [jnp.asarray(offs), jnp.asarray(cnts), jnp.asarray(cols),
-               jnp.asarray(kinds), seed.reshape(1).astype(jnp.int32)]
+    scalars = _walk_scalars(mask.csr(), seed)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(b * h, mask.nq),
@@ -836,8 +936,10 @@ def _masked_fwd(q, k, v, kpm, seed, mask, sm_scale, interpret, rate,
             pl.BlockSpec((1, blk, d), lambda i, j, *_: (i, j, 0)),
             pl.BlockSpec((1, blk, 1), lambda i, j, *_: (i, j, 0)),
         ],
-        scratch_shapes=_stream_scratch(d, blk, k.dtype, v.dtype)
-        if stream else [])
+        # running max and sum (lane-replicated), the output's
+        # accumulator; then the streamed path's tiles
+        scratch_shapes=_f32_scratch((blk, _LANES), (blk, _LANES), (blk, d))
+        + (_stream_scratch(d, blk, k.dtype, v.dtype) if stream else []))
     o, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -878,7 +980,7 @@ def _masked_bwd(res, g, mask, sm_scale, interpret, rate,
         _mf_dq_kernel, **_kernel_statics(mask, h, hkv, sm_scale, rate,
                                          stream))
     if not has_kpm:
-        kernel = _drop_kpm(kernel, 8)       # 5 scalars + q, k, v
+        kernel = _drop_kpm(kernel, 7)       # 4 scalars + q, k, v
     if stream:
         kv_spec = pl.BlockSpec(memory_space=pltpu.HBM)
         k_arg, v_arg = _stream_layout(kr, blk), _stream_layout(vr, blk)
@@ -889,9 +991,7 @@ def _masked_bwd(res, g, mask, sm_scale, interpret, rate,
         k_arg, v_arg = kr, vr
     row_spec = pl.BlockSpec((1, blk, d), lambda i, j, *_: (i, j, 0))
     row_vec = pl.BlockSpec((1, blk, 1), lambda i, j, *_: (i, j, 0))
-    offs, cnts, cols, kinds = mask.csr()
-    scalars = [jnp.asarray(offs), jnp.asarray(cnts), jnp.asarray(cols),
-               jnp.asarray(kinds), seed.reshape(1).astype(jnp.int32)]
+    scalars = _walk_scalars(mask.csr(), seed)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(b * h, mask.nq),
@@ -899,8 +999,8 @@ def _masked_bwd(res, g, mask, sm_scale, interpret, rate,
             pl.BlockSpec((1, 1, sk), lambda i, j, *_: (i // h, 0, 0))]
             if has_kpm else []) + [row_spec, row_vec, row_vec],
         out_specs=row_spec,
-        scratch_shapes=_stream_scratch(d, blk, k.dtype, v.dtype)
-        if stream else [])
+        scratch_shapes=_f32_scratch((blk, d))               # dq
+        + (_stream_scratch(d, blk, k.dtype, v.dtype) if stream else []))
     dq = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -912,10 +1012,10 @@ def _masked_bwd(res, g, mask, sm_scale, interpret, rate,
     # ---- dk, dv (CSC column walk, per-q-head partials) ----
     kernel = functools.partial(
         _mf_dkv_kernel, sm_scale=sm_scale, block=blk, H=h, Hm=mask.heads,
-        nk=mask.nk, seq_k=sk, band=mask.band,
-        has_partials=mask.has_partials, dropout_rate=rate, stream=stream)
+        nk=mask.nk, seq_k=sk, band=mask.band, run_kinds=mask.run_kinds,
+        dropout_rate=rate, stream=stream)
     if not has_kpm:
-        kernel = _drop_kpm(kernel, 8)       # 5 scalars + q, k, v
+        kernel = _drop_kpm(kernel, 7)       # 4 scalars + q, k, v
     if stream:
         q_spec = pl.BlockSpec(memory_space=pltpu.HBM)
         q_arg, do_arg = _stream_layout(qr, blk), _stream_layout(dor, blk)
@@ -925,9 +1025,7 @@ def _masked_bwd(res, g, mask, sm_scale, interpret, rate,
     col_spec = pl.BlockSpec(
         (1, blk, d),
         lambda i, j, *_: ((i // h) * hkv + (i % h) // G, j, 0))
-    coffs, ccnts, crows, ckinds = mask.csc()
-    scalars = [jnp.asarray(coffs), jnp.asarray(ccnts), jnp.asarray(crows),
-               jnp.asarray(ckinds), seed.reshape(1).astype(jnp.int32)]
+    scalars = _walk_scalars(mask.csc(), seed)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(b * h, mask.nk),
@@ -944,8 +1042,8 @@ def _masked_bwd(res, g, mask, sm_scale, interpret, rate,
             pl.BlockSpec((1, blk, d), lambda i, j, *_: (i, j, 0)),
             pl.BlockSpec((1, blk, d), lambda i, j, *_: (i, j, 0)),
         ],
-        scratch_shapes=_stream_scratch(d, blk, q.dtype, do.dtype)
-        if stream else [])
+        scratch_shapes=_f32_scratch((blk, d), (blk, d))     # dk, dv
+        + (_stream_scratch(d, blk, q.dtype, do.dtype) if stream else []))
     dk, dv = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

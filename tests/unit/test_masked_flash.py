@@ -262,6 +262,221 @@ class TestGradients:
 
 
 # --------------------------------------------------------------------- #
+# PR 33: a row's items walked one run a kind, FULL tiles in a loop of
+# their own that does none of the mask's work
+# --------------------------------------------------------------------- #
+def _kpm_emptying_rows(batch, s, block):
+    """Batch 0 loses one whole key tile, so that tile's rows are empty
+    inside a FULL item; batch 1 loses every key, so its rows are empty
+    altogether (zero output, zero gradients)."""
+    kpm = np.zeros((batch, s), np.float32)
+    kpm[0, block:2 * block] = F.NEG_INF
+    kpm[1, :] = F.NEG_INF
+    return jnp.asarray(kpm)
+
+
+# name: (mask builder, q heads, kv heads, head_dim, key mask, dropout)
+SPLIT_WALK_CASES = {
+    # one tile a row, the diagonal's: no FULL run at all
+    "partial_only": (lambda: BlockMask.causal(64, 64), 2, 2, 16, False, 0.0),
+    "full_only": (lambda: BlockMask.dense(64, 64, 16), 2, 2, 16, False, 0.0),
+    "full_then_partial": (lambda: BlockMask.causal(64, 16), 2, 2, 16,
+                          False, 0.0),
+    # window == block: the far-edge tile's last query row sees nothing
+    # in it, its first row all but one key
+    "window_far_edge_empty_row": (
+        lambda: BlockMask.causal_window(64, 16, 16), 2, 2, 16, False, 0.0),
+    # a diagonal tile wider than the window: both comparisons in one tile
+    "window_under_block": (
+        lambda: BlockMask.causal_window(128, 40, 64), 2, 2, 16, False, 0.0),
+    "full_tiles_key_mask_empties_rows": (
+        lambda: BlockMask.dense(64, 64, 16), 2, 2, 16, True, 0.0),
+    "causal_key_mask": (lambda: BlockMask.causal(64, 16), 2, 2, 16,
+                        True, 0.0),
+    "dropout": (lambda: BlockMask.causal_window(64, 40, 16), 2, 2, 16,
+                False, 0.25),
+    "gqa_28_over_4_d128": (
+        lambda: BlockMask.causal_window(256, 192, 64), 28, 4, 128,
+        False, 0.0),
+    "block_128": (lambda: BlockMask.causal_window(1024, 640, 128), 2, 1, 16,
+                  False, 0.0),
+    "block_256": (lambda: BlockMask.causal_window(1024, 640, 256), 2, 1, 16,
+                  False, 0.0),
+    "block_512": (lambda: BlockMask.causal_window(1024, 640, 512), 2, 1, 16,
+                  False, 0.0),
+}
+
+
+class TestSplitWalk:
+    @pytest.mark.parametrize("stream", [False, True],
+                             ids=["resident", "streamed"])
+    @pytest.mark.parametrize("case", sorted(SPLIT_WALK_CASES))
+    def test_parity(self, case, stream):
+        """Forward, dq, dk and dv against the oracle."""
+        build, h, hkv, d, with_kpm, rate = SPLIT_WALK_CASES[case]
+        M._FORCE_STREAM = stream
+        mask = build()
+        s = mask.seq_q
+        q, k, v = _qkv(B=2, H=h, hkv=hkv, s=s, d=d, seed=11)
+        cot = jnp.asarray(np.random.RandomState(12).randn(*q.shape),
+                          jnp.float32)
+        kpm = _kpm_emptying_rows(2, s, mask.block) if with_kpm else None
+        rng = jax.random.PRNGKey(7)
+        seed = F.dropout_seed_from_rng(rng).reshape(())
+
+        def ours(q, k, v):
+            return masked_flash_attention(
+                q, k, v, mask, key_mask=kpm, dropout_rate=rate,
+                dropout_rng=rng if rate else None, interpret=True)
+
+        def ref(q, k, v):
+            return masked_flash_reference(
+                q, k, v, mask, key_mask=kpm, dropout_rate=rate,
+                dropout_seed=seed if rate else None)
+
+        o, vjp = jax.vjp(ours, q, k, v)
+        o_w, vjp_w = jax.vjp(ref, q, k, v)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(o_w),
+                                   atol=5e-5, err_msg="o")
+        if with_kpm:
+            assert np.all(np.asarray(o)[1] == 0.0)
+        for got, want, n in zip(vjp(cot), vjp_w(cot), ("dq", "dk", "dv")):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       atol=2e-4, rtol=1e-3, err_msg=n)
+
+    @staticmethod
+    def _kernel_loops(fn, *args):
+        """The ``while`` loops of every Pallas kernel under ``fn``, in
+        order, a list a kernel."""
+        def subjaxprs(eqn):
+            for val in eqn.params.values():
+                for x in (val if isinstance(val, (tuple, list)) else [val]):
+                    x = getattr(x, "jaxpr", x)
+                    if hasattr(x, "eqns"):
+                        yield x
+
+        kernels = []
+
+        def visit(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    kernels.append([e for e in eqn.params["jaxpr"].eqns
+                                    if e.primitive.name == "while"])
+                else:
+                    for sub in subjaxprs(eqn):
+                        visit(sub)
+        visit(jax.make_jaxpr(fn)(*args).jaxpr)
+        return kernels
+
+    @staticmethod
+    def _primitives(jaxpr, tile=None):
+        """Names of the primitives under ``jaxpr``; with ``tile`` only
+        of those whose result has that shape (one value a score)."""
+        names = set()
+        for eqn in jaxpr.eqns:
+            if tile is None or any(getattr(o.aval, "shape", None) == tile
+                                   for o in eqn.outvars):
+                names.add(eqn.primitive.name)
+            for val in eqn.params.values():
+                sub = getattr(val, "jaxpr", val)
+                if hasattr(sub, "eqns"):
+                    names |= TestSplitWalk._primitives(sub, tile)
+        return names
+
+    MASK_WORK = {"select_n", "ge", "gt", "le", "lt", "eq", "ne", "and",
+                 "or", "mul"}
+
+    def test_the_full_loop_does_none_of_the_masks_work(self):
+        """A causal call without key mask: every kernel is two loops, and
+        the FULL tiles' body holds no index, and per score no comparison,
+        no select and no multiply in the forward (the backward's one is
+        ``p * (dp - delta)``); the diagonal tiles' body holds them."""
+        M._FORCE_STREAM = False
+        block = 16
+        mask = BlockMask.causal(64, block)
+        q, k, v = _qkv(s=64, d=32)         # a tile is not (block, d)
+
+        def fwd(q, k, v):
+            return masked_flash_attention(q, k, v, mask, interpret=True)
+
+        def bwd(q, k, v):
+            return jax.grad(lambda *a: jnp.sum(fwd(*a)),
+                            argnums=(0, 1, 2))(q, k, v)
+
+        (fwd_loops,) = self._kernel_loops(fwd, q, k, v)
+        kernels = self._kernel_loops(bwd, q, k, v)
+        assert len(kernels) == 3                   # fwd, dq, dk/dv
+        for loops, allowed in [(fwd_loops, set())] + [
+                (loops, {"mul"}) for loops in kernels[1:]]:
+            assert len(loops) == len(mask.run_kinds) == 2
+            full, partial = (w.params["body_jaxpr"].jaxpr for w in loops)
+            assert "iota" not in self._primitives(full)
+            a_score = self._primitives(full, (block, block))
+            assert not (a_score & self.MASK_WORK) - allowed, a_score
+            assert {"exp", "dot_general", "sub"} <= a_score
+            assert "iota" in self._primitives(partial)
+            assert {"select_n", "ge"} <= self._primitives(
+                partial, (block, block))
+
+    def test_a_key_mask_keeps_the_validity_select_in_the_full_loop(self):
+        M._FORCE_STREAM = False
+        mask = BlockMask.dense(64, 64, 16)
+        q, k, v = _qkv(s=64, d=32)
+        kpm = jnp.zeros((2, 64), jnp.float32)
+        for key_mask, selects in ((None, False), (kpm, True)):
+            (loops,) = self._kernel_loops(
+                lambda q, k, v: masked_flash_attention(
+                    q, k, v, mask, key_mask=key_mask, interpret=True),
+                q, k, v)
+            (full,) = loops                        # a dense mask: one run
+            full = full.params["body_jaxpr"].jaxpr
+            assert ("select_n" in self._primitives(full, (16, 16))
+                    ) == selects
+            assert "iota" not in self._primitives(full)
+
+    @pytest.mark.parametrize("name,build,full,total", [
+        ("gpt2_causal_1k_at_512", lambda: BlockMask.causal(1024, 512), 1, 3),
+        ("causal_8k_at_512", lambda: BlockMask.causal(8192, 512), 120, 136),
+        ("window_4k_of_8k_at_512",
+         lambda: BlockMask.causal_window(8192, 4096, 512), 84, 108),
+        ("window_under_block",
+         lambda: BlockMask.causal_window(512, 40, 128), 0, 7),
+        ("coarsened_longformer", lambda: BlockMask.from_layout(
+            BSLongformerSparsityConfig(
+                num_heads=2, block=128,
+                num_sliding_window_blocks=3).make_layout(2048), 128),
+         None, None),
+    ])
+    def test_runs_list_full_items_first(self, name, build, full, total):
+        mask = build()
+        if full is not None:
+            assert (mask.n_full, mask.nnz) == (full, total)
+        assert f"full={mask.n_full}, partial={mask.nnz - mask.n_full}" \
+            in mask.describe()
+        assert mask.run_kinds == tuple(sorted(set(
+            mask.kinds[mask.active].tolist())))
+        for (offs, ends, idxs), kinds, active in (
+                (mask.csr(), mask.kinds, mask.active),
+                (mask.csc(), mask.kinds.transpose(0, 2, 1),
+                 mask.active.transpose(0, 2, 1))):
+            n_rows = active.shape[0] * active.shape[1]
+            assert ends.shape == (len(mask.run_kinds), n_rows)
+            kinds, active = (x.reshape(n_rows, -1) for x in (kinds, active))
+            for row in range(n_rows):
+                items = idxs[offs[row]:offs[row] + ends[-1, row]]
+                assert sorted(items) == np.nonzero(active[row])[0].tolist()
+                lo = 0
+                for kind, hi in zip(mask.run_kinds, ends[:, row]):
+                    run = items[lo:hi]
+                    assert (kinds[row, run] == kind).all()
+                    assert (np.diff(run) > 0).all()
+                    lo = hi
+                if mask.run_kinds[0] == M.KIND_FULL:
+                    assert ends[0, row] == (
+                        active[row] & (kinds[row] == 0)).sum()
+
+
+# --------------------------------------------------------------------- #
 # banded coarsening: big walk tiles, fine structure in registers
 # --------------------------------------------------------------------- #
 class TestCoarsening:
@@ -604,7 +819,7 @@ class TestCostModel:
 
     def test_item_counts_match_csr(self):
         mask = _mask_for("bigbird")
-        offs, cnts, cols, kinds = mask.csr()
-        assert int(cnts.sum()) == mask.nnz == len(cols)
-        coffs, ccnts, crows, ckinds = mask.csc()
-        assert int(ccnts.sum()) == mask.nnz
+        offs, ends, cols = mask.csr()
+        assert int(ends[-1].sum()) == mask.nnz == len(cols)
+        coffs, cends, crows = mask.csc()
+        assert int(cends[-1].sum()) == mask.nnz

@@ -31,6 +31,7 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
+from deepspeed_tpu.ops.attention import flash
 from deepspeed_tpu.ops.attention.flash import flash_attention
 from deepspeed_tpu.ops.attention.paged import (paged_decode_attention,
                                                paged_decode_supported)
@@ -91,25 +92,30 @@ def _attention(dropout_rate):
     return {"fwd": fwd, "bwd": bwd}
 
 
+# the walk tiles PR 33's sweep tried on the chip, at both cells' shapes
+SWEEP_BLOCKS = [128, 256, 512]
+
+
+@pytest.mark.parametrize("block", SWEEP_BLOCKS)
 @pytest.mark.parametrize("shape", [GPT2_345M, HEAD_128],
                          ids=["gpt2_345m", "head128"])
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 @pytest.mark.parametrize("dropout_rate", [0.0, 0.1],
                          ids=["nodrop", "dropout"])
-def test_masked_flash_causal_compiles(shape, direction, dropout_rate):
+def test_masked_flash_causal_compiles(monkeypatch, shape, direction,
+                                      dropout_rate, block):
     """The default training attention (the unified masked kernel with a
-    causal BlockMask): the parent of PR 21 failed every one of these
-    with ``failed to legalize operation 'arith.select'``."""
+    causal BlockMask: a loop over the FULL tiles and one over the
+    diagonal's, PR 33), at every tile the sweep tried: the parent of
+    PR 21 failed every one of these with ``failed to legalize operation
+    'arith.select'``."""
+    monkeypatch.setattr(flash, "_FORCE_BLOCKS", (block, block))
     qkv = _spec(shape)
     compiled = _compile(_attention(dropout_rate)[direction], qkv, qkv, qkv,
                         _spec((2,), jnp.uint32))
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# the sparse layouts: what the deleted per-layout kernel generations were
-# kept for, compiled through the one kernel that is left. Fixed with
-# per-head patterns is the per-head layout (a BlockMask with one mask
-# head per q head).
 def _bslongformer(h, block=128):
     return BSLongformerSparsityConfig(num_heads=h, block=block,
                                       num_sliding_window_blocks=3)
@@ -602,13 +608,20 @@ SMALLTHINKER_Q = (1, 28, 8192, 128)
 SMALLTHINKER_KV = (1, 4, 8192, 128)
 
 
+@pytest.mark.parametrize("block", SWEEP_BLOCKS)
+@pytest.mark.parametrize("window", [4096, None], ids=["window", "global"])
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_banded_causal_mask_compiles_at_28_over_4_heads_of_128(direction):
-    """First model use of the banded causal BlockMask: a 4,096 window at
-    8,192 positions, 28 query heads in groups of 7 over 4 key-value
-    heads of 128, streamed tiles."""
+def test_banded_causal_mask_compiles_at_28_over_4_heads_of_128(
+        monkeypatch, direction, window, block):
+    """The model's two layer kinds at 8,192 positions, 28 query heads in
+    groups of 7 over 4 key-value heads of 128, streamed tiles: causal
+    inside a 4,096 window (FULL tiles, the diagonal's causal compare, the
+    far edge's window compare: three loops) and causal alone, at every
+    tile PR 33's sweep tried."""
+    monkeypatch.setattr(flash, "_FORCE_BLOCKS", (block, block))
+
     def fwd(q, k, v):
-        return flash_attention(q, k, v, causal=True, window=4096,
+        return flash_attention(q, k, v, causal=True, window=window,
                                interpret=False)
 
     def bwd(q, k, v):
