@@ -7,14 +7,25 @@ The engine runs `ramp_s` before the window opens (set-up the traffic
 needs: the slots have left their common start and the page pool is at
 its steady fill). Lengths come from `core/draws.backlog_lengths`: the
 stated laws by inverse CDF over a stratified grid, queued as epochs of
-`epoch_requests` that all hold the same lengths, each shuffled by the
-seed. Every seed therefore serves the same work in another order.
+`epoch_requests` that all hold the same lengths, each epoch shuffled by
+itself from the traffic file's `order_seed`, NOT from `--seed`: every
+run of the cell queues the same lengths in the same order, so the same
+requests meet in the same prefill buckets in the same steps, and
+`--seed` makes the weights and every prompt's ids. (Under the seed's
+own shuffle the order was a third to a half of the spread between runs:
+PERF.md §6, PR 36.)
+
+The model comes from `families/<family>.py`, named by the
+configuration's `family` (as `kinds/train_family_blocks.py` finds its
+own): the program's model at the file's sizes, its initialiser, the
+plain float32 reference, what a token and a slot hold in the engine's
+pools, the parameter count. This file names no architecture.
 
 Traffic parameters (`traffic/<name>.json`): `prompt`, `output` (the two
-laws), `backlog_requests`, `epoch_requests`, `ramp_s`, `trace_s` (the
-traced seconds, the window's last), `check_requests`, `check_pad_to`,
-`logit_tolerance`. The engine's settings are the configuration's
-`serve.inference`.
+laws), `backlog_requests`, `epoch_requests`, `order_seed`,
+`ramp_s`, `trace_s` (the traced seconds, the window's last),
+`check_requests`, `check_pad_to`, `logit_tolerance`. The engine's
+settings are the configuration's `serve.inference`.
 
 Correctness, outside the window: a seeded sample of finished requests is
 teacher-forced through the plain float32 reference, and every served
@@ -32,27 +43,26 @@ import numpy as np
 
 from deepspeed_tpu.inference import InferenceEngine
 from deepspeed_tpu.inference.scheduler import Request
-from deepspeed_tpu.models.gpt2 import init_gpt2_params
 
-from core import draws, flops
-from core.gpt2_model import model_of
-from reference import gpt2_reference
+from core import draws
+from loader import load_module
 
 
 def run(ctx):
     cfg, tr = ctx.config, ctx.traffic
-    serve = cfg["serve"]
-    model = model_of(cfg, serve["vocab_size"])
-    inference = serve["inference"]
+    family = load_module("families", cfg["family"])
+    model = family.serve_model_of(cfg)
+    inference = cfg["serve"]["inference"]
     ramp = tr["ramp_s"]
 
     t0 = time.perf_counter()
     key = jax.random.PRNGKey(draws.seed32(ctx.seed, 31) % (2 ** 31))
-    params = jax.jit(lambda k: init_gpt2_params(model, k))(key)
+    params = jax.jit(lambda k: family.init_params(model, k))(key)
     engine = InferenceEngine(model, params, inference)
     programs = engine.warmup()
     spec = engine.paged_spec
-    kv_token = flops.kv_bytes_per_token(model.num_layers, model.hidden_size)
+    held = family.cache_bytes(model, engine)
+    kv_token, kv_slot = held["per_token"], held["per_slot"]
     pool_tokens = (spec.num_pages - 1) * spec.page_size
     ctx.log(f"engine up and {programs} programs warm in "
             f"{time.perf_counter() - t0:.1f} s; {engine.num_slots} slots of "
@@ -63,20 +73,24 @@ def run(ctx):
 
     n = int(tr["backlog_requests"])
     plen, olen = draws.backlog_lengths(
-        n, int(tr["epoch_requests"]), ctx.seed, tr["prompt"], tr["output"])
+        n, int(tr["epoch_requests"]), tr["order_seed"], tr["prompt"],
+        tr["output"])
     prompts = draws.prompt_tokens(plen, model.vocab_size, ctx.seed)
     requests = [Request(prompt=prompts[i], max_new_tokens=int(olen[i]),
                         temperature=0.0, seed=i, eos_id=None)
                 for i in range(n)]
     index_of = {r.uid: i for i, r in enumerate(requests)}
     ctx.log(f"{n} requests queued at once in epochs of "
-            f"{tr['epoch_requests']}, prompts {int(plen.min())}-"
-            f"{int(plen.max())} (mean {plen.mean():.1f}), outputs "
-            f"{int(olen.min())}-{int(olen.max())} (mean {olen.mean():.1f})")
+            f"{tr['epoch_requests']} ordered by the traffic file's "
+            f"order_seed {tr['order_seed']}, {int(olen.sum())} output "
+            f"tokens in all; prompts "
+            f"{int(plen.min())}-{int(plen.max())} (mean {plen.mean():.1f}), "
+            f"outputs {int(olen.min())}-{int(olen.max())} (mean "
+            f"{olen.mean():.1f})")
 
-    done_at, finished, seen_tokens = {}, {}, {}
-    step_log = []     # (t_end, tokens emitted, active slots, live tokens,
-    #                    reserved pages)
+    done_at, finished = {}, {}
+    step_log = []     # (t_end, tokens emitted so far, active slots, live
+    #                    tokens, reserved pages)
     sched = engine.scheduler
     with ctx.span("submit"):
         for r in requests:
@@ -87,17 +101,16 @@ def run(ctx):
     t_open = t_close = None
     tokens_open = tokens_close = compiles_open = compiles_close = 0
     traced = False
-    emitted = 0
     t_start = time.perf_counter()
     while not sched.idle():
         now = time.perf_counter() - t_start
         if t_open is None and now >= ramp:
             # the window opens on a step boundary
-            t_open, tokens_open = now, emitted
+            t_open, tokens_open = now, sched.total_tokens
             compiles_open = ctx.compiles.compiles
             ctx.setup_done()
         if t_open is not None and now >= t_open + ctx.seconds:
-            t_close, tokens_close = now, emitted
+            t_close, tokens_close = now, sched.total_tokens
             compiles_close = ctx.compiles.compiles
             break
         if ctx.trace and not traced and t_open is not None \
@@ -111,22 +124,15 @@ def run(ctx):
             out = engine.step()
         t = time.perf_counter() - t_start
         with ctx.span("observe"):
-            delta = active = live = 0
-            for slot in sched.slots:
-                if slot is None:
-                    continue
-                uid, k = slot.request.uid, len(slot.tokens)
-                active += 1
-                live += slot.position
-                delta += k - seen_tokens.get(uid, 0)
-                seen_tokens[uid] = k
+            # the scheduler's own count of every token it recorded (a
+            # prefill's first and each decoded one), and one pass over
+            # the slots for what they hold
+            held_by = [s.position for s in sched.slots if s is not None]
             for f in out:
-                delta += len(f.tokens) - seen_tokens.pop(f.uid, 0)
                 i = index_of[f.uid]
                 done_at[i], finished[i] = t, f
-            emitted += delta
-            step_log.append((t, delta, active, live,
-                             sched.allocator.pages_in_use))
+            step_log.append((t, sched.total_tokens, len(held_by),
+                             sum(held_by), sched.allocator.pages_in_use))
     ctx.stop_trace()
     if t_close is None:
         raise SystemExit("benchmarks: the backlog ran out before the "
@@ -135,15 +141,19 @@ def run(ctx):
     window = t_close - t_open
     compiles_in_window = compiles_close - compiles_open
     recompiles = engine.steady_state_recompiles
-    in_window = [s for s in step_log if t_open < s[0] <= t_close]
+    # columns: t_end, tokens so far, active, live, pages
+    log = np.asarray(step_log, np.float64)
+    ends = log[:, 0]
+    in_window = log[(ends > t_open) & (ends <= t_close)]
     tokens_in_window = tokens_close - tokens_open
-    live_tokens = float(np.mean([s[3] for s in in_window]))
-    reserved_tokens = float(np.mean([s[4] for s in in_window])) \
-        * spec.page_size
+    rate = tokens_in_window / window
+    active_slots = float(in_window[:, 2].mean())
+    live_tokens = float(in_window[:, 3].mean())
+    reserved_tokens = float(in_window[:, 4].mean()) * spec.page_size
     ctx.log(f"ramp {t_open:.3f} s, window {window:.3f} s, "
             f"{len(in_window)} steps in it, {tokens_in_window} tokens "
-            f"emitted in it ({tokens_in_window / window:.1f} tokens/s); "
-            f"longest step {max(np.diff([s[0] for s in in_window])):.3f} s")
+            f"emitted in it ({rate:.1f} tokens/s); longest step "
+            f"{np.diff(in_window[:, 0]).max():.3f} s")
     ctx.log(f"page pool over the window's steps: live keys and values "
             f"{live_tokens:.0f} tokens = {live_tokens * kv_token / 1e9:.3f} "
             f"GB ({100 * live_tokens / pool_tokens:.1f}% of the pool), "
@@ -155,17 +165,14 @@ def run(ctx):
         "kind": "serve_backlog", "window_s": window,
         "tokens_in_window": tokens_in_window,
         "steps_in_window": len(in_window),
-        "mean_active_slots": float(np.mean([s[2] for s in in_window])),
+        "mean_active_slots": active_slots,
         "mean_live_tokens": live_tokens,
         "mean_reserved_tokens": reserved_tokens,
         "pool_tokens": pool_tokens, "kv_bytes_per_token": kv_token,
         "num_slots": engine.num_slots,
         "compiles_in_window": compiles_in_window,
-        "n_params": flops.gpt2_param_count(
-            model.vocab_size, model.max_position_embeddings,
-            model.hidden_size, model.num_layers, model.inter),
-        "model": {"layers": model.num_layers, "hidden": model.hidden_size,
-                  "heads": model.num_heads},
+        "n_params": family.param_count(model),
+        "model": family.describe_served(model),
     }
     in_win = [i for i, t in done_at.items() if t_open < t <= t_close]
     failed = sum(1 for i in in_win if finished[i].finish_reason != "length")
@@ -177,17 +184,23 @@ def run(ctx):
             f"{engine.num_slots}")
     if sched.queue_depth == 0:
         why_not.append("the backlog ran dry inside the window")
+    in_requests = sum(len(f.tokens) for f in finished.values()) + sum(
+        len(s.tokens) for s in sched.slots if s is not None)
+    if in_requests != tokens_close:
+        why_not.append(f"the scheduler counted {tokens_close} tokens where "
+                       f"its requests hold {in_requests}")
 
     # ---- correctness, outside the window
     rs = np.random.RandomState(draws.seed32(ctx.seed, 41))
     sample = [sample_from[j] for j in rs.permutation(len(sample_from))[
         :tr["check_requests"]]]
+    worst = None
     if not sample:
         why_not.append("no finished request to check")
     else:
         worst, exact, total = check_served(
-            model, engine.params, [finished[i] for i in sample],
-            tr["check_pad_to"])
+            family.reference_logits(model), engine.params,
+            [finished[i] for i in sample], tr["check_pad_to"])
         ctx.log(f"checked {len(sample)} requests against the plain "
                 f"float32 forward: {exact}/{total} served tokens are its "
                 f"argmax, the worst {worst:.4f} below it (tolerance "
@@ -205,23 +218,32 @@ def run(ctx):
         why_not.append(f"{compiles_in_window} compilations inside the "
                        f"window, {recompiles} recompiles since warm-up")
     engine.close()
+    compared = {
+        "served_logit_gap": {"value": worst,
+                             "limit": tr["logit_tolerance"]},
+        "compiles_in_window": {"value": compiles_in_window, "limit": 0},
+        "recompiles_since_warmup": {"value": recompiles, "limit": 0},
+        "tokens_counted_less_held": {"value": tokens_close - in_requests,
+                                     "limit": 0}}
     return {"correct": not why_not, "why_not": why_not,
+            "compared": compared,
             "attempted": len(in_win), "failed": failed,
-            "end_to_end": {
-                "serve_tokens_per_s": tokens_in_window / window},
+            "end_to_end": {"serve_tokens_per_s": rate},
             "device_also": {
-                "kv_pool_bytes": int(pool_tokens * kv_token),
-                "kv_live_bytes_mean": int(live_tokens * kv_token),
-                "kv_reserved_bytes_mean": int(reserved_tokens * kv_token)},
+                "kv_pool_bytes": int(pool_tokens * kv_token
+                                     + engine.num_slots * kv_slot),
+                "kv_live_bytes_mean": int(live_tokens * kv_token
+                                          + active_slots * kv_slot),
+                "kv_reserved_bytes_mean": int(reserved_tokens * kv_token
+                                              + active_slots * kv_slot)},
             "facts": facts}
 
 
-def check_served(model, params, finished, pad_to):
+def check_served(reference_logits, params, finished, pad_to):
     """(worst gap, exact, total): each served sequence teacher-forced
-    once through the plain forward; row t holds the logits for token
-    t + 1 given the first t + 1."""
-    fn = jax.jit(lambda p, ids: gpt2_reference.logits(
-        p, ids, model.num_layers, model.num_heads))
+    once through the family's plain forward; row t holds the logits for
+    token t + 1 given the first t + 1."""
+    fn = jax.jit(reference_logits)
     worst, exact, total = 0.0, 0, 0
     for f in finished:
         seq = list(f.prompt) + list(f.tokens)

@@ -225,6 +225,13 @@ def main():
                            for name, unit in units.items()}
     if not line["correct"]:
         log("NOT CORRECT: " + "; ".join(result.get("why_not", [])))
+    if "compared" in result:
+        # each number compared beside its limit: the last key of the
+        # line, and the last lines on standard error
+        line["compared"] = result["compared"]
+        for name, c in result["compared"].items():
+            print(f"compared {name}: {c['value']} (limit {c['limit']})",
+                  file=sys.stderr, flush=True)
     if args.rehearse_cpu:
         log("rehearsal on " + d0.platform + " (not a measurement, no "
             "result line): " + json.dumps(line))

@@ -46,6 +46,28 @@ def test_spread_is_the_contracts():
     assert stats.spread(vals) == pytest.approx((q3 - q1) / 102.5)
 
 
+def test_driver_spread_leaves_out_the_one_farthest_run():
+    # six runs, one far high: the range is 10 of a median 102.5; without
+    # the run farthest from the median it is 4
+    vals = [100.0, 101.0, 102.0, 103.0, 104.0, 110.0]
+    assert stats.driver_spread(vals) == pytest.approx(4.0 / 102.5)
+    # wider than the distance between quartiles, which the bound is
+    # five times: q1 = 100.75, q3 = 105.5
+    assert stats.spread(vals) == pytest.approx(4.75 / 102.5)
+    # the far run may be the low one, and the order does not matter
+    assert stats.driver_spread([103.0, 90.0, 101.0, 102.0, 100.0]) == \
+        pytest.approx(3.0 / 101.0)
+    # two far runs: one goes, the other stays in the spread
+    assert stats.driver_spread([100.0, 100.0, 100.0, 100.0, 90.0, 112.0]) \
+        == pytest.approx(10.0 / 100.0)
+    # nothing is left out where that would narrow nothing
+    assert stats.driver_spread([100.0, 100.0, 104.0, 104.0]) == \
+        pytest.approx(4.0 / 102.0)
+    assert stats.driver_spread([100.0, 101.0]) == pytest.approx(1.0 / 100.5)
+    with pytest.raises(ValueError):
+        stats.driver_spread([100.0])
+
+
 # ----------------------------------------------------------------- draws
 LAW = {"median": 96, "sigma": 0.8, "low": 16, "high": 512}
 OUT = {"median": 48, "sigma": 0.6, "low": 8, "high": 128}
@@ -71,6 +93,66 @@ def test_two_seeds_queue_the_same_work_in_another_order():
     # an epoch is the stratified law: one request from each stratum
     assert sorted(a[0][:64]) == list(draws.lognormal_clipped(64, **LAW))
     assert sorted(a[1][:64]) == list(draws.lognormal_clipped(64, **OUT))
+
+
+def traffic_file(name):
+    with open(os.path.join(BENCH_DIR, "traffic", name + ".json")) as f:
+        return json.load(f)
+
+
+def test_serve_saturated_orders_its_backlog_from_the_traffic_file():
+    """The order is the traffic file's `order_seed`, which the kind hands
+    `backlog_lengths` whatever `--seed` is: two `--seed`s queue the same
+    lengths in the same order and differ in every prompt's ids."""
+    tr = traffic_file("serve-saturated")
+    assert tr["backlog_requests"] == 12096
+    with open(os.path.join(BENCH_DIR, "kinds", "serve_backlog.py")) as f:
+        assert 'tr["order_seed"], tr["prompt"]' in f.read()
+    n, epoch = 640, tr["epoch_requests"]
+    plen, _ = draws.backlog_lengths(n, epoch, tr["order_seed"],
+                                    tr["prompt"], tr["output"])
+    ids_a = draws.prompt_tokens(plen, 50257, 1)
+    ids_b = draws.prompt_tokens(plen, 50257, 2 ** 31 + 11)
+    assert [len(x) for x in ids_a] == [len(x) for x in ids_b] == list(plen)
+    assert not any((x == y).all() for x, y in zip(ids_a, ids_b))
+    # each epoch still in an order of its own
+    assert list(plen[:epoch]) != list(plen[epoch:2 * epoch])
+
+
+def test_serve_saturated_backlog_outlasts_9000_tokens_per_s():
+    """Ramp + window at the contract's run_seconds: the queued output
+    tokens cover 9,000 tokens/s, three times what the cell serves."""
+    tr = traffic_file("serve-saturated")
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        run_seconds = json.load(f)["run_seconds"]
+    _, olen = draws.backlog_lengths(
+        tr["backlog_requests"], tr["epoch_requests"], tr["order_seed"],
+        tr["prompt"], tr["output"])
+    assert int(olen.sum()) == 189 * 3542 == 669_438
+    assert olen.sum() >= (tr["ramp_s"] + run_seconds) * 9000
+
+
+def test_the_serving_kind_names_no_architecture():
+    """`kinds/serve_backlog.py` finds its model through the
+    configuration's `family`: it imports no model, initialiser or
+    reference module and names no architecture."""
+    with open(os.path.join(BENCH_DIR, "kinds", "serve_backlog.py")) as f:
+        source = f.read()
+    imports = [ln for ln in source.splitlines()
+               if ln.startswith(("import ", "from "))]
+    assert not any(re.search(r"models|reference|gpt2_model", ln)
+                   for ln in imports), imports
+    assert not re.search(r"gpt2|gpt-2|smallthinker|llama", source, re.I)
+    assert 'load_module("families", cfg["family"])' in source
+    family = os.path.join(BENCH_DIR, "families", "gpt2.py")
+    assert os.path.isfile(family)
+    with open(os.path.join(BENCH_DIR, "configs", "gpt2-345m.json")) as f:
+        assert json.load(f)["family"] == "gpt2"
+    with open(family) as f:
+        text = f.read()
+    for name in ("serve_model_of", "init_params", "reference_logits",
+                 "cache_bytes", "param_count", "describe_served"):
+        assert re.search(rf"^(def )?{name}\b", text, re.M), name
 
 
 def test_the_law_is_the_stated_one():

@@ -306,6 +306,14 @@ RECORDED = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "recorded_program_trace.json")
 
 
+# metrics retired since the recording was made, as their files read: the
+# recording is of the gather reader (before PR 30), and still holds the
+# scope reader to what that chip run reported under `kv_gather`
+RETIRED = {"decode_scope_kv_gather_ms.sat": {
+    "reader": "scope_ms_per_step",
+    "params": {"scopes": ["kv_gather"], "step_pattern": "decode"}}}
+
+
 @pytest.fixture(scope="module")
 def recorded():
     """The recording, with each operation's interned scope path put
@@ -347,8 +355,9 @@ def test_recorded_chip_trace_reads_as_on_the_chip(recorded, monkeypatch,
                 HOST_SPANS)
     checked = 0
     for name, value in recorded["reported"][cell].items():
-        spec = json.load(open(os.path.join(BENCH_DIR, "metrics",
-                                           name + ".json")))
+        path = os.path.join(BENCH_DIR, "metrics", name + ".json")
+        spec = json.load(open(path)) if os.path.isfile(path) \
+            else RETIRED[name]
         if spec["reader"] != "scope_ms_per_step":
             continue
         # the cut holds the first run of each program, the metric's
