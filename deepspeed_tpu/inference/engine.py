@@ -98,11 +98,15 @@ from deepspeed_tpu.inference.disagg import (DispatchTrace, HandoffQueue,
                                             MigrationRecord,
                                             price_handoff)
 from deepspeed_tpu.inference.draft import make_drafter
-from deepspeed_tpu.inference.kv_cache import (PageAllocator, cache_spec_for,
+from deepspeed_tpu.inference.kv_cache import (PageAllocator, PagedStateCache,
+                                              cache_spec_for,
                                               init_kv_cache,
                                               init_paged_kv_cache,
+                                              init_state_pool,
                                               kv_cache_bytes, paged_kv_bytes,
-                                              paged_spec_for, pages_for)
+                                              paged_spec_for, pages_for,
+                                              state_pool_bytes,
+                                              state_pool_spec_for)
 from deepspeed_tpu.inference.scheduler import (FinishedRequest, Request,
                                                Scheduler)
 from deepspeed_tpu.inference.tracing import ServeTracer
@@ -110,6 +114,10 @@ from deepspeed_tpu.models.gpt2 import (GPT2Config, gpt2_forward,
                                        gpt2_param_specs, init_gpt2_params)
 from deepspeed_tpu.models.llama import (LlamaConfig, init_llama_params,
                                         llama_forward, llama_param_specs)
+from deepspeed_tpu.models.solar_open2 import (SolarOpen2Config,
+                                              init_solar_open2_params,
+                                              solar_open2_forward,
+                                              solar_open2_param_specs)
 from deepspeed_tpu.ops.attention.flash import NEG_INF
 from deepspeed_tpu.ops.attention.paged import (live_pages,
                                                paged_decode_supported)
@@ -133,6 +141,8 @@ _FAMILIES = {
                  gpt2_param_specs),
     LlamaConfig: ("llama", llama_forward, init_llama_params,
                   llama_param_specs),
+    SolarOpen2Config: ("solar_open2", solar_open2_forward,
+                       init_solar_open2_params, solar_open2_param_specs),
 }
 
 
@@ -302,6 +312,12 @@ class InferenceEngine:
         enable_compile_cache()
         cfg = _normalize_inference_config(inference_config)
         self.config = cfg
+        # a family with recurrent layers keeps a per-slot state pool
+        # beside the pages (inference/kv_cache.py)
+        self.state_spec = state_pool_spec_for(
+            model_config, cfg["max_batch_size"] + 1, tail_dtype=dtype)
+        if self.state_spec is not None:
+            self._refuse_what_state_cannot_follow(cfg, mesh)
         from deepspeed_tpu.runtime.config import get_observability_config
         self.obs_config = get_observability_config(
             {"observability": dict(observability_config or {})})
@@ -524,6 +540,11 @@ class InferenceEngine:
                                       prefix_cache=pk["prefix_cache"])
             cache_bytes = paged_kv_bytes(self.paged_spec)
             self._page_bytes = cache_bytes // num_pages
+            if self.state_spec is not None:
+                # two more leaves of the one cache tree, one row a slot,
+                # each leaf by name for the family's forward
+                self._cache = PagedStateCache(
+                    *self._cache, *init_state_pool(self.state_spec))
             # static pool cost per token of capacity — the
             # Serve/kv_pool_bytes_per_token gauge (int8 pools land
             # near half the bf16 figure; scales are the remainder)
@@ -600,7 +621,35 @@ class InferenceEngine:
         self._weight_ordinal = 0
         self.scheduler.weight_version = self._weight_version
 
-        if self.paged:
+        # routed experts, where the family's config declares them
+        # (``expert_counters``: the assignments a row that decodes
+        # offers the router over the layers, the experts held here):
+        # the paged decode program then returns the layers' counters
+        # with its tokens, and a span carries those of the step before
+        # (its own are known only once its tokens are read)
+        self._expert_counters = getattr(model_config, "expert_counters",
+                                        None)
+        self._moe_counts = (0, 0)
+        if self._expert_counters is not None and not self.paged:
+            raise ValueError(
+                f"{type(model_config).__name__} reports its routed "
+                f"experts' counters through the paged decode program: "
+                f"paged_kv.enabled must be true")
+        if self.state_spec is not None:
+            self._prefill = self._wrap_program(
+                self._prefill_state_impl, 9, "prefill")
+            self._decode = self._wrap_program(
+                self._decode_paged_impl, 7, "decode")
+            self._verify = None
+            geom = (f"paged KV cache: {self.paged_spec.num_pages} pages x "
+                    f"{self.paged_spec.page_size} tokens over "
+                    f"{self.paged_spec.num_layers} softmax layers "
+                    f"({cache_bytes / 2**20:.1f} MiB), state pool "
+                    f"{self.state_spec.rows} rows over "
+                    f"{self.state_spec.num_layers} recurrent layers "
+                    f"({state_pool_bytes(self.state_spec) / 2**20:.1f} "
+                    f"MiB), decode attn {self._decode_attn_path}")
+        elif self.paged:
             self._prefill = self._wrap_program(
                 self._prefill_paged_impl, 8, "prefill")
             self._decode = self._wrap_program(
@@ -663,6 +712,41 @@ class InferenceEngine:
             f"inference engine: {self.family}, {self.num_slots} slots, "
             f"max_len {max_len}, prompt buckets {cfg['prompt_buckets']}, "
             f"batch buckets {cfg['batch_buckets']}, {geom}{mesh_note}")
+
+    def _refuse_what_state_cannot_follow(self, cfg, mesh):
+        """A recurrent state is one row a slot and is not addressed by
+        position: every feature that takes "state = pages + position"
+        for granted would serve this family WRONGLY, so each is refused
+        here, once, with what would have to exist (docs/solar_open2.md).
+        """
+        pk = cfg["paged_kv"]
+        refused = [
+            (not pk["enabled"], "the dense cache (paged_kv.enabled: "
+             "false): the state pool is a leaf of the paged cache tree"),
+            (pk["prefix_cache"], "the prefix cache (paged_kv.prefix_cache)"
+             ": a shared prefix's pages carry no recurrent state; it "
+             "needs a state snapshot at every shared page boundary"),
+            (cfg["chunked_prefill"]["enabled"], "chunked prefill: a "
+             "chunk would have to start from the state its predecessor "
+             "left, and prefill starts every row from an empty one"),
+            (cfg["spec_decode"]["enabled"], "speculative decoding: a "
+             "rejected draft is rolled back by position, and the state "
+             "has already absorbed it"),
+            (cfg["disagg"]["enabled"], "disaggregated prefill/decode: "
+             "the handoff moves pages, not a slot's state row"),
+            (pk["kv_dtype"] == "int8", "an int8 page pool: the family's "
+             "softmax layers read their pages without scales"),
+            (bool(cfg["quantize_weights"]), "quantized weights: the "
+             "family holds its weights in bfloat16 as they are"),
+            (mesh is not None or bool(cfg["mesh"]["axes"]), "a serving "
+             "mesh: only the single-device engine serves this family"),
+        ]
+        asked = [what for cond, what in refused if cond]
+        if asked:
+            raise ValueError(
+                f"{type(self.model_config).__name__} keeps a per-slot "
+                f"recurrent state and cannot be served with: "
+                + "; ".join(asked))
 
     def _resolve_decode_attn(self, pk):
         """Pick the paged decode attention path once, at init (the
@@ -911,6 +995,24 @@ class InferenceEngine:
         first = self._sample_tokens(last, first_keys, temps)
         return first, cache
 
+    def _prefill_state_impl(self, params, cache, ids, lengths, positions,
+                            tables, keys, temps, slots):
+        """:meth:`_prefill_paged_impl` for a family with a state pool:
+        the forward also learns each row's true length and which SLOT it
+        is (pad rows name the scratch row), writes the row's final state
+        whole at that index, and returns the logits of the last true
+        position alone."""
+        logits, cache = self._forward(
+            params, self.model_config, ids, dtype=self.dtype,
+            kv_cache=cache, cache_position=positions,
+            block_tables=tables,
+            paged_attn_kernel=self._decode_attn_path, lengths=lengths,
+            slots=slots)
+        first_keys = jax.vmap(jax.random.fold_in)(keys,
+                                                  positions + lengths)
+        first = self._sample_tokens(logits[:, 0], first_keys, temps)
+        return first, cache
+
     def _chunk_cp_impl(self, params, cache, ids, lengths, positions,
                        tables, keys, temps):
         """The context-parallel chunk program: the SAME paged prefill
@@ -939,14 +1041,26 @@ class InferenceEngine:
         dispatch's live-page bucket (one compiled program per width),
         so even the fallback's reads scale with tokens in flight.
         Inactive rows carry all-null tables — garbage in, garbage
-        discarded."""
-        logits, cache = self._forward(
+        discarded. A family with a state pool runs row i against row i
+        of it. Where the family declares routed experts
+        (``expert_counters``) the layers' counters ride home WITH the
+        sampled tokens, one int32 array, no second transfer: ``rows``
+        tokens, then the assignments of the rows that decode (a row
+        decodes where its table names a page) that landed on held
+        experts and the fullest held expert's count, each summed over
+        the layers."""
+        counted = {}
+        if self._expert_counters is not None:
+            counted = dict(active=tables[:, 0] > 0, with_counts=True)
+        logits, cache, *counts = self._forward(
             params, self.model_config, toks[:, None], dtype=self.dtype,
             kv_cache=cache, cache_position=positions,
             block_tables=tables,
-            paged_attn_kernel=self._decode_attn_path)
+            paged_attn_kernel=self._decode_attn_path, **counted)
         step_keys = jax.vmap(jax.random.fold_in)(keys, positions + 1)
         nxt = self._sample_tokens(logits[:, 0], step_keys, temps)
+        if counts:
+            nxt = jnp.concatenate([nxt, jnp.sum(counts[0], axis=0)])
         return nxt, cache
 
     def _verify_paged_impl(self, params, cache, toks, positions, tables,
@@ -1040,7 +1154,33 @@ class InferenceEngine:
                 self._handoff_q.dropped(rec)
         return self.scheduler.evict(uid, reason=reason)
 
+    def slot_state(self, slot_id: int):
+        """What a serving slot's row of the state pool holds, for a
+        family that keeps one: (the tokens the state has absorbed — the
+        prompt and every served token but the pending one —, the row
+        ``(recurrent layers, heads, dk, dv)`` float32 on the host). None
+        for an empty slot or one still waiting for its first token. Call
+        between :meth:`step` calls (tests, the benchmark's comparison of
+        the pool with the reference's recurrence)."""
+        if self.state_spec is None:
+            raise ValueError(f"{type(self.model_config).__name__} keeps "
+                             f"no per-slot state")
+        slot = self.scheduler.slots[slot_id]
+        if slot is None or slot.pending_tok is None:
+            return None
+        absorbed = (list(slot.request.prompt)
+                    + list(slot.tokens))[:slot.position]
+        return absorbed, np.asarray(self._cache.state[:, slot_id])
+
     # ------------------------------------------- live KV migration (16)
+    def _refuse_migration_with_state(self):
+        if self.state_spec is not None:
+            raise NotImplementedError(
+                f"{type(self.model_config).__name__} keeps a per-slot "
+                f"recurrent state that a MigrationRecord does not carry: "
+                f"a request of this family cannot be exported, imported "
+                f"or migrated")
+
     def export_request(self, uid: int):
         """Export one in-flight request's complete portable state — a
         :class:`~.disagg.MigrationRecord` with its live pages gathered
@@ -1051,6 +1191,7 @@ class InferenceEngine:
         queue path redistributes those), or pages still in the prefill
         pool (separate-pools disagg, pre-claim). Call between
         :meth:`step` calls."""
+        self._refuse_migration_with_state()
         if self._mig_export is None:
             return None
         sched = self.scheduler
@@ -1115,6 +1256,7 @@ class InferenceEngine:
         — with nothing leaked — when this replica can't take it (no
         free slot, pool exhausted, or geometry/dtype mismatch with the
         source: a mismatched slab would mint a new program signature)."""
+        self._refuse_migration_with_state()
         if self._mig_import is None:
             return None
         spec = self.paged_spec
@@ -1445,11 +1587,17 @@ class InferenceEngine:
                             zip(batch.prefix_lens, batch.page_tables)):
                         positions[i] = pl
                         tables[i, :len(pages)] = pages
-                else:
+                if not self.paged or self.state_spec is not None:
                     slots = np.full((bb,), self._scratch, np.int32)
                     slots[:len(batch.slot_ids)] = batch.slot_ids
             with self._span("serve/prefill/dispatch"):
-                if not self.paged:
+                if self.state_spec is not None:
+                    first, self._cache = self._prefill(
+                        self.params, self._cache, jnp.asarray(ids),
+                        jnp.asarray(lengths), jnp.asarray(positions),
+                        jnp.asarray(tables), jnp.asarray(keys),
+                        jnp.asarray(temps), jnp.asarray(slots))
+                elif not self.paged:
                     first, self._cache = self._prefill(
                         self.params, self._cache, jnp.asarray(ids),
                         jnp.asarray(lengths), jnp.asarray(slots),
@@ -1707,7 +1855,10 @@ class InferenceEngine:
         per row. Returns whether anything dispatched."""
         sched = self.scheduler
         self.health.heartbeat("decode")
-        sids, toks, poss, temps, seeds = sched.decode_state()
+        with self._span("serve/plan"):
+            # the walks over the slots that make the dispatch's rows
+            sids, toks, poss, temps, seeds = sched.decode_state()
+            live_tokens = sched.tokens_in_flight
         if not sids:
             return False
         t0 = time.perf_counter()
@@ -1720,7 +1871,6 @@ class InferenceEngine:
         runs: Dict[int, List[int]] = {}
         draft_stats = None
         # what this dispatch reads, as the span's counters
-        live_tokens = sched.tokens_in_flight
         counters = dict(rows=self._rows, live_tokens=live_tokens)
         if self.paged:
             counters["page_size"] = self.paged_spec.page_size
@@ -1775,23 +1925,35 @@ class InferenceEngine:
                 spec_kw["spec_accept_rate"] = (accepted_total
                                                / proposed_total)
         else:
-            if self.paged:
-                # clamp the dispatch's table width to the batch's
-                # live-page bucket: reads (kernel walk or gather
-                # stripe) scale with tokens in flight, and every
-                # width was compiled at warmup
-                width = pick_bucket(
-                    min(sched.max_live_pages(),
-                        self.paged_spec.pages_per_seq),
-                    self._decode_page_buckets)
-                counters["table_pages"] = width
-                # what the Pallas kernel walks: each row's live pages,
-                # an inactive row's null page once; the gather reader
-                # walks none (it reads the table's whole width)
-                counters["read_pages"] = (
-                    sum(live_pages(p, self.paged_spec.page_size)
-                        for p in poss) + self._rows - len(sids)
-                    if self._decode_attn_path == "pallas" else 0)
+            with self._span("serve/plan"):
+                # ... and its span's counters
+                if self.paged:
+                    # clamp the dispatch's table width to the batch's
+                    # live-page bucket: reads (kernel walk or gather
+                    # stripe) scale with tokens in flight, and every
+                    # width was compiled at warmup
+                    width = pick_bucket(
+                        min(sched.max_live_pages(),
+                            self.paged_spec.pages_per_seq),
+                        self._decode_page_buckets)
+                    counters["table_pages"] = width
+                    # what the Pallas kernel walks: each row's live
+                    # pages, an inactive row's null page once; the
+                    # gather reader walks none (it reads the table's
+                    # whole width)
+                    counters["read_pages"] = (
+                        sum(live_pages(p, self.paged_spec.page_size)
+                            for p in poss) + self._rows - len(sids)
+                        if self._decode_attn_path == "pallas" else 0)
+                if self._expert_counters is not None:
+                    # routed experts: the rows that decode, and what the
+                    # router did with them the step before
+                    per_row, held = self._expert_counters
+                    counters.update(
+                        active=len(sids), held=held,
+                        assignments=len(sids) * per_row,
+                        landed=self._moe_counts[0],
+                        fullest=self._moe_counts[1])
             with self._span("serve/decode", **counters):
                 with self._span("serve/decode/build"):
                     toks_a, poss_a, temps_a, keys_a = self._decode_arrays(
@@ -1816,6 +1978,8 @@ class InferenceEngine:
                 with self._span("serve/decode/wait"):
                     # host sync: the scheduler needs the token values
                     nxt = np.asarray(nxt)
+                    if self._expert_counters is not None:
+                        self._moe_counts = (int(nxt[-2]), int(nxt[-1]))
             if self._dispatch_trace is not None:
                 self._dispatch_trace.record(self._steps, "decode")
             runs = {sid: [int(nxt[sid])] for sid in sids}
@@ -1987,7 +2151,14 @@ class InferenceEngine:
             lengths = np.ones((bb,), np.int32)
             keys = np.zeros((bb, 2), np.uint32)
             temps = np.zeros((bb,), np.float32)
-            if self.paged:
+            if self.state_spec is not None:
+                first, self._cache = self._prefill(
+                    self.params, self._cache, jnp.asarray(ids),
+                    jnp.asarray(lengths), jnp.zeros((bb,), jnp.int32),
+                    jnp.zeros((bb, self._prefill_pps), jnp.int32),
+                    jnp.asarray(keys), jnp.asarray(temps),
+                    jnp.full((bb,), self._scratch, jnp.int32))
+            elif self.paged:
                 ztab = jnp.zeros((bb, self._prefill_pps), jnp.int32)
                 if self._separate_pools:
                     first, self._cache_prefill = self._prefill(
@@ -2105,6 +2276,7 @@ class InferenceEngine:
         recompile baseline is re-anchored so
         :attr:`steady_state_recompiles` == 0 remains the contract with
         migration armed. Returns the number of programs compiled."""
+        self._refuse_migration_with_state()
         if not self.paged:
             raise RuntimeError(
                 "live migration requires the paged KV pool "

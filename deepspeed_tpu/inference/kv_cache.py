@@ -32,6 +32,19 @@ implements **prefix caching**: full, page-aligned prompt prefixes are
 chain-hashed and refcounted, so concurrent requests sharing a system
 prompt prefill the shared pages once.
 
+**The state pool (beside the pages)** — a family with recurrent layers
+(``models/solar_open2.py``: gated delta-rule attention) keeps, for every
+such layer, a state that does not grow with the sequence: two more
+leaves of the cache tree, ``(recurrent layers, slots + 1, heads, dk,
+dv)`` float32 and the short convolution's last inputs ``(recurrent
+layers, slots + 1, positions, channels)``, ONE ROW A SLOT (the last row
+is the scratch slot of a prefill bucket's pad rows). No allocator: a
+slot's row is written WHOLE by its request's prefill, so it is freed
+with the slot and a reused slot never sees its predecessor's state;
+decode rewrites every row in place. :class:`StatePoolSpec` is built
+from the model's ``state_geometry``; a family without one has no such
+leaves.
+
 Writes happen inside the model forwards via
 :func:`deepspeed_tpu.models.gpt2.write_kv_cache` (dense) /
 :func:`deepspeed_tpu.models.gpt2.write_paged_kv_cache` (paged); this
@@ -39,7 +52,7 @@ module only owns allocation, the family-specific geometry (GQA caches
 are kv_heads-sized), and byte accounting for telemetry.
 """
 
-from typing import Any, NamedTuple, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -49,7 +62,8 @@ from deepspeed_tpu.inference.paging import PageAllocator, pages_for
 __all__ = ["KVCacheSpec", "cache_spec_for", "init_kv_cache",
            "kv_cache_bytes", "PagedKVSpec", "paged_spec_for",
            "init_paged_kv_cache", "paged_kv_bytes", "pages_for",
-           "PageAllocator"]
+           "PageAllocator", "StatePoolSpec", "state_pool_spec_for",
+           "init_state_pool", "state_pool_bytes", "PagedStateCache"]
 
 
 class KVCacheSpec(NamedTuple):
@@ -184,7 +198,10 @@ def paged_spec_for(model_config, num_pages: int, page_size: int,
         raise ValueError(
             f"paged kv cache kv_quant_block ({block}) must divide "
             f"head_dim ({head_dim})")
-    return PagedKVSpec(num_layers=model_config.num_layers,
+    # a trunk of mixed layer kinds pages only its softmax layers
+    layers = getattr(model_config, "kv_cache_layers", None) or \
+        model_config.num_layers
+    return PagedKVSpec(num_layers=layers,
                        num_pages=num_pages, page_size=page_size,
                        kv_heads=kv_heads, head_dim=head_dim,
                        pages_per_seq=pages_for(max_len, page_size),
@@ -213,3 +230,68 @@ def paged_kv_bytes(spec: PagedKVSpec) -> int:
     if spec.quantized:
         total += 2 * int(np.prod(spec.scale_shape)) * 4
     return total
+
+
+# --------------------------------------------------------------------- #
+# per-slot recurrent state
+# --------------------------------------------------------------------- #
+class StatePoolSpec(NamedTuple):
+    """Static geometry of the per-slot state pool (module docstring):
+    ``rows`` is the serving slots + 1 scratch row."""
+    num_layers: int      # the recurrent layers only
+    rows: int
+    heads: int
+    key_dim: int
+    value_dim: int
+    tail_positions: int  # the short convolution's width - 1
+    tail_channels: int
+    tail_dtype: Any = jnp.bfloat16
+
+    @property
+    def state_shape(self) -> Tuple[int, int, int, int, int]:
+        return (self.num_layers, self.rows, self.heads, self.key_dim,
+                self.value_dim)
+
+    @property
+    def tail_shape(self) -> Tuple[int, int, int, int]:
+        return (self.num_layers, self.rows, self.tail_positions,
+                self.tail_channels)
+
+
+def state_pool_spec_for(model_config, rows: int,
+                        tail_dtype=jnp.bfloat16) -> Optional[StatePoolSpec]:
+    """The state pool's geometry from a model config's
+    ``state_geometry`` (recurrent layers, heads, key width, value width,
+    tail positions, tail channels); None for a family that keeps no
+    state beside its keys and values."""
+    geometry = getattr(model_config, "state_geometry", None)
+    if geometry is None:
+        return None
+    layers, heads, dk, dv, positions, channels = geometry
+    return StatePoolSpec(layers, rows, heads, dk, dv, positions, channels,
+                         tail_dtype)
+
+
+class PagedStateCache(NamedTuple):
+    """The cache tree of a family with a state pool, each leaf by name:
+    the page pools of its softmax layers and the per-slot pools of its
+    recurrent ones. The engine builds it and the family's forward takes
+    and returns it (``kv_cache._replace``); to everything that walks
+    the tree's leaves it is the tuple it always was."""
+    keys: Any
+    values: Any
+    state: Any
+    tails: Any
+
+
+def init_state_pool(spec: StatePoolSpec):
+    """The zeroed ``(state, tails)`` leaves: float32 states (the
+    recurrence is float32 whatever the engine computes in), the tails in
+    the engine's compute dtype (they are matmul outputs of it)."""
+    return (jnp.zeros(spec.state_shape, jnp.float32),
+            jnp.zeros(spec.tail_shape, spec.tail_dtype))
+
+
+def state_pool_bytes(spec: StatePoolSpec) -> int:
+    return int(np.prod(spec.state_shape)) * 4 + int(
+        np.prod(spec.tail_shape)) * jnp.dtype(spec.tail_dtype).itemsize

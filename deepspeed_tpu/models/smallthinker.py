@@ -36,7 +36,7 @@ from deepspeed_tpu.models.gpt2 import _tied_xent_chunked, _wd
 from deepspeed_tpu.models.llama import apply_rope, rope_cos_sin
 from deepspeed_tpu.ops.attention.flash import flash_attention
 from deepspeed_tpu.ops.functional import rms_norm
-from deepspeed_tpu.ops.moe import dropless_reglu_experts, route_top_k
+from deepspeed_tpu.ops.moe import dropless_experts, route_top_k
 from deepspeed_tpu.profiling.spans import scope
 
 
@@ -149,9 +149,9 @@ def _expert_half(lp, config: SmallThinkerConfig, x, idx, p, dtype):
     h2 = _norm(x, lp["ln_2"], config.rms_norm_eps)
     experts = {name: _wd(table, dtype)
                for name, table in lp["experts"].items()}
-    y, counts = dropless_reglu_experts(
+    y, counts = dropless_experts(
         h2.reshape(B * S, hdim), idx, p, experts, config.held,
-        config.num_experts)
+        config.num_experts, jax.nn.relu)
     with scope("moe_dispatch"):
         return x + y.reshape(B, S, hdim).astype(x.dtype), counts
 

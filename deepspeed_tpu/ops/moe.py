@@ -354,7 +354,14 @@ def chunk_rows(assignments: int, held: int, num_experts: int) -> int:
 _GMM_TILE = (512, 1280, 768)
 
 
-def grouped_matmul(lhs, rhs, group_sizes):
+def _whole_tile(dim: int, cap: int) -> int:
+    """The largest multiple of 128 up to ``cap`` that divides ``dim``
+    (``dim`` itself where it is smaller or none does)."""
+    fits = [t for t in range(128, min(cap, dim) + 1, 128) if dim % t == 0]
+    return fits[-1] if fits else min(cap, dim)
+
+
+def grouped_matmul(lhs, rhs, group_sizes, tile=None):
     """``lhs[rows of group g] @ rhs[g]`` for every group: lhs (M, K),
     rhs (G, K, N), group_sizes (G,) int32 with sum <= M -> (M, N) in
     lhs's dtype, float32 accumulation; M a multiple of 512. Only the row
@@ -362,10 +369,17 @@ def grouped_matmul(lhs, rhs, group_sizes):
     nothing a caller may read. The Pallas grouped product that ships
     with JAX (megablox: ``gmm`` forward, ``gmm`` against the transposed
     table and ``tgmm`` backward), chosen over ``jax.lax.ragged_dot`` by a
-    chip measurement (docs/smallthinker.md)."""
+    chip measurement (docs/smallthinker.md). ``tile`` (rows,
+    contraction, columns) caps the tile in place of ``_GMM_TILE``, each
+    side then cut to a whole divisor of the operand (a layer of other
+    widths: docs/solar_open2.md)."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
-    tm, tk, tn = _GMM_TILE
-    tiling = (tm, min(tk, rhs.shape[1]), min(tn, rhs.shape[2]))
+    if tile is None:
+        tm, tk, tn = _GMM_TILE
+        tiling = (tm, min(tk, rhs.shape[1]), min(tn, rhs.shape[2]))
+    else:
+        tiling = (tile[0], _whole_tile(rhs.shape[1], tile[1]),
+                  _whole_tile(rhs.shape[2], tile[2]))
     return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
                tiling=tiling, interpret=jax.default_backend() != "tpu")
 
@@ -434,7 +448,7 @@ def _combine_rows_bwd(res, g):
 _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
-def _reglu_chunk(rows, c, x, w, experts, plan):
+def _glu_chunk(rows, activation, tile, c, x, w, experts, plan):
     """The held experts on chunk ``c`` of the sorted assignments: rows
     [c * rows, (c + 1) * rows). Returns this chunk's part of y (T, H)."""
     order, pos, held, ends = plan
@@ -455,22 +469,27 @@ def _reglu_chunk(rows, c, x, w, experts, plan):
         counts = counts.at[-1].add(lo + rows - clipped[-1])
         xs = _take_rows(x, tok, posc, here)
     with scope("moe_experts"):
-        gate = grouped_matmul(xs, experts["w_gate"], counts)
-        up = grouped_matmul(xs, experts["w_up"], counts)
-        act = jnp.where(valid[:, None], jax.nn.relu(gate) * up, 0)
-        ys = grouped_matmul(act, experts["w_down"], counts)
+        gate = grouped_matmul(xs, experts["w_gate"], counts, tile)
+        up = grouped_matmul(xs, experts["w_up"], counts, tile)
+        act = jnp.where(valid[:, None], activation(gate) * up, 0)
+        ys = grouped_matmul(act, experts["w_down"], counts, tile)
     with scope("moe_dispatch"):
         return _combine_rows(ys, w, tok, posc, here, w_sorted)
 
 
-def dropless_reglu_experts(x, idx, p, experts, experts_held, num_experts):
+def dropless_experts(x, idx, p, experts, experts_held, num_experts,
+                     activation, tile=None):
     """``y[t] = sum over the choices j of token t whose expert idx[t, j]
-    is held here of p[t, j] * W_down,e (relu(W_gate,e x[t]) * (W_up,e x[t]))``.
+    is held here of p[t, j] * W_down,e (act(W_gate,e x[t]) * (W_up,e x[t]))``.
 
     x (T, H) in the compute dtype; idx, p (T, k) from
     :func:`route_top_k`; ``experts`` {"w_gate", "w_up": (held, H, F),
     "w_down": (held, F, H)} in the compute dtype; ``experts_held``
-    (first, count) of the ``num_experts`` the router scores. Returns
+    (first, count) of the ``num_experts`` the router scores;
+    ``activation`` the gate's (``jax.nn.relu``: ReGLU, SmallThinker's;
+    ``jax.nn.silu``: SwiGLU); ``tile`` as :func:`grouped_matmul`'s.
+    Trained and served alike: prefill serves a bucket's tokens through
+    it (``models/solar_open2.py``). Returns
     (y (T, H) float32, counts (held,) int32: the assignments that landed
     on each held expert). No assignment is dropped whatever the
     imbalance, and the time does not follow it. Traced under the scopes ``moe_route`` (the sort),
@@ -492,7 +511,8 @@ def dropless_reglu_experts(x, idx, p, experts, experts_held, num_experts):
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
         pos = jnp.argsort(order).astype(jnp.int32).reshape(t, top_k)
         w = jnp.where(held, p, 0.0).astype(jnp.float32)
-    chunk = jax.checkpoint(functools.partial(_reglu_chunk, rows))
+    chunk = jax.checkpoint(
+        functools.partial(_glu_chunk, rows, activation, tile))
 
     def turn(y, c):
         part = chunk(c, x, w, experts, (order, pos, held, ends))
@@ -501,4 +521,50 @@ def dropless_reglu_experts(x, idx, p, experts, experts_held, num_experts):
 
     y, _ = jax.lax.scan(turn, jnp.zeros(x.shape, jnp.float32),
                         jnp.arange(-(-t * top_k // rows)))
+    return y, counts
+
+
+def dropless_reglu_experts(x, idx, p, experts, experts_held, num_experts):
+    """:func:`dropless_experts` with ReGLU under the name it had while
+    ReGLU was all it did: ``benchmarks/families/smallthinker.py`` checks
+    the train-8k cell's backward pass through this name, and the
+    benchmark's files are not a model PR's to edit. Nothing in the
+    package calls it."""
+    return dropless_experts(x, idx, p, experts, experts_held, num_experts,
+                            jax.nn.relu)
+
+
+def held_experts_every_row(x, idx, p, experts, experts_held, activation,
+                           active=None):
+    """The same sum as :func:`dropless_experts` for a step of FEW rows
+    (decode: a slot table): every held expert is worked on every row and
+    a row's choices weigh the results, ``c[t, e] = p[t, j]`` where
+    ``idx[t, j]`` is held expert ``e`` and 0 elsewhere. Nothing is
+    sorted or gathered and no shape or trip count knows the router: the
+    work is ``T x held`` rows whatever lands here, and the tables are
+    read once, which is what bounds the step (a held expert sees a
+    handful of rows; its table is read whole either way). Three plain
+    products: ``(T, H) x (held, H, F)`` twice and ``(held x T, F)``
+    against ``(held, F, H)`` summed over the experts.
+
+    x (T, H); ``active`` (T,) bool: rows that count (None: all). Returns
+    (y (T, H) float32, counts (held,) int32: the assignments of ACTIVE
+    rows that landed on each held expert)."""
+    first, count = experts_held
+    with scope("moe_route"):
+        local = idx - first                                   # (T, k)
+        hit = local[..., None] == jnp.arange(count)           # (T, k, held)
+        if active is not None:
+            hit = hit & active[:, None, None]
+        weight = jnp.sum(jnp.where(hit, p[..., None], 0.0), axis=1)
+        counts = jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)
+    with scope("moe_experts"):
+        rows = jnp.broadcast_to(x, (count,) + x.shape)        # (held, T, H)
+        gate = jnp.einsum("eth,ehf->etf", rows, experts["w_gate"],
+                          preferred_element_type=jnp.float32)
+        up = jnp.einsum("eth,ehf->etf", rows, experts["w_up"],
+                        preferred_element_type=jnp.float32)
+        act = (activation(gate) * up * weight.T[..., None]).astype(x.dtype)
+        y = jnp.einsum("etf,efh->th", act, experts["w_down"],
+                       preferred_element_type=jnp.float32)
     return y, counts

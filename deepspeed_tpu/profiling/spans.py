@@ -54,6 +54,11 @@ DEVICE_SCOPES = (
     # covers the kernels of both kinds; and the routed expert layer
     "attn_window", "attn_global",
     "moe_route", "moe_dispatch", "moe_experts",
+    # served hybrids (models/solar_open2.py): the delta-rule layers'
+    # projections, convolution and gates, their prefill recurrence and
+    # their one-token state update; the softmax layers' output gate; the
+    # shared expert beside the routed ones
+    "kda_proj", "kda_scan", "kda_state", "attn_gate", "moe_shared",
 )
 
 # host phase spans (TraceAnnotation), each parent before its children
@@ -61,7 +66,7 @@ HOST_SPANS = (
     "train_batch", "data", "train/dispatch", "train/tail",
     "forward", "backward", "step", "eval",
     "pipe/stack_batch", "pipe/train_batch", "pipe/eval_batch",
-    "serve/admit",
+    "serve/admit", "serve/plan",
     "serve/prefill", "serve/prefill/build", "serve/prefill/dispatch",
     "serve/prefill/wait",
     "serve/chunk", "serve/chunk/build", "serve/chunk/dispatch",

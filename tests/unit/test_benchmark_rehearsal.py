@@ -84,3 +84,197 @@ def test_the_cells_controls_rehearse_on_the_cpu(tmp_path):
     assert len([ln for ln in out.stdout.splitlines()
                 if ln.startswith("[bench] control") and ln.endswith(":")]
                ) == 6
+
+
+# --------------------------------------------------------------------- #
+# ISSUE 37: solar-open2-250b.serve-rollout-saturated, a served cell found
+# through families/solar_open2.py
+# --------------------------------------------------------------------- #
+SERVED = "solar-open2-250b.serve-rollout-saturated"
+BENCH = os.path.join(REPO, "benchmarks")
+
+
+def test_served_cell_rehearses_through_its_family(tmp_path):
+    """`run.py --rehearse-cpu --trace 1`: the state pool, both prefill
+    buckets and decode through the engine, the served tokens through the
+    plain reference, the counters' metrics through their readers."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    out = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
+         SERVED, "--seed", "3000000019", "--seconds", "2", "--trace", "1",
+         "--rehearse-cpu"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=900)
+    assert out.returncode == 3, out.stdout[-2000:] + out.stderr[-2000:]
+    last = [ln for ln in out.stdout.splitlines()
+            if ln.startswith("[bench] rehearsal on cpu")]
+    line = json.loads(last[0].split("): ", 1)[1])
+    assert line["correct"] and line["failed"] == 0 and line["attempted"] > 0
+    assert line["compared"]["served_logit_gap"]["value"] <= \
+        line["compared"]["served_logit_gap"]["limit"]
+    # a slot's state counts in what the pools hold
+    assert line["device"]["kv_pool_bytes"] > 4 * 3 * 4 * 32 * 32 * 4
+    metrics = line["metrics"]
+    assert 0 < metrics["expert_held_share.roll"]["value"] <= 100
+    assert metrics["expert_load_max_over_mean.roll"]["value"] >= 100
+    assert metrics["slot_occupancy.sat"]["value"] > 0
+    assert "state pool" in out.stdout
+
+
+def test_the_served_cell_is_declared_as_the_issue_names_it():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cell = next(w for w in bench["workloads"] if w["name"] == SERVED)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        ("solar-open2-250b", "serve-rollout-saturated", 1)
+    assert bench["workloads"][-1] is cell and len(cell["why"]) <= 200
+    entry = next(c for c in bench["configs"]
+                 if c["name"] == "solar-open2-250b")
+    assert entry["reduced"] == ["num_hidden_layers", "n_routed_experts",
+                                "vocab_size"]
+    with open(os.path.join(BENCH, "traffic",
+                           "serve-rollout-saturated.json")) as f:
+        traffic = json.load(f)
+    assert (traffic["kind"], traffic["backlog_requests"],
+            traffic["epoch_requests"], traffic["order_seed"],
+            traffic["ramp_s"]) == ("serve_backlog", 8192, 64, 37, 25)
+    assert traffic["prompt"] == {"median": 192, "sigma": 0.8, "low": 32,
+                                 "high": 1024}
+    assert traffic["output"] == {"median": 192, "sigma": 0.5, "low": 64,
+                                 "high": 512}
+    with open(os.path.join(REPO, entry["file"])) as f:
+        config = json.load(f)
+    # every number of the catalog's row under its own key, but the three
+    # that are reduced
+    published = {
+        "model_type": "solar_open2", "partial_rotary_factor": 1,
+        "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": 128,
+                               "num_heads": 64, "num_kv_heads": None},
+        "hidden_size": 4096, "num_attention_heads": 64, "head_dim": 128,
+        "num_key_value_heads": 8, "intermediate_size": 10240,
+        "moe_intermediate_size": 1280, "rms_norm_eps": 1e-05,
+        "rope_theta": 10000, "tie_word_embeddings": False,
+        "max_position_embeddings": 1048576, "first_k_dense_replace": 0,
+        "use_rope": False, "gqa_interval": 3,
+        "gqa_layers": list(range(0, 48, 4)), "use_gqa_gate": True,
+        "kda_use_full_proj": False, "kda_allow_neg_eigval": True,
+        "n_shared_experts": 1, "norm_topk_prob": True,
+        "routed_scaling_factor": 1, "num_experts_per_tok": 8}
+    assert {k: config[k] for k in published} == published
+    assert (config["num_hidden_layers"], config["n_routed_experts"],
+            config["vocab_size"]) == (4, 40, 24576)
+    assert config["published"] == {"num_hidden_layers": 48,
+                                   "n_routed_experts": 320,
+                                   "vocab_size": 196608}
+    assert config["router_outputs"] == 320
+    assert {"hidden_act", "router_score", "gqa_gate", "decay",
+            "state_precision"} <= set(config["assumed"])
+    inference = config["serve"]["inference"]
+    assert inference["max_batch_size"] == 192
+    assert inference["max_seq_len"] == 1536
+    assert inference["paged_kv"]["prefix_cache"] is False
+    assert SERVED in next(m for m in bench["end_to_end"]
+                          if m["name"] == "serve_tokens_per_s")["workloads"]
+    reported = {m["name"] for m in bench["per_layer"]
+                if SERVED in m.get("workloads", [])}
+    assert {"kda_state_hbm_roofline.roll", "kda_scan_roofline.roll",
+            "moe_experts_hbm_roofline.roll", "decode_hbm_roofline.roll",
+            "decode_scope_kda_ms.roll", "decode_scope_moe_ms.roll",
+            "decode_scope_attn_ms.roll", "prefill_scope_kda_scan_ms.roll",
+            "expert_held_share.roll", "expert_load_max_over_mean.roll",
+            "decode_step_device_ms.sat", "prefill_device_ms.sat",
+            "decode_unscoped_share.sat", "decode_inherited_share.sat",
+            "serve_host_gap_ms.sat", "slot_occupancy.sat",
+            "prefill_pad_share.sat",
+            "serve_gap_unattributed_share.sat"} <= reported
+    for name in reported:
+        assert os.path.exists(os.path.join(BENCH, "metrics",
+                                           name + ".json")), name
+
+
+def _served_in_process(seed, monkeypatch, alter=None):
+    """`kinds/serve_backlog.run` at the cell's tiny sizes in this
+    process, past the harness's look for a chip; `alter(runs)` changes
+    the tokens a decode dispatch hands the scheduler."""
+    import jax
+    from deepspeed_tpu.inference.scheduler import Scheduler
+    for path in (REPO, BENCH):
+        if path not in sys.path:
+            sys.path.insert(0, path)
+    import run as bench_run
+    from core import device as dev
+    from loader import load_module
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cell, entry = bench_run.find_cell(bench, SERVED)
+    with open(os.path.join(REPO, entry["file"])) as f:
+        config = json.load(f)
+    traffic = bench_run.load_json("traffic", cell["traffic"] + ".json")
+    config = {**config, **config["tiny"]}
+    # wider weights than 0.02, which at hidden 64 leaves every logit
+    # within 0.01 of every other, so that no token is wrong
+    config["initializer_range"] = 0.2
+    traffic = {**traffic, **traffic["tiny"]}
+    if alter is not None:
+        plain = Scheduler.record_token_runs
+        monkeypatch.setattr(
+            Scheduler, "record_token_runs",
+            lambda self, runs, *a, **kw: plain(self, alter(runs), *a, **kw))
+    ctx = bench_run.Context(
+        cell=cell, config=config, traffic=traffic, seed=seed, seconds=1.0,
+        trace=False, devices=jax.devices()[:1], peaks=None,
+        compiles=dev.CompileCounter(), log=lambda msg: None, setup_s=None)
+    return load_module("kinds", traffic["kind"]).run(ctx)
+
+
+def test_an_altered_served_token_of_the_served_cell_is_not_correct(
+        monkeypatch):
+    sound = _served_in_process(1000003, monkeypatch)
+    assert sound["correct"], sound["why_not"]
+    limit = sound["compared"]["served_logit_gap"]["limit"]
+    assert sound["compared"]["served_logit_gap"]["value"] <= limit
+
+    def every_token_one_up(runs):
+        return {sid: [(int(t) + 1) % 128 for t in run]
+                for sid, run in runs.items()}
+    broken = _served_in_process(1000003, monkeypatch, every_token_one_up)
+    assert not broken["correct"]
+    assert any("below the reference's pick" in why
+               for why in broken["why_not"]), broken["why_not"]
+    assert broken["compared"]["served_logit_gap"]["value"] > 5 * limit
+
+
+def test_the_served_cells_controls_rehearse_on_the_cpu(tmp_path):
+    """`benchmarks/tools/serve_controls.py` at the tiny sizes: the
+    reference, the nearest precision below the stated one (float8
+    products) and the three that are shown only run through the cell's
+    own comparison. (At hidden 64 the logits lie 0.16 apart in the mean,
+    so nothing reaches the cell's limit here: the tool judges at the
+    published widths on the chip, PERF.md has the readings. What shows
+    here is the ORDER: float8 products move the logits at least ten
+    times as far as bfloat16 ones, a bfloat16 state no farther than
+    those.)"""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    out = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "tools", "serve_controls.py"),
+         "--workload", SERVED, "--seed", "5", "--requests", "4",
+         "--rehearse-cpu"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=900)
+    said = [ln for ln in out.stdout.splitlines()
+            if ln.startswith("[bench] control")]
+    assert len(said) == 5, out.stdout[-3000:] + out.stderr[-2000:]
+    assert "control reference" in said[0] and \
+        ": correct, has to be correct" in said[0]
+    assert "has to be NOT correct" in said[1]
+    assert all("shown only" in ln for ln in said[2:])
+    readings = json.loads(out.stdout.splitlines()[-1])
+    assert set(readings) == {"reference", "products_float8_e5m2",
+                             "products_bfloat16", "state_bfloat16",
+                             "recurrence_bfloat16"}
+    moved = {k: v["rms_from_reference"] for k, v in readings.items()}
+    assert moved["reference"] == 0.0
+    assert moved["products_float8_e5m2"] > 10 * moved["products_bfloat16"]
+    assert moved["state_bfloat16"] < 2 * moved["products_bfloat16"]
+    assert readings["products_float8_e5m2"]["worst_gap"] > \
+        10 * readings["reference"]["worst_gap"]

@@ -228,13 +228,15 @@ def test_no_token_is_dropped_when_one_expert_takes_half():
     experts = jax.tree_util.tree_map(lambda a: a[:2],
                                      _experts(jax.random.PRNGKey(2)))
     rows = moe.chunk_rows(t * k, 2, 8)
-    y, counts = moe.dropless_reglu_experts(x, idx, p, experts, (0, 2), 8)
+    y, counts = moe.dropless_experts(x, idx, p, experts, (0, 2), 8,
+                                     jax.nn.relu)
     assert int(counts[0]) == t and int(counts.sum()) > rows
     want = _dense_experts(x, idx, p, experts, 0)
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-5)
     loss = lambda f: lambda x, p, e: jnp.sum(jnp.sin(f(x, p, e)))
-    got = jax.grad(loss(lambda x, p, e: moe.dropless_reglu_experts(
-        x, idx, p, e, (0, 2), 8)[0]), argnums=(0, 1, 2))(x, p, experts)
+    got = jax.grad(loss(lambda x, p, e: moe.dropless_experts(
+        x, idx, p, e, (0, 2), 8, jax.nn.relu)[0]),
+        argnums=(0, 1, 2))(x, p, experts)
     ref = jax.grad(loss(lambda x, p, e: _dense_experts(x, idx, p, e, 0)),
                    argnums=(0, 1, 2))(x, p, experts)
     for a, b in zip(jax.tree_util.tree_leaves(got),
@@ -249,10 +251,10 @@ def test_the_four_shares_add_up_to_the_whole_layer(model):
     whole = _experts(jax.random.PRNGKey(5), h=cfg.hidden_size, f=32)
     h2 = _layer_input(cfg, seed=7)[0]
     idx, p, r = moe.route_top_k(h2, params["h_0"]["router"], 3)
-    parts = [moe.dropless_reglu_experts(
+    parts = [moe.dropless_experts(
         h2, idx, p,
         jax.tree_util.tree_map(lambda a: a[first:first + 2], whole),
-        (first, 2), 8)[0] for first in (0, 2, 4, 6)]
+        (first, 2), 8, jax.nn.relu)[0] for first in (0, 2, 4, 6)]
     want = reference._experts(h2, r, p, idx, whole, 0, None)
     np.testing.assert_allclose(np.asarray(sum(parts)), np.asarray(want),
                                atol=2e-5)

@@ -657,6 +657,136 @@ def test_grouped_product_compiles_at_the_expert_widths(monkeypatch, k, n,
     assert calls >= {"fwd": 1, "bwd": 2}[direction]
 
 
+@pytest.mark.parametrize("k,n", [(4096, 1280), (1280, 4096)],
+                         ids=["gate_up", "down"])
+def test_grouped_product_compiles_at_the_served_expert_widths(monkeypatch,
+                                                              k, n):
+    """Solar-Open2's experts (4,096 x 1,280, 40 held) through the capped
+    tile of models/solar_open2.py, a prefill bucket's turn of rows."""
+    from deepspeed_tpu.models.solar_open2 import _EXPERT_TILE
+    from deepspeed_tpu.ops import moe
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _compile(
+        lambda lhs, rhs, sizes: moe.grouped_matmul(lhs, rhs, sizes,
+                                                   _EXPERT_TILE),
+        _spec((8192, k)), _spec((40, k, n)), _spec((40,), jnp.int32))
+    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+
+
+def test_delta_rule_decode_update_compiles_in_place_at_published_widths(
+        monkeypatch):
+    """The one-token state update of ops/kda.py over the cell's 193 rows
+    of 64 heads of 128 x 128, two layers of a three-layer pool: Mosaic
+    takes the kernel, the pool is aliased through both calls and nothing
+    of its size is copied."""
+    from deepspeed_tpu.ops import kda
+    rows, heads, d = 193, 64, 128
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def two_layers(pool, q, k, v, g, beta):
+        o, pool = kda.kda_decode_update(pool, 0, q, k, v, g, beta)
+        return kda.kda_decode_update(pool, 2, q, k, o, g, beta)
+
+    vec = _spec((rows, heads, d), jnp.float32)
+    compiled = jax.jit(two_layers, donate_argnums=(0,)).lower(
+        _spec((3, rows, heads, d, d), jnp.float32), vec, vec, vec, vec,
+        _spec((rows, heads), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    memory = compiled.memory_analysis()
+    pool_bytes = 3 * rows * heads * d * d * 4
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < pool_bytes // 100
+    assert not re.search(r"= f32\[3,193,64,128,128\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("rows,seq", [(1, 256), (4, 1024)])
+def test_delta_rule_chunk_scan_compiles_at_published_widths(rows, seq):
+    """The prefill recurrence over the cell's smallest and largest
+    bucket at 64 heads of 128: chunks of 64, the triangular solve, the
+    scan that carries the state."""
+    from deepspeed_tpu.ops import kda
+    x = _spec((rows, seq, 64, 128), jnp.float32)
+    compiled = _compile(
+        kda.kda_chunk_scan, x, x, x, x, _spec((rows, seq, 64), jnp.float32),
+        _spec((rows, 64, 128, 128), jnp.float32),
+        _spec((rows,), jnp.int32))
+    memory = compiled.memory_analysis()
+    # what a bucket holds beside 9.7 GB of weights and state
+    assert memory.temp_size_in_bytes < 2.5e9
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_solar_open2_serving_programs_keep_the_pools_in_place(monkeypatch,
+                                                              program):
+    """The benchmark configuration's decode program (193 rows) and its
+    smallest prefill bucket (1 x 256) at the published widths, weights
+    held in bfloat16, the cache tree donated: the page pools and the
+    state pool are aliased through the four layers and the compiled
+    program holds nothing of the state pool's size beside it."""
+    import json
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(__file__), "..", "..", "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from families import solar_open2 as family
+    from deepspeed_tpu.inference.kv_cache import (PagedStateCache,
+                                                  paged_spec_for,
+                                                  state_pool_spec_for)
+    from deepspeed_tpu.models import solar_open2 as so
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(bench, "configs", "solar-open2-250b.json")) as f:
+        config = json.load(f)
+    model = family.serve_model_of(config)
+    inference = config["serve"]["inference"]
+    rows = inference["max_batch_size"] + 1
+    pages = paged_spec_for(model, inference["paged_kv"]["num_pages"], 16,
+                           inference["max_seq_len"])
+    state = state_pool_spec_for(model, rows)
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: so.init_solar_open2_params(
+            model, jax.random.PRNGKey(0))))
+    cache = PagedStateCache(_spec(pages.shape), _spec(pages.shape),
+                            _spec(state.state_shape, jnp.float32),
+                            _spec(state.tail_shape))
+    ints = lambda *shape: _spec(shape, jnp.int32)
+
+    def decode(params, cache, toks, positions, tables):
+        logits, cache, counts = so.solar_open2_forward(
+            params, model, toks[:, None], kv_cache=cache,
+            cache_position=positions, block_tables=tables,
+            paged_attn_kernel="pallas", active=tables[:, 0] > 0,
+            with_counts=True)
+        return jnp.argmax(logits[:, 0], -1), counts, cache
+
+    def prefill(params, cache, ids, lengths, tables, slots):
+        logits, cache = so.solar_open2_forward(
+            params, model, ids, kv_cache=cache,
+            cache_position=jnp.zeros_like(lengths), block_tables=tables,
+            paged_attn_kernel="pallas", lengths=lengths, slots=slots)
+        return jnp.argmax(logits[:, 0], -1), cache
+
+    if program == "decode":
+        fn, args = decode, (ints(rows), ints(rows),
+                            ints(rows, pages.pages_per_seq))
+    else:
+        fn, args = prefill, (ints(1, 256), ints(1),
+                             ints(1, pages.pages_per_seq), ints(1))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    memory = compiled.memory_analysis()
+    pool_bytes = int(np.prod(state.state_shape)) * 4
+    assert memory.alias_size_in_bytes >= pool_bytes + 2 * int(
+        np.prod(pages.shape)) * 2
+    assert memory.temp_size_in_bytes < pool_bytes // 4
+    text = compiled.as_text()
+    assert not re.search(r"= f32\[3,193,64,128,128\]\S* copy\(", text)
+    # the weights as they are held: 3.3B parameters in bfloat16
+    assert 9.6e9 < memory.argument_size_in_bytes < 9.8e9
+
+
 def test_smallthinker_train_step_compiles_at_the_cut_widths(monkeypatch):
     """`deepspeed_tpu.initialize` + the ONE compiled `_micro_step`
     (ZeRO-2, bf16, Adam, clipping) of the benchmark's configuration at

@@ -298,7 +298,12 @@ def test_serving_trace_holds_spans_in_order_with_scheduler_counters(
     # one step, in order: admission, its prefills with their bookkeeping,
     # the decode dispatch, tokens recorded, metrics
     first = [ev[0] for ev in events if ev[1] < decodes[1][1]
-             and ev[0].count("/") == 1]
+             and ev[0].count("/") == 1 and ev[0] != "serve/plan"]
+    # (the walk over the slots that makes the decode dispatch's rows and
+    # its span's counters comes right before it, under its own name)
+    plans = [ev for ev in events if ev[0] == "serve/plan"]
+    assert len(plans) == 2 * len(decodes)
+    assert all(p[2] <= d[1] for p, d in zip(plans[1::2], decodes))
     assert first[0] == "serve/admit"
     assert first[1:4] == ["serve/prefill", "serve/record", "serve/metrics"]
     i = first.index("serve/decode")
