@@ -119,7 +119,7 @@ from deepspeed_tpu.models.solar_open2 import (SolarOpen2Config,
                                               solar_open2_forward,
                                               solar_open2_param_specs)
 from deepspeed_tpu.ops.attention.flash import NEG_INF
-from deepspeed_tpu.ops.attention.paged import (live_pages,
+from deepspeed_tpu.ops.attention.paged import (block_pages, live_pages,
                                                paged_decode_supported)
 from deepspeed_tpu.parallel.mesh import axis_size, build_mesh
 from deepspeed_tpu.profiling.recompile import CompileTracker
@@ -1938,13 +1938,18 @@ class InferenceEngine:
                         self._decode_page_buckets)
                     counters["table_pages"] = width
                     # what the Pallas kernel walks: each row's live
-                    # pages, an inactive row's null page once; the
-                    # gather reader walks none (it reads the table's
-                    # whole width)
-                    counters["read_pages"] = (
-                        sum(live_pages(p, self.paged_spec.page_size)
-                            for p in poss) + self._rows - len(sids)
-                        if self._decode_attn_path == "pallas" else 0)
+                    # pages, ``block_pages`` a loop turn, an inactive
+                    # row's null page in one turn; the gather reader
+                    # walks none (it reads the table's whole width)
+                    ps = self.paged_spec.page_size
+                    walks = ([live_pages(p, ps) for p in poss]
+                             + [1] * (self._rows - len(sids))
+                             if self._decode_attn_path == "pallas" else [])
+                    per_turn = block_pages(ps)
+                    counters.update(
+                        read_pages=sum(walks),
+                        read_turns=sum(-(-w // per_turn) for w in walks),
+                        block_tokens=per_turn * ps if walks else 0)
                 if self._expert_counters is not None:
                     # routed experts: the rows that decode, and what the
                     # router did with them the step before

@@ -42,6 +42,12 @@ Design:
   streaming idiom) — 2 blocks of VMEM per stream at any pool size. Of
   a row's last block only the live pages are copied; the tail is
   masked.
+- **One stream of blocks across the sequences.** The grid runs in
+  order, and a walk's LAST turn issues the next sequence's first block
+  into the free slot (scratch and semaphores outlive a grid step), so
+  only the call's first walk starts with nothing in flight (ISSUE 38:
+  at about two turns a walk the un-overlapped first block was most of
+  a program).
 - **Online softmax in fp32.** Running (m, l, acc) across the walk, keys
   and values to the MXU in the pool dtype (bf16 in production) with
   fp32 accumulation, and the probabilities NOT rounded to that dtype
@@ -89,7 +95,7 @@ from deepspeed_tpu.ops.attention.flash import NEG_INF, _use_pallas
 
 __all__ = ["paged_decode_attention", "paged_decode_reference",
            "paged_decode_supported", "decode_read_bytes",
-           "live_pages", "dequantize_pool", "quantize_kv"]
+           "live_pages", "block_pages", "dequantize_pool", "quantize_kv"]
 
 
 def live_pages(cache_position, page_size: int):
@@ -245,16 +251,21 @@ def paged_decode_reference(q, kpool, vpool, block_tables, cache_position,
 # --------------------------------------------------------------------- #
 # the kernel
 # --------------------------------------------------------------------- #
-# Tokens a loop turn: a block is this many tokens' worth of pages (at
-# least one page). 128, one MXU tile of keys, is the fast setting: the
-# decode call of ``gpt2-345m.serve-saturated`` spends 7.7 ms in the
-# kernel there and 18.0 ms at 16 (PERF.md §6, PR 30) — but the cell then
-# serves 3,900 tokens/s, and its backlog of 4,032 requests runs dry
-# above 3,180 (the run aborts, by design of the harness). Only a
-# ``benchmark`` PR may enlarge the backlog, so until one has (ROADMAP
-# A2) a turn is ONE page of 16 tokens, which the driver can measure
-# (3,016 tokens/s). Every block size is tested and compiled.
-_BLOCK_TOKENS = 16
+# Tokens a loop turn: a block is this many tokens' worth of whole pages
+# (at least one page). 128 is one MXU tile of keys, 8 pages of 16, and
+# the best size at GPT-2 345M's serving shape (160 rows of about 170
+# live tokens, 24 layers; ms a decode call in the kernel alone, PERF.md
+# §6, PR 38: 16: 19.5, 64: 8.3, 128: 6.6, 256: 7.1): fewer turns a walk
+# against more of the last block's tile unused. A row with more live
+# tokens only has more turns to save. One constant for every model,
+# chosen by nothing a user sets; every size is tested and compiled.
+_BLOCK_TOKENS = 128
+
+
+def block_pages(page_size: int) -> int:
+    """Pages of ``page_size`` tokens that one loop turn of the walk
+    streams and waits for: whole pages, at least one."""
+    return max(1, _BLOCK_TOKENS // page_size)
 
 
 def _round_up(n: int, to: int) -> int:
@@ -292,7 +303,9 @@ def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref, v_ref,
     """One sequence's program: walk the row's live pages from the pool,
     ``block_pages`` whole pool rows' pages a loop turn through
     double-buffered DMA, and online-softmax EVERY head's query against
-    the streamed block with one pair of dots.
+    the streamed block with one pair of dots. Its first block was
+    issued by the program before it; its last turn issues the next
+    one's.
 
     The heads are separated by the contraction, not by a lane slice.
     ``q_ref`` is ``(1, groups, width)``: row ``g`` holds member ``g``
@@ -314,23 +327,27 @@ def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref, v_ref,
     fp32 exactly like the dense-pool path."""
     if quantized:
         (ks_ref, vs_ref, o_ref, kbuf, vbuf, ksbuf, vsbuf,
-         ksem, vsem, kssem, vssem) = rest
+         ksem, vsem, kssem, vssem, base_ref) = rest
         streams = ((k_ref, kbuf, ksem), (v_ref, vbuf, vsem),
                    (ks_ref, ksbuf, kssem), (vs_ref, vsbuf, vssem))
     else:
-        o_ref, kbuf, vbuf, ksem, vsem = rest
+        o_ref, kbuf, vbuf, ksem, vsem, base_ref = rest
         streams = ((k_ref, kbuf, ksem), (v_ref, vbuf, vsem))
     b = pl.program_id(0)
+    last_seq = pl.num_programs(0) - 1
     layer = layer_ref[0]
     pos = pos_ref[b]
     table_pages = tables_ref.shape[1]
     tokens, width = kbuf.shape[1:]
     block_pages = tokens // page_size
-    # positions 0..pos are attended (this call's token was written
-    # BEFORE attention — write_paged_kv_cache runs first), spanning
-    # exactly pos // page_size + 1 pages: the O(live tokens) bound
-    num_pg = pos // page_size + 1
-    num_blk = (num_pg + block_pages - 1) // block_pages
+
+    def _pages(seq):
+        # positions 0..pos are attended (this call's token was written
+        # BEFORE attention — write_paged_kv_cache runs first), spanning
+        # exactly pos // page_size + 1 pages: the O(live tokens) bound
+        return pos_ref[seq] // page_size + 1
+
+    num_blk = (_pages(b) + block_pages - 1) // block_pages
     groups = q_ref.shape[1]
     kv_rows = _round_up(width // head_dim, 8)
     if groups * kv_rows % 16:
@@ -344,19 +361,22 @@ def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref, v_ref,
         [jnp.where(own, q_ref[0, g:g + 1, :].astype(jnp.float32), 0.0)
          for g in range(groups)], axis=0).astype(q_ref.dtype)
 
-    def _page_id(blk, j):
+    def _page_id(seq, blk, j):
         # clamped: the last block's unwalked tail may lie past the table
-        return tables_ref[b, jnp.minimum(blk * block_pages + j,
-                                         table_pages - 1)]
+        return tables_ref[seq, jnp.minimum(blk * block_pages + j,
+                                           table_pages - 1)]
 
-    def _for_live_pages(blk, fn):
-        """``fn(copy)`` for every copy of block ``blk``'s LIVE pages:
-        the pages past the row's count are never touched."""
-        slot = jax.lax.rem(blk, 2)
-        live = num_pg - blk * block_pages
+    def _live_in(seq, blk):
+        return _pages(seq) - blk * block_pages
+
+    def _for_live_pages(seq, blk, slot, fn):
+        """``fn(copy)`` for every copy of the LIVE pages of sequence
+        ``seq``'s block ``blk``, which lands in ``slot``: the pages past
+        the row's count are never touched."""
+        live = _live_in(seq, blk)
         for j in range(block_pages):
             def _page(j=j):
-                page = _page_id(blk, j)
+                page = _page_id(seq, blk, j)
                 rows = pl.ds(j * page_size, page_size)
                 for ref, buf, sem in streams:
                     fn(pltpu.make_async_copy(ref.at[layer, page],
@@ -367,26 +387,43 @@ def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref, v_ref,
             else:
                 pl.when(j < live)(_page)
 
-    def _start(blk):
-        _for_live_pages(blk, lambda c: c.start())
+    def _start(seq, blk, slot):
+        if block_pages > 1:
+            # what a DMA does not fill (a walk's last block's tail: the
+            # slot keeps an older block's rows) is masked out of the
+            # scores below, but a NaN value times a zero probability is
+            # NaN: the value side of a block that lands short starts
+            # from zeros
+            @pl.when(_live_in(seq, blk) < block_pages)
+            def _zero_values():
+                for buf in (vbuf, vsbuf) if quantized else (vbuf,):
+                    buf[slot] = jnp.zeros(buf.shape[1:], buf.dtype)
+        _for_live_pages(seq, blk, slot, lambda c: c.start())
 
-    if block_pages > 1:
-        # what a DMA never filled (the last block's tail; scratch keeps
-        # the previous program's rows) is masked out of the scores
-        # below, but a NaN value times a zero probability is NaN: the
-        # value side starts from zeros in every program
-        for buf in (vbuf, vsbuf) if quantized else (vbuf,):
-            buf[...] = jnp.zeros(buf.shape, buf.dtype)
-    _start(0)                                         # num_blk >= 1 always
+    # The blocks of ALL the sequences are one stream through the two
+    # slots: a walk's first block is issued by the program BEFORE it,
+    # in its own last turn, so no program starts with nothing in
+    # flight. ``base_ref`` carries the slot of this program's block 0
+    # from grid step to grid step (scratch and semaphores outlive one).
+    @pl.when(b == 0)
+    def _first_walk():
+        base_ref[0] = 0
+        _start(0, 0, 0)
+    base = base_ref[0]
+    base_ref[0] = jax.lax.rem(base + num_blk, 2)
 
     def body(blk, carry):
         m, l, acc = carry
 
-        @pl.when(blk + 1 < num_blk)
+        slot = jax.lax.rem(base + blk, 2)
+        more = blk + 1 < num_blk
+
+        @pl.when(more | (b < last_seq))
         def _prefetch_next():
-            _start(blk + 1)
-        _for_live_pages(blk, lambda c: c.wait())
-        slot = jax.lax.rem(blk, 2)
+            # this walk's next block, else the next walk's first
+            _start(jnp.where(more, b, b + 1), jnp.where(more, blk + 1, 0),
+                   1 - slot)
+        _for_live_pages(b, blk, slot, lambda c: c.wait())
         kt = kbuf[slot]                               # (tokens, width)
         vt = vbuf[slot]
         if quantized:
@@ -409,7 +446,7 @@ def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref, v_ref,
         local = jax.lax.broadcasted_iota(jnp.int32, (1, tokens), 1)
         page_of = jnp.zeros((1, tokens), jnp.int32)
         for j in range(block_pages):
-            page_of = jnp.where(local >= j * page_size, _page_id(blk, j),
+            page_of = jnp.where(local >= j * page_size, _page_id(b, blk, j),
                                 page_of)
         valid = (blk * tokens + local <= pos) & (page_of != 0)
         s = jnp.where(valid, s, NEG_INF)
@@ -442,8 +479,9 @@ def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref, v_ref,
 def _compiler_params(interpret):
     if pltpu is None or interpret:
         return None
-    # a program is one sequence's walk; the sequences are independent
-    return pltpu.CompilerParams(dimension_semantics=("parallel",))
+    # a program is one sequence's walk and issues the next one's first
+    # block: the grid runs in order
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret",
@@ -456,7 +494,8 @@ def _paged_decode_call(q, kpool, vpool, scales, block_tables,
     kernel is handed the whole stacked pool, pinned in HBM, and indexes
     ``layer`` itself: a ``pool[layer]`` operand would be a copy of the
     layer. ``layer`` rides in SMEM beside the tables, so every layer's
-    call is the same kernel."""
+    call is the same kernel. ``block_tokens`` is the tokens a loop turn
+    streams: whole pages."""
     B, H, hd = q.shape
     ps, width = kpool.shape[2:]
     KH = width // hd
@@ -467,7 +506,6 @@ def _paged_decode_call(q, kpool, vpool, scales, block_tables,
     # tiles are fp32
     qg = q.reshape(B, KH, G, hd).transpose(0, 2, 1, 3).reshape(
         B, G, width).astype(jnp.float32 if quantized else kpool.dtype)
-    tokens = max(1, block_tokens // ps) * ps       # whole pages a turn
     kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
                                page_size=ps, head_dim=hd,
                                quantized=quantized)
@@ -476,9 +514,10 @@ def _paged_decode_call(q, kpool, vpool, scales, block_tables,
     pools = [kpool, vpool] + (list(scales) if quantized else [])
     in_specs = [pl.BlockSpec((1, G, width), lambda b, *_: (b, 0, 0))]
     in_specs += [pl.BlockSpec(memory_space=pltpu.HBM)] * len(pools)
-    scratch = [pltpu.VMEM((2, tokens, pool.shape[-1]), pool.dtype)
+    scratch = [pltpu.VMEM((2, block_tokens, pool.shape[-1]), pool.dtype)
                for pool in pools]
     scratch += [pltpu.SemaphoreType.DMA((2,))] * len(pools)
+    scratch += [pltpu.SMEM((1,), jnp.int32)]      # slot of a walk's block 0
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # layer, tables and positions prefetch into SMEM: page ids must
         # be available to index the DMAs before the body runs
@@ -557,4 +596,4 @@ def paged_decode_attention(q, kpool, vpool, block_tables, cache_position,
                               cache_position.astype(jnp.int32),
                               jnp.full((1,), layer, jnp.int32),
                               float(sm_scale), bool(interpret),
-                              _BLOCK_TOKENS)
+                              block_pages(kpool.shape[2]) * kpool.shape[2])

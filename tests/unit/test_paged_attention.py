@@ -71,6 +71,53 @@ def _pool_case(rng, kv_heads, gqa, page_size, pages_per_seq, hd=16,
     return q, kpool, vpool, tables
 
 
+# the blocks (tokens a loop turn) the parity cases run at: one page of
+# 16 a turn, and the shipped 8
+BLOCKS_PARITY = [16, 128]
+
+
+def _parity_case(monkeypatch, block_tokens, heads, kv_heads, hd, pool_dtype,
+                 ps, pos, dead_rows=(), table_pages=None):
+    """The kernel at ``block_tokens`` a loop turn against the float32
+    oracle: rows at ``pos``, of which ``dead_rows`` are inactive slots
+    (all-null tables, position 0) that must come out finite; every
+    reserved-but-unwritten page is NaN."""
+    from deepspeed_tpu.ops.attention import paged
+    from deepspeed_tpu.ops.attention.paged import (
+        paged_decode_attention, paged_decode_reference)
+    monkeypatch.setattr(paged, "_BLOCK_TOKENS", block_tokens)
+    P = table_pages or max(pos) // ps + 2           # one dead column
+    rng = np.random.RandomState(heads + hd + len(pos))
+    q, kpool, vpool, tables = _pool_case(
+        rng, kv_heads=kv_heads, gqa=heads // kv_heads, page_size=ps,
+        pages_per_seq=P, hd=hd, batch=len(pos))
+    live = [b for b in range(len(pos)) if b not in dead_rows]
+    for b in dead_rows:
+        tables[b] = 0                               # an inactive slot
+    for b in live:                                  # reserved, unwritten
+        dead = tables[b, pos[b] // ps + 1:]
+        kpool = kpool.at[LAYER, dead].set(jnp.nan)
+        vpool = vpool.at[LAYER, dead].set(jnp.nan)
+    q, kpool, vpool = (x.astype(pool_dtype) for x in (q, kpool, vpool))
+    tables, pos = jnp.asarray(tables), jnp.asarray(pos, jnp.int32)
+    out = paged_decode_attention(q, kpool, vpool, tables, pos,
+                                 interpret=True, layer=LAYER)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    assert bool(jnp.all(jnp.isfinite(out)))
+    if not live:
+        return
+    live = np.asarray(live)
+    clean = [jnp.nan_to_num(x.astype(jnp.float32))
+             for x in (q, kpool, vpool)]
+    ref = paged_decode_reference(clean[0][live], clean[1], clean[2],
+                                 tables[live], pos[live], layer=LAYER)
+    # the output is rounded to q's dtype once
+    atol = 2e-5 if pool_dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(out[live].astype(jnp.float32)), np.asarray(ref),
+        atol=atol)
+
+
 class TestKernelParity:
     @pytest.mark.parametrize("gqa", [1, 4])
     @pytest.mark.parametrize("page_size", [8, 16, 128])
@@ -97,7 +144,7 @@ class TestKernelParity:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5)
 
-    @pytest.mark.parametrize("block_tokens", [16, 128],
+    @pytest.mark.parametrize("block_tokens", BLOCKS_PARITY,
                              ids=["page_a_turn", "block128"])
     @pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.bfloat16],
                              ids=["float32", "bf16"])
@@ -105,56 +152,67 @@ class TestKernelParity:
         (16, 16, 64),       # GPT-2 345M: rows of 1,024 lanes
         (25, 25, 64),       # GPT-2 XL: 1,600, heads not a sublane tile
         (32, 8, 128),       # llama-sized GQA: 8 x 128
-    ], ids=["mha16x64", "mha25x64", "gqa32_8x128"])
+        (64, 8, 128),       # Solar-Open2's softmax layer: 8 query groups
+    ], ids=["mha16x64", "mha25x64", "gqa32_8x128", "gqa64_8x128"])
     def test_parity_at_served_widths_and_block_edges(self, monkeypatch,
                                                      heads, kv_heads, hd,
                                                      pool_dtype,
                                                      block_tokens):
-        """ISSUE 30: the kernel streams whole pool rows, a block of
-        pages a loop turn, at every head width — at the shipped block
-        (one page of 16) and at 8 pages, the setting that waits for a
-        benchmark that can hold its speed. Pages of 16; rows at
-        ``cache_position`` 0, with fewer live pages than a block, with
-        exactly a block (its last token and one short of it), with one
-        page more, and two blocks and a tail; the last row is an
-        inactive slot (all-null table), which must come out finite.
-        bf16 pools take the MXU path whose probabilities are split, not
-        rounded: the float32 oracle over the same bf16 values is met to
-        float32 accuracy."""
-        from deepspeed_tpu.ops.attention import paged
-        from deepspeed_tpu.ops.attention.paged import (
-            paged_decode_attention, paged_decode_reference)
-        monkeypatch.setattr(paged, "_BLOCK_TOKENS", block_tokens)
+        """ISSUE 30, ISSUE 38: the kernel streams whole pool rows, a
+        block of pages a loop turn, at every head width — at the shipped
+        block (8 pages of 16: one MXU tile of keys) and at one page a
+        turn. Pages of 16; rows at ``cache_position`` 0, with fewer live
+        pages than a block, with exactly a block (its last token and one
+        short of it), with one page more, and two blocks and a tail; the
+        last row is an inactive slot (all-null table), which must come
+        out finite. bf16 pools take the MXU path whose probabilities are
+        split, not rounded: the float32 oracle over the same bf16 values
+        is met to float32 accuracy."""
         ps = 16
         blk = 128                   # the edges of the widest block tested
         pos = [0, 3 * ps + 5, blk - 2, blk - 1, blk, blk + ps - 1,
                2 * blk + 2 * ps + 7, 0]
-        P = max(pos) // ps + 2                      # one dead column
-        rng = np.random.RandomState(heads + hd)
-        q, kpool, vpool, tables = _pool_case(
-            rng, kv_heads=kv_heads, gqa=heads // kv_heads, page_size=ps,
-            pages_per_seq=P, hd=hd, batch=len(pos))
-        tables[-1] = 0                              # the inactive slot
-        for b, p in enumerate(pos[:-1]):            # reserved, unwritten
-            dead = tables[b, p // ps + 1:]
-            kpool = kpool.at[LAYER, dead].set(jnp.nan)
-            vpool = vpool.at[LAYER, dead].set(jnp.nan)
-        q, kpool, vpool = (x.astype(pool_dtype) for x in (q, kpool, vpool))
-        tables, pos = jnp.asarray(tables), jnp.asarray(pos, jnp.int32)
-        out = paged_decode_attention(q, kpool, vpool, tables, pos,
-                                     interpret=True, layer=LAYER)
-        assert out.shape == q.shape and out.dtype == q.dtype
-        assert bool(jnp.all(jnp.isfinite(out)))
-        live = slice(0, len(pos) - 1)
-        clean = [jnp.nan_to_num(x.astype(jnp.float32))
-                 for x in (q, kpool, vpool)]
-        ref = paged_decode_reference(clean[0][live], clean[1], clean[2],
-                                     tables[live], pos[live], layer=LAYER)
-        # the output is rounded to q's dtype once
-        atol = 2e-5 if pool_dtype == jnp.float32 else 2e-2
-        np.testing.assert_allclose(
-            np.asarray(out[live].astype(jnp.float32)), np.asarray(ref),
-            atol=atol)
+        _parity_case(monkeypatch, block_tokens, heads, kv_heads, hd,
+                     pool_dtype, ps, pos, dead_rows=(len(pos) - 1,))
+
+    @pytest.mark.parametrize("block_tokens", BLOCKS_PARITY,
+                             ids=["page_a_turn", "block128"])
+    @pytest.mark.parametrize("ps,pos,table_pages,dead_rows", [
+        # a table NARROWER than a block: the engine clamps it to the
+        # batch's live-page bucket (4 pages under a block of 8)
+        (16, [0, 15, 16, 37, 63], 4, ()),
+        # pages of 8: 16 pages a turn
+        (8, [0, 7, 8, 126, 127, 128, 135, 300], None, ()),
+        # the sequences' blocks are ONE stream through the two slots (a
+        # program issues the next walk's first block): live rows and
+        # all-null tables alternate, so every walk follows, and is
+        # followed by, one of the other kind and of another length
+        (16, [0, 200, 0, 5, 0, 127, 0, 128, 0], None, (0, 2, 4, 6, 8)),
+        (16, [0, 0, 129, 0], None, (0, 1, 3)),
+        # batch 1: the first walk is also the last
+        (16, [150], None, ()),
+        (16, [0], None, (0,)),
+    ], ids=["table_narrower_than_block", "pages_of_8", "alternate_null",
+            "null_first_and_last", "batch1", "batch1_null"])
+    def test_parity_at_walk_edges(self, monkeypatch, block_tokens, ps, pos,
+                                  table_pages, dead_rows):
+        """ISSUE 38: what a wide block and the lead from walk to walk
+        make matter, at GPT-2 345M's row over a bf16 pool."""
+        _parity_case(monkeypatch, block_tokens, 16, 16, 64, jnp.bfloat16,
+                     ps, pos, dead_rows=dead_rows, table_pages=table_pages)
+
+    def test_shipped_block_is_a_tested_and_compiled_size(self):
+        """The walk's block is one constant; whatever it is moved to is
+        a size the parity cases above run at and the described chip's
+        compiler has taken (test_tpu_compile.py)."""
+        from deepspeed_tpu.ops.attention import paged
+        from tests.unit.test_tpu_compile import BLOCKS_COMPILED
+        assert paged._BLOCK_TOKENS in BLOCKS_PARITY
+        assert paged._BLOCK_TOKENS in BLOCKS_COMPILED
+        # whole pages at every page size, at least one
+        assert paged.block_pages(8) * 8 == paged._BLOCK_TOKENS
+        assert paged.block_pages(16) * 16 == paged._BLOCK_TOKENS
+        assert paged.block_pages(2 * paged._BLOCK_TOKENS) == 1
 
     def test_bf16_probabilities_are_not_rounded(self):
         """Point 4 of ISSUE 30: over a bf16 pool the probabilities reach

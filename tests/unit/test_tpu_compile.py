@@ -271,19 +271,52 @@ def test_paged_decode_bf16_head128_compiles():
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("block_tokens", [16, 64, 128, 256])
+# the blocks (tokens a loop turn) the walk is compiled at; the shipped
+# one is among them (test_paged_attention.py holds it to that)
+BLOCKS_COMPILED = [16, 64, 128, 256]
+
+
+@pytest.mark.parametrize("block_tokens", BLOCKS_COMPILED)
 @pytest.mark.parametrize("head_dim,heads,kv_heads", [
-    (64, 16, 16), (128, 32, 8)], ids=["gpt2_345m", "llama_gqa"])
+    (64, 16, 16), (128, 32, 8), (128, 64, 8)],
+    ids=["gpt2_345m", "llama_gqa", "solar_open2_gqa"])
 def test_paged_decode_compiles_at_every_block(monkeypatch, head_dim, heads,
                                               kv_heads, block_tokens):
     """The walk's block (tokens a loop turn) is one constant, shipped at
-    one page of 16 for what the benchmark can hold (ISSUE 30): the
-    settings a later PR may move it to compile today."""
+    128 = 8 pages of 16 (ISSUE 38): it and the settings a later PR may
+    move it to compile today, at every served geometry (Solar-Open2's 8
+    query groups make the widest accumulator)."""
     from deepspeed_tpu.ops.attention import paged
     monkeypatch.setattr(paged, "_BLOCK_TOKENS", block_tokens)
     compiled = _compile(_paged_decode, *_paged_decode_specs(
         head_dim, 16, jnp.bfloat16, heads, kv_heads))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sharded_paged_decode_compiles_across_four_chips():
+    """The serving mesh's reader (``sharded_paged_decode``: q over heads,
+    the pools over their rows, no collectives) wraps the same walk in
+    ``shard_map``: the in-order grid and the slot it carries from grid
+    step to grid step (ISSUE 38) compile for the four chips, a shard
+    holding 2 of llama-sized GQA's 8 kv heads of 128."""
+    from deepspeed_tpu.parallel.pallas_shard import sharded_paged_decode
+    mesh = Mesh(np.asarray(_DEVICES), ("model",))
+
+    def spec(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*axes)))
+    pool = spec((2, 128, 16, 8 * 128), jnp.bfloat16,
+                None, None, None, "model")
+
+    def decode(q, kpool, vpool, tables, positions):
+        return sharded_paged_decode(q, kpool, vpool, tables, positions,
+                                    mesh, interpret=False, layer=1)
+    compiled = _compile(decode, spec((8, 32, 128), jnp.bfloat16,
+                                     None, "model"), pool, pool,
+                        spec((8, 16), jnp.int32), spec((8,), jnp.int32))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"all-(gather|reduce|to-all)", text)
 
 
 @pytest.mark.parametrize("head_dim,page_size,pool_dtype,heads,kv_heads", [

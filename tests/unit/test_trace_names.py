@@ -29,6 +29,7 @@ from deepspeed_tpu.inference import InferenceEngine
 from deepspeed_tpu.inference.scheduler import Request
 from deepspeed_tpu.models.gpt2 import (GPT2Config, gpt2_loss_fn,
                                        init_gpt2_params)
+from deepspeed_tpu.ops.attention.paged import block_pages
 from deepspeed_tpu.profiling import spans
 from deepspeed_tpu.profiling.spans import (DEVICE_SCOPES, HOST_SPANS,
                                            ChromeTraceRecorder, scope,
@@ -247,17 +248,19 @@ def test_serving_trace_holds_spans_in_order_with_scheduler_counters(
                               seed=i, eos_id=None))
     reported = []                   # what the scheduler says at each open
     real_span = engine._span
+    ps = engine.paged_spec.page_size
+    per_turn = block_pages(ps)      # pages a loop turn of the Pallas reader
 
     def spy(name, **args):
         if name == "serve/decode":
-            # live tokens, and the pages the Pallas reader walks: each
+            # live tokens, and what the Pallas reader walks: each
             # decoding row's live pages, the null page once for a row
-            # that is not decoding
-            ps = engine.paged_spec.page_size
+            # that is not decoding, a block of pages a loop turn
             walked = [s.position // ps + 1 for s in sched.slots
                       if s is not None and s.pending_tok is not None]
-            reported.append((sched.tokens_in_flight,
-                             sum(walked) + engine._rows - len(walked)))
+            walked += [1] * (engine._rows - len(walked))
+            reported.append((sched.tokens_in_flight, sum(walked),
+                             sum(-(-w // per_turn) for w in walked)))
         return real_span(name, **args)
 
     engine._span = spy
@@ -268,18 +271,22 @@ def test_serving_trace_holds_spans_in_order_with_scheduler_counters(
     prefills = [ev for ev in events if ev[0] == "serve/prefill"]
     assert len(decodes) == 3 and len(prefills) >= 1
     assert engine._decode_attn_path == attn_kernel
-    for ev, (live, walked) in zip(decodes, reported):
+    pallas = attn_kernel == "pallas"
+    for ev, (live, walked, turns) in zip(decodes, reported):
         # every argument has a reader (decode_stripe_live_share.sat,
-        # decode_read_live_share.sat)
+        # decode_read_live_share.sat, decode_block_fill_share.sat)
         assert set(ev[3]) == {"rows", "table_pages", "page_size",
-                              "live_tokens", "read_pages"}
+                              "live_tokens", "read_pages", "read_turns",
+                              "block_tokens"}
         assert ev[3]["live_tokens"] == live
         # the gather reader walks no page list: it reads the table
-        assert ev[3]["read_pages"] == \
-            (walked if attn_kernel == "pallas" else 0)
-        assert live <= walked * ev[3]["page_size"]
+        assert ev[3]["read_pages"] == (walked if pallas else 0)
+        assert ev[3]["read_turns"] == (turns if pallas else 0)
+        assert ev[3]["block_tokens"] == (per_turn * ps if pallas else 0)
+        assert live <= walked * ps <= turns * per_turn * ps
+        assert engine._rows <= turns <= walked
         assert ev[3]["rows"] == engine._rows
-        assert ev[3]["page_size"] == engine.paged_spec.page_size
+        assert ev[3]["page_size"] == ps
         assert ev[3]["table_pages"] == engine.paged_spec.pages_per_seq
         assert _children(events, ev) == [
             "serve/decode/build", "serve/decode/dispatch",
