@@ -26,7 +26,8 @@ Two handoff modes (the engine picks per config):
   module stays import-clean).
 
 This module is the pure host-side half — the handoff queue, transfer
-records, wire pricing, and the dispatch interleaving trace that pins
+records, wire pricing, and the dispatch ledger (one row a device
+dispatch of every engine, with its stamps), whose ordering also pins
 "no decode dispatch waits behind a prefill dispatch" (the decode phase
 of every engine step runs FIRST). Nothing here imports jax (pinned
 source-level by tests/unit/test_inference.py, like scheduler/paging/
@@ -232,32 +233,132 @@ class MigrationRecord:
 
 
 class DispatchTrace:
-    """The interleaving trace of device dispatches under disaggregated
-    serving: (step, kind) per dispatch, kind in {"decode", "verify",
-    "prefill", "handoff", "chunk"}. The structural serving guarantee —
-    no decode dispatch ever waits behind a prefill dispatch — is
-    checkable as pure ordering: within every step, all decode/verify
+    """The dispatch ledger: the engine's one record of its device
+    dispatches, one row a dispatch, built for every engine.
+
+    A row holds ``seq`` (its ordinal since the engine was built),
+    ``step`` (``engine._steps``), the dispatch's CLASS (the compiled
+    program it ran: ``("decode", table width)``, ``("prefill", batch
+    bucket, prompt bucket)``, ``("verify", width)``, ``("chunk", batch
+    bucket, chunk tokens, shards)``, ``("handoff",)``; its first word is
+    the row's ``kind``), four stamps of ``time.perf_counter()`` and one
+    count (what the dispatch read is on its ``serve/*`` span, which
+    carries the row's ``seq``):
+
+    - ``t_begin``: the first host work for this dispatch alone
+      (:meth:`begin`: ``serve/plan`` for a decode, the batch's build for
+      a prefill, whose phase admits before it for all its batches; where
+      a phase dispatches again, the row before's ``t_done``);
+    - ``t_issued``: the jitted call has returned (:meth:`issued`);
+    - ``t_ready``: the host holds the result (:meth:`ready`, stamped as
+      the ``serve/*/wait`` span closes; it returns the milliseconds
+      since ``t_begin``, which is what the engine reports as a
+      dispatch's wall time);
+    - ``t_done``: record and metrics for this dispatch are finished
+      (:meth:`record`, which writes the row);
+    - ``tokens``: what the scheduler's ``total_tokens`` grew by since
+      the row before, so the rows' sum over any interval is that
+      counter's difference.
+
+    The rows cut the timeline into contiguous intervals (the row
+    before's ``t_done`` to this row's), each in three legs: BEFORE (to
+    ``t_issued``: admit, plan, build, the call, and whatever the caller
+    did between two steps), WAIT (to ``t_ready``: the device's program
+    with its launch and read-back) and AFTER (to ``t_done``).
+
+    The interleaving pin of disaggregated and chunked serving reads the
+    same rows as pure ordering: within every step, all decode/verify
     ordinals precede all prefill ordinals (the engine's disagg step
     runs its decode phase first; chunked prefill slips its at-most-one
     "chunk" dispatch between them, after every decode of the step).
-    Bounded (ring of ``cap`` entries) so a serving daemon can leave it
-    on."""
+
+    Preallocated columns, a ring of ``cap`` rows (several whole
+    benchmark runs) with the overwritten ones counted in ``dropped``;
+    written nowhere. ``profiling.spans.last_dispatch_ledger()`` finds
+    the one of the engine last built or closed."""
 
     DECODE_KINDS = ("decode", "verify", "handoff")
+    COLUMNS = ("step", "class_id", "t_begin", "t_issued", "t_ready",
+               "t_done", "tokens")
 
-    def __init__(self, cap: int = 4096):
+    def __init__(self, cap: int = 16384, clock=time.perf_counter):
         self.cap = int(cap)
-        self._rows: List[Tuple[int, str]] = []
-        self.total = 0
+        self._clock = clock
+        self.total = 0              # rows ever recorded: the next seq
+        self.classes: List[Tuple] = []          # class_id -> class
+        self._class_ids: Dict[Tuple, int] = {}
+        self._cols = {name: [0] * self.cap for name in self.COLUMNS}
+        # the open row
+        self.t_begin = clock()
+        self.t_issued = self.t_ready = 0.0
+        self._tokens_seen = 0
+        self._busy_s = 0.0
 
-    def record(self, step: int, kind: str) -> None:
-        self._rows.append((int(step), str(kind)))
+    def begin(self) -> None:
+        self.t_begin = self._clock()
+
+    def issued(self) -> None:
+        self.t_issued = self._clock()
+
+    def ready(self) -> float:
+        self.t_ready = t = self._clock()
+        return (t - self.t_begin) * 1e3
+
+    def record(self, step: int, kind: str, *program,
+               tokens_total: Optional[int] = None) -> None:
+        now = self._clock()
+        cls = (kind,) + program
+        class_id = self._class_ids.get(cls)
+        if class_id is None:
+            class_id = self._class_ids[cls] = len(self.classes)
+            self.classes.append(cls)
+        if tokens_total is None:
+            tokens_total = self._tokens_seen
+        i, c = self.total % self.cap, self._cols
+        c["step"][i] = int(step)
+        c["class_id"][i] = class_id
+        c["t_begin"][i] = self.t_begin
+        c["t_issued"][i] = self.t_issued or now
+        c["t_ready"][i] = self.t_ready or now
+        c["t_done"][i] = now
+        c["tokens"][i] = tokens_total - self._tokens_seen
+        self._tokens_seen = tokens_total
+        self._busy_s += now - self.t_begin
         self.total += 1
-        if len(self._rows) > self.cap:
-            del self._rows[:len(self._rows) - self.cap]
+        self.t_begin = now
+        self.t_issued = self.t_ready = 0.0
+
+    @property
+    def dropped(self) -> int:
+        return max(self.total - self.cap, 0)
+
+    def serve_seconds(self) -> float:
+        """Seconds inside dispatches so far: every row's ``t_begin`` to
+        ``t_done``, and the open row's ``t_begin`` to ``t_ready``."""
+        if self.t_ready:
+            return self._busy_s + self.t_ready - self.t_begin
+        return self._busy_s
+
+    def _kept(self, name: str) -> List:
+        """One column of the kept rows, in ``seq`` order."""
+        n = min(self.total, self.cap)
+        cut = self.total % self.cap if self.total > self.cap else 0
+        col = self._cols[name]
+        return col[cut:n] + col[:cut]
+
+    def table(self) -> Dict[str, List]:
+        """The kept rows in ``seq`` order, a list a column (``seq``,
+        ``kind`` and ``cls`` beside ``COLUMNS``)."""
+        out = {name: self._kept(name) for name in self.COLUMNS}
+        n = len(out["step"])
+        out["seq"] = list(range(self.total - n, self.total))
+        out["cls"] = [self.classes[k] for k in out["class_id"]]
+        out["kind"] = [cls[0] for cls in out["cls"]]
+        return out
 
     def rows(self) -> List[Tuple[int, str]]:
-        return list(self._rows)
+        return [(step, self.classes[k][0]) for step, k in zip(
+            self._kept("step"), self._kept("class_id"))]
 
     def decode_first_fraction(self) -> Optional[float]:
         """Fraction of traced steps where every decode-phase dispatch
@@ -265,7 +366,7 @@ class DispatchTrace:
         never-blocked-behind-prefill pin holds; None = no step mixed
         both phases, nothing to measure)."""
         by_step: Dict[int, List[str]] = {}
-        for step, kind in self._rows:
+        for step, kind in self.rows():
             by_step.setdefault(step, []).append(kind)
         mixed = ok = 0
         for kinds in by_step.values():

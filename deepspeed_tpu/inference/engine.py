@@ -123,7 +123,8 @@ from deepspeed_tpu.ops.attention.paged import (block_pages, live_pages,
                                                paged_decode_supported)
 from deepspeed_tpu.parallel.mesh import axis_size, build_mesh
 from deepspeed_tpu.profiling.recompile import CompileTracker
-from deepspeed_tpu.profiling.spans import (ChromeTraceRecorder, scope,
+from deepspeed_tpu.profiling.spans import (ChromeTraceRecorder,
+                                           _keep_dispatch_ledger, scope,
                                            trace_span)
 from deepspeed_tpu.runtime.quantized_params import (QuantizedParam,
                                                     dequantize_param_tree,
@@ -454,11 +455,13 @@ class InferenceEngine:
                 self.params, self._param_shardings_decode)
         self._handoff_q = HandoffQueue() if self.disagg else None
         self._handoff_stats = HandoffStats() if self.disagg else None
-        # chunked engines keep the trace too: the TBT bound is the pure
-        # ordering pin "at most one chunk dispatch per step, after every
-        # decode of that step" (tests/unit/test_chunked_prefill.py)
-        self._dispatch_trace = DispatchTrace() \
-            if (self.disagg or self.chunked) else None
+        # the dispatch ledger: one row a device dispatch, with its
+        # stamps (every wall time this engine reports comes from them).
+        # Its ordering is also the TBT pin "at most one chunk dispatch
+        # per step, after every decode of that step"
+        # (tests/unit/test_chunked_prefill.py)
+        self._dispatch_trace = DispatchTrace()
+        _keep_dispatch_ledger(self._dispatch_trace)
         self._link = None
         if self._separate_pools:
             from deepspeed_tpu.runtime.comm_autotune import LinkModel
@@ -499,7 +502,6 @@ class InferenceEngine:
             events_dir=cfg["events_dir"] or None)
         self._steps = 0
         self._warm_compiles: Optional[int] = None
-        self._serve_secs = 0.0
         # offline fp-oracle probe result (record_quant_logit_err):
         # serving can't afford an fp oracle per dispatch, so the error
         # rides telemetry only when a test measures it
@@ -1564,14 +1566,23 @@ class InferenceEngine:
         its counters ride as the annotation's arguments."""
         return trace_span(name, recorder=self._recorder, **args)
 
-    def _run_prefill(self, batch) -> np.ndarray:
+    @property
+    def dispatch_ledger(self) -> DispatchTrace:
+        """One row a device dispatch since the engine was built
+        (docs/observability.md "The dispatch ledger")."""
+        return self._dispatch_trace
+
+    def _run_prefill(self, batch):
+        """(first tokens, the dispatch's wall ms)."""
+        ledger = self._dispatch_trace
         bb, pb = batch.batch_bucket, batch.prompt_bucket
         if self.paged:
             prompts = [r.prompt[pl:] for r, pl in
                        zip(batch.requests, batch.prefix_lens)]
         else:
             prompts = [r.prompt for r in batch.requests]
-        with self._span("serve/prefill", batch=bb, prompt=pb,
+        with self._span("serve/prefill", seq=ledger.total,
+                        step=self._steps, batch=bb, prompt=pb,
                         real_tokens=sum(len(p) for p in prompts)):
             with self._span("serve/prefill/build"):
                 keys = np.zeros((bb, 2), np.uint32)
@@ -1614,8 +1625,10 @@ class InferenceEngine:
                         jnp.asarray(lengths), jnp.asarray(positions),
                         jnp.asarray(tables), jnp.asarray(keys),
                         jnp.asarray(temps))
+            ledger.issued()
             with self._span("serve/prefill/wait"):
-                return np.asarray(first)
+                first = np.asarray(first)
+            return first, ledger.ready()
 
     def _drain_request_metrics(self):
         """Per-admitted-request scalar writes (TTFT / queue wait)
@@ -1636,23 +1649,21 @@ class InferenceEngine:
         the DECODE phase claims it, so TTFT honestly includes the
         handoff wait."""
         sched = self.scheduler
+        ledger = self._dispatch_trace
         self.health.heartbeat("prefill")
-        t0 = time.perf_counter()
         with self._span("serve/admit"):
             batches = sched.admit()
+        # after the admission, which serves all the phase's batches:
+        # every batch's wall time is its own build, call and wait
+        ledger.begin()
         for batch in batches:
-            t_p = time.perf_counter()
-            first = self._run_prefill(batch)
-            prefill_ms = (time.perf_counter() - t_p) * 1e3
+            first, prefill_ms = self._run_prefill(batch)
             with self._span("serve/record"):
-                if self._dispatch_trace is not None:
-                    self._dispatch_trace.record(self._steps, "prefill")
                 for sid, req in zip(batch.slot_ids, batch.requests):
                     self._tracer.on_prefill(
                         req.uid, sid, prefill_ms, batch.prompt_bucket,
                         batch.batch_bucket, len(batch.requests))
                 if self.disagg:
-                    now = time.perf_counter()
                     ps = self.paged_spec.page_size
                     for i, (sid, req) in enumerate(zip(batch.slot_ids,
                                                        batch.requests)):
@@ -1660,14 +1671,17 @@ class InferenceEngine:
                             uid=req.uid, slot=sid,
                             first_token=int(first[i]),
                             live_pages=pages_for(len(req.prompt), ps),
-                            prompt_tokens=len(req.prompt), t_ready=now))
+                            prompt_tokens=len(req.prompt),
+                            t_ready=ledger.t_ready))
                 else:
                     finished.extend(sched.record_tokens(
                         {sid: int(first[i])
                          for i, sid in enumerate(batch.slot_ids)}))
             with self._span("serve/metrics"):
                 self._drain_request_metrics()
-        self._serve_secs += time.perf_counter() - t0
+            ledger.record(self._steps, "prefill", batch.batch_bucket,
+                          batch.prompt_bucket,
+                          tokens_total=sched.total_tokens)
 
     def _chunk_phase(self, finished: List[FinishedRequest]) -> None:
         """At most ONE chunk dispatch per engine step — the pinned TBT
@@ -1686,11 +1700,12 @@ class InferenceEngine:
         if not self.chunked:
             return
         sched = self.scheduler
+        ledger = self._dispatch_trace
+        ledger.begin()
         cand = sched.chunk_batch(cap=max(self.config["batch_buckets"]))
         if not cand:
             return
         self.health.heartbeat("chunk_prefill")
-        t0 = time.perf_counter()
         use_cp = False
         if self._cp_shards > 1:
             # one program per dispatch: the head's eligibility class
@@ -1704,8 +1719,8 @@ class InferenceEngine:
         ct = self._chunk_tokens
         shards = self._cp_shards if use_cp else 1
         prog = self._chunk_cp if use_cp else self._prefill
-        with self._span("serve/chunk", batch=bb, chunk=ct,
-                        cp_shards=shards):
+        with self._span("serve/chunk", seq=ledger.total, step=self._steps,
+                        batch=bb, chunk=ct, cp_shards=shards):
             with self._span("serve/chunk/build"):
                 ids = np.zeros((bb, ct), np.int32)
                 lengths = np.ones((bb,), np.int32)
@@ -1726,7 +1741,6 @@ class InferenceEngine:
                     tables[i, :len(slot.pages)] = slot.pages
                     keys[i] = self._key_for(req.seed)
                     temps[i] = req.temperature
-            t_c = time.perf_counter()
             with self._span("serve/chunk/dispatch"):
                 if self._separate_pools:
                     first, self._cache_prefill = prog(
@@ -1740,15 +1754,13 @@ class InferenceEngine:
                         jnp.asarray(lengths), jnp.asarray(positions),
                         jnp.asarray(tables), jnp.asarray(keys),
                         jnp.asarray(temps))
+            ledger.issued()
             with self._span("serve/chunk/wait"):
                 # host sync: final chunks release their first token
                 first = np.asarray(first)
-        wall_ms = (time.perf_counter() - t_c) * 1e3
+            wall_ms = ledger.ready()
         with self._span("serve/record"):
-            if self._dispatch_trace is not None:
-                self._dispatch_trace.record(self._steps, "chunk")
             self._chunk_dispatches += 1
-            now = time.perf_counter()
             released: Dict[int, int] = {}
             for i, (sid, req, start, n, k) in enumerate(spans):
                 self._tracer.on_prefill_chunk(req.uid, sid, k, n, wall_ms,
@@ -1760,7 +1772,8 @@ class InferenceEngine:
                     self._handoff_q.push(HandoffRecord(
                         uid=req.uid, slot=sid, first_token=int(first[i]),
                         live_pages=pages_for(len(req.prompt), ps),
-                        prompt_tokens=len(req.prompt), t_ready=now))
+                        prompt_tokens=len(req.prompt),
+                        t_ready=ledger.t_ready))
                 else:
                     released[sid] = int(first[i])
             if released:
@@ -1770,7 +1783,8 @@ class InferenceEngine:
                 chunk_dispatches=self._chunk_dispatches,
                 tokens=sched.total_tokens, flush=False)
             self._drain_request_metrics()
-        self._serve_secs += time.perf_counter() - t0
+        ledger.record(self._steps, "chunk", bb, ct, shards,
+                      tokens_total=sched.total_tokens)
 
     def _claim_phase(self, finished: List[FinishedRequest]) -> None:
         """Disagg decode-worker intake: claim completed prefills off
@@ -1786,8 +1800,8 @@ class InferenceEngine:
         sched = self.scheduler
         q = self._handoff_q
         tracer = self._tracer
+        ledger = self._dispatch_trace
         self.health.heartbeat("handoff_claim")
-        t0 = time.perf_counter()
         for rec in q.drain():
             slot = sched.slots[rec.slot]
             if slot is None or slot.request.uid != rec.uid:
@@ -1808,7 +1822,7 @@ class InferenceEngine:
                     continue
                 cross = self._mesh_decode is not self.mesh
                 mode = "migrate_mesh" if cross else "migrate"
-                t_m = time.perf_counter()
+                ledger.begin()
                 src = np.zeros((self._handoff_width,), np.int32)
                 dst = np.zeros((self._handoff_width,), np.int32)
                 live = slot.pages[:rec.live_pages]
@@ -1822,19 +1836,18 @@ class InferenceEngine:
                         for s in slab)
                 self._cache = self._import(self._cache, slab,
                                            jnp.asarray(dst))
+                ledger.issued()
                 # one host sync per CLAIM (once per request, never per
                 # dispatch): the measured wall time must cover the
                 # device copy it reports
                 jax.block_until_ready(self._cache[0])
-                transfer_ms = (time.perf_counter() - t_m) * 1e3
+                transfer_ms = ledger.ready()
                 pages = len(live)
                 nbytes = pages * self._page_bytes
                 priced = price_handoff(
                     pages, self._page_bytes, self._link,
                     axis="inter" if cross else "intra")
                 sched.adopt_pages(rec.slot, new_pages)
-                if self._dispatch_trace is not None:
-                    self._dispatch_trace.record(self._steps, "handoff")
             queue_ms = q.claimed(rec)
             tracer.on_handoff(rec.uid, queue_ms, transfer_ms, pages,
                               nbytes, mode, priced)
@@ -1846,7 +1859,9 @@ class InferenceEngine:
             finished.extend(sched.record_tokens(
                 {rec.slot: rec.first_token}))
             self._drain_request_metrics()
-        self._serve_secs += time.perf_counter() - t0
+            if self._separate_pools:
+                ledger.record(self._steps, "handoff",
+                              tokens_total=sched.total_tokens)
 
     def _decode_phase(self, finished: List[FinishedRequest]) -> bool:
         """Advance every in-flight sequence: a plain one-token decode
@@ -1854,6 +1869,8 @@ class InferenceEngine:
         seq-``v`` verify dispatch that emits ``accepted + 1`` tokens
         per row. Returns whether anything dispatched."""
         sched = self.scheduler
+        ledger = self._dispatch_trace
+        ledger.begin()
         self.health.heartbeat("decode")
         with self._span("serve/plan"):
             # the walks over the slots that make the dispatch's rows
@@ -1861,7 +1878,6 @@ class InferenceEngine:
             live_tokens = sched.tokens_in_flight
         if not sids:
             return False
-        t0 = time.perf_counter()
         occupancy = len(sids) / self.num_slots
         props: Dict[int, List[int]] = {}
         if self.spec and self.paged:
@@ -1870,8 +1886,10 @@ class InferenceEngine:
         spec_kw = {}
         runs: Dict[int, List[int]] = {}
         draft_stats = None
-        # what this dispatch reads, as the span's counters
-        counters = dict(rows=self._rows, live_tokens=live_tokens)
+        # the ledger row this dispatch will be, and what it reads, as
+        # the span's counters
+        counters = dict(seq=ledger.total, step=self._steps,
+                        rows=self._rows, live_tokens=live_tokens)
         if self.paged:
             counters["page_size"] = self.paged_spec.page_size
         if props:
@@ -1885,7 +1903,6 @@ class InferenceEngine:
                 with self._span("serve/verify/build"):
                     toks_a, poss_a, temps_a, keys_a = self._decode_arrays(
                         sids, toks, poss, temps, seeds)
-                    t_d = time.perf_counter()
                     vt = np.zeros((self._rows, v), np.int32)
                     vt[:, 0] = toks_a
                     for sid, p in props.items():
@@ -1896,11 +1913,12 @@ class InferenceEngine:
                         self.params_decode, self._cache, jnp.asarray(vt),
                         jnp.asarray(poss_a), jnp.asarray(tables),
                         jnp.asarray(keys_a), jnp.asarray(temps_a))
+                ledger.issued()
                 with self._span("serve/verify/wait"):
                     # host sync: the scheduler needs the token values
                     out = np.asarray(out)
-            if self._dispatch_trace is not None:
-                self._dispatch_trace.record(self._steps, "verify")
+                tok_ms = ledger.ready()
+            program = ("verify", v)
             draft_stats = {}
             proposed_total = accepted_total = 0
             for sid in sids:
@@ -1925,6 +1943,7 @@ class InferenceEngine:
                 spec_kw["spec_accept_rate"] = (accepted_total
                                                / proposed_total)
         else:
+            program = ("decode",)
             with self._span("serve/plan"):
                 # ... and its span's counters
                 if self.paged:
@@ -1937,6 +1956,7 @@ class InferenceEngine:
                             self.paged_spec.pages_per_seq),
                         self._decode_page_buckets)
                     counters["table_pages"] = width
+                    program = ("decode", width)
                     # what the Pallas kernel walks: each row's live
                     # pages, ``block_pages`` a loop turn, an inactive
                     # row's null page in one turn; the gather reader
@@ -1963,9 +1983,6 @@ class InferenceEngine:
                 with self._span("serve/decode/build"):
                     toks_a, poss_a, temps_a, keys_a = self._decode_arrays(
                         sids, toks, poss, temps, seeds)
-                    # Serve/token_latency_ms runs from the end of the
-                    # slot loop (verify's too), block tables included
-                    t_d = time.perf_counter()
                     if self.paged:
                         tables = sched.block_table_rows(self._rows, width)
                 with self._span("serve/decode/dispatch"):
@@ -1980,13 +1997,15 @@ class InferenceEngine:
                             self.params_decode, self._cache,
                             jnp.asarray(toks_a), jnp.asarray(poss_a),
                             jnp.asarray(keys_a), jnp.asarray(temps_a))
+                ledger.issued()
                 with self._span("serve/decode/wait"):
                     # host sync: the scheduler needs the token values
                     nxt = np.asarray(nxt)
                     if self._expert_counters is not None:
                         self._moe_counts = (int(nxt[-2]), int(nxt[-1]))
-            if self._dispatch_trace is not None:
-                self._dispatch_trace.record(self._steps, "decode")
+                # Serve/token_latency_ms (verify's too): the phase's
+                # first host work to the tokens' arrival on the host
+                tok_ms = ledger.ready()
             runs = {sid: [int(nxt[sid])] for sid in sids}
             if self.spec:
                 # speculation on, drafter had nothing anywhere: the
@@ -1994,13 +2013,13 @@ class InferenceEngine:
                 for sid in sids:
                     self._tracer.on_defer(
                         sched.slots[sid].request.uid, "draft_stall")
-        tok_ms = (time.perf_counter() - t_d) * 1e3
         with self._span("serve/record"):
             finished.extend(sched.record_token_runs(runs, draft_stats))
-        self._serve_secs += time.perf_counter() - t0
         with self._span("serve/metrics"):
             self._write_decode_metrics(tok_ms, occupancy, live_tokens,
                                        spec_kw)
+        ledger.record(self._steps, *program,
+                      tokens_total=sched.total_tokens)
         return True
 
     def _decode_arrays(self, sids, toks, poss, temps, seeds):
@@ -2025,8 +2044,9 @@ class InferenceEngine:
         scheduler's ``tokens_in_flight`` before it, walked once for the
         ``serve/decode`` span and ``Serve/tokens_in_flight`` both."""
         sched = self.scheduler
-        tps = (sched.total_tokens / self._serve_secs
-               if self._serve_secs > 0 else 0.0)
+        # seconds inside dispatches, this one up to its tokens' arrival
+        serve_secs = self._dispatch_trace.serve_seconds()
+        tps = sched.total_tokens / serve_secs if serve_secs > 0 else 0.0
         paged_kw = {}
         if self.paged:
             alloc = sched.allocator
@@ -2055,8 +2075,8 @@ class InferenceEngine:
             if att is not None:
                 slo_kw["slo_attainment"] = att
                 slo_kw["goodput_tokens_per_s"] = (
-                    tracer.good_tokens / self._serve_secs
-                    if self._serve_secs > 0 else 0.0)
+                    tracer.good_tokens / serve_secs
+                    if serve_secs > 0 else 0.0)
         self.monitor.write_serving_metrics(
             token_latency_ms=tok_ms, tokens_per_sec=tps,
             queue_depth=sched.queue_depth, batch_occupancy=occupancy,
@@ -2407,6 +2427,9 @@ class InferenceEngine:
                                 wall_ms=round(ev.wall_ms, 3), step=ev.step)
 
     def close(self):
+        # whoever drove this engine can still read its ledger
+        # (profiling.spans.last_dispatch_ledger)
+        _keep_dispatch_ledger(self._dispatch_trace)
         # health first: untapping restores the raw mirror so the
         # identity check below still clears our own writer
         self.health.close()

@@ -275,9 +275,10 @@ def test_serving_trace_holds_spans_in_order_with_scheduler_counters(
     for ev, (live, walked, turns) in zip(decodes, reported):
         # every argument has a reader (decode_stripe_live_share.sat,
         # decode_read_live_share.sat, decode_block_fill_share.sat)
-        assert set(ev[3]) == {"rows", "table_pages", "page_size",
-                              "live_tokens", "read_pages", "read_turns",
-                              "block_tokens"}
+        # (..., and the dispatch ledger's row: test_dispatch_ledger.py)
+        assert set(ev[3]) == {"seq", "step", "rows", "table_pages",
+                              "page_size", "live_tokens", "read_pages",
+                              "read_turns", "block_tokens"}
         assert ev[3]["live_tokens"] == live
         # the gather reader walks no page list: it reads the table
         assert ev[3]["read_pages"] == (walked if pallas else 0)
@@ -295,7 +296,8 @@ def test_serving_trace_holds_spans_in_order_with_scheduler_counters(
     assert sum(ev[3]["real_tokens"] for ev in prefills) == \
         sum(len(p) for p in PROMPTS)
     for ev in prefills:
-        assert set(ev[3]) == {"batch", "prompt", "real_tokens"}
+        assert set(ev[3]) == {"seq", "step", "batch", "prompt",
+                              "real_tokens"}
         assert ev[3]["batch"] in INF["batch_buckets"]
         assert ev[3]["prompt"] in INF["prompt_buckets"]
         assert ev[3]["real_tokens"] <= ev[3]["batch"] * ev[3]["prompt"]
