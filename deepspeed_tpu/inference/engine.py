@@ -1581,9 +1581,14 @@ class InferenceEngine:
                        zip(batch.requests, batch.prefix_lens)]
         else:
             prompts = [r.prompt for r in batch.requests]
+        real = sum(len(p) for p in prompts)
+        # what the program reads from `positions`: with every row at
+        # cache position 0 the batch attends to its own keys
+        # (models/gpt2.paged_attend), else to the gathered stripe
+        own = real if self.paged and not any(batch.prefix_lens) else 0
         with self._span("serve/prefill", seq=ledger.total,
                         step=self._steps, batch=bb, prompt=pb,
-                        real_tokens=sum(len(p) for p in prompts)):
+                        real_tokens=real, own_key_tokens=own):
             with self._span("serve/prefill/build"):
                 keys = np.zeros((bb, 2), np.uint32)
                 temps = np.zeros((bb,), np.float32)

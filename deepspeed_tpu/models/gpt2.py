@@ -8,6 +8,7 @@ over the ``model`` mesh axis — what the reference delegated to the client's
 mpu, SURVEY §2.3).
 """
 
+import functools
 from typing import Any, Dict, NamedTuple, Optional
 
 import jax
@@ -616,8 +617,9 @@ def gather_paged_kv(pool, layer: int, block_table, kv_heads: int):
     NB: this materializes each row's full logical stripe (every table
     entry it is handed) each call — per-step decode reads are bounded
     by the TABLE WIDTH, not the tokens actually live. It is the reader
-    of every query of more than one row (prefill, chunked prefill,
-    spec-verify), the paged paths' numerics oracle, and decode's
+    of every query of more than one row that does not start at cache
+    position 0 (a prefixed prefill, a later chunk, spec-verify; see
+    :func:`paged_attend`), the paged paths' numerics oracle, and decode's
     fallback where the fused Pallas decode kernel
     (``ops/attention/paged.py`` — streams whole rows of the live pages
     only, at every head width whose pool row is whole 128-lane tiles)
@@ -705,40 +707,121 @@ def paged_decode_ctx(q, pools, layer: int, block_table, cache_position):
         return out[:, :, None, :]
 
 
+# float32 scores (B * heads * S * S elements) up to which a call that
+# attends to its own keys runs the family's stripe mathematics over them
+# and not the flash kernel: the kernel's grid is a program a (row, head)
+# and at tiles this small it is bound by their launches. One GPT-2 345M
+# layer on the v5e, 8 x 64 / 128 / 256 (2 to 32 MB of scores): 9 / 11 /
+# 34 us against the kernel's 68 / 75 / 103; at 8 x 512 (134 MB) 582
+# against 198, at 32 x 256 590 against 427 (my chip runs, PR 40)
+_OWN_KEYS_DENSE_SCORES = 1 << 23
+
+# query rows of the flash kernel's smallest tile: a narrower or ragged
+# call falls to its O(S^2) reference there, so it keeps the stripe
+_OWN_KEYS_ROWS = 16
+
+
+def own_keys_attention(q, k, v, cache_position, stripe_attention):
+    """Causal attention of ``q`` (B, heads, S, hd) over the call's own
+    ``k``, ``v`` (B, kv_heads, S, hd): what a row that starts at cache
+    position 0 may see is exactly what this call has just computed, so
+    nothing is read back out of the pool. ``causal_cache_mask`` at
+    position 0 IS the causal mask: a small call hands its own keys to
+    the family's ``stripe_attention`` as a stripe of S positions (all
+    zeros in ``cache_position``), a larger one to the training kernel
+    (``ops/attention/flash.flash_attention``: GQA native, operands as
+    they come, float32 accumulation and softmax, no (S, S) scores in
+    HBM). Chosen by the call's shape (``_OWN_KEYS_DENSE_SCORES``)."""
+    from deepspeed_tpu.ops.attention import flash
+    from deepspeed_tpu.parallel.pallas_shard import current_kernel_mesh
+    B, H, S, _ = q.shape
+    return _own_keys(q, k, v, cache_position,
+                     stripe_attention=stripe_attention,
+                     dense=B * H * S * S <= _OWN_KEYS_DENSE_SCORES,
+                     interpret=not flash._use_pallas(),
+                     kernel_mesh=current_kernel_mesh())
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "stripe_attention", "dense", "interpret", "kernel_mesh"))
+def _own_keys(q, k, v, cache_position, stripe_attention, dense, interpret,
+              kernel_mesh):
+    """:func:`own_keys_attention` behind a ``jit`` of its own, so that a
+    program's layers share ONE trace and ONE lowering of it: traced a
+    layer, the flash kernel cost each program of 24 layers 4.5 s of
+    tracing and 5.5 s of lowering to Mosaic before the compile cache is
+    even asked (my chip run, PR 40: 27 s of the cell's set-up over three
+    programs). The static arguments are everything the trace depends on
+    besides the operands; ``kernel_mesh`` (the engine's trace context,
+    which ``flash_attention`` reads for itself) only keys it."""
+    del kernel_mesh
+    if dense:
+        return stripe_attention(q, k, v, cache_position)
+    with scope("attn_core"):
+        return flash_attention(q, k, v, causal=True, interpret=interpret)
+
+
 def paged_attend(q, k, v, pools, layer: int, block_table, cache_position,
                  page, offset, out_box, attn_kernel: str, stripe_attention):
     """Layer ``layer`` of the paged cached forward, for both families
     (prefill-into-pages and paged decode alike): write this call's K/V
     into the stacked pool tree at ``[layer, page, offset]``
-    (:func:`write_paged_layer`), then attend. Single-query calls
-    (decode — and any seq-1 prefill bucket) with
-    ``attn_kernel="pallas"`` run the fused paged-attention kernel
-    straight against the pool (:func:`paged_decode_ctx` — only live
-    pages are read); everything else gathers each row's logical stripe
-    back and hands it to the family's
-    ``stripe_attention(q, kc, vc, cache_position)`` (the numerics oracle
-    / fallback). The updated tree — the pair, or the int8 4-tuple —
-    returns through ``out_box``."""
+    (:func:`write_paged_layer`), then attend, through one of three
+    readers chosen by what the call shows:
+
+    - one query row (decode, and any seq-1 prefill bucket) with
+      ``attn_kernel="pallas"``: the fused paged-attention kernel
+      straight against the pool (:func:`paged_decode_ctx` — only live
+      pages are read);
+    - many rows that ALL start at cache position 0 (a prompt bucket with
+      no shared prefix, a chunked prefill's first chunk):
+      :func:`own_keys_attention` over the call's own ``k``, ``v`` — the
+      pool is written and not read, and a prompt of 64 attends to 64
+      keys, not to the table's 640. Picked at RUN time inside the one
+      program a bucket has (``lax.cond`` on the positions), and traced
+      only where the call's shape can use it: rows a multiple of
+      ``_OWN_KEYS_ROWS``, the plain pool pair in the keys' own dtype
+      (after an int8 or a narrower pool a decode sees ROUNDED keys, and
+      the first token sees the same), no context-parallel mesh (the
+      ring keeps its prefill);
+    - anything else (a batch with a prefixed row, a later chunk, a
+      spec-verify call): each row's logical stripe gathered back and
+      handed to the family's ``stripe_attention(q, kc, vc,
+      cache_position)`` (the numerics oracle / fallback).
+
+    The updated tree — the pair, or the int8 4-tuple — returns through
+    ``out_box``."""
+    from deepspeed_tpu.parallel.pallas_shard import current_cp_mesh
     written = write_paged_layer(pools, layer, k, v, page, offset)
     out_box.append(written)
-    if attn_kernel == "pallas" and q.shape[2] == 1:
+    rows = q.shape[2]
+    if attn_kernel == "pallas" and rows == 1:
         return paged_decode_ctx(q, written, layer, block_table,
                                 cache_position)
-    kc, vc = gather_paged_layer(written, layer, block_table, k.shape[1])
-    if q.shape[2] > 1:
-        # context-parallel chunked prefill (ISSUE 19): under the
-        # engine's CP trace context, the chunk's sequence axis runs
-        # ring-sharded over the serving mesh — same stripe, same
-        # absolute-position causal rule (GQA folds group-wise inside
-        # the ring)
-        from deepspeed_tpu.parallel.pallas_shard import current_cp_mesh
-        cp = current_cp_mesh()
+    cp = current_cp_mesh() if rows > 1 else None
+
+    def stripe():
+        kc, vc = gather_paged_layer(written, layer, block_table,
+                                    k.shape[1])
         if cp is not None:
+            # context-parallel chunked prefill (ISSUE 19): under the
+            # engine's CP trace context, the chunk's sequence axis runs
+            # ring-sharded over the serving mesh — same stripe, same
+            # absolute-position causal rule (GQA folds group-wise inside
+            # the ring)
             from deepspeed_tpu.ops.attention.ring import \
                 ring_prefill_attention
             return ring_prefill_attention(q, kc, vc, cache_position,
                                           cp.mesh, cp.axis)
-    return stripe_attention(q, kc, vc, cache_position)
+        return stripe_attention(q, kc, vc, cache_position)
+
+    if (rows % _OWN_KEYS_ROWS == 0 and cp is None and len(written) == 2
+            and written[0].dtype == k.dtype):
+        return jax.lax.cond(
+            jnp.all(cache_position == 0),
+            lambda: own_keys_attention(q, k, v, cache_position,
+                                       stripe_attention), stripe)
+    return stripe()
 
 
 def _paged_cache_attention(pools, layer: int, block_table, cache_position,

@@ -609,6 +609,59 @@ def test_pallas_decode_trunk_holds_no_gathered_stripe(monkeypatch, family,
         < stripe_elems * 2 // 4
 
 
+@pytest.mark.parametrize("rows,tokens,kernels", [(8, 64, 0), (8, 512, 1)],
+                         ids=["prefill8x64", "prefill8x512"])
+def test_gpt2_prefill_attends_to_its_own_keys(monkeypatch, rows, tokens,
+                                              kernels):
+    """GPT-2 345M's smallest and largest batch-8 prompt buckets over the
+    cell's pool (ISSUE 40): after each layer's in-place write ONE
+    conditional picks the reader from the positions. Its own-keys branch
+    holds no gather and no float32 ``(rows, heads, 640, 64)`` stripe: at
+    8 x 64 the stripe mathematics over the call's 64 keys (``kernels``
+    0), at 8 x 512 the flash kernel at 16 heads of 64 (Mosaic takes the
+    tile). The
+    stripe branch only READS the written pool, so the conditional copies
+    none: the donated pool still comes back in its own buffers and the
+    only results of its size are the 2 x layers scatters that alias
+    it."""
+    monkeypatch.setattr(flash, "_use_pallas", lambda: True)
+    pool_shape = (TRUNK_LAYERS, POOL_PAGES, PAGE, HEADS * HEAD_DIM)
+    pool_elems = int(np.prod(pool_shape))
+    fn, params = _paged_trunk("gpt2", HEADS, HEADS, HEAD_DIM, "pallas")
+    pool = _spec(pool_shape)
+    compiled = fn.lower(params, (pool, pool),
+                        _spec((rows, tokens), jnp.int32),
+                        _spec((rows,), jnp.int32),
+                        _spec((rows, TABLE_PAGES), jnp.int32)).compile()
+    text = compiled.as_text()
+
+    def computation(name):
+        return re.search(rf"^%?{re.escape(name)} .*?^}}", text,
+                         re.S | re.M).group(0)
+    branches = re.findall(
+        r" conditional\(.*?branch_computations=\{%?([\w.\-]+), "
+        r"%?([\w.\-]+)\}", _entry(compiled))
+    assert len(branches) == TRUNK_LAYERS
+    stripe_f32 = f"f32[{rows},{HEADS},{TABLE_PAGES * PAGE},{HEAD_DIM}]"
+    for stripe, own in branches:          # index 0 is the predicate's False
+        own, stripe = computation(own), computation(stripe)
+        assert own.count('custom_call_target="tpu_custom_call"') == kernels
+        assert " gather(" not in own and stripe_f32 not in own
+        assert "tpu_custom_call" not in stripe
+        assert "kv_gather" in stripe and "kv_gather" not in own
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == kernels * TRUNK_LAYERS
+
+    dims = ",".join(map(str, pool_shape))
+    assert not re.search(rf"bf16\[{dims}\]\S* copy\(", text)
+    aliases = dict(re.findall(r"\{(\d+)\}: \((\d+), \{\}, may-alias\)",
+                              text.split("\n", 1)[0]))
+    assert {"1", "2"} <= set(aliases)
+    written = [opcode for opcode, _, elems, _ in _entry_results(compiled)
+               if opcode not in _NO_WRITE and elems == pool_elems]
+    assert written == ["fusion"] * (2 * TRUNK_LAYERS), written
+
+
 def test_serving_compiler_options_share_the_layers_code(monkeypatch):
     """The in-place programs no longer run the compiler short of memory,
     and it was only when short of memory that it generated the code of
