@@ -112,6 +112,9 @@ from deepspeed_tpu.inference.scheduler import (FinishedRequest, Request,
 from deepspeed_tpu.inference.tracing import ServeTracer
 from deepspeed_tpu.models.gpt2 import (GPT2Config, gpt2_forward,
                                        gpt2_param_specs, init_gpt2_params)
+from deepspeed_tpu.models.granite_hybrid import (
+    GraniteHybridConfig, granite_hybrid_forward, granite_hybrid_param_specs,
+    init_granite_hybrid_params)
 from deepspeed_tpu.models.llama import (LlamaConfig, init_llama_params,
                                         llama_forward, llama_param_specs)
 from deepspeed_tpu.models.solar_open2 import (SolarOpen2Config,
@@ -144,6 +147,9 @@ _FAMILIES = {
                   llama_param_specs),
     SolarOpen2Config: ("solar_open2", solar_open2_forward,
                        init_solar_open2_params, solar_open2_param_specs),
+    GraniteHybridConfig: ("granite_hybrid", granite_hybrid_forward,
+                          init_granite_hybrid_params,
+                          granite_hybrid_param_specs),
 }
 
 

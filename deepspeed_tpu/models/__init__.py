@@ -12,5 +12,7 @@ from deepspeed_tpu.models.llama import (
 from deepspeed_tpu.models.smallthinker import (
     SmallThinkerConfig, init_smallthinker_params, smallthinker_logits,
     smallthinker_loss_fn)
+from deepspeed_tpu.models.granite_hybrid import (
+    GraniteHybridConfig, granite_hybrid_forward, init_granite_hybrid_params)
 from deepspeed_tpu.models.solar_open2 import (
     SolarOpen2Config, init_solar_open2_params, solar_open2_forward)

@@ -672,14 +672,16 @@ def gather_paged_layer(pools, layer: int, block_table, kv_heads: int):
     return kc, vc
 
 
-def paged_decode_ctx(q, pools, layer: int, block_table, cache_position):
-    """The seq-1 fused-kernel dispatch both families share: run
+def paged_decode_ctx(q, pools, layer: int, block_table, cache_position,
+                     sm_scale=None):
+    """The seq-1 fused-kernel dispatch the families share: run
     :func:`deepspeed_tpu.ops.attention.paged.paged_decode_attention`
     against layer ``layer`` of the (already-written) stacked pool tree
     and restore the (B, H, 1, hd) context layout. One home so the kernel
     call contract cannot drift between gpt2 and llama. The int8 4-tuple
     selects the kernel's scale arity — the per-page scale tiles stream
-    into the kernel and dequant happens in VMEM.
+    into the kernel and dequant happens in VMEM. ``sm_scale`` (None:
+    ``head_dim ** -0.5``) is what the scores are multiplied by.
 
     Under a serving mesh the engine traces its compiled programs inside
     ``parallel/pallas_shard.pallas_kernel_mesh``; consulting that
@@ -697,11 +699,13 @@ def paged_decode_ctx(q, pools, layer: int, block_table, cache_position):
             out = sharded_paged_decode(q[:, :, 0], kpool, vpool,
                                        block_table, cache_position,
                                        mesh=km.mesh, axis=km.axis,
+                                       sm_scale=sm_scale,
                                        k_scales=k_scales,
                                        v_scales=v_scales, layer=layer)
         else:
             out = paged_decode_attention(q[:, :, 0], kpool, vpool,
                                          block_table, cache_position,
+                                         sm_scale=sm_scale,
                                          k_scales=k_scales,
                                          v_scales=v_scales, layer=layer)
         return out[:, :, None, :]
@@ -721,7 +725,8 @@ _OWN_KEYS_DENSE_SCORES = 1 << 23
 _OWN_KEYS_ROWS = 16
 
 
-def own_keys_attention(q, k, v, cache_position, stripe_attention):
+def own_keys_attention(q, k, v, cache_position, stripe_attention,
+                       sm_scale=None):
     """Causal attention of ``q`` (B, heads, S, hd) over the call's own
     ``k``, ``v`` (B, kv_heads, S, hd): what a row that starts at cache
     position 0 may see is exactly what this call has just computed, so
@@ -731,7 +736,10 @@ def own_keys_attention(q, k, v, cache_position, stripe_attention):
     zeros in ``cache_position``), a larger one to the training kernel
     (``ops/attention/flash.flash_attention``: GQA native, operands as
     they come, float32 accumulation and softmax, no (S, S) scores in
-    HBM). Chosen by the call's shape (``_OWN_KEYS_DENSE_SCORES``)."""
+    HBM). Chosen by the call's shape (``_OWN_KEYS_DENSE_SCORES``).
+    ``sm_scale`` (None: ``head_dim ** -0.5``) is the kernel's score
+    scale; a family with another builds it into its ``stripe_attention``
+    too."""
     from deepspeed_tpu.ops.attention import flash
     from deepspeed_tpu.parallel.pallas_shard import current_kernel_mesh
     B, H, S, _ = q.shape
@@ -739,13 +747,13 @@ def own_keys_attention(q, k, v, cache_position, stripe_attention):
                      stripe_attention=stripe_attention,
                      dense=B * H * S * S <= _OWN_KEYS_DENSE_SCORES,
                      interpret=not flash._use_pallas(),
-                     kernel_mesh=current_kernel_mesh())
+                     kernel_mesh=current_kernel_mesh(), sm_scale=sm_scale)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "stripe_attention", "dense", "interpret", "kernel_mesh"))
+    "stripe_attention", "dense", "interpret", "kernel_mesh", "sm_scale"))
 def _own_keys(q, k, v, cache_position, stripe_attention, dense, interpret,
-              kernel_mesh):
+              kernel_mesh, sm_scale=None):
     """:func:`own_keys_attention` behind a ``jit`` of its own, so that a
     program's layers share ONE trace and ONE lowering of it: traced a
     layer, the flash kernel cost each program of 24 layers 4.5 s of
@@ -758,12 +766,14 @@ def _own_keys(q, k, v, cache_position, stripe_attention, dense, interpret,
     if dense:
         return stripe_attention(q, k, v, cache_position)
     with scope("attn_core"):
-        return flash_attention(q, k, v, causal=True, interpret=interpret)
+        return flash_attention(q, k, v, causal=True, sm_scale=sm_scale,
+                               interpret=interpret)
 
 
 def paged_attend(q, k, v, pools, layer: int, block_table, cache_position,
-                 page, offset, out_box, attn_kernel: str, stripe_attention):
-    """Layer ``layer`` of the paged cached forward, for both families
+                 page, offset, out_box, attn_kernel: str, stripe_attention,
+                 sm_scale=None):
+    """Layer ``layer`` of the paged cached forward, for every family
     (prefill-into-pages and paged decode alike): write this call's K/V
     into the stacked pool tree at ``[layer, page, offset]``
     (:func:`write_paged_layer`), then attend, through one of three
@@ -789,15 +799,17 @@ def paged_attend(q, k, v, pools, layer: int, block_table, cache_position,
       handed to the family's ``stripe_attention(q, kc, vc,
       cache_position)`` (the numerics oracle / fallback).
 
-    The updated tree — the pair, or the int8 4-tuple — returns through
-    ``out_box``."""
+    ``sm_scale`` (None: ``head_dim ** -0.5``) is handed to the two
+    kernels; ``stripe_attention`` is the family's own and carries its
+    scale itself. The updated tree — the pair, or the int8 4-tuple —
+    returns through ``out_box``."""
     from deepspeed_tpu.parallel.pallas_shard import current_cp_mesh
     written = write_paged_layer(pools, layer, k, v, page, offset)
     out_box.append(written)
     rows = q.shape[2]
     if attn_kernel == "pallas" and rows == 1:
         return paged_decode_ctx(q, written, layer, block_table,
-                                cache_position)
+                                cache_position, sm_scale)
     cp = current_cp_mesh() if rows > 1 else None
 
     def stripe():
@@ -820,7 +832,7 @@ def paged_attend(q, k, v, pools, layer: int, block_table, cache_position,
         return jax.lax.cond(
             jnp.all(cache_position == 0),
             lambda: own_keys_attention(q, k, v, cache_position,
-                                       stripe_attention), stripe)
+                                       stripe_attention, sm_scale), stripe)
     return stripe()
 
 

@@ -214,16 +214,18 @@ def _llama_trunk(params, config: LlamaConfig, input_ids,
     return rms_norm(x, params["ln_f"]["w"], config.rms_norm_eps)
 
 
-def _gqa_stripe_attention(q, kc, vc, cache_position):
+def _gqa_stripe_attention(q, kc, vc, cache_position, sm_scale=None):
     """Group-wise attention of ``q`` (B, heads, S, hd) over a whole
     kv_heads-sized key/value stripe (B, kv_heads, kv_len, hd) under the
-    shared ``causal_cache_mask``, in float32: no head is replicated."""
+    shared ``causal_cache_mask``, in float32: no head is replicated.
+    ``sm_scale`` (None: ``hd ** -0.5``) multiplies the scores."""
     from deepspeed_tpu.models.gpt2 import causal_cache_mask
     B, H, S, hd = q.shape
     hkv = kc.shape[1]
     qg = q.reshape(B, hkv, H // hkv, S, hd)
     scores = jnp.einsum("bkgsd,bkld->bkgsl", qg.astype(jnp.float32),
-                        kc.astype(jnp.float32)) / np.sqrt(hd)
+                        kc.astype(jnp.float32))
+    scores = scores / np.sqrt(hd) if sm_scale is None else scores * sm_scale
     mask = causal_cache_mask(cache_position, S, kc.shape[2])
     scores = jnp.where(mask[:, :, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)

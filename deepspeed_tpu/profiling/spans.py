@@ -61,6 +61,10 @@ DEVICE_SCOPES = (
     # their one-token state update; the softmax layers' output gate; the
     # shared expert beside the routed ones
     "kda_proj", "kda_scan", "kda_state", "attn_gate", "moe_shared",
+    # state-space mixers (models/granite_hybrid.py): projections,
+    # convolution, step, gated norm; the chunked prefill recurrence; the
+    # one-token state update and prefill's write of final state and tail
+    "ssd_proj", "ssd_scan", "ssd_state",
 )
 
 # host phase spans (TraceAnnotation), each parent before its children

@@ -379,7 +379,9 @@ def test_the_metric_is_declared_for_both_serving_cells(name):
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     entry = next(m for m in bench["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == CELLS
+    # PR 39's two cells first; a served cell added later joins the list
+    assert entry["workloads"][:2] == CELLS and set(entry["workloads"]) <= {
+        w["name"] for w in bench["workloads"] if ".serve-" in w["name"]}
     assert (entry["source"], entry["layer"], entry["moves"]) == \
         ("program_counter", "serving loop", "serve_tokens_per_s")
     # appended in PR 39 in this order, and still together: what later

@@ -873,6 +873,124 @@ def test_solar_open2_serving_programs_keep_the_pools_in_place(monkeypatch,
     assert 9.6e9 < memory.argument_size_in_bytes < 9.8e9
 
 
+def test_ssd_decode_update_compiles_in_place_at_published_widths(
+        monkeypatch):
+    """The one-token state update of ops/ssd.py over the cell's 65 rows
+    of 128 heads of 64 x 128 (a state that is not square), two layers of
+    a three-layer pool: Mosaic takes the kernel, the pool is aliased
+    through both calls and nothing of its size is copied."""
+    from deepspeed_tpu.ops import ssd
+    rows, heads, p, n = 65, 128, 64, 128
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def two_layers(pool, x, dt, a, bm, cm, d):
+        y, pool = ssd.ssd_decode_update(pool, 0, x, dt, a, bm, cm, d)
+        return ssd.ssd_decode_update(pool, 2, y, dt, a, bm, cm, d)
+
+    f32 = lambda *shape: _spec(shape, jnp.float32)
+    compiled = jax.jit(two_layers, donate_argnums=(0,)).lower(
+        f32(3, rows, heads, p, n), f32(rows, heads, p), f32(rows, heads),
+        f32(heads), f32(rows, n), f32(rows, n), f32(heads)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    memory = compiled.memory_analysis()
+    pool_bytes = 3 * rows * heads * p * n * 4
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < pool_bytes // 100
+    assert not re.search(r"= f32\[3,65,128,64,128\]\S* copy\(", text)
+
+
+def test_ssd_chunk_scan_compiles_at_published_widths():
+    """The prefill recurrence over the cell's smallest bucket at 128
+    heads of 64 x 128: four chunks of 256, the state carried."""
+    from deepspeed_tpu.ops import ssd
+    f32 = lambda *shape: _spec(shape, jnp.float32)
+    compiled = _compile(
+        ssd.ssd_chunk_scan, f32(1, 1024, 128, 64), f32(1, 1024, 128),
+        f32(128), f32(1, 1024, 128), f32(1, 1024, 128), f32(128),
+        f32(1, 128, 64, 128), _spec((1,), jnp.int32))
+    # what a chunk holds beside 12.8 GB of weights and pools
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_granite_hybrid_serving_programs_keep_the_pools_in_place(
+        monkeypatch, program):
+    """The benchmark configuration's decode program (65 rows) and its
+    smallest prefill bucket (1 x 1,024) at the published widths, weights
+    held in bfloat16, the cache tree donated: the page pools and the
+    state pool are aliased through the ten layers and the compiled
+    program holds nothing of the state pool's size beside it; the score
+    scale reaches the two attention kernels as a constant of theirs."""
+    import json
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(__file__), "..", "..", "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from families import granite_hybrid as family
+    from deepspeed_tpu.inference.kv_cache import (PagedStateCache,
+                                                  paged_spec_for,
+                                                  state_pool_spec_for)
+    from deepspeed_tpu.models import granite_hybrid as gh
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(flash, "_use_pallas", lambda: True)
+    with open(os.path.join(bench, "configs",
+                           "granite-4.0-h-small.json")) as f:
+        config = json.load(f)
+    model = family.serve_model_of(config)
+    inference = config["serve"]["inference"]
+    rows = inference["max_batch_size"] + 1
+    pages = paged_spec_for(model, inference["paged_kv"]["num_pages"], 16,
+                           inference["max_seq_len"])
+    state = state_pool_spec_for(model, rows)
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: gh.init_granite_hybrid_params(
+            model, jax.random.PRNGKey(0))))
+    cache = PagedStateCache(_spec(pages.shape), _spec(pages.shape),
+                            _spec(state.state_shape, jnp.float32),
+                            _spec(state.tail_shape))
+    ints = lambda *shape: _spec(shape, jnp.int32)
+
+    def decode(params, cache, toks, positions, tables):
+        logits, cache, counts = gh.granite_hybrid_forward(
+            params, model, toks[:, None], kv_cache=cache,
+            cache_position=positions, block_tables=tables,
+            paged_attn_kernel="pallas", active=tables[:, 0] > 0,
+            with_counts=True)
+        return jnp.argmax(logits[:, 0], -1), counts, cache
+
+    def prefill(params, cache, ids, lengths, tables, slots):
+        logits, cache = gh.granite_hybrid_forward(
+            params, model, ids, kv_cache=cache,
+            cache_position=jnp.zeros_like(lengths), block_tables=tables,
+            paged_attn_kernel="pallas", lengths=lengths, slots=slots)
+        return jnp.argmax(logits[:, 0], -1), cache
+
+    if program == "decode":
+        fn, args = decode, (ints(rows), ints(rows),
+                            ints(rows, pages.pages_per_seq))
+        kernels = 9 + 1            # the state updates and the paged reader
+    else:
+        fn, args = prefill, (ints(1, 1024), ints(1),
+                             ints(1, pages.pages_per_seq), ints(1))
+        kernels = 1 + 3 * 10       # flash, three grouped products a layer
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    memory = compiled.memory_analysis()
+    pool_bytes = int(np.prod(state.state_shape)) * 4
+    assert memory.alias_size_in_bytes >= pool_bytes + 2 * int(
+        np.prod(pages.shape)) * 2
+    assert memory.temp_size_in_bytes < pool_bytes // 4
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+    assert not re.search(r"= f32\[9,%d,128,64,128\]\S* copy\(" % rows, text)
+    # the weights as they are held (4,757M parameters in bfloat16) and
+    # the pools
+    assert 12.7e9 < memory.argument_size_in_bytes < 12.9e9
+
+
 def test_smallthinker_train_step_compiles_at_the_cut_widths(monkeypatch):
     """`deepspeed_tpu.initialize` + the ONE compiled `_micro_step`
     (ZeRO-2, bf16, Adam, clipping) of the benchmark's configuration at
