@@ -634,10 +634,13 @@ class InferenceEngine:
         # offers the router over the layers, the experts held here):
         # the paged decode program then returns the layers' counters
         # with its tokens, and a span carries those of the step before
-        # (its own are known only once its tokens are read)
+        # (its own are known only once its tokens are read); a prefill
+        # program returns the rows its expert turns worked and the rows
+        # static turns would have, the same way
         self._expert_counters = getattr(model_config, "expert_counters",
                                         None)
         self._moe_counts = (0, 0)
+        self._moe_prefill_rows = (0, 0)
         if self._expert_counters is not None and not self.paged:
             raise ValueError(
                 f"{type(model_config).__name__} reports its routed "
@@ -1009,16 +1012,24 @@ class InferenceEngine:
         the forward also learns each row's true length and which SLOT it
         is (pad rows name the scratch row), writes the row's final state
         whole at that index, and returns the logits of the last true
-        position alone."""
-        logits, cache = self._forward(
+        position alone. Where the family declares routed experts the
+        rows its expert turns worked and the rows static turns would
+        have, each summed over the layers, ride home behind the first
+        tokens as decode's counters do (:meth:`_decode_paged_impl`)."""
+        counted = {}
+        if self._expert_counters is not None:
+            counted = dict(with_counts=True)
+        logits, cache, *rows = self._forward(
             params, self.model_config, ids, dtype=self.dtype,
             kv_cache=cache, cache_position=positions,
             block_tables=tables,
             paged_attn_kernel=self._decode_attn_path, lengths=lengths,
-            slots=slots)
+            slots=slots, **counted)
         first_keys = jax.vmap(jax.random.fold_in)(keys,
                                                   positions + lengths)
         first = self._sample_tokens(logits[:, 0], first_keys, temps)
+        if rows:
+            first = jnp.concatenate([first, jnp.sum(rows[0], axis=0)])
         return first, cache
 
     def _chunk_cp_impl(self, params, cache, ids, lengths, positions,
@@ -1592,9 +1603,15 @@ class InferenceEngine:
         # cache position 0 the batch attends to its own keys
         # (models/gpt2.paged_attend), else to the gathered stripe
         own = real if self.paged and not any(batch.prefix_lens) else 0
+        counters = {}
+        if self._expert_counters is not None:
+            # routed experts: what the prefill before's turns worked
+            worked, static = self._moe_prefill_rows
+            counters = dict(expert_rows_worked=worked,
+                            expert_rows_sorted=static)
         with self._span("serve/prefill", seq=ledger.total,
                         step=self._steps, batch=bb, prompt=pb,
-                        real_tokens=real, own_key_tokens=own):
+                        real_tokens=real, own_key_tokens=own, **counters):
             with self._span("serve/prefill/build"):
                 keys = np.zeros((bb, 2), np.uint32)
                 temps = np.zeros((bb,), np.float32)
@@ -1639,6 +1656,10 @@ class InferenceEngine:
             ledger.issued()
             with self._span("serve/prefill/wait"):
                 first = np.asarray(first)
+                if len(first) > bb:     # the expert turns' rows ride behind
+                    self._moe_prefill_rows = (int(first[-2]),
+                                              int(first[-1]))
+                    first = first[:bb]
             return first, ledger.ready()
 
     def _drain_request_metrics(self):

@@ -55,8 +55,8 @@ from deepspeed_tpu.models.gpt2 import (own_keys_attention, paged_attend,
 from deepspeed_tpu.models.llama import _gqa_stripe_attention
 from deepspeed_tpu.models.solar_open2 import _mm, _norm, _Pages
 from deepspeed_tpu.ops.functional import rms_norm
-from deepspeed_tpu.ops.moe import (dropless_experts, held_experts_every_row,
-                                   route_top_k)
+from deepspeed_tpu.ops.moe import (held_experts_every_row, route_top_k,
+                                   served_experts)
 from deepspeed_tpu.ops.ssd import ssd_chunk_scan, ssd_decode_update
 from deepspeed_tpu.profiling.spans import scope
 
@@ -365,11 +365,16 @@ def _mamba_mixer(mp, config, h, dtype, lengths, cache):
         return _mm(y, mp["w_out"], dtype), pools
 
 
-def _expert_half(lp, config, x, dtype, active):
-    """x -> (x + r (routed + shared), (landed, fullest) int32 of this
-    layer), as ``models/solar_open2._expert_half``: one token a row
-    (decode) works every held expert on every row; a bucket of prompts
-    goes through the dropless layer's static turns."""
+def _expert_half(lp, config, x, dtype, active, lengths):
+    """x -> (x + r (routed + shared), a pair int32 of this layer), as
+    ``models/solar_open2._expert_half``: one token a row (decode) works
+    every held expert on every row, the pair (landed, fullest); a bucket
+    of prompts goes through ``ops.moe.served_experts``, whose work
+    follows the assignments that landed here at a true position
+    (``lengths``; None: every position), the pair (rows worked, rows
+    static turns would have). The trained ``dropless_experts`` is not
+    called: its time must not follow the router, a served prefill's
+    should."""
     B, S, hdim = x.shape
     h2 = _norm(x, lp["ln_2"]["w"], config.rms_norm_eps)
     flat = h2.reshape(B * S, hdim)
@@ -382,10 +387,13 @@ def _expert_half(lp, config, x, dtype, active):
     if S == 1:
         y, counts = held_experts_every_row(
             rows, idx, p, experts, config.held, jax.nn.silu, active)
+        pair = jnp.stack([jnp.sum(counts), jnp.max(counts)])
     else:
-        y, counts = dropless_experts(
+        counted = None if lengths is None else (
+            jnp.arange(S) < lengths[:, None]).reshape(B * S)
+        y, _, pair = served_experts(
             rows, idx, p, experts, config.held, config.num_experts,
-            jax.nn.silu, tile=_EXPERT_TILE)
+            jax.nn.silu, tile=_EXPERT_TILE, counted=counted)
     with scope("moe_shared"):
         sp = lp["shared"]
         act = jax.nn.silu(_mm(flat, sp["w_gate"], dtype)) * _mm(
@@ -393,7 +401,7 @@ def _expert_half(lp, config, x, dtype, active):
         y = y + _mm(act, sp["w_down"], dtype)
     with scope("moe_dispatch"):
         x = x + config.residual_multiplier * y.reshape(B, S, hdim)
-    return x, jnp.stack([jnp.sum(counts), jnp.max(counts)])
+    return x, pair
 
 
 def granite_hybrid_forward(params, config: GraniteHybridConfig, input_ids,
@@ -410,7 +418,8 @@ def granite_hybrid_forward(params, config: GraniteHybridConfig, input_ids,
     ``lengths`` and ``slots``) returning the logits at each row's last
     true position, (B, 1, rows), DECODE (S == 1) running row i against
     row i of the state pools. Returns (logits, the cache); with
-    ``with_counts`` also (layers, 2) int32 over the ``active`` rows."""
+    ``with_counts`` also (layers, 2) int32: over the ``active`` rows in
+    decode, the expert turns' rows (worked, static) in prefill."""
     B, S = input_ids.shape
     serving = kv_cache is not None
     if serving:
@@ -447,7 +456,7 @@ def granite_hybrid_forward(params, config: GraniteHybridConfig, input_ids,
                 state, tails = new
             n_rec += 1
         x = x + r * y
-        x, c = _expert_half(lp, config, x, dtype, active)
+        x, c = _expert_half(lp, config, x, dtype, active, lengths)
         counts.append(c)
     x = _norm(x, params["ln_f"]["w"], config.rms_norm_eps)
     if serving and S > 1:

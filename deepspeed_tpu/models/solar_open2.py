@@ -46,8 +46,8 @@ from deepspeed_tpu.models.llama import _gqa_stripe_attention
 from deepspeed_tpu.ops.attention.flash import flash_attention
 from deepspeed_tpu.ops.functional import rms_norm
 from deepspeed_tpu.ops.kda import kda_chunk_scan, kda_decode_update
-from deepspeed_tpu.ops.moe import (dropless_experts, held_experts_every_row,
-                                   route_top_k)
+from deepspeed_tpu.ops.moe import (held_experts_every_row, route_top_k,
+                                   served_experts)
 from deepspeed_tpu.profiling.spans import scope
 
 # caps of the grouped products' tile at these experts' widths (4,096 x
@@ -333,12 +333,19 @@ def _kda_mixer(kp, config, h, dtype, lengths, cache):
         return _mm((o * gate).astype(dtype), kp["wo"], dtype), pools
 
 
-def _expert_half(lp, config, x, dtype, active):
-    """x -> (x + routed + shared, (landed, fullest) int32 of this
-    layer: assignments of ``active`` rows that fell on held experts, and
-    the fullest held expert's). One token a row (decode) works every
-    held expert on every row; a bucket of prompts goes through the
-    dropless layer's static turns."""
+def _expert_half(lp, config, x, dtype, active, lengths):
+    """x -> (x + routed + shared, a pair int32 of this layer). One token
+    a row (decode) works every held expert on every row, and the pair is
+    (landed, fullest): assignments of ``active`` rows that fell on held
+    experts, and the fullest held expert's. A bucket of prompts goes
+    through ``ops.moe.served_experts``, whose work follows the
+    assignments that landed here at a TRUE position (``lengths`` (B,);
+    None, the plain forward: every position), and the pair is (rows its
+    turns worked, rows the dropless layer's static turns would have).
+    Not ``dropless_experts``: that is the TRAINED layer, whose time must
+    not follow the router and whose turns differentiate; what a padded
+    position's experts give is read by nothing (causal attention, a scan
+    to the true lengths, the logits of the last true position)."""
     B, S, hdim = x.shape
     h2 = _norm(x, lp["ln_2"]["w"], config.rms_norm_eps)
     flat = h2.reshape(B * S, hdim)
@@ -353,10 +360,13 @@ def _expert_half(lp, config, x, dtype, active):
     if S == 1:
         y, counts = held_experts_every_row(
             rows, idx, p, experts, config.held, jax.nn.silu, active)
+        pair = jnp.stack([jnp.sum(counts), jnp.max(counts)])
     else:
-        y, counts = dropless_experts(
+        counted = None if lengths is None else (
+            jnp.arange(S) < lengths[:, None]).reshape(B * S)
+        y, _, pair = served_experts(
             rows, idx, p, experts, config.held, config.num_experts,
-            jax.nn.silu, tile=_EXPERT_TILE)
+            jax.nn.silu, tile=_EXPERT_TILE, counted=counted)
     with scope("moe_shared"):
         sp = lp["shared"]
         act = jax.nn.silu(_mm(flat, sp["w_gate"], dtype)) * _mm(
@@ -364,7 +374,7 @@ def _expert_half(lp, config, x, dtype, active):
         y = y + _mm(act, sp["w_down"], dtype)
     with scope("moe_dispatch"):
         x = x + y.reshape(B, S, hdim)
-    return x, jnp.stack([jnp.sum(counts), jnp.max(counts)])
+    return x, pair
 
 
 def solar_open2_forward(params, config: SolarOpen2Config, input_ids,
@@ -389,9 +399,10 @@ def solar_open2_forward(params, config: SolarOpen2Config, input_ids,
     row names the scratch row), and returns logits at each row's LAST
     true position only, (B, 1, rows). DECODE (S == 1) runs row i
     against row i of the state pools. Returns (logits, the cache); with
-    ``with_counts`` also (layers, 2) int32, each layer's assignments
-    landed on held experts and its fullest held expert's, counted over
-    the ``active`` (B,) rows."""
+    ``with_counts`` also (layers, 2) int32: in DECODE each layer's
+    assignments landed on held experts and its fullest held expert's,
+    counted over the ``active`` (B,) rows; in PREFILL the rows each
+    layer's expert turns worked and the rows static turns would have."""
     B, S = input_ids.shape
     serving = kv_cache is not None
     if serving:
@@ -426,7 +437,7 @@ def solar_open2_forward(params, config: SolarOpen2Config, input_ids,
                 state, tails = new
             n_rec += 1
         x = x + y
-        x, c = _expert_half(lp, config, x, dtype, active)
+        x, c = _expert_half(lp, config, x, dtype, active, lengths)
         counts.append(c)
     x = _norm(x, params["ln_f"]["w"], config.rms_norm_eps)
     if serving and S > 1:

@@ -326,6 +326,12 @@ def moe_layer_sharded(params, config: MoEConfig, x, mesh,
 # chip that holds every expert has no padding and one turn. Each turn
 # is recomputed in the backward pass, so the layer keeps only its
 # inputs.
+#
+# That is what a TRAIN step needs. A SERVED prefill needs the opposite,
+# work that follows the rows whose result is read, and has its own entry
+# point, `served_experts`: the same sort and grouped product under a
+# loop whose trip count follows the rows that count. Which of the two a
+# program runs is its caller's choice.
 
 
 def route_top_k(router_in, w_router, top_k: int):
@@ -384,19 +390,30 @@ def grouped_matmul(lhs, rhs, group_sizes, tile=None):
                tiling=tiling, interpret=jax.default_backend() != "tpu")
 
 
-def _weighted_rows(rows, w, pos, here, block=2048):
+def _weighted_rows(rows, w, pos, here, block=2048, slabs=False):
     """(T, H) float32: token t sums ``w[t, j] * rows[pos[t, j]]`` over
     its choices j that are ``here``, a block of tokens at a time so that
-    nothing of (T, k, H) is ever held."""
+    nothing of (T, k, H) is ever held. ``slabs`` lays a block's picks a
+    CHOICE a slab, (k, block, H), and adds the slabs up: (block, k, H)
+    keeps the k choices on the sublanes (ten padded to sixteen) and pays
+    a relayout of every picked row before it sums them (granite's
+    prefill on the chip: 10.2 ms a layer at 8,192 tokens against 5.6 in
+    slabs of 512; docs/solar_open2.md). The same picks, weights and
+    float32 sum, the order of a token's k additions may differ; the
+    trained layer keeps the layout its compiled step was measured with."""
     t, k = pos.shape
     block = block if t % block == 0 else t
 
     def one(args):
         pos_b, w_b, here_b = args
         picked = jnp.where(here_b[..., None], rows[pos_b], 0)
-        return jnp.sum(picked.astype(jnp.float32) * w_b[..., None], axis=1)
+        return jnp.sum(picked.astype(jnp.float32) * w_b[..., None],
+                       axis=0 if slabs else 1)
 
-    split = lambda a: a.reshape(t // block, block, k)
+    if slabs:
+        split = lambda a: a.T.reshape(k, t // block, block).swapaxes(0, 1)
+    else:
+        split = lambda a: a.reshape(t // block, block, k)
     return jax.lax.map(one, (split(pos), split(w), split(here))
                        ).reshape(t, rows.shape[1])
 
@@ -448,6 +465,33 @@ def _combine_rows_bwd(res, g):
 _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
+def _sorted_plan(idx, p, experts_held, counted=None):
+    """The sort both entry points share. An assignment (token, choice)
+    COUNTS when its expert ``idx[t, j]`` is held here and, where
+    ``counted`` (T,) bool is given, its token counts; the rest sort
+    behind those that do. Returns (counts (held,) int32 of the
+    assignments that count on each held expert, (order, pos, here,
+    ends): sorted position -> assignment, assignment -> sorted position
+    (T, k), which assignments count (T, k), the held experts' ends among
+    the sorted rows, w (T, k) float32: ``p`` where it counts, else 0)."""
+    first, count = experts_held
+    t, top_k = idx.shape
+    local = idx - first
+    here = (local >= 0) & (local < count)
+    if counted is not None:
+        here = here & counted[:, None]
+    key = jnp.where(here, local, count).reshape(-1)
+    counts = jnp.sum(key[:, None] == jnp.arange(count)[None, :],
+                     axis=0, dtype=jnp.int32)
+    ends = jnp.cumsum(counts)
+    # sorted position -> assignment (stable: token order inside an
+    # expert), and assignment -> sorted position
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    pos = jnp.argsort(order).astype(jnp.int32).reshape(t, top_k)
+    w = jnp.where(here, p, 0.0).astype(jnp.float32)
+    return counts, (order, pos, here, ends), w
+
+
 def _glu_chunk(rows, activation, tile, c, x, w, experts, plan):
     """The held experts on chunk ``c`` of the sorted assignments: rows
     [c * rows, (c + 1) * rows). Returns this chunk's part of y (T, H)."""
@@ -488,34 +532,27 @@ def dropless_experts(x, idx, p, experts, experts_held, num_experts,
     (first, count) of the ``num_experts`` the router scores;
     ``activation`` the gate's (``jax.nn.relu``: ReGLU, SmallThinker's;
     ``jax.nn.silu``: SwiGLU); ``tile`` as :func:`grouped_matmul`'s.
-    Trained and served alike: prefill serves a bucket's tokens through
-    it (``models/solar_open2.py``). Returns
+    The TRAINED layer (``models/smallthinker.py``): static turns, each
+    worked whole and recomputed in the backward pass. A served prefill
+    calls :func:`served_experts`, which shares the sort, the grouped
+    product and the combine and not the loop; the caller picks, nothing
+    here does.
+    Returns
     (y (T, H) float32, counts (held,) int32: the assignments that landed
     on each held expert). No assignment is dropped whatever the
-    imbalance, and the time does not follow it. Traced under the scopes ``moe_route`` (the sort),
-    ``moe_dispatch`` (the permutes, the combine) and ``moe_experts`` (the
-    grouped products).
+    imbalance, and the time does not follow it. Traced under the scopes
+    ``moe_route`` (the sort), ``moe_dispatch`` (the permutes, the
+    combine) and ``moe_experts`` (the grouped products).
     """
-    first, count = experts_held
     t, top_k = idx.shape
-    rows = chunk_rows(t * top_k, count, num_experts)
+    rows = chunk_rows(t * top_k, experts_held[1], num_experts)
     with scope("moe_route"):
-        local = idx - first
-        held = (local >= 0) & (local < count)
-        key = jnp.where(held, local, count).reshape(-1)
-        counts = jnp.sum(key[:, None] == jnp.arange(count)[None, :],
-                         axis=0, dtype=jnp.int32)
-        ends = jnp.cumsum(counts)
-        # sorted position -> assignment (stable: token order inside an
-        # expert), and assignment -> sorted position
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        pos = jnp.argsort(order).astype(jnp.int32).reshape(t, top_k)
-        w = jnp.where(held, p, 0.0).astype(jnp.float32)
+        counts, plan, w = _sorted_plan(idx, p, experts_held)
     chunk = jax.checkpoint(
         functools.partial(_glu_chunk, rows, activation, tile))
 
     def turn(y, c):
-        part = chunk(c, x, w, experts, (order, pos, held, ends))
+        part = chunk(c, x, w, experts, plan)
         with scope("moe_dispatch"):
             return y + part, None
 
@@ -532,6 +569,82 @@ def dropless_reglu_experts(x, idx, p, experts, experts_held, num_experts):
     package calls it."""
     return dropless_experts(x, idx, p, experts, experts_held, num_experts,
                             jax.nn.relu)
+
+
+def served_turn_rows(assignments: int, held: int, num_experts: int,
+                     tile_rows: int) -> int:
+    """Rows of a SERVED turn's buffer: an eighth of this chip's even
+    share of the assignments, in whole row tiles of the grouped product,
+    at least one tile and at most every assignment."""
+    up = lambda n: -(-n // tile_rows) * tile_rows
+    return min(max(up(assignments * held // (8 * num_experts)), tile_rows),
+               up(assignments))
+
+
+def served_experts(x, idx, p, experts, experts_held, num_experts,
+                   activation, tile=None, counted=None):
+    """The sum of :func:`dropless_experts` for a SERVED prefill, where
+    the work follows the rows that count: an assignment counts when its
+    expert is held here and its token does (``counted`` (T,) bool: the
+    true positions of a bucket's prompts; None: every token). The rows
+    that do not count sort behind those that do and nothing is done for
+    them: the sorted rows are worked in turns of
+    :func:`served_turn_rows`, as many as reach the last row that counts
+    (a trip count read from the plan: nothing differentiates through
+    this); a turn takes its rows of ``x``, hands the two products up the
+    held experts' TRUE counts of its rows (megablox computes only the
+    row tiles they cover) and writes its activations, (rows, F), into
+    one buffer; after the last turn ONE product down over that buffer
+    under the held experts' true counts gives every row's output, and
+    the tokens combine ONCE from it (:func:`_weighted_rows` in slabs). A row
+    that counts goes through the operands, the float32 accumulation and
+    the weights it has in :func:`dropless_experts`; ``y`` of a token
+    that does not count is zero. The time follows the router and the
+    prompts' lengths, which is what a served prefill wants and a train
+    step does not (docs/solar_open2.md "Trained and served"): the CALLER
+    picks.
+
+    Arguments as :func:`dropless_experts`'s. Returns (y (T, H) float32,
+    counts (held,) int32 of the assignments that count, (2,) int32: the
+    rows the turns worked, and the rows :func:`dropless_experts`' static
+    turns would have). Scopes as there."""
+    t, top_k = idx.shape
+    held = experts_held[1]
+    rows = served_turn_rows(t * top_k, held, num_experts,
+                            (tile or _GMM_TILE)[0])
+    static = chunk_rows(t * top_k, held, num_experts)
+    with scope("moe_route"):
+        counts, (order, pos, here, ends), w = _sorted_plan(
+            idx, p, experts_held, counted)
+        turns = -(-ends[-1] // rows)
+    with scope("moe_dispatch"):
+        # (the buffer is whole turns: it may pass the end)
+        order = jnp.pad(order, (0, (-order.shape[0]) % rows))
+
+    def turn(c, act):
+        lo = c * rows
+        with scope("moe_dispatch"):
+            tok = jax.lax.dynamic_slice(order, (lo,), (rows,)) // top_k
+            sizes = jnp.diff(jnp.clip(ends, lo, lo + rows),
+                             prepend=lo).astype(jnp.int32)
+            xs = x[tok]
+        with scope("moe_experts"):
+            gate = grouped_matmul(xs, experts["w_gate"], sizes, tile)
+            up = grouped_matmul(xs, experts["w_up"], sizes, tile)
+            return jax.lax.dynamic_update_slice(
+                act, activation(gate) * up, (lo, 0))
+
+    # a turn's rows past its groups hold anything, as do the product's
+    # below: the combine reads a row only where it counts
+    with scope("moe_experts"):
+        act = jax.lax.fori_loop(
+            0, turns, turn, jnp.zeros(
+                (order.shape[0], experts["w_down"].shape[1]), x.dtype))
+        ys = grouped_matmul(act, experts["w_down"], counts, tile)
+    with scope("moe_dispatch"):
+        y = _weighted_rows(ys, w, pos, here, block=512, slabs=True)
+    return y, counts, jnp.stack(
+        [turns * rows, jnp.int32(-(-t * top_k // static) * static)])
 
 
 def held_experts_every_row(x, idx, p, experts, experts_held, activation,

@@ -238,6 +238,53 @@ def test_a_batch_with_a_pad_row_serves_what_each_prompt_alone_would(model):
     assert any(a["landed"] > 0 for a in decodes[1:])
 
 
+def test_a_prompt_of_a_third_of_its_bucket_beside_a_pad_row(model,
+                                                           monkeypatch):
+    """A prompt of 11 tokens in the bucket of 32, one prompt in the batch
+    bucket of two (``pad_prompts`` gives the pad row length 1): the
+    experts work the true positions' assignments alone, and the first
+    token and the slot's state row are the UNPADDED prompt's (the plain
+    reference on the 11 tokens); the next `serve/prefill` span carries
+    what this one's expert turns worked."""
+    cfg, params, ref = model
+    seen = []
+    plain = InferenceEngine._span
+
+    def recording(self, name, **args):
+        if name == "serve/prefill":
+            seen.append(args)
+        return plain(self, name, **args)
+
+    monkeypatch.setattr(InferenceEngine, "_span", recording)
+    rs = np.random.RandomState(5)
+    prompts = [list(rs.randint(0, 128, 11)) for _ in range(2)]
+    with jax.default_matmul_precision("highest"):
+        engine = InferenceEngine(
+            cfg, params, {**INFERENCE, "batch_buckets": [2],
+                          "prompt_buckets": [32]}, dtype=jnp.float32)
+        for i, prompt in enumerate(prompts):
+            engine.submit(Request(prompt=prompt, max_new_tokens=4,
+                                  temperature=0.0, seed=i, eos_id=None))
+            engine.step()
+            absorbed, row = engine.slot_state(i)
+            first = engine.scheduler.slots[i].tokens[0]
+            assert absorbed[:11] == prompt and absorbed[11] == first
+            logits = np.asarray(ref(params, jnp.asarray([prompt],
+                                                        jnp.int32)))[0]
+            assert first == int(logits[-1].argmax())
+            want = np.asarray(jax.jit(family.reference_state(cfg))(
+                params, jnp.asarray([absorbed], jnp.int32),
+                jnp.asarray([len(absorbed)], jnp.int32)))[0]
+            np.testing.assert_allclose(row, want, atol=2e-5 * np.abs(want).max())
+    engine.close()
+    assert [(a["batch"], a["prompt"], a["real_tokens"]) for a in seen] == [
+        (2, 32, 11)] * 2
+    # 4 layers, the bucket's 256 assignments in one static turn of 512
+    assert seen[0]["expert_rows_worked"] == seen[0]["expert_rows_sorted"] == 0
+    assert seen[1]["expert_rows_sorted"] == 4 * 512
+    assert 0 < seen[1]["expert_rows_worked"] <= seen[1]["expert_rows_sorted"]
+
+
 def test_the_published_score_scale_reaches_both_attention_readers():
     """`attention_multiplier` in the prefill reader (a prompt's own
     keys) AND in the paged decode reader (the Pallas kernel over the

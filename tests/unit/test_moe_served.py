@@ -83,3 +83,100 @@ def test_held_experts_on_every_row_equal_the_dropless_layer(first):
 def test_a_capped_tile_divides_its_operand(dim, cap, want):
     from deepspeed_tpu.ops.moe import _whole_tile
     assert _whole_tile(dim, cap) == want
+
+
+# ------------------------------------------------------------------ #
+# the served entry point: work that follows the rows that count
+# ------------------------------------------------------------------ #
+
+_SERVED_EXPERTS, _SERVED_HELD, _SERVED_TOP_K = 12, 4, 4
+
+
+def _served_case(first, landed, tokens=96, hidden=32, ffn=48):
+    """Tables of `_SERVED_HELD` experts from `first` and a routing in
+    which `landed` of a token's four choices name one of them."""
+    x, _, p, tables, _ = _glu_case(tokens, hidden, ffn, _SERVED_HELD,
+                                   _SERVED_EXPERTS, _SERVED_TOP_K)
+    rng = np.random.default_rng(first + landed)
+    here = np.arange(first, first + _SERVED_HELD)
+    away = np.setdiff1d(np.arange(_SERVED_EXPERTS), here)
+    idx = np.stack([rng.permutation(np.concatenate([
+        rng.permutation(here)[:landed],
+        rng.permutation(away)[:_SERVED_TOP_K - landed]]))
+        for _ in range(tokens)]).astype(np.int32)
+    return x, jnp.asarray(idx), p, tables
+
+
+@pytest.mark.parametrize("activation", [jax.nn.relu, jax.nn.silu],
+                         ids=["reglu", "swiglu"])
+@pytest.mark.parametrize("tile", [None, (128, 1280, 640)],
+                         ids=["tile512", "tile128"])
+@pytest.mark.parametrize("first", [0, 4, 8], ids=["first", "middle", "last"])
+@pytest.mark.parametrize("landed", [0, 2, 4],
+                         ids=["none_lands", "half_lands", "all_land"])
+def test_served_experts_equal_the_dropless_layer(activation, tile, first,
+                                                 landed):
+    from deepspeed_tpu.ops.moe import (chunk_rows, dropless_experts,
+                                       served_experts, served_turn_rows)
+    x, idx, p, tables = _served_case(first, landed)
+    held = (first, _SERVED_HELD)
+    with jax.default_matmul_precision("highest"):
+        want, want_counts = dropless_experts(
+            x, idx, p, tables, held, _SERVED_EXPERTS, activation, tile=tile)
+        y, counts, rows = served_experts(
+            x, idx, p, tables, held, _SERVED_EXPERTS, activation, tile=tile)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(counts),
+                                  np.asarray(want_counts))
+    assignments = idx.size
+    turn = served_turn_rows(assignments, _SERVED_HELD, _SERVED_EXPERTS,
+                            512 if tile is None else tile[0])
+    lands = idx.shape[0] * landed
+    static = chunk_rows(assignments, _SERVED_HELD, _SERVED_EXPERTS)
+    assert [int(r) for r in rows] == [
+        -(-lands // turn) * turn, -(-assignments // static) * static]
+    if not landed:
+        assert not np.asarray(y).any()
+
+
+@pytest.mark.parametrize("true_tokens", [0, 1, 37, 96])
+def test_served_experts_work_the_counted_rows_alone(true_tokens):
+    """A mask of the tokens that count (a bucket's true positions): their
+    rows as the dropless layer gives them, the rest exactly zero, the
+    counts those of the counted rows, the turns as many as reach the
+    last row that counts."""
+    from deepspeed_tpu.ops.moe import (dropless_experts, served_experts,
+                                       served_turn_rows)
+    x, idx, p, tables = _served_case(4, 2)
+    held, tile = (4, _SERVED_HELD), (128, 1280, 640)
+    counted = np.arange(idx.shape[0]) % 96 < true_tokens
+    with jax.default_matmul_precision("highest"):
+        want, _ = dropless_experts(x, idx, p, tables, held,
+                                   _SERVED_EXPERTS, jax.nn.silu, tile=tile)
+        y, counts, rows = jax.jit(lambda c: served_experts(
+            x, idx, p, tables, held, _SERVED_EXPERTS, jax.nn.silu,
+            tile=tile, counted=c))(jnp.asarray(counted))
+    y = np.asarray(y)
+    np.testing.assert_allclose(y[counted], np.asarray(want)[counted],
+                               atol=1e-6)
+    assert not y[~counted].any()
+    local = np.asarray(idx)[counted] - 4
+    np.testing.assert_array_equal(
+        np.asarray(counts),
+        [(local == e).sum() for e in range(_SERVED_HELD)])
+    turn = served_turn_rows(idx.size, _SERVED_HELD, _SERVED_EXPERTS, 128)
+    assert turn == 128
+    assert int(rows[0]) == -(-2 * true_tokens // turn) * turn
+
+
+@pytest.mark.parametrize("assignments,held,experts,tile,want", [
+    (81920, 36, 72, 256, 5120),      # granite, 2 x 4,096: an eighth
+    (10240, 36, 72, 256, 768),       # granite, 1 x 1,024: whole tiles
+    (2048, 40, 320, 128, 128),       # Solar, 1 x 256: at least a tile
+    (32768, 40, 320, 128, 512),      # Solar, 4 x 1,024
+    (64, 8, 8, 128, 128),            # at most every assignment
+])
+def test_a_served_turn_comes_from_the_shapes(assignments, held, experts,
+                                             tile, want):
+    from deepspeed_tpu.ops.moe import served_turn_rows
+    assert served_turn_rows(assignments, held, experts, tile) == want

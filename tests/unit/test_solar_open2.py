@@ -226,6 +226,53 @@ def test_a_slots_state_row_is_the_references_recurrence(model):
     engine.close()
 
 
+def test_a_prompt_of_a_third_of_its_bucket_beside_a_pad_row(model,
+                                                           monkeypatch):
+    """A prompt of 11 tokens in the bucket of 32, one prompt in the batch
+    bucket of two (``pad_prompts`` gives the pad row length 1): the
+    experts work the true positions' assignments alone, and the first
+    token and the slot's state row are the UNPADDED prompt's (the plain
+    reference on the 11 tokens); the next `serve/prefill` span carries
+    what this one's expert turns worked."""
+    cfg, params, ref = model
+    seen = []
+    plain = InferenceEngine._span
+
+    def recording(self, name, **args):
+        if name == "serve/prefill":
+            seen.append(args)
+        return plain(self, name, **args)
+
+    monkeypatch.setattr(InferenceEngine, "_span", recording)
+    rs = np.random.RandomState(5)
+    prompts = [list(rs.randint(0, 128, 11)) for _ in range(2)]
+    with jax.default_matmul_precision("highest"):
+        engine = InferenceEngine(
+            cfg, params, {**INFERENCE, "batch_buckets": [2],
+                          "prompt_buckets": [32]}, dtype=jnp.float32)
+        for i, prompt in enumerate(prompts):
+            engine.submit(Request(prompt=prompt, max_new_tokens=4,
+                                  temperature=0.0, seed=i, eos_id=None))
+            engine.step()
+            absorbed, row = engine.slot_state(i)
+            first = engine.scheduler.slots[i].tokens[0]
+            assert absorbed[:11] == prompt and absorbed[11] == first
+            logits = np.asarray(ref(params, jnp.asarray([prompt],
+                                                        jnp.int32)))[0]
+            assert first == int(logits[-1].argmax())
+            want = np.asarray(jax.jit(family.reference_state(cfg))(
+                params, jnp.asarray([absorbed], jnp.int32),
+                jnp.asarray([len(absorbed)], jnp.int32)))[0]
+            np.testing.assert_allclose(row, want, atol=2e-4)
+    engine.close()
+    assert [(a["batch"], a["prompt"], a["real_tokens"]) for a in seen] == [
+        (2, 32, 11)] * 2
+    # 4 layers, the bucket's 256 assignments in one static turn of 512
+    assert seen[0]["expert_rows_worked"] == seen[0]["expert_rows_sorted"] == 0
+    assert seen[1]["expert_rows_sorted"] == 4 * 512
+    assert 0 < seen[1]["expert_rows_worked"] <= seen[1]["expert_rows_sorted"]
+
+
 def test_the_reference_at_a_lower_precision_moves_as_its_precision(model):
     """The controls' knobs: products at bfloat16 move the logits a
     little, at float8 (e5m2) far more; a bfloat16 state moves the state
