@@ -110,6 +110,8 @@ from deepspeed_tpu.inference.kv_cache import (PageAllocator, PagedStateCache,
 from deepspeed_tpu.inference.scheduler import (FinishedRequest, Request,
                                                Scheduler)
 from deepspeed_tpu.inference.tracing import ServeTracer
+from deepspeed_tpu.models.axk1 import (AXK1Config, axk1_forward,
+                                       axk1_param_specs, init_axk1_params)
 from deepspeed_tpu.models.gpt2 import (GPT2Config, gpt2_forward,
                                        gpt2_param_specs, init_gpt2_params)
 from deepspeed_tpu.models.granite_hybrid import (
@@ -150,6 +152,7 @@ _FAMILIES = {
     GraniteHybridConfig: ("granite_hybrid", granite_hybrid_forward,
                           init_granite_hybrid_params,
                           granite_hybrid_param_specs),
+    AXK1Config: ("axk1", axk1_forward, init_axk1_params, axk1_param_specs),
 }
 
 
@@ -325,6 +328,15 @@ class InferenceEngine:
             model_config, cfg["max_batch_size"] + 1, tail_dtype=dtype)
         if self.state_spec is not None:
             self._refuse_what_state_cannot_follow(cfg, mesh)
+        # a family with latent attention keeps ONE latent row a token
+        # in place of keys and values (inference/kv_cache.py)
+        self.latent = getattr(model_config, "latent_geometry",
+                              None) is not None
+        if self.latent:
+            self._refuse_what_latent_rows_cannot_follow(cfg, mesh)
+        # such families' prefill programs take each row's true length
+        # and slot, and return the last true position's logits alone
+        self._prefill_by_length = self.state_spec is not None or self.latent
         from deepspeed_tpu.runtime.config import get_observability_config
         self.obs_config = get_observability_config(
             {"observability": dict(observability_config or {})})
@@ -646,20 +658,29 @@ class InferenceEngine:
                 f"{type(model_config).__name__} reports its routed "
                 f"experts' counters through the paged decode program: "
                 f"paged_kv.enabled must be true")
-        if self.state_spec is not None:
+        if self._prefill_by_length:
             self._prefill = self._wrap_program(
                 self._prefill_state_impl, 9, "prefill")
             self._decode = self._wrap_program(
                 self._decode_paged_impl, 7, "decode")
             self._verify = None
-            geom = (f"paged KV cache: {self.paged_spec.num_pages} pages x "
-                    f"{self.paged_spec.page_size} tokens over "
-                    f"{self.paged_spec.num_layers} softmax layers "
-                    f"({cache_bytes / 2**20:.1f} MiB), state pool "
-                    f"{self.state_spec.rows} rows over "
-                    f"{self.state_spec.num_layers} recurrent layers "
-                    f"({state_pool_bytes(self.state_spec) / 2**20:.1f} "
-                    f"MiB), decode attn {self._decode_attn_path}")
+            if self.latent:
+                geom = (f"latent page pool: {self.paged_spec.num_pages} "
+                        f"pages x {self.paged_spec.page_size} tokens over "
+                        f"{self.paged_spec.num_layers} layers, a row of "
+                        f"{self.paged_spec.row_lanes} lanes "
+                        f"({cache_bytes / 2**20:.1f} MiB), decode attn "
+                        f"{self._decode_attn_path} "
+                        f"({self._decode_attn_reason})")
+            else:
+                geom = (f"paged KV cache: {self.paged_spec.num_pages} "
+                        f"pages x {self.paged_spec.page_size} tokens over "
+                        f"{self.paged_spec.num_layers} softmax layers "
+                        f"({cache_bytes / 2**20:.1f} MiB), state pool "
+                        f"{self.state_spec.rows} rows over "
+                        f"{self.state_spec.num_layers} recurrent layers "
+                        f"({state_pool_bytes(self.state_spec) / 2**20:.1f}"
+                        f" MiB), decode attn {self._decode_attn_path}")
         elif self.paged:
             self._prefill = self._wrap_program(
                 self._prefill_paged_impl, 8, "prefill")
@@ -724,40 +745,78 @@ class InferenceEngine:
             f"max_len {max_len}, prompt buckets {cfg['prompt_buckets']}, "
             f"batch buckets {cfg['batch_buckets']}, {geom}{mesh_note}")
 
+    def _refuse_asked(self, cfg, mesh, keeps, reasons):
+        """Raise, naming each, if ``cfg`` asks for a feature in
+        ``reasons`` (feature -> why this family cannot follow it)."""
+        pk = cfg["paged_kv"]
+        asked = {
+            "dense_cache": not pk["enabled"],
+            "prefix_cache": pk["prefix_cache"],
+            "chunked_prefill": cfg["chunked_prefill"]["enabled"],
+            "spec_decode": cfg["spec_decode"]["enabled"],
+            "disagg": cfg["disagg"]["enabled"],
+            "int8_pool": pk["kv_dtype"] == "int8",
+            "quantized_weights": bool(cfg["quantize_weights"]),
+            "mesh": mesh is not None or bool(cfg["mesh"]["axes"]),
+        }
+        named = [reasons[what] for what, on in asked.items() if on]
+        if named:
+            raise ValueError(
+                f"{type(self.model_config).__name__} keeps {keeps} and "
+                f"cannot be served with: " + "; ".join(named))
+
     def _refuse_what_state_cannot_follow(self, cfg, mesh):
         """A recurrent state is one row a slot and is not addressed by
         position: every feature that takes "state = pages + position"
         for granted would serve this family WRONGLY, so each is refused
         here, once, with what would have to exist (docs/solar_open2.md).
         """
-        pk = cfg["paged_kv"]
-        refused = [
-            (not pk["enabled"], "the dense cache (paged_kv.enabled: "
-             "false): the state pool is a leaf of the paged cache tree"),
-            (pk["prefix_cache"], "the prefix cache (paged_kv.prefix_cache)"
-             ": a shared prefix's pages carry no recurrent state; it "
-             "needs a state snapshot at every shared page boundary"),
-            (cfg["chunked_prefill"]["enabled"], "chunked prefill: a "
-             "chunk would have to start from the state its predecessor "
-             "left, and prefill starts every row from an empty one"),
-            (cfg["spec_decode"]["enabled"], "speculative decoding: a "
-             "rejected draft is rolled back by position, and the state "
-             "has already absorbed it"),
-            (cfg["disagg"]["enabled"], "disaggregated prefill/decode: "
-             "the handoff moves pages, not a slot's state row"),
-            (pk["kv_dtype"] == "int8", "an int8 page pool: the family's "
-             "softmax layers read their pages without scales"),
-            (bool(cfg["quantize_weights"]), "quantized weights: the "
-             "family holds its weights in bfloat16 as they are"),
-            (mesh is not None or bool(cfg["mesh"]["axes"]), "a serving "
-             "mesh: only the single-device engine serves this family"),
-        ]
-        asked = [what for cond, what in refused if cond]
-        if asked:
-            raise ValueError(
-                f"{type(self.model_config).__name__} keeps a per-slot "
-                f"recurrent state and cannot be served with: "
-                + "; ".join(asked))
+        self._refuse_asked(cfg, mesh, "a per-slot recurrent state", {
+            "dense_cache": "the dense cache (paged_kv.enabled: false): "
+            "the state pool is a leaf of the paged cache tree",
+            "prefix_cache": "the prefix cache (paged_kv.prefix_cache): a "
+            "shared prefix's pages carry no recurrent state; it needs a "
+            "state snapshot at every shared page boundary",
+            "chunked_prefill": "chunked prefill: a chunk would have to "
+            "start from the state its predecessor left, and prefill "
+            "starts every row from an empty one",
+            "spec_decode": "speculative decoding: a rejected draft is "
+            "rolled back by position, and the state has already absorbed "
+            "it",
+            "disagg": "disaggregated prefill/decode: the handoff moves "
+            "pages, not a slot's state row",
+            "int8_pool": "an int8 page pool: the family's softmax layers "
+            "read their pages without scales",
+            "quantized_weights": "quantized weights: the family holds "
+            "its weights in bfloat16 as they are",
+            "mesh": "a serving mesh: only the single-device engine "
+            "serves this family"})
+
+    def _refuse_what_latent_rows_cannot_follow(self, cfg, mesh):
+        """A latent pool is one leaf of rows that are neither keys nor
+        values: what takes the ``(keys, values)`` pair, per-head lanes
+        or a prefill that reads the pool back for granted would serve
+        this family WRONGLY or not at all, so each is refused here,
+        once, by name (docs/axk1.md)."""
+        self._refuse_asked(cfg, mesh, "latent rows in its page pool", {
+            "dense_cache": "the dense cache (paged_kv.enabled: false): a "
+            "latent row has no per-head stripe to hold",
+            "prefix_cache": "the prefix cache (paged_kv.prefix_cache): "
+            "the prefill program attends to its own rows alone; a row "
+            "with a prefix needs the stripe reader in blocks",
+            "chunked_prefill": "chunked prefill: a later chunk needs the "
+            "same reader of a prefix",
+            "spec_decode": "speculative decoding: the verify program is "
+            "a query of several rows against the pool, which the latent "
+            "reader does not take",
+            "disagg": "disaggregated prefill/decode: the handoff moves a "
+            "(keys, values) pair of pools",
+            "int8_pool": "an int8 page pool: a latent row's scales have "
+            "no place in its one leaf",
+            "quantized_weights": "quantized weights: the family holds "
+            "its weights in bfloat16 as they are",
+            "mesh": "a serving mesh: only the single-device engine "
+            "serves this family"})
 
     def _resolve_decode_attn(self, pk):
         """Pick the paged decode attention path once, at init (the
@@ -1193,6 +1252,12 @@ class InferenceEngine:
 
     # ------------------------------------------- live KV migration (16)
     def _refuse_migration_with_state(self):
+        if self.latent:
+            raise NotImplementedError(
+                f"{type(self.model_config).__name__} keeps latent rows "
+                f"where a MigrationRecord carries keys and values: a "
+                f"request of this family cannot be exported, imported "
+                f"or migrated")
         if self.state_spec is not None:
             raise NotImplementedError(
                 f"{type(self.model_config).__name__} keeps a per-slot "
@@ -1626,11 +1691,11 @@ class InferenceEngine:
                             zip(batch.prefix_lens, batch.page_tables)):
                         positions[i] = pl
                         tables[i, :len(pages)] = pages
-                if not self.paged or self.state_spec is not None:
+                if not self.paged or self._prefill_by_length:
                     slots = np.full((bb,), self._scratch, np.int32)
                     slots[:len(batch.slot_ids)] = batch.slot_ids
             with self._span("serve/prefill/dispatch"):
-                if self.state_spec is not None:
+                if self._prefill_by_length:
                     first, self._cache = self._prefill(
                         self.params, self._cache, jnp.asarray(ids),
                         jnp.asarray(lengths), jnp.asarray(positions),
@@ -1997,7 +2062,7 @@ class InferenceEngine:
                     walks = ([live_pages(p, ps) for p in poss]
                              + [1] * (self._rows - len(sids))
                              if self._decode_attn_path == "pallas" else [])
-                    per_turn = block_pages(ps)
+                    per_turn = block_pages(ps, latent=self.latent)
                     counters.update(
                         read_pages=sum(walks),
                         read_turns=sum(-(-w // per_turn) for w in walks),
@@ -2011,6 +2076,10 @@ class InferenceEngine:
                         assignments=len(sids) * per_row,
                         landed=self._moe_counts[0],
                         fullest=self._moe_counts[1])
+                    if len(self._moe_counts) > 2:
+                        # a group-limited router: the rows whose kept
+                        # groups include a group held here
+                        counters["group_rows"] = self._moe_counts[2]
             with self._span("serve/decode", **counters):
                 with self._span("serve/decode/build"):
                     toks_a, poss_a, temps_a, keys_a = self._decode_arrays(
@@ -2018,23 +2087,27 @@ class InferenceEngine:
                     if self.paged:
                         tables = sched.block_table_rows(self._rows, width)
                 with self._span("serve/decode/dispatch"):
+                    # the host arrays go to the program as they are (as
+                    # warm-up's do: one entry of the jit's cache): the
+                    # call's own transfer of them is 0.07 ms where five
+                    # ``jnp.asarray`` in Python were 0.33 (a CPU host)
                     if self.paged:
                         nxt, self._cache = self._decode(
-                            self.params_decode, self._cache,
-                            jnp.asarray(toks_a), jnp.asarray(poss_a),
-                            jnp.asarray(tables), jnp.asarray(keys_a),
-                            jnp.asarray(temps_a))
+                            self.params_decode, self._cache, toks_a,
+                            poss_a, tables, keys_a, temps_a)
                     else:
                         nxt, self._cache = self._decode(
-                            self.params_decode, self._cache,
-                            jnp.asarray(toks_a), jnp.asarray(poss_a),
-                            jnp.asarray(keys_a), jnp.asarray(temps_a))
+                            self.params_decode, self._cache, toks_a,
+                            poss_a, keys_a, temps_a)
                 ledger.issued()
                 with self._span("serve/decode/wait"):
                     # host sync: the scheduler needs the token values
                     nxt = np.asarray(nxt)
                     if self._expert_counters is not None:
-                        self._moe_counts = (int(nxt[-2]), int(nxt[-1]))
+                        # (landed, fullest[, rows that kept a held
+                        # group]) ride behind the rows' tokens
+                        self._moe_counts = tuple(
+                            int(c) for c in nxt[self._rows:])
                 # Serve/token_latency_ms (verify's too): the phase's
                 # first host work to the tokens' arrival on the host
                 tok_ms = ledger.ready()
@@ -2208,7 +2281,7 @@ class InferenceEngine:
             lengths = np.ones((bb,), np.int32)
             keys = np.zeros((bb, 2), np.uint32)
             temps = np.zeros((bb,), np.float32)
-            if self.state_spec is not None:
+            if self._prefill_by_length:
                 first, self._cache = self._prefill(
                     self.params, self._cache, jnp.asarray(ids),
                     jnp.asarray(lengths), jnp.zeros((bb,), jnp.int32),
@@ -2261,13 +2334,14 @@ class InferenceEngine:
                         self._cache = cache
         if self.paged:
             for w in self._decode_page_buckets:
+                # host arrays, as every decode dispatch passes them
                 nxt, self._cache = self._decode(
                     self.params_decode, self._cache,
-                    jnp.zeros((self._rows,), jnp.int32),
-                    jnp.zeros((self._rows,), jnp.int32),
-                    jnp.zeros((self._rows, w), jnp.int32),
-                    jnp.zeros((self._rows, 2), jnp.uint32),
-                    jnp.zeros((self._rows,), jnp.float32))
+                    np.zeros((self._rows,), np.int32),
+                    np.zeros((self._rows,), np.int32),
+                    np.zeros((self._rows, w), np.int32),
+                    np.zeros((self._rows, 2), np.uint32),
+                    np.zeros((self._rows,), np.float32))
             if self.spec:
                 # one verify program per width — tables always ride at
                 # full pps, so widths x 1 (not widths x page buckets)
@@ -2295,10 +2369,10 @@ class InferenceEngine:
         else:
             nxt, self._cache = self._decode(
                 self.params_decode, self._cache,
-                jnp.zeros((self._rows,), jnp.int32),
-                jnp.zeros((self._rows,), jnp.int32),
-                jnp.zeros((self._rows, 2), jnp.uint32),
-                jnp.zeros((self._rows,), jnp.float32))
+                np.zeros((self._rows,), np.int32),
+                np.zeros((self._rows,), np.int32),
+                np.zeros((self._rows, 2), np.uint32),
+                np.zeros((self._rows,), np.float32))
         jax.block_until_ready(nxt)
         self._warm_compiles = self.compile_tracker.total_compiles
         if self._log is not None:

@@ -45,6 +45,20 @@ decode rewrites every row in place. :class:`StatePoolSpec` is built
 from the model's ``state_geometry``; a family without one has no such
 leaves.
 
+**The latent pool (in place of the pair)** — a family with multi-head
+latent attention (``models/axk1.py``) caches ONE row a token a layer,
+``[c (latent_width) | k_r (rope_width)]`` after the norm and the
+rotation: every head's keys and values are functions of it, and the
+values' operand IS its first ``latent_width`` lanes, so the cache tree
+is ONE leaf ``(layers, num_pages, page_size, row_lanes)`` and no second
+pool repeats those bytes. ONE rule for the row's lanes, on every
+backend: ``latent_width + rope_width`` rounded up to whole 128-lane
+tiles, the tail zeros (:func:`latent_row_lanes`; 512 + 64 -> 640: the
+Pallas reader's page DMA needs whole tiles, and the scores' contraction
+over zero lanes adds nothing). :class:`LatentPoolSpec` is built from the
+model's ``latent_geometry``; pages, block tables, the allocator and
+admission are the pair's.
+
 Writes happen inside the model forwards via
 :func:`deepspeed_tpu.models.gpt2.write_kv_cache` (dense) /
 :func:`deepspeed_tpu.models.gpt2.write_paged_kv_cache` (paged); this
@@ -63,7 +77,8 @@ __all__ = ["KVCacheSpec", "cache_spec_for", "init_kv_cache",
            "kv_cache_bytes", "PagedKVSpec", "paged_spec_for",
            "init_paged_kv_cache", "paged_kv_bytes", "pages_for",
            "PageAllocator", "StatePoolSpec", "state_pool_spec_for",
-           "init_state_pool", "state_pool_bytes", "PagedStateCache"]
+           "init_state_pool", "state_pool_bytes", "PagedStateCache",
+           "LatentPoolSpec", "latent_row_lanes"]
 
 
 class KVCacheSpec(NamedTuple):
@@ -173,9 +188,43 @@ class PagedKVSpec(NamedTuple):
                 self.kv_heads * self.scale_blocks)
 
 
+def latent_row_lanes(latent_width: int, rope_width: int) -> int:
+    """Lanes of a latent pool's row: the latent and the rotary key side
+    by side, rounded up to whole 128-lane tiles (the tail zeros)."""
+    return -(-(latent_width + rope_width) // 128) * 128
+
+
+class LatentPoolSpec(NamedTuple):
+    """Static geometry of the paged LATENT pool (module docstring): one
+    leaf, one row a token a layer. To what reads a pool's geometry it is
+    a pool of ONE kv head whose width is the row (``kv_heads``,
+    ``head_dim``), never quantized."""
+    num_layers: int
+    num_pages: int       # pool size, INCLUDING the reserved null page 0
+    page_size: int
+    latent_width: int    # c: what the values are read from
+    rope_width: int      # k_r: the one rotary key slice of all heads
+    pages_per_seq: int
+    dtype: Any = jnp.bfloat16
+
+    @property
+    def row_lanes(self) -> int:
+        return latent_row_lanes(self.latent_width, self.rope_width)
+
+    @property
+    def shape(self) -> Tuple[int, int, int, int]:
+        return (self.num_layers, self.num_pages, self.page_size,
+                self.row_lanes)
+
+    kv_heads = property(lambda self: 1)
+    head_dim = property(lambda self: self.row_lanes)
+    quantized = property(lambda self: False)
+    quant_block = property(lambda self: 0)
+
+
 def paged_spec_for(model_config, num_pages: int, page_size: int,
                    max_len: int, dtype=jnp.bfloat16,
-                   kv_quant_block: int = 0) -> PagedKVSpec:
+                   kv_quant_block: int = 0):
     """Paged cache geometry from a model config. ``num_pages == 0``
     auto-sizes the pool to the dense worst case (every slot is not known
     here, so callers pass the resolved count); the engine resolves 0
@@ -193,6 +242,16 @@ def paged_spec_for(model_config, num_pages: int, page_size: int,
             f"(one null + one usable), got page_size={page_size}, "
             f"num_pages={num_pages}")
     quantized = jnp.dtype(dtype) == jnp.dtype(jnp.int8)
+    latent = getattr(model_config, "latent_geometry", None)
+    if latent is not None:
+        if quantized:
+            raise ValueError("the latent pool has no int8 form: a row's "
+                             "scales have no place in its one leaf")
+        return LatentPoolSpec(
+            num_layers=model_config.kv_cache_layers, num_pages=num_pages,
+            page_size=page_size, latent_width=latent[0],
+            rope_width=latent[1],
+            pages_per_seq=pages_for(max_len, page_size), dtype=dtype)
     block = int(kv_quant_block) if quantized else 0
     if quantized and block and head_dim % block != 0:
         raise ValueError(
@@ -212,7 +271,10 @@ def init_paged_kv_cache(spec: PagedKVSpec):
     """Allocate the zeroed paged pool tree: the ``(kc, vc)`` pair, plus
     ``(kscale, vscale)`` fp32 scale pools when the spec is int8-
     quantized (4-tuple). Every engine cache op is leaf-generic over this
-    tuple, so the two geometries share one code path."""
+    tuple, so the two geometries share one code path. A latent pool is
+    the 1-tuple of its one leaf."""
+    if isinstance(spec, LatentPoolSpec):
+        return (jnp.zeros(spec.shape, spec.dtype),)
     pools = (jnp.zeros(spec.shape, spec.dtype),
              jnp.zeros(spec.shape, spec.dtype))
     if spec.quantized:
@@ -225,7 +287,10 @@ def init_paged_kv_cache(spec: PagedKVSpec):
 
 def paged_kv_bytes(spec: PagedKVSpec) -> int:
     """Total bytes of the paged pool tree — int8 payload + fp32 scales
-    when quantized (the KV lever of ``quant_serving_bytes``)."""
+    when quantized (the KV lever of ``quant_serving_bytes``); a latent
+    pool's one leaf."""
+    if isinstance(spec, LatentPoolSpec):
+        return int(np.prod(spec.shape)) * jnp.dtype(spec.dtype).itemsize
     total = _pair_bytes(spec)
     if spec.quantized:
         total += 2 * int(np.prod(spec.scale_shape)) * 4

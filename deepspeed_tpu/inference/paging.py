@@ -55,6 +55,12 @@ class PageAllocator:
         self.prefix_cache_enabled = bool(prefix_cache)
         self._free: List[int] = list(range(1, self.num_pages))
         self._ref: Dict[int, int] = {}
+        # readers beyond each page's first, summed over the pool: kept
+        # as references come and go, because the scheduler asks for
+        # ``shared_duplicate_tokens`` three times a step, and three
+        # walks over 42,000 reserved pages were half of the host's
+        # serial section a decode step (PERF.md section 6, PR 43)
+        self._extra_readers = 0
         self._prefix: Dict[int, int] = {}        # chain hash -> page id
         self._page_hash: Dict[int, int] = {}     # page id -> chain hash
         # page id -> the exact token chunk it holds, and the physical
@@ -114,8 +120,7 @@ class PageAllocator:
         lengths. Only prefix sharing ever raises a refcount above 1, and
         shared prefix pages are always FULL pages, so each extra reader
         of a page duplicates exactly ``page_size`` tokens."""
-        return sum((c - 1) * self.page_size
-                   for c in self._ref.values() if c > 1)
+        return self._extra_readers * self.page_size
 
     # ------------------------------------------------------ alloc / free
     def alloc(self, n: int) -> Optional[List[int]]:
@@ -133,6 +138,7 @@ class PageAllocator:
             if self._ref.get(p, 0) < 1:
                 raise ValueError(f"incref of unowned page {p}")
             self._ref[p] += 1
+            self._extra_readers += 1
 
     def free(self, pages: Sequence[int]):
         """Drop one reference per page; pages whose count hits 0 return
@@ -153,6 +159,7 @@ class PageAllocator:
                 self._free.append(p)
             else:
                 self._ref[p] = c - 1
+                self._extra_readers -= 1
 
     # ----------------------------------------------------- prefix cache
     def _chain_hashes(self, tokens: Sequence[int]):

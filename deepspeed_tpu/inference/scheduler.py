@@ -211,6 +211,9 @@ class Scheduler:
         self.tracer = tracer
         self.queue: List[Request] = []
         self.slots: List[Optional[_Slot]] = [None] * self.num_slots
+        # slot -> (its page list, the same as an int32 array): what
+        # ``block_table_rows`` copies into the dispatch's table
+        self._page_rows: Dict[int, Tuple[List[int], np.ndarray]] = {}
         self._submit_time: Dict[int, float] = {}
         self.finished: List[FinishedRequest] = []
         # graceful submit-time rejections awaiting the engine's next
@@ -858,7 +861,15 @@ class Scheduler:
             slot = self.slots[sid]
             if slot.pending_tok is None:
                 continue
-            pages = slot.pages[:pages_per_seq]
+            # a slot's page list is replaced, never edited: its array is
+            # made once a reservation, not once a step (192 rows: the
+            # build read 0.85 ms a step at 96 pages a row from lists,
+            # 0.51 at 384 from arrays; PERF.md section 6, PR 43)
+            kept = self._page_rows.get(sid)
+            if kept is None or kept[0] is not slot.pages:
+                kept = self._page_rows[sid] = (
+                    slot.pages, np.asarray(slot.pages, np.int32))
+            pages = kept[1][:pages_per_seq]
             out[sid, :len(pages)] = pages
         return out
 

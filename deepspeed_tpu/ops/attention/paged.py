@@ -95,7 +95,8 @@ from deepspeed_tpu.ops.attention.flash import NEG_INF, _use_pallas
 
 __all__ = ["paged_decode_attention", "paged_decode_reference",
            "paged_decode_supported", "decode_read_bytes",
-           "live_pages", "block_pages", "dequantize_pool", "quantize_kv"]
+           "live_pages", "block_pages", "dequantize_pool", "quantize_kv",
+           "latent_decode_attention", "latent_decode_reference"]
 
 
 def live_pages(cache_position, page_size: int):
@@ -248,6 +249,27 @@ def paged_decode_reference(q, kpool, vpool, block_tables, cache_position,
     return ctx.reshape(B, H, hd).astype(q.dtype)
 
 
+def latent_decode_reference(q, pool, block_tables, cache_position,
+                            sm_scale: float, value_lanes: int,
+                            layer: int = 0):
+    """Dense oracle of :func:`latent_decode_attention`: each row's whole
+    logical stripe of latent rows gathered from layer ``layer`` of the
+    pool ``(layers, num_pages, page_size, width)``, every head's query
+    ``q`` (B, H, width) against it, positions past ``cache_position``
+    masked, softmax in fp32, the probabilities against the stripe's
+    first ``value_lanes`` lanes. Returns (B, H, value_lanes)."""
+    B = q.shape[0]
+    rows = pool[layer, block_tables].reshape(B, -1, pool.shape[-1]).astype(
+        jnp.float32)
+    s = jnp.einsum("bhw,blw->bhl", q.astype(jnp.float32), rows,
+                   precision=jax.lax.Precision.HIGHEST) * sm_scale
+    mask = jnp.arange(rows.shape[1])[None, :] <= cache_position[:, None]
+    p = jax.nn.softmax(jnp.where(mask[:, None, :], s, NEG_INF), axis=-1)
+    ctx = jnp.einsum("bhl,blc->bhc", p, rows[..., :value_lanes],
+                     precision=jax.lax.Precision.HIGHEST)
+    return ctx.astype(q.dtype)
+
+
 # --------------------------------------------------------------------- #
 # the kernel
 # --------------------------------------------------------------------- #
@@ -262,10 +284,27 @@ def paged_decode_reference(q, kpool, vpool, block_tables, cache_position,
 _BLOCK_TOKENS = 128
 
 
-def block_pages(page_size: int) -> int:
+# The latent arity's tokens a loop turn, swept where its rows are long
+# (193 rows of about 2,900 live tokens, 5 layers; ms the five calls in
+# the kernel alone, my chip runs, PR 43): pages of 16 tokens, 128: 17.4,
+# 256: 13.6, 512: 11.5, 1024: 11.4; pages of 64, 128: 14.7, 256: 10.2,
+# 512: 8.1, 1024: 7.8. A turn's fixed cost (the page copies' issue and
+# wait, the softmax's bookkeeping) bounds the walk, not bytes; past 512
+# a turn's tile of probabilities (64 x 1,024 float32) gains 3%. The
+# pair pool at long rows gains too (65 rows of 2,400 tokens, 8 kv heads
+# of 128, four layers: 128: 4.82, 256: 4.15, 512: 3.99) and keeps its
+# 128 until a change of its own re-measures the short rows it was
+# chosen at: the arities' constants differ by what was measured, not by
+# what the kernel can do.
+_LATENT_BLOCK_TOKENS = 512
+
+
+def block_pages(page_size: int, latent: bool = False) -> int:
     """Pages of ``page_size`` tokens that one loop turn of the walk
-    streams and waits for: whole pages, at least one."""
-    return max(1, _BLOCK_TOKENS // page_size)
+    streams and waits for: whole pages, at least one. ``latent``: the
+    walk over a latent pool (:func:`latent_decode_attention`)."""
+    tokens = _LATENT_BLOCK_TOKENS if latent else _BLOCK_TOKENS
+    return max(1, tokens // page_size)
 
 
 def _round_up(n: int, to: int) -> int:
@@ -298,8 +337,9 @@ def _probs_dot(p, vt):
     return out[:rows] + out[rows:2 * rows] + out[2 * rows:]
 
 
-def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref, v_ref,
-                   *rest, sm_scale, page_size, head_dim, quantized):
+def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref,
+                   *rest, sm_scale, page_size, head_dim, quantized,
+                   value_lanes=None):
     """One sequence's program: walk the row's live pages from the pool,
     ``block_pages`` whole pool rows' pages a loop turn through
     double-buffered DMA, and online-softmax EVERY head's query against
@@ -324,14 +364,30 @@ def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref, v_ref,
     int8 K/V rows AND its scale rows, and the dequant happens right
     after the block lands in VMEM — the int8 bytes are what crossed
     HBM, the math below (scores, online softmax, accumulation) stays
-    fp32 exactly like the dense-pool path."""
-    if quantized:
+    fp32 exactly like the dense-pool path.
+
+    ``value_lanes`` (an int) is the LATENT arity
+    (:func:`latent_decode_attention`): ONE pool whose row is every
+    head's key, ``[c | k_r | zeros]``, and whose first ``value_lanes``
+    lanes are every head's value. There is one stream: a page is copied
+    ONCE and the landed tile is both operands, ``q . tile^T`` the scores
+    and ``p . tile[:, :value_lanes]`` the context. All the heads share
+    the one key row, so ``q_ref`` ``(1, heads, width)`` is the dot's
+    operand as it comes (no block-diagonal spread, no row dropped) and
+    ``o_ref`` is ``(1, heads, value_lanes)``."""
+    latent = value_lanes is not None
+    if latent:
+        o_ref, kbuf, ksem, base_ref = rest
+        vbuf = kbuf
+        streams = ((k_ref, kbuf, ksem),)
+    elif quantized:
+        v_ref, *rest = rest
         (ks_ref, vs_ref, o_ref, kbuf, vbuf, ksbuf, vsbuf,
          ksem, vsem, kssem, vssem, base_ref) = rest
         streams = ((k_ref, kbuf, ksem), (v_ref, vbuf, vsem),
                    (ks_ref, ksbuf, kssem), (vs_ref, vsbuf, vssem))
     else:
-        o_ref, kbuf, vbuf, ksem, vsem, base_ref = rest
+        v_ref, o_ref, kbuf, vbuf, ksem, vsem, base_ref = rest
         streams = ((k_ref, kbuf, ksem), (v_ref, vbuf, vsem))
     b = pl.program_id(0)
     last_seq = pl.num_programs(0) - 1
@@ -349,17 +405,20 @@ def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref, v_ref,
 
     num_blk = (_pages(b) + block_pages - 1) // block_pages
     groups = q_ref.shape[1]
-    kv_rows = _round_up(width // head_dim, 8)
-    if groups * kv_rows % 16:
-        kv_rows = _round_up(kv_rows, 16)
-    # row kh of a group keeps kv head kh's lanes
-    lane = jax.lax.broadcasted_iota(jnp.int32, (kv_rows, width), 1)
-    first = jax.lax.broadcasted_iota(
-        jnp.int32, (kv_rows, width), 0) * head_dim
-    own = (lane >= first) & (lane < first + head_dim)
-    q = jnp.concatenate(
-        [jnp.where(own, q_ref[0, g:g + 1, :].astype(jnp.float32), 0.0)
-         for g in range(groups)], axis=0).astype(q_ref.dtype)
+    if latent:
+        kv_rows, q = 1, q_ref[0]
+    else:
+        kv_rows = _round_up(width // head_dim, 8)
+        if groups * kv_rows % 16:
+            kv_rows = _round_up(kv_rows, 16)
+        # row kh of a group keeps kv head kh's lanes
+        lane = jax.lax.broadcasted_iota(jnp.int32, (kv_rows, width), 1)
+        first = jax.lax.broadcasted_iota(
+            jnp.int32, (kv_rows, width), 0) * head_dim
+        own = (lane >= first) & (lane < first + head_dim)
+        q = jnp.concatenate(
+            [jnp.where(own, q_ref[0, g:g + 1, :].astype(jnp.float32), 0.0)
+             for g in range(groups)], axis=0).astype(q_ref.dtype)
 
     def _page_id(seq, blk, j):
         # clamped: the last block's unwalked tail may lie past the table
@@ -396,6 +455,7 @@ def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref, v_ref,
             # from zeros
             @pl.when(_live_in(seq, blk) < block_pages)
             def _zero_values():
+                # (the latent tile is its own value side)
                 for buf in (vbuf, vsbuf) if quantized else (vbuf,):
                     buf[slot] = jnp.zeros(buf.shape[1:], buf.dtype)
         _for_live_pages(seq, blk, slot, lambda c: c.start())
@@ -425,7 +485,7 @@ def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref, v_ref,
                    1 - slot)
         _for_live_pages(b, blk, slot, lambda c: c.wait())
         kt = kbuf[slot]                               # (tokens, width)
-        vt = vbuf[slot]
+        vt = kt[:, :value_lanes] if latent else vbuf[slot]
         if quantized:
             nb = ksbuf.shape[-1]
             lanes = width // nb
@@ -463,9 +523,13 @@ def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref, v_ref,
     rows = groups * kv_rows
     m0 = jnp.full((rows, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((rows, 1), jnp.float32)
-    acc0 = jnp.zeros((rows, width), jnp.float32)
+    acc0 = jnp.zeros((rows, value_lanes if latent else width),
+                     jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, num_blk, body, (m0, l0, acc0))
     ctx = acc / jnp.where(l == 0.0, 1.0, l)
+    if latent:
+        o_ref[0] = ctx.astype(o_ref.dtype)
+        return
     # row (g, kh) keeps kv head kh's lanes; summed over kh that is
     # group member g's context for every kv head, in the pool row's
     # own layout
@@ -536,6 +600,73 @@ def _paged_decode_call(q, kpool, vpool, scales, block_tables,
     )(layer, block_tables, cache_position, qg, *pools)
     # (B, G, KH * hd) -> heads in q's order, kh major
     return out.reshape(B, G, KH, hd).transpose(0, 2, 1, 3).reshape(B, H, hd)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "value_lanes", "interpret", "block_tokens"))
+def _latent_decode_call(q, pool, block_tables, cache_position, layer,
+                        sm_scale, value_lanes, interpret, block_tokens):
+    """:func:`_paged_decode_call` for the latent arity: one pool pinned
+    in HBM, one stream, the queries as they come."""
+    B, H, width = q.shape
+    kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
+                               page_size=pool.shape[2], head_dim=width,
+                               quantized=False, value_lanes=value_lanes)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, H, width), lambda b, *_: (b, 0, 0)),
+                  pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=pl.BlockSpec((1, H, value_lanes),
+                               lambda b, *_: (b, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, block_tokens, width), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SMEM((1,), jnp.int32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, value_lanes), q.dtype),
+        interpret=interpret,
+        compiler_params=_compiler_params(interpret),
+    )(layer, block_tables, cache_position, q.astype(pool.dtype), pool)
+
+
+def latent_decode_attention(q, pool, block_tables, cache_position,
+                            sm_scale: float, value_lanes: int,
+                            interpret: Optional[bool] = None,
+                            layer: int = 0):
+    """Decode attention in its ABSORBED form straight from a latent page
+    pool (``inference/kv_cache.LatentPoolSpec``), O(live tokens): the
+    page walk, double buffer and stream across sequences of
+    :func:`paged_decode_attention`, over ONE pool whose page is copied
+    once for keys and values.
+
+    q: ``(B, heads, width)``, one token a row, each head's query already
+    carried into the row's space (``[q_n W_uk^T | q_r | zeros]``); pool:
+    ``(layers, num_pages, page_size, width)``, a token's row ``[c | k_r
+    | zeros]``; block_tables, cache_position, ``layer`` as there.
+    ``sm_scale`` multiplies the scores. Returns ``(B, heads,
+    value_lanes)``: each head's probabilities against the rows' first
+    ``value_lanes`` lanes (the context in latent space; the caller
+    carries it out through ``W_uv``). Callers gate the compiled path on
+    :func:`paged_decode_supported` with ``head_dim`` the row's lanes and
+    one kv head."""
+    assert q.ndim == 3 and pool.ndim == 4 and \
+        q.shape[-1] == pool.shape[-1] and value_lanes <= pool.shape[-1], (
+            q.shape, pool.shape, value_lanes)
+    assert block_tables.shape[0] == q.shape[0] and \
+        cache_position.shape == (q.shape[0],), (
+            block_tables.shape, cache_position.shape)
+    if interpret is None:
+        interpret = not _use_pallas()
+    return _latent_decode_call(q, pool, block_tables.astype(jnp.int32),
+                               cache_position.astype(jnp.int32),
+                               jnp.full((1,), layer, jnp.int32),
+                               float(sm_scale), int(value_lanes),
+                               bool(interpret),
+                               block_pages(pool.shape[2], latent=True)
+                               * pool.shape[2])
 
 
 def paged_decode_attention(q, kpool, vpool, block_tables, cache_position,

@@ -345,6 +345,32 @@ def route_top_k(router_in, w_router, top_k: int):
     return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1), r
 
 
+def route_group_limited(router_in, w_router, top_k: int, n_group: int,
+                        topk_group: int, scale: float = 1.0):
+    """float32 router by SIGMOID scores with the choice limited to the
+    best groups (models/axk1.py): ``p = sigmoid(router_in @ w_router)``
+    (T, E) at full precision; the E experts are ``n_group`` groups of
+    consecutive ``E / n_group``; a group's score is the sum of its two
+    largest p; the ``topk_group`` best groups are kept; the ``top_k``
+    largest p among THEIR experts are chosen, and their weights are
+    ``p_i / sum p_i * scale``. Ties go to the lower index, among groups
+    and among experts (``lax.top_k``). Returns (idx (T, k) int32, w
+    (T, k) f32, p (T, E) f32, the groups kept (T, topk_group) int32)."""
+    p = jax.nn.sigmoid(jnp.dot(router_in.astype(jnp.float32),
+                               w_router.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST))
+    t, e = p.shape
+    grouped = p.reshape(t, n_group, e // n_group)
+    _, kept = jax.lax.top_k(
+        jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1), topk_group)
+    open_ = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
+    # a sigmoid is positive: -1 stands behind every expert of a kept group
+    masked = jnp.where(open_[:, :, None], grouped, -1.0).reshape(t, e)
+    top, idx = jax.lax.top_k(masked, top_k)
+    w = top / jnp.sum(top, axis=-1, keepdims=True) * scale
+    return idx.astype(jnp.int32), w, p, kept.astype(jnp.int32)
+
+
 def chunk_rows(assignments: int, held: int, num_experts: int) -> int:
     """Rows of a turn's buffer: twice this chip's even share of the
     assignments, a multiple of 512 (a grouped product's row tile), at

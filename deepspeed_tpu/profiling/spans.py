@@ -65,6 +65,13 @@ DEVICE_SCOPES = (
     # convolution, step, gated norm; the chunked prefill recurrence; the
     # one-token state update and prefill's write of final state and tail
     "ssd_proj", "ssd_scan", "ssd_state",
+    # latent attention (models/axk1.py): both query projections and the
+    # norm between; W_kva, the latent's norm and the rotary key; the
+    # queries carried into the latent's space and the context out of it
+    # (decode); the latent rows expanded to keys and values (prefill);
+    # the output projection. The reader itself stays `attn_core`, the
+    # pool's write `kv_write`
+    "mla_q", "mla_latent", "mla_absorb", "mla_expand", "mla_out",
 )
 
 # host phase spans (TraceAnnotation), each parent before its children

@@ -292,9 +292,10 @@ def test_the_docs_cell_is_declared_as_the_issue_names_it():
     cell = next(w for w in bench["workloads"] if w["name"] == DOCS)
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         ("granite-4.0-h-small", "serve-docs-saturated", 1)
-    assert bench["workloads"][-1] is cell and len(cell["why"]) <= 200
-    entry = bench["configs"][-1]
-    assert entry["name"] == "granite-4.0-h-small" and entry["reduced"] == [
+    assert len(cell["why"]) <= 200
+    entry = next(c for c in bench["configs"]
+                 if c["name"] == "granite-4.0-h-small")
+    assert entry["reduced"] == [
         "num_hidden_layers", "num_local_experts", "vocab_size"]
     with open(os.path.join(BENCH, "traffic",
                            "serve-docs-saturated.json")) as f:
@@ -366,3 +367,102 @@ def test_the_docs_cell_is_declared_as_the_issue_names_it():
     # every metric new with this cell is this cell's alone
     assert all(m["workloads"] == [DOCS] for m in bench["per_layer"]
                if m["name"].endswith(".docs"))
+
+
+REASON = "ax-k1.serve-reason-saturated"
+
+
+def test_the_reason_cell_is_declared_as_the_issue_names_it():
+    """ISSUE 43's cell: the traffic's laws, the backlog, the ramp, the
+    check; the configuration's published keys under their own names and
+    the three cuts; the per-layer entries; appended, nothing before it
+    moved."""
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cell = bench["workloads"][-1]
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
+        == (REASON, "ax-k1", "serve-reason-saturated", 1)
+    assert len(cell["why"]) <= 200
+    entry = bench["configs"][-1]
+    assert entry["name"] == "ax-k1" and entry["reduced"] == [
+        "num_hidden_layers", "n_routed_experts", "vocab_size"]
+    assert entry["source"] == \
+        "https://huggingface.co/skt/A.X-K1/blob/main/config.json"
+    with open(os.path.join(BENCH, "traffic",
+                           "serve-reason-saturated.json")) as f:
+        traffic = json.load(f)
+    assert (traffic["kind"], traffic["backlog_requests"],
+            traffic["epoch_requests"], traffic["order_seed"],
+            traffic["ramp_s"], traffic["check_requests"],
+            traffic["check_pad_to"]) == \
+        ("serve_backlog", 2048, 64, 43, 30, 4, 6144)
+    assert traffic["prompt"] == {"median": 2048, "sigma": 0.5, "low": 512,
+                                 "high": 4096}
+    assert traffic["output"] == {"median": 1024, "sigma": 0.5, "low": 256,
+                                 "high": 2048}
+    assert 0.2 < traffic["logit_tolerance"] < 0.9   # between its readings
+    with open(os.path.join(REPO, entry["file"])) as f:
+        config = json.load(f)
+    assert config["family"] == "axk1"
+    published = {
+        "hidden_size": 7168, "intermediate_size": 18432,
+        "moe_intermediate_size": 2048, "num_attention_heads": 64,
+        "q_lora_rank": 1536, "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+        "qk_rope_head_dim": 64, "v_head_dim": 128, "n_group": 8,
+        "topk_group": 4, "num_experts_per_tok": 8, "n_shared_experts": 1,
+        "first_k_dense_replace": 1, "routed_scaling_factor": 2.5,
+        "scoring_func": "sigmoid", "norm_topk_prob": True,
+        "rms_norm_eps": 1e-06, "rope_theta": 10000,
+        "max_position_embeddings": 131072, "model_type": "axk1"}
+    assert {k: config[k] for k in published} == published
+    assert config["rope_scaling"] == {
+        "beta_fast": 32, "beta_slow": 1, "factor": 32, "mscale": 1,
+        "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
+        "type": "yarn"}
+    assert (config["num_hidden_layers"], config["n_routed_experts"],
+            config["vocab_size"]) == (5, 12, 20480)
+    assert config["published"] == {"num_hidden_layers": 61,
+                                   "n_routed_experts": 192,
+                                   "vocab_size": 163840}
+    assert set(config["changed"]) == set(config["reduced"])
+    assert (config["router_outputs"], config["experts_held"],
+            config["vocab_held"]) == (192, [0, 12], [0, 20480])
+    assert {"router", "rotary_layout", "weights", "bias",
+            "cache_row"} <= set(config["assumed"])
+    assert "sixteen v5e chips" in config["deployment"]
+    inference = config["serve"]["inference"]
+    assert (inference["max_batch_size"], inference["max_seq_len"]) == \
+        (192, 6144)
+    assert inference["prompt_buckets"] == [2048, 4096]   # five programs
+    assert len(inference["batch_buckets"]) * len(
+        inference["prompt_buckets"]) + 1 <= 9
+    pool = inference["paged_kv"]
+    assert pool["prefix_cache"] is False
+    assert (pool["num_pages"] - 1, pool["page_size"]) == (45056, 16)
+    for name in ("decode_read_live_share.sat",
+                 "decode_stripe_live_share.sat"):     # the last page's tail
+        assert next(m for m in bench["per_layer"] if m["name"] == name)[
+            "workloads"] == ["gpt2-345m.serve-saturated", REASON]
+    assert REASON in next(m for m in bench["end_to_end"]
+                          if m["name"] == "serve_tokens_per_s")["workloads"]
+    reported = {m["name"] for m in bench["per_layer"]
+                if REASON in m.get("workloads", [])}
+    assert {"decode_scope_mla_ms.reason", "prefill_scope_mla_ms.reason",
+            "decode_scope_moe_ms.reason", "prefill_scope_moe_ms.reason",
+            "mla_decode_roofline.reason", "decode_hbm_roofline.reason",
+            "moe_experts_hbm_roofline.reason", "prefill_mfu.reason",
+            "expert_held_share.reason", "expert_load_max_over_mean.reason",
+            "prefill_expert_rows_worked_share.sat"} <= reported
+    # the 22 metrics that list every served cell list this one too
+    every = [m for m in bench["per_layer"] if DOCS in m.get(
+        "workloads", []) and "gpt2-345m.serve-saturated" in m["workloads"]
+        and "solar-open2-250b.serve-rollout-saturated" in m["workloads"]]
+    assert len(every) == 22 and all(m["workloads"][-1] == REASON
+                                    for m in every)
+    for name in reported:
+        assert os.path.exists(os.path.join(BENCH, "metrics",
+                                           name + ".json")), name
+    # every metric new with this cell is this cell's alone
+    new = [m for m in bench["per_layer"] if m["name"].endswith(".reason")]
+    assert len(new) == 10 and all(m["workloads"] == [REASON] for m in new)
+    assert bench["per_layer"][-10:] == new

@@ -713,6 +713,47 @@ class TestPageAllocator:
         al.free(owner)
         assert al.shared_duplicate_tokens == 0
 
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_shared_duplicate_tokens_is_kept_not_walked(self, seed):
+        """The count is kept as references come and go (the scheduler
+        reads it three times a step and a walk over a pool of 45,056
+        pages was a quarter of the host's serial section, PR 43): after
+        any run of alloc / incref / free, a refused incref and a refused
+        free among them, it equals the walk over the refcounts."""
+        rs = np.random.RandomState(seed)
+        al = self._alloc(pages=65)
+        held = []                                # one entry a reference
+
+        def walked():
+            return sum((c - 1) * al.page_size
+                       for c in al._ref.values() if c > 1)
+
+        for _ in range(400):
+            op = rs.randint(4)
+            if op == 0:
+                pages = al.alloc(int(rs.randint(1, 5)))
+                if pages is not None:
+                    held.append(pages)
+            elif op == 1 and held:
+                pages = held[rs.randint(len(held))]
+                al.incref(pages)
+                held.append(list(pages))
+            elif op == 2 and held:
+                al.free(held.pop(rs.randint(len(held))))
+            elif op == 3:
+                free_page = al._free[-1] if al._free else None
+                if free_page is not None:
+                    owned = held[0][:1] if held else []
+                    with pytest.raises(ValueError):
+                        al.incref(owned + [free_page])
+                    al.free(owned)           # undo the half that landed
+                    with pytest.raises(ValueError):
+                        al.free([free_page])
+            assert al.shared_duplicate_tokens == walked()
+        for pages in held:
+            al.free(pages)
+        assert al.shared_duplicate_tokens == 0 and not al._ref
+
 
 class TestPagedServing:
     @pytest.mark.parametrize("family", ["gpt2", "llama"])

@@ -953,6 +953,33 @@ class TestDecodeWidthBuckets:
         clamped = s.block_table_rows(4, 3)
         np.testing.assert_array_equal(clamped, full[:, :3])
 
+    def test_table_rows_follow_a_slots_pages(self):
+        """The table's rows are copied from arrays kept a reservation,
+        not rebuilt from the lists a step: a slot re-homed onto other
+        pages, and a slot freed and taken by the next request, show
+        THEIR pages the step after."""
+        from deepspeed_tpu.inference.kv_cache import PageAllocator
+        from deepspeed_tpu.inference.scheduler import Request, Scheduler
+        s = Scheduler(2, (4, 16), (1, 2), 32,
+                      allocator=PageAllocator(20, 4))
+        s.submit(Request(prompt=[1] * 5, max_new_tokens=1))
+        s.submit(Request(prompt=[2] * 3, max_new_tokens=6))
+        s.admit()
+        s.record_tokens({0: 7, 1: 8})     # first tokens: slot 0 is done
+        for _ in range(2):                # the second reads the kept rows
+            table = s.block_table_rows(3, 4)
+            assert table[0].tolist() == [0] * 4
+            assert table[1].tolist() == (s.slots[1].pages + [0] * 4)[:4]
+        fresh = s.allocator.alloc(len(s.slots[1].pages))
+        s.adopt_pages(1, fresh)
+        assert s.block_table_rows(3, 4)[1].tolist() == (fresh + [0] * 4)[:4]
+        s.submit(Request(prompt=[3] * 9, max_new_tokens=4))
+        s.admit()
+        s.record_tokens({0: 9})
+        assert s.block_table_rows(3, 4)[0].tolist() == \
+            (s.slots[0].pages + [0] * 4)[:4]
+        assert s.slots[0].pages[0] not in (0, fresh[0])
+
 
 class TestDecodeAttnTelemetry:
     def test_path_lands_in_events_and_report(self, tmp_path):

@@ -352,6 +352,79 @@ def test_paged_decode_gate_agrees_with_the_compiler(head_dim, page_size,
 
 
 # --------------------------------------------------------------------- #
+# the latent pool's reader (ISSUE 43)
+# --------------------------------------------------------------------- #
+def _latent_decode_specs(row_lanes, rows, table_pages, pool_pages,
+                         heads=64, layers=5, page_size=16):
+    pool = _spec((layers, pool_pages, page_size, row_lanes))
+    return (_spec((rows, heads, row_lanes)), pool,
+            _spec((rows, table_pages), jnp.int32),
+            _spec((rows,), jnp.int32))
+
+
+def _latent_decode(value_lanes):
+    from deepspeed_tpu.ops.attention.paged import latent_decode_attention
+
+    def decode(q, pool, tables, positions):
+        return latent_decode_attention(q, pool, tables, positions, 0.13,
+                                       value_lanes, interpret=False,
+                                       layer=3)
+    return decode
+
+
+@pytest.mark.parametrize("page_size", [16, 64])
+@pytest.mark.parametrize("block_tokens", [128, 512, 1024])
+def test_latent_decode_compiles_at_the_cells_rows(monkeypatch,
+                                                  block_tokens, page_size):
+    """ax-k1.serve-reason-saturated's reader: 193 rows of 64 heads
+    against ONE pool of 720,896 tokens' rows of 640 lanes over 5 layers
+    (pages of 16 as the cell has them, ISSUE 43's, and of 64),
+    a table of 6,144 positions, the values the row's first 512 lanes (a
+    lane slice of the landed key tile: no second stream), at the shipped
+    block of 512 tokens a turn and the ones measured beside it. The pool
+    stays in HBM: the compiled call holds no copy of a layer of it."""
+    from deepspeed_tpu.ops.attention import paged
+    monkeypatch.setattr(paged, "_LATENT_BLOCK_TOKENS", block_tokens)
+    compiled = _compile(_latent_decode(512), *_latent_decode_specs(
+        640, 193, 6144 // page_size, 720896 // page_size + 1,
+        page_size=page_size))
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 720896 * 640
+
+
+@pytest.mark.parametrize("row_lanes,page_size,kv_heads,head_dim", [
+    (640, 16, 1, 640),      # the latent pool's ONE rule: 576 held at 640
+    (576, 16, 1, 576),      # the row as the equations give it: refused
+    (640, 4, 1, 640),       # a page of half a sublane tile
+    (1024, 16, 16, 64),     # GPT-2 345M's pair of pools
+    (1024, 16, 8, 128),     # Solar-Open2's and Granite's softmax layers
+], ids=["latent_640", "latent_576", "latent_page_4", "gpt2_345m",
+        "hybrids_gqa"])
+def test_every_served_pool_geometry_meets_the_gate_it_met(row_lanes,
+                                                          page_size,
+                                                          kv_heads,
+                                                          head_dim):
+    """``paged_decode_supported`` on the latent row (one kv head whose
+    width is the row) agrees with Mosaic on the latent reader, and the
+    three geometries the benchmark already served still take the
+    compiled path."""
+    ok, why = paged_decode_supported(page_size, head_dim, jnp.bfloat16,
+                                     backend="tpu", kv_heads=kv_heads)
+    if kv_heads > 1:
+        assert ok and why == "compiled pallas kernel"
+        return
+    specs = _latent_decode_specs(row_lanes, 8, 16, 64,
+                                 page_size=page_size)
+    if ok:
+        _compile(_latent_decode(512), *specs)
+    else:
+        with pytest.raises(Exception, match="aligned to tiling"):
+            _compile(_latent_decode(512), *specs)
+        assert why
+
+
+# --------------------------------------------------------------------- #
 # serving's one-token attention over a gathered stripe (ISSUE 25)
 # --------------------------------------------------------------------- #
 # gpt2-345m.serve-saturated's decode program: 161 rows, 16 heads of 64, a
@@ -917,7 +990,7 @@ def test_ssd_chunk_scan_compiles_at_published_widths():
 def test_granite_hybrid_serving_programs_keep_the_pools_in_place(
         monkeypatch, program):
     """The benchmark configuration's decode program (65 rows) and its
-    smallest prefill bucket (1 x 1,024) at the published widths, weights
+    smallest prefill bucket (1 x 2,048) at the published widths, weights
     held in bfloat16, the cache tree donated: the page pools and the
     state pool are aliased through the ten layers and the compiled
     program holds nothing of the state pool's size beside it; the score
@@ -989,6 +1062,85 @@ def test_granite_hybrid_serving_programs_keep_the_pools_in_place(
     # the weights as they are held (4,757M parameters in bfloat16) and
     # the pools
     assert 12.7e9 < memory.argument_size_in_bytes < 12.9e9
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_axk1_serving_programs_keep_the_latent_pool_in_place(monkeypatch,
+                                                             program):
+    """The benchmark configuration's decode program (193 rows) and its
+    smallest prefill bucket (1 x 2,048) at the published widths, weights
+    held in bfloat16, the cache tree donated: the ONE latent pool is
+    aliased through the five layers; decode runs one latent reader a
+    layer and forms no key or value of a cached token (nothing of 64
+    heads x 192 or x 128 over the table's positions); prefill runs the
+    flash kernel at keys of 192 and three grouped products an expert
+    layer."""
+    import json
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(__file__), "..", "..", "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from families import axk1 as family
+    from deepspeed_tpu.inference.kv_cache import (paged_kv_bytes,
+                                                  paged_spec_for)
+    from deepspeed_tpu.models import axk1 as ax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(flash, "_use_pallas", lambda: True)
+    with open(os.path.join(bench, "configs", "ax-k1.json")) as f:
+        config = json.load(f)
+    model = family.serve_model_of(config)
+    inference = config["serve"]["inference"]
+    rows = inference["max_batch_size"] + 1
+    pages = paged_spec_for(model, inference["paged_kv"]["num_pages"],
+                           inference["paged_kv"]["page_size"],
+                           inference["max_seq_len"])
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: ax.init_axk1_params(
+            model, jax.random.PRNGKey(0))))
+    cache = (_spec(pages.shape),)
+    ints = lambda *shape: _spec(shape, jnp.int32)
+
+    def decode(params, cache, toks, positions, tables):
+        logits, cache, counts = ax.axk1_forward(
+            params, model, toks[:, None], kv_cache=cache,
+            cache_position=positions, block_tables=tables,
+            paged_attn_kernel="pallas", active=tables[:, 0] > 0,
+            with_counts=True)
+        return jnp.argmax(logits[:, 0], -1), counts, cache
+
+    def prefill(params, cache, ids, lengths, tables):
+        logits, cache, counts = ax.axk1_forward(
+            params, model, ids, kv_cache=cache,
+            cache_position=jnp.zeros_like(lengths), block_tables=tables,
+            paged_attn_kernel="pallas", lengths=lengths, with_counts=True)
+        return jnp.argmax(logits[:, 0], -1), counts, cache
+
+    if program == "decode":
+        fn, args = decode, (ints(rows), ints(rows),
+                            ints(rows, pages.pages_per_seq))
+        kernels = 5                # the latent reader, a layer
+    else:
+        fn, args = prefill, (ints(1, min(inference["prompt_buckets"])),
+                             ints(1), ints(1, pages.pages_per_seq))
+        kernels = 5 + 3 * 4        # flash a layer, the grouped products
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= paged_kv_bytes(pages)
+    # less than ONE layer of the pool: no copy of one is made
+    assert memory.temp_size_in_bytes < paged_kv_bytes(pages) // pages.num_layers
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+    if program == "decode":
+        # no per-head key or value over the table's 6,144 positions
+        assert not re.search(r"\[%d,(64,)?6144,(64,)?(192|128|256)\]" % rows,
+                             text)
+        assert "paged_decode" not in text and "latent_decode" in text
+    # the weights as they are held (3,491M parameters in bfloat16) and
+    # the pool (4.61 GB)
+    assert 11.55e9 < memory.argument_size_in_bytes < 11.65e9
 
 
 def test_smallthinker_train_step_compiles_at_the_cut_widths(monkeypatch):
