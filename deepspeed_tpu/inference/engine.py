@@ -1668,6 +1668,12 @@ class InferenceEngine:
         # cache position 0 the batch attends to its own keys
         # (models/gpt2.paged_attend), else to the gathered stripe
         own = real if self.paged and not any(batch.prefix_lens) else 0
+        # and what its pool write reads: a bucket of whole pages whose
+        # rows all start on a page boundary lands a page an index
+        # (models/gpt2.write_paged_kv_cache), else a row an index
+        ps = self.paged_spec.page_size if self.paged else 0
+        paged_whole = real if ps and pb % ps == 0 and not any(
+            pl % ps for pl in batch.prefix_lens) else 0
         counters = {}
         if self._expert_counters is not None:
             # routed experts: what the prefill before's turns worked
@@ -1676,7 +1682,8 @@ class InferenceEngine:
                             expert_rows_sorted=static)
         with self._span("serve/prefill", seq=ledger.total,
                         step=self._steps, batch=bb, prompt=pb,
-                        real_tokens=real, own_key_tokens=own, **counters):
+                        real_tokens=real, own_key_tokens=own,
+                        page_write_tokens=paged_whole, **counters):
             with self._span("serve/prefill/build"):
                 keys = np.zeros((bb, 2), np.uint32)
                 temps = np.zeros((bb,), np.float32)

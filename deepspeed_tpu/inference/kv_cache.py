@@ -18,9 +18,11 @@ token of one layer: its ``kv_heads`` heads side by side, heads major
 lane-dense at every model width (1,024 lanes for GPT-2 345M, 1,600 for
 GPT-2 XL) where a ``head_dim`` of 64 alone is half a lane tile. The
 three index dimensions lead, so a token is written in place at
-``[layer, page, offset]`` and a donated pool is never copied around a
-program's layer stack (ISSUE 28); a ``(page_size, head_dim)`` tile of
-head ``h`` is ``pool[layer, page, :, h * head_dim:(h + 1) * head_dim]``.
+``[layer, page, offset]`` — a prompt bucket whose rows start on page
+boundaries a whole page at ``[layer, page]`` (ISSUE 44) — and a donated
+pool is never copied around a program's layer stack (ISSUE 28); a
+``(page_size, head_dim)`` tile of head ``h`` is
+``pool[layer, page, :, h * head_dim:(h + 1) * head_dim]``.
 A request occupies ``ceil(total_tokens / page_size)`` pages mapped
 through a static-shape per-slot *block table*; HBM occupancy is
 therefore bounded by the tokens actually reserved in flight, not

@@ -275,8 +275,7 @@ class _Pages(NamedTuple):
     layer: int
     tables: Any
     positions: Any
-    page: Any
-    offset: Any
+    index: Any          # gpt2.paged_write_index's, shared by the layers
     reader: str
 
 
@@ -326,7 +325,7 @@ def _latent_mixer(ap, config, h, dtype, positions, cache):
         held = jnp.pad(row, ((0, 0), (0, 0),
                              (0, cache.pool.shape[-1] - rkv - dr)))
         pool = write_paged_kv_cache(cache.pool, cache.layer, held[:, None],
-                                    cache.page, cache.offset)
+                                    cache.index)
 
     def expanded(rows, cache_position, own):
         """Attention over latent ``rows`` (B, L, >= r_kv + d_r) expanded
@@ -456,8 +455,8 @@ def axk1_forward(params, config: AXK1Config, input_ids, dtype=jnp.bfloat16,
         (pool,) = kv_cache
         if cache_position is None:
             cache_position = jnp.zeros((B,), jnp.int32)
-        page, offset = paged_write_index(block_tables, cache_position, S,
-                                         pool.shape[2])
+        index = paged_write_index(block_tables, cache_position, S,
+                                  pool.shape[2])
         if S > 1:
             assert lengths is not None, \
                 "a served prefill needs each row's length"
@@ -471,7 +470,7 @@ def axk1_forward(params, config: AXK1Config, input_ids, dtype=jnp.bfloat16,
         h = _norm(x, lp["ln_1"]["w"], config.rms_norm_eps)
         y, new = _latent_mixer(
             lp["attn"], config, h, dtype, positions,
-            _Pages(pool, l, block_tables, cache_position, page, offset,
+            _Pages(pool, l, block_tables, cache_position, index,
                    paged_attn_kernel) if serving else None)
         pool = new
         x = x + y

@@ -412,13 +412,13 @@ def _gpt2_trunk_cached(params, config: GPT2Config, input_ids, kv_cache,
         # the stacked pool is carried through the layers and written in
         # place at [layer, page, offset]: no layer is sliced out of it
         # and nothing is stacked back (ISSUE 28)
-        page, offset = paged_write_index(block_tables, cache_position, S,
-                                         kv_cache[0].shape[2])
+        index = paged_write_index(block_tables, cache_position, S,
+                                  kv_cache[0].shape[2])
         for i in range(config.num_layers):
             box = []
             attn = _paged_cache_attention(
-                kv_cache, i, block_tables, cache_position, page, offset,
-                box, attn_kernel=paged_attn_kernel)
+                kv_cache, i, block_tables, cache_position, index, box,
+                attn_kernel=paged_attn_kernel)
             x = gpt2_block(layer_params(params, config, i), config, x,
                            None, True, dtype, attention_fn=attn)
             kv_cache = box[0]
@@ -564,17 +564,37 @@ def write_kv_cache(cache, new, cache_position):
         )(cache, new.astype(cache.dtype), cache_position)
 
 
+class PagedWriteIndex(NamedTuple):
+    """Where a call's tokens go in a paged pool
+    (:func:`paged_write_index`). ``pages`` and ``aligned`` are None
+    where the call's width is not whole pages."""
+    page: jax.Array         # (B * S,) int32: token (b, j)'s page
+    offset: jax.Array       # (B * S,) int32: its row inside that page
+    pages: Optional[jax.Array]      # (B * S / page_size,) int32
+    aligned: Optional[jax.Array]    # () bool: every row starts a page
+
+
 def paged_write_index(block_table, cache_position, num_tokens: int,
-                      page_size: int):
+                      page_size: int) -> PagedWriteIndex:
     """Where this call's tokens go in a paged pool, computed once a call
-    and shared by every layer's write: ``(page, offset)``, each
-    ``(B * num_tokens,)`` int32, row-major over (row, token). Row b's
+    and shared by every layer's write. ``page`` and ``offset``, each
+    ``(B * num_tokens,)`` int32, row-major over (row, token): row b's
     token j lands in page
     ``block_table[b, (cache_position[b]+j) // page_size]`` at offset
     ``(cache_position[b]+j) % page_size``. Positions past the table's
     logical extent — and unreserved table entries, which the host
     allocator leaves at 0 — land in the reserved null page 0, whose
-    garbage ``causal_cache_mask`` keeps unread."""
+    garbage ``causal_cache_mask`` keeps unread.
+
+    Where ``num_tokens`` is whole pages (a static fact: the prompt
+    buckets, a chunk width) the index also gives ``pages``,
+    ``(B * num_tokens / page_size,)`` int32, the page of each run of
+    ``page_size`` tokens — row b's j-th is
+    ``block_table[b, cache_position[b] // page_size + j]`` under the same
+    null-page rule — and ``aligned``, the one run-time fact that makes
+    those runs WHOLE pages: every row of the call starts on a page
+    boundary (a prompt at 0, a prefix hit that ends a page, a later
+    chunk). :func:`write_paged_kv_cache` then writes a page an index."""
     P = block_table.shape[1]
     with scope("kv_write"):
         pos = cache_position[:, None] + jnp.arange(num_tokens)[None, :]
@@ -584,24 +604,85 @@ def paged_write_index(block_table, cache_position, num_tokens: int,
             jnp.take_along_axis(block_table, jnp.minimum(slot, P - 1),
                                 axis=1),
             0)
-        return page.reshape(-1), (pos % page_size).reshape(-1)
+        pages = aligned = None
+        if num_tokens % page_size == 0:
+            pages = page[:, ::page_size].reshape(-1)
+            aligned = jnp.all(cache_position % page_size == 0)
+        return PagedWriteIndex(page.reshape(-1),
+                               (pos % page_size).reshape(-1), pages, aligned)
 
 
-def write_paged_kv_cache(pool, layer: int, new, page, offset):
+def write_paged_kv_cache(pool, layer: int, new, index: PagedWriteIndex):
     """Write ``new`` (B, heads, S, w) into the stacked paged pool
-    ``(layers, num_pages, page_size, heads * w)`` in place: token (b, j)
-    becomes the pool row ``[layer, page[b*S+j], offset[b*S+j]]``, heads
-    major within the row (``page``/``offset`` from
-    :func:`paged_write_index`). The index dimensions lead and a whole
-    row is written, so under a donated pool the scatter aliases its
-    operand: no layer is sliced out and nothing of the pool's size is
-    copied (ISSUE 28). ``w`` is head_dim for the payload pools,
-    scale_blocks for an int8 pool's scale leaves."""
-    B, H, S, w = new.shape
+    ``(layers, num_pages, page_size, heads * w)`` in place, heads major
+    within a token's row (``index`` from :func:`paged_write_index`).
+    ``w`` is head_dim for the payload pools, scale_blocks for an int8
+    pool's scale leaves, the whole row for a one-head latent pool.
+    ``pool`` and ``new`` may be matching tuples of leaves (a layer's keys
+    and values; an int8 pool's payloads and scales): they share the
+    index, and one conditional covers them.
+
+    ONE write whose index granularity follows the input. A call of
+    whole pages (``index.pages``: ``S % page_size == 0``) whose rows all
+    start on a page boundary (``index.aligned``, read at RUN time inside
+    the one program a bucket has) writes ``S / page_size`` whole pages a
+    row, ``pool.at[layer, pages]``, one scatter index a page: on the v5e
+    a scatter costs about 140 ns an index whatever the index moves, so a
+    page of 16 rows lands in the time of one (PR 44). Any other call —
+    decode's one row, a verify's ``k + 1``, a ragged width, a batch with
+    one row that starts mid-page — writes token (b, j) to
+    ``[layer, page[b*S+j], offset[b*S+j]]``, one index a row; where the
+    width is not whole pages that is the only form traced. Both forms
+    put the same bytes in the same place: a page's tail past a row's
+    true length holds the pad tokens' keys either way (the causal mask
+    never reads them and decode overwrites them in order), pad rows and
+    slots past a reservation land in the null page.
+
+    The index dimensions lead and whole rows are written, so under a
+    donated pool either scatter aliases its operand, through the
+    conditional too: no layer is sliced out and nothing of the pool's
+    size is copied (ISSUE 28; ``tests/unit/test_tpu_compile.py``)."""
     with scope("kv_write"):
-        vals = new.astype(pool.dtype).transpose(0, 2, 1, 3).reshape(
-            B * S, H * w)
-        return pool.at[layer, page, offset].set(vals)
+        rows = jax.tree_util.tree_map(_token_rows, pool, new)
+    if index.pages is None:
+        return _write_token_rows(pool, layer, rows, index)
+    return _write_pages_or_rows(pool, layer, rows, index)
+
+
+def _token_rows(leaf, new):
+    """``new`` (B, heads, S, w) as the pool leaf's rows (B * S, heads * w)."""
+    B, H, S, w = new.shape
+    return new.astype(leaf.dtype).transpose(0, 2, 1, 3).reshape(B * S, H * w)
+
+
+def _write_token_rows(pool, layer, rows, index):
+    """:func:`write_paged_kv_cache`, one scatter index a token row."""
+    with scope("kv_write"):
+        return jax.tree_util.tree_map(
+            lambda leaf, x: leaf.at[layer, index.page, index.offset].set(x),
+            pool, rows)
+
+
+@jax.jit
+def _write_pages_or_rows(pool, layer, rows, index):
+    """:func:`write_paged_kv_cache` at a width of whole pages: whole
+    pages or token rows, as ``index.aligned`` says when it runs. Behind
+    a ``jit`` of its own with the LAYER a traced scalar, so that a
+    program's layers share ONE trace and ONE lowering of the conditional
+    and its four scatters (as :func:`_own_keys`): traced a layer, it
+    cost each of the cell's twelve prefill programs 0.4-0.9 s before
+    the compile cache is even asked, 5-10 s of `setup_s`. ``rows`` come
+    made (:func:`_token_rows`): re-laid in here they were written out
+    once more before the scatter, 0.4 ms a prefill (my chip runs, PR
+    44)."""
+    def whole_pages(pool):
+        return jax.tree_util.tree_map(
+            lambda leaf, x: leaf.at[layer, index.pages].set(
+                x.reshape(-1, *leaf.shape[2:])), pool, rows)
+    with scope("kv_write"):
+        return jax.lax.cond(
+            index.aligned, whole_pages,
+            lambda pool: _write_token_rows(pool, layer, rows, index), pool)
 
 
 def gather_paged_kv(pool, layer: int, block_table, kv_heads: int):
@@ -635,13 +716,14 @@ def gather_paged_kv(pool, layer: int, block_table, kv_heads: int):
             B, P * ps, kv_heads, width // kv_heads).transpose(0, 2, 1, 3)
 
 
-def write_paged_layer(pools, layer: int, k, v, page, offset):
+def write_paged_layer(pools, layer: int, k, v, index: PagedWriteIndex):
     """One layer's new K/V (each (B, kv_heads, S, hd)) into the stacked
     pool tree, in place; returns the updated tree. The pair
     ``(kpool, vpool)`` stores them as they come; the int8 4-tuple
     ``(kpool, vpool, kscale, vscale)`` quantizes per token row
     (``ops.attention.paged.quantize_kv``), payload and scales landing
-    through the same ``[layer, page, offset]`` write."""
+    through the same write (:func:`write_paged_kv_cache`: whole pages
+    or token rows, as ``index`` says)."""
     if len(pools) == 4:
         from deepspeed_tpu.ops.attention.paged import quantize_kv
         nb = pools[2].shape[-1] // k.shape[1]
@@ -651,8 +733,7 @@ def write_paged_layer(pools, layer: int, k, v, page, offset):
         new = (k, v, k_s, v_s)
     else:
         new = (k, v)
-    return tuple(write_paged_kv_cache(pool, layer, x, page, offset)
-                 for pool, x in zip(pools, new))
+    return write_paged_kv_cache(tuple(pools), layer, new, index)
 
 
 def gather_paged_layer(pools, layer: int, block_table, kv_heads: int):
@@ -771,13 +852,15 @@ def _own_keys(q, k, v, cache_position, stripe_attention, dense, interpret,
 
 
 def paged_attend(q, k, v, pools, layer: int, block_table, cache_position,
-                 page, offset, out_box, attn_kernel: str, stripe_attention,
+                 index, out_box, attn_kernel: str, stripe_attention,
                  sm_scale=None):
     """Layer ``layer`` of the paged cached forward, for every family
     (prefill-into-pages and paged decode alike): write this call's K/V
-    into the stacked pool tree at ``[layer, page, offset]``
-    (:func:`write_paged_layer`), then attend, through one of three
-    readers chosen by what the call shows:
+    into the stacked pool tree (:func:`write_paged_layer`, where
+    ``index`` from :func:`paged_write_index` says: whole pages for a
+    call of whole pages whose rows all start on a page boundary, token
+    rows for any other), then attend, through one of three readers
+    chosen by what the call shows:
 
     - one query row (decode, and any seq-1 prefill bucket) with
       ``attn_kernel="pallas"``: the fused paged-attention kernel
@@ -804,7 +887,7 @@ def paged_attend(q, k, v, pools, layer: int, block_table, cache_position,
     scale itself. The updated tree — the pair, or the int8 4-tuple —
     returns through ``out_box``."""
     from deepspeed_tpu.parallel.pallas_shard import current_cp_mesh
-    written = write_paged_layer(pools, layer, k, v, page, offset)
+    written = write_paged_layer(pools, layer, k, v, index)
     out_box.append(written)
     rows = q.shape[2]
     if attn_kernel == "pallas" and rows == 1:
@@ -837,16 +920,15 @@ def paged_attend(q, k, v, pools, layer: int, block_table, cache_position,
 
 
 def _paged_cache_attention(pools, layer: int, block_table, cache_position,
-                           page, offset, out_box,
-                           attn_kernel: str = "gather"):
+                           index, out_box, attn_kernel: str = "gather"):
     """attention_fn for layer ``layer`` of the paged cached forward:
     :func:`paged_attend` with GPT-2's stripe math under the shared
     ``causal_cache_mask``."""
     def attn(q, k, v, rate, rng):
         del rate, rng                  # cached forward is deterministic
         return paged_attend(q, k, v, pools, layer, block_table,
-                            cache_position, page, offset, out_box,
-                            attn_kernel, _stripe_attention)
+                            cache_position, index, out_box, attn_kernel,
+                            _stripe_attention)
     return attn
 
 

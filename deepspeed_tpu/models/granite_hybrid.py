@@ -288,13 +288,13 @@ def _softmax_mixer(ap, config, h, dtype, cache):
     if cache is not None and S == 1:
         box = []
         ctx = paged_attend(q, k, v, cache.pools, cache.layer, cache.tables,
-                           cache.positions, cache.page, cache.offset, box,
-                           cache.reader, stripe, sm_scale=scale)
+                           cache.positions, cache.index, box, cache.reader,
+                           stripe, sm_scale=scale)
         pools = box[0]
     else:
         if cache is not None:
             pools = write_paged_layer(cache.pools, cache.layer, k, v,
-                                      cache.page, cache.offset)
+                                      cache.index)
         # every row starts at position 0: its own keys and values are
         # all it may see
         with scope("attn_core"):
@@ -427,8 +427,8 @@ def granite_hybrid_forward(params, config: GraniteHybridConfig, input_ids,
         state, tails = kv_cache.state, kv_cache.tails
         if cache_position is None:
             cache_position = jnp.zeros((B,), jnp.int32)
-        page, offset = paged_write_index(block_tables, cache_position, S,
-                                         pools[0].shape[2])
+        index = paged_write_index(block_tables, cache_position, S,
+                                  pools[0].shape[2])
         if S > 1:
             assert lengths is not None and slots is not None, \
                 "a served prefill needs each row's length and slot"
@@ -444,8 +444,8 @@ def granite_hybrid_forward(params, config: GraniteHybridConfig, input_ids,
         if kind == "attention":
             y, new = _softmax_mixer(
                 lp["attn"], config, h, dtype,
-                _Pages(pools, n_soft, block_tables, cache_position, page,
-                       offset, paged_attn_kernel) if serving else None)
+                _Pages(pools, n_soft, block_tables, cache_position, index,
+                       paged_attn_kernel) if serving else None)
             pools = new if serving else None
             n_soft += 1
         else:

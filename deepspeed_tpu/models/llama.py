@@ -251,7 +251,7 @@ def _gqa_offset_cache_attention(kcache, vcache, cache_position, out_box):
 
 
 def _gqa_paged_cache_attention(pools, layer: int, block_table,
-                               cache_position, page, offset, out_box,
+                               cache_position, index, out_box,
                                attn_kernel: str = "gather"):
     """Paged attention_fn for layer ``layer`` of the cached llama
     forward: ``gpt2.paged_attend`` over the kv_heads-sized stacked pool
@@ -265,8 +265,8 @@ def _gqa_paged_cache_attention(pools, layer: int, block_table,
 
     def attn(q, k, v):
         return paged_attend(q, k, v, pools, layer, block_table,
-                            cache_position, page, offset, out_box,
-                            attn_kernel, _gqa_stripe_attention)
+                            cache_position, index, out_box, attn_kernel,
+                            _gqa_stripe_attention)
     return attn
 
 
@@ -290,8 +290,8 @@ def _llama_trunk_cached(params, config: LlamaConfig, input_ids, kv_cache,
     if paged:
         page_size = kv_cache[0].shape[2]
         max_len = block_tables.shape[1] * page_size
-        page, offset = paged_write_index(block_tables, cache_position, S,
-                                         page_size)
+        index = paged_write_index(block_tables, cache_position, S,
+                                  page_size)
     else:
         max_len = kv_cache[0].shape[3]
     pos = cache_position[:, None] + jnp.arange(S)[None, :]
@@ -303,8 +303,8 @@ def _llama_trunk_cached(params, config: LlamaConfig, input_ids, kv_cache,
         for i in range(config.num_layers):
             box = []
             attn = _gqa_paged_cache_attention(
-                kv_cache, i, block_tables, cache_position, page, offset,
-                box, attn_kernel=paged_attn_kernel)
+                kv_cache, i, block_tables, cache_position, index, box,
+                attn_kernel=paged_attn_kernel)
             x = llama_block(layer_params(params, config, i), config, x,
                             cos_b, sin_b, dtype, attention_fn=attn)
             kv_cache = box[0]

@@ -229,8 +229,7 @@ class _Pages(NamedTuple):
     layer: int          # among the softmax layers
     tables: Any
     positions: Any
-    page: Any
-    offset: Any
+    index: Any          # gpt2.paged_write_index's, shared by the layers
     reader: str
 
 
@@ -250,13 +249,13 @@ def _softmax_mixer(ap, config, h, dtype, cache):
     if cache is not None and S == 1:
         box = []
         ctx = paged_attend(q, k, v, cache.pools, cache.layer, cache.tables,
-                           cache.positions, cache.page, cache.offset, box,
-                           cache.reader, _gqa_stripe_attention)
+                           cache.positions, cache.index, box, cache.reader,
+                           _gqa_stripe_attention)
         pools = box[0]
     else:
         if cache is not None:
             pools = write_paged_layer(cache.pools, cache.layer, k, v,
-                                      cache.page, cache.offset)
+                                      cache.index)
         # every row starts at position 0: its own keys and values are
         # all it may see
         with scope("attn_core"):
@@ -410,8 +409,8 @@ def solar_open2_forward(params, config: SolarOpen2Config, input_ids,
         state, tails = kv_cache.state, kv_cache.tails
         if cache_position is None:
             cache_position = jnp.zeros((B,), jnp.int32)
-        page, offset = paged_write_index(block_tables, cache_position, S,
-                                         pools[0].shape[2])
+        index = paged_write_index(block_tables, cache_position, S,
+                                  pools[0].shape[2])
         if S > 1:
             assert lengths is not None and slots is not None, \
                 "a served prefill needs each row's length and slot"
@@ -425,8 +424,8 @@ def solar_open2_forward(params, config: SolarOpen2Config, input_ids,
         if l in config.gqa_layers:
             y, new = _softmax_mixer(
                 lp["attn"], config, h, dtype,
-                _Pages(pools, n_soft, block_tables, cache_position, page,
-                       offset, paged_attn_kernel) if serving else None)
+                _Pages(pools, n_soft, block_tables, cache_position, index,
+                       paged_attn_kernel) if serving else None)
             pools = new if serving else None
             n_soft += 1
         else:

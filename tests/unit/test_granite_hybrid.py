@@ -309,11 +309,11 @@ def test_the_published_score_scale_reaches_both_attention_readers():
         out = []
         for lo, hi in ((0, 16), (16, 17)):
             at = jnp.asarray([lo], jnp.int32)
-            page, offset = paged_write_index(tables, at, hi - lo, 16)
+            index = paged_write_index(tables, at, hi - lo, 16)
             with jax.default_matmul_precision("highest"):
                 y, pools = gh._softmax_mixer(
                     ap, config, h[:, lo:hi], jnp.float32,
-                    gh._Pages(pools, 0, tables, at, page, offset, "pallas"))
+                    gh._Pages(pools, 0, tables, at, index, "pallas"))
             out.append(y)
         return out
 
@@ -361,7 +361,8 @@ def test_the_score_scale_is_handed_to_the_two_kernels(monkeypatch):
     pools = (jnp.zeros((1, 4, 16, 32)), jnp.zeros((1, 4, 16, 32)))
     gpt2.paged_attend(q[:, :, :1], kv[:, :, :1], kv[:, :, :1], pools, 0,
                       jnp.asarray([[1, 2]], jnp.int32), zero,
-                      jnp.asarray([[1]]), jnp.asarray([[0]]), [], "pallas",
+                      gpt2.PagedWriteIndex(jnp.asarray([1]), jnp.asarray([0]),
+                                           None, None), [], "pallas",
                       stripe, sm_scale=0.0625)
     gpt2._own_keys.clear_cache()
     assert seen == [("flash", 0.0625), ("decode", 0.0625)]

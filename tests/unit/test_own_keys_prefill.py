@@ -71,12 +71,15 @@ def _prefill(family, positions, seq=SEQ, dtype=jnp.float32, **case):
 
 
 def _conds(jaxpr):
-    """``cond`` equations anywhere in a jaxpr."""
+    """``cond`` equations anywhere in a jaxpr that pick a READER: the
+    conditional whose branches scatter is the pool's write picking its
+    index granularity (``tests/unit/test_page_writes.py``)."""
     n = 0
     for eqn in jaxpr.eqns:
-        n += eqn.primitive.name == "cond"
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            n += _conds(sub)
+        subs = list(jax.core.jaxprs_in_params(eqn.params))
+        n += eqn.primitive.name == "cond" and not any(
+            e.primitive.name == "scatter" for sub in subs for e in sub.eqns)
+        n += sum(_conds(sub) for sub in subs)
     return n
 
 

@@ -680,10 +680,10 @@ class TestGatherSeq1Path:
         else:
             pools = (kpool.astype(dtype), vpool.astype(dtype))
         tables, positions = jnp.asarray(tables), jnp.asarray(positions)
-        page, offset = paged_write_index(tables, positions, 1, ps)
+        index = paged_write_index(tables, positions, 1, ps)
         box = []
         got = _paged_cache_attention(
-            pools, LAYER, tables, positions, page, offset, box,
+            pools, LAYER, tables, positions, index, box,
             attn_kernel="gather")(q, k, v, 0.0, None)
         assert got.shape == (batch, heads, 1, hd) and got.dtype == dtype
         kp, vp, *written_scales = box[0]
@@ -790,10 +790,9 @@ class TestPoolRoundTrip:
                                                write_paged_kv_cache)
         kpool, _, tables, positions, k, _ = self._case(28, positions,
                                                        tokens)
-        page, offset = paged_write_index(jnp.asarray(tables),
-                                         jnp.asarray(positions), tokens,
-                                         self.PS)
-        got = write_paged_kv_cache(kpool, LAYER, k, page, offset)
+        index = paged_write_index(jnp.asarray(tables),
+                                  jnp.asarray(positions), tokens, self.PS)
+        got = write_paged_kv_cache(kpool, LAYER, k, index)
         want = _np_write(kpool, LAYER, k, tables, positions)
         assert got.shape == kpool.shape and got.dtype == kpool.dtype
         np.testing.assert_array_equal(np.asarray(got), want)
@@ -812,14 +811,12 @@ class TestPoolRoundTrip:
         kpool, _, tables, positions, k, _ = self._case(
             29, [extent - 2, extent, 1], 3)
         tables[2] = 0
-        page, offset = paged_write_index(jnp.asarray(tables),
-                                         jnp.asarray(positions), 3,
-                                         self.PS)
+        index = paged_write_index(jnp.asarray(tables),
+                                  jnp.asarray(positions), 3, self.PS)
         np.testing.assert_array_equal(
-            np.asarray(page).reshape(3, 3),
+            np.asarray(index.page).reshape(3, 3),
             [[tables[0, -1], tables[0, -1], 0], [0, 0, 0], [0, 0, 0]])
-        got = np.asarray(write_paged_kv_cache(kpool, LAYER, k, page,
-                                              offset))
+        got = np.asarray(write_paged_kv_cache(kpool, LAYER, k, index))
         want = _np_write(kpool, LAYER, k, tables, positions)
         # several rows land on one null-page row: which of them stays
         # is not defined, and nothing reads it unmasked
@@ -842,10 +839,9 @@ class TestPoolRoundTrip:
                                 kv_heads=self.HEADS)
         assert pools[2].shape == kpool.shape[:3] + (
             self.HEADS * scale_blocks,)
-        page, offset = paged_write_index(jnp.asarray(tables),
-                                         jnp.asarray(positions), 5,
-                                         self.PS)
-        got = write_paged_layer(pools, LAYER, k, v, page, offset)
+        index = paged_write_index(jnp.asarray(tables),
+                                  jnp.asarray(positions), 5, self.PS)
+        got = write_paged_layer(pools, LAYER, k, v, index)
         assert [g.dtype for g in got] == [jnp.int8, jnp.int8,
                                           jnp.float32, jnp.float32]
         new = quantize_kv(k, scale_blocks) + quantize_kv(v, scale_blocks)

@@ -446,9 +446,9 @@ def _cached_reader(cache, q_rows, cache_dtype):
 
         def fn(q, k, v, kpool, vpool, tables, pos):
             box = []
-            page, offset = gpt2.paged_write_index(tables, pos, q_rows, PAGE)
+            index = gpt2.paged_write_index(tables, pos, q_rows, PAGE)
             out = gpt2._paged_cache_attention(
-                (kpool, vpool), 0, tables, pos, page, offset, box)(
+                (kpool, vpool), 0, tables, pos, index, box)(
                     q, k, v, 0.0, None)
             return out, box[0]
         return fn, (qkv, qkv, qkv, pool, pool,
@@ -469,13 +469,24 @@ def _entry(compiled):
                      re.S | re.M).group(0)
 
 
+def _computation(text, name):
+    """The computation ``name`` of an optimized HLO module, as text."""
+    return re.search(rf"^%?{re.escape(name)} .*?^}}", text,
+                     re.S | re.M).group(0)
+
+
 def _entry_results(compiled):
     """``(opcode, dtype, elements, called computation)`` of every array
     an instruction of the optimized HLO's ENTRY computation produces
     (tuple results included): what the program really writes, not what
     a fusion holds in registers."""
+    return _results(_entry(compiled))
+
+
+def _results(computation):
+    """:func:`_entry_results` of any computation's text."""
     results = []
-    for line in _entry(compiled).splitlines():
+    for line in computation.splitlines():
         inst = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
         if inst:
             calls = re.search(r"calls=%?([\w.\-]+)", line)
@@ -569,6 +580,45 @@ def _paged_trunk(family, heads, kv_heads, head_dim, attn_kernel="gather"):
     return jax.jit(fn, donate_argnums=(1,)), params
 
 
+def _scatter_root(text, fusion):
+    """The scatter (or update slice) of its first operand that roots the
+    fusion computation ``fusion``, if it is one: what aliases a donated
+    pool."""
+    return re.search(
+        rf"^%?{re.escape(fusion or '?')} .*?^\s*ROOT [^\n]*? "
+        r"(scatter|dynamic-update-slice)\(%?param_0", text, re.S | re.M)
+
+
+def _pool_write_branches(text, computation, pool_shape):
+    """``[(token rows, whole pages)]``, the two branch computations of
+    every conditional of ``computation`` that hands the pool on (ISSUE
+    44: the write picks its index granularity from the positions; index
+    0 is the predicate's False). Checked here: in either branch the
+    ONLY results of the pool's size are scatters that alias it, one a
+    leaf."""
+    dims = ",".join(map(str, pool_shape))
+    pairs = []
+    for line in computation.splitlines():
+        cond = re.match(
+            rf"\s*%?[\w.\-]+ = \((bf16\[{dims}\]\S*(?:, )?)+\) "
+            r"conditional\(.*?branch_computations=\{%?([\w.\-]+), "
+            r"%?([\w.\-]+)\}", line)
+        if not cond:
+            continue
+        leaves = line.split(" conditional(")[0].count(f"bf16[{dims}]")
+        pair = tuple(_computation(text, name) for name in cond.groups()[1:])
+        for branch in pair:
+            written = [(opcode, calls)
+                       for opcode, _, elems, calls in _results(branch)
+                       if opcode not in _NO_WRITE
+                       and elems == int(np.prod(pool_shape))]
+            assert len(written) == leaves, written
+            assert all(opcode == "fusion" and _scatter_root(text, calls)
+                       for opcode, calls in written), written
+        pairs.append(pair)
+    return pairs
+
+
 @pytest.mark.parametrize("rows,tokens", [(ROWS, 1), (8, 128)],
                          ids=["decode", "prefill8x128"])
 @pytest.mark.parametrize("family,heads,kv_heads,head_dim", [
@@ -582,7 +632,9 @@ def test_paged_trunk_writes_the_pool_in_place(family, heads, kv_heads,
     keeps its default layout as an entry parameter, so a serving program
     that carries it through its layers (a) hands the donated pool back
     in the buffers it came in, (b) writes nothing of a layer slice's
-    size or more but the 2 x layers scatters that alias it and, in
+    size or more but the 2 x layers scatters that alias it (in a prompt
+    bucket of whole pages inside ONE conditional a layer, whose either
+    branch holds the layer's two: ISSUE 44) and, in
     decode, the gathered stripes, and (c) needs temporaries of no more
     than one layer's K and V stripes (their heads on whole lane tiles)
     plus one layer slice. With a ``head_dim`` of 64 as the last
@@ -613,19 +665,21 @@ def test_paged_trunk_writes_the_pool_in_place(family, heads, kv_heads,
 
     # (b) what is written at a layer slice's size or more
     scatters = 0
+    whole_pages = tokens % PAGE == 0
     for opcode, dtype, elems, calls in _entry_results(compiled):
         if elems < layer_elems or opcode in _NO_WRITE:
             continue
         if elems == pool_elems:
-            root = re.search(
-                rf"^%?{re.escape(calls or '?')} .*?^\s*ROOT [^\n]*? "
-                r"(scatter|dynamic-update-slice)\(%?param_0", text,
-                re.S | re.M)
-            assert opcode == "fusion" and root, (opcode, dtype, calls)
+            assert (opcode == "conditional" if whole_pages else
+                    opcode == "fusion" and _scatter_root(text, calls)), (
+                        opcode, dtype, calls)
             scatters += 1
         else:
             assert elems == stripe_elems, (opcode, dtype, elems)
     assert scatters == 2 * TRUNK_LAYERS
+    assert len(_pool_write_branches(text, _entry(compiled), pool_shape)) \
+        == TRUNK_LAYERS * whole_pages
+    assert not re.search(rf"bf16\[{dims}\]\S* copy\(", text)
 
     # (c) temporaries: K and V stripes of one layer, a head on whole
     # lane tiles, and one layer slice
@@ -682,10 +736,29 @@ def test_pallas_decode_trunk_holds_no_gathered_stripe(monkeypatch, family,
         < stripe_elems * 2 // 4
 
 
+_PREFILL_PROGRAMS = {}
+
+
+def _gpt2_prefill_program(rows, tokens):
+    """GPT-2 345M's ``rows x tokens`` prompt bucket over the cell's pool
+    (four layers, the pool donated, the Pallas kernels on), compiled for
+    the described chip once a module: ``(compiled, pool shape)``."""
+    pool_shape = (TRUNK_LAYERS, POOL_PAGES, PAGE, HEADS * HEAD_DIM)
+    if (rows, tokens) not in _PREFILL_PROGRAMS:
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(flash, "_use_pallas", lambda: True)
+            fn, params = _paged_trunk("gpt2", HEADS, HEADS, HEAD_DIM, "pallas")
+            pool = _spec(pool_shape)
+            _PREFILL_PROGRAMS[rows, tokens] = fn.lower(
+                params, (pool, pool), _spec((rows, tokens), jnp.int32),
+                _spec((rows,), jnp.int32),
+                _spec((rows, TABLE_PAGES), jnp.int32)).compile()
+    return _PREFILL_PROGRAMS[rows, tokens], pool_shape
+
+
 @pytest.mark.parametrize("rows,tokens,kernels", [(8, 64, 0), (8, 512, 1)],
                          ids=["prefill8x64", "prefill8x512"])
-def test_gpt2_prefill_attends_to_its_own_keys(monkeypatch, rows, tokens,
-                                              kernels):
+def test_gpt2_prefill_attends_to_its_own_keys(rows, tokens, kernels):
     """GPT-2 345M's smallest and largest batch-8 prompt buckets over the
     cell's pool (ISSUE 40): after each layer's in-place write ONE
     conditional picks the reader from the positions. Its own-keys branch
@@ -696,28 +769,21 @@ def test_gpt2_prefill_attends_to_its_own_keys(monkeypatch, rows, tokens,
     stripe branch only READS the written pool, so the conditional copies
     none: the donated pool still comes back in its own buffers and the
     only results of its size are the 2 x layers scatters that alias
-    it."""
-    monkeypatch.setattr(flash, "_use_pallas", lambda: True)
-    pool_shape = (TRUNK_LAYERS, POOL_PAGES, PAGE, HEADS * HEAD_DIM)
+    it (since ISSUE 44 inside the write's own conditional, a layer:
+    ``test_gpt2_prefill_writes_whole_pages_in_place``)."""
+    compiled, pool_shape = _gpt2_prefill_program(rows, tokens)
     pool_elems = int(np.prod(pool_shape))
-    fn, params = _paged_trunk("gpt2", HEADS, HEADS, HEAD_DIM, "pallas")
-    pool = _spec(pool_shape)
-    compiled = fn.lower(params, (pool, pool),
-                        _spec((rows, tokens), jnp.int32),
-                        _spec((rows,), jnp.int32),
-                        _spec((rows, TABLE_PAGES), jnp.int32)).compile()
     text = compiled.as_text()
-
-    def computation(name):
-        return re.search(rf"^%?{re.escape(name)} .*?^}}", text,
-                         re.S | re.M).group(0)
+    dims = ",".join(map(str, pool_shape))
+    # the reader's conditional hands on a context, the write's the pool
     branches = re.findall(
-        r" conditional\(.*?branch_computations=\{%?([\w.\-]+), "
-        r"%?([\w.\-]+)\}", _entry(compiled))
+        rf" = \((?!bf16\[{dims}\])[^\n]*? conditional\(.*?"
+        r"branch_computations=\{%?([\w.\-]+), %?([\w.\-]+)\}",
+        _entry(compiled))
     assert len(branches) == TRUNK_LAYERS
     stripe_f32 = f"f32[{rows},{HEADS},{TABLE_PAGES * PAGE},{HEAD_DIM}]"
     for stripe, own in branches:          # index 0 is the predicate's False
-        own, stripe = computation(own), computation(stripe)
+        own, stripe = _computation(text, own), _computation(text, stripe)
         assert own.count('custom_call_target="tpu_custom_call"') == kernels
         assert " gather(" not in own and stripe_f32 not in own
         assert "tpu_custom_call" not in stripe
@@ -725,14 +791,68 @@ def test_gpt2_prefill_attends_to_its_own_keys(monkeypatch, rows, tokens,
     assert text.count('custom_call_target="tpu_custom_call"') \
         == kernels * TRUNK_LAYERS
 
-    dims = ",".join(map(str, pool_shape))
     assert not re.search(rf"bf16\[{dims}\]\S* copy\(", text)
     aliases = dict(re.findall(r"\{(\d+)\}: \((\d+), \{\}, may-alias\)",
                               text.split("\n", 1)[0]))
     assert {"1", "2"} <= set(aliases)
     written = [opcode for opcode, _, elems, _ in _entry_results(compiled)
                if opcode not in _NO_WRITE and elems == pool_elems]
-    assert written == ["fusion"] * (2 * TRUNK_LAYERS), written
+    assert written == ["conditional"] * (2 * TRUNK_LAYERS), written
+    assert len(_pool_write_branches(text, _entry(compiled), pool_shape)) \
+        == TRUNK_LAYERS
+
+
+@pytest.mark.parametrize("rows,tokens", [(8, 64), (8, 512)],
+                         ids=["prefill8x64", "prefill8x512"])
+def test_gpt2_prefill_writes_whole_pages_in_place(rows, tokens):
+    """The same two programs at published widths, pages of 16 (ISSUE
+    44): the 4.0 GB pool now passes THROUGH a conditional a layer, whose
+    branches are the two index granularities of the one write. Nothing
+    of the pool's size is copied anywhere in the program, the donated
+    pool is aliased input to output, either branch writes it by ONE
+    aliasing scatter a leaf (``_pool_write_branches``), the whole-page
+    branch with ``rows x tokens / 16`` indices that each move a page of
+    ``(16, 1024)``, the other with an index a token row."""
+    compiled, pool_shape = _gpt2_prefill_program(rows, tokens)
+    text = compiled.as_text()
+    dims = ",".join(map(str, pool_shape))
+    assert " copy(" not in "".join(        # neither of it nor into it
+        line for line in text.splitlines() if f"bf16[{dims}]" in line)
+    aliases = dict(re.findall(r"\{(\d+)\}: \((\d+), \{\}, may-alias\)",
+                              text.split("\n", 1)[0]))
+    pool_params = set(re.findall(
+        rf"= bf16\[{dims}\]\S* parameter\((\d+)\)", _entry(compiled)))
+    assert len(pool_params) == 2
+    assert {aliases.get("1"), aliases.get("2")} == pool_params
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == 2 * 2 * int(np.prod(pool_shape))
+
+    branches = _pool_write_branches(text, _entry(compiled), pool_shape)
+    assert len(branches) == TRUNK_LAYERS
+
+    def scatters(branch):
+        """(indices, the update) of the branch's scatters, as the fused
+        scatter takes them."""
+        found = []
+        for fusion in re.findall(r" fusion\([^\n]*calls=%?([\w.\-]+)",
+                                 branch):
+            body = _computation(text, fusion)
+            root = re.search(r"ROOT [^\n]*? scatter\(%?param_0[\w.]*, "
+                             r"%?([\w.\-]+), %?([\w.\-]+)\)", body)
+            if root:
+                shape = dict(re.findall(
+                    r"^\s*%?([\w.\-]+) = (\w+\[[\d,]*\])", body, re.M))
+                indices = re.match(r"s32\[(\d+)", shape[root.group(1)])
+                found.append((int(indices.group(1)), shape[root.group(2)]))
+        return found
+
+    pages = rows * tokens // PAGE
+    width = HEADS * HEAD_DIM
+    for token_rows, whole_pages in branches:
+        assert scatters(whole_pages) == [
+            (pages, f"bf16[{pages},{PAGE},{width}]")] * 2
+        assert scatters(token_rows) == [
+            (rows * tokens, f"bf16[{rows * tokens},{width}]")] * 2
 
 
 def test_serving_compiler_options_share_the_layers_code(monkeypatch):

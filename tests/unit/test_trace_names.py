@@ -297,10 +297,17 @@ def test_serving_trace_holds_spans_in_order_with_scheduler_counters(
         sum(len(p) for p in PROMPTS)
     for ev in prefills:
         assert set(ev[3]) == {"seq", "step", "batch", "prompt",
-                              "real_tokens", "own_key_tokens"}
+                              "real_tokens", "own_key_tokens",
+                              "page_write_tokens"}
         # no prompt here rides a shared prefix: every row starts at
         # cache position 0 (prefill_own_keys_share.sat)
         assert ev[3]["own_key_tokens"] == ev[3]["real_tokens"]
+        # and the pool write lands a page an index where the bucket is
+        # whole pages (prefill_page_write_share.sat), a row an index in
+        # a narrower one
+        assert ev[3]["page_write_tokens"] == (
+            0 if ev[3]["prompt"] % ps
+            else ev[3]["real_tokens"])
         assert ev[3]["batch"] in INF["batch_buckets"]
         assert ev[3]["prompt"] in INF["prompt_buckets"]
         assert ev[3]["real_tokens"] <= ev[3]["batch"] * ev[3]["prompt"]
