@@ -303,6 +303,41 @@ def _program_compiler_options():
     return None
 
 
+def _keys_for(seeds: Sequence[int]) -> np.ndarray:
+    """``(len(seeds), 2)`` ``uint32``: row i the two words of
+    ``jax.random.PRNGKey(seeds[i])``, written on the host. Under the
+    default ``threefry2x32`` a key is its seed's two 32-bit halves, and
+    with 64-bit types off the library narrows the seed to 32 bits
+    first, so the high word is 0. No device call: the library's own
+    form is several eager dispatches and a read-back (1.2 ms a request
+    on a TPU's host). A dispatch's rows at once, a column an
+    assignment: a decode step asks for every slot's. Held to the
+    library once, at engine construction (:func:`_check_keys_for`)."""
+    keys = np.zeros((len(seeds), 2), np.uint32)
+    keys[:, 1] = [int(s) & 0xFFFFFFFF for s in seeds]
+    if jax.config.jax_enable_x64:
+        keys[:, 0] = [(int(s) >> 32) & 0xFFFFFFFF for s in seeds]
+    return keys
+
+
+def _check_keys_for():
+    """One key made both ways, from a seed with both words set: a
+    library whose default PRNG is not ``threefry2x32``, or that seeds
+    it another way, stops the engine here and not in a served token."""
+    seed = 0x0123456789ABCDEF
+    want, got = np.asarray(jax.random.PRNGKey(seed)), _keys_for([seed])[0]
+    if want.dtype != got.dtype or want.shape != got.shape \
+            or (want != got).any():
+        raise RuntimeError(
+            f"InferenceEngine writes a request's sampling key on the "
+            f"host as jax.random.PRNGKey would make it under "
+            f"threefry2x32, and this JAX disagrees: PRNGKey({seed:#x}) "
+            f"is {want.dtype}{want.tolist()}, the host's form "
+            f"{got.dtype}{got.tolist()} (jax_default_prng_impl="
+            f"{jax.config.jax_default_prng_impl!r}, jax_enable_x64="
+            f"{jax.config.jax_enable_x64})")
+
+
 class InferenceEngine:
     """Paged (or dense) bucketed prefill/decode serving over a
     continuous-batching scheduler, optionally sharded over a serving
@@ -317,6 +352,9 @@ class InferenceEngine:
         (self.family, self._forward, _,
          self._param_specs_fn) = _family_of(model_config)
         self.dtype = dtype
+        # a request's sampling key is written on the host: held to the
+        # library's here, once, before anything is built
+        _check_keys_for()
         # same persistent compile cache as the trainer: a restarted
         # server reloads its warmup programs instead of recompiling
         enable_compile_cache()
@@ -525,7 +563,6 @@ class InferenceEngine:
         # rides telemetry only when a test measures it
         self.quant_logit_err: Optional[float] = None
         self._state_event_every = 64       # serve_state cadence (steps)
-        self._key_cache: Dict[int, np.ndarray] = {}
 
         # ------------------------------------------------- KV cache
         pk = cfg["paged_kv"]
@@ -1190,20 +1227,6 @@ class InferenceEngine:
         return tuple(c.at[:, idx].set(s) for c, s in zip(cache, slab))
 
     # ----------------------------------------------------------- serving
-    # seeds are caller-supplied, so the memo must be bounded: a serving
-    # daemon taking per-request random seeds would otherwise grow it one
-    # entry per distinct seed, forever
-    _KEY_CACHE_CAP = 4096
-
-    def _key_for(self, seed: int) -> np.ndarray:
-        key = self._key_cache.get(seed)
-        if key is None:
-            if len(self._key_cache) >= self._KEY_CACHE_CAP:
-                self._key_cache.clear()
-            key = np.asarray(jax.random.PRNGKey(seed))
-            self._key_cache[seed] = key
-        return key
-
     def submit(self, request: Request) -> int:
         """Queue one request; returns its uid (serving order is FIFO
         with bounded-lookahead admission)."""
@@ -1685,11 +1708,11 @@ class InferenceEngine:
                         real_tokens=real, own_key_tokens=own,
                         page_write_tokens=paged_whole, **counters):
             with self._span("serve/prefill/build"):
+                n = len(batch.requests)
                 keys = np.zeros((bb, 2), np.uint32)
+                keys[:n] = _keys_for([r.seed for r in batch.requests])
                 temps = np.zeros((bb,), np.float32)
-                for i, req in enumerate(batch.requests):
-                    keys[i] = self._key_for(req.seed)
-                    temps[i] = req.temperature
+                temps[:n] = [r.temperature for r in batch.requests]
                 ids, lengths = pad_prompts(prompts, pb, bb)
                 if self.paged:
                     positions = np.zeros((bb,), np.int32)
@@ -1702,29 +1725,25 @@ class InferenceEngine:
                     slots = np.full((bb,), self._scratch, np.int32)
                     slots[:len(batch.slot_ids)] = batch.slot_ids
             with self._span("serve/prefill/dispatch"):
+                # host arrays in, as every dispatch passes them and as
+                # warm-up did (docs/inference.md "What a dispatch's
+                # host section may touch")
                 if self._prefill_by_length:
                     first, self._cache = self._prefill(
-                        self.params, self._cache, jnp.asarray(ids),
-                        jnp.asarray(lengths), jnp.asarray(positions),
-                        jnp.asarray(tables), jnp.asarray(keys),
-                        jnp.asarray(temps), jnp.asarray(slots))
+                        self.params, self._cache, ids, lengths, positions,
+                        tables, keys, temps, slots)
                 elif not self.paged:
                     first, self._cache = self._prefill(
-                        self.params, self._cache, jnp.asarray(ids),
-                        jnp.asarray(lengths), jnp.asarray(slots),
-                        jnp.asarray(keys), jnp.asarray(temps))
+                        self.params, self._cache, ids, lengths, slots,
+                        keys, temps)
                 elif self._separate_pools:
                     first, self._cache_prefill = self._prefill(
-                        self.params, self._cache_prefill,
-                        jnp.asarray(ids), jnp.asarray(lengths),
-                        jnp.asarray(positions), jnp.asarray(tables),
-                        jnp.asarray(keys), jnp.asarray(temps))
+                        self.params, self._cache_prefill, ids, lengths,
+                        positions, tables, keys, temps)
                 else:
                     first, self._cache = self._prefill(
-                        self.params, self._cache, jnp.asarray(ids),
-                        jnp.asarray(lengths), jnp.asarray(positions),
-                        jnp.asarray(tables), jnp.asarray(keys),
-                        jnp.asarray(temps))
+                        self.params, self._cache, ids, lengths, positions,
+                        tables, keys, temps)
             ledger.issued()
             with self._span("serve/prefill/wait"):
                 first = np.asarray(first)
@@ -1843,21 +1862,18 @@ class InferenceEngine:
                     lengths[i] = n
                     positions[i] = start
                     tables[i, :len(slot.pages)] = slot.pages
-                    keys[i] = self._key_for(req.seed)
                     temps[i] = req.temperature
+                keys[:len(spans)] = _keys_for(
+                    [req.seed for _, req, *_ in spans])
             with self._span("serve/chunk/dispatch"):
                 if self._separate_pools:
                     first, self._cache_prefill = prog(
-                        self.params, self._cache_prefill,
-                        jnp.asarray(ids), jnp.asarray(lengths),
-                        jnp.asarray(positions), jnp.asarray(tables),
-                        jnp.asarray(keys), jnp.asarray(temps))
+                        self.params, self._cache_prefill, ids, lengths,
+                        positions, tables, keys, temps)
                 else:
                     first, self._cache = prog(
-                        self.params, self._cache, jnp.asarray(ids),
-                        jnp.asarray(lengths), jnp.asarray(positions),
-                        jnp.asarray(tables), jnp.asarray(keys),
-                        jnp.asarray(temps))
+                        self.params, self._cache, ids, lengths, positions,
+                        tables, keys, temps)
             ledger.issued()
             with self._span("serve/chunk/wait"):
                 # host sync: final chunks release their first token
@@ -1932,14 +1948,12 @@ class InferenceEngine:
                 live = slot.pages[:rec.live_pages]
                 src[:len(live)] = live
                 dst[:len(live)] = new_pages[:len(live)]
-                slab = self._export(self._cache_prefill,
-                                    jnp.asarray(src))
+                slab = self._export(self._cache_prefill, src)
                 if cross and self._slab_sharding_decode is not None:
                     slab = tuple(
                         jax.device_put(s, self._slab_sharding_decode)
                         for s in slab)
-                self._cache = self._import(self._cache, slab,
-                                           jnp.asarray(dst))
+                self._cache = self._import(self._cache, slab, dst)
                 ledger.issued()
                 # one host sync per CLAIM (once per request, never per
                 # dispatch): the measured wall time must cover the
@@ -2014,9 +2028,8 @@ class InferenceEngine:
                     tables = sched.block_table_rows(self._rows, width)
                 with self._span("serve/verify/dispatch"):
                     out, self._cache = self._verify(
-                        self.params_decode, self._cache, jnp.asarray(vt),
-                        jnp.asarray(poss_a), jnp.asarray(tables),
-                        jnp.asarray(keys_a), jnp.asarray(temps_a))
+                        self.params_decode, self._cache, vt, poss_a,
+                        tables, keys_a, temps_a)
                 ledger.issued()
                 with self._span("serve/verify/wait"):
                     # host sync: the scheduler needs the token values
@@ -2096,8 +2109,9 @@ class InferenceEngine:
                 with self._span("serve/decode/dispatch"):
                     # the host arrays go to the program as they are (as
                     # warm-up's do: one entry of the jit's cache): the
-                    # call's own transfer of them is 0.07 ms where five
-                    # ``jnp.asarray`` in Python were 0.33 (a CPU host)
+                    # call's own transfer of them costs less than a
+                    # ``jnp.asarray`` each in Python (on a TPU's host six
+                    # were 1.9 ms and took 0.8 off the call)
                     if self.paged:
                         nxt, self._cache = self._decode(
                             self.params_decode, self._cache, toks_a,
@@ -2141,12 +2155,10 @@ class InferenceEngine:
         poss_a = np.zeros((self._rows,), np.int32)
         temps_a = np.zeros((self._rows,), np.float32)
         keys_a = np.zeros((self._rows, 2), np.uint32)
-        for sid, tok, pos, temp, seed in zip(sids, toks, poss, temps,
-                                             seeds):
-            toks_a[sid] = tok
-            poss_a[sid] = pos
-            temps_a[sid] = temp
-            keys_a[sid] = self._key_for(seed)
+        toks_a[sids] = toks
+        poss_a[sids] = poss
+        temps_a[sids] = temps
+        keys_a[sids] = _keys_for(seeds)
         return toks_a, poss_a, temps_a, keys_a
 
     def _write_decode_metrics(self, tok_ms, occupancy, live_tokens,
@@ -2282,39 +2294,35 @@ class InferenceEngine:
         this, :attr:`steady_state_recompiles` staying 0 is the serving
         latency contract."""
         assert self.scheduler.idle(), "warmup with requests in flight"
+        # every program is warmed with HOST arrays, the kind each
+        # dispatch passes: a device array would be a second entry in
+        # the jit's cache, and the first real dispatch a recompile
         for bb, sb in warmup_plan(self.config["batch_buckets"],
                                   self.config["prompt_buckets"]):
             ids = np.zeros((bb, sb), np.int32)
             lengths = np.ones((bb,), np.int32)
             keys = np.zeros((bb, 2), np.uint32)
             temps = np.zeros((bb,), np.float32)
+            slots = np.full((bb,), self._scratch, np.int32)
+            if self.paged:
+                positions = np.zeros((bb,), np.int32)
+                ztab = np.zeros((bb, self._prefill_pps), np.int32)
             if self._prefill_by_length:
                 first, self._cache = self._prefill(
-                    self.params, self._cache, jnp.asarray(ids),
-                    jnp.asarray(lengths), jnp.zeros((bb,), jnp.int32),
-                    jnp.zeros((bb, self._prefill_pps), jnp.int32),
-                    jnp.asarray(keys), jnp.asarray(temps),
-                    jnp.full((bb,), self._scratch, jnp.int32))
-            elif self.paged:
-                ztab = jnp.zeros((bb, self._prefill_pps), jnp.int32)
-                if self._separate_pools:
-                    first, self._cache_prefill = self._prefill(
-                        self.params, self._cache_prefill,
-                        jnp.asarray(ids), jnp.asarray(lengths),
-                        jnp.zeros((bb,), jnp.int32), ztab,
-                        jnp.asarray(keys), jnp.asarray(temps))
-                else:
-                    first, self._cache = self._prefill(
-                        self.params, self._cache, jnp.asarray(ids),
-                        jnp.asarray(lengths),
-                        jnp.zeros((bb,), jnp.int32), ztab,
-                        jnp.asarray(keys), jnp.asarray(temps))
-            else:
-                slots = np.full((bb,), self._scratch, np.int32)
+                    self.params, self._cache, ids, lengths, positions,
+                    ztab, keys, temps, slots)
+            elif not self.paged:
                 first, self._cache = self._prefill(
-                    self.params, self._cache, jnp.asarray(ids),
-                    jnp.asarray(lengths), jnp.asarray(slots),
-                    jnp.asarray(keys), jnp.asarray(temps))
+                    self.params, self._cache, ids, lengths, slots, keys,
+                    temps)
+            elif self._separate_pools:
+                first, self._cache_prefill = self._prefill(
+                    self.params, self._cache_prefill, ids, lengths,
+                    positions, ztab, keys, temps)
+            else:
+                first, self._cache = self._prefill(
+                    self.params, self._cache, ids, lengths, positions,
+                    ztab, keys, temps)
         if self.paged and self.chunked:
             # one chunk shape per batch bucket (single chunk bucket x
             # batch buckets — the ladder collapse), plus the CP chunk
@@ -2325,23 +2333,21 @@ class InferenceEngine:
                                      self._chunk_tokens)
             for prog in progs:
                 for bb, ct in plan:
-                    ids = np.zeros((bb, ct), np.int32)
-                    ztab = jnp.zeros((bb, self._prefill_pps), jnp.int32)
                     cache = self._cache_prefill if self._separate_pools \
                         else self._cache
                     first, cache = prog(
-                        self.params, cache, jnp.asarray(ids),
-                        jnp.ones((bb,), jnp.int32),
-                        jnp.zeros((bb,), jnp.int32), ztab,
-                        jnp.zeros((bb, 2), jnp.uint32),
-                        jnp.zeros((bb,), jnp.float32))
+                        self.params, cache, np.zeros((bb, ct), np.int32),
+                        np.ones((bb,), np.int32),
+                        np.zeros((bb,), np.int32),
+                        np.zeros((bb, self._prefill_pps), np.int32),
+                        np.zeros((bb, 2), np.uint32),
+                        np.zeros((bb,), np.float32))
                     if self._separate_pools:
                         self._cache_prefill = cache
                     else:
                         self._cache = cache
         if self.paged:
             for w in self._decode_page_buckets:
-                # host arrays, as every decode dispatch passes them
                 nxt, self._cache = self._decode(
                     self.params_decode, self._cache,
                     np.zeros((self._rows,), np.int32),
@@ -2355,18 +2361,18 @@ class InferenceEngine:
                 for v in self._verify_widths:
                     nxt2, self._cache = self._verify(
                         self.params_decode, self._cache,
-                        jnp.zeros((self._rows, v), jnp.int32),
-                        jnp.zeros((self._rows,), jnp.int32),
-                        jnp.zeros(
+                        np.zeros((self._rows, v), np.int32),
+                        np.zeros((self._rows,), np.int32),
+                        np.zeros(
                             (self._rows, self.paged_spec.pages_per_seq),
-                            jnp.int32),
-                        jnp.zeros((self._rows, 2), jnp.uint32),
-                        jnp.zeros((self._rows,), jnp.float32))
+                            np.int32),
+                        np.zeros((self._rows, 2), np.uint32),
+                        np.zeros((self._rows,), np.float32))
                     nxt = nxt2[:, 0]
             if self._separate_pools:
                 # warm both handoff programs against the null page so
                 # the first real claim doesn't compile on the clock
-                idx = jnp.zeros((self._handoff_width,), jnp.int32)
+                idx = np.zeros((self._handoff_width,), np.int32)
                 slab = self._export(self._cache_prefill, idx)
                 if self._slab_sharding_decode is not None:
                     slab = tuple(

@@ -453,14 +453,16 @@ def test_the_reason_cell_is_declared_as_the_issue_names_it():
             "moe_experts_hbm_roofline.reason", "prefill_mfu.reason",
             "expert_held_share.reason", "expert_load_max_over_mean.reason",
             "prefill_expert_rows_worked_share.sat"} <= reported
-    # the 22 metrics that listed every served cell list this one too,
-    # and PR 44's `prefill_page_write_share.sat` came with all four
+    # the 22 metrics that listed every served cell list this one too;
+    # PR 44's `prefill_page_write_share.sat` and PR 45's
+    # `serve_prefill_build_ms.sat` came with all four
     every = [m for m in bench["per_layer"] if DOCS in m.get(
         "workloads", []) and "gpt2-345m.serve-saturated" in m["workloads"]
         and "solar-open2-250b.serve-rollout-saturated" in m["workloads"]]
-    assert len(every) == 23 and all(m["workloads"][-1] == REASON
+    assert len(every) == 24 and all(m["workloads"][-1] == REASON
                                     for m in every)
-    assert every[-1]["name"] == "prefill_page_write_share.sat"
+    assert [m["name"] for m in every[-2:]] == [
+        "prefill_page_write_share.sat", "serve_prefill_build_ms.sat"]
     for name in reported:
         assert os.path.exists(os.path.join(BENCH, "metrics",
                                            name + ".json")), name
