@@ -260,7 +260,7 @@ def qwz_distribute_params(params, block: int = 256,
       unchanged.
     - ``"int8"``: keep the int8 blocks + scales live (a tree of
       ``QuantizedParam`` leaves). The compiled prefill/decode programs
-      dequantize per block at each weight use (``models/*`` ``_wd``),
+      dequantize per block at each weight use (``models/*`` ``cast_weight``),
       so resident weight HBM drops ~2x and the wire saving survives on
       the replica.
 
@@ -421,7 +421,7 @@ class InferenceEngine:
         # ------------------------------------- int8-resident weights
         # quantize_weights: False | "bf16" (wire-only) | "int8" (keep
         # qwZ blocks + scales as the LIVE tree; compiled programs
-        # dequant per block at each matmul — models/* ``_wd``)
+        # dequant per block at each matmul — models/* ``cast_weight``)
         qw = cfg["quantize_weights"]
         self.weights_resident = "int8" if qw == "int8" else (
             "bf16" if qw else "off")
@@ -1689,11 +1689,11 @@ class InferenceEngine:
         real = sum(len(p) for p in prompts)
         # what the program reads from `positions`: with every row at
         # cache position 0 the batch attends to its own keys
-        # (models/gpt2.paged_attend), else to the gathered stripe
+        # (page_pool.paged_attend), else to the gathered stripe
         own = real if self.paged and not any(batch.prefix_lens) else 0
         # and what its pool write reads: a bucket of whole pages whose
         # rows all start on a page boundary lands a page an index
-        # (models/gpt2.write_paged_kv_cache), else a row an index
+        # (page_pool.write_paged_kv_cache), else a row an index
         ps = self.paged_spec.page_size if self.paged else 0
         paged_whole = real if ps and pb % ps == 0 and not any(
             pl % ps for pl in batch.prefix_lens) else 0

@@ -63,9 +63,9 @@ admission are the pair's.
 
 Writes happen inside the model forwards via
 :func:`deepspeed_tpu.models.gpt2.write_kv_cache` (dense) /
-:func:`deepspeed_tpu.models.gpt2.write_paged_kv_cache` (paged); this
-module only owns allocation, the family-specific geometry (GQA caches
-are kv_heads-sized), and byte accounting for telemetry.
+:func:`deepspeed_tpu.ops.attention.page_pool.write_paged_kv_cache`
+(paged); this module only owns allocation, the family-specific geometry
+(GQA caches are kv_heads-sized), and byte accounting for telemetry.
 """
 
 from typing import Any, NamedTuple, Optional, Tuple

@@ -1,8 +1,8 @@
 from deepspeed_tpu.models.gpt2 import (
     GPT2Config, GPT2_SMALL, GPT2_MEDIUM, GPT2_LARGE, GPT2_XL,
-    causal_cache_mask, gpt2_forward, gpt2_loss_fn, gpt2_param_specs,
-    gpt2_pipeline_spec, gpt2_sp_loss_fn, init_gpt2_params, count_params,
-    write_kv_cache)
+    gpt2_forward, gpt2_loss_fn, gpt2_param_specs, gpt2_pipeline_spec,
+    gpt2_sp_loss_fn, init_gpt2_params, count_params, write_kv_cache)
+from deepspeed_tpu.ops.attention.page_pool import causal_cache_mask
 from deepspeed_tpu.models.bert import (
     BertConfig, BERT_BASE, BERT_LARGE, bert_encoder, bert_mlm_loss_fn,
     bert_mlm_sp_loss_fn, bert_param_specs, init_bert_params)

@@ -53,15 +53,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.models.gpt2 import (causal_cache_mask, gather_paged_kv,
-                                       own_keys_attention,
-                                       paged_write_index,
-                                       write_paged_kv_cache)
+from deepspeed_tpu.models.served_trunk import (ServedFamily, _mm,
+                                               served_forward,
+                                               whole_leaf_specs)
 from deepspeed_tpu.ops.attention.flash import NEG_INF
+from deepspeed_tpu.ops.attention.page_pool import (causal_cache_mask,
+                                                   gather_paged_kv,
+                                                   own_keys_attention,
+                                                   write_paged_kv_cache)
 from deepspeed_tpu.ops.attention.paged import latent_decode_attention
 from deepspeed_tpu.ops.functional import rms_norm
-from deepspeed_tpu.ops.moe import (held_experts_every_row,
-                                   route_group_limited, served_experts)
+from deepspeed_tpu.ops.moe import route_group_limited
 from deepspeed_tpu.profiling.spans import scope
 
 # caps of the grouped products' tile at these experts' widths (7,168 x
@@ -251,32 +253,8 @@ def init_axk1_params(config: AXK1Config, key,
 
 
 def axk1_param_specs(config: AXK1Config):
-    """Only the single-device engine serves this family
-    (``inference/engine.py`` refuses a serving mesh): every leaf whole."""
-    from jax.sharding import PartitionSpec as P
-    return jax.tree_util.tree_map(
-        lambda _: P(), jax.eval_shape(
-            lambda: init_axk1_params(config, jax.random.PRNGKey(0))))
-
-
-def _norm(x, w, eps):
-    with scope("ln"):
-        return rms_norm(x, w, eps)
-
-
-def _mm(x, w, dtype):
-    return jnp.dot(x.astype(dtype), w.astype(dtype),
-                   preferred_element_type=jnp.float32)
-
-
-class _Pages(NamedTuple):
-    """Where a layer's latent rows go and come from."""
-    pool: Any
-    layer: int
-    tables: Any
-    positions: Any
-    index: Any          # gpt2.paged_write_index's, shared by the layers
-    reader: str
+    """Every leaf whole (``served_trunk.whole_leaf_specs``)."""
+    return whole_leaf_specs(init_axk1_params, config)
 
 
 @functools.lru_cache(maxsize=None)
@@ -297,10 +275,13 @@ def _stripe_attention(sm_scale: float):
     return attend
 
 
-def _latent_mixer(ap, config, h, dtype, positions, cache):
+def _latent_mixer(lp, h, call, cache, n):
     """Latent attention of one layer on ``h`` (B, S, H) whose tokens sit
-    at ``positions`` (B, S). ``cache`` None (no pages: the plain
-    forward) or :class:`_Pages`; returns (y, the pool)."""
+    at ``call.token_positions`` (B, S), a mixer of
+    ``models/served_trunk.py``: layer ``n`` of the tree's ONE latent
+    pool (``cache`` None: no pages, the plain forward)."""
+    ap, config, dtype, positions = (lp["attn"], call.config, call.dtype,
+                                    call.token_positions)
     B, S, _ = h.shape
     nh, rkv = config.num_heads, config.kv_lora_rank
     dn, dr, dv = (config.qk_nope_head_dim, config.qk_rope_head_dim,
@@ -319,13 +300,13 @@ def _latent_mixer(ap, config, h, dtype, positions, cache):
             [rms_norm(kv[..., :rkv], ap["kv_norm"], eps),
              _rope(kv[..., rkv:], positions, inv_freq)], -1).astype(dtype)
     w_kvb = ap["wkv_b"].astype(dtype).reshape(rkv, nh, dn + dv)
-    pool = None
     if cache is not None:
         # the pool's row is ONE head as wide as its lanes, the tail zeros
+        (pool,) = cache
         held = jnp.pad(row, ((0, 0), (0, 0),
-                             (0, cache.pool.shape[-1] - rkv - dr)))
-        pool = write_paged_kv_cache(cache.pool, cache.layer, held[:, None],
-                                    cache.index)
+                             (0, pool.shape[-1] - rkv - dr)))
+        pool = write_paged_kv_cache(pool, n, held[:, None], call.index)
+        cache = (pool,)
 
     def expanded(rows, cache_position, own):
         """Attention over latent ``rows`` (B, L, >= r_kv + d_r) expanded
@@ -351,7 +332,7 @@ def _latent_mixer(ap, config, h, dtype, positions, cache):
         return own_keys_attention(qx, k, v, cache_position, stripe,
                                   sm_scale=config.sm_scale)[..., :dv]
 
-    if cache is not None and S == 1 and cache.reader == "pallas":
+    if cache is not None and S == 1 and call.reader == "pallas":
         with scope("mla_absorb"):
             q_c = jnp.einsum("bhd,chd->bhc", q_n[:, 0], w_kvb[..., :dn],
                              preferred_element_type=jnp.float32)
@@ -361,69 +342,48 @@ def _latent_mixer(ap, config, h, dtype, positions, cache):
                                     (0, pool.shape[-1] - rkv - dr)))
         with scope("attn_core"):
             ctx = latent_decode_attention(
-                q_abs, pool, cache.tables, cache.positions,
-                config.sm_scale, rkv, layer=cache.layer)
+                q_abs, pool, call.tables, call.positions,
+                config.sm_scale, rkv, layer=n)
         with scope("mla_absorb"):
             o = jnp.einsum("bhc,chd->bhd", ctx.astype(dtype),
                            w_kvb[..., dn:],
                            preferred_element_type=jnp.float32)[:, :, None]
-    elif cache is not None and cache.reader != "pallas":
-        rows = gather_paged_kv(pool, cache.layer, cache.tables, 1)[:, 0]
-        o = expanded(rows, cache.positions, own=False)
+    elif cache is not None and call.reader != "pallas":
+        rows = gather_paged_kv(pool, n, call.tables, 1)[:, 0]
+        o = expanded(rows, call.positions, own=False)
     else:
         # every row starts at position 0: its own rows are all it may
         # see (rounded to the dtype the pool holds them in)
         o = expanded(row, jnp.zeros((B,), jnp.int32), own=True)
     with scope("mla_out"):
         o = o.transpose(0, 2, 1, 3).reshape(B, S, nh * dv)
-        return _mm(o, ap["wo"], dtype), pool
+        return _mm(o, ap["wo"], dtype), cache
 
 
-def _swiglu(p, flat, dtype):
-    act = jax.nn.silu(_mm(flat, p["w_gate"], dtype)) * _mm(
-        flat, p["w_up"], dtype)
-    return _mm(act, p["w_down"], dtype)
-
-
-def _expert_half(lp, config, x, dtype, active, lengths):
-    """x -> (x + routed + shared, this layer's int32 counters), as
-    ``models/solar_open2._expert_half`` with the group-limited router.
-    DECODE: (landed, fullest, active rows whose kept groups include a
-    group with an expert held here); PREFILL: (rows the turns worked,
-    rows static turns would have)."""
-    B, S, hdim = x.shape
-    h2 = _norm(x, lp["ln_2"]["w"], config.rms_norm_eps)
-    flat = h2.reshape(B * S, hdim)
-    with scope("moe_route"):
+def _family(config: AXK1Config) -> ServedFamily:
+    def route(flat, router):
         idx, p, _, kept = route_group_limited(
-            flat, lp["router"], config.experts_per_token, config.n_group,
+            flat, router, config.experts_per_token, config.n_group,
             config.topk_group, config.routed_scaling_factor)
-    experts = {n: t.astype(dtype) for n, t in lp["experts"].items()}
-    rows = flat.astype(dtype)
-    if S == 1:
-        y, counts = held_experts_every_row(
-            rows, idx, p, experts, config.held, jax.nn.silu, active)
-        with scope("moe_route"):
-            per = config.num_experts // config.n_group
-            first, count = config.held
-            mine = (kept >= first // per) & (
-                kept <= (first + count - 1) // per)
-            here = jnp.any(mine, axis=-1)
-            if active is not None:
-                here = here & active
-        counters = jnp.stack([jnp.sum(counts), jnp.max(counts),
-                              jnp.sum(here, dtype=jnp.int32)])
-    else:
-        counted = None if lengths is None else (
-            jnp.arange(S) < lengths[:, None]).reshape(B * S)
-        y, _, counters = served_experts(
-            rows, idx, p, experts, config.held, config.num_experts,
-            jax.nn.silu, tile=_EXPERT_TILE, counted=counted)
-    with scope("moe_shared"):
-        y = y + _swiglu(lp["shared"], flat, dtype)
-    with scope("moe_dispatch"):
-        x = x + y.reshape(B, S, hdim)
-    return x, counters
+        return idx, p, kept
+
+    def rows_kept_here(kept, active):
+        """Rows whose kept groups include a group with an expert held
+        here: the third DECODE counter."""
+        per = config.num_experts // config.n_group
+        first, count = config.held
+        mine = (kept >= first // per) & (kept <= (first + count - 1) // per)
+        here = jnp.any(mine, axis=-1)
+        if active is not None:
+            here = here & active
+        return here
+
+    return ServedFamily(
+        layers=tuple(("latent", "dense" if l < config.first_k_dense
+                      else "experts") for l in range(config.num_layers)),
+        mixers={"latent": _latent_mixer}, route=route,
+        expert_tile=_EXPERT_TILE, decode_rows=rows_kept_here,
+        token_positions=True)
 
 
 def axk1_forward(params, config: AXK1Config, input_ids, dtype=jnp.bfloat16,
@@ -445,55 +405,14 @@ def axk1_forward(params, config: AXK1Config, input_ids, dtype=jnp.bfloat16,
     DECODE (S == 1) is absorbed through the Pallas reader, or the stripe
     reader under ``"gather"``. Returns (logits, the cache); with
     ``with_counts`` also (expert layers, 3 in decode and 2 in prefill)
-    int32 (:func:`_expert_half`).
-    """
-    del slots
-    B, S = input_ids.shape
-    serving = kv_cache is not None
-    pool = None
-    if serving:
-        (pool,) = kv_cache
-        if cache_position is None:
-            cache_position = jnp.zeros((B,), jnp.int32)
-        index = paged_write_index(block_tables, cache_position, S,
-                                  pool.shape[2])
-        if S > 1:
-            assert lengths is not None, \
-                "a served prefill needs each row's length"
-    start = cache_position if serving else jnp.zeros((B,), jnp.int32)
-    positions = start[:, None] + jnp.arange(S)[None, :]
-    with scope("embed"):
-        x = params["tok_emb"][input_ids].astype(jnp.float32)
-    counts = []
-    for l in range(config.num_layers):
-        lp = params[f"h_{l}"]
-        h = _norm(x, lp["ln_1"]["w"], config.rms_norm_eps)
-        y, new = _latent_mixer(
-            lp["attn"], config, h, dtype, positions,
-            _Pages(pool, l, block_tables, cache_position, index,
-                   paged_attn_kernel) if serving else None)
-        pool = new
-        x = x + y
-        if l < config.first_k_dense:
-            h2 = _norm(x, lp["ln_2"]["w"], config.rms_norm_eps)
-            with scope("mlp"):
-                x = x + _swiglu(lp["mlp"], h2.reshape(B * S, -1),
-                                dtype).reshape(x.shape)
-        else:
-            x, c = _expert_half(lp, config, x, dtype, active, lengths)
-            counts.append(c)
-    x = _norm(x, params["ln_f"]["w"], config.rms_norm_eps)
-    if serving and S > 1:
-        x = x[jnp.arange(B), lengths - 1][:, None]
-    with scope("lm_head"):
-        logits = jax.lax.dot_general(
-            x.astype(dtype), params["lm_head"].astype(dtype),
-            (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    if not serving:
-        return logits
-    if with_counts:
-        return logits, (pool,), jnp.stack(counts).astype(jnp.int32)
-    return logits, (pool,)
+    int32 (``served_trunk._expert_half``): DECODE (landed, fullest,
+    active rows whose kept groups include a group with an expert held
+    here), PREFILL (rows the turns worked, rows static turns would
+    have)."""
+    return served_forward(_family(config), params, config, input_ids, dtype,
+                          kv_cache, cache_position, block_tables,
+                          paged_attn_kernel, lengths, slots, active,
+                          with_counts)
 
 
 def axk1_param_count(config: AXK1Config):

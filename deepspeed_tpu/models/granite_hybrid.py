@@ -35,7 +35,7 @@ Two programs, as that family's. PREFILL (more than one token a row):
 every row starts at position 0 with an empty state (served without
 prefix cache or chunked prefill: ``inference/engine.py`` refuses them),
 the attention layer attends the prompt's own keys and values
-(``models/gpt2.own_keys_attention``) and writes them to the pages, the
+(``page_pool.own_keys_attention``) and writes them to the pages, the
 Mamba layers run ``ssd_chunk_scan`` to each row's TRUE length and write
 the final state and tail WHOLE at the row's slot. DECODE (one token a
 row, the rows the slot table): the Pallas paged reader,
@@ -50,13 +50,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.models.gpt2 import (own_keys_attention, paged_attend,
-                                       paged_write_index, write_paged_layer)
-from deepspeed_tpu.models.llama import _gqa_stripe_attention
-from deepspeed_tpu.models.solar_open2 import _mm, _norm, _Pages
+from deepspeed_tpu.models.served_trunk import (ServedFamily, _mm,
+                                               paged_pair_mixer,
+                                               served_forward,
+                                               whole_leaf_specs)
+from deepspeed_tpu.ops.attention.page_pool import (gqa_stripe_attention,
+                                                   own_keys_attention,
+                                                   paged_attend,
+                                                   write_paged_layer)
 from deepspeed_tpu.ops.functional import rms_norm
-from deepspeed_tpu.ops.moe import (held_experts_every_row, route_top_k,
-                                   served_experts)
+from deepspeed_tpu.ops.moe import route_top_k
 from deepspeed_tpu.ops.ssd import ssd_chunk_scan, ssd_decode_update
 from deepspeed_tpu.profiling.spans import scope
 
@@ -252,28 +255,23 @@ def init_granite_hybrid_params(config: GraniteHybridConfig, key,
 
 
 def granite_hybrid_param_specs(config: GraniteHybridConfig):
-    """Only the single-device engine serves this family
-    (``inference/engine.py`` refuses a serving mesh): every leaf whole."""
-    from jax.sharding import PartitionSpec as P
-    return jax.tree_util.tree_map(
-        lambda _: P(), jax.eval_shape(
-            lambda: init_granite_hybrid_params(config,
-                                               jax.random.PRNGKey(0))))
+    """Every leaf whole (``served_trunk.whole_leaf_specs``)."""
+    return whole_leaf_specs(init_granite_hybrid_params, config)
 
 
 @functools.lru_cache(maxsize=None)
 def _stripe_attention_at(sm_scale: float):
-    """``models/llama._gqa_stripe_attention`` at the published score
+    """``page_pool.gqa_stripe_attention`` at the published score
     scale: the numerics oracle behind ``paged_attend``'s gather reader
     and a small call's own keys. ONE function a scale (``_own_keys`` is
     jitted with it as a static argument)."""
-    return functools.partial(_gqa_stripe_attention, sm_scale=sm_scale)
+    return functools.partial(gqa_stripe_attention, sm_scale=sm_scale)
 
 
 def _softmax_mixer(ap, config, h, dtype, cache):
     """NoPE attention of one layer on ``h`` (B, S, H). ``cache`` None
-    (no pages: the plain forward) or :class:`_Pages`; returns (y, the
-    pools)."""
+    (no pages: the plain forward) or ``served_trunk._Pages``; returns
+    (y, the pools)."""
     B, S, _ = h.shape
     H, hkv, hd = config.num_heads, config.num_kv_heads, config.head_dim
     scale = float(config.attention_multiplier)
@@ -305,14 +303,17 @@ def _softmax_mixer(ap, config, h, dtype, cache):
         return _mm(ctx, ap["wo"], dtype), pools
 
 
-def _mamba_mixer(mp, config, h, dtype, lengths, cache):
-    """The Mamba-2 mixer of one layer on ``h`` (B, S, H). ``cache`` None
-    (the plain forward: an empty state, nothing kept) or (state pool,
-    tail pool, pool layer, slots); ``lengths`` (B,) the rows' true
-    lengths or None. Returns (y, (state pool, tail pool))."""
+def _mamba_mixer(lp, h, call, cache, n):
+    """The Mamba-2 mixer of one layer on ``h`` (B, S, H), a mixer of
+    ``models/served_trunk.py``: the ``n``-th recurrent layer over the
+    tree's ``state`` and ``tails`` (``cache`` None, the plain forward:
+    an empty state, nothing kept), a served prefill to ``call.lengths``
+    and into rows ``call.slots``."""
+    mp, config, dtype, lengths = (lp["mamba"], call.config, call.dtype,
+                                  call.lengths)
     B, S, _ = h.shape
-    nh, hd, n = (config.mamba_n_heads, config.mamba_d_head,
-                 config.mamba_d_state)
+    nh, hd, ds = (config.mamba_n_heads, config.mamba_d_head,
+                  config.mamba_d_state)
     di, cw = config.d_inner, config.mamba_d_conv
     decode = cache is not None and S == 1
     with scope("ssd_proj"):
@@ -322,8 +323,7 @@ def _mamba_mixer(mp, config, h, dtype, lengths, cache):
         dt = jax.nn.softplus(proj[..., di + config.conv_channels:]
                              + mp["dt_bias"])                 # (B, S, nh)
         if decode:
-            state, tails, layer, _ = cache
-            window = jnp.concatenate([tails[layer], raw], axis=1)
+            window = jnp.concatenate([cache.tails[n], raw], axis=1)
             tail = window[:, 1:]
         else:
             window = jnp.pad(raw, ((0, 0), (cw - 1, 0), (0, 0)))
@@ -338,70 +338,48 @@ def _mamba_mixer(mp, config, h, dtype, lengths, cache):
             window[:, j:j + S].astype(jnp.float32) * taps[j]
             for j in range(cw)) + mp["conv_b"])
         x = mixed[..., :di].reshape(B, S, nh, hd)
-        bm, cm = mixed[..., di:di + n], mixed[..., di + n:]
+        bm, cm = mixed[..., di:di + ds], mixed[..., di + ds:]
         a, d = -jnp.exp(mp["a_log"]), mp["d"]
-    pools = None
     if decode:
         with scope("ssd_state"):
-            y, state = ssd_decode_update(state, layer, x[:, 0], dt[:, 0], a,
-                                         bm[:, 0], cm[:, 0], d)
+            y, state = ssd_decode_update(cache.state, n, x[:, 0], dt[:, 0],
+                                         a, bm[:, 0], cm[:, 0], d)
             y = y[:, None]
-            tails = tails.at[layer].set(tail)
-        pools = (state, tails)
+            tails = cache.tails.at[n].set(tail)
     else:
         with scope("ssd_scan"):
             y, last = ssd_chunk_scan(
-                x, dt, a, bm, cm, d, jnp.zeros((B, nh, hd, n), jnp.float32),
+                x, dt, a, bm, cm, d, jnp.zeros((B, nh, hd, ds), jnp.float32),
                 lengths, chunk=config.mamba_chunk_size)
         if cache is not None:
-            state, tails, layer, slots = cache
+            assert call.slots is not None, \
+                "a served prefill needs each row's slot"
             with scope("ssd_state"):
-                pools = (state.at[layer, slots].set(last),
-                         tails.at[layer, slots].set(tail))
+                state = cache.state.at[n, call.slots].set(last)
+                tails = cache.tails.at[n, call.slots].set(tail)
+    if cache is not None:
+        cache = cache._replace(state=state, tails=tails)
     with scope("ssd_proj"):
         # the gate BEFORE the norm, the norm over the whole inner width
         y = rms_norm(y.reshape(B, S, di) * jax.nn.silu(z), mp["norm"],
                      config.rms_norm_eps)
-        return _mm(y, mp["w_out"], dtype), pools
+        return _mm(y, mp["w_out"], dtype), cache
 
 
-def _expert_half(lp, config, x, dtype, active, lengths):
-    """x -> (x + r (routed + shared), a pair int32 of this layer), as
-    ``models/solar_open2._expert_half``: one token a row (decode) works
-    every held expert on every row, the pair (landed, fullest); a bucket
-    of prompts goes through ``ops.moe.served_experts``, whose work
-    follows the assignments that landed here at a true position
-    (``lengths``; None: every position), the pair (rows worked, rows
-    static turns would have). The trained ``dropless_experts`` is not
-    called: its time must not follow the router, a served prefill's
-    should."""
-    B, S, hdim = x.shape
-    h2 = _norm(x, lp["ln_2"]["w"], config.rms_norm_eps)
-    flat = h2.reshape(B * S, hdim)
-    with scope("moe_route"):
+def _family(config: GraniteHybridConfig) -> ServedFamily:
+    def route(flat, router):
         # the ten largest logits, softmax over those ten
-        idx, p, _ = route_top_k(flat, lp["router"],
-                                config.experts_per_token)
-    experts = {n: t.astype(dtype) for n, t in lp["experts"].items()}
-    rows = flat.astype(dtype)
-    if S == 1:
-        y, counts = held_experts_every_row(
-            rows, idx, p, experts, config.held, jax.nn.silu, active)
-        pair = jnp.stack([jnp.sum(counts), jnp.max(counts)])
-    else:
-        counted = None if lengths is None else (
-            jnp.arange(S) < lengths[:, None]).reshape(B * S)
-        y, _, pair = served_experts(
-            rows, idx, p, experts, config.held, config.num_experts,
-            jax.nn.silu, tile=_EXPERT_TILE, counted=counted)
-    with scope("moe_shared"):
-        sp = lp["shared"]
-        act = jax.nn.silu(_mm(flat, sp["w_gate"], dtype)) * _mm(
-            flat, sp["w_up"], dtype)
-        y = y + _mm(act, sp["w_down"], dtype)
-    with scope("moe_dispatch"):
-        x = x + config.residual_multiplier * y.reshape(B, S, hdim)
-    return x, pair
+        idx, p, _ = route_top_k(flat, router, config.experts_per_token)
+        return idx, p, None
+
+    return ServedFamily(
+        layers=tuple((kind, "experts") for kind in config.kinds),
+        mixers={"attention": paged_pair_mixer(_softmax_mixer),
+                "mamba": _mamba_mixer},
+        route=route, expert_tile=_EXPERT_TILE,
+        embedding_multiplier=config.embedding_multiplier,
+        residual_multiplier=config.residual_multiplier,
+        logits_scaling=config.logits_scaling, head="tok_emb")
 
 
 def granite_hybrid_forward(params, config: GraniteHybridConfig, input_ids,
@@ -420,59 +398,10 @@ def granite_hybrid_forward(params, config: GraniteHybridConfig, input_ids,
     row i of the state pools. Returns (logits, the cache); with
     ``with_counts`` also (layers, 2) int32: over the ``active`` rows in
     decode, the expert turns' rows (worked, static) in prefill."""
-    B, S = input_ids.shape
-    serving = kv_cache is not None
-    if serving:
-        pools = (kv_cache.keys, kv_cache.values)
-        state, tails = kv_cache.state, kv_cache.tails
-        if cache_position is None:
-            cache_position = jnp.zeros((B,), jnp.int32)
-        index = paged_write_index(block_tables, cache_position, S,
-                                  pools[0].shape[2])
-        if S > 1:
-            assert lengths is not None and slots is not None, \
-                "a served prefill needs each row's length and slot"
-    r = config.residual_multiplier
-    with scope("embed"):
-        x = config.embedding_multiplier * params["tok_emb"][
-            input_ids].astype(jnp.float32)
-    counts = []
-    n_soft = n_rec = 0
-    for l, kind in enumerate(config.kinds):
-        lp = params[f"h_{l}"]
-        h = _norm(x, lp["ln_1"]["w"], config.rms_norm_eps)
-        if kind == "attention":
-            y, new = _softmax_mixer(
-                lp["attn"], config, h, dtype,
-                _Pages(pools, n_soft, block_tables, cache_position, index,
-                       paged_attn_kernel) if serving else None)
-            pools = new if serving else None
-            n_soft += 1
-        else:
-            y, new = _mamba_mixer(
-                lp["mamba"], config, h, dtype, lengths,
-                (state, tails, n_rec, slots) if serving else None)
-            if serving:
-                state, tails = new
-            n_rec += 1
-        x = x + r * y
-        x, c = _expert_half(lp, config, x, dtype, active, lengths)
-        counts.append(c)
-    x = _norm(x, params["ln_f"]["w"], config.rms_norm_eps)
-    if serving and S > 1:
-        x = x[jnp.arange(B), lengths - 1][:, None]
-    with scope("lm_head"):
-        logits = jax.lax.dot_general(
-            x.astype(dtype), params["tok_emb"].astype(dtype),
-            (((2,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) / config.logits_scaling
-    if not serving:
-        return logits
-    cache = kv_cache._replace(keys=pools[0], values=pools[1], state=state,
-                              tails=tails)
-    if with_counts:
-        return logits, cache, jnp.stack(counts).astype(jnp.int32)
-    return logits, cache
+    return served_forward(_family(config), params, config, input_ids, dtype,
+                          kv_cache, cache_position, block_tables,
+                          paged_attn_kernel, lengths, slots, active,
+                          with_counts)
 
 
 def granite_hybrid_param_count(config: GraniteHybridConfig):

@@ -17,7 +17,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.ops.attention.flash import NEG_INF, flash_attention
+from deepspeed_tpu.models.gpt2 import (cast_weight, embedding_rows,
+                                       layer_params, make_token_sampler,
+                                       run_decode_scan, tied_logits,
+                                       tied_xent_chunked, write_kv_cache)
+from deepspeed_tpu.ops.attention.flash import flash_attention
+from deepspeed_tpu.ops.attention.page_pool import (gqa_stripe_attention,
+                                                   paged_attend,
+                                                   paged_write_index)
 from deepspeed_tpu.ops.functional import rms_norm
 
 
@@ -61,7 +68,7 @@ def init_llama_params(config: LlamaConfig, key) -> Dict[str, Any]:
                                      jnp.float32) * rng,
         "ln_f": {"w": jnp.ones((h,), jnp.float32)},
         # untied output head, stored (V, H) like a tied embedding so the
-        # chunked fused head (gpt2._tied_xent_chunked) applies unchanged
+        # chunked fused head (gpt2.tied_xent_chunked) applies unchanged
         "lm_head": jax.random.normal(keys[1], (config.vocab_size, h),
                                      jnp.float32) * rng,
     }
@@ -125,9 +132,6 @@ def llama_param_specs(config: LlamaConfig) -> Dict[str, Any]:
     return specs
 
 
-from deepspeed_tpu.models.gpt2 import count_params  # noqa: E402 (reuse)
-
-
 def rope_cos_sin(seq_len: int, head_dim: int, theta: float,
                  dtype=jnp.float32):
     """(S, hd/2) cos/sin tables for rotary embedding."""
@@ -167,12 +171,11 @@ def llama_block(block_params, config: LlamaConfig, x, cos, sin, dtype,
     B, S, h = x.shape
     H, hkv, hd = config.num_heads, config.kv_heads, config.head_dim
 
-    from deepspeed_tpu.models.gpt2 import _wd
     a_in = rms_norm(x, block_params["ln_1"]["w"], config.rms_norm_eps)
     ap = block_params["attn"]
-    q = (a_in @ _wd(ap["wq"], dtype)).reshape(B, S, H, hd)
-    k = (a_in @ _wd(ap["wk"], dtype)).reshape(B, S, hkv, hd)
-    v = (a_in @ _wd(ap["wv"], dtype)).reshape(B, S, hkv, hd)
+    q = (a_in @ cast_weight(ap["wq"], dtype)).reshape(B, S, H, hd)
+    k = (a_in @ cast_weight(ap["wk"], dtype)).reshape(B, S, hkv, hd)
+    v = (a_in @ cast_weight(ap["wv"], dtype)).reshape(B, S, hkv, hd)
     q = apply_rope(q.transpose(0, 2, 1, 3), cos, sin)
     k = apply_rope(k.transpose(0, 2, 1, 3), cos, sin)
     v = v.transpose(0, 2, 1, 3)
@@ -181,13 +184,13 @@ def llama_block(block_params, config: LlamaConfig, x, cos, sin, dtype,
     else:
         ctx = flash_attention(q, k, v, causal=True)  # native GQA
     ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, h)
-    x = x + ctx @ _wd(ap["wo"], dtype)
+    x = x + ctx @ cast_weight(ap["wo"], dtype)
 
     m_in = rms_norm(x, block_params["ln_2"]["w"], config.rms_norm_eps)
     mp = block_params["mlp"]
-    gate = jax.nn.silu(m_in @ _wd(mp["w_gate"], dtype))
-    up = m_in @ _wd(mp["w_up"], dtype)
-    return x + (gate * up) @ _wd(mp["w_down"], dtype)
+    gate = jax.nn.silu(m_in @ cast_weight(mp["w_gate"], dtype))
+    up = m_in @ cast_weight(mp["w_up"], dtype)
+    return x + (gate * up) @ cast_weight(mp["w_down"], dtype)
 
 
 def _llama_trunk(params, config: LlamaConfig, input_ids,
@@ -196,8 +199,7 @@ def _llama_trunk(params, config: LlamaConfig, input_ids,
     assert S <= config.max_position_embeddings, (
         "sequence length exceeds max_position_embeddings — RoPE would "
         "silently extrapolate", S, config.max_position_embeddings)
-    from deepspeed_tpu.models.gpt2 import _emb_rows
-    x = _emb_rows(params["tok_emb"], input_ids, dtype)
+    x = embedding_rows(params["tok_emb"], input_ids, dtype)
     cos, sin = rope_cos_sin(S, config.head_dim, config.rope_theta)
 
     block = llama_block
@@ -214,25 +216,6 @@ def _llama_trunk(params, config: LlamaConfig, input_ids,
     return rms_norm(x, params["ln_f"]["w"], config.rms_norm_eps)
 
 
-def _gqa_stripe_attention(q, kc, vc, cache_position, sm_scale=None):
-    """Group-wise attention of ``q`` (B, heads, S, hd) over a whole
-    kv_heads-sized key/value stripe (B, kv_heads, kv_len, hd) under the
-    shared ``causal_cache_mask``, in float32: no head is replicated.
-    ``sm_scale`` (None: ``hd ** -0.5``) multiplies the scores."""
-    from deepspeed_tpu.models.gpt2 import causal_cache_mask
-    B, H, S, hd = q.shape
-    hkv = kc.shape[1]
-    qg = q.reshape(B, hkv, H // hkv, S, hd)
-    scores = jnp.einsum("bkgsd,bkld->bkgsl", qg.astype(jnp.float32),
-                        kc.astype(jnp.float32))
-    scores = scores / np.sqrt(hd) if sm_scale is None else scores * sm_scale
-    mask = causal_cache_mask(cache_position, S, kc.shape[2])
-    scores = jnp.where(mask[:, :, None], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    ctx = jnp.einsum("bkgsl,bkld->bkgsd", probs, vc.astype(jnp.float32))
-    return ctx.reshape(B, H, S, hd).astype(q.dtype)
-
-
 def _gqa_offset_cache_attention(kcache, vcache, cache_position, out_box):
     """attention_fn for the cached llama forward (prefill-into-cache and
     decode alike): write this call's post-RoPE K/V into the hkv-head
@@ -240,13 +223,12 @@ def _gqa_offset_cache_attention(kcache, vcache, cache_position, out_box):
     slots <= each query's absolute position (the shared
     ``causal_cache_mask``). The cache stays kv_heads-sized — GQA's
     serving payoff. Updated caches return through ``out_box``."""
-    from deepspeed_tpu.models.gpt2 import write_kv_cache
 
     def attn(q, k, v):
         kc = write_kv_cache(kcache, k, cache_position)
         vc = write_kv_cache(vcache, v, cache_position)
         out_box.append((kc, vc))
-        return _gqa_stripe_attention(q, kc, vc, cache_position)
+        return gqa_stripe_attention(q, kc, vc, cache_position)
     return attn
 
 
@@ -254,19 +236,18 @@ def _gqa_paged_cache_attention(pools, layer: int, block_table,
                                cache_position, index, out_box,
                                attn_kernel: str = "gather"):
     """Paged attention_fn for layer ``layer`` of the cached llama
-    forward: ``gpt2.paged_attend`` over the kv_heads-sized stacked pool
-    tree with this call's post-RoPE K/V. Single-query calls with
+    forward: ``page_pool.paged_attend`` over the kv_heads-sized stacked
+    pool tree with this call's post-RoPE K/V. Single-query calls with
     ``attn_kernel="pallas"`` run the fused paged-decode kernel, which
     serves GQA natively — the q_heads/kv_heads query rows of each group
     share their kv head's page stream inside the kernel, so no head
     replication ever materializes; otherwise the gathered stripe is
-    attended group-wise (:func:`_gqa_stripe_attention`)."""
-    from deepspeed_tpu.models.gpt2 import paged_attend
+    attended group-wise (:func:`gqa_stripe_attention`)."""
 
     def attn(q, k, v):
         return paged_attend(q, k, v, pools, layer, block_table,
                             cache_position, index, out_box, attn_kernel,
-                            _gqa_stripe_attention)
+                            gqa_stripe_attention)
     return attn
 
 
@@ -283,8 +264,6 @@ def _llama_trunk_cached(params, config: LlamaConfig, input_ids, kv_cache,
     4-tuple ``(kc, vc, kscale, vscale)``; ``paged_attn_kernel`` picks
     the fused Pallas decode kernel or the gather oracle for seq-1
     queries."""
-    from deepspeed_tpu.models.gpt2 import (_emb_rows, layer_params,
-                                           paged_write_index)
     B, S = input_ids.shape
     paged = block_tables is not None
     if paged:
@@ -298,7 +277,7 @@ def _llama_trunk_cached(params, config: LlamaConfig, input_ids, kv_cache,
     cos_full, sin_full = rope_cos_sin(max_len, config.head_dim,
                                       config.rope_theta)
     cos_b, sin_b = cos_full[pos], sin_full[pos]        # (B, S, hd/2)
-    x = _emb_rows(params["tok_emb"], input_ids, dtype)
+    x = embedding_rows(params["tok_emb"], input_ids, dtype)
     if paged:
         for i in range(config.num_layers):
             box = []
@@ -335,7 +314,6 @@ def llama_forward(params, config: LlamaConfig, input_ids,
     paged-pool interpretation under ``block_tables`` and the
     ``paged_attn_kernel`` fused-decode switch. Training call signature
     unchanged."""
-    from deepspeed_tpu.models.gpt2 import _tied_logits
     if kv_cache is not None:
         if cache_position is None:
             cache_position = jnp.zeros((input_ids.shape[0],), jnp.int32)
@@ -343,9 +321,9 @@ def llama_forward(params, config: LlamaConfig, input_ids,
                                        kv_cache, cache_position, dtype,
                                        block_tables=block_tables,
                                        paged_attn_kernel=paged_attn_kernel)
-        return _tied_logits(x, params["lm_head"], dtype), cache
+        return tied_logits(x, params["lm_head"], dtype), cache
     x = _llama_trunk(params, config, input_ids, dtype=dtype, remat=remat)
-    return _tied_logits(x, params["lm_head"], dtype)
+    return tied_logits(x, params["lm_head"], dtype)
 
 
 def _gqa_cached_attention(kcache, vcache, pos, out_box):
@@ -365,9 +343,6 @@ def llama_generate(params, config: LlamaConfig, prompt_ids,
     inference payoff: cache memory is kv_heads/heads of the MHA cache).
     Same contract as :func:`deepspeed_tpu.models.gpt2.gpt2_generate`;
     decode is one ``lax.scan``."""
-    from deepspeed_tpu.models.gpt2 import (_tied_logits, layer_params,
-                                           make_token_sampler,
-                                           run_decode_scan)
     B, Pl = prompt_ids.shape
     if max_new_tokens <= 0:
         return prompt_ids
@@ -401,7 +376,7 @@ def llama_generate(params, config: LlamaConfig, prompt_ids,
         kc = kc.at[i, :, :, :Pl].set(k.astype(dtype))
         vc = vc.at[i, :, :, :Pl].set(v.astype(dtype))
     x = rms_norm(x, params["ln_f"]["w"], config.rms_norm_eps)
-    last_logits = _tied_logits(x[:, -1:], params["lm_head"], dtype)[:, 0]
+    last_logits = tied_logits(x[:, -1:], params["lm_head"], dtype)[:, 0]
 
     if rng is None:
         rng = jax.random.PRNGKey(0)
@@ -424,7 +399,7 @@ def llama_generate(params, config: LlamaConfig, prompt_ids,
             new_kc.append(ki)
             new_vc.append(vi)
         x = rms_norm(x, params["ln_f"]["w"], config.rms_norm_eps)
-        logits = _tied_logits(x, params["lm_head"], dtype)[:, 0]
+        logits = tied_logits(x, params["lm_head"], dtype)[:, 0]
         return logits, (jnp.stack(new_kc), jnp.stack(new_vc))
 
     gen = run_decode_scan(step_logits, sample, first_tok, (kc, vc),
@@ -438,12 +413,11 @@ def llama_loss_fn(config: LlamaConfig, dtype=jnp.bfloat16,
     next-token cross entropy via the chunked fused head. The family has
     no dropout (llama recipe), so ``deterministic`` is accepted for
     engine-contract parity and ignored."""
-    from deepspeed_tpu.models.gpt2 import _tied_xent_chunked
 
     def loss_fn(params, batch, rng):
         del rng
         ids = batch["input_ids"]
         inputs, targets = ids[:, :-1], ids[:, 1:]
         x = _llama_trunk(params, config, inputs, dtype=dtype, remat=remat)
-        return _tied_xent_chunked(x, params["lm_head"], targets, dtype)
+        return tied_xent_chunked(x, params["lm_head"], targets, dtype)
     return loss_fn

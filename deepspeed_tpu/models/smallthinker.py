@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.models.gpt2 import _tied_xent_chunked, _wd
+from deepspeed_tpu.models.gpt2 import cast_weight, tied_xent_chunked
 from deepspeed_tpu.models.llama import apply_rope, rope_cos_sin
 from deepspeed_tpu.ops.attention.flash import flash_attention
 from deepspeed_tpu.ops.functional import rms_norm
@@ -123,9 +123,9 @@ def _attention_half(lp, config: SmallThinkerConfig, layer: int, x, dtype):
                                 config.experts_per_token)
     ap = lp["attn"]
     with scope("attn_proj"):
-        q = (h @ _wd(ap["wq"], dtype)).reshape(B, S, H, hd)
-        k = (h @ _wd(ap["wk"], dtype)).reshape(B, S, hkv, hd)
-        v = (h @ _wd(ap["wv"], dtype)).reshape(B, S, hkv, hd)
+        q = (h @ cast_weight(ap["wq"], dtype)).reshape(B, S, H, hd)
+        k = (h @ cast_weight(ap["wk"], dtype)).reshape(B, S, hkv, hd)
+        v = (h @ cast_weight(ap["wv"], dtype)).reshape(B, S, hkv, hd)
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         if config.rope_layout[layer]:
             cos, sin = rope_cos_sin(S, hd, config.rope_theta)
@@ -138,7 +138,7 @@ def _attention_half(lp, config: SmallThinkerConfig, layer: int, x, dtype):
                 window=config.sliding_window_size if windowed else None)
     with scope("attn_proj"):
         ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
-        x = x + ctx @ _wd(ap["wo"], dtype)
+        x = x + ctx @ cast_weight(ap["wo"], dtype)
     return x, (idx, p)
 
 
@@ -147,7 +147,7 @@ def _expert_half(lp, config: SmallThinkerConfig, x, idx, p, dtype):
     held expert)."""
     B, S, hdim = x.shape
     h2 = _norm(x, lp["ln_2"], config.rms_norm_eps)
-    experts = {name: _wd(table, dtype)
+    experts = {name: cast_weight(table, dtype)
                for name, table in lp["experts"].items()}
     y, counts = dropless_experts(
         h2.reshape(B * S, hdim), idx, p, experts, config.held,
@@ -182,7 +182,7 @@ def smallthinker_logits(params, config: SmallThinkerConfig, input_ids,
     x, facts = smallthinker_trunk(params, config, input_ids, dtype)
     with scope("lm_head"):
         logits = jax.lax.dot_general(
-            x[:, positions], _wd(params["lm_head"], dtype),
+            x[:, positions], cast_weight(params["lm_head"], dtype),
             (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32)
     return logits, facts
 
@@ -195,7 +195,7 @@ def smallthinker_loss_fn(config: SmallThinkerConfig, dtype=jnp.bfloat16):
     def loss_fn(params, batch):
         ids = batch["input_ids"]
         x, facts = smallthinker_trunk(params, config, ids[:, :-1], dtype)
-        loss = _tied_xent_chunked(x, params["lm_head"], ids[:, 1:], dtype)
+        loss = tied_xent_chunked(x, params["lm_head"], ids[:, 1:], dtype)
         return loss, {"moe_counts": facts["moe_counts"]}
     loss_fn.owns_cast = True
     return loss_fn

@@ -40,14 +40,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.models.gpt2 import (paged_attend, paged_write_index,
-                                       write_paged_layer)
-from deepspeed_tpu.models.llama import _gqa_stripe_attention
+from deepspeed_tpu.models.served_trunk import (ServedFamily, _mm,
+                                               paged_pair_mixer,
+                                               served_forward,
+                                               whole_leaf_specs)
 from deepspeed_tpu.ops.attention.flash import flash_attention
+from deepspeed_tpu.ops.attention.page_pool import (gqa_stripe_attention,
+                                                   paged_attend,
+                                                   write_paged_layer)
 from deepspeed_tpu.ops.functional import rms_norm
 from deepspeed_tpu.ops.kda import kda_chunk_scan, kda_decode_update
-from deepspeed_tpu.ops.moe import (held_experts_every_row, route_top_k,
-                                   served_experts)
+from deepspeed_tpu.ops.moe import route_top_k
 from deepspeed_tpu.profiling.spans import scope
 
 # caps of the grouped products' tile at these experts' widths (4,096 x
@@ -205,38 +208,14 @@ def init_solar_open2_params(config: SolarOpen2Config, key,
 
 
 def solar_open2_param_specs(config: SolarOpen2Config):
-    """Only the single-device engine serves this family
-    (``inference/engine.py`` refuses a serving mesh): every leaf whole."""
-    from jax.sharding import PartitionSpec as P
-    return jax.tree_util.tree_map(
-        lambda _: P(), jax.eval_shape(
-            lambda: init_solar_open2_params(config, jax.random.PRNGKey(0))))
-
-
-def _norm(x, w, eps):
-    with scope("ln"):
-        return rms_norm(x, w, eps)
-
-
-def _mm(x, w, dtype):
-    return jnp.dot(x.astype(dtype), w.astype(dtype),
-                   preferred_element_type=jnp.float32)
-
-
-class _Pages(NamedTuple):
-    """Where a softmax layer's keys and values go and come from."""
-    pools: Any
-    layer: int          # among the softmax layers
-    tables: Any
-    positions: Any
-    index: Any          # gpt2.paged_write_index's, shared by the layers
-    reader: str
+    """Every leaf whole (``served_trunk.whole_leaf_specs``)."""
+    return whole_leaf_specs(init_solar_open2_params, config)
 
 
 def _softmax_mixer(ap, config, h, dtype, cache):
     """Gated softmax attention of one layer on ``h`` (B, S, H). ``cache``
-    None (no pages: the plain forward) or :class:`_Pages`; returns (y,
-    the pools)."""
+    None (no pages: the plain forward) or ``served_trunk._Pages``;
+    returns (y, the pools)."""
     B, S, _ = h.shape
     H, hkv, hd = config.num_heads, config.num_kv_heads, config.head_dim
     with scope("attn_proj"):
@@ -250,7 +229,7 @@ def _softmax_mixer(ap, config, h, dtype, cache):
         box = []
         ctx = paged_attend(q, k, v, cache.pools, cache.layer, cache.tables,
                            cache.positions, cache.index, box, cache.reader,
-                           _gqa_stripe_attention)
+                           gqa_stripe_attention)
         pools = box[0]
     else:
         if cache is not None:
@@ -272,29 +251,31 @@ def _unit(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
 
 
-def _kda_mixer(kp, config, h, dtype, lengths, cache):
-    """Gated delta-rule attention of one layer on ``h`` (B, S, H).
-    ``cache`` None (the plain forward: an empty state, nothing kept) or
-    (state pool, tail pool, pool layer, slots); ``lengths`` (B,) the
-    rows' true lengths or None. Returns (y, (state pool, tail pool))."""
+def _kda_mixer(lp, h, call, cache, n):
+    """Gated delta-rule attention of one layer on ``h`` (B, S, H), a
+    mixer of ``models/served_trunk.py``: the ``n``-th recurrent layer
+    over the tree's ``state`` and ``tails`` (``cache`` None, the plain
+    forward: an empty state, nothing kept), a served prefill to
+    ``call.lengths`` and into rows ``call.slots``."""
+    kp, config, dtype, lengths = (lp["kda"], call.config, call.dtype,
+                                  call.lengths)
     B, S, _ = h.shape
     nh, hd, cw = (config.kda_num_heads, config.kda_head_dim,
                   config.kda_conv_width)
     decode = cache is not None and S == 1
     with scope("kda_proj"):
-        raw = jnp.concatenate([_mm(h, kp[n], dtype).astype(dtype)
-                               for n in ("wq", "wk", "wv")], axis=-1)
+        raw = jnp.concatenate([_mm(h, kp[w], dtype).astype(dtype)
+                               for w in ("wq", "wk", "wv")], axis=-1)
         if decode:
-            state, tails, layer, _ = cache
-            window = jnp.concatenate([tails[layer], raw], axis=1)
+            window = jnp.concatenate([cache.tails[n], raw], axis=1)
             tail = window[:, 1:]
         else:
             window = jnp.pad(raw, ((0, 0), (cw - 1, 0), (0, 0)))
             if cache is not None:
                 # the last inputs before each row's TRUE length (zeros
                 # before position 0)
-                tail = jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
-                    w, n, cw - 1))(window, lengths)
+                tail = jax.vmap(lambda w, m: jax.lax.dynamic_slice_in_dim(
+                    w, m, cw - 1))(window, lengths)
         # conv[c, j] weighs the input j - (cw - 1) positions back
         taps = jnp.concatenate(list(kp["conv"].astype(jnp.float32)), -1)
         mixed = sum(window[:, j:j + S].astype(jnp.float32) * taps[j]
@@ -308,72 +289,44 @@ def _kda_mixer(kp, config, h, dtype, lengths, cache):
         beta = 2.0 * jax.nn.sigmoid(_mm(h, kp["wb"], dtype))
         gate = jax.nn.sigmoid(
             _mm(_mm(h, kp["wg1"], dtype), kp["wg2"], dtype) + kp["b_g"])
-    pools = None
     if decode:
         with scope("kda_state"):
             o, state = kda_decode_update(
-                state, layer, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                cache.state, n, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
                 beta[:, 0])
             o = o[:, None]
-            tails = tails.at[layer].set(tail)
-        pools = (state, tails)
+            tails = cache.tails.at[n].set(tail)
     else:
         with scope("kda_scan"):
             o, last = kda_chunk_scan(
                 q, k, v, g, beta, jnp.zeros((B, nh, hd, hd), jnp.float32),
                 lengths)
         if cache is not None:
-            state, tails, layer, slots = cache
+            assert call.slots is not None, \
+                "a served prefill needs each row's slot"
             with scope("kda_state"):
-                pools = (state.at[layer, slots].set(last),
-                         tails.at[layer, slots].set(tail))
+                state = cache.state.at[n, call.slots].set(last)
+                tails = cache.tails.at[n, call.slots].set(tail)
+    if cache is not None:
+        cache = cache._replace(state=state, tails=tails)
     with scope("kda_proj"):
         o = rms_norm(o, kp["norm"], config.rms_norm_eps).reshape(B, S, -1)
-        return _mm((o * gate).astype(dtype), kp["wo"], dtype), pools
+        return _mm((o * gate).astype(dtype), kp["wo"], dtype), cache
 
 
-def _expert_half(lp, config, x, dtype, active, lengths):
-    """x -> (x + routed + shared, a pair int32 of this layer). One token
-    a row (decode) works every held expert on every row, and the pair is
-    (landed, fullest): assignments of ``active`` rows that fell on held
-    experts, and the fullest held expert's. A bucket of prompts goes
-    through ``ops.moe.served_experts``, whose work follows the
-    assignments that landed here at a TRUE position (``lengths`` (B,);
-    None, the plain forward: every position), and the pair is (rows its
-    turns worked, rows the dropless layer's static turns would have).
-    Not ``dropless_experts``: that is the TRAINED layer, whose time must
-    not follow the router and whose turns differentiate; what a padded
-    position's experts give is read by nothing (causal attention, a scan
-    to the true lengths, the logits of the last true position)."""
-    B, S, hdim = x.shape
-    h2 = _norm(x, lp["ln_2"]["w"], config.rms_norm_eps)
-    flat = h2.reshape(B * S, hdim)
-    with scope("moe_route"):
+def _family(config: SolarOpen2Config) -> ServedFamily:
+    def route(flat, router):
         # softmax over all the experts, the eight largest, renormalised
         # to sum 1: the softmax over those eight's own scores
-        idx, p, _ = route_top_k(flat, lp["router"],
-                                config.experts_per_token)
-        p = p * config.routed_scaling_factor
-    experts = {n: t.astype(dtype) for n, t in lp["experts"].items()}
-    rows = flat.astype(dtype)
-    if S == 1:
-        y, counts = held_experts_every_row(
-            rows, idx, p, experts, config.held, jax.nn.silu, active)
-        pair = jnp.stack([jnp.sum(counts), jnp.max(counts)])
-    else:
-        counted = None if lengths is None else (
-            jnp.arange(S) < lengths[:, None]).reshape(B * S)
-        y, _, pair = served_experts(
-            rows, idx, p, experts, config.held, config.num_experts,
-            jax.nn.silu, tile=_EXPERT_TILE, counted=counted)
-    with scope("moe_shared"):
-        sp = lp["shared"]
-        act = jax.nn.silu(_mm(flat, sp["w_gate"], dtype)) * _mm(
-            flat, sp["w_up"], dtype)
-        y = y + _mm(act, sp["w_down"], dtype)
-    with scope("moe_dispatch"):
-        x = x + y.reshape(B, S, hdim)
-    return x, pair
+        idx, p, _ = route_top_k(flat, router, config.experts_per_token)
+        return idx, p * config.routed_scaling_factor, None
+
+    return ServedFamily(
+        layers=tuple(("softmax" if l in config.gqa_layers else "kda",
+                      "experts") for l in range(config.num_layers)),
+        mixers={"softmax": paged_pair_mixer(_softmax_mixer),
+                "kda": _kda_mixer},
+        route=route, expert_tile=_EXPERT_TILE)
 
 
 def solar_open2_forward(params, config: SolarOpen2Config, input_ids,
@@ -402,56 +355,10 @@ def solar_open2_forward(params, config: SolarOpen2Config, input_ids,
     assignments landed on held experts and its fullest held expert's,
     counted over the ``active`` (B,) rows; in PREFILL the rows each
     layer's expert turns worked and the rows static turns would have."""
-    B, S = input_ids.shape
-    serving = kv_cache is not None
-    if serving:
-        pools = (kv_cache.keys, kv_cache.values)
-        state, tails = kv_cache.state, kv_cache.tails
-        if cache_position is None:
-            cache_position = jnp.zeros((B,), jnp.int32)
-        index = paged_write_index(block_tables, cache_position, S,
-                                  pools[0].shape[2])
-        if S > 1:
-            assert lengths is not None and slots is not None, \
-                "a served prefill needs each row's length and slot"
-    with scope("embed"):
-        x = params["tok_emb"][input_ids].astype(jnp.float32)
-    counts = []
-    n_soft = n_rec = 0
-    for l in range(config.num_layers):
-        lp = params[f"h_{l}"]
-        h = _norm(x, lp["ln_1"]["w"], config.rms_norm_eps)
-        if l in config.gqa_layers:
-            y, new = _softmax_mixer(
-                lp["attn"], config, h, dtype,
-                _Pages(pools, n_soft, block_tables, cache_position, index,
-                       paged_attn_kernel) if serving else None)
-            pools = new if serving else None
-            n_soft += 1
-        else:
-            y, new = _kda_mixer(
-                lp["kda"], config, h, dtype, lengths,
-                (state, tails, n_rec, slots) if serving else None)
-            if serving:
-                state, tails = new
-            n_rec += 1
-        x = x + y
-        x, c = _expert_half(lp, config, x, dtype, active, lengths)
-        counts.append(c)
-    x = _norm(x, params["ln_f"]["w"], config.rms_norm_eps)
-    if serving and S > 1:
-        x = x[jnp.arange(B), lengths - 1][:, None]
-    with scope("lm_head"):
-        logits = jax.lax.dot_general(
-            x.astype(dtype), params["lm_head"].astype(dtype),
-            (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    if not serving:
-        return logits
-    cache = kv_cache._replace(keys=pools[0], values=pools[1], state=state,
-                              tails=tails)
-    if with_counts:
-        return logits, cache, jnp.stack(counts).astype(jnp.int32)
-    return logits, cache
+    return served_forward(_family(config), params, config, input_ids, dtype,
+                          kv_cache, cache_position, block_tables,
+                          paged_attn_kernel, lengths, slots, active,
+                          with_counts)
 
 
 def solar_open2_param_count(config: SolarOpen2Config):

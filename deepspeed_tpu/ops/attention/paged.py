@@ -3,9 +3,9 @@
 The paged KV cache (PR 7, ``inference/kv_cache.py``) made serving
 *capacity* paged, but the decode step still materialized each row's
 full ``max_len``-bounded K/V stripe through
-:func:`~deepspeed_tpu.models.gpt2.gather_paged_kv` before running dense
-attention — per-step decode bandwidth stayed O(max_len) regardless of
-how many tokens were actually in flight. This module is the missing
+:func:`~deepspeed_tpu.ops.attention.page_pool.gather_paged_kv` before
+running dense attention — per-step decode bandwidth stayed O(max_len)
+regardless of how many tokens were actually in flight. This module is the missing
 half of that design (vLLM's PagedAttention, PAPERS.md, fused with the
 flash online-softmax core this repo already carries in
 ``ops/attention/flash.py``): a Pallas TPU kernel that computes decode

@@ -461,7 +461,7 @@ def _ring_prefill_shard(q, kc, vc, cache_position, axis_name, P, Sl, Ll,
     shard_map): my Q block stays resident while K/V stripe blocks
     rotate around the ring; each visit contributes a normalized fp32
     partial (o_j, lse_j) masked by the ABSOLUTE-position causal rule of
-    ``models/gpt2.causal_cache_mask`` — q position ``cache_position +
+    ``page_pool.causal_cache_mask`` — q position ``cache_position +
     global_q_idx`` attends stripe slots ``<=`` it — and partials merge
     with the exact online-softmax combine. GQA runs group-wise like
     the llama gather fallback (q heads fold onto their kv head)."""

@@ -34,7 +34,7 @@ wraps:
   models' kernel call sites without widening every forward signature:
   an engine traces its compiled programs under the context,
   ``ops/attention/flash.flash_attention`` and
-  ``models/gpt2.paged_decode_ctx`` consult it.
+  ``page_pool.paged_decode_ctx`` consult it.
 
 Head-axis legality mirrors the PR 7 cache sharding: the mesh axis must
 divide q heads AND kv heads (each shard then owns whole GQA groups, so
@@ -71,7 +71,7 @@ _CP_ACTIVE: list = []       # context-parallel prefill stack (ISSUE 19)
 def pallas_kernel_mesh(mesh: Optional[Mesh], axis: str = "model",
                        batch_axes: Tuple[str, ...] = ()):
     """Trace-time context: while active, mesh-aware kernel call sites
-    (``flash_attention``, ``models/gpt2.paged_decode_ctx``) wrap their
+    (``flash_attention``, ``page_pool.paged_decode_ctx``) wrap their
     Pallas kernels in shard_map over the mesh — heads over ``axis``,
     batch over ``batch_axes`` (the training engine's data axes; the
     serving engines shard heads only). ``mesh=None`` (or axes that are

@@ -23,9 +23,10 @@ import jax
 import numpy as np
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.models.llama import (LlamaConfig, count_params,
-                                        init_llama_params, llama_generate,
-                                        llama_loss_fn, llama_param_specs)
+from deepspeed_tpu.models.gpt2 import count_params
+from deepspeed_tpu.models.llama import (LlamaConfig, init_llama_params,
+                                        llama_generate, llama_loss_fn,
+                                        llama_param_specs)
 
 # ~1B-class config (llama-style ratios, GQA 4:1)
 LLAMA_1B = dict(vocab_size=32128, hidden_size=2048, num_layers=16,

@@ -291,7 +291,8 @@ def test_the_published_score_scale_reaches_both_attention_readers():
     pages the prefill wrote): the attention layer alone against the
     reference's, which at head_dim ** -0.5 = 0.25 in place of the
     published 0.0625 either reader fails."""
-    from deepspeed_tpu.models.gpt2 import paged_write_index
+    from deepspeed_tpu.models.served_trunk import _Pages
+    from deepspeed_tpu.ops.attention.page_pool import paged_write_index
     cfg = TINY
     ks = jax.random.split(jax.random.PRNGKey(12), 5)
     n = lambda k, *shape: jax.random.normal(k, shape, jnp.float32)
@@ -313,7 +314,7 @@ def test_the_published_score_scale_reaches_both_attention_readers():
             with jax.default_matmul_precision("highest"):
                 y, pools = gh._softmax_mixer(
                     ap, config, h[:, lo:hi], jnp.float32,
-                    gh._Pages(pools, 0, tables, at, index, "pallas"))
+                    _Pages(pools, 0, tables, at, index, "pallas"))
             out.append(y)
         return out
 
@@ -334,37 +335,38 @@ def test_the_score_scale_is_handed_to_the_two_kernels(monkeypatch):
     `paged_decode_attention` and `flash_attention` (left out it stays
     None: each kernel's own head_dim ** -0.5, the other families'
     programs as they were)."""
-    from deepspeed_tpu.models import gpt2
-    from deepspeed_tpu.ops.attention import paged
+    from deepspeed_tpu.ops.attention import page_pool
     seen = []
-    plain_decode, plain_flash = paged.paged_decode_attention, \
-        gpt2.flash_attention
+    plain_decode, plain_flash = page_pool.paged_decode_attention, \
+        page_pool.flash_attention
     monkeypatch.setattr(
-        paged, "paged_decode_attention",
+        page_pool, "paged_decode_attention",
         lambda *a, sm_scale=None, **kw: seen.append(("decode", sm_scale))
         or plain_decode(*a, sm_scale=sm_scale, **kw))
     monkeypatch.setattr(
-        gpt2, "flash_attention",
+        page_pool, "flash_attention",
         lambda *a, sm_scale=None, **kw: seen.append(("flash", sm_scale))
         or plain_flash(*a, sm_scale=sm_scale, **kw))
-    monkeypatch.setattr(gpt2, "_OWN_KEYS_DENSE_SCORES", 0)
-    gpt2._own_keys.clear_cache()
+    monkeypatch.setattr(page_pool, "_OWN_KEYS_DENSE_SCORES", 0)
+    page_pool._own_keys.clear_cache()
     q = jax.random.normal(jax.random.PRNGKey(0), (1, 4, 16, 16))
     kv = jax.random.normal(jax.random.PRNGKey(1), (1, 2, 16, 16))
     zero = jnp.zeros((1,), jnp.int32)
     stripe = gh._stripe_attention_at(0.0625)
-    got = gpt2.own_keys_attention(q, kv, kv, zero, stripe, sm_scale=0.0625)
+    got = page_pool.own_keys_attention(q, kv, kv, zero, stripe,
+                                       sm_scale=0.0625)
     # the flash kernel (in the interpreter) at the handed scale is the
     # stripe mathematics at it
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(stripe(q, kv, kv, zero)), atol=2e-5)
     pools = (jnp.zeros((1, 4, 16, 32)), jnp.zeros((1, 4, 16, 32)))
-    gpt2.paged_attend(q[:, :, :1], kv[:, :, :1], kv[:, :, :1], pools, 0,
-                      jnp.asarray([[1, 2]], jnp.int32), zero,
-                      gpt2.PagedWriteIndex(jnp.asarray([1]), jnp.asarray([0]),
-                                           None, None), [], "pallas",
-                      stripe, sm_scale=0.0625)
-    gpt2._own_keys.clear_cache()
+    page_pool.paged_attend(
+        q[:, :, :1], kv[:, :, :1], kv[:, :, :1], pools, 0,
+        jnp.asarray([[1, 2]], jnp.int32), zero,
+        page_pool.PagedWriteIndex(jnp.asarray([1]), jnp.asarray([0]), None,
+                                  None), [], "pallas", stripe,
+        sm_scale=0.0625)
+    page_pool._own_keys.clear_cache()
     assert seen == [("flash", 0.0625), ("decode", 0.0625)]
 
 

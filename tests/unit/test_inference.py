@@ -235,7 +235,8 @@ class TestScheduler:
 # --------------------------------------------------------------------- #
 class TestCachedForward:
     def test_causal_cache_mask(self):
-        from deepspeed_tpu.models.gpt2 import causal_cache_mask
+        from deepspeed_tpu.ops.attention.page_pool import \
+            causal_cache_mask
         m = np.asarray(causal_cache_mask(jnp.asarray([0, 2]), 2, 5))
         assert m.shape == (2, 1, 2, 5)
         # row 0 at offset 0: query j attends k <= j

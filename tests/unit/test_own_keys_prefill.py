@@ -1,7 +1,7 @@
 # Copyright The DeepSpeed-TPU authors. Licensed under Apache 2.0.
 """A prompt that starts at position 0 attends to its own keys (ISSUE 40).
 
-``models/gpt2.paged_attend`` (shared by ``models/llama.py``) writes the
+``ops/attention/page_pool.paged_attend`` (every family's) writes the
 pool and then, for a query of many rows, picks its reader from what the
 call shows:
 
@@ -24,9 +24,9 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.inference import InferenceEngine
-from deepspeed_tpu.models import gpt2
 from deepspeed_tpu.models.gpt2 import gpt2_forward
 from deepspeed_tpu.models.llama import llama_forward
+from deepspeed_tpu.ops.attention import page_pool
 from tests.unit.test_inference import tiny_gpt2, tiny_llama
 
 ROWS, SEQ, PAGE, TABLE = 4, 16, 4, 8       # a table of 32 positions
@@ -89,12 +89,12 @@ def _branches(run):
 
 def _stripe_only(monkeypatch):
     """The parent's program: no call is wide enough for its own keys."""
-    monkeypatch.setattr(gpt2, "_OWN_KEYS_ROWS", 1 << 30)
+    monkeypatch.setattr(page_pool, "_OWN_KEYS_ROWS", 1 << 30)
 
 
 def _poison_own_keys(monkeypatch):
     """Whatever attends to its own keys reads NaN."""
-    monkeypatch.setattr(gpt2, "own_keys_attention",
+    monkeypatch.setattr(page_pool, "own_keys_attention",
                         lambda q, *_: jnp.full_like(q, jnp.nan))
 
 
@@ -104,7 +104,7 @@ def form(request, monkeypatch):
     stripe mathematics (what a shape this small gets) and the flash
     kernel (forced: no score matrix is small enough)."""
     if request.param == "flash":
-        monkeypatch.setattr(gpt2, "_OWN_KEYS_DENSE_SCORES", 0)
+        monkeypatch.setattr(page_pool, "_OWN_KEYS_DENSE_SCORES", 0)
     return request.param
 
 
@@ -247,7 +247,7 @@ def test_a_serving_mesh_keeps_both_branches(monkeypatch):
     the conditional and the flash kernel inside it (shard_mapped by
     ``pallas_kernel_mesh``) give the one-device engine's tokens, with
     and without a prefixed row."""
-    monkeypatch.setattr(gpt2, "_OWN_KEYS_DENSE_SCORES", 0)
+    monkeypatch.setattr(page_pool, "_OWN_KEYS_DENSE_SCORES", 0)
     inf = dict(INF, paged_kv={"page_size": 8, "num_pages": 24})
     assert _generate("llama", dict(inf, mesh={"axes": {"model": 2}})) \
         == _generate("llama", inf)

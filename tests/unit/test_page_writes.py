@@ -1,8 +1,9 @@
 # Copyright The DeepSpeed-TPU authors. Licensed under Apache 2.0.
 """A prefill that starts on a page boundary writes whole pages (ISSUE 44).
 
-``models/gpt2.write_paged_kv_cache`` (every family's pool write) picks
-the granularity of its scatter's index from what the call shows:
+``ops/attention/page_pool.write_paged_kv_cache`` (every family's pool
+write) picks the granularity of its scatter's index from what the call
+shows:
 
 - a width of whole pages (``S % page_size == 0``, known when the program
   is traced) whose rows ALL start on a page boundary (read from the
@@ -25,10 +26,12 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.inference import InferenceEngine
-from deepspeed_tpu.models import axk1, gpt2
-from deepspeed_tpu.models.gpt2 import (PagedWriteIndex, paged_write_index,
-                                       write_paged_kv_cache,
-                                       write_paged_layer)
+from deepspeed_tpu.models import axk1
+from deepspeed_tpu.ops.attention import page_pool
+from deepspeed_tpu.ops.attention.page_pool import (PagedWriteIndex,
+                                                   paged_write_index,
+                                                   write_paged_kv_cache,
+                                                   write_paged_layer)
 from tests.unit.test_inference import tiny_gpt2, tiny_llama
 from tests.unit.test_paged_attention import _np_write, _quantize_pools
 
@@ -207,11 +210,11 @@ def test_a_width_that_is_not_whole_pages_traces_one_branch(kind, width, why):
 # ------------------------------------------------------------ the engines
 def _parent_writes(monkeypatch):
     """The parent's programs: every write an index a token row."""
-    real = gpt2.write_paged_kv_cache
+    real = page_pool.write_paged_kv_cache
 
     def rows(pool, layer, new, index):
         return real(pool, layer, new, _rows_only(index))
-    monkeypatch.setattr(gpt2, "write_paged_kv_cache", rows)
+    monkeypatch.setattr(page_pool, "write_paged_kv_cache", rows)
     monkeypatch.setattr(axk1, "write_paged_kv_cache", rows)
 
 

@@ -634,7 +634,8 @@ class TestEngineParity:
 def _plain_stripe_attention(q, kc, vc, cache_position):
     """The gather path's stripe math as it was before ISSUE 25: every
     operand upcast to float32, whatever the query's rows."""
-    from deepspeed_tpu.models.gpt2 import NEG_INF, causal_cache_mask
+    from deepspeed_tpu.ops.attention.flash import NEG_INF
+    from deepspeed_tpu.ops.attention.page_pool import causal_cache_mask
     scores = jnp.einsum("bhqd,bhld->bhql", q.astype(jnp.float32),
                         kc.astype(jnp.float32)) / np.sqrt(q.shape[-1])
     mask = causal_cache_mask(cache_position, q.shape[2], kc.shape[2])
@@ -660,8 +661,8 @@ class TestGatherSeq1Path:
         """Ragged positions, a one-token cache, a table mapped only as
         far as it is used, the last position of the table, and an
         inactive slot whose table is all null page."""
-        from deepspeed_tpu.models.gpt2 import (_paged_cache_attention,
-                                               paged_write_index)
+        from deepspeed_tpu.models.gpt2 import _paged_cache_attention
+        from deepspeed_tpu.ops.attention.page_pool import paged_write_index
         from deepspeed_tpu.ops.attention.paged import \
             paged_decode_reference
         rng = np.random.RandomState(25)
@@ -785,9 +786,8 @@ class TestPoolRoundTrip:
         other row of the pool — the other layer's too — is bit for bit
         what it was, and the gather returns the plain gather's
         stripe."""
-        from deepspeed_tpu.models.gpt2 import (gather_paged_kv,
-                                               paged_write_index,
-                                               write_paged_kv_cache)
+        from deepspeed_tpu.ops.attention.page_pool import (
+            gather_paged_kv, paged_write_index, write_paged_kv_cache)
         kpool, _, tables, positions, k, _ = self._case(28, positions,
                                                        tokens)
         index = paged_write_index(jnp.asarray(tables),
@@ -805,8 +805,8 @@ class TestPoolRoundTrip:
         """A decode position at the table's extent, a prefill that runs
         over it, and a row whose table is unreserved (all 0): what has
         no page goes to null page 0 and no live page is touched."""
-        from deepspeed_tpu.models.gpt2 import (paged_write_index,
-                                               write_paged_kv_cache)
+        from deepspeed_tpu.ops.attention.page_pool import (
+            paged_write_index, write_paged_kv_cache)
         extent = self.PS * self.PAGES
         kpool, _, tables, positions, k, _ = self._case(
             29, [extent - 2, extent, 1], 3)
@@ -828,9 +828,8 @@ class TestPoolRoundTrip:
         """The int8 4-tuple: payload rows and scale rows
         (``kv_heads * scale_blocks`` wide) land through the same index,
         and the gathered stripe is the dequantized plain gather."""
-        from deepspeed_tpu.models.gpt2 import (gather_paged_layer,
-                                               paged_write_index,
-                                               write_paged_layer)
+        from deepspeed_tpu.ops.attention.page_pool import (
+            gather_paged_layer, paged_write_index, write_paged_layer)
         from deepspeed_tpu.ops.attention.paged import (dequantize_pool,
                                                        quantize_kv)
         kpool, vpool, tables, positions, k, v = self._case(
@@ -869,7 +868,7 @@ class TestPoolRoundTrip:
         from deepspeed_tpu.inference import InferenceEngine
         from deepspeed_tpu.inference.kv_cache import (init_paged_kv_cache,
                                                       paged_spec_for)
-        from deepspeed_tpu.models.gpt2 import gather_paged_kv
+        from deepspeed_tpu.ops.attention.page_pool import gather_paged_kv
         cfg, _ = tiny_gpt2()
         dtype = jnp.int8 if kv_dtype == "int8" else jnp.bfloat16
         spec = paged_spec_for(cfg, 9, 4, 16, dtype=dtype)

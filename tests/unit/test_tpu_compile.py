@@ -438,6 +438,7 @@ def _cached_reader(cache, q_rows, cache_dtype):
     forward runs them: ``(fn, specs)`` for the paged pool or the
     contiguous cache at the cell's shapes."""
     from deepspeed_tpu.models import gpt2
+    from deepspeed_tpu.ops.attention.page_pool import paged_write_index
 
     qkv = _spec((ROWS, HEADS, q_rows, HEAD_DIM))
     positions = _spec((ROWS,), jnp.int32)
@@ -446,7 +447,7 @@ def _cached_reader(cache, q_rows, cache_dtype):
 
         def fn(q, k, v, kpool, vpool, tables, pos):
             box = []
-            index = gpt2.paged_write_index(tables, pos, q_rows, PAGE)
+            index = paged_write_index(tables, pos, q_rows, PAGE)
             out = gpt2._paged_cache_attention(
                 (kpool, vpool), 0, tables, pos, index, box)(
                     q, k, v, 0.0, None)

@@ -197,7 +197,8 @@ def test_scopes_leave_the_program_set_and_recompiles_alone(monkeypatch):
     scoped = programs()
     from deepspeed_tpu.inference import engine as serve_engine
     from deepspeed_tpu.models import gpt2
-    for module in (gpt2, serve_engine):
+    from deepspeed_tpu.ops.attention import page_pool
+    for module in (gpt2, page_pool, serve_engine):
         monkeypatch.setattr(module, "scope",
                             lambda name: contextlib.nullcontext())
     bare = programs()
