@@ -98,7 +98,8 @@ from deepspeed_tpu.inference.disagg import (DispatchTrace, HandoffQueue,
                                             MigrationRecord,
                                             price_handoff)
 from deepspeed_tpu.inference.draft import make_drafter
-from deepspeed_tpu.inference.kv_cache import (PageAllocator, PagedStateCache,
+from deepspeed_tpu.inference.kv_cache import (LatentStateCache,
+                                              PageAllocator, PagedStateCache,
                                               cache_spec_for,
                                               init_kv_cache,
                                               init_paged_kv_cache,
@@ -117,6 +118,10 @@ from deepspeed_tpu.models.gpt2 import (GPT2Config, gpt2_forward,
 from deepspeed_tpu.models.granite_hybrid import (
     GraniteHybridConfig, granite_hybrid_forward, granite_hybrid_param_specs,
     init_granite_hybrid_params)
+from deepspeed_tpu.models.kimi_linear import (KimiLinearConfig,
+                                              init_kimi_linear_params,
+                                              kimi_linear_forward,
+                                              kimi_linear_param_specs)
 from deepspeed_tpu.models.llama import (LlamaConfig, init_llama_params,
                                         llama_forward, llama_param_specs)
 from deepspeed_tpu.models.solar_open2 import (SolarOpen2Config,
@@ -153,6 +158,8 @@ _FAMILIES = {
                           init_granite_hybrid_params,
                           granite_hybrid_param_specs),
     AXK1Config: ("axk1", axk1_forward, init_axk1_params, axk1_param_specs),
+    KimiLinearConfig: ("kimi_linear", kimi_linear_forward,
+                       init_kimi_linear_params, kimi_linear_param_specs),
 }
 
 
@@ -364,13 +371,17 @@ class InferenceEngine:
         # beside the pages (inference/kv_cache.py)
         self.state_spec = state_pool_spec_for(
             model_config, cfg["max_batch_size"] + 1, tail_dtype=dtype)
-        if self.state_spec is not None:
-            self._refuse_what_state_cannot_follow(cfg, mesh)
         # a family with latent attention keeps ONE latent row a token
         # in place of keys and values (inference/kv_cache.py)
         self.latent = getattr(model_config, "latent_geometry",
                               None) is not None
-        if self.latent:
+        # ONE list a family: a family of both kinds of layer states in
+        # its config what its mixers can follow (chunked prefill)
+        if self.state_spec is not None and self.latent:
+            self._refuse_what_state_and_latent_rows_cannot_follow(cfg, mesh)
+        elif self.state_spec is not None:
+            self._refuse_what_state_cannot_follow(cfg, mesh)
+        elif self.latent:
             self._refuse_what_latent_rows_cannot_follow(cfg, mesh)
         # such families' prefill programs take each row's true length
         # and slot, and return the last true position's logits alone
@@ -600,7 +611,8 @@ class InferenceEngine:
             if self.state_spec is not None:
                 # two more leaves of the one cache tree, one row a slot,
                 # each leaf by name for the family's forward
-                self._cache = PagedStateCache(
+                tree = LatentStateCache if self.latent else PagedStateCache
+                self._cache = tree(
                     *self._cache, *init_state_pool(self.state_spec))
             # static pool cost per token of capacity — the
             # Serve/kv_pool_bytes_per_token gauge (int8 pools land
@@ -701,7 +713,18 @@ class InferenceEngine:
             self._decode = self._wrap_program(
                 self._decode_paged_impl, 7, "decode")
             self._verify = None
-            if self.latent:
+            if self.latent and self.state_spec is not None:
+                geom = (f"latent page pool: {self.paged_spec.num_pages} "
+                        f"pages x {self.paged_spec.page_size} tokens over "
+                        f"{self.paged_spec.num_layers} latent layers "
+                        f"({cache_bytes / 2**20:.1f} MiB), state pool "
+                        f"{self.state_spec.rows} rows over "
+                        f"{self.state_spec.num_layers} recurrent layers "
+                        f"({state_pool_bytes(self.state_spec) / 2**20:.1f}"
+                        f" MiB), chunked prefill "
+                        f"{self._chunk_tokens or 'off'}, decode attn "
+                        f"{self._decode_attn_path}")
+            elif self.latent:
                 geom = (f"latent page pool: {self.paged_spec.num_pages} "
                         f"pages x {self.paged_spec.page_size} tokens over "
                         f"{self.paged_spec.num_layers} layers, a row of "
@@ -796,7 +819,9 @@ class InferenceEngine:
             "quantized_weights": bool(cfg["quantize_weights"]),
             "mesh": mesh is not None or bool(cfg["mesh"]["axes"]),
         }
-        named = [reasons[what] for what, on in asked.items() if on]
+        # a feature with no entry is one the family follows
+        named = [reasons[what] for what, on in asked.items()
+                 if on and what in reasons]
         if named:
             raise ValueError(
                 f"{type(self.model_config).__name__} keeps {keeps} and "
@@ -854,6 +879,41 @@ class InferenceEngine:
             "its weights in bfloat16 as they are",
             "mesh": "a serving mesh: only the single-device engine "
             "serves this family"})
+
+    def _refuse_what_state_and_latent_rows_cannot_follow(self, cfg, mesh):
+        """A family whose every layer keeps either a per-slot state or a
+        latent row (``models/kimi_linear.py``): the ONE list of what it
+        is refused, by name. Chunked prefill is NOT on it where the
+        family's config says its mixers follow a chunk
+        (``serves_chunked_prefill``: a delta-rule layer starts from the
+        state and tail its slot holds, a latent layer reads its prefix
+        back from the pool); everything else the two kinds of layer
+        refuse apart stays refused (docs/kimi_linear.md)."""
+        reasons = {
+            "dense_cache": "the dense cache (paged_kv.enabled: false): "
+            "the state pool and the latent pool are leaves of the paged "
+            "cache tree",
+            "prefix_cache": "the prefix cache (paged_kv.prefix_cache): a "
+            "shared prefix's pages carry no recurrent state; it needs a "
+            "state snapshot at every shared page boundary",
+            "spec_decode": "speculative decoding: a rejected draft is "
+            "rolled back by position, and the state has already absorbed "
+            "it",
+            "disagg": "disaggregated prefill/decode: the handoff moves a "
+            "(keys, values) pair of pools, not a latent pool or a slot's "
+            "state row",
+            "int8_pool": "an int8 page pool: a latent row's scales have "
+            "no place in its one leaf",
+            "quantized_weights": "quantized weights: the family holds "
+            "its weights in bfloat16 as they are",
+            "mesh": "a serving mesh: only the single-device engine "
+            "serves this family"}
+        if not getattr(self.model_config, "serves_chunked_prefill", False):
+            reasons["chunked_prefill"] = (
+                "chunked prefill: the family's mixers start every row "
+                "from an empty state and their own rows")
+        self._refuse_asked(cfg, mesh, "a per-slot recurrent state and "
+                           "latent rows in its page pool", reasons)
 
     def _resolve_decode_attn(self, pk):
         """Pick the paged decode attention path once, at init (the
@@ -1746,12 +1806,17 @@ class InferenceEngine:
                         tables, keys, temps)
             ledger.issued()
             with self._span("serve/prefill/wait"):
-                first = np.asarray(first)
-                if len(first) > bb:     # the expert turns' rows ride behind
-                    self._moe_prefill_rows = (int(first[-2]),
-                                              int(first[-1]))
-                    first = first[:bb]
+                first = self._first_tokens(first, bb)
             return first, ledger.ready()
+
+    def _first_tokens(self, first, bb):
+        """A prefill program's result on the host (the sync): its ``bb``
+        first tokens; the expert turns' rows that ride behind them are
+        kept for the next span."""
+        first = np.asarray(first)
+        if len(first) > bb:
+            self._moe_prefill_rows = (int(first[-2]), int(first[-1]))
+        return first[:bb]
 
     def _drain_request_metrics(self):
         """Per-admitted-request scalar writes (TTFT / queue wait)
@@ -1842,8 +1907,29 @@ class InferenceEngine:
         ct = self._chunk_tokens
         shards = self._cp_shards if use_cp else 1
         prog = self._chunk_cp if use_cp else self._prefill
+        # what the rows read, as sums a reader can add up over spans:
+        # real tokens, the rows that start from what a predecessor left
+        # (a carried state, a prefix in the pool), and the pairs of
+        # (query, key) their attention spans over that prefix and over
+        # their own rows
+        spans = []
+        for sid in cand:
+            slot = sched.slots[sid]
+            start, n = sched.chunk_span(sid)
+            spans.append((sid, slot.request, start, n,
+                          (start - slot.prefix_len) // ct))
+        counters = dict(
+            rows=len(spans), real_tokens=sum(n for *_, n, _ in spans),
+            start_tokens=sum(st for _, _, st, _, _ in spans),
+            carried_rows=sum(st > 0 for _, _, st, _, _ in spans),
+            prefix_pairs=sum(st * n for _, _, st, n, _ in spans),
+            own_pairs=sum(n * n for *_, n, _ in spans))
+        if self._expert_counters is not None:
+            worked, static = self._moe_prefill_rows
+            counters.update(expert_rows_worked=worked,
+                            expert_rows_sorted=static)
         with self._span("serve/chunk", seq=ledger.total, step=self._steps,
-                        batch=bb, chunk=ct, cp_shards=shards):
+                        batch=bb, chunk=ct, cp_shards=shards, **counters):
             with self._span("serve/chunk/build"):
                 ids = np.zeros((bb, ct), np.int32)
                 lengths = np.ones((bb,), np.int32)
@@ -1851,22 +1937,25 @@ class InferenceEngine:
                 tables = np.zeros((bb, self._prefill_pps), np.int32)
                 keys = np.zeros((bb, 2), np.uint32)
                 temps = np.zeros((bb,), np.float32)
-                spans = []
-                for i, sid in enumerate(cand):
+                slots = np.full((bb,), self._scratch, np.int32)
+                for i, (sid, req, start, n, _) in enumerate(spans):
                     slot = sched.slots[sid]
-                    req = slot.request
-                    start, n = sched.chunk_span(sid)
-                    spans.append((sid, req, start, n,
-                                  (start - slot.prefix_len) // ct))
                     ids[i, :n] = req.prompt[start:start + n]
                     lengths[i] = n
                     positions[i] = start
                     tables[i, :len(slot.pages)] = slot.pages
                     temps[i] = req.temperature
+                    slots[i] = sid
                 keys[:len(spans)] = _keys_for(
                     [req.seed for _, req, *_ in spans])
             with self._span("serve/chunk/dispatch"):
-                if self._separate_pools:
+                if self._prefill_by_length:
+                    # a state family's chunk takes its rows' SLOTS: it
+                    # starts from the row's state and leaves it there
+                    first, self._cache = prog(
+                        self.params, self._cache, ids, lengths, positions,
+                        tables, keys, temps, slots)
+                elif self._separate_pools:
                     first, self._cache_prefill = prog(
                         self.params, self._cache_prefill, ids, lengths,
                         positions, tables, keys, temps)
@@ -1877,7 +1966,7 @@ class InferenceEngine:
             ledger.issued()
             with self._span("serve/chunk/wait"):
                 # host sync: final chunks release their first token
-                first = np.asarray(first)
+                first = self._first_tokens(first, bb)
             wall_ms = ledger.ready()
         with self._span("serve/record"):
             self._chunk_dispatches += 1
@@ -2335,13 +2424,15 @@ class InferenceEngine:
                 for bb, ct in plan:
                     cache = self._cache_prefill if self._separate_pools \
                         else self._cache
+                    more = (np.full((bb,), self._scratch, np.int32),) \
+                        if self._prefill_by_length else ()
                     first, cache = prog(
                         self.params, cache, np.zeros((bb, ct), np.int32),
                         np.ones((bb,), np.int32),
                         np.zeros((bb,), np.int32),
                         np.zeros((bb, self._prefill_pps), np.int32),
                         np.zeros((bb, 2), np.uint32),
-                        np.zeros((bb,), np.float32))
+                        np.zeros((bb,), np.float32), *more)
                     if self._separate_pools:
                         self._cache_prefill = cache
                     else:

@@ -47,6 +47,12 @@ decode rewrites every row in place. :class:`StatePoolSpec` is built
 from the model's ``state_geometry``; a family without one has no such
 leaves.
 
+A family served in CHUNKS (``models/kimi_linear.py``) writes the row at
+every chunk's true end, so the row is a snapshot at each chunk boundary:
+the next chunk starts from it, a chunk at position 0 from zeros (a
+reused slot never sees its predecessor's state), and a decode dispatch
+leaves the row of a slot that is mid-prefill as it is.
+
 **The latent pool (in place of the pair)** — a family with multi-head
 latent attention (``models/axk1.py``) caches ONE row a token a layer,
 ``[c (latent_width) | k_r (rope_width)]`` after the norm and the
@@ -80,7 +86,7 @@ __all__ = ["KVCacheSpec", "cache_spec_for", "init_kv_cache",
            "init_paged_kv_cache", "paged_kv_bytes", "pages_for",
            "PageAllocator", "StatePoolSpec", "state_pool_spec_for",
            "init_state_pool", "state_pool_bytes", "PagedStateCache",
-           "LatentPoolSpec", "latent_row_lanes"]
+           "LatentStateCache", "LatentPoolSpec", "latent_row_lanes"]
 
 
 class KVCacheSpec(NamedTuple):
@@ -347,6 +353,17 @@ class PagedStateCache(NamedTuple):
     the tree's leaves it is the tuple it always was."""
     keys: Any
     values: Any
+    state: Any
+    tails: Any
+
+
+class LatentStateCache(NamedTuple):
+    """The cache tree of a family with latent attention AND recurrent
+    layers (``models/kimi_linear.py``): the ONE latent page pool of its
+    latent layers and the per-slot pools of its recurrent ones. The
+    pool comes first, as in every tree (the trunk reads the page size
+    off the first leaf)."""
+    pool: Any
     state: Any
     tails: Any
 

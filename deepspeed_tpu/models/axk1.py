@@ -60,6 +60,8 @@ from deepspeed_tpu.ops.attention.flash import NEG_INF
 from deepspeed_tpu.ops.attention.page_pool import (causal_cache_mask,
                                                    gather_paged_kv,
                                                    own_keys_attention,
+                                                   prefix_block_rows,
+                                                   prefix_own_attention,
                                                    write_paged_kv_cache)
 from deepspeed_tpu.ops.attention.paged import latent_decode_attention
 from deepspeed_tpu.ops.functional import rms_norm
@@ -141,6 +143,12 @@ class AXK1Config(NamedTuple):
         rows whose kept groups include a group held here)."""
         return (self.experts_per_token * len(self.expert_layers),
                 self.held[1])
+
+    @property
+    def rotary(self):
+        """``q_r`` and ``k_r`` are rotated by their positions (a second
+        family of these mixers has no rotation anywhere)."""
+        return True
 
     @property
     def sm_scale(self):
@@ -275,62 +283,105 @@ def _stripe_attention(sm_scale: float):
     return attend
 
 
-def _latent_mixer(lp, h, call, cache, n):
+def latent_mixer(lp, h, call, cache, n):
     """Latent attention of one layer on ``h`` (B, S, H) whose tokens sit
     at ``call.token_positions`` (B, S), a mixer of
-    ``models/served_trunk.py``: layer ``n`` of the tree's ONE latent
-    pool (``cache`` None: no pages, the plain forward)."""
+    ``models/served_trunk.py``: layer ``n`` of the tree's latent pool,
+    its FIRST leaf (``cache`` None: no pages, the plain forward). Shared
+    with ``models/kimi_linear.py``; what differs is read from the config:
+    a queries' rank or none (``q_lora_rank`` 0: ``wq`` alone, no norm),
+    rotation or none (``rotary``), the scores' scale. Under
+    ``call.carry`` (a family served in chunks) a prefill row whose
+    ``call.positions`` is past 0 also attends the latent rows earlier
+    chunks wrote, read back through its block table a block at a time
+    (``page_pool.prefix_own_attention``)."""
     ap, config, dtype, positions = (lp["attn"], call.config, call.dtype,
                                     call.token_positions)
     B, S, _ = h.shape
     nh, rkv = config.num_heads, config.kv_lora_rank
     dn, dr, dv = (config.qk_nope_head_dim, config.qk_rope_head_dim,
                   config.v_head_dim)
-    inv_freq = jnp.asarray(yarn_inv_freq(config))
     eps = config.rms_norm_eps
+    if config.rotary:
+        inv_freq = jnp.asarray(yarn_inv_freq(config))
+        turned = lambda x: _rope(x, positions, inv_freq)
+    else:
+        turned = lambda x: x
     with scope("mla_q"):
-        c_q = rms_norm(_mm(h, ap["wq_a"], dtype), ap["q_norm"], eps)
-        q = _mm(c_q, ap["wq_b"], dtype).reshape(B, S, nh, dn + dr)
+        if config.q_lora_rank:
+            c_q = rms_norm(_mm(h, ap["wq_a"], dtype), ap["q_norm"], eps)
+            q = _mm(c_q, ap["wq_b"], dtype)
+        else:
+            q = _mm(h, ap["wq"], dtype)
+        q = q.reshape(B, S, nh, dn + dr)
         q_n = q[..., :dn].astype(dtype)
-        q_r = _rope(q[..., dn:], positions, inv_freq).astype(dtype)
+        q_r = turned(q[..., dn:]).astype(dtype)
     with scope("mla_latent"):
         kv = _mm(h, ap["wkv_a"], dtype)
         # the cache row: after the norm and the rotation, as it is held
         row = jnp.concatenate(
             [rms_norm(kv[..., :rkv], ap["kv_norm"], eps),
-             _rope(kv[..., rkv:], positions, inv_freq)], -1).astype(dtype)
+             turned(kv[..., rkv:])], -1).astype(dtype)
     w_kvb = ap["wkv_b"].astype(dtype).reshape(rkv, nh, dn + dv)
     if cache is not None:
         # the pool's row is ONE head as wide as its lanes, the tail zeros
-        (pool,) = cache
+        pool = cache[0]
         held = jnp.pad(row, ((0, 0), (0, 0),
                              (0, pool.shape[-1] - rkv - dr)))
         pool = write_paged_kv_cache(pool, n, held[:, None], call.index)
-        cache = (pool,)
+        # a tree of the pool alone, or one that names it beside others
+        cache = cache._replace(pool=pool) if hasattr(cache, "_replace") \
+            else (pool,)
+
+    def keys_values(rows):
+        """Latent ``rows`` (B, L, >= r_kv + d_r) expanded to every
+        head's keys (B, heads, L, d_n + d_r), and the expansion itself,
+        whose lanes past d_n are the values (.., d_v)."""
+        L = rows.shape[1]
+        kvx = jnp.einsum("blc,chd->bhld", rows[..., :rkv].astype(dtype),
+                         w_kvb, preferred_element_type=jnp.float32
+                         ).astype(dtype)
+        k = jnp.concatenate(
+            [kvx[..., :dn], jnp.broadcast_to(
+                rows[:, None, :, rkv:rkv + dr].astype(dtype),
+                (B, nh, L, dr))], -1)
+        return k, kvx
+
+    # the flash kernel has one width for keys and values: the values
+    # ride zero-padded to the keys' (docs/axk1.md: the padding is half
+    # again the P V products of the prefill reader)
+    wide = lambda v: jnp.pad(v, ((0, 0),) * 3 + ((0, dn + dr - dv),))
 
     def expanded(rows, cache_position, own):
-        """Attention over latent ``rows`` (B, L, >= r_kv + d_r) expanded
-        to every head's keys and values."""
+        """Attention over latent ``rows`` expanded."""
         with scope("mla_expand"):
-            L = rows.shape[1]
-            kvx = jnp.einsum("blc,chd->bhld", rows[..., :rkv].astype(dtype),
-                             w_kvb, preferred_element_type=jnp.float32
-                             ).astype(dtype)
-            k = jnp.concatenate(
-                [kvx[..., :dn], jnp.broadcast_to(
-                    rows[:, None, :, rkv:rkv + dr].astype(dtype),
-                    (B, nh, L, dr))], -1)
+            k, kvx = keys_values(rows)
             qx = jnp.concatenate([q_n, q_r], -1).transpose(0, 2, 1, 3)
             v = kvx[..., dn:]
         stripe = _stripe_attention(config.sm_scale)
         if not own:
             return stripe(qx, k, v, cache_position)
-        # the flash kernel has one width for keys and values: the
-        # values ride zero-padded to the keys' (docs/axk1.md: the
-        # padding is half again the P V products of the prefill reader)
-        v = jnp.pad(v, ((0, 0),) * 3 + ((0, dn + dr - dv),))
-        return own_keys_attention(qx, k, v, cache_position, stripe,
-                                  sm_scale=config.sm_scale)[..., :dv]
+        if not call.carry:
+            return own_keys_attention(qx, k, wide(v), cache_position,
+                                      stripe,
+                                      sm_scale=config.sm_scale)[..., :dv]
+        # a chunk: its own rows and the prefix earlier chunks wrote,
+        # whole pages of the block table a loop turn
+        ps = pool.shape[2]
+        per = prefix_block_rows(call.tables.shape[1] * ps) // ps
+        tables = jnp.pad(call.tables,
+                         ((0, 0), (0, -call.tables.shape[1] % per)))
+
+        def prefix_block(j, block_rows):
+            pages = jax.lax.dynamic_slice_in_dim(tables, j * per, per, 1)
+            k_j, kvx_j = keys_values(
+                pool[n, pages].reshape(B, block_rows, pool.shape[-1]))
+            return k_j, wide(kvx_j[..., dn:])
+
+        return prefix_own_attention(
+            qx, k, wide(v), cache_position, prefix_block,
+            tables.shape[1] * ps, sm_scale=config.sm_scale,
+            prefix_scope="mla_prefix")[..., :dv]
 
     if cache is not None and S == 1 and call.reader == "pallas":
         with scope("mla_absorb"):
@@ -352,9 +403,11 @@ def _latent_mixer(lp, h, call, cache, n):
         rows = gather_paged_kv(pool, n, call.tables, 1)[:, 0]
         o = expanded(rows, call.positions, own=False)
     else:
-        # every row starts at position 0: its own rows are all it may
-        # see (rounded to the dtype the pool holds them in)
-        o = expanded(row, jnp.zeros((B,), jnp.int32), own=True)
+        # its own rows (rounded to the dtype the pool holds them in) are
+        # all a row that starts at position 0 may see; a later chunk's
+        # start is its prefix's length
+        o = expanded(row, call.positions if call.carry
+                     else jnp.zeros((B,), jnp.int32), own=True)
     with scope("mla_out"):
         o = o.transpose(0, 2, 1, 3).reshape(B, S, nh * dv)
         return _mm(o, ap["wo"], dtype), cache
@@ -381,7 +434,7 @@ def _family(config: AXK1Config) -> ServedFamily:
     return ServedFamily(
         layers=tuple(("latent", "dense" if l < config.first_k_dense
                       else "experts") for l in range(config.num_layers)),
-        mixers={"latent": _latent_mixer}, route=route,
+        mixers={"latent": latent_mixer}, route=route,
         expert_tile=_EXPERT_TILE, decode_rows=rows_kept_here,
         token_positions=True)
 

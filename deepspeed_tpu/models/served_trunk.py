@@ -67,6 +67,12 @@ class _Call(NamedTuple):
     lengths: Any            # (B,) true lengths of a served prefill's rows
     slots: Any              # (B,) their rows of the per-slot pools
     token_positions: Any    # (B, S), where the family's mixers rotate
+    active: Any = None      # (B,) bool: the rows of a decode that decode
+    # a served prefill's rows may start past position 0 (a later CHUNK
+    # of a prompt): a mixer then starts from what its predecessors left
+    # (the slot's state and tail; the latent rows in the pool), and a
+    # decode leaves an inactive row's state and tail as they are
+    carry: bool = False
 
 
 class ServedFamily(NamedTuple):
@@ -85,6 +91,8 @@ class ServedFamily(NamedTuple):
     logits_scaling: Optional[float] = None
     head: str = "lm_head"           # "tok_emb": the table, tied
     token_positions: bool = False
+    # the family's mixers follow chunked prefill (``_Call.carry``)
+    chunked: bool = False
 
 
 def paged_pair_mixer(softmax_mixer):
@@ -131,7 +139,9 @@ def _expert_half(lp, family, x, call, active):
     h2 = _norm(x, lp["ln_2"]["w"], config.rms_norm_eps)
     flat = h2.reshape(B * S, hdim)
     with scope("moe_route"):
-        idx, p, facts = family.route(flat, lp["router"])
+        # a correction bias an expert, where the layer has one
+        bias = [lp["router_bias"]] if "router_bias" in lp else []
+        idx, p, facts = family.route(flat, lp["router"], *bias)
     experts = {n: t.astype(dtype) for n, t in lp["experts"].items()}
     rows = flat.astype(dtype)
     if S == 1:
@@ -193,7 +203,8 @@ def served_forward(family: ServedFamily, params, config, input_ids, dtype,
         start = cache_position if serving else jnp.zeros((B,), jnp.int32)
         token_positions = start[:, None] + jnp.arange(S)[None, :]
     call = _Call(config, dtype, block_tables, cache_position, index,
-                 paged_attn_kernel, lengths, slots, token_positions)
+                 paged_attn_kernel, lengths, slots, token_positions,
+                 active, serving and family.chunked)
     eps = config.rms_norm_eps
     with scope("embed"):
         x = params["tok_emb"][input_ids].astype(jnp.float32)

@@ -122,6 +122,12 @@ class SolarOpen2Config(NamedTuple):
         return (self.experts_per_token * self.num_layers, self.held[1])
 
     @property
+    def kda_beta_scale(self):
+        """The step is ``2 sigmoid``: an eigenvalue of the transition
+        may be negative (a second family's is ``sigmoid`` alone)."""
+        return 2.0
+
+    @property
     def state_geometry(self):
         """What a slot holds whatever its length, for
         ``kv_cache.state_pool_spec_for``: (recurrent layers, heads, key
@@ -251,12 +257,18 @@ def _unit(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
 
 
-def _kda_mixer(lp, h, call, cache, n):
+def kda_mixer(lp, h, call, cache, n):
     """Gated delta-rule attention of one layer on ``h`` (B, S, H), a
     mixer of ``models/served_trunk.py``: the ``n``-th recurrent layer
     over the tree's ``state`` and ``tails`` (``cache`` None, the plain
     forward: an empty state, nothing kept), a served prefill to
-    ``call.lengths`` and into rows ``call.slots``."""
+    ``call.lengths`` and into rows ``call.slots``. Shared with
+    ``models/kimi_linear.py``; what differs is read from the config (the
+    step's range, ``kda_beta_scale``) and the tree (a gate bias ``b_g``
+    or none). Under ``call.carry`` (a family served in chunks) a prefill
+    row whose ``call.positions`` is past 0 starts from its slot's state
+    and tail, and a decode leaves a row that is not ``call.active`` as
+    it is."""
     kp, config, dtype, lengths = (lp["kda"], call.config, call.dtype,
                                   call.lengths)
     B, S, _ = h.shape
@@ -266,11 +278,23 @@ def _kda_mixer(lp, h, call, cache, n):
     with scope("kda_proj"):
         raw = jnp.concatenate([_mm(h, kp[w], dtype).astype(dtype)
                                for w in ("wq", "wk", "wv")], axis=-1)
+        carried = None
         if decode:
             window = jnp.concatenate([cache.tails[n], raw], axis=1)
             tail = window[:, 1:]
+            if call.carry:
+                # a slot in the middle of its prefill keeps its tail
+                tail = jnp.where(call.active[:, None, None], tail,
+                                 cache.tails[n])
         else:
             window = jnp.pad(raw, ((0, 0), (cw - 1, 0), (0, 0)))
+            if cache is not None and call.carry:
+                # a later chunk: the three inputs before its first, and
+                # the state its predecessor left (zeros at position 0)
+                carried = call.positions > 0
+                window = jnp.concatenate([jnp.where(
+                    carried[:, None, None], cache.tails[n, call.slots],
+                    0).astype(raw.dtype), raw], axis=1)
             if cache is not None:
                 # the last inputs before each row's TRUE length (zeros
                 # before position 0)
@@ -286,10 +310,16 @@ def _kda_mixer(lp, h, call, cache, n):
         decay = _mm(_mm(h, kp["wf1"], dtype), kp["wf2"], dtype) + kp["b_dt"]
         g = -jnp.exp(kp["a_log"])[:, None] * jax.nn.softplus(
             decay).reshape(B, S, nh, hd)
-        beta = 2.0 * jax.nn.sigmoid(_mm(h, kp["wb"], dtype))
-        gate = jax.nn.sigmoid(
-            _mm(_mm(h, kp["wg1"], dtype), kp["wg2"], dtype) + kp["b_g"])
+        beta = jax.nn.sigmoid(_mm(h, kp["wb"], dtype))
+        if config.kda_beta_scale != 1.0:
+            beta = config.kda_beta_scale * beta
+        gate = _mm(_mm(h, kp["wg1"], dtype), kp["wg2"], dtype)
+        gate = jax.nn.sigmoid(gate + kp["b_g"] if "b_g" in kp else gate)
     if decode:
+        if call.carry:
+            # g = 0, b = 0: the identity on an inactive row's state
+            g = jnp.where(call.active[:, None, None, None], g, 0.0)
+            beta = jnp.where(call.active[:, None, None], beta, 0.0)
         with scope("kda_state"):
             o, state = kda_decode_update(
                 cache.state, n, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
@@ -298,9 +328,11 @@ def _kda_mixer(lp, h, call, cache, n):
             tails = cache.tails.at[n].set(tail)
     else:
         with scope("kda_scan"):
-            o, last = kda_chunk_scan(
-                q, k, v, g, beta, jnp.zeros((B, nh, hd, hd), jnp.float32),
-                lengths)
+            start = jnp.zeros((B, nh, hd, hd), jnp.float32)
+            if carried is not None:
+                start = jnp.where(carried[:, None, None, None],
+                                  cache.state[n, call.slots], start)
+            o, last = kda_chunk_scan(q, k, v, g, beta, start, lengths)
         if cache is not None:
             assert call.slots is not None, \
                 "a served prefill needs each row's slot"
@@ -325,7 +357,7 @@ def _family(config: SolarOpen2Config) -> ServedFamily:
         layers=tuple(("softmax" if l in config.gqa_layers else "kda",
                       "experts") for l in range(config.num_layers)),
         mixers={"softmax": paged_pair_mixer(_softmax_mixer),
-                "kda": _kda_mixer},
+                "kda": kda_mixer},
         route=route, expert_tile=_EXPERT_TILE)
 
 

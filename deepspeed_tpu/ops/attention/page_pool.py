@@ -324,6 +324,60 @@ def _own_keys(q, k, v, cache_position, stripe_attention, dense, interpret,
                                interpret=interpret)
 
 
+# rows of a prefix that one loop turn of :func:`prefix_own_attention`
+# has gathered, expanded and handed to the flash kernel (whole pages)
+PREFIX_BLOCK = 2048
+
+
+def prefix_block_rows(table_tokens: int) -> int:
+    """Rows of one block of :func:`prefix_own_attention`'s walk over a
+    block table of ``table_tokens`` positions."""
+    return min(PREFIX_BLOCK, table_tokens)
+
+
+def prefix_own_attention(q, k, v, cache_position, prefix_block,
+                         table_tokens: int, sm_scale=None, *,
+                         prefix_scope: str):
+    """Attention of a CHUNK's queries ``q`` (B, heads, S, hd), which sit
+    at positions ``cache_position[b] + j``, over the call's own ``k``,
+    ``v`` (B, heads, S, hd), causally, AND over the ``cache_position[b]``
+    rows earlier chunks left in the pool: one softmax over both, exact,
+    with no (S x prefix) scores in HBM. The own rows go through the
+    flash kernel as :func:`own_keys_attention`'s do; the prefix a block
+    at a time: ``prefix_block(j, rows) -> (k_j, v_j)`` (B, heads, rows,
+    hd) are the keys and values at positions ``[j * rows, (j + 1) *
+    rows)`` as the family reads them back through its block table
+    (``rows`` = :func:`prefix_block_rows`), handed to the same kernel without the causal cut and
+    with an additive mask that hides positions at or past the row's
+    start; each block's normalised partial joins the running one by its
+    log-sum-exp (``ops/attention/ring._combine``: the ring prefill's
+    merge). The loop runs to the LONGEST prefix of the call's rows and
+    no further: a bucket of rows at position 0 runs no turn. The
+    prefix's part runs under the family's registered ``prefix_scope``."""
+    from deepspeed_tpu.ops.attention.ring import _combine
+    hd = q.shape[-1]
+    interpret = not flash._use_pallas()
+    scale = float(sm_scale) if sm_scale is not None else hd ** -0.5
+    with scope("attn_core"):
+        o, lse = flash._flash_fwd(q, k, v, None, True, scale, interpret)
+    rows = prefix_block_rows(table_tokens)
+
+    def turn(j, acc):
+        k_j, v_j = prefix_block(j, rows)
+        seen = (j * rows + jnp.arange(rows))[None, :] \
+            < cache_position[:, None]
+        hide = jnp.where(seen, 0.0, flash.NEG_INF).astype(jnp.float32)
+        o_j, lse_j = flash._flash_fwd(q, k_j, v_j, hide[:, None, None, :],
+                                      False, scale, interpret)
+        return _combine(*acc, o_j, lse_j)
+
+    with scope(prefix_scope):
+        turns = (jnp.max(cache_position) + rows - 1) // rows
+        o, _ = jax.lax.fori_loop(0, turns, turn,
+                                 (o.astype(jnp.float32), lse))
+    return o.astype(q.dtype)
+
+
 def paged_attend(q, k, v, pools, layer: int, block_table, cache_position,
                  index, out_box, attn_kernel: str, stripe_attention,
                  sm_scale=None):

@@ -346,7 +346,7 @@ def route_top_k(router_in, w_router, top_k: int):
 
 
 def route_group_limited(router_in, w_router, top_k: int, n_group: int,
-                        topk_group: int, scale: float = 1.0):
+                        topk_group: int, scale: float = 1.0, bias=None):
     """float32 router by SIGMOID scores with the choice limited to the
     best groups (models/axk1.py): ``p = sigmoid(router_in @ w_router)``
     (T, E) at full precision; the E experts are ``n_group`` groups of
@@ -354,19 +354,28 @@ def route_group_limited(router_in, w_router, top_k: int, n_group: int,
     largest p; the ``topk_group`` best groups are kept; the ``top_k``
     largest p among THEIR experts are chosen, and their weights are
     ``p_i / sum p_i * scale``. Ties go to the lower index, among groups
-    and among experts (``lax.top_k``). Returns (idx (T, k) int32, w
-    (T, k) f32, p (T, E) f32, the groups kept (T, topk_group) int32)."""
+    and among experts (``lax.top_k``). ``bias`` (E,) float32 (None: the
+    family has none) is a correction an expert for the CHOICE only
+    (models/kimi_linear.py): groups and experts are ranked by ``p +
+    bias``, the weights are made of ``p`` alone. Returns (idx (T, k)
+    int32, w (T, k) f32, p (T, E) f32, the groups kept (T, topk_group)
+    int32)."""
     p = jax.nn.sigmoid(jnp.dot(router_in.astype(jnp.float32),
                                w_router.astype(jnp.float32),
                                precision=jax.lax.Precision.HIGHEST))
     t, e = p.shape
-    grouped = p.reshape(t, n_group, e // n_group)
+    ranked = p if bias is None else p + bias.astype(jnp.float32)
+    grouped = ranked.reshape(t, n_group, e // n_group)
     _, kept = jax.lax.top_k(
         jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1), topk_group)
     open_ = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
-    # a sigmoid is positive: -1 stands behind every expert of a kept group
-    masked = jnp.where(open_[:, :, None], grouped, -1.0).reshape(t, e)
+    # a sigmoid is positive: -1 stands behind every expert of a kept
+    # group (under a bias: what no ranked score falls to)
+    floor = -1.0 if bias is None else jnp.min(ranked) - 1.0
+    masked = jnp.where(open_[:, :, None], grouped, floor).reshape(t, e)
     top, idx = jax.lax.top_k(masked, top_k)
+    if bias is not None:
+        top = jnp.take_along_axis(p, idx, axis=-1)
     w = top / jnp.sum(top, axis=-1, keepdims=True) * scale
     return idx.astype(jnp.int32), w, p, kept.astype(jnp.int32)
 

@@ -72,6 +72,11 @@ DEVICE_SCOPES = (
     # the output projection. The reader itself stays `attn_core`, the
     # pool's write `kv_write`
     "mla_q", "mla_latent", "mla_absorb", "mla_expand", "mla_out",
+    # a later CHUNK of a prompt (models/kimi_linear.py): the prefix's
+    # part of its latent attention: the rows earlier chunks wrote
+    # gathered through the block table, expanded, put to the flash
+    # kernel a block at a time and merged into the own rows' softmax
+    "mla_prefix",
 )
 
 # host phase spans (TraceAnnotation), each parent before its children

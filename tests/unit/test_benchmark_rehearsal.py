@@ -379,11 +379,13 @@ def test_the_reason_cell_is_declared_as_the_issue_names_it():
     moved."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    cell = bench["workloads"][-1]
+    # the sixth cell and the fifth configuration: what later PRs add
+    # comes after them
+    cell = bench["workloads"][5]
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
         == (REASON, "ax-k1", "serve-reason-saturated", 1)
     assert len(cell["why"]) <= 200
-    entry = bench["configs"][-1]
+    entry = bench["configs"][4]
     assert entry["name"] == "ax-k1" and entry["reduced"] == [
         "num_hidden_layers", "n_routed_experts", "vocab_size"]
     assert entry["source"] == \
@@ -442,7 +444,7 @@ def test_the_reason_cell_is_declared_as_the_issue_names_it():
     for name in ("decode_read_live_share.sat",
                  "decode_stripe_live_share.sat"):     # the last page's tail
         assert next(m for m in bench["per_layer"] if m["name"] == name)[
-            "workloads"] == ["gpt2-345m.serve-saturated", REASON]
+            "workloads"][:2] == ["gpt2-345m.serve-saturated", REASON]
     assert REASON in next(m for m in bench["end_to_end"]
                           if m["name"] == "serve_tokens_per_s")["workloads"]
     reported = {m["name"] for m in bench["per_layer"]
@@ -459,7 +461,7 @@ def test_the_reason_cell_is_declared_as_the_issue_names_it():
     every = [m for m in bench["per_layer"] if DOCS in m.get(
         "workloads", []) and "gpt2-345m.serve-saturated" in m["workloads"]
         and "solar-open2-250b.serve-rollout-saturated" in m["workloads"]]
-    assert len(every) == 24 and all(m["workloads"][-1] == REASON
+    assert len(every) == 24 and all(m["workloads"][3] == REASON
                                     for m in every)
     assert [m["name"] for m in every[-2:]] == [
         "prefill_page_write_share.sat", "serve_prefill_build_ms.sat"]

@@ -348,14 +348,16 @@ def test_the_share_is_declared_for_the_four_serving_cells():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     # appended in PR 44, nothing moved; what later PRs add comes after
-    entry = next(m for m in bench["per_layer"] if m["name"] == name)
-    assert [m["name"] for m in bench["per_layer"][-2:]] == [
-        name, "serve_prefill_build_ms.sat"]
+    names = [m["name"] for m in bench["per_layer"]]
+    entry = bench["per_layer"][names.index(name)]
+    assert names[names.index(name) + 1] == "serve_prefill_build_ms.sat"
+    # the four serving cells of PR 44's day; a later cell whose every
+    # prompt goes in chunks (PR 48) has no `serve/prefill` span to read
     assert entry == {
         "name": name, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "page pool",
         "moves": "serve_tokens_per_s",
-        "workloads": [w["name"] for w in bench["workloads"]
+        "workloads": [w["name"] for w in bench["workloads"][:6]
                       if ".serve-" in w["name"]]}
     assert len(entry["workloads"]) == 4
     with open(os.path.join(BENCH, "metrics", name + ".json")) as f:

@@ -1264,6 +1264,93 @@ def test_axk1_serving_programs_keep_the_latent_pool_in_place(monkeypatch,
     assert 11.55e9 < memory.argument_size_in_bytes < 11.65e9
 
 
+@pytest.mark.parametrize("program", ["decode", "chunk_1", "chunk_2"])
+def test_kimi_linear_serving_programs_keep_one_cache_tree_in_place(
+        monkeypatch, program):
+    """The benchmark configuration's three programs at the published
+    widths (decode at 65 rows; a chunk of 2,048 at 1 and at 2 rows, any
+    start), weights held in bfloat16, the cache tree donated: the latent
+    pool, the state pool and the tails are aliased through the nine
+    layers. Decode runs the delta-rule update in place at seven layers
+    and the latent reader at two; a chunk runs three grouped products an
+    expert layer and the flash kernel TWICE a latent layer (its own
+    rows, and a block of the prefix a loop turn), and holds no (chunk x
+    table) scores."""
+    import json
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(__file__), "..", "..", "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from families import kimi_linear as family
+    from deepspeed_tpu.inference.kv_cache import (LatentStateCache,
+                                                  paged_kv_bytes,
+                                                  paged_spec_for,
+                                                  state_pool_bytes,
+                                                  state_pool_spec_for)
+    from deepspeed_tpu.models import kimi_linear as kl
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(flash, "_use_pallas", lambda: True)
+    with open(os.path.join(bench, "configs",
+                           "kimi-linear-48b-a3b.json")) as f:
+        config = json.load(f)
+    model = family.serve_model_of(config)
+    inference = config["serve"]["inference"]
+    rows = inference["max_batch_size"] + 1
+    chunk = inference["chunked_prefill"]["chunk_tokens"]
+    pages = paged_spec_for(model, inference["paged_kv"]["num_pages"],
+                           inference["paged_kv"]["page_size"],
+                           inference["max_seq_len"])
+    state = state_pool_spec_for(model, rows)
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: kl.init_kimi_linear_params(
+            model, jax.random.PRNGKey(0))))
+    cache = LatentStateCache(_spec(pages.shape),
+                             _spec(state.state_shape, jnp.float32),
+                             _spec(state.tail_shape))
+    ints = lambda *shape: _spec(shape, jnp.int32)
+
+    def decode(params, cache, toks, positions, tables):
+        logits, cache, counts = kl.kimi_linear_forward(
+            params, model, toks[:, None], kv_cache=cache,
+            cache_position=positions, block_tables=tables,
+            paged_attn_kernel="pallas", active=tables[:, 0] > 0,
+            with_counts=True)
+        return jnp.argmax(logits[:, 0], -1), counts, cache
+
+    def prefill(params, cache, ids, lengths, positions, tables, slots):
+        logits, cache, counts = kl.kimi_linear_forward(
+            params, model, ids, kv_cache=cache, cache_position=positions,
+            block_tables=tables, paged_attn_kernel="pallas",
+            lengths=lengths, slots=slots, with_counts=True)
+        return jnp.argmax(logits[:, 0], -1), counts, cache
+
+    if program == "decode":
+        fn, args = decode, (ints(rows), ints(rows),
+                            ints(rows, pages.pages_per_seq))
+        kernels = 7 + 2
+    else:
+        b = int(program[-1])
+        fn, args = prefill, (ints(b, chunk), ints(b), ints(b),
+                             ints(b, pages.pages_per_seq), ints(b))
+        kernels = 3 * 8 + 2 * 2
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    memory = compiled.memory_analysis()
+    held = paged_kv_bytes(pages) + state_pool_bytes(state)
+    assert memory.alias_size_in_bytes >= held
+    # less than ONE layer of either pool: no copy of one is made
+    assert memory.temp_size_in_bytes < 1.1e9
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+    # no score of a chunk's queries against the table's 18,432 positions
+    assert not re.search(r"\[\d+,(32,)?%d,18432\]" % chunk, text)
+    # the weights as they are held (2,366M parameters in bfloat16), the
+    # latent pool (2.01 GB) and the state pool (0.99 GB)
+    assert 7.70e9 < memory.argument_size_in_bytes < 7.80e9
+
+
 def test_smallthinker_train_step_compiles_at_the_cut_widths(monkeypatch):
     """`deepspeed_tpu.initialize` + the ONE compiled `_micro_step`
     (ZeRO-2, bf16, Adam, clipping) of the benchmark's configuration at

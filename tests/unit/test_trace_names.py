@@ -184,6 +184,52 @@ def test_paged_serving_programs_carry_their_scopes(monkeypatch, program):
     engine.close()
 
 
+@pytest.mark.parametrize("program", ["chunk", "decode"])
+def test_a_chunked_hybrids_programs_carry_their_scopes(monkeypatch, program):
+    """models/kimi_linear.py: a chunk carries the delta-rule scan, the
+    own rows' `attn_core` and the prefix's `mla_prefix`; decode the
+    state update and the absorbed reader; neither opens a name outside
+    the registry."""
+    from deepspeed_tpu.models import kimi_linear as kl
+    cfg = kl.KimiLinearConfig(
+        vocab_size=64, hidden_size=32, num_layers=3, latent_layers=(1,),
+        num_heads=2, kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=8, v_head_dim=8, kda_num_heads=2, kda_head_dim=8,
+        kda_gate_rank=4, intermediate_size=48, moe_intermediate_size=16,
+        num_experts=4, experts_per_token=2, max_position_embeddings=64)
+    engine = InferenceEngine(
+        cfg, kl.init_kimi_linear_params(cfg, jax.random.PRNGKey(0)),
+        {"max_batch_size": 2, "prompt_buckets": [16], "batch_buckets": [1],
+         "max_seq_len": 48, "paged_kv": {"prefix_cache": False},
+         "chunked_prefill": {"enabled": True, "chunk_tokens": 16}})
+    rows, pps = engine._rows, engine.paged_spec.pages_per_seq
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)      # noqa: E731
+    keys = lambda n: jnp.zeros((n, 2), jnp.uint32)        # noqa: E731
+    temps = lambda n: jnp.zeros((n,), jnp.float32)        # noqa: E731
+    if program == "decode":
+        fn, args = engine._decode_paged_impl, (
+            i32(rows), i32(rows), i32(rows, pps), keys(rows), temps(rows))
+        want = {"kda_proj", "kda_state", "mla_q", "mla_latent",
+                "mla_absorb", "attn_core", "mla_out", "moe_route",
+                "moe_experts", "moe_shared", "mlp", "lm_head", "sample"}
+        never = {"kda_scan", "mla_prefix", "mla_expand"}
+    else:
+        fn, args = engine._prefill_state_impl, (
+            i32(1, 16), i32(1) + 1, i32(1) + 16, i32(1, pps), keys(1),
+            temps(1), i32(1))
+        want = {"kda_proj", "kda_scan", "kda_state", "mla_q", "mla_latent",
+                "mla_expand", "attn_core", "mla_prefix", "mla_out",
+                "moe_route", "moe_experts", "moe_shared", "mlp", "lm_head"}
+        never = {"mla_absorb"}
+    with _opened_scopes(monkeypatch) as opened:
+        text = jax.jit(fn).lower(engine.params, engine._cache,
+                                 *args).as_text(debug_info=True)
+    assert set(opened) <= set(DEVICE_SCOPES)
+    assert want <= _scopes_in(text), want - _scopes_in(text)
+    assert not never & _scopes_in(text)
+    engine.close()
+
+
 def test_scopes_leave_the_program_set_and_recompiles_alone(monkeypatch):
     def programs():
         engine = _serve_engine()
