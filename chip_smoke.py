@@ -14,7 +14,9 @@ the published widths of GPT-2 345M:
 
 - *device*  — fails at once unless JAX's first device is a TPU;
 - *kernels* — each Pallas kernel of the main path, compiled
-  (``interpret=False``), against its ``jnp`` oracle at real widths;
+  (``interpret=False``), against its ``jnp`` oracle at real widths, and
+  the delta-rule chunk scan of the served hybrids (float32 products in
+  Mosaic) against the sequential recurrence;
 - *train*   — ``deepspeed_tpu.initialize`` with
   ``examples/megatron_gpt2/ds_config_zero2.json`` (bf16, ZeRO-2,
   micro-batch 8 x 1024 tokens), a few ``train_batch`` steps on one
@@ -220,6 +222,42 @@ def check_paged_decode(seed, head_dim=128, page_size=16):
     assert_close(got, want, f"paged decode hd={head_dim} ps={page_size}")
 
 
+def check_delta_rule_scan(seed, shape=(2, 2048, 32, 128), tol=1e-4):
+    """``ops/kda.kda_chunk_scan`` (one Mosaic kernel: the products at
+    float32, the solve by substitution) against ``kda_sequential`` from
+    a random state at a chunk dispatch's shape: steps up to 2 under
+    mild decays, under decays no exp(-G) could hold (a chunk of 64 by
+    e^-290), and the step 2 at EVERY position under those (the solve at
+    an eigenvalue of -1). Interpret-mode equality says nothing of the
+    chip's float32 products; the worst differences are logged."""
+    from deepspeed_tpu.ops import kda
+    B, S, H, D = shape
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(keys[0], shape)) * D ** -0.5
+    k = unit(jax.random.normal(keys[1], shape))
+    v = jax.random.normal(keys[2], shape)
+    state = jax.random.normal(keys[5], (B, H, D, D))
+    sequential, scan = jax.jit(kda.kda_sequential), jax.jit(kda.kda_chunk_scan)
+    worst = [0.0, 0.0]
+    for strong, every in ((False, False), (True, False), (True, True)):
+        g = -jnp.exp(jax.random.uniform(
+            keys[3], shape, minval=-7.0, maxval=1.5 if strong else -1.0))
+        beta = 2.0 * jax.nn.sigmoid(jax.random.normal(keys[4], (B, S, H)))
+        if every:
+            beta = jnp.full_like(beta, 2.0)
+        want_o, want_s = sequential(q, k, v, g, beta, state)
+        got_o, got_s = scan(q, k, v, g, beta, state)
+        gaps = [float(jnp.max(jnp.abs(a - b)))
+                for a, b in ((got_o, want_o), (got_s, want_s))]
+        assert gaps[0] <= tol and gaps[1] <= tol, (strong, every, gaps)
+        worst = [max(w, x) for w, x in zip(worst, gaps)]
+    log("kernels", f"delta-rule chunk scan {shape}: worst |o - sequential| "
+                   f"{worst[0]:.3g}, worst |state - sequential| "
+                   f"{worst[1]:.3g} (limit {tol})")
+    return worst
+
+
 def phase_kernels(seed):
     checks = [(f"masked flash causal fwd+bwd {s}",
                lambda s=s: check_flash_causal(s, seed))
@@ -233,6 +271,8 @@ def phase_kernels(seed):
         # it is the row (16 x 64 lanes) that has to be whole lane tiles
         ("paged decode bf16 head_dim=64 vs gather reader",
          lambda: check_paged_decode(seed, head_dim=64)),
+        ("delta-rule chunk scan float32 vs the sequential recurrence",
+         lambda: check_delta_rule_scan(seed)),
     ]
     for name, check in checks:
         t0 = time.perf_counter()

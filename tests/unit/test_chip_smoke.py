@@ -45,6 +45,13 @@ def test_train_and_serve_phases_rehearse_on_the_cpu_mesh():
     assert [len(o) for o in outputs] == [9, 13, 26]
 
 
+def test_the_delta_rule_check_rehearses_in_the_interpreter():
+    """Two chunks of a ragged tail, three heads: the check's three cases
+    run and report (o, state) differences far under its limit."""
+    worst = chip_smoke.check_delta_rule_scan(0, shape=(1, 70, 3, 16))
+    assert len(worst) == 2 and max(worst) < 2e-5
+
+
 def test_device_phase_refuses_the_cpu(capsys):
     with pytest.raises(SystemExit) as failure:
         chip_smoke.phase_device(1)

@@ -207,9 +207,14 @@ def test_a_reused_slot_never_sees_its_predecessors_state(model):
     assert _gaps(ref, params, both) < 2e-3
 
 
-def test_the_chunk_scan_from_a_carried_state_equals_the_sequential_form():
+@pytest.mark.parametrize("padding", [0, 136])
+def test_the_chunk_scan_from_a_carried_state_equals_the_sequential_form(
+        padding):
     """``kda_chunk_scan`` from a NON-ZERO state, in two calls that hand
-    the state over, against ``kda_sequential`` over the whole."""
+    the state over, against ``kda_sequential`` over the whole. With
+    ``padding`` the second call is a bucket that much longer than its
+    rows (noise past their lengths): the carried state goes through the
+    64-token turns that are skipped as through the live ones."""
     rs = np.random.RandomState(2)
     B, S, H, d = 2, 96, 3, 16
     r = lambda *s: jnp.asarray(rs.randn(*s), jnp.float32)
@@ -224,11 +229,14 @@ def test_the_chunk_scan_from_a_carried_state_equals_the_sequential_form():
     o_1, s_1 = kda.kda_chunk_scan(q[:, :cut], k[:, :cut], v[:, :cut],
                                   g[:, :cut], beta[:, :cut], start)
     lengths = jnp.asarray([S - cut, S - cut - 9])
-    o_2, s_2 = kda.kda_chunk_scan(q[:, cut:], k[:, cut:], v[:, cut:],
-                                  g[:, cut:], beta[:, cut:], s_1, lengths)
+    tail = lambda a: jnp.concatenate(
+        [a[:, cut:], jnp.abs(r(B, padding, *a.shape[2:]))], axis=1)
+    o_2, s_2 = kda.kda_chunk_scan(tail(q), tail(k), tail(v), -tail(-g),
+                                  tail(beta), s_1, lengths)
+    assert np.isfinite(np.asarray(o_2)).all()
     np.testing.assert_allclose(np.asarray(o_1), np.asarray(want_o[:, :cut]),
                                atol=2e-5)
-    np.testing.assert_allclose(np.asarray(o_2[0]),
+    np.testing.assert_allclose(np.asarray(o_2[0, :S - cut]),
                                np.asarray(want_o[0, cut:]), atol=2e-5)
     np.testing.assert_allclose(np.asarray(s_2[0]), np.asarray(want_s[0]),
                                atol=2e-5)

@@ -65,10 +65,16 @@ def _recurrence_inputs(B, S, H, D, seed=0, strong=False):
     return q, k, v, g, beta, s0
 
 
-@pytest.mark.parametrize("S,strong", [(64, False), (150, False),
-                                      (130, True), (7, False)])
-def test_chunked_scan_equals_the_sequential_recurrence(S, strong):
+@pytest.mark.parametrize("S,strong,beta", [
+    (64, False, None), (150, False, None), (130, True, None),
+    (7, False, None),
+    # the step at its upper end everywhere (an eigenvalue of -1 a token)
+    # under decays no exp(-G) could hold: the kernel's own solve
+    (130, True, 2.0)])
+def test_chunked_scan_equals_the_sequential_recurrence(S, strong, beta):
     args = _recurrence_inputs(2, S, 3, 16, seed=S, strong=strong)
+    if beta is not None:
+        args = args[:4] + (jnp.full_like(args[4], beta),) + args[5:]
     with jax.default_matmul_precision("highest"):
         want_o, want_s = kda.kda_sequential(*args)
         got_o, got_s = jax.jit(kda.kda_chunk_scan)(*args)
@@ -79,15 +85,23 @@ def test_chunked_scan_equals_the_sequential_recurrence(S, strong):
                                atol=2e-5)
 
 
-def test_a_padded_bucket_ends_at_each_rows_true_length():
+@pytest.mark.parametrize("S,true", [
+    (96, (96, 41, 1)),
+    # on a chunk's boundary, one past it, and a row with nothing in it:
+    # two, one and all three of the 64-token turns are skipped
+    (192, (64, 65, 0))])
+def test_a_padded_bucket_ends_at_each_rows_true_length(S, true):
     """Ragged true lengths inside one padded bucket: outputs up to the
-    length and the final state are those of the row alone."""
-    q, k, v, g, beta, s0 = _recurrence_inputs(3, 96, 2, 16, seed=5)
-    lengths = jnp.asarray([96, 41, 1])
+    length and the final state are those of the row alone (a row of
+    length 0 hands its state back as it came), and what lies past a
+    length is finite."""
+    q, k, v, g, beta, s0 = _recurrence_inputs(3, S, 2, 16, seed=5)
+    lengths = jnp.asarray(true)
     with jax.default_matmul_precision("highest"):
         got_o, got_s = jax.jit(kda.kda_chunk_scan)(q, k, v, g, beta, s0,
                                                    lengths)
-        for row, n in enumerate((96, 41, 1)):
+        assert np.isfinite(np.asarray(got_o)).all()
+        for row, n in enumerate(true):
             cut = lambda a: a[row:row + 1, :n]
             want_o, want_s = kda.kda_sequential(
                 cut(q), cut(k), cut(v), cut(g), cut(beta),
@@ -96,6 +110,9 @@ def test_a_padded_bucket_ends_at_each_rows_true_length():
                                        np.asarray(want_o[0]), atol=2e-5)
             np.testing.assert_allclose(np.asarray(got_s[row]),
                                        np.asarray(want_s[0]), atol=2e-5)
+    if 0 in true:
+        np.testing.assert_array_equal(np.asarray(got_s[true.index(0)]),
+                                      np.asarray(s0[true.index(0)]))
 
 
 def test_decode_update_is_one_step_of_the_recurrence_in_its_layer():

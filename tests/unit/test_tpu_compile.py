@@ -980,20 +980,31 @@ def test_delta_rule_decode_update_compiles_in_place_at_published_widths(
     assert not re.search(r"= f32\[3,193,64,128,128\]\S* copy\(", text)
 
 
-@pytest.mark.parametrize("rows,seq", [(1, 256), (4, 1024)])
-def test_delta_rule_chunk_scan_compiles_at_published_widths(rows, seq):
-    """The prefill recurrence over the cell's smallest and largest
-    bucket at 64 heads of 128: chunks of 64, the triangular solve, the
-    scan that carries the state."""
+@pytest.mark.parametrize("rows,seq,heads", [
+    (1, 256, 64), (4, 1024, 64),        # Solar's smallest, largest bucket
+    (1, 2048, 32), (2, 2048, 32)])      # kimi's chunk at one and two rows
+def test_delta_rule_chunk_scan_compiles_at_published_widths(
+        monkeypatch, rows, seq, heads):
+    """The prefill recurrence at heads of 128 with ``lengths`` given:
+    ONE Mosaic kernel (chunks of 64, the solve and the carried state
+    inside it) and no triangular solve left for XLA to expand."""
     from deepspeed_tpu.ops import kda
-    x = _spec((rows, seq, 64, 128), jnp.float32)
-    compiled = _compile(
-        kda.kda_chunk_scan, x, x, x, x, _spec((rows, seq, 64), jnp.float32),
-        _spec((rows, 64, 128, 128), jnp.float32),
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    x = _spec((rows, seq, heads, 128), jnp.float32)
+    lowered = jax.jit(kda.kda_chunk_scan).lower(
+        x, x, x, x, _spec((rows, seq, heads), jnp.float32),
+        _spec((rows, heads, 128, 128), jnp.float32),
         _spec((rows,), jnp.int32))
+    assert "triangular_solve" not in lowered.as_text()
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "triangular" not in text and "while(" not in text
+    # nothing a chunk makes is held outside the kernel: at most a copy of
+    # each 4-D PARAMETER into the (rows, seq, heads x 128) the kernel
+    # reads (inside a model the producers write that form), a fifth over
     memory = compiled.memory_analysis()
-    # what a bucket holds beside 9.7 GB of weights and state
-    assert memory.temp_size_in_bytes < 2.5e9
+    assert memory.temp_size_in_bytes <= 4.8 * rows * seq * heads * 128 * 4
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -1273,9 +1284,9 @@ def test_kimi_linear_serving_programs_keep_one_cache_tree_in_place(
     pool, the state pool and the tails are aliased through the nine
     layers. Decode runs the delta-rule update in place at seven layers
     and the latent reader at two; a chunk runs three grouped products an
-    expert layer and the flash kernel TWICE a latent layer (its own
-    rows, and a block of the prefix a loop turn), and holds no (chunk x
-    table) scores."""
+    expert layer, the chunk scan a delta-rule layer and the flash kernel
+    TWICE a latent layer (its own rows, and a block of the prefix a loop
+    turn), and holds no (chunk x table) scores."""
     import json
     import os
     import sys
@@ -1334,7 +1345,7 @@ def test_kimi_linear_serving_programs_keep_one_cache_tree_in_place(
         b = int(program[-1])
         fn, args = prefill, (ints(b, chunk), ints(b), ints(b),
                              ints(b, pages.pages_per_seq), ints(b))
-        kernels = 3 * 8 + 2 * 2
+        kernels = 3 * 8 + 7 + 2 * 2
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, cache, *args).compile()
     memory = compiled.memory_analysis()
