@@ -425,30 +425,33 @@ def grouped_matmul(lhs, rhs, group_sizes, tile=None):
                tiling=tiling, interpret=jax.default_backend() != "tpu")
 
 
-def _weighted_rows(rows, w, pos, here, block=2048, slabs=False):
+# tokens a block of the TRAINED layer's pick-sums (the served combine
+# passes its own): of 512, 1,024 and 2,048 the fastest on a v5e, the
+# layer alone at train-8k's shapes (docs/smallthinker.md "Counters and
+# scopes")
+_TRAINED_BLOCK = 2048
+
+
+def _weighted_rows(rows, w, pos, here, block):
     """(T, H) float32: token t sums ``w[t, j] * rows[pos[t, j]]`` over
-    its choices j that are ``here``, a block of tokens at a time so that
-    nothing of (T, k, H) is ever held. ``slabs`` lays a block's picks a
-    CHOICE a slab, (k, block, H), and adds the slabs up: (block, k, H)
-    keeps the k choices on the sublanes (ten padded to sixteen) and pays
-    a relayout of every picked row before it sums them (granite's
-    prefill on the chip: 10.2 ms a layer at 8,192 tokens against 5.6 in
-    slabs of 512; docs/solar_open2.md). The same picks, weights and
-    float32 sum, the order of a token's k additions may differ; the
-    trained layer keeps the layout its compiled step was measured with."""
+    its choices j that are ``here``, ``block`` tokens at a time (all of
+    them at once where ``block`` does not divide T) so that nothing of
+    (T, k, H) is ever held. A block's picks lie a CHOICE a slab,
+    (k, block, H), and the slabs add up: (block, k, H) would put the k
+    choices on the sublanes (six or ten padded to sixteen) and pay a
+    relayout of every picked row before it sums them (granite's prefill
+    on the chip: 10.2 ms a layer at 8,192 tokens against 5.6 in slabs of
+    512; docs/solar_open2.md). ONE layout for the trained layer's two
+    pick-sums and the served combine: they differ in ``block`` alone."""
     t, k = pos.shape
     block = block if t % block == 0 else t
 
     def one(args):
         pos_b, w_b, here_b = args
         picked = jnp.where(here_b[..., None], rows[pos_b], 0)
-        return jnp.sum(picked.astype(jnp.float32) * w_b[..., None],
-                       axis=0 if slabs else 1)
+        return jnp.sum(picked.astype(jnp.float32) * w_b[..., None], axis=0)
 
-    if slabs:
-        split = lambda a: a.T.reshape(k, t // block, block).swapaxes(0, 1)
-    else:
-        split = lambda a: a.reshape(t // block, block, k)
+    split = lambda a: a.T.reshape(k, t // block, block).swapaxes(0, 1)
     return jax.lax.map(one, (split(pos), split(w), split(here))
                        ).reshape(t, rows.shape[1])
 
@@ -468,8 +471,8 @@ def _take_rows_fwd(x, tok, pos, here):
 def _take_rows_bwd(res, g):
     pos, here = res
     ones = jnp.ones(pos.shape, jnp.float32)
-    return _weighted_rows(g, ones, pos, here).astype(g.dtype), \
-        None, None, None
+    d_x = _weighted_rows(g, ones, pos, here, _TRAINED_BLOCK)
+    return d_x.astype(g.dtype), None, None, None
 
 
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
@@ -480,7 +483,7 @@ def _combine_rows(ys, w, tok, pos, here, w_sorted):
     """ys (B, H) expert outputs in sorted order -> (T, H): token t sums
     ``w[t, j] * ys[pos[t, j]]`` over its choices j that sit in this
     buffer, in float32."""
-    return _weighted_rows(ys, w, pos, here)
+    return _weighted_rows(ys, w, pos, here, _TRAINED_BLOCK)
 
 
 def _combine_rows_fwd(ys, w, tok, pos, here, w_sorted):
@@ -568,10 +571,12 @@ def dropless_experts(x, idx, p, experts, experts_held, num_experts,
     ``activation`` the gate's (``jax.nn.relu``: ReGLU, SmallThinker's;
     ``jax.nn.silu``: SwiGLU); ``tile`` as :func:`grouped_matmul`'s.
     The TRAINED layer (``models/smallthinker.py``): static turns, each
-    worked whole and recomputed in the backward pass. A served prefill
-    calls :func:`served_experts`, which shares the sort, the grouped
-    product and the combine and not the loop; the caller picks, nothing
-    here does.
+    worked whole and recomputed in the backward pass; a turn sums a
+    token's picks twice, the combine forward and the rows' backward,
+    both :func:`_weighted_rows`. A served prefill calls
+    :func:`served_experts`, which shares the sort, the grouped product
+    and the combine with its layout, and not the loop; the caller picks,
+    nothing here does.
     Returns
     (y (T, H) float32, counts (held,) int32: the assignments that landed
     on each held expert). No assignment is dropped whatever the
@@ -631,7 +636,7 @@ def served_experts(x, idx, p, experts, experts_held, num_experts,
     row tiles they cover) and writes its activations, (rows, F), into
     one buffer; after the last turn ONE product down over that buffer
     under the held experts' true counts gives every row's output, and
-    the tokens combine ONCE from it (:func:`_weighted_rows` in slabs). A row
+    the tokens combine ONCE from it (:func:`_weighted_rows`). A row
     that counts goes through the operands, the float32 accumulation and
     the weights it has in :func:`dropless_experts`; ``y`` of a token
     that does not count is zero. The time follows the router and the
@@ -677,7 +682,7 @@ def served_experts(x, idx, p, experts, experts_held, num_experts,
                 (order.shape[0], experts["w_down"].shape[1]), x.dtype))
         ys = grouped_matmul(act, experts["w_down"], counts, tile)
     with scope("moe_dispatch"):
-        y = _weighted_rows(ys, w, pos, here, block=512, slabs=True)
+        y = _weighted_rows(ys, w, pos, here, block=512)
     return y, counts, jnp.stack(
         [turns * rows, jnp.int32(-(-t * top_k // static) * static)])
 

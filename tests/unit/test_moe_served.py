@@ -139,6 +139,34 @@ def test_served_experts_equal_the_dropless_layer(activation, tile, first,
         assert not np.asarray(y).any()
 
 
+@pytest.mark.parametrize("top_k", [2, 6, 10])
+@pytest.mark.parametrize("block", [32, 40],
+                         ids=["block_divides", "block_does_not"])
+@pytest.mark.parametrize("share_here", [0.4, 0.0],
+                         ids=["some_here", "no_pick_here"])
+def test_weighted_rows_equal_a_plain_sum_over_picks(top_k, block,
+                                                    share_here):
+    """The ONE layout of `_weighted_rows` (a block's picks a choice a
+    slab), the trained layer's and the served combine's: token t sums
+    `w[t, j] * rows[pos[t, j]]` over its choices that are `here`, in
+    float32, whatever k, whether `block` divides the tokens or not, and
+    exactly zero where no pick is here."""
+    from deepspeed_tpu.ops.moe import _weighted_rows
+    tokens, hidden, buffer = 96, 32, 160
+    rng = np.random.default_rng(top_k)
+    rows = jnp.asarray(rng.standard_normal((buffer, hidden)), jnp.bfloat16)
+    w = jnp.asarray(rng.random((tokens, top_k)), jnp.float32)
+    pos = jnp.asarray(rng.integers(0, buffer, (tokens, top_k)), jnp.int32)
+    here = jnp.asarray(rng.random((tokens, top_k)) < share_here)
+    got = jax.jit(lambda *a: _weighted_rows(*a, block))(rows, w, pos, here)
+    picks = jnp.where(here[..., None], rows[pos].astype(jnp.float32), 0.0)
+    want = jnp.sum(picks * w[..., None], axis=1)
+    assert got.dtype == jnp.float32 and got.shape == (tokens, hidden)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+    if not share_here:
+        assert not np.asarray(got).any()
+
+
 @pytest.mark.parametrize("true_tokens", [0, 1, 37, 96])
 def test_served_experts_work_the_counted_rows_alone(true_tokens):
     """A mask of the tokens that count (a bucket's true positions): their

@@ -212,6 +212,27 @@ def _dense_experts(x, idx, p, experts, first):
     return reference._experts(x, None, p, idx, experts, first, None)
 
 
+def _layer_and_gradients_equal_the_dense_sum(x, idx, p, experts, experts_n):
+    """`dropless_experts` over the first held experts of `experts_n`: y
+    and the gradients to x, p and the tables against the dense sum's.
+    Returns the layer's counts."""
+    held = (0, experts["w_gate"].shape[0])
+    layer = lambda x, p, e: moe.dropless_experts(
+        x, idx, p, e, held, experts_n, jax.nn.relu)
+    dense = lambda x, p, e: _dense_experts(x, idx, p, e, 0)
+    y, counts = layer(x, p, experts)
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(dense(x, p, experts)), atol=2e-5)
+    loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)))
+    got = jax.grad(loss(lambda *a: layer(*a)[0]),
+                   argnums=(0, 1, 2))(x, p, experts)
+    ref = jax.grad(loss(dense), argnums=(0, 1, 2))(x, p, experts)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(ref)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
+    return counts
+
+
 def test_no_token_is_dropped_when_one_expert_takes_half():
     """Every token's first choice is expert 0, so it takes half of the
     assignments (k = 2): more than one turn's buffer of a chip that holds
@@ -228,20 +249,40 @@ def test_no_token_is_dropped_when_one_expert_takes_half():
     experts = jax.tree_util.tree_map(lambda a: a[:2],
                                      _experts(jax.random.PRNGKey(2)))
     rows = moe.chunk_rows(t * k, 2, 8)
-    y, counts = moe.dropless_experts(x, idx, p, experts, (0, 2), 8,
-                                     jax.nn.relu)
+    counts = _layer_and_gradients_equal_the_dense_sum(x, idx, p, experts, 8)
     assert int(counts[0]) == t and int(counts.sum()) > rows
-    want = _dense_experts(x, idx, p, experts, 0)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-5)
-    loss = lambda f: lambda x, p, e: jnp.sum(jnp.sin(f(x, p, e)))
-    got = jax.grad(loss(lambda x, p, e: moe.dropless_experts(
-        x, idx, p, e, (0, 2), 8, jax.nn.relu)[0]),
-        argnums=(0, 1, 2))(x, p, experts)
-    ref = jax.grad(loss(lambda x, p, e: _dense_experts(x, idx, p, e, 0)),
-                   argnums=(0, 1, 2))(x, p, experts)
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(ref)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
+
+
+@pytest.mark.parametrize("top_k", [2, 6, 10])
+@pytest.mark.parametrize("block", [128, 192],
+                         ids=["block_divides", "block_does_not"])
+@pytest.mark.parametrize("held_share", [0.25, 0.7], ids=[
+    "second_turn_holds_no_pick", "landed_rows_pass_the_first_turn"])
+def test_the_trained_pick_sums_differentiate_like_the_dense_sum(
+        monkeypatch, top_k, block, held_share):
+    """The layer's two pick-sums a turn, the combine forward and
+    `_take_rows`' backward, in the one layout (a choice a slab): y and
+    the gradients to x (through `_take_rows_bwd`), to p and the tables
+    (through `_combine_rows_bwd`) are `jax.grad`'s of the dense expert
+    sum, at every k a served family has, in blocks that divide the
+    tokens and in one that does not, where the landed rows run into the
+    second turn and where that turn holds no pick at all."""
+    t, experts_n, held = 512, 16, 4
+    monkeypatch.setattr(moe, "_TRAINED_BLOCK", block)
+    rs = np.random.RandomState(top_k)
+    x = jnp.asarray(rs.standard_normal((t, 32)), jnp.float32)
+    idx = jnp.asarray(np.where(
+        rs.random_sample((t, top_k)) < held_share,
+        rs.randint(0, held, (t, top_k)),
+        rs.randint(held, experts_n, (t, top_k))), jnp.int32)
+    p = jax.nn.softmax(jnp.asarray(rs.standard_normal((t, top_k)),
+                                   jnp.float32), -1)
+    experts = _experts(jax.random.PRNGKey(2), e=held)
+    rows = moe.chunk_rows(t * top_k, held, experts_n)
+    assert rows * 2 == t * top_k                       # two turns
+    counts = _layer_and_gradients_equal_the_dense_sum(x, idx, p, experts,
+                                                      experts_n)
+    assert (int(counts.sum()) > rows) == (held_share > 0.5)
 
 
 def test_the_four_shares_add_up_to_the_whole_layer(model):

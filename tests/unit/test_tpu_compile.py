@@ -937,6 +937,42 @@ def test_grouped_product_compiles_at_the_expert_widths(monkeypatch, k, n,
     assert calls >= {"fwd": 1, "bwd": 2}[direction]
 
 
+def test_trained_expert_layer_sums_its_picks_a_choice_a_slab(monkeypatch):
+    """`jax.grad` of ONE trained expert layer at train-8k's shapes
+    (16,384 tokens x top 6 of 64, 16 held, 2,560 x 768, bfloat16): each
+    of its pick-sums (the combine forward, `_take_rows`' backward) lays
+    a block's picks (k, block, H). Nothing of shape (block, k, H) may be
+    in the compiled text but a bitcast: there the six choices lie on
+    sixteen sublanes and every picked row is written out once more
+    before it is summed (a `reshape` of 2.05 ms a pair of turns beside a
+    reduce of 1.84 where slabs read 0 and 0.42: PERF.md, PR 50)."""
+    from deepspeed_tpu.ops import moe
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    tokens, top_k, hidden, ffn, held, experts = 16384, 6, 2560, 768, 16, 64
+
+    def layer_grads(x, idx, p, tables):
+        def loss(x, p, tables):
+            y, _ = moe.dropless_experts(x, idx, p, tables, (0, held),
+                                        experts, jax.nn.relu)
+            return jnp.sum(y * y)
+        return jax.grad(loss, argnums=(0, 1, 2))(x, p, tables)
+
+    compiled = _compile(
+        layer_grads, _spec((tokens, hidden)),
+        _spec((tokens, top_k), jnp.int32), _spec((tokens, top_k), jnp.float32),
+        {"w_gate": _spec((held, hidden, ffn)),
+         "w_up": _spec((held, hidden, ffn)),
+         "w_down": _spec((held, ffn, hidden))})
+    block = moe._TRAINED_BLOCK
+    made = lambda shape: re.findall(
+        r"= \w+\[%s\]\S* ([a-z-]+)\(" % shape, compiled.as_text())
+    assert made(f"{top_k},{block},{hidden}").count("gather") == 2
+    assert set(made(f"{block},{top_k},{hidden}")) <= {"bitcast"}
+    # the parent's reading, in (2048, k, H): 1,591,955,456; in slabs
+    # 1,592,084,480 (the padded copy lay where other temporaries peak)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1.60e9
+
+
 @pytest.mark.parametrize("k,n", [(4096, 1280), (1280, 4096)],
                          ids=["gate_up", "down"])
 def test_grouped_product_compiles_at_the_served_expert_widths(monkeypatch,
