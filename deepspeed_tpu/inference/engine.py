@@ -100,6 +100,7 @@ from deepspeed_tpu.inference.disagg import (DispatchTrace, HandoffQueue,
 from deepspeed_tpu.inference.draft import make_drafter
 from deepspeed_tpu.inference.kv_cache import (LatentStateCache,
                                               PageAllocator, PagedStateCache,
+                                              PagedTailCache,
                                               cache_spec_for,
                                               init_kv_cache,
                                               init_paged_kv_cache,
@@ -122,6 +123,8 @@ from deepspeed_tpu.models.kimi_linear import (KimiLinearConfig,
                                               init_kimi_linear_params,
                                               kimi_linear_forward,
                                               kimi_linear_param_specs)
+from deepspeed_tpu.models.lfm2 import (LFM2Config, init_lfm2_params,
+                                       lfm2_forward, lfm2_param_specs)
 from deepspeed_tpu.models.llama import (LlamaConfig, init_llama_params,
                                         llama_forward, llama_param_specs)
 from deepspeed_tpu.models.solar_open2 import (SolarOpen2Config,
@@ -160,6 +163,7 @@ _FAMILIES = {
     AXK1Config: ("axk1", axk1_forward, init_axk1_params, axk1_param_specs),
     KimiLinearConfig: ("kimi_linear", kimi_linear_forward,
                        init_kimi_linear_params, kimi_linear_param_specs),
+    LFM2Config: ("lfm2", lfm2_forward, init_lfm2_params, lfm2_param_specs),
 }
 
 
@@ -611,7 +615,8 @@ class InferenceEngine:
             if self.state_spec is not None:
                 # two more leaves of the one cache tree, one row a slot,
                 # each leaf by name for the family's forward
-                tree = LatentStateCache if self.latent else PagedStateCache
+                tree = (LatentStateCache if self.latent else PagedStateCache
+                        if self.state_spec.has_state else PagedTailCache)
                 self._cache = tree(
                     *self._cache, *init_state_pool(self.state_spec))
             # static pool cost per token of capacity — the
@@ -733,12 +738,14 @@ class InferenceEngine:
                         f"{self._decode_attn_path} "
                         f"({self._decode_attn_reason})")
             else:
+                kept = ("state pool" if self.state_spec.has_state
+                        else "convolution tails")
                 geom = (f"paged KV cache: {self.paged_spec.num_pages} "
                         f"pages x {self.paged_spec.page_size} tokens over "
                         f"{self.paged_spec.num_layers} softmax layers "
-                        f"({cache_bytes / 2**20:.1f} MiB), state pool "
+                        f"({cache_bytes / 2**20:.1f} MiB), {kept} "
                         f"{self.state_spec.rows} rows over "
-                        f"{self.state_spec.num_layers} recurrent layers "
+                        f"{self.state_spec.num_layers} per-slot layers "
                         f"({state_pool_bytes(self.state_spec) / 2**20:.1f}"
                         f" MiB), decode attn {self._decode_attn_path}")
         elif self.paged:
@@ -832,21 +839,26 @@ class InferenceEngine:
         position: every feature that takes "state = pages + position"
         for granted would serve this family WRONGLY, so each is refused
         here, once, with what would have to exist (docs/solar_open2.md).
-        """
-        self._refuse_asked(cfg, mesh, "a per-slot recurrent state", {
+        The same list, word for word, for a family whose slot keeps a
+        short convolution's tail and no state (``models/lfm2.py``: the
+        last products before the pending position; docs/lfm2.md)."""
+        name, short = (("recurrent state", "state")
+                       if self.state_spec.has_state
+                       else ("convolution tail", "tail"))
+        self._refuse_asked(cfg, mesh, f"a per-slot {name}", {
             "dense_cache": "the dense cache (paged_kv.enabled: false): "
-            "the state pool is a leaf of the paged cache tree",
+            f"the {short} pool is a leaf of the paged cache tree",
             "prefix_cache": "the prefix cache (paged_kv.prefix_cache): a "
-            "shared prefix's pages carry no recurrent state; it needs a "
-            "state snapshot at every shared page boundary",
+            f"shared prefix's pages carry no {name}; it needs a "
+            f"{short} snapshot at every shared page boundary",
             "chunked_prefill": "chunked prefill: a chunk would have to "
-            "start from the state its predecessor left, and prefill "
+            f"start from the {short} its predecessor left, and prefill "
             "starts every row from an empty one",
             "spec_decode": "speculative decoding: a rejected draft is "
-            "rolled back by position, and the state has already absorbed "
-            "it",
+            f"rolled back by position, and the {short} has already "
+            "absorbed it",
             "disagg": "disaggregated prefill/decode: the handoff moves "
-            "pages, not a slot's state row",
+            f"pages, not a slot's {short} row",
             "int8_pool": "an int8 page pool: the family's softmax layers "
             "read their pages without scales",
             "quantized_weights": "quantized weights: the family holds "
@@ -1319,7 +1331,9 @@ class InferenceEngine:
         """What a serving slot's row of the state pool holds, for a
         family that keeps one: (the tokens the state has absorbed — the
         prompt and every served token but the pending one —, the row
-        ``(recurrent layers, heads, dk, dv)`` float32 on the host). None
+        ``(recurrent layers, heads, dk, dv)`` float32 on the host; for a
+        family that keeps tails and no state its row of the tails,
+        ``(convolution layers, positions, channels)``). None
         for an empty slot or one still waiting for its first token. Call
         between :meth:`step` calls (tests, the benchmark's comparison of
         the pool with the reference's recurrence)."""
@@ -1331,7 +1345,9 @@ class InferenceEngine:
             return None
         absorbed = (list(slot.request.prompt)
                     + list(slot.tokens))[:slot.position]
-        return absorbed, np.asarray(self._cache.state[:, slot_id])
+        row = (self._cache.state if self.state_spec.has_state
+               else self._cache.tails)
+        return absorbed, np.asarray(row[:, slot_id])
 
     # ------------------------------------------- live KV migration (16)
     def _refuse_migration_with_state(self):
@@ -1342,9 +1358,11 @@ class InferenceEngine:
                 f"request of this family cannot be exported, imported "
                 f"or migrated")
         if self.state_spec is not None:
+            kept = ("recurrent state" if self.state_spec.has_state
+                    else "convolution tail")
             raise NotImplementedError(
                 f"{type(self.model_config).__name__} keeps a per-slot "
-                f"recurrent state that a MigrationRecord does not carry: "
+                f"{kept} that a MigrationRecord does not carry: "
                 f"a request of this family cannot be exported, imported "
                 f"or migrated")
 
@@ -2168,23 +2186,31 @@ class InferenceEngine:
                     # row's null page in one turn; the gather reader
                     # walks none (it reads the table's whole width)
                     ps = self.paged_spec.page_size
-                    walks = ([live_pages(p, ps) for p in poss]
-                             + [1] * (self._rows - len(sids))
-                             if self._decode_attn_path == "pallas" else [])
                     per_turn = block_pages(ps, latent=self.latent)
-                    counters.update(
-                        read_pages=sum(walks),
-                        read_turns=sum(-(-w // per_turn) for w in walks),
-                        block_tokens=per_turn * ps if walks else 0)
+                    if self._decode_attn_path == "pallas":
+                        walks = live_pages(np.asarray(poss, np.int64), ps)
+                        idle = self._rows - len(sids)
+                        counters.update(
+                            read_pages=int(walks.sum()) + idle,
+                            read_turns=int((-(-walks // per_turn)).sum())
+                            + idle,
+                            block_tokens=per_turn * ps)
+                    else:
+                        counters.update(read_pages=0, read_turns=0,
+                                        block_tokens=0)
                 if self._expert_counters is not None:
                     # routed experts: the rows that decode, and what the
                     # router did with them the step before
                     per_row, held = self._expert_counters
+                    layers = per_row // self.model_config.experts_per_token
                     counters.update(
                         active=len(sids), held=held,
                         assignments=len(sids) * per_row,
                         landed=self._moe_counts[0],
-                        fullest=self._moe_counts[1])
+                        fullest=self._moe_counts[1],
+                        # every held expert on every row of the slot
+                        # table (``held_experts_every_row``): static
+                        expert_rows_worked=self._rows * held * layers)
                     if len(self._moe_counts) > 2:
                         # a group-limited router: the rows whose kept
                         # groups include a group held here
@@ -2221,7 +2247,8 @@ class InferenceEngine:
                 # Serve/token_latency_ms (verify's too): the phase's
                 # first host work to the tokens' arrival on the host
                 tok_ms = ledger.ready()
-            runs = {sid: [int(nxt[sid])] for sid in sids}
+            nxt = nxt.tolist()      # Python ints once, not a row
+            runs = {sid: [nxt[sid]] for sid in sids}
             if self.spec:
                 # speculation on, drafter had nothing anywhere: the
                 # whole dispatch fell back to plain decode

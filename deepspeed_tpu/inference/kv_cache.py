@@ -53,6 +53,13 @@ the next chunk starts from it, a chunk at position 0 from zeros (a
 reused slot never sees its predecessor's state), and a decode dispatch
 leaves the row of a slot that is mid-prefill as it is.
 
+A family whose per-slot layers are gated short convolutions
+(``models/lfm2.py``, ``tail_geometry``) keeps the tails and NO state:
+its tree is ``(keys, values, tails)`` (:class:`PagedTailCache`), the
+state leaf absent, not a zero-sized stand-in. A prefill writes a slot's
+tail row whole at its rows' TRUE ends; decode rewrites the rows that
+decode and leaves the others as they are.
+
 **The latent pool (in place of the pair)** — a family with multi-head
 latent attention (``models/axk1.py``) caches ONE row a token a layer,
 ``[c (latent_width) | k_r (rope_width)]`` after the norm and the
@@ -86,7 +93,8 @@ __all__ = ["KVCacheSpec", "cache_spec_for", "init_kv_cache",
            "init_paged_kv_cache", "paged_kv_bytes", "pages_for",
            "PageAllocator", "StatePoolSpec", "state_pool_spec_for",
            "init_state_pool", "state_pool_bytes", "PagedStateCache",
-           "LatentStateCache", "LatentPoolSpec", "latent_row_lanes"]
+           "PagedTailCache", "LatentStateCache", "LatentPoolSpec",
+           "latent_row_lanes"]
 
 
 class KVCacheSpec(NamedTuple):
@@ -310,8 +318,11 @@ def paged_kv_bytes(spec: PagedKVSpec) -> int:
 # --------------------------------------------------------------------- #
 class StatePoolSpec(NamedTuple):
     """Static geometry of the per-slot state pool (module docstring):
-    ``rows`` is the serving slots + 1 scratch row."""
-    num_layers: int      # the recurrent layers only
+    ``rows`` is the serving slots + 1 scratch row. A family whose
+    per-slot layers keep a convolution's tail and NO recurrent state
+    (``models/lfm2.py``) has ``heads`` 0: its pool is the tails alone
+    (:attr:`has_state`), and no state leaf is built."""
+    num_layers: int      # the per-slot (recurrent / convolution) layers
     rows: int
     heads: int
     key_dim: int
@@ -330,16 +341,27 @@ class StatePoolSpec(NamedTuple):
         return (self.num_layers, self.rows, self.tail_positions,
                 self.tail_channels)
 
+    @property
+    def has_state(self) -> bool:
+        return self.heads > 0
+
 
 def state_pool_spec_for(model_config, rows: int,
                         tail_dtype=jnp.bfloat16) -> Optional[StatePoolSpec]:
     """The state pool's geometry from a model config's
     ``state_geometry`` (recurrent layers, heads, key width, value width,
-    tail positions, tail channels); None for a family that keeps no
-    state beside its keys and values."""
+    tail positions, tail channels), or from its ``tail_geometry``
+    (convolution layers, tail positions, tail channels) where a slot
+    keeps tails and no state; None for a family that keeps nothing a
+    slot beside its keys and values."""
     geometry = getattr(model_config, "state_geometry", None)
     if geometry is None:
-        return None
+        tails = getattr(model_config, "tail_geometry", None)
+        if tails is None:
+            return None
+        layers, positions, channels = tails
+        return StatePoolSpec(layers, rows, 0, 0, 0, positions, channels,
+                             tail_dtype)
     layers, heads, dk, dv, positions, channels = geometry
     return StatePoolSpec(layers, rows, heads, dk, dv, positions, channels,
                          tail_dtype)
@@ -357,6 +379,15 @@ class PagedStateCache(NamedTuple):
     tails: Any
 
 
+class PagedTailCache(NamedTuple):
+    """The cache tree of a family whose per-slot layers are short
+    convolutions (``models/lfm2.py``): the page pools of its softmax
+    layers and the convolutions' tails, one row a slot; NO state leaf."""
+    keys: Any
+    values: Any
+    tails: Any
+
+
 class LatentStateCache(NamedTuple):
     """The cache tree of a family with latent attention AND recurrent
     layers (``models/kimi_linear.py``): the ONE latent page pool of its
@@ -371,11 +402,16 @@ class LatentStateCache(NamedTuple):
 def init_state_pool(spec: StatePoolSpec):
     """The zeroed ``(state, tails)`` leaves: float32 states (the
     recurrence is float32 whatever the engine computes in), the tails in
-    the engine's compute dtype (they are matmul outputs of it)."""
-    return (jnp.zeros(spec.state_shape, jnp.float32),
-            jnp.zeros(spec.tail_shape, spec.tail_dtype))
+    the engine's compute dtype (they are matmul outputs of it). A spec
+    without a state (:attr:`StatePoolSpec.has_state`): ``(tails,)``."""
+    tails = jnp.zeros(spec.tail_shape, spec.tail_dtype)
+    if not spec.has_state:
+        return (tails,)
+    return (jnp.zeros(spec.state_shape, jnp.float32), tails)
 
 
 def state_pool_bytes(spec: StatePoolSpec) -> int:
-    return int(np.prod(spec.state_shape)) * 4 + int(
+    """Bytes of the leaves :func:`init_state_pool` builds."""
+    state = int(np.prod(spec.state_shape)) * 4 if spec.has_state else 0
+    return state + int(
         np.prod(spec.tail_shape)) * jnp.dtype(spec.tail_dtype).itemsize

@@ -124,7 +124,7 @@ class PrefillBatch:
     page_tables: List[List[int]] = field(default_factory=list)
 
 
-@dataclass
+@dataclass(slots=True)
 class _Slot:
     request: Request
     position: int                # tokens currently in this row's cache
@@ -611,8 +611,12 @@ class Scheduler:
         now = self._clock()
         tracer = self.tracer
         done: List[FinishedRequest] = []
+        # the decode tokens' uids, handed to the tracer in ONE call
+        # (before any request of this call is finished there)
+        decoded: List[int] = []
+        slots = self.slots
         for sid, run in runs.items():
-            slot = self.slots[sid]
+            slot = slots[sid]
             if slot is None:
                 raise KeyError(f"slot {sid} is not active")
             req = slot.request
@@ -634,8 +638,8 @@ class Scheduler:
                     self._new_ttfts.append(slot.ttft_ms)
                     if tracer is not None:
                         tracer.on_first_token(req.uid, slot.ttft_ms)
-                elif tracer is not None:
-                    tracer.on_token(req.uid)
+                else:
+                    decoded.append(req.uid)
                 slot.tokens.append(tok)
                 slot.pending_tok = tok
                 self.total_tokens += 1
@@ -666,8 +670,10 @@ class Scheduler:
                 done.append(fin)
                 self._release(slot)
                 self.slots[sid] = None
-                if tracer is not None:
-                    tracer.on_finish(fin)
+        if tracer is not None:
+            tracer.on_tokens(decoded)
+            for fin in done:
+                tracer.on_finish(fin)
         self.finished.extend(done)
         self.peak_tokens_in_flight = max(self.peak_tokens_in_flight,
                                          self.tokens_in_flight)
@@ -831,17 +837,16 @@ class Scheduler:
         (slot_ids, toks, positions, temps, seeds) — inactive rows carry
         zeros and are ignored on the way back. Empty when nothing is
         mid-decode."""
-        sids, toks, poss, temps, seeds = [], [], [], [], []
-        for sid in self.active_slots():
-            slot = self.slots[sid]
-            if slot.pending_tok is None:
-                continue        # admitted this step; first token pending
-            sids.append(sid)
-            toks.append(slot.pending_tok)
-            poss.append(slot.position)
-            temps.append(slot.request.temperature)
-            seeds.append(slot.request.seed)
-        return sids, toks, poss, temps, seeds
+        rows = [(sid, slot.pending_tok, slot.position,
+                 slot.request.temperature, slot.request.seed)
+                for sid, slot in enumerate(self.slots)
+                # None: admitted this step; first token pending
+                if slot is not None and slot.pending_tok is not None]
+        if not rows:
+            return [], [], [], [], []
+        sids, toks, poss, temps, seeds = zip(*rows)
+        return (list(sids), list(toks), list(poss), list(temps),
+                list(seeds))
 
     def block_table_rows(self, rows: int, pages_per_seq: int) -> np.ndarray:
         """The decode dispatch's static-shape block tables: one

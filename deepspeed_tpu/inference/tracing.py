@@ -102,7 +102,7 @@ SHED_REASONS = ("shed_slo", "shed_capacity", "degrade_max_new",
                 "degrade_spec_off", "drain", "reject_too_long")
 
 
-@dataclass
+@dataclass(slots=True)
 class _ReqTrace:
     """Host-side per-request stamps (tracer clock)."""
     uid: int
@@ -439,32 +439,43 @@ class ServeTracer:
         """One decode token for ``uid``: a time-between-tokens sample,
         plus the sampled ``serve_decode_window`` row at window
         boundaries."""
+        self.on_tokens((uid,))
+
+    def on_tokens(self, uids) -> None:
+        """``on_token`` for each of ``uids`` in order at ONE reading of
+        the clock: a dispatch's tokens reach the host together, so the
+        scheduler hands over a step's decode tokens in one call (a uid
+        may repeat: a speculative run's kept tokens)."""
         if not self.enabled:
             return
-        tr = self._req.get(uid)
-        if tr is None or tr.t_last is None:
-            return
         now = self._clock()
-        tbt = (now - tr.t_last) * 1e3
-        tr.t_last = now
-        tr.n_tokens += 1
-        tr.tbt_sum += tbt
-        tr.tbt_max = max(tr.tbt_max, tbt)
-        self.hist["tbt_ms"].record(tbt)
-        self._step_tbts.append(tbt)
-        tr.window_tokens += 1
-        tr.window_intervals += 1
-        if self.window_tokens and tr.window_tokens >= self.window_tokens:
-            window_ms = (now - tr.window_t0) * 1e3
-            self._event(
-                "serve_decode_window", uid=uid, tokens=tr.window_tokens,
-                end_token=tr.n_tokens,
-                window_ms=self._r(window_ms),
-                tbt_ms=self._r(window_ms / max(tr.window_intervals, 1)),
-                **self._ctx(uid))
-            tr.window_t0 = now
-            tr.window_tokens = 0
-            tr.window_intervals = 0
+        reqs, window_at, tbts = self._req, self.window_tokens, []
+        for uid in uids:
+            tr = reqs.get(uid)
+            if tr is None or tr.t_last is None:
+                continue
+            tbt = (now - tr.t_last) * 1e3
+            tr.t_last = now
+            tr.n_tokens += 1
+            tr.tbt_sum += tbt
+            if tbt > tr.tbt_max:
+                tr.tbt_max = tbt
+            tbts.append(tbt)
+            tr.window_tokens += 1
+            tr.window_intervals += 1
+            if window_at and tr.window_tokens >= window_at:
+                window_ms = (now - tr.window_t0) * 1e3
+                self._event(
+                    "serve_decode_window", uid=uid, tokens=tr.window_tokens,
+                    end_token=tr.n_tokens,
+                    window_ms=self._r(window_ms),
+                    tbt_ms=self._r(window_ms / max(tr.window_intervals, 1)),
+                    **self._ctx(uid))
+                tr.window_t0 = now
+                tr.window_tokens = 0
+                tr.window_intervals = 0
+        self.hist["tbt_ms"].record_many(tbts)
+        self._step_tbts.extend(tbts)
 
     def on_finish(self, fin, evicted: bool = False) -> None:
         """Terminal hook — ``fin`` is the scheduler's

@@ -18,3 +18,5 @@ from deepspeed_tpu.models.solar_open2 import (
     SolarOpen2Config, init_solar_open2_params, solar_open2_forward)
 from deepspeed_tpu.models.axk1 import (
     AXK1Config, axk1_forward, init_axk1_params)
+from deepspeed_tpu.models.lfm2 import (
+    LFM2Config, init_lfm2_params, lfm2_forward)

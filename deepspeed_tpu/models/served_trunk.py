@@ -95,16 +95,20 @@ class ServedFamily(NamedTuple):
     chunked: bool = False
 
 
-def paged_pair_mixer(softmax_mixer):
+def paged_pair_mixer(softmax_mixer, positions=False):
     """``softmax_mixer(ap, config, h, dtype, _Pages or None) -> (y, the
     pools)`` as the trunk calls a mixer: the ``n``-th paged layer over
-    the ``keys`` and ``values`` of a ``kv_cache.PagedStateCache``."""
+    the ``keys`` and ``values`` of a cache tree that names them
+    (``kv_cache.PagedStateCache``, ``PagedTailCache``). With
+    ``positions`` the mixer rotates: it also takes the trunk's
+    ``token_positions`` (B, S)."""
     def mixer(lp, h, call, cache, n):
         pages = None if cache is None else _Pages(
             (cache.keys, cache.values), n, call.tables, call.positions,
             call.index, call.reader)
+        more = (call.token_positions,) if positions else ()
         y, pools = softmax_mixer(lp["attn"], call.config, h, call.dtype,
-                                 pages)
+                                 pages, *more)
         if cache is None:
             return y, None
         return y, cache._replace(keys=pools[0], values=pools[1])
@@ -121,7 +125,7 @@ def whole_leaf_specs(init, config):
 
 
 def _expert_half(lp, family, x, call, active):
-    """x -> (x + r (routed + shared), this layer's int32 counters). One
+    """x -> (x + r (routed [+ shared]), this layer's int32 counters). One
     token a row (decode) works every held expert on every row, and the
     counters are (landed, fullest): assignments of ``active`` rows that
     fell on held experts, and the fullest held expert's (then the
@@ -133,7 +137,9 @@ def _expert_half(lp, family, x, call, active):
     ``dropless_experts``: that is the TRAINED layer, whose time must not
     follow the router and whose turns differentiate; what a padded
     position's experts give is read by nothing (causal attention, a scan
-    to the true lengths, the logits of the last true position)."""
+    to the true lengths, the logits of the last true position). A layer
+    with a ``shared`` expert adds it; one without has none in its
+    program."""
     config, dtype = call.config, call.dtype
     B, S, hdim = x.shape
     h2 = _norm(x, lp["ln_2"]["w"], config.rms_norm_eps)
@@ -160,8 +166,9 @@ def _expert_half(lp, family, x, call, active):
         y, _, counters = served_experts(
             rows, idx, p, experts, config.held, config.num_experts,
             jax.nn.silu, tile=family.expert_tile, counted=counted)
-    with scope("moe_shared"):
-        y = y + _swiglu(lp["shared"], flat, dtype)
+    if "shared" in lp:
+        with scope("moe_shared"):
+            y = y + _swiglu(lp["shared"], flat, dtype)
     with scope("moe_dispatch"):
         x = _residual(family, x, y.reshape(B, S, hdim))
     return x, counters

@@ -77,6 +77,11 @@ DEVICE_SCOPES = (
     # gathered through the block table, expanded, put to the flash
     # kernel a block at a time and merged into the own rows' softmax
     "mla_prefix",
+    # gated short convolutions among rotary attention (models/lfm2.py):
+    # a convolution layer's two products (W_in, W_out); its gates, the
+    # convolution and the tail; an attention layer's norm a head and
+    # rotation of queries and keys, between `attn_proj` and `attn_core`
+    "conv_proj", "conv_core", "attn_norm_rope",
 )
 
 # host phase spans (TraceAnnotation), each parent before its children

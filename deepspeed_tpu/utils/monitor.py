@@ -139,6 +139,31 @@ class Histogram:
         self.min = v if self.min is None else min(self.min, v)
         self.max = v if self.max is None else max(self.max, v)
 
+    def record_many(self, values) -> None:
+        """``record`` for each of ``values`` in order, the same counts
+        and sums to the bit: one call a decode step instead of one a
+        token (256 slots: the per-token calls were a fifth of the
+        host's section of a step)."""
+        buckets, floor, bins = self._buckets, self.floor, self.bins_per_decade
+        isfinite, log10 = math.isfinite, math.log10
+        count, total, lo, hi = self.count, self.sum, self.min, self.max
+        last = b = None
+        for v in values:
+            v = float(v)
+            if v != last:       # a step's samples are mostly one value
+                if not isfinite(v):
+                    continue
+                last = v
+                b = 0 if v <= floor else 1 + int(log10(v / floor) * bins)
+            buckets[b] = buckets.get(b, 0) + 1
+            count += 1
+            total += v
+            if lo is None or v < lo:
+                lo = v
+            if hi is None or v > hi:
+                hi = v
+        self.count, self.sum, self.min, self.max = count, total, lo, hi
+
     @property
     def mean(self) -> Optional[float]:
         return self.sum / self.count if self.count else None

@@ -29,7 +29,8 @@ def test_new_cell_rehearses_on_the_cpu(tmp_path):
             if ln.startswith("[bench] rehearsal on cpu")]
     assert last, out.stdout[-2000:]
     line = json.loads(last[0].split("): ", 1)[1])
-    assert line["correct"] and line["failed"] == 0 and line["attempted"] > 0
+    assert line["correct"] and line["failed"] == 0 and line["attempted"] > 0, \
+        out.stdout[-2000:]
     assert set(line["metrics"]) == {"train_tokens_per_s", "setup_s"}
 
 

@@ -160,6 +160,31 @@ class TestScheduler:
         with pytest.raises(ValueError, match="empty"):
             Request(prompt=[])
 
+    def test_a_steps_decode_tokens_reach_the_tracer_in_one_call(self):
+        """``record_token_runs`` hands the tracer a call's decode tokens
+        at once, AFTER every first token of the call and BEFORE any of
+        its requests is finished there (a finishing request's last
+        interval is in its finish row)."""
+        from deepspeed_tpu.inference.scheduler import Request, Scheduler
+        calls = []
+
+        class Tracer:
+            def __getattr__(self, name):
+                return lambda *a, **k: calls.append((name, a))
+        s = Scheduler(3, (4, 8), (1, 2), 32, tracer=Tracer())
+        ra = Request(prompt=[1, 2, 3], max_new_tokens=2)
+        rb = Request(prompt=[4, 5], max_new_tokens=3)
+        s.submit(ra), s.submit(rb)
+        (batch,) = s.admit()
+        a, b = batch.slot_ids
+        s.record_tokens({a: 7, b: 8})
+        del calls[:]
+        done = s.record_token_runs({a: [9], b: [10]})
+        assert [f.uid for f in done] == [ra.uid]
+        assert [c[0] for c in calls] == ["on_tokens", "on_finish"]
+        assert list(calls[0][1][0]) == [ra.uid, rb.uid]
+        assert calls[1][1][0].tokens == [7, 9]
+
     def test_admission_groups_by_bucket_fifo(self):
         from deepspeed_tpu.inference.scheduler import Request
         s = self._sched(slots=3)

@@ -208,3 +208,59 @@ def test_a_served_turn_comes_from_the_shapes(assignments, held, experts,
                                              tile, want):
     from deepspeed_tpu.ops.moe import served_turn_rows
     assert served_turn_rows(assignments, held, experts, tile) == want
+
+
+def _decode_case(tokens, hidden, ffn, experts, top_k, seed=11):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    n = lambda k, shape: jax.random.normal(k, shape, jnp.float32) * 0.3
+    x = n(ks[0], (tokens, hidden))
+    tables = {"w_gate": n(ks[1], (experts, hidden, ffn)),
+              "w_up": n(ks[2], (experts, hidden, ffn)),
+              "w_down": n(ks[3], (experts, ffn, hidden))}
+    from deepspeed_tpu.ops.moe import route_group_limited
+    # expert 5 gets no row: its bias keeps it out of every choice
+    bias = jnp.zeros((experts,)).at[5].set(-10.0)
+    idx, p, _, _ = route_group_limited(x, n(ks[4], (hidden, experts)),
+                                       top_k, 1, 1, bias=bias)
+    return x, idx, p, tables
+
+
+@pytest.mark.parametrize("inactive", [0, 7], ids=["all_active", "inactive"])
+def test_every_row_with_a_whole_layer_held_equals_the_dense_sum(inactive):
+    """All 16 experts held, top 4, 41 rows: every held expert on every
+    row == the plain sum, with rows that do not decode (their picks are
+    worked and never counted) and an expert that gets no row."""
+    from deepspeed_tpu.ops.moe import held_experts_every_row
+    x, idx, p, tables = _decode_case(41, 32, 48, 16, 4)
+    active = None if not inactive else jnp.arange(41) % inactive != 0
+    with jax.default_matmul_precision("highest"):
+        y, counts = held_experts_every_row(
+            x, idx, p, tables, (0, 16), jax.nn.silu, active)
+        dense = _glu_sum(x, idx, p, tables, 0, jax.nn.silu)
+    rows = np.ones(41, bool) if active is None else np.asarray(active)
+    np.testing.assert_allclose(np.asarray(y)[rows], np.asarray(dense)[rows],
+                               atol=2e-5)
+    assert int(counts[5]) == 0 and int(counts.sum()) == 4 * int(rows.sum())
+
+
+def test_eight_shares_of_eight_add_up_to_the_whole_layers_sum():
+    """The family is TOLD what it holds like any other: shares (0, 8),
+    (8, 8), ... (56, 8) of 64 experts through the decode form add up to
+    what all 64 held give, and to the plain sum."""
+    from deepspeed_tpu.ops.moe import held_experts_every_row
+    x, idx, p, tables = _decode_case(23, 32, 16, 64, 4, seed=13)
+    with jax.default_matmul_precision("highest"):
+        whole, counts = held_experts_every_row(
+            x, idx, p, tables, (0, 64), jax.nn.silu)
+        parts, landed = 0.0, 0
+        for first in range(0, 64, 8):
+            share = {n: t[first:first + 8] for n, t in tables.items()}
+            y, c = held_experts_every_row(
+                x, idx, p, share, (first, 8), jax.nn.silu)
+            parts, landed = parts + y, landed + int(c.sum())
+        dense = _glu_sum(x, idx, p, tables, 0, jax.nn.silu)
+    np.testing.assert_allclose(np.asarray(parts), np.asarray(whole),
+                               atol=2e-5)
+    np.testing.assert_allclose(np.asarray(whole), np.asarray(dense),
+                               atol=2e-5)
+    assert landed == int(counts.sum()) == 23 * 4

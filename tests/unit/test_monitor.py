@@ -283,3 +283,20 @@ def test_step_time_scalar_written(tmp_path):
         engine.train_batch(iter([b]))
     assert engine._last_step_time_ms is not None
     assert engine._last_step_time_ms > 0
+
+
+@pytest.mark.parametrize("values", [
+    [23.1] * 7,
+    [0.0, 1e-4, 1e-3, 23.1, 23.1, 5.5, 23.1, 1e6],
+    [float("nan"), 2.0, float("inf"), 2.0, float("-inf"), 3.0],
+    [],
+], ids=["one_value", "mixed", "not_finite", "none"])
+def test_histogram_record_many_is_record_in_order(values):
+    from deepspeed_tpu.utils.monitor import Histogram
+    one, many = Histogram(), Histogram()
+    for v in values:
+        one.record(v)
+    many.record_many(values)
+    assert many._buckets == one._buckets
+    assert (many.count, many.sum, many.min, many.max) == (
+        one.count, one.sum, one.min, one.max)

@@ -351,15 +351,20 @@ def test_the_share_is_declared_for_the_four_serving_cells():
     names = [m["name"] for m in bench["per_layer"]]
     entry = bench["per_layer"][names.index(name)]
     assert names[names.index(name) + 1] == "serve_prefill_build_ms.sat"
-    # the four serving cells of PR 44's day; a later cell whose every
-    # prompt goes in chunks (PR 48) has no `serve/prefill` span to read
+    # the four serving cells of PR 44's day first; after them, by name,
+    # any later serving cell whose prompts are prefilled whole (one
+    # whose every prompt goes in chunks, PR 48's, has no `serve/prefill`
+    # span to read)
+    cells = [w["name"] for w in bench["workloads"]]
+    listed = entry.pop("workloads")
     assert entry == {
         "name": name, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "page pool",
-        "moves": "serve_tokens_per_s",
-        "workloads": [w["name"] for w in bench["workloads"][:6]
-                      if ".serve-" in w["name"]]}
-    assert len(entry["workloads"]) == 4
+        "moves": "serve_tokens_per_s"}
+    assert listed[:4] == [c for c in cells[:6] if ".serve-" in c]
+    assert all(".serve-" in c and c in cells for c in listed[4:])
+    assert "lfm2-24b-a2b.serve-chat-saturated" in listed
+    assert "kimi-linear-48b-a3b.serve-longctx-saturated" not in listed
     with open(os.path.join(BENCH, "metrics", name + ".json")) as f:
         spec = json.load(f)
     assert (spec["reader"], spec["params"]) == (

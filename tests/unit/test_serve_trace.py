@@ -295,6 +295,39 @@ class TestServeTracerUnit:
         assert row["event"] == "serve_evict"
         assert row["ttft_ms"] is None            # null, never 0.0
 
+    def test_on_tokens_is_on_token_at_one_reading_of_the_clock(self):
+        """A step's decode tokens in ONE call: the rows and sums a call
+        a token gives at the same clock; an unknown uid and a request
+        with no first token yet are passed over; a repeated uid (a
+        speculative run) counts each token."""
+        def drive(batched):
+            tr, w, t = self._tracer(sample_rate=0.5)
+            for uid in (1, 2, 3):
+                tr.on_submit(uid, 4, 16)
+                tr.on_admit(uid, 0, 1.0, uid, 4, 1)
+            tr.on_first_token(1, 5.0)
+            t[0] += 0.001
+            tr.on_first_token(2, 6.0)       # 3 has no first token yet
+            for _ in range(4):
+                t[0] += 0.002
+                uids = (1, 9, 2, 3, 2)
+                if batched:
+                    tr.on_tokens(uids)
+                else:
+                    for uid in uids:
+                        tr.on_token(uid)
+            return tr, w
+        (a, wa), (b, wb) = drive(True), drive(False)
+        assert wa.rows == wb.rows
+        assert [r["uid"] for r in wa.rows
+                if r["event"] == "serve_decode_window"].count(2) == 4
+        ha, hb = a.hist["tbt_ms"], b.hist["tbt_ms"]
+        assert (ha.count, ha.sum, ha.min, ha.max, ha._buckets) == (
+            hb.count, hb.sum, hb.min, hb.max, hb._buckets)
+        assert ha.count == 12 and ha.min == 0.0
+        assert a.drain_step_tbts() == b.drain_step_tbts()
+        assert a._req[3].n_tokens == 0
+
     def test_snapshot_histograms(self):
         tr, _w, t = self._tracer()
         tr.on_submit(1, 4, 8)

@@ -153,3 +153,95 @@ def test_a_layer_kind_without_a_mixer_is_refused_by_name():
     family = TOY._replace(layers=(("toy", "dense"), ("window", "experts")))
     with pytest.raises(ValueError, match="window"):
         _forward(family, _params(), jnp.zeros((1, 4), jnp.int32))
+
+
+def test_a_family_without_a_shared_expert_has_none_in_its_program():
+    """A layer with no ``shared`` leaf: its routed sum alone, and no
+    ``moe_shared`` scope traced; with the leaf, the shared expert's
+    output on top."""
+    params = _params()
+    ids = jax.random.randint(jax.random.PRNGKey(9), (2, 8), 0, VOCAB)
+    with_shared = _forward(TOY, params, ids)
+    bare = {**params, "h_1": {k: v for k, v in params["h_1"].items()
+                              if k != "shared"}}
+    without = _forward(TOY, bare, ids)
+    assert float(jnp.abs(with_shared - without).max()) > 1e-2
+    zeroed = {**params, "h_1": {**params["h_1"], "shared": jax.tree_util
+                                .tree_map(jnp.zeros_like,
+                                          params["h_1"]["shared"])}}
+    np.testing.assert_allclose(np.asarray(_forward(TOY, zeroed, ids)),
+                               np.asarray(without), atol=1e-6)
+    scopes = lambda p: jax.jit(lambda q: _forward(TOY, q, ids)).lower(
+        p).as_text(debug_info=True)
+    assert "/moe_shared" in scopes(params)
+    assert "/moe_shared" not in scopes(bare)
+    assert "/moe_experts" in scopes(bare)
+
+
+class _PagesAndTails(NamedTuple):
+    """A cache tree without a state leaf: the pool pair and one more
+    per-slot leaf (``kv_cache.PagedTailCache``'s shape)."""
+    keys: jnp.ndarray
+    values: jnp.ndarray
+    tails: jnp.ndarray
+
+
+def test_a_tree_without_a_state_leaf_goes_through_the_trunk():
+    """The trunk names no leaf: a mixer over (keys, values, tails) gets
+    the tree as the engine built it, replaces ITS leaves and hands it
+    on; the page size is read off the first leaf."""
+    from deepspeed_tpu.models.served_trunk import paged_pair_mixer
+
+    def softmax(ap, config, h, dtype, pages, token_positions):
+        assert token_positions.shape == h.shape[:2]
+        B, S, _ = h.shape
+        heads = lambda t, k: t.reshape(B, S, k, HEAD_DIM).transpose(
+            0, 2, 1, 3)
+        q, k, v = (heads(h @ ap["wq"], HEADS), heads(h @ ap["wk"], KV_HEADS),
+                   heads(h @ ap["wv"], KV_HEADS))
+        if pages is None:
+            ctx, pools = gqa_stripe_attention(
+                q, k, v, jnp.zeros((B,), jnp.int32)), None
+        else:
+            box = []
+            ctx = paged_attend(q, k, v, pages.pools, pages.layer,
+                               pages.tables, pages.positions, pages.index,
+                               box, pages.reader, gqa_stripe_attention)
+            pools = box[0]
+        return ctx.transpose(0, 2, 1, 3).reshape(B, S, -1) @ ap["wo"], pools
+
+    def tailed(lp, h, call, cache, n):
+        # keeps the last normed input of every row it is given
+        y = h @ lp["attn"]["wq"][:, :HIDDEN]
+        if cache is not None:
+            rows = call.slots if h.shape[1] > 1 else jnp.arange(h.shape[0])
+            cache = cache._replace(
+                tails=cache.tails.at[n, rows].set(h[:, -1]))
+        return y, cache
+
+    family = TOY._replace(
+        layers=(("tailed", "dense"), ("paged", "experts")),
+        mixers={"tailed": tailed,
+                "paged": paged_pair_mixer(softmax, positions=True)},
+        token_positions=True)
+    params = _params()
+    ids = jax.random.randint(jax.random.PRNGKey(10), (1, 17), 0, VOCAB)
+    want = _forward(family, params, ids)
+    pool = jnp.zeros((1, PAGES, PAGE, KV_HEADS * HEAD_DIM), jnp.float32)
+    tree = _PagesAndTails(pool, pool, jnp.zeros((1, 2, HIDDEN)))
+    tables = jnp.asarray([[1, 2]], jnp.int32)
+    logits, tree = _forward(
+        family, params, ids[:, :16], tree,
+        cache_position=jnp.zeros((1,), jnp.int32), block_tables=tables,
+        lengths=jnp.asarray([16]), slots=jnp.asarray([1]))
+    assert isinstance(tree, _PagesAndTails)
+    np.testing.assert_allclose(np.asarray(logits[:, 0]),
+                               np.asarray(want[:, 15]), atol=2e-4)
+    assert float(jnp.abs(tree.tails[0, 1]).max()) > 0
+    assert float(jnp.abs(tree.tails[0, 0]).max()) == 0
+    logits, tree = _forward(
+        family, params, ids[:, 16:], tree,
+        cache_position=jnp.asarray([16], jnp.int32), block_tables=tables,
+        active=jnp.asarray([True]))
+    np.testing.assert_allclose(np.asarray(logits[:, 0]),
+                               np.asarray(want[:, 16]), atol=2e-4)

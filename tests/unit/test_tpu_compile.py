@@ -1398,6 +1398,101 @@ def test_kimi_linear_serving_programs_keep_one_cache_tree_in_place(
     assert 7.70e9 < memory.argument_size_in_bytes < 7.80e9
 
 
+@pytest.mark.parametrize("program", [
+    "decode", "prefill_1x256", "prefill_1x512", "prefill_1x1024",
+    "prefill_4x256", "prefill_4x512", "prefill_4x1024"])
+def test_lfm2_serving_programs_keep_pages_and_tails_in_place(monkeypatch,
+                                                             program):
+    """The benchmark configuration's SEVEN programs at the published
+    widths, weights held in bfloat16, the cache tree (keys, values,
+    tails: no state leaf) donated and aliased through the ten layers.
+    Decode (257 rows): the paged reader over a pool row of 8 x 64 = 512
+    lanes with groups of 4 queries a key-value head, once an attention
+    layer, its only kernel (the expert half is every held expert on
+    every row: plain products). A prefill bucket: the flash kernel where the
+    scores are many, three grouped products an expert layer, no
+    ``moe_shared`` scope anywhere."""
+    import json
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(__file__), "..", "..", "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from families import lfm2 as family
+    from deepspeed_tpu.inference.kv_cache import (PagedTailCache,
+                                                  paged_spec_for,
+                                                  state_pool_spec_for)
+    from deepspeed_tpu.models import lfm2
+    from deepspeed_tpu.ops.attention.paged import paged_decode_supported
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(flash, "_use_pallas", lambda: True)
+    with open(os.path.join(bench, "configs", "lfm2-24b-a2b.json")) as f:
+        config = json.load(f)
+    model = family.serve_model_of(config)
+    inference = config["serve"]["inference"]
+    rows = inference["max_batch_size"] + 1
+    pages = paged_spec_for(model, inference["paged_kv"]["num_pages"], 16,
+                           inference["max_seq_len"])
+    tails = state_pool_spec_for(model, rows)
+    assert pages.shape[-1] == 8 * 64 and not tails.has_state
+    assert paged_decode_supported(pages.head_dim, pages.kv_heads,
+                                  jnp.bfloat16)
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: lfm2.init_lfm2_params(
+            model, jax.random.PRNGKey(0))))
+    cache = PagedTailCache(_spec(pages.shape), _spec(pages.shape),
+                           _spec(tails.tail_shape))
+    ints = lambda *shape: _spec(shape, jnp.int32)
+
+    def decode(params, cache, toks, positions, tables):
+        logits, cache, counts = lfm2.lfm2_forward(
+            params, model, toks[:, None], kv_cache=cache,
+            cache_position=positions, block_tables=tables,
+            paged_attn_kernel="pallas", active=tables[:, 0] > 0,
+            with_counts=True)
+        return jnp.argmax(logits[:, 0], -1), counts, cache
+
+    def prefill(params, cache, ids, lengths, tables, slots):
+        logits, cache, counts = lfm2.lfm2_forward(
+            params, model, ids, kv_cache=cache,
+            cache_position=jnp.zeros_like(lengths), block_tables=tables,
+            paged_attn_kernel="pallas", lengths=lengths, slots=slots,
+            with_counts=True)
+        return jnp.argmax(logits[:, 0], -1), counts, cache
+
+    if program == "decode":
+        fn, args = decode, (ints(rows), ints(rows),
+                            ints(rows, pages.pages_per_seq))
+        kernels = 2                # the paged reader, an attention layer
+    else:
+        b, s = (int(n) for n in program.split("_")[1].split("x"))
+        fn, args = prefill, (ints(b, s), ints(b),
+                             ints(b, pages.pages_per_seq), ints(b))
+        kernels = None
+    lowered = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, *args)
+    assert "/moe_shared" not in lowered.as_text(debug_info=True)
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    tree_bytes = 2 * int(np.prod(pages.shape)) * 2 + int(
+        np.prod(tails.tail_shape)) * 2
+    assert memory.alias_size_in_bytes >= tree_bytes
+    text = compiled.as_text()
+    calls = text.count('custom_call_target="tpu_custom_call"')
+    if kernels is None:
+        # three grouped products a turn of an expert layer's loop + the
+        # product down; the flash kernel where the scores are many
+        assert calls >= 3 * 8
+    else:
+        assert calls == kernels
+    # the weights as they are held (5,267M parameters in bfloat16), the
+    # pools (1.07 GB) and the tails
+    assert 11.5e9 < memory.argument_size_in_bytes < 11.8e9
+    assert memory.temp_size_in_bytes < 2.5e9
+    print(program, "temp", memory.temp_size_in_bytes, "code",
+          memory.generated_code_size_in_bytes)
+
+
 def test_smallthinker_train_step_compiles_at_the_cut_widths(monkeypatch):
     """`deepspeed_tpu.initialize` + the ONE compiled `_micro_step`
     (ZeRO-2, bf16, Adam, clipping) of the benchmark's configuration at

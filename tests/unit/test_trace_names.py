@@ -230,6 +230,47 @@ def test_a_chunked_hybrids_programs_carry_their_scopes(monkeypatch, program):
     engine.close()
 
 
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_a_short_convolution_hybrids_programs_carry_their_scopes(
+        monkeypatch, program):
+    """models/lfm2.py: both programs carry the convolution's two scopes
+    and, in an attention layer, the norm a head and the rotation between
+    `attn_proj` and the reader; NO `moe_shared` (the family has no shared
+    expert); neither opens a name outside the registry."""
+    from deepspeed_tpu.models import lfm2
+    cfg = lfm2.LFM2Config(
+        vocab_size=64, hidden_size=32, num_layers=4, num_heads=2,
+        num_kv_heads=1, intermediate_size=48, moe_intermediate_size=16,
+        num_dense_layers=1, num_experts=4, experts_per_token=2,
+        max_position_embeddings=64)
+    engine = InferenceEngine(
+        cfg, lfm2.init_lfm2_params(cfg, jax.random.PRNGKey(0)),
+        {"max_batch_size": 2, "prompt_buckets": [16], "batch_buckets": [1],
+         "max_seq_len": 48, "paged_kv": {"prefix_cache": False}})
+    rows, pps = engine._rows, engine.paged_spec.pages_per_seq
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)      # noqa: E731
+    keys = lambda n: jnp.zeros((n, 2), jnp.uint32)        # noqa: E731
+    temps = lambda n: jnp.zeros((n,), jnp.float32)        # noqa: E731
+    want = {"conv_proj", "conv_core", "attn_proj", "attn_norm_rope",
+            "kv_write", "moe_route", "moe_dispatch", "moe_experts", "mlp",
+            "lm_head"}
+    if program == "decode":
+        fn, args = engine._decode_paged_impl, (
+            i32(rows), i32(rows), i32(rows, pps), keys(rows), temps(rows))
+        want |= {"attn_cached", "sample"}
+    else:
+        fn, args = engine._prefill_state_impl, (
+            i32(1, 16), i32(1) + 9, i32(1), i32(1, pps), keys(1),
+            temps(1), i32(1))
+    with _opened_scopes(monkeypatch) as opened:
+        text = jax.jit(fn).lower(engine.params, engine._cache,
+                                 *args).as_text(debug_info=True)
+    assert set(opened) <= set(DEVICE_SCOPES)
+    assert want <= _scopes_in(text), want - _scopes_in(text)
+    assert not {"moe_shared", "kda_state", "ssd_state"} & _scopes_in(text)
+    engine.close()
+
+
 def test_scopes_leave_the_program_set_and_recompiles_alone(monkeypatch):
     def programs():
         engine = _serve_engine()
