@@ -237,23 +237,31 @@ class DispatchTrace:
     dispatches, one row a dispatch, built for every engine.
 
     A row holds ``seq`` (its ordinal since the engine was built),
-    ``step`` (``engine._steps``), the dispatch's CLASS (the compiled
+    ``step`` (``engine._steps`` as the dispatch was ISSUED), the
+    dispatch's CLASS (the compiled
     program it ran: ``("decode", table width)``, ``("prefill", batch
     bucket, prompt bucket)``, ``("verify", width)``, ``("chunk", batch
     bucket, chunk tokens, shards)``, ``("handoff",)``; its first word is
     the row's ``kind``), four stamps of ``time.perf_counter()`` and one
     count (what the dispatch read is on its ``serve/*`` span, which
-    carries the row's ``seq``):
+    carries the row's ``seq``). The engine reads a dispatch's tokens
+    after it has issued the NEXT dispatch, and a row is written when its
+    tokens arrive, so rows are in the order issued and a row's stamps
+    are the host's, not the program's:
 
-    - ``t_begin``: the first host work for this dispatch alone
-      (:meth:`begin`: ``serve/plan`` for a decode, the batch's build for
-      a prefill, whose phase admits before it for all its batches; where
-      a phase dispatches again, the row before's ``t_done``);
-    - ``t_issued``: the jitted call has returned (:meth:`issued`);
+    - ``t_begin``: the first host work since the row before was done,
+      admission apart (:meth:`begin`: ``serve/plan`` for a decode, the
+      batch's build for a prefill, whose phase admits before it for all
+      its batches; with the read deferred, the build of the dispatch
+      issued while this row's program ran);
+    - ``t_issued``: where the host starts to block for this row's
+      tokens (:meth:`issued`; read at once: the jitted call has
+      returned);
     - ``t_ready``: the host holds the result (:meth:`ready`, stamped as
       the ``serve/*/wait`` span closes; it returns the milliseconds
       since ``t_begin``, which is what the engine reports as a
-      dispatch's wall time);
+      dispatch's wall time: the rows tile the host's clock, so no two
+      wall times overlap);
     - ``t_done``: record and metrics for this dispatch are finished
       (:meth:`record`, which writes the row);
     - ``tokens``: what the scheduler's ``total_tokens`` grew by since
@@ -262,9 +270,10 @@ class DispatchTrace:
 
     The rows cut the timeline into contiguous intervals (the row
     before's ``t_done`` to this row's), each in three legs: BEFORE (to
-    ``t_issued``: admit, plan, build, the call, and whatever the caller
-    did between two steps), WAIT (to ``t_ready``: the device's program
-    with its launch and read-back) and AFTER (to ``t_done``).
+    ``t_issued``: the host's work since the row before: admit, plan,
+    build and call of the dispatch issued meanwhile, and whatever the
+    caller did between two steps), WAIT (to ``t_ready``: what the host
+    truly waited) and AFTER (to ``t_done``).
 
     The interleaving pin of disaggregated and chunked serving reads the
     same rows as pure ordering: within every step, all decode/verify

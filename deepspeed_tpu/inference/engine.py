@@ -59,6 +59,15 @@ TPU-first:
   finished sequences (EOS / max_tokens) — iteration-level scheduling
   with bounded-lookahead admission (a head that doesn't fit the free
   pages can't stall the queue), per-request sampling state.
+- **The host works under the device.** A step issues a dispatch and
+  only then takes the dispatch before's tokens to the host
+  (``_issue`` / ``_settle``): the last tokens stay on the device, the
+  scheduler advances by count when a dispatch is issued and fills by
+  value when its tokens arrive, every end is seen one dispatch late,
+  and build, call, record and metrics run while the device works. An
+  engine that needs the values before its next issue (a drafter, a
+  handoff queue) reads every dispatch at once through the same code
+  (docs/inference.md "The order of a step").
 - **Checkpoint -> serving bridge.** :meth:`from_checkpoint` loads a
   committed PR-1 checkpoint's ``model_states`` group only
   (``runtime/checkpoint.load_params_only``), optionally shipping the
@@ -84,7 +93,8 @@ TPU-first:
 
 import os
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from collections import deque
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -349,6 +359,24 @@ def _check_keys_for():
             f"{jax.config.jax_enable_x64})")
 
 
+class _Read:
+    """A dispatch whose tokens the host has not taken yet: the ledger
+    row and ``serve/*`` span it will be (``seq``, ``step``, the span's
+    counters, the row's class), the program's result still on the
+    device, and what to do with the values once they are here
+    (``arrive(values, wall ms)``: record, then metrics)."""
+
+    __slots__ = ("name", "seq", "step", "counters", "program", "result",
+                 "arrive")
+
+    def __init__(self, name: str, seq: int, step: int,
+                 counters: Dict[str, Any], program: Tuple, result,
+                 arrive: Callable):
+        self.name, self.seq, self.step = name, seq, step
+        self.counters, self.program = counters, program
+        self.result, self.arrive = result, arrive
+
+
 class InferenceEngine:
     """Paged (or dense) bucketed prefill/decode serving over a
     continuous-batching scheduler, optionally sharded over a serving
@@ -533,6 +561,16 @@ class InferenceEngine:
         # (tests/unit/test_chunked_prefill.py)
         self._dispatch_trace = DispatchTrace()
         _keep_dispatch_ledger(self._dispatch_trace)
+        # a dispatch's tokens are read AFTER the next dispatch has been
+        # issued: build, call, record and metrics run while the device
+        # works (docs/inference.md "The order of a step"). The reads not
+        # yet taken wait here, oldest first. Where the values are needed
+        # before the next issue the same code keeps none waiting: a
+        # drafter proposes from values, a handoff record carries the
+        # first token
+        self._pending: deque = deque()
+        self._read_depth = 0 if (self.spec or self.disagg) else 1
+        self._issues = 0            # dispatches issued, ever
         self._link = None
         if self._separate_pools:
             from deepspeed_tpu.runtime.comm_autotune import LinkModel
@@ -699,13 +737,15 @@ class InferenceEngine:
         # (``expert_counters``: the assignments a row that decodes
         # offers the router over the layers, the experts held here):
         # the paged decode program then returns the layers' counters
-        # with its tokens, and a span carries those of the step before
-        # (its own are known only once its tokens are read); a prefill
-        # program returns the rows its expert turns worked and the rows
-        # static turns would have, the same way
+        # with its tokens, and a span carries those of the last decode
+        # READ when its dispatch was planned, beside the rows that
+        # decoded in THAT one (its own are known only once its tokens
+        # are read); a prefill program returns the rows its expert turns
+        # worked and the rows static turns would have, the same way
         self._expert_counters = getattr(model_config, "expert_counters",
                                         None)
         self._moe_counts = (0, 0)
+        self._moe_active = 0
         self._moe_prefill_rows = (0, 0)
         if self._expert_counters is not None and not self.paged:
             raise ValueError(
@@ -795,6 +835,17 @@ class InferenceEngine:
                 self._decode_impl, 6, "decode")
             geom = (f"dense KV cache "
                     f"{cache_bytes / 2**20:.1f} MiB")
+        # the tokens the device holds pending, one a row: what the
+        # decode program reads. A decode's result IS the next one's
+        # input, and a prefill's (or a final chunk's) first tokens are
+        # merged in at their slots by one tiny program, so no token goes
+        # to the host and back between two dispatches
+        self._all_rows = np.arange(self._rows, dtype=np.int32)
+        self._merge = self._wrap_merge()
+        self._last_tokens = jnp.zeros((self._rows,), jnp.int32)
+        if self._mesh_decode is not None:
+            self._last_tokens = jax.device_put(
+                self._last_tokens, NamedSharding(self._mesh_decode, P()))
         mesh_note = (f", mesh {dict(self.mesh.shape)}"
                      if self.mesh is not None else "")
         if self.spec:
@@ -1065,6 +1116,19 @@ class InferenceEngine:
                              compiler_options=_program_compiler_options())
         return self.compile_tracker.wrap(jitted, name)
 
+    def _wrap_merge(self):
+        """``merge_tokens``: the one helper program of the step loop
+        (:meth:`_merge_tokens_impl`), compiled a (result, slots) shape at
+        warm-up. Under a mesh its result is replicated over the decode
+        mesh, as the decode program's own tokens are."""
+        if self._mesh_decode is None:
+            jitted = jax.jit(self._merge_tokens_impl)
+        else:
+            jitted = jax.jit(
+                self._merge_tokens_impl,
+                out_shardings=NamedSharding(self._mesh_decode, P()))
+        return self.compile_tracker.wrap(jitted, "merge_tokens")
+
     def _wrap_handoff_programs(self):
         """The two cross-pool page-migration programs (separate-pools
         disaggregation only): ``handoff_export`` gathers the live
@@ -1112,6 +1176,13 @@ class InferenceEngine:
                 scaled = jnp.where(scaled < kth, NEG_INF, scaled)
             sampled = jax.vmap(jax.random.categorical)(keys, scaled)
             return jnp.where(temps > 0, sampled.astype(jnp.int32), greedy)
+
+    def _merge_tokens_impl(self, last, out, slots):
+        """``last`` with a program's tokens written at ``slots``:
+        ``out`` is the program's result as it returned it (its tokens
+        first, whatever counters ride behind them cut off here), a row
+        that released no token names the scratch row."""
+        return last.at[slots].set(out[:slots.shape[0]])
 
     def _prefill_impl(self, params, cache, ids, lengths, slots, keys,
                       temps):
@@ -1311,7 +1382,11 @@ class InferenceEngine:
         FinishedRequest carries ``ttft_ms=None`` — never 0.0 — when the
         request was evicted before its first token. None for unknown/
         finished uids. Call between :meth:`step` calls, not inside
-        one."""
+        one. The reads still waiting are taken first: the request
+        leaves with every token the device has made for it (and one
+        whose last has just arrived is finished, not evicted: None
+        here, its answer with the next :meth:`step`'s)."""
+        self._settle()
         # disagg: a prefill-complete request can be waiting in the
         # handoff queue — pop its record NOW, before the slot eviction
         # below. Left queued it would sit as a phantom entry (depth
@@ -1340,6 +1415,7 @@ class InferenceEngine:
         if self.state_spec is None:
             raise ValueError(f"{type(self.model_config).__name__} keeps "
                              f"no per-slot state")
+        self._settle()
         slot = self.scheduler.slots[slot_id]
         if slot is None or slot.pending_tok is None:
             return None
@@ -1379,6 +1455,8 @@ class InferenceEngine:
         self._refuse_migration_with_state()
         if self._mig_export is None:
             return None
+        # the record carries every token the device has made
+        self._settle()
         sched = self.scheduler
         for sid in sched.active_slots():
             slot = sched.slots[sid]
@@ -1503,6 +1581,8 @@ class InferenceEngine:
         if sid is None:
             sched.allocator.free(pages)
             return None
+        # the decode program reads its tokens from the device
+        self._hold_values({sid: rec.pending_tok})
         # destination half of the lineage pair: resumes the ORIGINAL
         # trace id (hop bumped), so later decode-window/finish rows on
         # this replica stitch to the source's serve_migrate_out
@@ -1554,6 +1634,8 @@ class InferenceEngine:
         Returns the new version stamp (the tag name)."""
         from deepspeed_tpu.runtime import checkpoint as ckptlib
         from deepspeed_tpu.runtime import fault
+        # what the old weights made is stamped with their version
+        self._settle()
         t0 = time.perf_counter()
         try:
             chosen = _resolve_committed_tag(ckptlib, load_dir, tag,
@@ -1658,13 +1740,20 @@ class InferenceEngine:
         return True
 
     def debug_state(self) -> Dict[str, Any]:
-        """Live introspection snapshot — pure host reads, zero device
-        syncs, safe to call mid-serving from a debug endpoint: page
-        pool occupancy/fragmentation + prefix-cache accounting, the
-        slot table, queue depth by prompt bucket, per-program dispatch
-        counts, and the tracer's SLO/latency histograms. Rendered by
-        ``tools/obs_report.py --serve`` from the periodic
-        ``serve_state`` event rows."""
+        """Live introspection snapshot between two steps: page pool
+        occupancy/fragmentation + prefix-cache accounting, the slot
+        table, queue depth by prompt bucket, per-program dispatch
+        counts, and the tracer's SLO/latency histograms. The reads
+        still waiting are taken first (the one device sync: a slot's
+        ``generated`` is what the device has made); the periodic
+        ``serve_state`` event row inside a step takes none. Rendered by
+        ``tools/obs_report.py --serve`` from those rows."""
+        self._settle()
+        return self._debug_state()
+
+    def _debug_state(self) -> Dict[str, Any]:
+        """:meth:`debug_state` as the host holds it now: pure host
+        reads, no device sync."""
         sched = self.scheduler
         slots = []
         for sid in sched.active_slots():
@@ -1755,9 +1844,98 @@ class InferenceEngine:
         (docs/observability.md "The dispatch ledger")."""
         return self._dispatch_trace
 
-    def _run_prefill(self, batch):
-        """(first tokens, the dispatch's wall ms)."""
+    # ------------------------------------------- issue now, read later
+    def _issue(self, name: str, counters: Dict[str, Any], program: Tuple,
+               result, arrive: Callable) -> None:
+        """A dispatch has been called: its read joins the ones waiting,
+        and those that have waited long enough are taken — every read
+        but this one, which the device is about to work on while the
+        host goes on (or this one too, where the engine needs values
+        before its next issue). ``seq`` counts the waiting rows in: it
+        is the ledger row this dispatch WILL be."""
+        self._pending.append(_Read(
+            name, self._dispatch_trace.total + len(self._pending),
+            self._steps, counters, program, result, arrive))
+        self._issues += 1
+        # the copy to the host starts when the program ends, not when
+        # the host asks
+        result.copy_to_host_async()
+        self._settle(keep=self._read_depth)
+
+    def _settle(self, keep: int = 0) -> None:
+        """Take the waiting reads to the host, oldest first, until
+        ``keep`` are left: the dispatch's ``serve/*`` span with its
+        ``wait`` child (``deferred``: whether a later dispatch was
+        issued first, i.e. the host worked while this program ran), its
+        values' arrival in the scheduler (``serve/record``), its
+        metrics, its ledger row. ``issued()`` is stamped where the host
+        starts to block and ``ready()`` where it holds the values, so a
+        row's legs are the host's work since the row before, what the
+        host truly waited, and record + metrics. Requests that finish go
+        to ``scheduler.undelivered``: the ``step`` that is running, or
+        the next one, returns them."""
         ledger = self._dispatch_trace
+        pending = self._pending
+        while len(pending) > keep:
+            read = pending.popleft()
+            with self._span(read.name, seq=read.seq, step=read.step,
+                            deferred=int(bool(pending)), **read.counters):
+                ledger.issued()
+                with self._span(read.name + "/wait"):
+                    # host sync: the request's tokens, the tracer and
+                    # the finished requests need the values
+                    values = np.asarray(read.result)
+                # the row's wall time: the host's first work since the
+                # row before to these tokens' arrival (rows tile the
+                # host's clock: no two wall times overlap)
+                wall_ms = ledger.ready()
+            read.arrive(values, wall_ms)
+            ledger.record(read.step, *read.program,
+                          tokens_total=self.scheduler.total_tokens)
+
+    def _arrived(self, rows, runs, draft_stats=None) -> None:
+        """A dispatch's token values, a run a row of ``rows`` (``(slot
+        id, slot)`` as issued): into the scheduler, but for a row whose
+        slot was released since (a request that hit EOS one dispatch
+        earlier: the token past its stop is dropped)."""
+        sched = self.scheduler
+        sched.undelivered.extend(sched.record_token_runs(
+            {sid: run for (sid, slot), run in zip(rows, runs)
+             if sched.holds(sid, slot)}, draft_stats))
+
+    def _hold(self, out, slots) -> None:
+        """The device keeps ``out``'s tokens at ``slots`` for the next
+        decode (``out`` a program's result, still on the device)."""
+        self._last_tokens = self._merge(self._last_tokens, out, slots)
+
+    def _hold_decoded(self, nxt) -> None:
+        """A decode program's result is the next one's tokens as it is;
+        where the routed experts' counters ride behind the rows' tokens
+        ((landed, fullest[, rows that kept a held group])) the merge
+        program cuts them off."""
+        if self._expert_counters is None:
+            self._last_tokens = nxt
+        else:
+            self._hold(nxt, self._all_rows)
+
+    def _hold_values(self, tokens: Dict[int, int]) -> None:
+        """:meth:`_hold` of values the HOST chose (a verify run's last
+        kept token, a claimed handoff's or a migrated request's pending
+        one), ``{slot id: token}``, one shape whatever their number."""
+        if not tokens:
+            return
+        out = np.zeros((self._rows,), np.int32)
+        slots = np.full((self._rows,), self._scratch, np.int32)
+        out[:len(tokens)] = list(tokens.values())
+        slots[:len(tokens)] = list(tokens)
+        self._hold(out, slots)
+
+    def _issue_prefill(self, batch) -> None:
+        """Build and call one bucketed prefill; its first tokens stay on
+        the device, merged in where the decode program reads them."""
+        sched = self.scheduler
+        ledger = self._dispatch_trace
+        ledger.begin()
         bb, pb = batch.batch_bucket, batch.prompt_bucket
         if self.paged:
             prompts = [r.prompt[pl:] for r, pl in
@@ -1775,63 +1953,86 @@ class InferenceEngine:
         ps = self.paged_spec.page_size if self.paged else 0
         paged_whole = real if ps and pb % ps == 0 and not any(
             pl % ps for pl in batch.prefix_lens) else 0
-        counters = {}
+        counters = dict(batch=bb, prompt=pb, real_tokens=real,
+                        own_key_tokens=own, page_write_tokens=paged_whole)
         if self._expert_counters is not None:
-            # routed experts: what the prefill before's turns worked
+            # routed experts: what the last prefill READ's turns worked
             worked, static = self._moe_prefill_rows
-            counters = dict(expert_rows_worked=worked,
+            counters.update(expert_rows_worked=worked,
                             expert_rows_sorted=static)
-        with self._span("serve/prefill", seq=ledger.total,
-                        step=self._steps, batch=bb, prompt=pb,
-                        real_tokens=real, own_key_tokens=own,
-                        page_write_tokens=paged_whole, **counters):
-            with self._span("serve/prefill/build"):
-                n = len(batch.requests)
-                keys = np.zeros((bb, 2), np.uint32)
-                keys[:n] = _keys_for([r.seed for r in batch.requests])
-                temps = np.zeros((bb,), np.float32)
-                temps[:n] = [r.temperature for r in batch.requests]
-                ids, lengths = pad_prompts(prompts, pb, bb)
-                if self.paged:
-                    positions = np.zeros((bb,), np.int32)
-                    tables = np.zeros((bb, self._prefill_pps), np.int32)
-                    for i, (pl, pages) in enumerate(
-                            zip(batch.prefix_lens, batch.page_tables)):
-                        positions[i] = pl
-                        tables[i, :len(pages)] = pages
-                if not self.paged or self._prefill_by_length:
-                    slots = np.full((bb,), self._scratch, np.int32)
-                    slots[:len(batch.slot_ids)] = batch.slot_ids
-            with self._span("serve/prefill/dispatch"):
-                # host arrays in, as every dispatch passes them and as
-                # warm-up did (docs/inference.md "What a dispatch's
-                # host section may touch")
-                if self._prefill_by_length:
-                    first, self._cache = self._prefill(
-                        self.params, self._cache, ids, lengths, positions,
-                        tables, keys, temps, slots)
-                elif not self.paged:
-                    first, self._cache = self._prefill(
-                        self.params, self._cache, ids, lengths, slots,
-                        keys, temps)
-                elif self._separate_pools:
-                    first, self._cache_prefill = self._prefill(
-                        self.params, self._cache_prefill, ids, lengths,
-                        positions, tables, keys, temps)
+        with self._span("serve/prefill/build"):
+            n = len(batch.requests)
+            keys = np.zeros((bb, 2), np.uint32)
+            keys[:n] = _keys_for([r.seed for r in batch.requests])
+            temps = np.zeros((bb,), np.float32)
+            temps[:n] = [r.temperature for r in batch.requests]
+            ids, lengths = pad_prompts(prompts, pb, bb)
+            if self.paged:
+                positions = np.zeros((bb,), np.int32)
+                tables = np.zeros((bb, self._prefill_pps), np.int32)
+                for i, (pl, pages) in enumerate(
+                        zip(batch.prefix_lens, batch.page_tables)):
+                    positions[i] = pl
+                    tables[i, :len(pages)] = pages
+            slots = np.full((bb,), self._scratch, np.int32)
+            slots[:n] = batch.slot_ids
+        with self._span("serve/prefill/dispatch"):
+            # host arrays in, as every dispatch passes them and as
+            # warm-up did (docs/inference.md "What a dispatch's
+            # host section may touch")
+            if self._prefill_by_length:
+                first, self._cache = self._prefill(
+                    self.params, self._cache, ids, lengths, positions,
+                    tables, keys, temps, slots)
+            elif not self.paged:
+                first, self._cache = self._prefill(
+                    self.params, self._cache, ids, lengths, slots,
+                    keys, temps)
+            elif self._separate_pools:
+                first, self._cache_prefill = self._prefill(
+                    self.params, self._cache_prefill, ids, lengths,
+                    positions, tables, keys, temps)
+            else:
+                first, self._cache = self._prefill(
+                    self.params, self._cache, ids, lengths, positions,
+                    tables, keys, temps)
+            rows = ()
+            if not self.disagg:
+                # the rows are mid-decode from here on, by count
+                rows = list(zip(batch.slot_ids,
+                                sched.issue_tokens(batch.slot_ids)))
+                self._hold(first, slots)
+
+        def arrive(first, prefill_ms):
+            first = self._first_tokens(first, bb)
+            with self._span("serve/record"):
+                for sid, req in zip(batch.slot_ids, batch.requests):
+                    self._tracer.on_prefill(
+                        req.uid, sid, prefill_ms, pb, bb, n)
+                if self.disagg:
+                    # the first token parks in the handoff queue: the
+                    # DECODE phase claims it
+                    page = self.paged_spec.page_size
+                    for i, (sid, req) in enumerate(zip(batch.slot_ids,
+                                                       batch.requests)):
+                        self._handoff_q.push(HandoffRecord(
+                            uid=req.uid, slot=sid,
+                            first_token=int(first[i]),
+                            live_pages=pages_for(len(req.prompt), page),
+                            prompt_tokens=len(req.prompt),
+                            t_ready=ledger.t_ready))
                 else:
-                    first, self._cache = self._prefill(
-                        self.params, self._cache, ids, lengths, positions,
-                        tables, keys, temps)
-            ledger.issued()
-            with self._span("serve/prefill/wait"):
-                first = self._first_tokens(first, bb)
-            return first, ledger.ready()
+                    self._arrived(rows, [[int(t)] for t in first[:n]])
+            with self._span("serve/metrics"):
+                self._drain_request_metrics()
+
+        self._issue("serve/prefill", counters, ("prefill", bb, pb), first,
+                    arrive)
 
     def _first_tokens(self, first, bb):
-        """A prefill program's result on the host (the sync): its ``bb``
-        first tokens; the expert turns' rows that ride behind them are
-        kept for the next span."""
-        first = np.asarray(first)
+        """A prefill program's result on the host: its ``bb`` first
+        tokens; the expert turns' rows that ride behind them are kept
+        for the next span."""
         if len(first) > bb:
             self._moe_prefill_rows = (int(first[-2]), int(first[-1]))
         return first[:bb]
@@ -1848,56 +2049,30 @@ class InferenceEngine:
                 queue_wait_ms=qwait, tokens=sched.total_tokens,
                 flush=False)
 
-    def _prefill_phase(self, finished: List[FinishedRequest]) -> None:
+    def _prefill_phase(self) -> None:
         """Admission + bucketed prefill dispatches (the prefill worker
-        loop). Non-disagg: each first token releases to its request
-        immediately. Disagg: it parks in the handoff queue instead —
-        the DECODE phase claims it, so TTFT honestly includes the
-        handoff wait."""
-        sched = self.scheduler
-        ledger = self._dispatch_trace
+        loop). Non-disagg: each first token stays on the device for the
+        step's decode and reaches its request when the read is taken.
+        Disagg: it parks in the handoff queue instead — the DECODE
+        phase claims it, so TTFT honestly includes the handoff wait."""
         self.health.heartbeat("prefill")
         with self._span("serve/admit"):
-            batches = sched.admit()
-        # after the admission, which serves all the phase's batches:
-        # every batch's wall time is its own build, call and wait
-        ledger.begin()
+            batches = self.scheduler.admit()
+        # every batch's wall time is its own build, call and wait: a
+        # batch begins after the admission, which serves them all
         for batch in batches:
-            first, prefill_ms = self._run_prefill(batch)
-            with self._span("serve/record"):
-                for sid, req in zip(batch.slot_ids, batch.requests):
-                    self._tracer.on_prefill(
-                        req.uid, sid, prefill_ms, batch.prompt_bucket,
-                        batch.batch_bucket, len(batch.requests))
-                if self.disagg:
-                    ps = self.paged_spec.page_size
-                    for i, (sid, req) in enumerate(zip(batch.slot_ids,
-                                                       batch.requests)):
-                        self._handoff_q.push(HandoffRecord(
-                            uid=req.uid, slot=sid,
-                            first_token=int(first[i]),
-                            live_pages=pages_for(len(req.prompt), ps),
-                            prompt_tokens=len(req.prompt),
-                            t_ready=ledger.t_ready))
-                else:
-                    finished.extend(sched.record_tokens(
-                        {sid: int(first[i])
-                         for i, sid in enumerate(batch.slot_ids)}))
-            with self._span("serve/metrics"):
-                self._drain_request_metrics()
-            ledger.record(self._steps, "prefill", batch.batch_bucket,
-                          batch.prompt_bucket,
-                          tokens_total=sched.total_tokens)
+            self._issue_prefill(batch)
 
-    def _chunk_phase(self, finished: List[FinishedRequest]) -> None:
+    def _chunk_phase(self) -> None:
         """At most ONE chunk dispatch per engine step — the pinned TBT
         bound: a decode dispatch never waits behind more than one
         ``chunk_tokens``-sized prefill slice, however long the prompt.
         The dispatch reuses the prefill program at ids shape
         (batch_bucket, chunk_tokens) — ``positions`` is each slot's
         absolute prefilled offset, ``tables`` its full page list, K/V
-        scatter straight into the pool. Intermediate chunks' sampled
-        tokens are discarded on the host; the FINAL chunk samples from
+        scatter straight into the pool. A slot's chunk position advances
+        by COUNT as the chunk is issued. Intermediate chunks' sampled
+        tokens are never used; the FINAL chunk samples from
         ``fold_in(key, positions + lengths)`` = the whole-prompt key,
         so the first token is bitwise the one whole-prompt prefill
         would have produced. Past ``cp_threshold_tokens`` (and with an
@@ -1937,6 +2112,7 @@ class InferenceEngine:
             spans.append((sid, slot.request, start, n,
                           (start - slot.prefix_len) // ct))
         counters = dict(
+            batch=bb, chunk=ct, cp_shards=shards,
             rows=len(spans), real_tokens=sum(n for *_, n, _ in spans),
             start_tokens=sum(st for _, _, st, _, _ in spans),
             carried_rows=sum(st > 0 for _, _, st, _, _ in spans),
@@ -1946,74 +2122,83 @@ class InferenceEngine:
             worked, static = self._moe_prefill_rows
             counters.update(expert_rows_worked=worked,
                             expert_rows_sorted=static)
-        with self._span("serve/chunk", seq=ledger.total, step=self._steps,
-                        batch=bb, chunk=ct, cp_shards=shards, **counters):
-            with self._span("serve/chunk/build"):
-                ids = np.zeros((bb, ct), np.int32)
-                lengths = np.ones((bb,), np.int32)
-                positions = np.zeros((bb,), np.int32)
-                tables = np.zeros((bb, self._prefill_pps), np.int32)
-                keys = np.zeros((bb, 2), np.uint32)
-                temps = np.zeros((bb,), np.float32)
-                slots = np.full((bb,), self._scratch, np.int32)
-                for i, (sid, req, start, n, _) in enumerate(spans):
-                    slot = sched.slots[sid]
-                    ids[i, :n] = req.prompt[start:start + n]
-                    lengths[i] = n
-                    positions[i] = start
-                    tables[i, :len(slot.pages)] = slot.pages
-                    temps[i] = req.temperature
-                    slots[i] = sid
-                keys[:len(spans)] = _keys_for(
-                    [req.seed for _, req, *_ in spans])
-            with self._span("serve/chunk/dispatch"):
-                if self._prefill_by_length:
-                    # a state family's chunk takes its rows' SLOTS: it
-                    # starts from the row's state and leaves it there
-                    first, self._cache = prog(
-                        self.params, self._cache, ids, lengths, positions,
-                        tables, keys, temps, slots)
-                elif self._separate_pools:
-                    first, self._cache_prefill = prog(
-                        self.params, self._cache_prefill, ids, lengths,
-                        positions, tables, keys, temps)
-                else:
-                    first, self._cache = prog(
-                        self.params, self._cache, ids, lengths, positions,
-                        tables, keys, temps)
-            ledger.issued()
-            with self._span("serve/chunk/wait"):
-                # host sync: final chunks release their first token
-                first = self._first_tokens(first, bb)
-            wall_ms = ledger.ready()
-        with self._span("serve/record"):
+        with self._span("serve/chunk/build"):
+            ids = np.zeros((bb, ct), np.int32)
+            lengths = np.ones((bb,), np.int32)
+            positions = np.zeros((bb,), np.int32)
+            tables = np.zeros((bb, self._prefill_pps), np.int32)
+            keys = np.zeros((bb, 2), np.uint32)
+            temps = np.zeros((bb,), np.float32)
+            slots = np.full((bb,), self._scratch, np.int32)
+            for i, (sid, req, start, n, _) in enumerate(spans):
+                slot = sched.slots[sid]
+                ids[i, :n] = req.prompt[start:start + n]
+                lengths[i] = n
+                positions[i] = start
+                tables[i, :len(slot.pages)] = slot.pages
+                temps[i] = req.temperature
+                slots[i] = sid
+            keys[:len(spans)] = _keys_for(
+                [req.seed for _, req, *_ in spans])
+        with self._span("serve/chunk/dispatch"):
+            if self._prefill_by_length:
+                # a state family's chunk takes its rows' SLOTS: it
+                # starts from the row's state and leaves it there
+                first, self._cache = prog(
+                    self.params, self._cache, ids, lengths, positions,
+                    tables, keys, temps, slots)
+            elif self._separate_pools:
+                first, self._cache_prefill = prog(
+                    self.params, self._cache_prefill, ids, lengths,
+                    positions, tables, keys, temps)
+            else:
+                first, self._cache = prog(
+                    self.params, self._cache, ids, lengths, positions,
+                    tables, keys, temps)
             self._chunk_dispatches += 1
-            released: Dict[int, int] = {}
-            for i, (sid, req, start, n, k) in enumerate(spans):
-                self._tracer.on_prefill_chunk(req.uid, sid, k, n, wall_ms,
-                                              cp_shards=shards)
-                if not sched.record_chunk(sid, n):
-                    continue                # mid-prompt, keep chunking
-                if self.disagg:
-                    ps = self.paged_spec.page_size
-                    self._handoff_q.push(HandoffRecord(
-                        uid=req.uid, slot=sid, first_token=int(first[i]),
-                        live_pages=pages_for(len(req.prompt), ps),
-                        prompt_tokens=len(req.prompt),
-                        t_ready=ledger.t_ready))
-                else:
-                    released[sid] = int(first[i])
-            if released:
-                finished.extend(sched.record_tokens(released))
-        with self._span("serve/metrics"):
-            self.monitor.write_serving_metrics(
-                chunk_dispatches=self._chunk_dispatches,
-                tokens=sched.total_tokens, flush=False)
-            self._drain_request_metrics()
-        ledger.record(self._steps, "chunk", bb, ct, shards,
-                      tokens_total=sched.total_tokens)
+            # the rows whose prompt this chunk completes: (row of the
+            # batch, slot id); the others keep chunking
+            final = [(i, sid) for i, (sid, _, _, n, _) in enumerate(spans)
+                     if sched.record_chunk(sid, n)]
+            rows = ()
+            if final and not self.disagg:
+                rows = list(zip(
+                    (sid for _, sid in final),
+                    sched.issue_tokens([sid for _, sid in final])))
+                held = np.full((bb,), self._scratch, np.int32)
+                for i, sid in final:
+                    held[i] = sid
+                self._hold(first, held)
 
-    def _claim_phase(self, finished: List[FinishedRequest]) -> None:
+        def arrive(first, wall_ms):
+            first = self._first_tokens(first, bb)
+            with self._span("serve/record"):
+                for sid, req, start, n, k in spans:
+                    self._tracer.on_prefill_chunk(
+                        req.uid, sid, k, n, wall_ms, cp_shards=shards)
+                if self.disagg:
+                    page = self.paged_spec.page_size
+                    for i, sid in final:
+                        req = spans[i][1]
+                        self._handoff_q.push(HandoffRecord(
+                            uid=req.uid, slot=sid,
+                            first_token=int(first[i]),
+                            live_pages=pages_for(len(req.prompt), page),
+                            prompt_tokens=len(req.prompt),
+                            t_ready=ledger.t_ready))
+                elif final:
+                    self._arrived(rows, [[int(first[i])]
+                                         for i, _ in final])
+            with self._span("serve/metrics"):
+                self.monitor.write_serving_metrics(
+                    chunk_dispatches=self._chunk_dispatches,
+                    tokens=sched.total_tokens, flush=False)
+                self._drain_request_metrics()
+
+        self._issue("serve/chunk", counters, ("chunk", bb, ct, shards),
+                    first, arrive)
+
+    def _claim_phase(self) -> None:
         """Disagg decode-worker intake: claim completed prefills off
         the handoff queue, transferring page OWNERSHIP to the decode
         loop — a zero-copy host bookkeeping move on a shared pool, or
@@ -2029,6 +2214,7 @@ class InferenceEngine:
         tracer = self._tracer
         ledger = self._dispatch_trace
         self.health.heartbeat("handoff_claim")
+        claimed: Dict[int, int] = {}
         for rec in q.drain():
             slot = sched.slots[rec.slot]
             if slot is None or slot.request.uid != rec.uid:
@@ -2081,18 +2267,24 @@ class InferenceEngine:
             self.monitor.write_serving_metrics(
                 handoff_ms=queue_ms + transfer_ms,
                 tokens=sched.total_tokens, flush=False)
-            finished.extend(sched.record_tokens(
+            sched.undelivered.extend(sched.record_tokens(
                 {rec.slot: rec.first_token}))
+            if sched.slots[rec.slot] is slot:
+                claimed[rec.slot] = rec.first_token
             self._drain_request_metrics()
             if self._separate_pools:
                 ledger.record(self._steps, "handoff",
                               tokens_total=sched.total_tokens)
+        # the decode program reads its tokens from the device
+        self._hold_values(claimed)
 
-    def _decode_phase(self, finished: List[FinishedRequest]) -> bool:
+    def _decode_phase(self) -> bool:
         """Advance every in-flight sequence: a plain one-token decode
         dispatch, or — with speculation and live draft proposals — ONE
         seq-``v`` verify dispatch that emits ``accepted + 1`` tokens
-        per row. Returns whether anything dispatched."""
+        per row. The rows, their positions and their pages follow from
+        COUNTS (``Scheduler.decode_state``); the tokens are the array
+        the device holds. Returns whether anything dispatched."""
         sched = self.scheduler
         ledger = self._dispatch_trace
         ledger.begin()
@@ -2108,13 +2300,8 @@ class InferenceEngine:
         if self.spec and self.paged:
             props = sched.draft_proposals(
                 cap=max(self._verify_widths) - 1)
-        spec_kw = {}
-        runs: Dict[int, List[int]] = {}
-        draft_stats = None
-        # the ledger row this dispatch will be, and what it reads, as
-        # the span's counters
-        counters = dict(seq=ledger.total, step=self._steps,
-                        rows=self._rows, live_tokens=live_tokens)
+        # what the dispatch reads, as its span's counters
+        counters = dict(rows=self._rows, live_tokens=live_tokens)
         if self.paged:
             counters["page_size"] = self.paged_spec.page_size
         if props:
@@ -2123,159 +2310,163 @@ class InferenceEngine:
             # verify tables ride at FULL width: one compiled program
             # per verify width, not per width x page bucket
             width = self.paged_spec.pages_per_seq
-            with self._span("serve/verify", width=v, table_pages=width,
-                            **counters):
-                with self._span("serve/verify/build"):
-                    toks_a, poss_a, temps_a, keys_a = self._decode_arrays(
-                        sids, toks, poss, temps, seeds)
-                    vt = np.zeros((self._rows, v), np.int32)
-                    vt[:, 0] = toks_a
-                    for sid, p in props.items():
-                        vt[sid, 1:1 + len(p)] = p
-                    tables = sched.block_table_rows(self._rows, width)
-                with self._span("serve/verify/dispatch"):
-                    out, self._cache = self._verify(
-                        self.params_decode, self._cache, vt, poss_a,
-                        tables, keys_a, temps_a)
-                ledger.issued()
-                with self._span("serve/verify/wait"):
-                    # host sync: the scheduler needs the token values
-                    out = np.asarray(out)
-                tok_ms = ledger.ready()
-            program = ("verify", v)
-            draft_stats = {}
-            proposed_total = accepted_total = 0
-            for sid in sids:
-                p = props.get(sid)
-                if not p:
-                    # rode the verify program with zero drafts — a
-                    # draft stall, traced once per request
-                    runs[sid] = [int(out[sid, 0])]
-                    tracer_uid = sched.slots[sid].request.uid
-                    self._tracer.on_defer(tracer_uid, "draft_stall")
-                    continue
-                m = 0
-                while m < len(p) and p[m] == int(out[sid, m]):
-                    m += 1
-                runs[sid] = [int(t) for t in out[sid, :m + 1]]
-                draft_stats[sid] = (len(p), m)
-                self._tracer.on_spec(
-                    sched.slots[sid].request.uid, len(p), m)
-                proposed_total += len(p)
-                accepted_total += m
-            if proposed_total:
-                spec_kw["spec_accept_rate"] = (accepted_total
-                                               / proposed_total)
-        else:
-            program = ("decode",)
-            with self._span("serve/plan"):
-                # ... and its span's counters
-                if self.paged:
-                    # clamp the dispatch's table width to the batch's
-                    # live-page bucket: reads (kernel walk or gather
-                    # stripe) scale with tokens in flight, and every
-                    # width was compiled at warmup
-                    width = pick_bucket(
-                        min(sched.max_live_pages(),
-                            self.paged_spec.pages_per_seq),
-                        self._decode_page_buckets)
-                    counters["table_pages"] = width
-                    program = ("decode", width)
-                    # what the Pallas kernel walks: each row's live
-                    # pages, ``block_pages`` a loop turn, an inactive
-                    # row's null page in one turn; the gather reader
-                    # walks none (it reads the table's whole width)
-                    ps = self.paged_spec.page_size
-                    per_turn = block_pages(ps, latent=self.latent)
-                    if self._decode_attn_path == "pallas":
-                        walks = live_pages(np.asarray(poss, np.int64), ps)
-                        idle = self._rows - len(sids)
-                        counters.update(
-                            read_pages=int(walks.sum()) + idle,
-                            read_turns=int((-(-walks // per_turn)).sum())
-                            + idle,
-                            block_tokens=per_turn * ps)
-                    else:
-                        counters.update(read_pages=0, read_turns=0,
-                                        block_tokens=0)
-                if self._expert_counters is not None:
-                    # routed experts: the rows that decode, and what the
-                    # router did with them the step before
-                    per_row, held = self._expert_counters
-                    layers = per_row // self.model_config.experts_per_token
+            counters.update(width=v, table_pages=width)
+            with self._span("serve/verify/build"):
+                poss_a, temps_a, keys_a = self._decode_arrays(
+                    sids, poss, temps, seeds)
+                vt = np.zeros((self._rows, v), np.int32)
+                # the drafter proposed from VALUES: this engine reads
+                # every dispatch at once, so the host's are the last
+                vt[sids, 0] = toks
+                for sid, p in props.items():
+                    vt[sid, 1:1 + len(p)] = p
+                tables = sched.block_table_rows(self._rows, width)
+            with self._span("serve/verify/dispatch"):
+                out, self._cache = self._verify(
+                    self.params_decode, self._cache, vt, poss_a,
+                    tables, keys_a, temps_a)
+                rows = list(zip(sids, sched.issue_tokens(sids)))
+
+            def arrive(out, tok_ms):
+                runs, draft_stats = [], {}
+                proposed_total = accepted_total = 0
+                for sid, slot in rows:
+                    p = props.get(sid)
+                    if not p:
+                        # rode the verify program with zero drafts — a
+                        # draft stall, traced once per request
+                        runs.append([int(out[sid, 0])])
+                        self._tracer.on_defer(slot.request.uid,
+                                              "draft_stall")
+                        continue
+                    m = 0
+                    while m < len(p) and p[m] == int(out[sid, m]):
+                        m += 1
+                    runs.append([int(t) for t in out[sid, :m + 1]])
+                    draft_stats[sid] = (len(p), m)
+                    self._tracer.on_spec(slot.request.uid, len(p), m)
+                    proposed_total += len(p)
+                    accepted_total += m
+                spec_kw = {}
+                if proposed_total:
+                    spec_kw["spec_accept_rate"] = (accepted_total
+                                                   / proposed_total)
+                with self._span("serve/record"):
+                    self._arrived(rows, runs, draft_stats)
+                    # the device's tokens: each row's last KEPT one
+                    self._hold_values({
+                        sid: run[-1] for (sid, slot), run in zip(rows, runs)
+                        if sched.holds(sid, slot)})
+                with self._span("serve/metrics"):
+                    self._write_decode_metrics(tok_ms, occupancy,
+                                               live_tokens, spec_kw)
+
+            self._issue("serve/verify", counters, ("verify", v), out,
+                        arrive)
+            return True
+        program = ("decode",)
+        with self._span("serve/plan"):
+            # ... and its span's counters
+            if self.paged:
+                # clamp the dispatch's table width to the batch's
+                # live-page bucket: reads (kernel walk or gather
+                # stripe) scale with tokens in flight, and every
+                # width was compiled at warmup
+                width = pick_bucket(
+                    min(sched.max_live_pages(),
+                        self.paged_spec.pages_per_seq),
+                    self._decode_page_buckets)
+                counters["table_pages"] = width
+                program = ("decode", width)
+                # what the Pallas kernel walks: each row's live
+                # pages, ``block_pages`` a loop turn, an inactive
+                # row's null page in one turn; the gather reader
+                # walks none (it reads the table's whole width)
+                ps = self.paged_spec.page_size
+                per_turn = block_pages(ps, latent=self.latent)
+                if self._decode_attn_path == "pallas":
+                    walks = live_pages(np.asarray(poss, np.int64), ps)
+                    idle = self._rows - len(sids)
                     counters.update(
-                        active=len(sids), held=held,
-                        assignments=len(sids) * per_row,
-                        landed=self._moe_counts[0],
-                        fullest=self._moe_counts[1],
-                        # every held expert on every row of the slot
-                        # table (``held_experts_every_row``): static
-                        expert_rows_worked=self._rows * held * layers)
-                    if len(self._moe_counts) > 2:
-                        # a group-limited router: the rows whose kept
-                        # groups include a group held here
-                        counters["group_rows"] = self._moe_counts[2]
-            with self._span("serve/decode", **counters):
-                with self._span("serve/decode/build"):
-                    toks_a, poss_a, temps_a, keys_a = self._decode_arrays(
-                        sids, toks, poss, temps, seeds)
-                    if self.paged:
-                        tables = sched.block_table_rows(self._rows, width)
-                with self._span("serve/decode/dispatch"):
-                    # the host arrays go to the program as they are (as
-                    # warm-up's do: one entry of the jit's cache): the
-                    # call's own transfer of them costs less than a
-                    # ``jnp.asarray`` each in Python (on a TPU's host six
-                    # were 1.9 ms and took 0.8 off the call)
-                    if self.paged:
-                        nxt, self._cache = self._decode(
-                            self.params_decode, self._cache, toks_a,
-                            poss_a, tables, keys_a, temps_a)
-                    else:
-                        nxt, self._cache = self._decode(
-                            self.params_decode, self._cache, toks_a,
-                            poss_a, keys_a, temps_a)
-                ledger.issued()
-                with self._span("serve/decode/wait"):
-                    # host sync: the scheduler needs the token values
-                    nxt = np.asarray(nxt)
-                    if self._expert_counters is not None:
-                        # (landed, fullest[, rows that kept a held
-                        # group]) ride behind the rows' tokens
-                        self._moe_counts = tuple(
-                            int(c) for c in nxt[self._rows:])
-                # Serve/token_latency_ms (verify's too): the phase's
-                # first host work to the tokens' arrival on the host
-                tok_ms = ledger.ready()
+                        read_pages=int(walks.sum()) + idle,
+                        read_turns=int((-(-walks // per_turn)).sum())
+                        + idle,
+                        block_tokens=per_turn * ps)
+                else:
+                    counters.update(read_pages=0, read_turns=0,
+                                    block_tokens=0)
+            if self._expert_counters is not None:
+                # routed experts: the rows that decoded in the last
+                # decode READ, and what the router did with them
+                per_row, held = self._expert_counters
+                layers = per_row // self.model_config.experts_per_token
+                counters.update(
+                    active=self._moe_active, held=held,
+                    assignments=self._moe_active * per_row,
+                    landed=self._moe_counts[0],
+                    fullest=self._moe_counts[1],
+                    # every held expert on every row of the slot
+                    # table (``held_experts_every_row``): static
+                    expert_rows_worked=self._rows * held * layers)
+                if len(self._moe_counts) > 2:
+                    # a group-limited router: the rows whose kept
+                    # groups include a group held here
+                    counters["group_rows"] = self._moe_counts[2]
+        with self._span("serve/decode/build"):
+            poss_a, temps_a, keys_a = self._decode_arrays(
+                sids, poss, temps, seeds)
+            if self.paged:
+                tables = sched.block_table_rows(self._rows, width)
+        with self._span("serve/decode/dispatch"):
+            # the tokens are the device's own (the decode before's
+            # result, first tokens merged in: never an upload of what
+            # the host read back); the host arrays go to the program as
+            # they are (as warm-up's do: one entry of the jit's cache):
+            # the call's own transfer of them costs less than a
+            # ``jnp.asarray`` each in Python (on a TPU's host six
+            # were 1.9 ms and took 0.8 off the call)
+            if self.paged:
+                nxt, self._cache = self._decode(
+                    self.params_decode, self._cache, self._last_tokens,
+                    poss_a, tables, keys_a, temps_a)
+            else:
+                nxt, self._cache = self._decode(
+                    self.params_decode, self._cache, self._last_tokens,
+                    poss_a, keys_a, temps_a)
+            self._hold_decoded(nxt)
+            rows = list(zip(sids, sched.issue_tokens(sids)))
+
+        def arrive(nxt, tok_ms):
+            if self._expert_counters is not None:
+                self._moe_counts = tuple(int(c) for c in nxt[self._rows:])
+                self._moe_active = len(rows)
             nxt = nxt.tolist()      # Python ints once, not a row
-            runs = {sid: [nxt[sid]] for sid in sids}
             if self.spec:
                 # speculation on, drafter had nothing anywhere: the
                 # whole dispatch fell back to plain decode
-                for sid in sids:
-                    self._tracer.on_defer(
-                        sched.slots[sid].request.uid, "draft_stall")
-        with self._span("serve/record"):
-            finished.extend(sched.record_token_runs(runs, draft_stats))
-        with self._span("serve/metrics"):
-            self._write_decode_metrics(tok_ms, occupancy, live_tokens,
-                                       spec_kw)
-        ledger.record(self._steps, *program,
-                      tokens_total=sched.total_tokens)
+                for _, slot in rows:
+                    self._tracer.on_defer(slot.request.uid, "draft_stall")
+            with self._span("serve/record"):
+                self._arrived(rows, [[nxt[sid]] for sid in sids])
+            with self._span("serve/metrics"):
+                # Serve/token_latency_ms (verify's too): the phase's
+                # first host work to the tokens' arrival on the host
+                self._write_decode_metrics(tok_ms, occupancy, live_tokens,
+                                           {})
+
+        self._issue("serve/decode", counters, program, nxt, arrive)
         return True
 
-    def _decode_arrays(self, sids, toks, poss, temps, seeds):
+    def _decode_arrays(self, sids, poss, temps, seeds):
         """The decode dispatch's per-row host arrays over the full slot
         table (inactive rows stay zero)."""
-        toks_a = np.zeros((self._rows,), np.int32)
         poss_a = np.zeros((self._rows,), np.int32)
         temps_a = np.zeros((self._rows,), np.float32)
         keys_a = np.zeros((self._rows, 2), np.uint32)
-        toks_a[sids] = toks
         poss_a[sids] = poss
         temps_a[sids] = temps
         keys_a[sids] = _keys_for(seeds)
-        return toks_a, poss_a, temps_a, keys_a
+        return poss_a, temps_a, keys_a
 
     def _write_decode_metrics(self, tok_ms, occupancy, live_tokens,
                               spec_kw) -> None:
@@ -2325,34 +2516,49 @@ class InferenceEngine:
 
     def step(self) -> List[FinishedRequest]:
         """One serving iteration. Default: admit waiting requests into
-        free slots (bucketed prefill, first token released), then
-        advance every in-flight sequence one decode (or speculative
-        verify) dispatch. Disaggregated (``inference.disagg``): the
-        DECODE phase runs FIRST — handoff claims, then the decode/
-        verify dispatch — and the prefill phase runs after it, so no
-        decode dispatch ever waits behind a prefill dispatch
-        (structural; pinned by the dispatch trace). Chunked prefill
-        (``inference.chunked_prefill``) makes every step decode-first
-        and slips AT MOST ONE chunk dispatch between the decode and
-        admission phases: claim? -> decode -> chunk -> prefill.
-        Returns requests that finished this iteration."""
-        finished: List[FinishedRequest] = []
-        finished.extend(self.scheduler.drain_rejects())
+        free slots (bucketed prefill), then advance every in-flight
+        sequence one decode (or speculative verify) dispatch.
+        Disaggregated (``inference.disagg``): the DECODE phase runs
+        FIRST — handoff claims, then the decode/verify dispatch — and
+        the prefill phase runs after it, so no decode dispatch ever
+        waits behind a prefill dispatch (structural; pinned by the
+        dispatch trace). Chunked prefill (``inference.chunked_prefill``)
+        makes every step decode-first and slips AT MOST ONE chunk
+        dispatch between the decode and admission phases: claim? ->
+        decode -> chunk -> prefill.
+
+        A dispatch's tokens are read after the NEXT dispatch has been
+        issued (:meth:`_issue`), so the last dispatch of a step is read
+        in the step after: a request that ends is seen one dispatch
+        late, and its slot is admitted into one step later than a loop
+        that read at once would (a chunked engine whose step has a
+        chunk reads its decode before it admits, and loses nothing). An
+        engine that needs the values before its next issue (speculation,
+        disaggregation) reads every dispatch at once, through the same
+        code. Returns the requests whose last token ARRIVED since the
+        step before returned."""
+        sched = self.scheduler
+        issues = self._issues
+        sched.undelivered.extend(sched.drain_rejects())
         if self.disagg:
-            self._claim_phase(finished)
-            self._decode_phase(finished)
-            self._chunk_phase(finished)
-            self._prefill_phase(finished)
+            self._claim_phase()
+            self._decode_phase()
+            self._chunk_phase()
+            self._prefill_phase()
         elif self.chunked:
             # decode-first for chunked engines: the in-flight decodes
             # advance, then at most one chunk slice, then admission —
             # the interleave guarantee that bounds TBT-max
-            self._decode_phase(finished)
-            self._chunk_phase(finished)
-            self._prefill_phase(finished)
+            self._decode_phase()
+            self._chunk_phase()
+            self._prefill_phase()
         else:
-            self._prefill_phase(finished)
-            self._decode_phase(finished)
+            self._prefill_phase()
+            self._decode_phase()
+        if self._issues == issues:
+            # nothing was issued (every slot waits for its last value):
+            # no device work for the host to hide behind
+            self._settle()
 
         # serve_finish / serve_evict rows are emitted by the tracer as
         # the scheduler retires each request (sync-free host appends)
@@ -2362,15 +2568,18 @@ class InferenceEngine:
             if self._log is not None and self._state_event_every and \
                     self._steps % self._state_event_every == 0:
                 self._log.add_event("serve_state", step=self._steps,
-                                    **self.debug_state())
+                                    **self._debug_state())
+        finished, sched.undelivered = sched.undelivered, []
         return finished
 
     def run(self) -> List[FinishedRequest]:
-        """Serve until queue and slots drain; returns everything that
-        finished."""
-        out: List[FinishedRequest] = list(self.scheduler.drain_rejects())
+        """Serve until queue and slots drain and every answer has been
+        handed out; returns everything that finished."""
+        out: List[FinishedRequest] = []
         while not self.scheduler.idle():
             out.extend(self.step())
+        # a read may still wait whose rows were all released already
+        self._settle()
         out.extend(self.scheduler.drain_rejects())
         return out
 
@@ -2410,9 +2619,12 @@ class InferenceEngine:
         this, :attr:`steady_state_recompiles` staying 0 is the serving
         latency contract."""
         assert self.scheduler.idle(), "warmup with requests in flight"
-        # every program is warmed with HOST arrays, the kind each
-        # dispatch passes: a device array would be a second entry in
-        # the jit's cache, and the first real dispatch a recompile
+        # every program is warmed with the KIND of argument each
+        # dispatch passes: host arrays, and for the decode program's
+        # tokens the device array a program returned (another kind
+        # would be a second entry in the jit's cache, and the first
+        # real dispatch a recompile). The merge program is warmed at
+        # every shape the loop hands it, by the loop's own helpers
         for bb, sb in warmup_plan(self.config["batch_buckets"],
                                   self.config["prompt_buckets"]):
             ids = np.zeros((bb, sb), np.int32)
@@ -2439,6 +2651,8 @@ class InferenceEngine:
                 first, self._cache = self._prefill(
                     self.params, self._cache, ids, lengths, positions,
                     ztab, keys, temps)
+            if not self.disagg:
+                self._hold(first, slots)
         if self.paged and self.chunked:
             # one chunk shape per batch bucket (single chunk bucket x
             # batch buckets — the ladder collapse), plus the CP chunk
@@ -2451,8 +2665,8 @@ class InferenceEngine:
                 for bb, ct in plan:
                     cache = self._cache_prefill if self._separate_pools \
                         else self._cache
-                    more = (np.full((bb,), self._scratch, np.int32),) \
-                        if self._prefill_by_length else ()
+                    slots = np.full((bb,), self._scratch, np.int32)
+                    more = (slots,) if self._prefill_by_length else ()
                     first, cache = prog(
                         self.params, cache, np.zeros((bb, ct), np.int32),
                         np.ones((bb,), np.int32),
@@ -2464,20 +2678,28 @@ class InferenceEngine:
                         self._cache_prefill = cache
                     else:
                         self._cache = cache
+                    if not self.disagg:
+                        self._hold(first, slots)
+        if self.spec or self.disagg:
+            # tokens the host chose: a verify run's, a claimed handoff's
+            self._hold_values({self._scratch: 0})
         if self.paged:
-            for w in self._decode_page_buckets:
+            # the first width once more at the end: it reads a decode's
+            # own result, as every decode of the loop does
+            for w in self._decode_page_buckets + \
+                    self._decode_page_buckets[:1]:
                 nxt, self._cache = self._decode(
-                    self.params_decode, self._cache,
-                    np.zeros((self._rows,), np.int32),
+                    self.params_decode, self._cache, self._last_tokens,
                     np.zeros((self._rows,), np.int32),
                     np.zeros((self._rows, w), np.int32),
                     np.zeros((self._rows, 2), np.uint32),
                     np.zeros((self._rows,), np.float32))
+                self._hold_decoded(nxt)
             if self.spec:
                 # one verify program per width — tables always ride at
                 # full pps, so widths x 1 (not widths x page buckets)
                 for v in self._verify_widths:
-                    nxt2, self._cache = self._verify(
+                    nxt, self._cache = self._verify(
                         self.params_decode, self._cache,
                         np.zeros((self._rows, v), np.int32),
                         np.zeros((self._rows,), np.int32),
@@ -2486,7 +2708,6 @@ class InferenceEngine:
                             np.int32),
                         np.zeros((self._rows, 2), np.uint32),
                         np.zeros((self._rows,), np.float32))
-                    nxt = nxt2[:, 0]
             if self._separate_pools:
                 # warm both handoff programs against the null page so
                 # the first real claim doesn't compile on the clock
@@ -2498,12 +2719,13 @@ class InferenceEngine:
                         for s in slab)
                 self._cache = self._import(self._cache, slab, idx)
         else:
-            nxt, self._cache = self._decode(
-                self.params_decode, self._cache,
-                np.zeros((self._rows,), np.int32),
-                np.zeros((self._rows,), np.int32),
-                np.zeros((self._rows, 2), np.uint32),
-                np.zeros((self._rows,), np.float32))
+            for _ in range(2):
+                nxt, self._cache = self._decode(
+                    self.params_decode, self._cache, self._last_tokens,
+                    np.zeros((self._rows,), np.int32),
+                    np.zeros((self._rows, 2), np.uint32),
+                    np.zeros((self._rows,), np.float32))
+                self._hold_decoded(nxt)
         jax.block_until_ready(nxt)
         self._warm_compiles = self.compile_tracker.total_compiles
         if self._log is not None:
@@ -2572,6 +2794,8 @@ class InferenceEngine:
         idx = jnp.zeros((self._mig_width,), jnp.int32)
         slab = self._mig_export(self._cache, idx)
         self._cache = self._mig_import(self._cache, slab, idx)
+        # ... and the merge of a migrated request's pending token
+        self._hold_values({self._scratch: 0})
         jax.block_until_ready(self._cache[0])
         compiled = self.compile_tracker.total_compiles - before
         self._warm_compiles = self.compile_tracker.total_compiles
@@ -2664,6 +2888,8 @@ class InferenceEngine:
                                 wall_ms=round(ev.wall_ms, 3), step=ev.step)
 
     def close(self):
+        # every dispatch is a row of the ledger, every token counted
+        self._settle()
         # whoever drove this engine can still read its ledger
         # (profiling.spans.last_dispatch_ledger)
         _keep_dispatch_ledger(self._dispatch_trace)
@@ -2674,7 +2900,7 @@ class InferenceEngine:
             # seal the run with a final pool/SLO snapshot — obs_report
             # renders the LAST serve_state row as the pool view
             self._log.add_event("serve_state", step=self._steps,
-                                **self.debug_state())
+                                **self._debug_state())
         if self._chrome_path and self._recorder is not None:
             try:
                 self._recorder.dump(self._chrome_path)

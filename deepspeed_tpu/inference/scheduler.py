@@ -128,7 +128,7 @@ class PrefillBatch:
 class _Slot:
     request: Request
     position: int                # tokens currently in this row's cache
-    pending_tok: Optional[int]   # sampled, not yet written to cache
+    pending_tok: Optional[int]   # the last sampled VALUE the host holds
     tokens: List[int]
     t_submit: float
     ttft_ms: Optional[float] = None
@@ -146,9 +146,14 @@ class _Slot:
     # scattered into this slot's pages — the ONLY extra state a chunk
     # needs (the next chunk is just the prefill program at
     # ``positions = chunk_pos``). None = not chunked / prefill done.
-    # While an int, ``pending_tok`` stays None, which already keeps the
-    # slot out of decode dispatches and nulls its block-table rows.
+    # While an int, ``issued`` stays 0, which already keeps the slot out
+    # of decode dispatches and nulls its block-table rows.
     chunk_pos: Optional[int] = None
+    # tokens the device has been ASKED for: a prefill's (or a final
+    # chunk's) first, and one a decode dispatch since. Dispatches are
+    # built from this count and ``position`` alone; ``len(tokens)`` of
+    # them have ARRIVED on the host, ``pending_tok`` the last of those
+    issued: int = 0
 
 
 class Scheduler:
@@ -219,6 +224,13 @@ class Scheduler:
         # graceful submit-time rejections awaiting the engine's next
         # ``step``/``run`` drain (they are already in ``finished`` too)
         self._rejects: List[FinishedRequest] = []
+        # requests whose last value has arrived and that no ``step`` has
+        # returned yet: the engine reads a dispatch's tokens after it
+        # has issued the next, or when a call from outside a step makes
+        # it settle (``cancel``, ``export_request``); its ``step`` hands
+        # them out and empties this. Not idle while it holds any: a
+        # driver that stops stepping an idle engine loses no answer.
+        self.undelivered: List[FinishedRequest] = []
         self._new_ttfts: List[float] = []
         self._new_queue_waits: List[float] = []
         # cumulative counters (serving telemetry)
@@ -259,7 +271,8 @@ class Scheduler:
         return n
 
     def idle(self) -> bool:
-        return not self.queue and not self.active_slots()
+        return not self.queue and not self.active_slots() \
+            and not self.undelivered
 
     # ----------------------------------------------------------- submit
     def _reject_too_long(self, request: Request) -> int:
@@ -447,7 +460,10 @@ class Scheduler:
             ttft_ms=ttft_ms, pages=list(pages),
             queue_wait_ms=float(queue_wait_ms), pool=pool,
             draft_proposed=int(draft_proposed),
-            draft_accepted=int(draft_accepted))
+            draft_accepted=int(draft_accepted),
+            # every token it brings has arrived; the pending one is
+            # what the device must be handed (the engine's to do)
+            issued=max(len(tokens), 1))
         self.total_admitted += 1
         self.peak_tokens_in_flight = max(self.peak_tokens_in_flight,
                                          self.tokens_in_flight)
@@ -578,6 +594,44 @@ class Scheduler:
         return batches
 
     # ----------------------------------------------------- token stream
+    # A token is ISSUED (a dispatch that will sample it has been called:
+    # the slot advances by count) and later ARRIVES (the host holds its
+    # value: the request's tokens, the tracer, the finish). The engine
+    # issues a step's dispatches before it reads the step before's
+    # tokens, so the two are apart by a dispatch; a caller that records
+    # as it reads does both in ``record_token_runs``.
+    @staticmethod
+    def _decodes(slot: _Slot) -> bool:
+        """Whether the next decode dispatch carries this slot: the
+        device holds a sampled token of its (``issued``: no VALUE is
+        needed) and its last has not been asked for."""
+        return 0 < slot.issued < slot.request.max_new_tokens
+
+    @staticmethod
+    def _issue(slot: _Slot) -> None:
+        if slot.issued:
+            # the sample before is written to the cache by the dispatch
+            # that produces this one
+            slot.position += 1
+        slot.issued += 1
+
+    def issue_tokens(self, slot_ids: Sequence[int]) -> List[_Slot]:
+        """A dispatch that samples ONE token for each of ``slot_ids`` has
+        been called: advance every row by count, so that the next
+        dispatch can be built before this one's values are read. Returns
+        the rows' slots: what :meth:`holds` is asked when the values
+        arrive (a slot may have been released in between)."""
+        slots = [self.slots[sid] for sid in slot_ids]
+        for slot in slots:
+            self._issue(slot)
+        return slots
+
+    def holds(self, sid: int, slot: _Slot) -> bool:
+        """Whether ``slot`` is still slot ``sid``'s: a value that arrives
+        for a released slot (its request hit EOS a dispatch earlier) is
+        dropped by the caller, never recorded."""
+        return self.slots[sid] is slot
+
     def record_tokens(self, tokens: Dict[int, int]
                       ) -> List[FinishedRequest]:
         """Record one sampled token per slot (``{slot_id: token}``) —
@@ -593,16 +647,21 @@ class Scheduler:
                           draft_stats: Optional[
                               Dict[int, Tuple[int, int]]] = None
                           ) -> List[FinishedRequest]:
-        """Record a RUN of kept tokens per slot — one token from a
-        plain decode/prefill dispatch, or ``m + 1`` from a speculative
+        """The ARRIVAL of a run of kept tokens per slot — one token from
+        a plain decode/prefill dispatch, or ``m + 1`` from a speculative
         verify dispatch that accepted ``m`` draft tokens (the accepted
-        drafts plus the dispatch's fresh bonus sample). Every token in
-        a run advances position by one: each was written to the cache
-        by the dispatch that produced it, except the LAST, which
-        becomes the new pending token — exactly the single-token
-        invariant, iterated. A mid-run EOS (or max_new) finishes the
-        request and DISCARDS the run's remainder: tokens past a stop
-        are never emitted, counted, or written back.
+        drafts plus the dispatch's fresh bonus sample). The values go
+        into the slot's ``tokens``, ``total_tokens``, the tracer and,
+        at a stop, the :class:`FinishedRequest`, which all grow HERE and
+        together. A token that was not issued ahead (:meth:`issue_tokens`)
+        is counted as it arrives — a verify run's accepted drafts, a
+        caller that records as it reads: every token in a run advances
+        position by one, each was written to the cache by the dispatch
+        that produced it, except the LAST, which the device holds
+        pending — exactly the single-token invariant, iterated. A
+        mid-run EOS (or max_new) finishes the request and DISCARDS the
+        run's remainder, and whatever was issued past the stop: tokens
+        past a stop are never emitted, counted, or written back.
 
         ``draft_stats`` (``{slot_id: (proposed, accepted)}``) settles
         the speculative ledger for the dispatch that produced the runs
@@ -629,10 +688,8 @@ class Scheduler:
             fin = None
             for tok in run:
                 tok = int(tok)
-                if slot.pending_tok is not None:
-                    # the previous sample was written to the cache by
-                    # the dispatch that produced this one
-                    slot.position += 1
+                if len(slot.tokens) == slot.issued:
+                    self._issue(slot)
                 if slot.ttft_ms is None:
                     slot.ttft_ms = (now - slot.t_submit) * 1e3
                     self._new_ttfts.append(slot.ttft_ms)
@@ -833,15 +890,20 @@ class Scheduler:
 
     # -------------------------------------------- decode-batch assembly
     def decode_state(self):
-        """Host arrays for one decode dispatch over the full slot table:
-        (slot_ids, toks, positions, temps, seeds) — inactive rows carry
-        zeros and are ignored on the way back. Empty when nothing is
-        mid-decode."""
+        """Host lists for one decode dispatch over the full slot table,
+        from COUNTS alone: (slot_ids, toks, positions, temps, seeds) of
+        the rows the device holds a pending token of and whose last has
+        not been asked for — inactive rows carry zeros and are ignored
+        on the way back. ``toks`` is the last VALUE the host holds of
+        each row (a verify dispatch's, whose engine reads every dispatch
+        at once): the decode program reads its tokens from the device,
+        where the one it needs may not have arrived here yet. Empty when
+        nothing is mid-decode."""
         rows = [(sid, slot.pending_tok, slot.position,
                  slot.request.temperature, slot.request.seed)
                 for sid, slot in enumerate(self.slots)
-                # None: admitted this step; first token pending
-                if slot is not None and slot.pending_tok is not None]
+                # issued 0: admitted this step; first token not asked for
+                if slot is not None and self._decodes(slot)]
         if not rows:
             return [], [], [], [], []
         sids, toks, poss, temps, seeds = zip(*rows)
@@ -864,7 +926,7 @@ class Scheduler:
         out = np.zeros((rows, pages_per_seq), np.int32)
         for sid in self.active_slots():
             slot = self.slots[sid]
-            if slot.pending_tok is None:
+            if not self._decodes(slot):
                 continue
             # a slot's page list is replaced, never edited: its array is
             # made once a reservation, not once a step (192 rows: the

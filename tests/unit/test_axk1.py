@@ -478,8 +478,10 @@ def test_a_request_of_the_family_cannot_be_exported_or_imported(model):
 def test_the_decode_span_carries_the_experts_and_the_groups_counters(
         model, monkeypatch):
     """active, assignments, landed, fullest, held and group_rows on
-    `serve/decode`: the counts of the step before, read with the sampled
-    tokens; the prefill span carries its expert turns' rows."""
+    `serve/decode`: the counts of the last decode READ when the dispatch
+    was planned (its tokens are taken after the next dispatch has been
+    issued, so two steps before), read with the sampled tokens; the
+    prefill span carries its expert turns' rows."""
     cfg, params, _ = model
     seen, prefills = [], []
     plain = InferenceEngine._span
@@ -503,14 +505,14 @@ def test_the_decode_span_carries_the_experts_and_the_groups_counters(
     layers = len(cfg.expert_layers)
     for args in seen:
         assert {"active", "assignments", "landed", "fullest", "held",
-                "group_rows"} <= set(args) or args is seen[0]
+                "group_rows"} <= set(args) or args in seen[:2]
         assert args["held"] == 2
         assert args["assignments"] == args["active"] * 4 * layers
         assert 0 <= args["fullest"] <= args["landed"] <= 2 * 4 * layers
     assert seen[0]["landed"] == 0               # nothing decoded before
     # a row keeps 4 of 8 groups: about half the rows keep the held one's
-    assert all(0 <= a["group_rows"] <= 2 * layers for a in seen[1:])
-    assert any(a["group_rows"] > 0 for a in seen[1:])
+    assert all(0 <= a["group_rows"] <= 2 * layers for a in seen[2:])
+    assert any(a["group_rows"] > 0 for a in seen[2:])
     assert all(a["own_key_tokens"] == a["real_tokens"] for a in prefills)
 
 

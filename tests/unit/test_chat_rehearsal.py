@@ -37,7 +37,8 @@ def test_the_chat_cell_rehearses_on_the_cpu(tmp_path):
     assert line["correct"] and line["failed"] == 0 and line["attempted"] > 0
     assert set(line["metrics"]) == {"serve_tokens_per_s", "setup_s"}
     assert "convolution tails 5 rows over 5 per-slot layers" in out.stdout
-    assert "5 programs warm" in out.stdout
+    # 4 prefill buckets, the decode, and the token merge at its 3 shapes
+    assert "8 programs warm" in out.stdout
     assert "order_seed 51" in out.stdout
 
 

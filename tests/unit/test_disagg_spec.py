@@ -441,9 +441,12 @@ class TestSpecEngine:
 
     def test_warmup_program_set_pinned(self, runs):
         # speculation adds exactly one verify program per verify width
-        # (tables ride at full pps — never widths x page buckets)
+        # (tables ride at full pps — never widths x page buckets), and
+        # one shape of the token merge: a verify run's last kept tokens
+        # are the host's to hand the device
         widths = runs["spec"]["state"]["spec_decode"]["verify_widths"]
-        assert runs["spec"]["warm"] == runs["base"]["warm"] + len(widths)
+        assert runs["spec"]["warm"] == \
+            runs["base"]["warm"] + len(widths) + 1
         progs = runs["spec"]["state"]["programs"]
         assert "verify" in progs and progs["verify"]["dispatches"] > 0
 

@@ -1,7 +1,8 @@
 """What a dispatch's host section may touch (docs/inference.md): a
 request's sampling key is two words the host writes, held to the
 library's once at engine construction, and every dispatch hands its
-host arrays to the program as they are, the kind warm-up passed, so a
+host arrays to the program as they are, and the decode program the
+device's own array of last tokens, each the kind warm-up passed, so a
 program keeps ONE entry in its jit cache. Tiny sizes on the CPU."""
 
 import types
@@ -223,7 +224,7 @@ def test_serving_leaves_every_jit_cache_as_warmup_left_it(case):
     name, engine, phases = case
     engine.warmup()
     programs = _programs(engine)
-    assert {"_prefill", "_decode"} <= set(programs)
+    assert {"_prefill", "_decode", "_merge"} <= set(programs)
     warm = {n: p._cache_size() for n, p in programs.items()}
     finished = _serve(engine, phases)
     assert len(finished) == sum(len(g) for g in phases)
@@ -241,6 +242,60 @@ def test_serving_leaves_every_jit_cache_as_warmup_left_it(case):
     if name == "separate_pools":
         assert ("handoff",) in ran
         assert warm["_export"] == warm["_import"] == 1
+
+
+def _kinds(args):
+    """("device" | "host" | "tree") an argument of a program call."""
+    return tuple("device" if isinstance(a, jax.Array)
+                 else "host" if isinstance(a, np.ndarray) else "tree"
+                 for a in args)
+
+
+@pytest.mark.parametrize("case", list(CASES), indirect=True)
+def test_the_loop_passes_the_kinds_of_argument_warmup_passed(case):
+    """To a jit a NumPy argument and a device array are two cache
+    entries: every program is called in the loop with the kinds (and
+    shapes) of argument warm-up called it with. The decode program's
+    tokens are a DEVICE array (the one the decode before returned, first
+    tokens merged in), never an upload of what the host read back; the
+    merge program takes the device's tokens, a program's result as it
+    returned it, and the host's slots."""
+    name, engine, phases = case
+    calls = {}
+
+    def watch(attr):
+        prog = getattr(engine, attr)
+
+        def watched(*args):
+            shapes = tuple(getattr(a, "shape", None) for a in args)
+            calls.setdefault(attr, set()).add((_kinds(args), shapes))
+            return prog(*args)
+        watched._cache_size = prog._cache_size
+        setattr(engine, attr, watched)
+
+    for attr in _programs(engine):
+        watch(attr)
+    engine.warmup()
+    warmed = {attr: set(seen) for attr, seen in calls.items()}
+    finished = _serve(engine, phases)
+    assert len(finished) == sum(len(g) for g in phases)
+    assert engine.steady_state_recompiles == 0
+    for attr, seen in calls.items():
+        assert seen <= warmed[attr], (attr, seen - warmed[attr])
+    rows = engine._rows
+    for kinds, shapes in calls["_decode"]:
+        # params, cache, tokens, then host arrays
+        assert kinds[:3] == ("tree", "tree", "device")
+        assert set(kinds[3:]) == {"host"} and shapes[2] == (rows,)
+    for kinds, shapes in calls["_merge"]:
+        assert kinds[0] == "device" and kinds[2] == "host"
+        assert shapes[0] == (rows,) and shapes[1][0] >= shapes[2][0]
+    host_values = {k for k, _ in calls["_merge"] if k[1] == "host"}
+    # values the HOST chose reach the device only where an engine reads
+    # every dispatch at once: a verify run's last kept tokens, a claimed
+    # handoff's first token
+    assert bool(host_values) == (name in ("chunked_spec",
+                                          "separate_pools"))
 
 
 class _NoAsarray(types.ModuleType):

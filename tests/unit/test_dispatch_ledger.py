@@ -157,9 +157,11 @@ def served():
             engine.step()
         assert not engine.scheduler.idle()
         ledger = engine.dispatch_ledger
-        total_tokens = engine.scheduler.total_tokens
         steps = engine._steps
+        # the step's last dispatch is read in the step after: close()
+        # takes the one still waiting, and its tokens are counted then
         engine.close()
+        total_tokens = engine.scheduler.total_tokens
     finally:
         spans.set_default_recorder(None)
     return {"ledger": ledger, "events": list(recorder.events),
@@ -176,8 +178,14 @@ def test_every_dispatch_is_one_row_with_a_dense_seq(served):
     events = _dispatch_events(served)
     assert served["ledger"].dropped == 0
     assert table["seq"] == list(range(len(events)))
-    assert sum(k == "decode" for k in table["kind"]) == 40 == served["steps"]
-    # the spans carry the row's seq and step, in the order dispatched
+    # at most one decode a step (none where every slot waits for the
+    # value of a last token already asked for), each in a step of its own
+    decode_steps = [s for s, k in zip(table["step"], table["kind"])
+                    if k == "decode"]
+    assert decode_steps == sorted(set(decode_steps))
+    assert 30 <= len(decode_steps) <= 40 == served["steps"]
+    # the spans carry the row's seq and step (the step that ISSUED the
+    # dispatch), in the order dispatched, which is the order read
     assert [ev["args"]["seq"] for ev in events] == table["seq"]
     assert [ev["args"]["step"] for ev in events] == table["step"]
     assert [ev["name"].split("/")[1] for ev in events] == table["kind"]
@@ -218,6 +226,80 @@ def test_the_ledger_outlives_close(served):
     assert spans.last_dispatch_ledger() is first.dispatch_ledger
     second.close()
     assert spans.last_dispatch_ledger() is second.dispatch_ledger
+
+
+class _Ticking:
+    """A clock that moves a millisecond every time it is read."""
+
+    def __init__(self):
+        self.t = 50.0
+
+    def __call__(self):
+        self.t += 0.001
+        return self.t
+
+
+def test_deferred_rows_legs_sum_to_their_intervals_on_a_fake_clock():
+    """The engine's own stamping, on a clock that ticks a read: a row is
+    recorded when its tokens ARRIVE (after the next dispatch was
+    issued), in the order issued; `t_issued` is where the host starts to
+    block for it, `t_ready` where it holds the tokens; before + wait +
+    after of every row is the time since the row before was done, so the
+    rows tile the host's clock with deferred rows as they did without."""
+    recorder = spans.ChromeTraceRecorder()
+    spans.set_default_recorder(recorder)
+    try:
+        engine = InferenceEngine(
+            CFG, init_gpt2_params(CFG, jax.random.PRNGKey(3)), INF,
+            dtype=jnp.float32)
+        clock = _Ticking()
+        engine._dispatch_trace = ledger = DispatchTrace(clock=clock)
+        rs = np.random.RandomState(1)
+        for i in range(12):
+            engine.submit(Request(
+                prompt=[int(x) for x in rs.randint(1, 60, rs.randint(2, 9))],
+                max_new_tokens=int(rs.randint(3, 7)), temperature=0.0,
+                seed=i, eos_id=None))
+        issued_in = []          # the step each dispatch was issued in
+        plain = engine._issue
+
+        def issue(name, *args):
+            issued_in.append((engine._steps, name.split("/")[1]))
+            return plain(name, *args)
+
+        engine._issue = issue
+        while not engine.scheduler.idle():
+            engine.step()
+        total_tokens = engine.scheduler.total_tokens
+        engine.close()
+    finally:
+        spans.set_default_recorder(None)
+    table = ledger.table()
+    # a row a dispatch, in the order issued, under the step that issued it
+    assert list(zip(table["step"], table["kind"])) == issued_in
+    t = {k: np.asarray(table[k]) for k in ("t_begin", "t_issued", "t_ready",
+                                           "t_done")}
+    assert (t["t_begin"] < t["t_issued"]).all()
+    assert (t["t_issued"] < t["t_ready"]).all()
+    assert (t["t_ready"] < t["t_done"]).all()
+    assert (t["t_begin"][1:] >= t["t_done"][:-1]).all()
+    start = np.concatenate([t["t_begin"][:1], t["t_done"][:-1]])
+    before, wait, after = (t["t_issued"] - start, t["t_ready"] - t["t_issued"],
+                           t["t_done"] - t["t_ready"])
+    np.testing.assert_allclose(before + wait + after, t["t_done"] - start,
+                               rtol=0, atol=1e-9)
+    assert (before + wait + after).sum() == pytest.approx(
+        t["t_done"][-1] - t["t_begin"][0], abs=1e-9)
+    # the wait leg is the read alone: one tick of this clock
+    assert wait == pytest.approx(0.001)
+    assert sum(table["tokens"]) == total_tokens > 0
+    # the spans carry the rows' seq, and most reads were deferred (the
+    # next dispatch's build and call are in their BEFORE leg)
+    events = [ev for ev in recorder.events
+              if ev["name"] in ("serve/prefill", "serve/decode")]
+    assert [ev["args"]["seq"] for ev in events] == table["seq"]
+    deferred = np.asarray([ev["args"]["deferred"] for ev in events])
+    assert 0.7 * len(events) < deferred.sum() < len(events)
 
 
 # ------------------------------------------- the reader, on a hand ledger

@@ -339,9 +339,11 @@ class TestInferenceEngine:
                                  dtype=jnp.float32)
         assert engine.steady_state_recompiles == -1   # before warmup
         programs = engine.warmup()
-        assert programs == 2 * 2 + 1
+        # (the merge of first tokens into the device's: a batch bucket)
+        assert programs == 2 * 2 + 1 + 2
         assert engine.compile_tracker.counts == {"prefill": 4,
-                                                 "decode": 1}
+                                                 "decode": 1,
+                                                 "merge_tokens": 2}
         rng = np.random.RandomState(2)
         prompts = [rng.randint(1, 61, (n,)).tolist()
                    for n in (1, 4, 5, 8, 3, 6, 2, 7)]
@@ -920,9 +922,11 @@ class TestPagedServing:
             dict(TINY_INF, paged_kv={"page_size": 4, "num_pages": 14}),
             dtype=jnp.float32)
         programs = engine.warmup()
-        assert programs == 2 * 2 + 1
+        # (the merge of first tokens into the device's: a batch bucket)
+        assert programs == 2 * 2 + 1 + 2
         assert engine.compile_tracker.counts == {"prefill": 4,
-                                                 "decode": 1}
+                                                 "decode": 1,
+                                                 "merge_tokens": 2}
         rng = np.random.RandomState(5)
         sys_prompt = rng.randint(1, 61, (4,)).tolist()
         churn = [rng.randint(1, 61, (n,)).tolist()
@@ -1098,7 +1102,8 @@ class TestServingMesh:
         engine = InferenceEngine(cfg, params, self.MESH_INF,
                                  dtype=jnp.float32)
         programs = engine.warmup()
-        assert programs == 2 * 2 + 1
+        # (the merge of first tokens into the device's: a batch bucket)
+        assert programs == 2 * 2 + 1 + 2
         rng = np.random.RandomState(2)
         prompts = [rng.randint(1, 61, (n,)).tolist()
                    for n in (1, 4, 5, 8, 3)]

@@ -129,10 +129,13 @@ def _until_first_token(engine, prompt, whole=False):
     engine.submit(Request(prompt=prompt, max_new_tokens=4, temperature=0.0,
                           seed=7, eos_id=None))
     if whole:
-        engine._prefill_phase([])       # a step would decode behind it
+        engine._prefill_phase()         # a step would decode behind it
     while engine.scheduler.slots[0] is None \
-            or engine.scheduler.slots[0].pending_tok is None:
+            or not engine.scheduler.slots[0].issued:
         engine.step()                   # decode first, then ONE chunk
+    # the first token has been asked for and no decode issued behind it:
+    # its read, still waiting, is taken here
+    engine.debug_state()
     cache = engine._cache
     return (engine.scheduler.slots[0].tokens[0],
             np.asarray(cache.state[:, 0]), np.asarray(cache.tails[:, 0]))
@@ -173,11 +176,11 @@ def test_a_decode_between_two_chunks_leaves_the_slots_state_as_it_is(model):
         return (np.asarray(engine._cache.state[:, 1]),
                 np.asarray(engine._cache.tails[:, 1]))
 
-    def watched_decode(finished):
+    def watched_decode():
         mid = engine.scheduler.slots[1] is not None and \
             engine.scheduler.slots[1].chunk_pos not in (None, 0)
         before = rows()
-        ran = decode(finished)
+        ran = decode()
         if mid and ran:
             seen.append((before, rows()))
         return ran

@@ -566,9 +566,11 @@ class TestEngineParity:
                                  dict(TINY_INF, paged_kv=PAGED_PALLAS),
                                  dtype=jnp.float32)
         programs = engine.warmup()
-        assert programs == 2 * 2 + 1
+        # (the merge of first tokens into the device's: a batch bucket)
+        assert programs == 2 * 2 + 1 + 2
         assert engine.compile_tracker.counts == {"prefill": 4,
-                                                 "decode": 1}
+                                                 "decode": 1,
+                                                 "merge_tokens": 2}
         rng = np.random.RandomState(5)
         churn = [rng.randint(1, 61, (n,)).tolist()
                  for n in (1, 4, 5, 8, 3, 6)]
@@ -915,9 +917,11 @@ class TestDecodeWidthBuckets:
             dtype=jnp.float32)
         assert engine._decode_page_buckets == (2, 8)
         programs = engine.warmup()
-        assert programs == 2 * 2 + 2
+        # (the merge of first tokens into the device's: a batch bucket)
+        assert programs == 2 * 2 + 2 + 2
         assert engine.compile_tracker.counts == {"prefill": 4,
-                                                 "decode": 2}
+                                                 "decode": 2,
+                                                 "merge_tokens": 2}
         rng = np.random.RandomState(6)
         # short requests decode at width 2; the 8-token prompts cross
         # into the full-width program

@@ -334,25 +334,27 @@ def test_serving_trace_holds_spans_in_order_with_scheduler_counters(
     for i, p in enumerate(PROMPTS):
         engine.submit(Request(prompt=p, max_new_tokens=6, temperature=0.0,
                               seed=i, eos_id=None))
-    reported = []                   # what the scheduler says at each open
+    reported = []                   # what the scheduler says at each build
     real_span = engine._span
     ps = engine.paged_spec.page_size
     per_turn = block_pages(ps)      # pages a loop turn of the Pallas reader
 
     def spy(name, **args):
-        if name == "serve/decode":
+        if name == "serve/decode/build":
             # live tokens, and what the Pallas reader walks: each
             # decoding row's live pages, the null page once for a row
             # that is not decoding, a block of pages a loop turn
-            walked = [s.position // ps + 1 for s in sched.slots
-                      if s is not None and s.pending_tok is not None]
+            walked = [p // ps + 1 for p in sched.decode_state()[2]]
             walked += [1] * (engine._rows - len(walked))
             reported.append((sched.tokens_in_flight, sum(walked),
                              sum(-(-w // per_turn) for w in walked)))
         return real_span(name, **args)
 
     engine._span = spy
-    events = _traced(tmp_path, lambda: [engine.step() for _ in range(3)])
+    # a dispatch's span opens where its tokens are read, after the next
+    # dispatch has been issued: the third decode's by `debug_state()`
+    events = _traced(tmp_path, lambda: [engine.step() for _ in range(3)]
+                     + [engine.debug_state()])
     engine.close()
 
     decodes = [ev for ev in events if ev[0] == "serve/decode"]
@@ -360,13 +362,18 @@ def test_serving_trace_holds_spans_in_order_with_scheduler_counters(
     assert len(decodes) == 3 and len(prefills) >= 1
     assert engine._decode_attn_path == attn_kernel
     pallas = attn_kernel == "pallas"
+    # the host worked on another dispatch under every program but the
+    # last, whose read nothing was issued behind
+    assert [ev[3]["deferred"] for ev in decodes] == [1, 1, 0]
+    assert all(ev[3]["deferred"] == 1 for ev in prefills)
     for ev, (live, walked, turns) in zip(decodes, reported):
         # every argument has a reader (decode_stripe_live_share.sat,
-        # decode_read_live_share.sat, decode_block_fill_share.sat)
+        # decode_read_live_share.sat, decode_block_fill_share.sat,
+        # decode_deferred_share.sat)
         # (..., and the dispatch ledger's row: test_dispatch_ledger.py)
-        assert set(ev[3]) == {"seq", "step", "rows", "table_pages",
-                              "page_size", "live_tokens", "read_pages",
-                              "read_turns", "block_tokens"}
+        assert set(ev[3]) == {"seq", "step", "deferred", "rows",
+                              "table_pages", "page_size", "live_tokens",
+                              "read_pages", "read_turns", "block_tokens"}
         assert ev[3]["live_tokens"] == live
         # the gather reader walks no page list: it reads the table
         assert ev[3]["read_pages"] == (walked if pallas else 0)
@@ -377,14 +384,12 @@ def test_serving_trace_holds_spans_in_order_with_scheduler_counters(
         assert ev[3]["rows"] == engine._rows
         assert ev[3]["page_size"] == ps
         assert ev[3]["table_pages"] == engine.paged_spec.pages_per_seq
-        assert _children(events, ev) == [
-            "serve/decode/build", "serve/decode/dispatch",
-            "serve/decode/wait"]
+        assert _children(events, ev) == ["serve/decode/wait"]
     # every prompt token is real, the rest of the buckets is padding
     assert sum(ev[3]["real_tokens"] for ev in prefills) == \
         sum(len(p) for p in PROMPTS)
     for ev in prefills:
-        assert set(ev[3]) == {"seq", "step", "batch", "prompt",
+        assert set(ev[3]) == {"seq", "step", "deferred", "batch", "prompt",
                               "real_tokens", "own_key_tokens",
                               "page_write_tokens"}
         # no prompt here rides a shared prefix: every row starts at
@@ -399,23 +404,41 @@ def test_serving_trace_holds_spans_in_order_with_scheduler_counters(
         assert ev[3]["batch"] in INF["batch_buckets"]
         assert ev[3]["prompt"] in INF["prompt_buckets"]
         assert ev[3]["real_tokens"] <= ev[3]["batch"] * ev[3]["prompt"]
-        assert _children(events, ev) == [
-            "serve/prefill/build", "serve/prefill/dispatch",
-            "serve/prefill/wait"]
-    # one step, in order: admission, its prefills with their bookkeeping,
-    # the decode dispatch, tokens recorded, metrics
-    first = [ev[0] for ev in events if ev[1] < decodes[1][1]
-             and ev[0].count("/") == 1 and ev[0] != "serve/plan"]
+        assert _children(events, ev) == ["serve/prefill/wait"]
+    # a dispatch's build and call come before its span, and between
+    # them and it the NEXT dispatch's build and call: the spans' seq is
+    # the order issued
+    for kind, parents in (("decode", decodes), ("prefill", prefills)):
+        builds = [ev for ev in events if ev[0] == f"serve/{kind}/build"]
+        calls = [ev for ev in events if ev[0] == f"serve/{kind}/dispatch"]
+        assert len(builds) == len(calls) == len(parents)
+        assert all(b[2] <= c[1] and c[2] <= p[1]
+                   for b, c, p in zip(builds, calls, parents))
+    both = sorted(decodes + prefills, key=lambda ev: ev[1])
+    assert [ev[3]["seq"] for ev in both] == list(range(len(both)))
+    issued = sorted((ev for ev in events if ev[0] in (
+        "serve/decode/dispatch", "serve/prefill/dispatch")),
+        key=lambda ev: ev[1])
+    for this, nxt, span in zip(issued, issued[1:], both):
+        assert span[0] + "/dispatch" == this[0]
+        assert nxt[2] <= span[1]
+    # one step, in order: admission, the prefill issued, the decode
+    # issued, then the prefill's span with its bookkeeping (the decode's
+    # comes in the step after, once that step's prefill is issued)
+    top = [ev[0] for ev in events
+           if ev[0].count("/") == 1 and ev[0] != "serve/plan"]
     # (the walk over the slots that makes the decode dispatch's rows and
-    # its span's counters comes right before it, under its own name)
+    # its span's counters comes right before its build, under its own
+    # name)
     plans = [ev for ev in events if ev[0] == "serve/plan"]
+    builds = [ev for ev in events if ev[0] == "serve/decode/build"]
     assert len(plans) == 2 * len(decodes)
-    assert all(p[2] <= d[1] for p, d in zip(plans[1::2], decodes))
-    assert first[0] == "serve/admit"
-    assert first[1:4] == ["serve/prefill", "serve/record", "serve/metrics"]
-    i = first.index("serve/decode")
-    assert first[i:i + 4] == ["serve/decode", "serve/record",
-                              "serve/metrics", "serve/metrics"]
+    assert all(p[2] <= b[1] for p, b in zip(plans[1::2], builds))
+    assert top[:4] == ["serve/admit", "serve/prefill", "serve/record",
+                       "serve/metrics"]
+    for i, name in enumerate(top):
+        if name in ("serve/decode", "serve/prefill"):
+            assert top[i + 1:i + 3] == ["serve/record", "serve/metrics"]
 
 
 def test_training_trace_holds_dispatch_and_tail_spans(tmp_path):
