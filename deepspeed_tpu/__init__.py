@@ -7,7 +7,15 @@ named device mesh, fused transformer kernels in Pallas, bf16-first mixed
 precision, block-sparse attention, and a multi-host launcher.
 """
 
-from deepspeed_tpu.runtime.engine import DeepSpeedEngine, TrainState
+# setup/import: stamped here and closed at the bottom of this file; the
+# compile ledger's listeners are on from the first line below
+import time as _time
+_T_IMPORT = _time.perf_counter()
+from deepspeed_tpu.profiling.recompile import setup_span as _setup_span  # noqa: E402
+_IMPORT = _setup_span("setup/import", t0=_T_IMPORT)
+_IMPORT.__enter__()
+
+from deepspeed_tpu.runtime.engine import DeepSpeedEngine, TrainState  # noqa: E402
 from deepspeed_tpu.runtime.config import DeepSpeedConfig
 from deepspeed_tpu.runtime.pipe import (
     LayerSpec, PipelineModule, PipelineSpec, TiedLayerSpec)
@@ -158,3 +166,6 @@ def add_config_arguments(parser):
     group.add_argument("--deepscale_config", default=None, type=str,
                        help="Deprecated alias of --deepspeed_config")
     return parser
+
+
+_IMPORT.__exit__(None, None, None)

@@ -145,7 +145,7 @@ from deepspeed_tpu.ops.attention.flash import NEG_INF
 from deepspeed_tpu.ops.attention.paged import (block_pages, live_pages,
                                                paged_decode_supported)
 from deepspeed_tpu.parallel.mesh import axis_size, build_mesh
-from deepspeed_tpu.profiling.recompile import CompileTracker
+from deepspeed_tpu.profiling.recompile import CompileTracker, setup_span
 from deepspeed_tpu.profiling.spans import (ChromeTraceRecorder,
                                            _keep_dispatch_ledger, scope,
                                            trace_span)
@@ -383,6 +383,7 @@ class InferenceEngine:
     mesh. See the module docstring for the architecture;
     ``docs/inference.md`` for usage."""
 
+    @setup_span("setup/engine")
     def __init__(self, model_config, params, inference_config=None,
                  dtype=jnp.bfloat16, monitor: Optional[Any] = None,
                  mesh: Optional[Any] = None, observability_config=None,
@@ -469,37 +470,38 @@ class InferenceEngine:
         self.weights_resident = "int8" if qw == "int8" else (
             "bf16" if qw else "off")
         self._weight_block = int(cfg["quantize_block"])
-        if qw == "int8":
-            # no-op when from_checkpoint already shipped a quantized
-            # tree (quantize_param_tree passes quantized leaves through)
-            params = quantize_param_tree(params, self._weight_block)
-            if self.mesh is None:
-                # host round-trip: pin the quantized tree to the dense
-                # constructor's UNcommitted placement, so swap_params'
-                # requantize lands on identical program keys
-                params = jax.tree_util.tree_map(
-                    lambda x: jnp.asarray(np.asarray(x)), params)
+        with setup_span("setup/engine/params"):
+            if qw == "int8":
+                # no-op when from_checkpoint already shipped a quantized
+                # tree (quantize_param_tree passes quantized leaves through)
+                params = quantize_param_tree(params, self._weight_block)
+                if self.mesh is None:
+                    # host round-trip: pin the quantized tree to the dense
+                    # constructor's UNcommitted placement, so swap_params'
+                    # requantize lands on identical program keys
+                    params = jax.tree_util.tree_map(
+                        lambda x: jnp.asarray(np.asarray(x)), params)
 
-        self._param_shardings = None
-        self._cache_sharding = None
-        if self.mesh is not None:
-            tp = axis_size(self.mesh, "model")
-            kv_heads = getattr(model_config, "kv_heads", None) or \
-                model_config.num_heads
-            if model_config.num_heads % tp or kv_heads % tp:
-                raise ValueError(
-                    f"inference.mesh model axis ({tp}) must divide "
-                    f"num_heads ({model_config.num_heads}) and kv_heads "
-                    f"({kv_heads})")
-            self._param_shardings = _param_shardings(
-                self.mesh, self._param_specs_fn, model_config, params)
-            self._cache_sharding = NamedSharding(
-                self.mesh, _cache_pspec(cfg["paged_kv"]["enabled"]))
-            self.params = jax.tree_util.tree_map(
-                lambda x, s: jax.device_put(jnp.asarray(x), s),
-                params, self._param_shardings)
-        else:
-            self.params = jax.tree_util.tree_map(jnp.asarray, params)
+            self._param_shardings = None
+            self._cache_sharding = None
+            if self.mesh is not None:
+                tp = axis_size(self.mesh, "model")
+                kv_heads = getattr(model_config, "kv_heads", None) or \
+                    model_config.num_heads
+                if model_config.num_heads % tp or kv_heads % tp:
+                    raise ValueError(
+                        f"inference.mesh model axis ({tp}) must divide "
+                        f"num_heads ({model_config.num_heads}) and kv_heads "
+                        f"({kv_heads})")
+                self._param_shardings = _param_shardings(
+                    self.mesh, self._param_specs_fn, model_config, params)
+                self._cache_sharding = NamedSharding(
+                    self.mesh, _cache_pspec(cfg["paged_kv"]["enabled"]))
+                self.params = jax.tree_util.tree_map(
+                    lambda x, s: jax.device_put(jnp.asarray(x), s),
+                    params, self._param_shardings)
+            else:
+                self.params = jax.tree_util.tree_map(jnp.asarray, params)
 
         # -------------------- disaggregation + speculative decoding
         sd = cfg["spec_decode"]
@@ -628,93 +630,95 @@ class InferenceEngine:
         self.paged_spec_prefill = None
         self._cache_prefill = None
         self._page_bytes = 0
-        if self.paged:
-            ps = pk["page_size"]
-            # auto pool: the dense-equivalent worst case (+ null page) —
-            # same capacity, but shared/short requests no longer charge
-            # max_len each
-            num_pages = pk["num_pages"] or (
-                self.num_slots * pages_for(max_len, ps) + 1)
-            # pool payload dtype: the engine dtype unless paged_kv.
-            # kv_dtype overrides it ("int8" = quantized pool — the
-            # cache tree grows per-token-row fp32 scale pools and the
-            # decode kernel dequantizes tiles in VMEM)
-            kv_dtype = {"bf16": jnp.bfloat16, "int8": jnp.int8}.get(
-                pk["kv_dtype"], dtype)
-            self.paged_spec = paged_spec_for(
-                model_config, num_pages, ps, max_len, dtype=kv_dtype,
-                kv_quant_block=pk["kv_quant_block"])
-            self.cache_spec = None
-            self._cache = init_paged_kv_cache(self.paged_spec)
-            allocator = PageAllocator(num_pages, ps,
-                                      prefix_cache=pk["prefix_cache"])
-            cache_bytes = paged_kv_bytes(self.paged_spec)
-            self._page_bytes = cache_bytes // num_pages
-            if self.state_spec is not None:
-                # two more leaves of the one cache tree, one row a slot,
-                # each leaf by name for the family's forward
-                tree = (LatentStateCache if self.latent else PagedStateCache
-                        if self.state_spec.has_state else PagedTailCache)
-                self._cache = tree(
-                    *self._cache, *init_state_pool(self.state_spec))
-            # static pool cost per token of capacity — the
-            # Serve/kv_pool_bytes_per_token gauge (int8 pools land
-            # near half the bf16 figure; scales are the remainder)
-            self._kv_bpt = cache_bytes / float(num_pages * ps)
-            if self._separate_pools:
-                # the prefill workers' own pool: prompts only (decode
-                # lifetime is reserved from the main pool at handoff
-                # claim), sized for num_slots worst-case prompts unless
-                # pinned by disagg.prefill_pages. The prefix cache
-                # lives HERE — sharing is a prefill-side concern and
-                # ends at the handoff (the migrated copy is private)
-                # chunked prefill holds WHOLE long prompts on the
-                # prefill side until the final chunk hands off, so the
-                # pool (and the handoff slab width) is sized by max_len
-                # rather than the largest prompt bucket
-                max_prompt = max_len if self.chunked \
-                    else max(cfg["prompt_buckets"])
-                ppages = dg["prefill_pages"] or (
-                    self.num_slots * pages_for(max_prompt, ps) + 1)
-                self.paged_spec_prefill = paged_spec_for(
-                    model_config, ppages, ps, max_prompt, dtype=kv_dtype,
+        with setup_span("setup/engine/state"):
+            if self.paged:
+                ps = pk["page_size"]
+                # auto pool: the dense-equivalent worst case (+ null page) —
+                # same capacity, but shared/short requests no longer charge
+                # max_len each
+                num_pages = pk["num_pages"] or (
+                    self.num_slots * pages_for(max_len, ps) + 1)
+                # pool payload dtype: the engine dtype unless paged_kv.
+                # kv_dtype overrides it ("int8" = quantized pool — the
+                # cache tree grows per-token-row fp32 scale pools and the
+                # decode kernel dequantizes tiles in VMEM)
+                kv_dtype = {"bf16": jnp.bfloat16, "int8": jnp.int8}.get(
+                    pk["kv_dtype"], dtype)
+                self.paged_spec = paged_spec_for(
+                    model_config, num_pages, ps, max_len, dtype=kv_dtype,
                     kv_quant_block=pk["kv_quant_block"])
-                self._cache_prefill = init_paged_kv_cache(
-                    self.paged_spec_prefill)
-                admit_allocator = PageAllocator(
-                    ppages, ps, prefix_cache=pk["prefix_cache"])
-                cache_bytes += paged_kv_bytes(self.paged_spec_prefill)
-            self._resolve_decode_attn(pk)
-        else:
-            self.paged_spec = None
-            self.cache_spec = cache_spec_for(model_config, self._rows,
-                                             max_len, dtype=dtype)
-            self._cache = init_kv_cache(self.cache_spec)
-            cache_bytes = kv_cache_bytes(self.cache_spec)
-            self._kv_bpt = cache_bytes / float(self._rows * max_len)
-        # pages_per_seq of the pool the PREFILL program scatters into
-        self._prefill_pps = (self.paged_spec_prefill.pages_per_seq
-                             if self._separate_pools else
-                             self.paged_spec.pages_per_seq) \
-            if self.paged else 0
-        # the width of one handoff migration (pad-0 rows land in the
-        # null page): every live prompt page fits, shape stays static
-        self._handoff_width = (self.paged_spec_prefill.pages_per_seq
-                               if self._separate_pools else 0)
-        # cross-REPLICA live migration programs (ISSUE 16) — compiled
-        # on demand by warm_migration(), against the MAIN pool
-        self._mig_export = None
-        self._mig_import = None
-        self._mig_width = 0
-        if self._cache_sharding_decode is not None:
-            self._cache = tuple(
-                jax.device_put(c, self._cache_sharding_decode)
-                for c in self._cache)
-        if self._cache_prefill is not None and \
-                self._cache_sharding is not None:
-            self._cache_prefill = tuple(
-                jax.device_put(c, self._cache_sharding)
-                for c in self._cache_prefill)
+                self.cache_spec = None
+                self._cache = init_paged_kv_cache(self.paged_spec)
+                allocator = PageAllocator(num_pages, ps,
+                                          prefix_cache=pk["prefix_cache"])
+                cache_bytes = paged_kv_bytes(self.paged_spec)
+                self._page_bytes = cache_bytes // num_pages
+                if self.state_spec is not None:
+                    # two more leaves of the one cache tree, one row a slot,
+                    # each leaf by name for the family's forward
+                    tree = (LatentStateCache if self.latent
+                            else PagedStateCache
+                            if self.state_spec.has_state else PagedTailCache)
+                    self._cache = tree(
+                        *self._cache, *init_state_pool(self.state_spec))
+                # static pool cost per token of capacity — the
+                # Serve/kv_pool_bytes_per_token gauge (int8 pools land
+                # near half the bf16 figure; scales are the remainder)
+                self._kv_bpt = cache_bytes / float(num_pages * ps)
+                if self._separate_pools:
+                    # the prefill workers' own pool: prompts only (decode
+                    # lifetime is reserved from the main pool at handoff
+                    # claim), sized for num_slots worst-case prompts unless
+                    # pinned by disagg.prefill_pages. The prefix cache
+                    # lives HERE — sharing is a prefill-side concern and
+                    # ends at the handoff (the migrated copy is private)
+                    # chunked prefill holds WHOLE long prompts on the
+                    # prefill side until the final chunk hands off, so the
+                    # pool (and the handoff slab width) is sized by max_len
+                    # rather than the largest prompt bucket
+                    max_prompt = max_len if self.chunked \
+                        else max(cfg["prompt_buckets"])
+                    ppages = dg["prefill_pages"] or (
+                        self.num_slots * pages_for(max_prompt, ps) + 1)
+                    self.paged_spec_prefill = paged_spec_for(
+                        model_config, ppages, ps, max_prompt, dtype=kv_dtype,
+                        kv_quant_block=pk["kv_quant_block"])
+                    self._cache_prefill = init_paged_kv_cache(
+                        self.paged_spec_prefill)
+                    admit_allocator = PageAllocator(
+                        ppages, ps, prefix_cache=pk["prefix_cache"])
+                    cache_bytes += paged_kv_bytes(self.paged_spec_prefill)
+                self._resolve_decode_attn(pk)
+            else:
+                self.paged_spec = None
+                self.cache_spec = cache_spec_for(model_config, self._rows,
+                                                 max_len, dtype=dtype)
+                self._cache = init_kv_cache(self.cache_spec)
+                cache_bytes = kv_cache_bytes(self.cache_spec)
+                self._kv_bpt = cache_bytes / float(self._rows * max_len)
+            # pages_per_seq of the pool the PREFILL program scatters into
+            self._prefill_pps = (self.paged_spec_prefill.pages_per_seq
+                                 if self._separate_pools else
+                                 self.paged_spec.pages_per_seq) \
+                if self.paged else 0
+            # the width of one handoff migration (pad-0 rows land in the
+            # null page): every live prompt page fits, shape stays static
+            self._handoff_width = (self.paged_spec_prefill.pages_per_seq
+                                   if self._separate_pools else 0)
+            # cross-REPLICA live migration programs (ISSUE 16) — compiled
+            # on demand by warm_migration(), against the MAIN pool
+            self._mig_export = None
+            self._mig_import = None
+            self._mig_width = 0
+            if self._cache_sharding_decode is not None:
+                self._cache = tuple(
+                    jax.device_put(c, self._cache_sharding_decode)
+                    for c in self._cache)
+            if self._cache_prefill is not None and \
+                    self._cache_sharding is not None:
+                self._cache_prefill = tuple(
+                    jax.device_put(c, self._cache_sharding)
+                    for c in self._cache_prefill)
         self.scheduler = Scheduler(self.num_slots, cfg["prompt_buckets"],
                                    cfg["batch_buckets"], max_len,
                                    allocator=allocator,
@@ -752,100 +756,102 @@ class InferenceEngine:
                 f"{type(model_config).__name__} reports its routed "
                 f"experts' counters through the paged decode program: "
                 f"paged_kv.enabled must be true")
-        if self._prefill_by_length:
-            self._prefill = self._wrap_program(
-                self._prefill_state_impl, 9, "prefill")
-            self._decode = self._wrap_program(
-                self._decode_paged_impl, 7, "decode")
-            self._verify = None
-            if self.latent and self.state_spec is not None:
-                geom = (f"latent page pool: {self.paged_spec.num_pages} "
-                        f"pages x {self.paged_spec.page_size} tokens over "
-                        f"{self.paged_spec.num_layers} latent layers "
-                        f"({cache_bytes / 2**20:.1f} MiB), state pool "
-                        f"{self.state_spec.rows} rows over "
-                        f"{self.state_spec.num_layers} recurrent layers "
-                        f"({state_pool_bytes(self.state_spec) / 2**20:.1f}"
-                        f" MiB), chunked prefill "
-                        f"{self._chunk_tokens or 'off'}, decode attn "
-                        f"{self._decode_attn_path}")
-            elif self.latent:
-                geom = (f"latent page pool: {self.paged_spec.num_pages} "
-                        f"pages x {self.paged_spec.page_size} tokens over "
-                        f"{self.paged_spec.num_layers} layers, a row of "
-                        f"{self.paged_spec.row_lanes} lanes "
-                        f"({cache_bytes / 2**20:.1f} MiB), decode attn "
-                        f"{self._decode_attn_path} "
-                        f"({self._decode_attn_reason})")
-            else:
-                kept = ("state pool" if self.state_spec.has_state
-                        else "convolution tails")
-                geom = (f"paged KV cache: {self.paged_spec.num_pages} "
-                        f"pages x {self.paged_spec.page_size} tokens over "
-                        f"{self.paged_spec.num_layers} softmax layers "
-                        f"({cache_bytes / 2**20:.1f} MiB), {kept} "
-                        f"{self.state_spec.rows} rows over "
-                        f"{self.state_spec.num_layers} per-slot layers "
-                        f"({state_pool_bytes(self.state_spec) / 2**20:.1f}"
-                        f" MiB), decode attn {self._decode_attn_path}")
-        elif self.paged:
-            self._prefill = self._wrap_program(
-                self._prefill_paged_impl, 8, "prefill")
-            self._decode = self._wrap_program(
-                self._decode_paged_impl, 7, "decode",
-                mesh=self._mesh_decode,
-                param_shardings=self._param_shardings_decode,
-                cache_sharding=self._cache_sharding_decode)
-            self._verify = None
-            if self.spec:
-                self._verify = self._wrap_program(
-                    self._verify_paged_impl, 7, "verify",
+        with setup_span("setup/engine/programs"):
+            if self._prefill_by_length:
+                self._prefill = self._wrap_program(
+                    self._prefill_state_impl, 9, "prefill")
+                self._decode = self._wrap_program(
+                    self._decode_paged_impl, 7, "decode")
+                self._verify = None
+                if self.latent and self.state_spec is not None:
+                    geom = (f"latent page pool: {self.paged_spec.num_pages} "
+                            f"pages x {self.paged_spec.page_size} tokens over "
+                            f"{self.paged_spec.num_layers} latent layers "
+                            f"({cache_bytes / 2**20:.1f} MiB), state pool "
+                            f"{self.state_spec.rows} rows over "
+                            f"{self.state_spec.num_layers} recurrent layers "
+                            f"({state_pool_bytes(self.state_spec) / 2**20:.1f}"
+                            f" MiB), chunked prefill "
+                            f"{self._chunk_tokens or 'off'}, decode attn "
+                            f"{self._decode_attn_path}")
+                elif self.latent:
+                    geom = (f"latent page pool: {self.paged_spec.num_pages} "
+                            f"pages x {self.paged_spec.page_size} tokens over "
+                            f"{self.paged_spec.num_layers} layers, a row of "
+                            f"{self.paged_spec.row_lanes} lanes "
+                            f"({cache_bytes / 2**20:.1f} MiB), decode attn "
+                            f"{self._decode_attn_path} "
+                            f"({self._decode_attn_reason})")
+                else:
+                    kept = ("state pool" if self.state_spec.has_state
+                            else "convolution tails")
+                    geom = (f"paged KV cache: {self.paged_spec.num_pages} "
+                            f"pages x {self.paged_spec.page_size} tokens over "
+                            f"{self.paged_spec.num_layers} softmax layers "
+                            f"({cache_bytes / 2**20:.1f} MiB), {kept} "
+                            f"{self.state_spec.rows} rows over "
+                            f"{self.state_spec.num_layers} per-slot layers "
+                            f"({state_pool_bytes(self.state_spec) / 2**20:.1f}"
+                            f" MiB), decode attn {self._decode_attn_path}")
+            elif self.paged:
+                self._prefill = self._wrap_program(
+                    self._prefill_paged_impl, 8, "prefill")
+                self._decode = self._wrap_program(
+                    self._decode_paged_impl, 7, "decode",
                     mesh=self._mesh_decode,
                     param_shardings=self._param_shardings_decode,
                     cache_sharding=self._cache_sharding_decode)
-            if self._separate_pools:
-                self._wrap_handoff_programs()
-            if self.chunked:
-                self._resolve_context_parallel()
-            geom = (f"paged KV cache: {self.paged_spec.num_pages} pages "
-                    f"x {self.paged_spec.page_size} tokens "
-                    f"({cache_bytes / 2**20:.1f} MiB, "
-                    f"{jnp.dtype(self.paged_spec.dtype).name}"
-                    f"{' + fp32 scales' if self.paged_spec.quantized else ''}"
-                    f"), prefix cache "
-                    f"{'on' if pk['prefix_cache'] else 'off'}, "
-                    f"decode attn {self._decode_attn_path}")
-            # the which-decode-attention-compiled line (PR 6's
-            # which-exchange pattern): a silent fallback to the
-            # stripe-gather path must be visible in logs + run reports
-            logger.info(
-                f"inference decode attention: {self._decode_attn_path} "
-                f"({self._decode_attn_reason}; page walk widths "
-                f"{list(self._decode_page_buckets)})")
-            if self._log is not None:
-                self._log.add_event(
-                    "decode_attn_path", path=self._decode_attn_path,
-                    reason=self._decode_attn_reason,
-                    requested=pk["attn_kernel"],
-                    decode_page_buckets=list(self._decode_page_buckets))
-        else:
-            self._prefill = self._wrap_program(
-                self._prefill_impl, 7, "prefill")
-            self._decode = self._wrap_program(
-                self._decode_impl, 6, "decode")
-            geom = (f"dense KV cache "
-                    f"{cache_bytes / 2**20:.1f} MiB")
-        # the tokens the device holds pending, one a row: what the
-        # decode program reads. A decode's result IS the next one's
-        # input, and a prefill's (or a final chunk's) first tokens are
-        # merged in at their slots by one tiny program, so no token goes
-        # to the host and back between two dispatches
-        self._all_rows = np.arange(self._rows, dtype=np.int32)
-        self._merge = self._wrap_merge()
-        self._last_tokens = jnp.zeros((self._rows,), jnp.int32)
-        if self._mesh_decode is not None:
-            self._last_tokens = jax.device_put(
-                self._last_tokens, NamedSharding(self._mesh_decode, P()))
+                self._verify = None
+                if self.spec:
+                    self._verify = self._wrap_program(
+                        self._verify_paged_impl, 7, "verify",
+                        mesh=self._mesh_decode,
+                        param_shardings=self._param_shardings_decode,
+                        cache_sharding=self._cache_sharding_decode)
+                if self._separate_pools:
+                    self._wrap_handoff_programs()
+                if self.chunked:
+                    self._resolve_context_parallel()
+                geom = (f"paged KV cache: {self.paged_spec.num_pages} pages "
+                        f"x {self.paged_spec.page_size} tokens "
+                        f"({cache_bytes / 2**20:.1f} MiB, "
+                        f"{jnp.dtype(self.paged_spec.dtype).name}"
+                        + (" + fp32 scales" if self.paged_spec.quantized
+                           else "")
+                        + f"), prefix cache "
+                        f"{'on' if pk['prefix_cache'] else 'off'}, "
+                        f"decode attn {self._decode_attn_path}")
+                # the which-decode-attention-compiled line (PR 6's
+                # which-exchange pattern): a silent fallback to the
+                # stripe-gather path must be visible in logs + run reports
+                logger.info(
+                    f"inference decode attention: {self._decode_attn_path} "
+                    f"({self._decode_attn_reason}; page walk widths "
+                    f"{list(self._decode_page_buckets)})")
+                if self._log is not None:
+                    self._log.add_event(
+                        "decode_attn_path", path=self._decode_attn_path,
+                        reason=self._decode_attn_reason,
+                        requested=pk["attn_kernel"],
+                        decode_page_buckets=list(self._decode_page_buckets))
+            else:
+                self._prefill = self._wrap_program(
+                    self._prefill_impl, 7, "prefill")
+                self._decode = self._wrap_program(
+                    self._decode_impl, 6, "decode")
+                geom = (f"dense KV cache "
+                        f"{cache_bytes / 2**20:.1f} MiB")
+            # the tokens the device holds pending, one a row: what the
+            # decode program reads. A decode's result IS the next one's
+            # input, and a prefill's (or a final chunk's) first tokens are
+            # merged in at their slots by one tiny program, so no token goes
+            # to the host and back between two dispatches
+            self._all_rows = np.arange(self._rows, dtype=np.int32)
+            self._merge = self._wrap_merge()
+            self._last_tokens = jnp.zeros((self._rows,), jnp.int32)
+            if self._mesh_decode is not None:
+                self._last_tokens = jax.device_put(
+                    self._last_tokens, NamedSharding(self._mesh_decode, P()))
         mesh_note = (f", mesh {dict(self.mesh.shape)}"
                      if self.mesh is not None else "")
         if self.spec:
@@ -2608,6 +2614,15 @@ class InferenceEngine:
         return [finished[u].prompt + finished[u].tokens for u in uids]
 
     # ----------------------------------------------------------- warmup
+    @staticmethod
+    def _warming(*cls):
+        """``setup/program`` around one warmed call: the program it
+        builds is a row of the compile ledger with the dispatch ledger's
+        class ``cls`` (the merge program's shapes, which are no
+        dispatch's, go by ``("merge_tokens", rows)``)."""
+        return setup_span("setup/program", cls=cls)
+
+    @setup_span("setup/warmup")
     def warmup(self):
         """Compile the steady-state program set: one prefill per
         (batch bucket, prompt bucket) pair + one decode program per
@@ -2619,6 +2634,7 @@ class InferenceEngine:
         this, :attr:`steady_state_recompiles` staying 0 is the serving
         latency contract."""
         assert self.scheduler.idle(), "warmup with requests in flight"
+        warming = self._warming
         # every program is warmed with the KIND of argument each
         # dispatch passes: host arrays, and for the decode program's
         # tokens the device array a program returned (another kind
@@ -2635,97 +2651,108 @@ class InferenceEngine:
             if self.paged:
                 positions = np.zeros((bb,), np.int32)
                 ztab = np.zeros((bb, self._prefill_pps), np.int32)
-            if self._prefill_by_length:
-                first, self._cache = self._prefill(
-                    self.params, self._cache, ids, lengths, positions,
-                    ztab, keys, temps, slots)
-            elif not self.paged:
-                first, self._cache = self._prefill(
-                    self.params, self._cache, ids, lengths, slots, keys,
-                    temps)
-            elif self._separate_pools:
-                first, self._cache_prefill = self._prefill(
-                    self.params, self._cache_prefill, ids, lengths,
-                    positions, ztab, keys, temps)
-            else:
-                first, self._cache = self._prefill(
-                    self.params, self._cache, ids, lengths, positions,
-                    ztab, keys, temps)
+            with warming("prefill", bb, sb):
+                if self._prefill_by_length:
+                    first, self._cache = self._prefill(
+                        self.params, self._cache, ids, lengths, positions,
+                        ztab, keys, temps, slots)
+                elif not self.paged:
+                    first, self._cache = self._prefill(
+                        self.params, self._cache, ids, lengths, slots,
+                        keys, temps)
+                elif self._separate_pools:
+                    first, self._cache_prefill = self._prefill(
+                        self.params, self._cache_prefill, ids, lengths,
+                        positions, ztab, keys, temps)
+                else:
+                    first, self._cache = self._prefill(
+                        self.params, self._cache, ids, lengths, positions,
+                        ztab, keys, temps)
             if not self.disagg:
-                self._hold(first, slots)
+                with warming("merge_tokens", bb):
+                    self._hold(first, slots)
         if self.paged and self.chunked:
             # one chunk shape per batch bucket (single chunk bucket x
             # batch buckets — the ladder collapse), plus the CP chunk
             # program when context parallelism resolved on
-            progs = [self._prefill] + (
-                [self._chunk_cp] if self._chunk_cp is not None else [])
+            progs = [(self._prefill, 1)] + (
+                [(self._chunk_cp, self._cp_shards)]
+                if self._chunk_cp is not None else [])
             plan = chunk_warmup_plan(self.config["batch_buckets"],
                                      self._chunk_tokens)
-            for prog in progs:
+            for prog, shards in progs:
                 for bb, ct in plan:
                     cache = self._cache_prefill if self._separate_pools \
                         else self._cache
                     slots = np.full((bb,), self._scratch, np.int32)
                     more = (slots,) if self._prefill_by_length else ()
-                    first, cache = prog(
-                        self.params, cache, np.zeros((bb, ct), np.int32),
-                        np.ones((bb,), np.int32),
-                        np.zeros((bb,), np.int32),
-                        np.zeros((bb, self._prefill_pps), np.int32),
-                        np.zeros((bb, 2), np.uint32),
-                        np.zeros((bb,), np.float32), *more)
+                    with warming("chunk", bb, ct, shards):
+                        first, cache = prog(
+                            self.params, cache,
+                            np.zeros((bb, ct), np.int32),
+                            np.ones((bb,), np.int32),
+                            np.zeros((bb,), np.int32),
+                            np.zeros((bb, self._prefill_pps), np.int32),
+                            np.zeros((bb, 2), np.uint32),
+                            np.zeros((bb,), np.float32), *more)
                     if self._separate_pools:
                         self._cache_prefill = cache
                     else:
                         self._cache = cache
                     if not self.disagg:
-                        self._hold(first, slots)
+                        with warming("merge_tokens", bb):
+                            self._hold(first, slots)
         if self.spec or self.disagg:
             # tokens the host chose: a verify run's, a claimed handoff's
-            self._hold_values({self._scratch: 0})
+            with warming("merge_tokens", self._rows):
+                self._hold_values({self._scratch: 0})
         if self.paged:
             # the first width once more at the end: it reads a decode's
             # own result, as every decode of the loop does
             for w in self._decode_page_buckets + \
                     self._decode_page_buckets[:1]:
-                nxt, self._cache = self._decode(
-                    self.params_decode, self._cache, self._last_tokens,
-                    np.zeros((self._rows,), np.int32),
-                    np.zeros((self._rows, w), np.int32),
-                    np.zeros((self._rows, 2), np.uint32),
-                    np.zeros((self._rows,), np.float32))
-                self._hold_decoded(nxt)
+                with warming("decode", w):
+                    nxt, self._cache = self._decode(
+                        self.params_decode, self._cache, self._last_tokens,
+                        np.zeros((self._rows,), np.int32),
+                        np.zeros((self._rows, w), np.int32),
+                        np.zeros((self._rows, 2), np.uint32),
+                        np.zeros((self._rows,), np.float32))
+                    self._hold_decoded(nxt)
             if self.spec:
                 # one verify program per width — tables always ride at
                 # full pps, so widths x 1 (not widths x page buckets)
                 for v in self._verify_widths:
-                    nxt, self._cache = self._verify(
-                        self.params_decode, self._cache,
-                        np.zeros((self._rows, v), np.int32),
-                        np.zeros((self._rows,), np.int32),
-                        np.zeros(
-                            (self._rows, self.paged_spec.pages_per_seq),
-                            np.int32),
-                        np.zeros((self._rows, 2), np.uint32),
-                        np.zeros((self._rows,), np.float32))
+                    with warming("verify", v):
+                        nxt, self._cache = self._verify(
+                            self.params_decode, self._cache,
+                            np.zeros((self._rows, v), np.int32),
+                            np.zeros((self._rows,), np.int32),
+                            np.zeros(
+                                (self._rows, self.paged_spec.pages_per_seq),
+                                np.int32),
+                            np.zeros((self._rows, 2), np.uint32),
+                            np.zeros((self._rows,), np.float32))
             if self._separate_pools:
                 # warm both handoff programs against the null page so
                 # the first real claim doesn't compile on the clock
-                idx = np.zeros((self._handoff_width,), np.int32)
-                slab = self._export(self._cache_prefill, idx)
-                if self._slab_sharding_decode is not None:
-                    slab = tuple(
-                        jax.device_put(s, self._slab_sharding_decode)
-                        for s in slab)
-                self._cache = self._import(self._cache, slab, idx)
+                with warming("handoff"):
+                    idx = np.zeros((self._handoff_width,), np.int32)
+                    slab = self._export(self._cache_prefill, idx)
+                    if self._slab_sharding_decode is not None:
+                        slab = tuple(
+                            jax.device_put(s, self._slab_sharding_decode)
+                            for s in slab)
+                    self._cache = self._import(self._cache, slab, idx)
         else:
             for _ in range(2):
-                nxt, self._cache = self._decode(
-                    self.params_decode, self._cache, self._last_tokens,
-                    np.zeros((self._rows,), np.int32),
-                    np.zeros((self._rows, 2), np.uint32),
-                    np.zeros((self._rows,), np.float32))
-                self._hold_decoded(nxt)
+                with warming("decode"):
+                    nxt, self._cache = self._decode(
+                        self.params_decode, self._cache, self._last_tokens,
+                        np.zeros((self._rows,), np.int32),
+                        np.zeros((self._rows, 2), np.uint32),
+                        np.zeros((self._rows,), np.float32))
+                    self._hold_decoded(nxt)
         jax.block_until_ready(nxt)
         self._warm_compiles = self.compile_tracker.total_compiles
         if self._log is not None:

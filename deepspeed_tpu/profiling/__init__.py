@@ -6,9 +6,12 @@ through the engine:
 - **FLOPs/MFU profiler** (:mod:`.flops`): cost-analysis of the compiled
   micro-step → model FLOPs, bytes accessed, per-step MFU against a
   peak-FLOPs device registry.
-- **Recompile tracking** (:mod:`.recompile`): every compiled entry
-  point is wrapped; compile counts/wall-times are recorded and
-  steady-state recompiles (the silent TPU perf killer) warn loudly.
+- **The compile ledger** (:mod:`.recompile`): one row a program the
+  process builds or loads (JAX's trace, lowering and backend seconds,
+  cache hit or miss, the ``setup/*`` span it was built in); every
+  compiled entry point is wrapped, compile counts/wall-times are
+  recorded and steady-state recompiles (the silent TPU perf killer)
+  warn loudly, with the arguments that changed.
 - **HBM watermarks** (:mod:`.memory`): structured
   ``device.memory_stats()`` samples at step boundaries, with per-phase
   deltas and a run peak (host-RSS fallback on backends without

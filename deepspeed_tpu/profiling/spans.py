@@ -43,8 +43,8 @@ except Exception:        # an odd jax profiler degrades to timing-only
         return nullcontext()
 
 __all__ = ["ChromeTraceRecorder", "trace_span", "set_default_recorder",
-           "get_default_recorder", "last_dispatch_ledger", "scope",
-           "DEVICE_SCOPES", "HOST_SPANS"]
+           "get_default_recorder", "last_dispatch_ledger",
+           "compile_ledger", "scope", "DEVICE_SCOPES", "HOST_SPANS"]
 
 # scopes inside the compiled programs (jax.named_scope)
 DEVICE_SCOPES = (
@@ -99,6 +99,15 @@ HOST_SPANS = (
     "serve/decode", "serve/decode/build", "serve/decode/dispatch",
     "serve/decode/wait",
     "serve/record", "serve/metrics",
+    # set-up (profiling/recompile.py ``setup_span``: each also a row of
+    # the compile ledger): the package's import; an engine's
+    # construction with its parameters' placement and cast, its cache
+    # tree's or optimizer state's allocation and the building of its
+    # jitted functions; warm-up; one warmed call (or the first
+    # train_batch) with the program it builds
+    "setup/import", "setup/engine", "setup/engine/params",
+    "setup/engine/state", "setup/engine/programs",
+    "setup/warmup", "setup/program",
 )
 
 
@@ -227,6 +236,23 @@ def last_dispatch_ledger():
     """The dispatch ledger of the serving engine last built or closed
     in this process, or None."""
     return _last_ledger
+
+
+# the compile ledger (profiling/recompile.py ``CompileLedger``): one for
+# the process, handed in as that module is imported
+_compile_ledger = None
+
+
+def _keep_compile_ledger(ledger) -> None:
+    global _compile_ledger
+    _compile_ledger = ledger
+
+
+def compile_ledger():
+    """The process's compile ledger: a row a program built or loaded, a
+    row a ``setup/*`` span (docs/observability.md "The compile
+    ledger")."""
+    return _compile_ledger
 
 
 @contextmanager

@@ -221,7 +221,7 @@ class PipelineEngine(DeepSpeedEngine):
         _t0 = _time.perf_counter()
         if self._window_anchor is None:
             self._window_anchor = _t0   # see base train_batch
-        with self.observability.span("pipe/train_batch"):
+        with self._batch_span("pipe/train_batch"):
             self.state, loss = step_fn(self.state, batch)
         self.tput_timer.stop()
         self._last_step_time_ms = (_time.perf_counter() - _t0) * 1e3

@@ -262,17 +262,291 @@ def test_compile_tracker_counts_and_warns(monkeypatch):
     assert s["total_compiles"] == 2 and s["per_fn"]["f"]["count"] == 2
 
 
-def test_compile_tracker_signature_fallback():
-    """Without _cache_size (non-jit callables, exotic jax builds) the
-    shape/dtype-signature detector still counts compiles exactly."""
+# ------------------------------------------------------ compile ledger
+def _ledger():
+    from deepspeed_tpu.profiling.spans import compile_ledger
+    return compile_ledger()
+
+
+def _rows_since(seq):
+    return [r for r in _ledger().table()["programs"] if r["seq"] >= seq]
+
+
+def test_a_plain_jit_outside_any_tracker_has_its_row_by_fun_name():
+    """The ledger is process-wide: a ``jax.jit`` nobody wrapped makes a
+    row by JAX's ``fun_name``, with the three durations, no wrap's name
+    and no step, phase ``steady`` where no ``setup/*`` span is open."""
+    ledger, x = _ledger(), jnp.ones((3,))
+    seq = ledger.total
+
+    def an_unwrapped_function(x):
+        return jnp.where(x > 0, x, 0) * 2         # jitted jnp inside
+
+    jax.jit(an_unwrapped_function)(x)
+    rows = [r for r in _rows_since(seq)
+            if r["fun_name"] == "jit(an_unwrapped_function)"]
+    assert len(rows) == 1
+    row = rows[0]
+    assert row["name"] is None and row["step"] is None
+    assert row["phase"] == "steady" and row["cls"] is None
+    assert row["trace_s"] > 0 and row["lower_s"] > 0 and row["backend_s"] > 0
+    assert row["call_s"] is None and row["changed"] is None
+    # one interval on perf_counter that holds JAX's three spans
+    assert row["t_end"] - row["t_begin"] >= \
+        row["trace_s"] + row["lower_s"] + row["backend_s"] - 1e-6
+    # the inner jits' traces were the outer's time, and made no row
+    assert not [r for r in _rows_since(seq) if "where" in r["fun_name"]]
+
+
+def test_a_rebuild_with_another_shape_is_a_steady_row_that_says_what_changed():
+    import deepspeed_tpu.profiling.recompile as rc
+    step = [0]
+    tracker = rc.CompileTracker(step_provider=lambda: step[0], warn_after=1)
+    f = tracker.wrap(jax.jit(lambda p, x: p["w"] * x), "scaled")
+    seq = _ledger().total
+    with rc.setup_span("setup/warmup"):
+        with rc.setup_span("setup/program", cls=("prefill", 1, 4)):
+            f({"w": jnp.ones((4,))}, np.ones((4,), np.float32))
+    f({"w": jnp.ones((4,))}, np.ones((4,), np.float32))     # no build
+    step[0] = 7
+    f({"w": jnp.ones((4,))}, np.ones((2, 4), np.float32))   # steady build
+    warm, steady = [r for r in _rows_since(seq) if r["name"] == "scaled"]
+    assert warm["phase"] == "setup/program" and warm["changed"] is None
+    assert warm["cls"] == ("prefill", 1, 4) and warm["step"] == 0
+    assert steady["phase"] == "steady" and steady["step"] == 7
+    assert steady["cls"] is None
+    # the argument that changed, and only it
+    assert steady["changed"] == [("args[1]", "float32[4]", "float32[2,4]")]
+    # the tracked rows span the calls that built them
+    assert steady["call_s"] == steady["t_end"] - steady["t_begin"] > 0
+    assert steady["call_s"] >= (steady["trace_s"] + steady["lower_s"]
+                                + steady["backend_s"])
+    # and the tracker's own record is the same two builds
+    assert tracker.counts == {"scaled": 2}
+    assert [e.step for e in tracker.events] == [0, 7]
+    assert tracker.events[1].wall_ms == pytest.approx(
+        steady["call_s"] * 1e3)
+    assert tracker.dispatch_counts == {"scaled": 3}
+
+
+@pytest.fixture
+def own_cache(tmp_path):
+    """A persistent cache of this test's own that keeps every program,
+    jax's once-initialized cache object dropped; restored after."""
+    from jax.experimental.compilation_cache import compilation_cache
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes",
+            "jax_enable_compilation_cache")
+    prev = {k: getattr(jax.config, k) for k in keys}
+    jax.config.update(keys[0], str(tmp_path))
+    jax.config.update(keys[1], 0.0)
+    jax.config.update(keys[2], -1)
+    compilation_cache.reset_cache()
+    yield compilation_cache
+    for k, v in prev.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+
+
+def test_the_cache_column_reads_miss_then_hit_and_not_asked(own_cache):
+    """The CPU backend of the pinned jax serves a persistent cache, so
+    the events are JAX's own: a program built twice across
+    ``jax.clear_caches()`` misses (and is written), then hits with what
+    the load took and saved; with the cache off it is not asked (with
+    no DIRECTORY set JAX still asks, and misses)."""
+    def built(tag):
+        def cached_or_not(x):
+            return jnp.sin(x) * 41.25 + len(tag)
+        seq = _ledger().total
+        jax.jit(cached_or_not)(jnp.ones((5, 5)))
+        return [r for r in _rows_since(seq)
+                if r["fun_name"] == "jit(cached_or_not)"][-1]
+
+    miss = built("aa")
+    assert miss["cache"] == "miss" and miss["written"]
+    assert miss["retrieval_s"] is None and miss["saved_s"] is None
+    jax.clear_caches()
+    hit = built("aa")
+    assert hit["cache"] == "hit" and not hit["written"]
+    assert hit["retrieval_s"] >= 0 and hit["saved_s"] is not None
+    # a program under the threshold is asked for and never kept: it
+    # misses in every process (utils/platform's min_compile_secs 1.0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 60.0)
+    short = built("bbb")
+    assert short["cache"] == "miss" and not short["written"]
+    jax.config.update("jax_enable_compilation_cache", False)
+    own_cache.reset_cache()
+    off = built("cccc")
+    assert off["cache"] == "not_asked" and not off["written"]
+
+
+def test_a_trace_that_was_never_compiled_is_not_the_next_rows_time():
+    """``lower()`` and ``eval_shape`` trace (and lower) without building:
+    they leave no row, and the next program's row does not begin in
+    them."""
+    import time
+    ledger, x, other = _ledger(), jnp.ones((6,)), jnp.ones((7,))
+    seq = ledger.total
+    f = jax.jit(lambda x: jnp.cos(x) * 3)
+    f.lower(x)
+    jax.eval_shape(f, other)
+    assert ledger.total == seq
+    mark = time.perf_counter()
+    jax.jit(lambda x: jnp.cos(x) * 5)(x)
+    (row,) = _rows_since(seq)
+    assert row["t_begin"] >= mark
+
+
+def test_the_ledger_is_a_ring_and_hand_fired_events_make_rows():
+    """A ledger of its own, fed JAX's events by hand: a backend event a
+    row, the overwritten ones counted, and a backend event INSIDE a
+    trace (a program built while another is traced) a row with the
+    backend's time alone."""
+    import deepspeed_tpu.profiling.recompile as rc
+    ledger = rc.CompileLedger(cap=2)
+    for name in ("a", "b"):
+        ledger._span_opens(rc.TRACE, 0.0, fun_name=name)
+        ledger._span_closes(rc.TRACE, 0.25, fun_name=name)
+        ledger._span_opens(rc.LOWER, 0.0, fun_name=name)
+        ledger._span_closes(rc.LOWER, 0.5, fun_name=name)
+        ledger._span_opens(rc.BACKEND, 0.0, fun_name=name)
+        ledger._cache_says("/jax/compilation_cache/"
+                           "compile_requests_use_cache")
+        ledger._span_closes(rc.BACKEND, 1.0, fun_name=name)
+    ledger._span_opens(rc.TRACE, 0.0, fun_name="outer")
+    ledger._span_opens(rc.BACKEND, 0.0, fun_name="inner")
+    ledger._span_closes(rc.BACKEND, 0.125, fun_name="inner")
+    ledger._span_closes(rc.TRACE, 2.0, fun_name="outer")
+    assert ledger.total == 3 and ledger.dropped == 1
+    b, inner = ledger.table()["programs"]
+    assert (b["fun_name"], b["seq"], b["cache"]) == ("b", 1, "miss")
+    assert (b["trace_s"], b["lower_s"], b["backend_s"]) == (0.25, 0.5, 1.0)
+    assert (inner["fun_name"], inner["seq"]) == ("inner", 2)
+    assert (inner["trace_s"], inner["lower_s"], inner["backend_s"],
+            inner["cache"]) == (0.0, 0.0, 0.125, "not_asked")
+
+
+def test_a_program_that_begins_at_its_lowering_takes_no_older_trace():
+    """pjit keeps a jaxpr: a program lowered again (other shardings,
+    committed devices) begins at LOWER. A ``lower()`` before it, traced
+    and lowered and never compiled, is not its trace nor its begin."""
+    import time
+    import deepspeed_tpu.profiling.recompile as rc
+    ledger = rc.CompileLedger()
+    ledger._span_opens(rc.TRACE, 0.0, fun_name="lowered_by_hand")
+    ledger._span_closes(rc.TRACE, 0.25, fun_name="lowered_by_hand")
+    ledger._span_opens(rc.LOWER, 0.0, fun_name="lowered_by_hand")
+    ledger._span_closes(rc.LOWER, 0.5, fun_name="lowered_by_hand")
+    mark = time.perf_counter()
+    ledger._span_opens(rc.LOWER, 0.0, fun_name="again")
+    ledger._span_closes(rc.LOWER, 0.0, fun_name="again")
+    ledger._span_opens(rc.BACKEND, 0.0, fun_name="again")
+    ledger._span_closes(rc.BACKEND, 0.0, fun_name="again")
+    (row,) = ledger.table()["programs"]
+    assert (row["fun_name"], row["trace_s"], row["lower_s"]) == (
+        "again", 0.0, 0.0)
+    assert row["t_begin"] >= mark
+    # a trace and THEN this program's own lowering is one program still
+    ledger._span_opens(rc.TRACE, 0.0, fun_name="whole")
+    ledger._span_closes(rc.TRACE, 0.25, fun_name="whole")
+    ledger._span_opens(rc.LOWER, 0.0, fun_name="whole")
+    ledger._span_closes(rc.LOWER, 0.5, fun_name="whole")
+    ledger._span_opens(rc.BACKEND, 0.0, fun_name="whole")
+    ledger._span_closes(rc.BACKEND, 1.0, fun_name="whole")
+    whole = ledger.table()["programs"][-1]
+    assert (whole["trace_s"], whole["lower_s"], whole["backend_s"]) == (
+        0.25, 0.5, 1.0)
+
+
+def test_a_tracked_call_claims_its_own_rows_and_a_donated_argument_is_read():
+    """The join needs no frame of the call: the rows a thread made since
+    a tracked call began are the call's. An unwrapped build before it
+    stays nobody's, and an argument the program DONATED (gone when the
+    call returns) still says its shape and dtype."""
+    import deepspeed_tpu.profiling.recompile as rc
+    step = [3]
+    tracker = rc.CompileTracker(step_provider=lambda: step[0])
+    f = tracker.wrap(jax.jit(lambda s, x: s + x.sum(), donate_argnums=0),
+                     "accumulate")
+    x, small, gone = (jnp.asarray(np.ones(shape, np.float32))
+                      for shape in ((2,), (4,), (4, 4)))
+    seq = _ledger().total
+    jax.jit(lambda x: x - 11)(x)                         # nobody's
+    f(small, x)
+    step[0] = 9
+    f(gone, x)
+    gone.delete()       # as the chip's donation leaves it, CPU or not
+    assert rc._arg_signature((gone,), {}) == {"args[0]": "float32[4,4]"}
+    nobody, first, again = _rows_since(seq)
+    assert nobody["name"] is None and nobody["call_s"] is None
+    assert (first["name"], first["step"], first["changed"]) == (
+        "accumulate", 3, None)
+    assert (again["name"], again["step"]) == ("accumulate", 9)
+    assert again["changed"] == [("args[0]", "float32[4]", "float32[4,4]")]
+
+
+def test_a_ledger_that_fails_loses_its_row_and_not_the_build(monkeypatch):
+    """The listeners run inside JAX's compile, and a tracked call claims
+    its rows inside an engine's step: what goes wrong in either is a
+    warning (once) and a lost row, never a failed build or step."""
+    import deepspeed_tpu.profiling.recompile as rc
+    warnings = []
+    monkeypatch.setattr(rc.logger, "warning",
+                        lambda msg, *a, **k: warnings.append(str(msg)))
+    monkeypatch.setattr(rc._LEDGER, "_warned", False)
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("an argument no tree can hold")
+    monkeypatch.setattr(rc, "_arg_signature", broken)    # the claim
+    tracker = rc.CompileTracker()
+    f = tracker.wrap(jax.jit(lambda x: x * 7), "sevenfold")
+    seq = _ledger().total
+    assert float(f(jnp.ones((3,)))[0]) == 7.0
+    assert float(f(jnp.ones((2, 3)))[0, 0]) == 7.0
+    assert tracker.counts == {"sevenfold": 2}
+    assert not [r for r in _rows_since(seq) if r["name"] == "sevenfold"]
+    monkeypatch.setattr(rc._LEDGER, "_built", broken)    # the listener
+    seq = _ledger().total
+    assert float(jax.jit(lambda x: x * 9)(jnp.ones((3,)))[0]) == 9.0
+    assert _ledger().total == seq
+    assert [w for w in warnings if "a row was lost" in w] == [
+        "compile ledger: a row was lost"]
+
+
+def test_a_tracked_function_does_not_pin_the_engine_that_owns_it():
+    """An engine holds its tracked programs, and a program's jit holds
+    the engine's bound method: a cycle the collector must see through.
+    (jaxlib's bound ``_cache_size`` kept on the wrapper hid it, and
+    every dropped engine kept its executables mapped: a test worker ran
+    out of memory maps.)"""
+    import gc
+    import weakref
     from deepspeed_tpu.profiling.recompile import CompileTracker
-    tracker = CompileTracker()
-    calls = []
-    f = tracker.wrap(lambda x: calls.append(x.shape) or x, "g")
-    f._has_cache_size = False
-    x4, x8 = np.ones((4,)), np.ones((8,))
-    f(x4); f(x4); f(x8); f(x4)
-    assert tracker.counts == {"g": 2}
+
+    class Owner:
+        def __init__(self):
+            self.program = CompileTracker().wrap(jax.jit(self.double), "d")
+
+        def double(self, x):
+            return x * 2
+
+    owner = Owner()
+    assert float(owner.program(jnp.ones((3,)))[0]) == 2.0
+    gone = weakref.ref(owner)
+    del owner
+    gc.collect()
+    assert gone() is None
+
+
+def test_setup_span_refuses_a_name_the_registry_does_not_hold():
+    from deepspeed_tpu.profiling.recompile import setup_span
+    with pytest.raises(ValueError, match="setup/teardown"):
+        with setup_span("setup/teardown"):
+            pass
+    with pytest.raises(ValueError, match="serve/decode"):
+        with setup_span("serve/decode"):          # registered, not set-up
+            pass
 
 
 def test_tracked_function_passes_lower_through():

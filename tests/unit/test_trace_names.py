@@ -107,8 +107,12 @@ def test_registry_refuses_unknown_scope_and_orders_spans():
     assert len(set(HOST_SPANS)) == len(HOST_SPANS)
     for i, name in enumerate(HOST_SPANS):
         parent = name.rsplit("/", 1)[0]
-        if parent.startswith("serve/") and parent != name:
+        if parent.startswith(("serve/", "setup/")) and parent != name:
             assert parent in HOST_SPANS[:i], name
+    # set-up's spans (profiling/recompile.py ``setup_span``): ten at most
+    setup = [name for name in HOST_SPANS if name.startswith("setup/")]
+    assert {"setup/import", "setup/engine", "setup/warmup",
+            "setup/program"} <= set(setup) and len(setup) <= 10
     # the names tests and docs have pinned since PR 18
     for name in ("train_batch", "data", "serve/prefill", "serve/chunk",
                  "serve/verify", "serve/decode"):
@@ -455,3 +459,167 @@ def test_training_trace_holds_dispatch_and_tail_spans(tmp_path):
         assert _children(events, step) == ["data", "train/dispatch"]
         assert tail[1] >= step[2]                     # after, not inside
     assert tails[0][2] <= steps[1][1]
+
+
+# ------------------------------------------------------- set-up's spans
+def _ledger_since(mark):
+    """The compile ledger's program rows and spans that began after
+    ``mark`` (a ``time.perf_counter()`` stamp)."""
+    from deepspeed_tpu.profiling.spans import compile_ledger
+    table = compile_ledger().table()
+    return ([r for r in table["programs"] if r["t_begin"] >= mark],
+            [s for s in table["spans"] if s["t0"] >= mark])
+
+
+def _assert_phases_nest(programs, spans):
+    """Every span lies inside a span of its parent's name, a parent's
+    children do not overlap and never add up to more than it, and every
+    program row lies inside a span of the phase it names."""
+    for s in spans:
+        assert s["t1"] >= s["t0"]
+        if s["parent"] is not None:
+            assert [p for p in spans if p["name"] == s["parent"]
+                    and p["t0"] <= s["t0"] and s["t1"] <= p["t1"]], s
+    for p in spans:
+        children = sorted(
+            (s for s in spans if s is not p and s["parent"] == p["name"]
+             and p["t0"] <= s["t0"] and s["t1"] <= p["t1"]),
+            key=lambda s: s["t0"])
+        for a, b in zip(children, children[1:]):
+            assert a["t1"] <= b["t0"]
+        assert sum(s["t1"] - s["t0"] for s in children) <= p["t1"] - p["t0"]
+    for r in programs:
+        if r["phase"] != "steady":
+            assert [s for s in spans if s["name"] == r["phase"]
+                    and s["t0"] <= r["t_begin"] and r["t_end"] <= s["t1"]], r
+
+
+def test_warmup_leaves_a_row_a_warmed_program_inside_its_spans():
+    import time
+    mark = time.perf_counter()
+    engine = _serve_engine()
+    warm = engine.warmup()
+    engine.generate(PROMPTS, max_new_tokens=3)
+    assert engine.steady_state_recompiles == 0
+    engine.close()
+    programs, spans = _ledger_since(mark)
+    _assert_phases_nest(programs, spans)
+    # one engine, one warm-up, a setup/program a warmed call
+    names = [s["name"] for s in spans]
+    assert names.count("setup/engine") == names.count("setup/warmup") == 1
+    assert {"setup/engine/params", "setup/engine/state",
+            "setup/engine/programs"} <= set(names)
+    warmed = [s for s in spans if s["name"] == "setup/program"]
+    assert all(s["parent"] == "setup/warmup" for s in warmed)
+    # a row a program the tracker counted, each with the wrap's name, the
+    # dispatch ledger's class and JAX's three durations
+    tracked = [r for r in programs if r["name"] is not None]
+    assert len(tracked) == warm == engine.compile_tracker.total_compiles
+    assert all(r["phase"] == "setup/program" for r in tracked)
+    assert all(r["trace_s"] > 0 and r["lower_s"] > 0 and r["backend_s"] > 0
+               and r["call_s"] > 0 for r in tracked)
+    by_class = {r["cls"]: r["name"] for r in tracked}
+    assert {cls: name for cls, name in by_class.items()
+            if name != "merge_tokens"} == {
+        **{("prefill", bb, pb): "prefill"
+           for bb in INF["batch_buckets"] for pb in INF["prompt_buckets"]},
+        ("decode", 2): "decode"}
+    assert {cls for cls, name in by_class.items()
+            if name == "merge_tokens"} == {("merge_tokens", 1),
+                                           ("merge_tokens", 2)}
+    # the same tuples the dispatch ledger wrote as the programs ran
+    ran = set(engine.dispatch_ledger.table()["cls"])
+    assert ran <= set(by_class)
+    # a program the engine built as it was constructed (a pool's zeros,
+    # where this process had not built them yet) is a row by JAX's name
+    assert all(r["name"] is None for r in programs
+               if r["phase"].startswith("setup/engine"))
+
+
+def test_first_train_batch_is_a_setup_program_and_the_step_has_its_row():
+    """The training engine wraps nothing unless observability is on: its
+    step program still has its row, by JAX's name and the span around
+    the first ``train_batch``'s dispatches; the second opens none."""
+    import time
+    mark = time.perf_counter()
+    engine = _train_engine()
+    it = _batches()
+    engine.train_batch(it)
+    engine.train_batch(it)
+    engine.close()
+    programs, spans = _ledger_since(mark)
+    _assert_phases_nest(programs, spans)
+    first = [s for s in spans if s["name"] == "setup/program"]
+    assert len(first) == 1 and first[0]["cls"] == ("train_batch",)
+    assert first[0]["parent"] is None
+    (step,) = [r for r in programs if r["fun_name"] == "jit(_micro_step)"]
+    assert step["phase"] == "setup/program" and step["name"] is None
+    assert step["cls"] == ("train_batch",) and step["call_s"] is None
+    assert step["trace_s"] > 0 and step["lower_s"] > 0
+    assert step["backend_s"] > 0
+    (built,) = [s for s in spans if s["name"] == "setup/engine"]
+    assert {s["name"] for s in spans if s["parent"] == "setup/engine"} == {
+        "setup/engine/params", "setup/engine/state"}
+    assert built["t1"] <= first[0]["t0"]
+
+
+def test_the_first_batchs_span_adds_no_frame_and_nothing_to_the_instance():
+    """``setup/program`` is opened in ``train_batch``'s own frame (one
+    more Python frame under the step program's trace cost GPT-2 345M
+    2.5 s of set-up on the benchmark's host: PERF.md §6, PR 53), and the
+    engine's public methods stay the class's: a ``train_batch`` held
+    from before the first step is callable for good."""
+    import sys
+    import time
+    mark = time.perf_counter()
+    engine = _train_engine()
+    assert "train_batch" not in vars(engine)
+    depths = []
+    traced = engine._micro_step
+
+    def micro_step(state, batch):       # runs as the program is traced
+        frame, depth = sys._getframe(), 0
+        while frame.f_code is not type(engine).train_batch.__code__:
+            frame, depth = frame.f_back, depth + 1
+        depths.append((depth, frame.f_back.f_code.co_name))
+        return traced(state, batch)
+    engine._micro_step = micro_step
+    step, it = engine.train_batch, _batches()
+    losses = [float(step(it)) for _ in range(3)]
+    engine.close()
+    assert all(np.isfinite(losses)) and engine.global_steps == 3
+    # traced once, and train_batch was called by THIS test, directly
+    ((_, caller),) = depths
+    assert caller == \
+        "test_the_first_batchs_span_adds_no_frame_and_nothing_to_the_instance"
+    programs, spans = _ledger_since(mark)
+    assert len([s for s in spans if s["name"] == "setup/program"]) == 1
+    assert len([r for r in programs if r["phase"] == "setup/program"
+                and r["trace_s"] > 0 and r["lower_s"] > 0]) == 1
+
+
+def test_the_packages_import_is_a_span_of_the_ledger():
+    from deepspeed_tpu.profiling.spans import compile_ledger
+    spans = [s for s in compile_ledger().table()["spans"]
+             if s["name"] == "setup/import"]
+    assert len(spans) == 1 and spans[0]["parent"] is None
+    assert spans[0]["t0"] == deepspeed_tpu._T_IMPORT < spans[0]["t1"]
+
+
+def test_a_trace_over_setup_shows_its_spans_on_the_profilers_clock(tmp_path):
+    def body():
+        engine = _serve_engine()
+        engine.warmup()
+        engine.close()
+    events = [ev for ev in _traced(tmp_path, body)
+              if ev[0].startswith("setup/")]
+    (built,) = [ev for ev in events if ev[0] == "setup/engine"]
+    (warm,) = [ev for ev in events if ev[0] == "setup/warmup"]
+    assert built[2] <= warm[1]
+    assert set(_children(events, built)) == {
+        "setup/engine/params", "setup/engine/state",
+        "setup/engine/programs"}
+    warmed = [ev for ev in events if ev[0] == "setup/program"]
+    assert warmed and set(_children(events, warm)) == {"setup/program"}
+    # a warmed call's class rides as the annotation's argument
+    assert {ev[3]["cls"] for ev in warmed} >= {"prefill 1 4", "decode 2"}
