@@ -649,8 +649,15 @@ class InferenceEngine:
                     kv_quant_block=pk["kv_quant_block"])
                 self.cache_spec = None
                 self._cache = init_paged_kv_cache(self.paged_spec)
+                self._resolve_decode_attn(pk)
+                # the Pallas reader copies a block of consecutive pages
+                # with one descriptor: requests get their pages in runs
+                # of a block (the gather reader takes any order)
+                run_pages = (block_pages(ps, latent=self.latent)
+                             if self._decode_attn_path == "pallas" else 1)
                 allocator = PageAllocator(num_pages, ps,
-                                          prefix_cache=pk["prefix_cache"])
+                                          prefix_cache=pk["prefix_cache"],
+                                          run_pages=run_pages)
                 cache_bytes = paged_kv_bytes(self.paged_spec)
                 self._page_bytes = cache_bytes // num_pages
                 if self.state_spec is not None:
@@ -686,9 +693,9 @@ class InferenceEngine:
                     self._cache_prefill = init_paged_kv_cache(
                         self.paged_spec_prefill)
                     admit_allocator = PageAllocator(
-                        ppages, ps, prefix_cache=pk["prefix_cache"])
+                        ppages, ps, prefix_cache=pk["prefix_cache"],
+                        run_pages=run_pages)
                     cache_bytes += paged_kv_bytes(self.paged_spec_prefill)
-                self._resolve_decode_attn(pk)
             else:
                 self.paged_spec = None
                 self.cache_spec = cache_spec_for(model_config, self._rows,
@@ -2386,8 +2393,10 @@ class InferenceEngine:
                 program = ("decode", width)
                 # what the Pallas kernel walks: each row's live
                 # pages, ``block_pages`` a loop turn, an inactive
-                # row's null page in one turn; the gather reader
-                # walks none (it reads the table's whole width)
+                # row's null page in one turn (``run_turns``, those of
+                # them it copies as ONE run, follow the table below);
+                # the gather reader walks none (it reads the table's
+                # whole width)
                 ps = self.paged_spec.page_size
                 per_turn = block_pages(ps, latent=self.latent)
                 if self._decode_attn_path == "pallas":
@@ -2423,6 +2432,10 @@ class InferenceEngine:
                 sids, poss, temps, seeds)
             if self.paged:
                 tables = sched.block_table_rows(self._rows, width)
+                # an inactive row's one null page is a run of one
+                counters["run_turns"] = (
+                    sched.run_turns(sids, poss) + self._rows - len(sids)
+                    if self._decode_attn_path == "pallas" else 0)
         with self._span("serve/decode/dispatch"):
             # the tokens are the device's own (the decode before's
             # result, first tokens merged in: never an upload of what

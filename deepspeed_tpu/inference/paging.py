@@ -10,7 +10,9 @@ works.
 
 from typing import Dict, List, Optional, Sequence, Tuple
 
-__all__ = ["PageAllocator", "pages_for"]
+import numpy as np
+
+__all__ = ["PageAllocator", "pages_for", "run_leads"]
 
 
 def pages_for(tokens: int, page_size: int) -> int:
@@ -18,9 +20,23 @@ def pages_for(tokens: int, page_size: int) -> int:
     return -(-int(tokens) // int(page_size))
 
 
+def run_leads(pages: Sequence[int], run_pages: int,
+              blocks: int) -> np.ndarray:
+    """For each of the ``blocks`` blocks of ``run_pages`` entries of a
+    block table that holds ``pages``: how many of the block's pages,
+    from its first on, are consecutive ids (at least 1). The decode
+    reader copies a block's LIVE pages as one run exactly when they are
+    no more than this (``ops/attention/paged.py`` ``_block_runs``)."""
+    table = np.full((blocks * run_pages,), -1, np.int64)
+    table[:len(pages)] = pages[:len(table)]
+    table = table.reshape(blocks, run_pages)
+    follows = table[:, 1:] == table[:, :-1] + 1
+    return 1 + np.cumprod(follows, axis=1).sum(axis=1)
+
+
 class PageAllocator:
-    """Host-side page bookkeeping: free list, per-page refcounts, and
-    the prefix cache (chain-hashed full prompt pages).
+    """Host-side page bookkeeping: free extents and pages, per-page
+    refcounts, and the prefix cache (chain-hashed full prompt pages).
 
     Pure host code, no jax — page allocation happens in the scheduler
     (the jit programs only ever see static-shape block tables), so the
@@ -45,15 +61,31 @@ class PageAllocator:
     """
 
     def __init__(self, num_pages: int, page_size: int,
-                 prefix_cache: bool = True):
+                 prefix_cache: bool = True, run_pages: int = 1):
         if num_pages < 2:
             raise ValueError(
                 f"PageAllocator needs >= 2 pages (one is the reserved "
                 f"null page), got {num_pages}")
+        if run_pages < 1:
+            raise ValueError(f"run_pages must be >= 1, got {run_pages}")
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         self.prefix_cache_enabled = bool(prefix_cache)
-        self._free: List[int] = list(range(1, self.num_pages))
+        # the pages one loop turn of the engine's decode reader streams
+        # (``ops/attention/paged.block_pages``): pages 1..N-1 in aligned
+        # extents of that many consecutive ids, extent ``e`` the ids
+        # from ``1 + e * run_pages``. The whole free ones are a LIFO
+        # stack; a broken one keeps its free pages in ``_loose``. With
+        # ``run_pages`` 1 every page is an extent, and the stack is the
+        # plain free list it was.
+        self.run_pages = int(run_pages)
+        extents = (self.num_pages - 1) // self.run_pages
+        self._whole: List[int] = list(range(extents))
+        self._loose: Dict[int, List[int]] = {}
+        rest = list(range(1 + extents * self.run_pages, self.num_pages))
+        if rest:         # the pool's end: too short ever to be whole
+            self._loose[extents] = rest
+        self._n_free = self.num_pages - 1
         self._ref: Dict[int, int] = {}
         # readers beyond each page's first, summed over the pool: kept
         # as references come and go, because the scheduler asks for
@@ -78,11 +110,11 @@ class PageAllocator:
     # ------------------------------------------------------------ state
     @property
     def free_pages(self) -> int:
-        return len(self._free)
+        return self._n_free
 
     @property
     def pages_in_use(self) -> int:
-        return self.num_pages - 1 - len(self._free)
+        return self.num_pages - 1 - self._n_free
 
     def refcount(self, page: int) -> int:
         return self._ref.get(page, 0)
@@ -100,6 +132,8 @@ class PageAllocator:
         return {
             "num_pages": self.num_pages,
             "page_size": self.page_size,
+            "run_pages": self.run_pages,
+            "extents_free": len(self._whole),
             "pages_free": self.free_pages,
             "pages_in_use": self.pages_in_use,
             "pages_shared": shared,
@@ -123,15 +157,55 @@ class PageAllocator:
         return self._extra_readers * self.page_size
 
     # ------------------------------------------------------ alloc / free
-    def alloc(self, n: int) -> Optional[List[int]]:
-        """Take ``n`` pages off the free list (refcount 1 each), or
-        None — never a partial grab — when the pool can't supply them."""
-        if n > len(self._free):
+    def alloc(self, n: int, at: int = 0) -> Optional[List[int]]:
+        """Take ``n`` pages (refcount 1 each), or None — never a
+        partial grab — when fewer than ``n`` are free. They are laid
+        for a block table that holds them from index ``at``: single
+        pages up to the table's next multiple of ``run_pages``, then
+        one whole extent (ascending, consecutive ids) a block of
+        ``run_pages``, then the pages left over, out of ONE broken
+        extent where one has that many. A block for which no whole
+        extent is free takes single pages."""
+        if n > self._n_free:
             return None
-        pages = [self._free.pop() for _ in range(n)]
+        rp = self.run_pages
+        head = min(n, -at % rp)
+        pages = self._take_loose(head, together=False)
+        for _ in range((n - head) // rp):
+            if self._whole:
+                first = 1 + self._whole.pop() * rp
+                pages.extend(range(first, first + rp))
+            else:
+                pages.extend(self._take_loose(rp, together=False))
+        pages.extend(self._take_loose((n - head) % rp, together=True))
+        self._n_free -= n
         for p in pages:
             self._ref[p] = 1
         return pages
+
+    def _take_loose(self, n: int, together: bool) -> List[int]:
+        """``n`` single pages, the lowest free ids of a broken extent
+        first. ``together`` (a table's last, short block): out of the
+        first broken extent that has all ``n``, else out of a whole one
+        broken for them; otherwise, and where neither is there, out of
+        the broken extents in turn, a whole one broken when they run
+        out."""
+        taken: List[int] = []
+        while len(taken) < n:
+            need = n - len(taken)
+            ext = next((e for e, free in self._loose.items()
+                        if not together or len(free) >= need), None)
+            if ext is None and self._whole:
+                ext, rp = self._whole.pop(), self.run_pages
+                self._loose[ext] = list(range(1 + ext * rp,
+                                              1 + (ext + 1) * rp))
+            elif ext is None:
+                ext = next(iter(self._loose))
+            free = sorted(self._loose.pop(ext))
+            taken.extend(free[:need])
+            if free[need:]:
+                self._loose[ext] = free[need:]
+        return taken
 
     def incref(self, pages: Sequence[int]):
         for p in pages:
@@ -156,7 +230,13 @@ class PageAllocator:
                     self.prefix_evictions += 1
                 self._page_tokens.pop(p, None)
                 self._page_parent.pop(p, None)
-                self._free.append(p)
+                self._n_free += 1
+                ext = (p - 1) // self.run_pages
+                free = self._loose.setdefault(ext, [])
+                free.append(p)
+                if len(free) == self.run_pages:      # whole again
+                    del self._loose[ext]
+                    self._whole.append(ext)
             else:
                 self._ref[p] = c - 1
                 self._extra_readers -= 1

@@ -36,7 +36,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from deepspeed_tpu.inference.buckets import pick_bucket
-from deepspeed_tpu.inference.paging import PageAllocator, pages_for
+from deepspeed_tpu.inference.paging import (PageAllocator, pages_for,
+                                            run_leads)
 
 __all__ = ["Request", "FinishedRequest", "PrefillBatch", "Scheduler"]
 
@@ -219,6 +220,13 @@ class Scheduler:
         # slot -> (its page list, the same as an int32 array): what
         # ``block_table_rows`` copies into the dispatch's table
         self._page_rows: Dict[int, Tuple[List[int], np.ndarray]] = {}
+        # slot x block of its table -> ``run_leads`` of its pages, set
+        # where ``_page_rows`` is: what ``run_turns`` counts from
+        self._run_leads = None
+        if allocator is not None:
+            blocks = pages_for(pages_for(max_len, allocator.page_size),
+                               allocator.run_pages)
+            self._run_leads = np.ones((self.num_slots, blocks), np.int64)
         self._submit_time: Dict[int, float] = {}
         self.finished: List[FinishedRequest] = []
         # graceful submit-time rejections awaiting the engine's next
@@ -389,7 +397,8 @@ class Scheduler:
         tokens = len(req.prompt) if self._separate_pools else \
             len(req.prompt) + req.max_new_tokens
         total = pages_for(tokens, alloc.page_size)
-        fresh = alloc.alloc(total - len(shared))
+        # laid behind the shared pages: extents on the table's blocks
+        fresh = alloc.alloc(total - len(shared), at=len(shared))
         if fresh is None:
             return None
         alloc.incref(shared)
@@ -936,9 +945,26 @@ class Scheduler:
             if kept is None or kept[0] is not slot.pages:
                 kept = self._page_rows[sid] = (
                     slot.pages, np.asarray(slot.pages, np.int32))
+                self._run_leads[sid] = run_leads(
+                    kept[1], self.allocator.run_pages,
+                    self._run_leads.shape[1])
             pages = kept[1][:pages_per_seq]
             out[sid, :len(pages)] = pages
         return out
+
+    def run_turns(self, sids: Sequence[int],
+                  positions: Sequence[int]) -> int:
+        """Of the loop turns in which a decode reader that walks pages
+        reads rows ``sids`` at ``positions``, those whose block it
+        copies as ONE run: the block's live pages are consecutive ids.
+        Counted from what ``block_table_rows`` last kept of each slot's
+        pages (call it first), numpy over the rows."""
+        rp = self.allocator.run_pages
+        walks = np.asarray(positions, np.int64) \
+            // self.allocator.page_size + 1
+        first = np.arange(self._run_leads.shape[1]) * rp
+        live = np.clip(walks[:, None] - first, 0, rp)
+        return int(((live > 0) & (self._run_leads[sids] >= live)).sum())
 
     def max_live_pages(self) -> int:
         """Widest live page count across active slots for ONE decode

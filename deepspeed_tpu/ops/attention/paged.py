@@ -42,6 +42,15 @@ Design:
   streaming idiom) — 2 blocks of VMEM per stream at any pool size. Of
   a row's last block only the live pages are copied; the tail is
   masked.
+- **A run of pages is one copy.** Where a block's live pages are
+  consecutive ids in the pool (the allocator hands a request its pages
+  in runs of a block: ``inference/paging.py``) a stream's copy of the
+  block is ONE descriptor, a walk's last block the binary pieces of
+  its live count; any other block goes page by page. Which blocks are
+  runs is read off the tables once a decode program
+  (:func:`_block_runs`) and rides in SMEM beside them (ISSUE 54: the
+  copies were bound by their descriptors, 32 a turn of the latent
+  walk).
 - **One stream of blocks across the sequences.** The grid runs in
   order, and a walk's LAST turn issues the next sequence's first block
   into the free slot (scratch and semaphores outlive a grid step), so
@@ -295,7 +304,15 @@ _BLOCK_TOKENS = 128
 # of 128, four layers: 128: 4.82, 256: 4.15, 512: 3.99) and keeps its
 # 128 until a change of its own re-measures the short rows it was
 # chosen at: the arities' constants differ by what was measured, not by
-# what the kernel can do.
+# what the kernel can do. WITH RUNS (PR 54: a block of consecutive
+# pages is one descriptor; the same shape, the tables out of a churned
+# extent allocator, 1,180 of 1,184 turns runs; my chip runs, PR 54,
+# parent -> change): pages of 16, 512: 12.1 -> 8.7, 1024: 12.0 -> 7.8;
+# pages of 64, 512: 8.6 -> 8.4. Pages of 64 still beat pages of 16 at
+# 512 a turn, by 3% where it was 29%, and lose to them at 1,024; a
+# shuffled table (every block page by page) reads 12.2 where the
+# parent's kernel reads 12.0. What is left of a turn is its products
+# and bookkeeping, so 1,024 a turn now buys 11%: a change of its own.
 _LATENT_BLOCK_TOKENS = 512
 
 
@@ -337,7 +354,7 @@ def _probs_dot(p, vt):
     return out[:rows] + out[rows:2 * rows] + out[2 * rows:]
 
 
-def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref,
+def _decode_kernel(layer_ref, tables_ref, pos_ref, runs_ref, q_ref, k_ref,
                    *rest, sm_scale, page_size, head_dim, quantized,
                    value_lanes=None):
     """One sequence's program: walk the row's live pages from the pool,
@@ -396,6 +413,7 @@ def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref,
     table_pages = tables_ref.shape[1]
     tokens, width = kbuf.shape[1:]
     block_pages = tokens // page_size
+    pool_pages = k_ref.shape[1]
 
     def _pages(seq):
         # positions 0..pos are attended (this call's token was written
@@ -431,20 +449,58 @@ def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref,
     def _for_live_pages(seq, blk, slot, fn):
         """``fn(copy)`` for every copy of the LIVE pages of sequence
         ``seq``'s block ``blk``, which lands in ``slot``: the pages past
-        the row's count are never touched."""
-        live = _live_in(seq, blk)
-        for j in range(block_pages):
-            def _page(j=j):
-                page = _page_id(seq, blk, j)
-                rows = pl.ds(j * page_size, page_size)
+        the row's count are never touched. Where ``runs_ref`` says the
+        block's live pages are consecutive ids in the pool (as an
+        allocator that hands out runs lays them, ``inference/
+        paging.py``), a stream's copy of a full block is ONE descriptor
+        and of a walk's last, short block the binary pieces of its
+        count, each over whole pages; else one a page."""
+        live = jnp.minimum(_live_in(seq, blk), block_pages)
+
+        def _by_page():
+            for j in range(block_pages):
+                def _page(j=j):
+                    page = _page_id(seq, blk, j)
+                    rows = pl.ds(j * page_size, page_size)
+                    for ref, buf, sem in streams:
+                        fn(pltpu.make_async_copy(ref.at[layer, page],
+                                                 buf.at[slot, rows],
+                                                 sem.at[slot]))
+                if j == 0:
+                    _page()              # a walked block has a live page
+                else:
+                    pl.when(j < live)(_page)
+        if block_pages == 1:
+            return _by_page()
+
+        def _as_run():
+            first = _page_id(seq, blk, 0)
+
+            def _copy(done, n):
+                # pages ``done .. done + n`` of the block in ONE copy a
+                # stream, from the pool as rows of tokens (a view)
                 for ref, buf, sem in streams:
-                    fn(pltpu.make_async_copy(ref.at[layer, page],
-                                             buf.at[slot, rows],
-                                             sem.at[slot]))
-            if j == 0:
-                _page()                  # a walked block has a live page
-            else:
-                pl.when(j < live)(_page)
+                    rows = ref.reshape(ref.shape[0], -1, ref.shape[-1])
+                    fn(pltpu.make_async_copy(
+                        rows.at[layer, pl.ds((first + done) * page_size,
+                                             n * page_size)],
+                        buf.at[slot, pl.ds(done * page_size,
+                                           n * page_size)],
+                        sem.at[slot]))
+
+            def _pieces():
+                # a walk's last block: the binary pieces of its count,
+                # the pieces above one holding ``live``'s higher bits
+                for bit in reversed(range(
+                        min(block_pages - 1, pool_pages).bit_length())):
+                    pl.when((live & (1 << bit)) != 0)(
+                        lambda bit=bit: _copy(
+                            (live >> (bit + 1)) << (bit + 1), 1 << bit))
+            if block_pages > pool_pages:     # (no pool holds such a run)
+                return _pieces()
+            jax.lax.cond(live == block_pages,
+                         lambda: _copy(0, block_pages), _pieces)
+        jax.lax.cond(runs_ref[seq, blk] != 0, _as_run, _by_page)
 
     def _start(seq, blk, slot):
         if block_pages > 1:
@@ -540,6 +596,27 @@ def _decode_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref,
             keepdims=True).astype(o_ref.dtype)
 
 
+def _block_runs(block_tables, cache_position, page_size, block_pages,
+                pool_pages):
+    """``(rows, blocks) int32``: is the walk's block ``blk`` of a row a
+    RUN, its live pages consecutive ids in the pool? Read off the
+    tables, whatever laid them, once a decode program: the same
+    operation in every layer's call. (A pool of fewer pages than a
+    block holds no longer run.)"""
+    rows, width = block_tables.shape
+    blocks = -(-width // block_pages)
+    tables = jnp.pad(block_tables,
+                     ((0, 0), (0, blocks * block_pages - width))).reshape(
+                         rows, blocks, block_pages)
+    live = jnp.clip(
+        live_pages(cache_position, page_size)[:, None]
+        - jnp.arange(blocks, dtype=jnp.int32) * block_pages, 0, block_pages)
+    at = jnp.arange(block_pages, dtype=jnp.int32)
+    follows = (tables == tables[:, :, :1] + at) | (at >= live[:, :, None])
+    return (jnp.all(follows, axis=-1)
+            & (live <= pool_pages)).astype(jnp.int32)
+
+
 def _compiler_params(interpret):
     if pltpu is None or interpret:
         return None
@@ -583,9 +660,10 @@ def _paged_decode_call(q, kpool, vpool, scales, block_tables,
     scratch += [pltpu.SemaphoreType.DMA((2,))] * len(pools)
     scratch += [pltpu.SMEM((1,), jnp.int32)]      # slot of a walk's block 0
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        # layer, tables and positions prefetch into SMEM: page ids must
-        # be available to index the DMAs before the body runs
-        num_scalar_prefetch=3,
+        # layer, tables, positions and the blocks that are runs prefetch
+        # into SMEM: page ids must be available to index the DMAs before
+        # the body runs
+        num_scalar_prefetch=4,
         grid=(B,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, G, width), lambda b, *_: (b, 0, 0)),
@@ -597,7 +675,9 @@ def _paged_decode_call(q, kpool, vpool, scales, block_tables,
         out_shape=jax.ShapeDtypeStruct((B, G, width), q.dtype),
         interpret=interpret,
         compiler_params=_compiler_params(interpret),
-    )(layer, block_tables, cache_position, qg, *pools)
+    )(layer, block_tables, cache_position,
+      _block_runs(block_tables, cache_position, ps, block_tokens // ps,
+                  kpool.shape[1]), qg, *pools)
     # (B, G, KH * hd) -> heads in q's order, kh major
     return out.reshape(B, G, KH, hd).transpose(0, 2, 1, 3).reshape(B, H, hd)
 
@@ -613,7 +693,7 @@ def _latent_decode_call(q, pool, block_tables, cache_position, layer,
                                page_size=pool.shape[2], head_dim=width,
                                quantized=False, value_lanes=value_lanes)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B,),
         in_specs=[pl.BlockSpec((1, H, width), lambda b, *_: (b, 0, 0)),
                   pl.BlockSpec(memory_space=pltpu.HBM)],
@@ -629,7 +709,10 @@ def _latent_decode_call(q, pool, block_tables, cache_position, layer,
         out_shape=jax.ShapeDtypeStruct((B, H, value_lanes), q.dtype),
         interpret=interpret,
         compiler_params=_compiler_params(interpret),
-    )(layer, block_tables, cache_position, q.astype(pool.dtype), pool)
+    )(layer, block_tables, cache_position,
+      _block_runs(block_tables, cache_position, pool.shape[2],
+                  block_tokens // pool.shape[2], pool.shape[1]),
+      q.astype(pool.dtype), pool)
 
 
 def latent_decode_attention(q, pool, block_tables, cache_position,

@@ -462,12 +462,13 @@ def test_the_reason_cell_is_declared_as_the_issue_names_it():
     every = [m for m in bench["per_layer"] if DOCS in m.get(
         "workloads", []) and "gpt2-345m.serve-saturated" in m["workloads"]
         and "solar-open2-250b.serve-rollout-saturated" in m["workloads"]]
-    # ... and PR 52's `decode_deferred_share.sat` with all six
-    assert len(every) == 25 and all(m["workloads"][3] == REASON
+    # ... and PR 52's `decode_deferred_share.sat` and PR 54's
+    # `decode_run_turn_share.sat` with all six
+    assert len(every) == 26 and all(m["workloads"][3] == REASON
                                     for m in every)
-    assert [m["name"] for m in every[-3:]] == [
+    assert [m["name"] for m in every[-4:]] == [
         "prefill_page_write_share.sat", "serve_prefill_build_ms.sat",
-        "decode_deferred_share.sat"]
+        "decode_deferred_share.sat", "decode_run_turn_share.sat"]
     for name in reported:
         assert os.path.exists(os.path.join(BENCH, "metrics",
                                            name + ".json")), name

@@ -769,7 +769,8 @@ class TestPageAllocator:
             elif op == 2 and held:
                 al.free(held.pop(rs.randint(len(held))))
             elif op == 3:
-                free_page = al._free[-1] if al._free else None
+                free_page = next((p for p in range(1, al.num_pages)
+                                  if not al.refcount(p)), None)
                 if free_page is not None:
                     owned = held[0][:1] if held else []
                     with pytest.raises(ValueError):

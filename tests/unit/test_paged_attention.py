@@ -302,6 +302,151 @@ class TestKernelParity:
                                    atol=2e-5)
 
 
+# --------------------------------------------------------------------- #
+# a block of consecutive pages is ONE copy (ISSUE 54)
+# --------------------------------------------------------------------- #
+def _lay(tables, layout, block_pages, live_pages, rng):
+    """``tables`` (every row's pages consecutive ids: all runs) laid
+    another way: ``shuffled`` (descending: no two neighbours in order),
+    ``mixed`` (every other block's first two pages swapped),
+    ``broken_tail`` (the full blocks as they are, a walk's last block
+    swapped) or ``run_tail_only`` (the reverse)."""
+    out = tables.copy()
+    for b, row in enumerate(out):
+        n = int((row != 0).sum())
+        last = (live_pages[b] - 1) // block_pages * block_pages
+        for at in range(0, n - 1, block_pages):
+            swap = {"runs": False, "shuffled": False,
+                    "mixed": at // block_pages % 2 == 0,
+                    "broken_tail": at == last,
+                    "run_tail_only": at != last}[layout]
+            if swap:
+                row[[at, at + 1]] = row[[at + 1, at]]
+        if layout == "shuffled":
+            row[:n] = row[:n][::-1]
+    return out
+
+
+class TestRunsOfPages:
+    """The kernel reads a block whose live pages are consecutive ids
+    with one copy a stream (a walk's last block: the binary pieces of
+    its count) and any other block page by page, deciding from the
+    table a block at a time. The same tiles land either way: a row's
+    output is BIT-EQUAL under every layout of the same rows, for the
+    pair arity, the int8 one and the latent one, at blocks of 4 pages
+    and of 6 (not a power of two). Rows: one page; an inactive slot's
+    null table; the whole table; a walk that ends inside a block;
+    exactly one block; part of the first block. Every page past a
+    row's live ones is NaN, so a copy that takes more than the live
+    pages shows."""
+
+    PS = 4
+    # (position, active)
+    ROWS = [(0, True), (0, False), (None, True), (None, True),
+            (None, True), (None, True)]
+
+    def _case(self, monkeypatch, arity, block_pages):
+        from deepspeed_tpu.ops.attention import paged
+        ps = self.PS
+        monkeypatch.setattr(paged, "_BLOCK_TOKENS", block_pages * ps)
+        monkeypatch.setattr(paged, "_LATENT_BLOCK_TOKENS",
+                            block_pages * ps)
+        assert paged.block_pages(ps) == block_pages == \
+            paged.block_pages(ps, latent=True)
+        P = 3 * block_pages + 2
+        pos = np.asarray([0, 0, P * ps - 1,
+                          (2 * block_pages + 2) * ps + 1,
+                          block_pages * ps - 1,
+                          (block_pages - 1) * ps - 2], np.int32)
+        B = len(pos)
+        rng = np.random.RandomState(block_pages + len(arity))
+        tables = (1 + np.arange(B * P, dtype=np.int32)).reshape(B, P)
+        tables[1] = 0                                 # inactive slot
+        width = 32
+        rows = rng.randn(2, B * P + 1, ps, width).astype(np.float32)
+        values = rng.randn(2, B * P + 1, ps, width).astype(np.float32)
+        live = pos // ps + 1
+        for b in range(B):
+            dead = tables[b, live[b]:]
+            rows[:, dead[dead != 0]] = np.nan
+            values[:, dead[dead != 0]] = np.nan
+        rows[1 - LAYER] = values[1 - LAYER] = np.nan
+        return pos, live, tables, rows, values, rng
+
+    def _read(self, arity, rows, values, base, tables, pos):
+        """The rows of ``base``'s pages moved to ``tables``' pages, and
+        the kernel's answer there."""
+        from deepspeed_tpu.ops.attention.paged import (
+            latent_decode_attention, paged_decode_attention)
+        k, v = np.full_like(rows, np.nan), np.full_like(values, np.nan)
+        k[:, tables.reshape(-1)] = rows[:, base.reshape(-1)]
+        v[:, tables.reshape(-1)] = values[:, base.reshape(-1)]
+        k[:, 0] = v[:, 0] = 0.0                       # the null page
+        rng = np.random.RandomState(7)
+        args = (jnp.asarray(tables), jnp.asarray(pos))
+        if arity == "latent":
+            q = jnp.asarray(rng.randn(len(pos), 4, k.shape[-1]),
+                            jnp.float32)
+            pool = jnp.asarray(k)
+            return q, (pool,), latent_decode_attention(
+                q, pool, *args, 0.2, 24, interpret=True, layer=LAYER)
+        q = jnp.asarray(rng.randn(len(pos), 4, 16), jnp.float32)
+        if arity == "pair":
+            pools = (jnp.asarray(k), jnp.asarray(v))
+            return q, pools, paged_decode_attention(
+                q, *pools, *args, interpret=True, layer=LAYER)
+        kq, vq, ks, vs = _quantize_pools(jnp.nan_to_num(jnp.asarray(k)),
+                                         jnp.nan_to_num(jnp.asarray(v)))
+        # what no live row holds stays poison where it can: the scales
+        ks = jnp.where(jnp.isnan(jnp.asarray(k[..., :1])), jnp.nan, ks)
+        vs = jnp.where(jnp.isnan(jnp.asarray(v[..., :1])), jnp.nan, vs)
+        return q, (kq, vq, ks, vs), paged_decode_attention(
+            q, kq, vq, *args, interpret=True, layer=LAYER,
+            k_scales=ks, v_scales=vs)
+
+    @pytest.mark.parametrize("layout", ["runs", "mixed", "broken_tail",
+                                        "run_tail_only"])
+    @pytest.mark.parametrize("block_pages", [4, 6])
+    @pytest.mark.parametrize("arity", ["pair", "int8", "latent"])
+    def test_every_layout_of_the_same_rows_reads_the_same_bits(
+            self, monkeypatch, arity, block_pages, layout):
+        from deepspeed_tpu.inference.paging import run_leads
+        from deepspeed_tpu.ops.attention.paged import (
+            latent_decode_reference, paged_decode_reference)
+        pos, live, base, rows, values, rng = self._case(
+            monkeypatch, arity, block_pages)
+        blocks = -(-base.shape[1] // block_pages)
+        outs = {}
+        for name in (layout, "shuffled"):
+            tables = _lay(base, name, block_pages, live, rng)
+            # the layout is what its name says, by the reader's rule
+            runs = [[bool(lead >= n) for lead, n in zip(
+                run_leads(tables[b], block_pages, blocks), np.clip(
+                    live[b] - np.arange(blocks) * block_pages, 0,
+                    block_pages)) if n > 1] for b in range(2, len(pos))]
+            flat = [r for row in runs for r in row]
+            assert {"runs": all(flat), "shuffled": not any(flat)}.get(
+                name, any(flat) and not all(flat)), (name, runs)
+            q, pools, out = self._read(arity, rows, values, base, tables,
+                                       pos)
+            assert bool(jnp.all(jnp.isfinite(out)))
+            outs[name] = (np.asarray(out), tables, pools)
+        got, tables, pools = outs[layout]
+        np.testing.assert_array_equal(got, outs["shuffled"][0])
+        active = np.asarray([0, 2, 3, 4, 5])
+        clean = [jnp.nan_to_num(p) for p in pools]
+        if arity == "latent":
+            ref = latent_decode_reference(
+                q[active], clean[0], jnp.asarray(tables[active]),
+                jnp.asarray(pos[active]), 0.2, 24, layer=LAYER)
+        else:
+            scales = dict(zip(("k_scales", "v_scales"), clean[2:]))
+            ref = paged_decode_reference(
+                q[active], clean[0], clean[1], jnp.asarray(tables[active]),
+                jnp.asarray(pos[active]), layer=LAYER, **scales)
+        np.testing.assert_allclose(got[active], np.asarray(ref), atol=2e-5)
+
+
 def _quantize_rows(pool, kv_heads, scale_blocks=1):
     """One fp pool (or any array of pool rows, ``kv_heads * hd`` last) as
     int8 rows + per-token-row fp32 scale rows ``kv_heads * scale_blocks``

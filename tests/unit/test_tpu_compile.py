@@ -241,6 +241,11 @@ def test_flash_attention_under_a_data_mesh_needs_the_shard_wrap():
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# what Mosaic says of a copy whose rows or lanes are not whole tiles (a
+# page's, or a run of pages')
+REFUSED = "aligned to tiling|divisible by the tiling"
+
+
 def _paged_decode_specs(head_dim, page_size, pool_dtype, heads=16,
                         kv_heads=16):
     """The kernel's operands over a stacked pool in its row layout,
@@ -346,7 +351,7 @@ def test_paged_decode_gate_agrees_with_the_compiler(head_dim, page_size,
     if ok:
         _compile(_paged_decode, *specs)
     else:
-        with pytest.raises(Exception, match="aligned to tiling"):
+        with pytest.raises(Exception, match=REFUSED):
             _compile(_paged_decode, *specs)
         assert why
 
@@ -393,6 +398,49 @@ def test_latent_decode_compiles_at_the_cells_rows(monkeypatch,
     assert compiled.memory_analysis().temp_size_in_bytes < 720896 * 640
 
 
+@pytest.mark.parametrize("arity,block_pages", [("pair", 8), ("latent", 32)])
+def test_a_run_of_pages_is_one_copy_at_the_cells_blocks(arity, block_pages):
+    """ISSUE 54: where a block's live pages are consecutive ids the
+    walk copies them with one descriptor a stream, a walk's last block
+    as the binary pieces of its count, from the pool viewed as rows of
+    tokens INSIDE the kernel. Both arities at their cells' shapes
+    (GPT-2 345M's pair of pools: pages of 16, 8 a block; ax-k1's latent
+    pool: 32 a block; bfloat16) hold copies of a page and of 1, 2, 4,
+    ... ``block_pages`` pages and nothing else; Mosaic takes the
+    dynamic starts of the pieces on both sides of the copy; and the
+    view costs no copy of the pool."""
+    if arity == "pair":
+        fn = _paged_decode
+        pool = _spec((24, POOL_PAGES, PAGE, HEADS * HEAD_DIM))
+        specs = (_spec((ROWS, HEADS, HEAD_DIM)), pool, pool,
+                 _spec((ROWS, TABLE_PAGES), jnp.int32),
+                 _spec((ROWS,), jnp.int32))
+        layer_bytes = POOL_PAGES * PAGE * HEADS * HEAD_DIM * 2
+    else:
+        fn = _latent_decode(512)
+        specs = _latent_decode_specs(640, 193, 6144 // 16,
+                                     720896 // 16 + 1)
+        layer_bytes = 720896 * 640 * 2
+    text = re.sub(r"\s+", " ", str(jax.make_jaxpr(fn)(*specs)))
+    sizes = {}
+    for src, rows in re.findall(
+            r"dma_start\(p0\) (.+?) -> \w+\[\w+,([^,]+),:\]", text):
+        # a piece lands at ``start:start+rows``; a page and a whole
+        # block at static rows ``from:to`` of the slot's block
+        if "+" in rows:
+            rows = int(rows.split("+")[1])
+        else:
+            low, high = rows.split(":")
+            rows = int(high or block_pages * PAGE) - int(low or 0)
+        sizes.setdefault(rows, set()).add("reshape" in src)
+    assert sizes == {16 * 2 ** bit: {True} if bit else {True, False}
+                     for bit in range(block_pages.bit_length())}
+    compiled = _compile(fn, *specs)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+
+
 @pytest.mark.parametrize("row_lanes,page_size,kv_heads,head_dim", [
     (640, 16, 1, 640),      # the latent pool's ONE rule: 576 held at 640
     (576, 16, 1, 576),      # the row as the equations give it: refused
@@ -419,7 +467,7 @@ def test_every_served_pool_geometry_meets_the_gate_it_met(row_lanes,
     if ok:
         _compile(_latent_decode(512), *specs)
     else:
-        with pytest.raises(Exception, match="aligned to tiling"):
+        with pytest.raises(Exception, match=REFUSED):
             _compile(_latent_decode(512), *specs)
         assert why
 

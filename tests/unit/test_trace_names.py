@@ -373,15 +373,19 @@ def test_serving_trace_holds_spans_in_order_with_scheduler_counters(
     for ev, (live, walked, turns) in zip(decodes, reported):
         # every argument has a reader (decode_stripe_live_share.sat,
         # decode_read_live_share.sat, decode_block_fill_share.sat,
-        # decode_deferred_share.sat)
+        # decode_deferred_share.sat, decode_run_turn_share.sat)
         # (..., and the dispatch ledger's row: test_dispatch_ledger.py)
         assert set(ev[3]) == {"seq", "step", "deferred", "rows",
                               "table_pages", "page_size", "live_tokens",
-                              "read_pages", "read_turns", "block_tokens"}
+                              "read_pages", "read_turns", "run_turns",
+                              "block_tokens"}
         assert ev[3]["live_tokens"] == live
         # the gather reader walks no page list: it reads the table
         assert ev[3]["read_pages"] == (walked if pallas else 0)
         assert ev[3]["read_turns"] == (turns if pallas else 0)
+        # a fresh pool gives every request its pages as one extent
+        # and what is left over out of one more: every turn is a run
+        assert ev[3]["run_turns"] == ev[3]["read_turns"]
         assert ev[3]["block_tokens"] == (per_turn * ps if pallas else 0)
         assert live <= walked * ps <= turns * per_turn * ps
         assert engine._rows <= turns <= walked
