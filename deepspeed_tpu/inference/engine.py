@@ -129,6 +129,10 @@ from deepspeed_tpu.models.gpt2 import (GPT2Config, gpt2_forward,
 from deepspeed_tpu.models.granite_hybrid import (
     GraniteHybridConfig, granite_hybrid_forward, granite_hybrid_param_specs,
     init_granite_hybrid_params)
+from deepspeed_tpu.models.keye_vl2 import (KeyeVL2Config,
+                                           init_keye_vl2_params,
+                                           keye_vl2_forward,
+                                           keye_vl2_param_specs)
 from deepspeed_tpu.models.kimi_linear import (KimiLinearConfig,
                                               init_kimi_linear_params,
                                               kimi_linear_forward,
@@ -174,6 +178,8 @@ _FAMILIES = {
     KimiLinearConfig: ("kimi_linear", kimi_linear_forward,
                        init_kimi_linear_params, kimi_linear_param_specs),
     LFM2Config: ("lfm2", lfm2_forward, init_lfm2_params, lfm2_param_specs),
+    KeyeVL2Config: ("keye_vl2", keye_vl2_forward, init_keye_vl2_params,
+                    keye_vl2_param_specs),
 }
 
 
@@ -408,6 +414,10 @@ class InferenceEngine:
         # in place of keys and values (inference/kv_cache.py)
         self.latent = getattr(model_config, "latent_geometry",
                               None) is not None
+        # a family whose attention reads a learned selection keeps ONE
+        # indexer key a token beside keys and values: (its lanes, the
+        # positions a query selects) (inference/kv_cache.py)
+        self.indexer = getattr(model_config, "indexer_geometry", None)
         # ONE list a family: a family of both kinds of layer states in
         # its config what its mixers can follow (chunked prefill)
         if self.state_spec is not None and self.latent:
@@ -416,9 +426,12 @@ class InferenceEngine:
             self._refuse_what_state_cannot_follow(cfg, mesh)
         elif self.latent:
             self._refuse_what_latent_rows_cannot_follow(cfg, mesh)
+        elif self.indexer is not None:
+            self._refuse_what_an_indexer_leaf_cannot_follow(cfg, mesh)
         # such families' prefill programs take each row's true length
         # and slot, and return the last true position's logits alone
-        self._prefill_by_length = self.state_spec is not None or self.latent
+        self._prefill_by_length = (self.state_spec is not None or self.latent
+                                   or self.indexer is not None)
         from deepspeed_tpu.runtime.config import get_observability_config
         self.obs_config = get_observability_config(
             {"observability": dict(observability_config or {})})
@@ -781,6 +794,16 @@ class InferenceEngine:
                             f" MiB), chunked prefill "
                             f"{self._chunk_tokens or 'off'}, decode attn "
                             f"{self._decode_attn_path}")
+                elif self.indexer is not None:
+                    geom = (f"paged KV cache with an indexer leaf: "
+                            f"{self.paged_spec.num_pages} pages x "
+                            f"{self.paged_spec.page_size} tokens over "
+                            f"{self.paged_spec.num_layers} layers, an "
+                            f"indexer key of {self.indexer[0]} lanes a "
+                            f"token, {self.indexer[1]} tokens selected a "
+                            f"query ({cache_bytes / 2**20:.1f} MiB), "
+                            f"chunked prefill "
+                            f"{self._chunk_tokens or 'off'}")
                 elif self.latent:
                     geom = (f"latent page pool: {self.paged_spec.num_pages} "
                             f"pages x {self.paged_spec.page_size} tokens over "
@@ -991,6 +1014,43 @@ class InferenceEngine:
         self._refuse_asked(cfg, mesh, "a per-slot recurrent state and "
                            "latent rows in its page pool", reasons)
 
+    def _refuse_what_an_indexer_leaf_cannot_follow(self, cfg, mesh):
+        """A family whose attention reads a learned selection
+        (``models/keye_vl2.py``) keeps an indexer key a token in a THIRD
+        leaf of the pair's tree, and its readers are the selected-rows
+        readers alone (``ops/attention/indexed.py``): what moves, shares,
+        scales or shards the (keys, values) pair knows no third leaf,
+        and would serve this family WRONGLY. The ONE list of what it is
+        refused, by name, each with what it waits for
+        (docs/keye_vl2.md). Chunked prefill is NOT on it: a chunk scores
+        and attends the prefix earlier chunks left in the pools."""
+        reasons = {
+            "dense_cache": "the dense cache (paged_kv.enabled: false): "
+            "the indexer's keys are a leaf of the paged cache tree",
+            "prefix_cache": "the prefix cache (paged_kv.prefix_cache): a "
+            "row that starts past a shared prefix is followed (a chunk "
+            "does), but admission and eviction of shared pages have not "
+            "been shown to keep the indexer leaf's rows with them",
+            "spec_decode": "speculative decoding: the verify program is "
+            "a query of several rows against the pool, and the selected-"
+            "rows readers take one row or a chunk",
+            "disagg": "disaggregated prefill/decode: the handoff moves a "
+            "(keys, values) pair of pools, not the indexer leaf",
+            "int8_pool": "an int8 page pool: the indexer leaf has no "
+            "int8 form, and the selected-rows readers read their pages "
+            "without scales",
+            "quantized_weights": "quantized weights: the family holds "
+            "its weights in bfloat16 as they are",
+            "mesh": "a serving mesh: only the single-device engine "
+            "serves this family (the cache's sharding names the pair's "
+            "head lanes, and the indexer's key has one head)"}
+        if not getattr(self.model_config, "serves_chunked_prefill", False):
+            reasons["chunked_prefill"] = (
+                "chunked prefill: the family's mixer starts every row "
+                "from its own rows")
+        self._refuse_asked(cfg, mesh, "an indexer key a token in a third "
+                           "leaf of its page pools", reasons)
+
     def _resolve_decode_attn(self, pk):
         """Pick the paged decode attention path once, at init (the
         compiled program set is fixed, so the choice is too):
@@ -1005,7 +1065,13 @@ class InferenceEngine:
         width; default = a single full-width program, preserving the
         PR 5/7 warmup program count)."""
         requested = pk["attn_kernel"]
-        if requested != "pallas":
+        if self.indexer is not None:
+            # the family's readers are its own (ops/attention/indexed.py):
+            # no kernel walks the pages in runs, so the allocator owes
+            # it none and the walk's counters read nothing
+            self._decode_attn_path = "gather"
+            self._decode_attn_reason = "selected-rows readers"
+        elif requested != "pallas":
             self._decode_attn_path = "gather"
             self._decode_attn_reason = "configured"
         else:
@@ -1440,6 +1506,12 @@ class InferenceEngine:
 
     # ------------------------------------------- live KV migration (16)
     def _refuse_migration_with_state(self):
+        if self.indexer is not None:
+            raise NotImplementedError(
+                f"{type(self.model_config).__name__} keeps an indexer key "
+                f"a token that a MigrationRecord does not carry: a "
+                f"request of this family cannot be exported, imported "
+                f"or migrated")
         if self.latent:
             raise NotImplementedError(
                 f"{type(self.model_config).__name__} keeps latent rows "
@@ -2135,6 +2207,11 @@ class InferenceEngine:
             worked, static = self._moe_prefill_rows
             counters.update(expert_rows_worked=worked,
                             expert_rows_sorted=static)
+        if self.indexer is not None:
+            counters.update(
+                live_tokens=sum(st + n for _, _, st, n, _ in spans),
+                **self._selection_counters(
+                    [(st, n) for _, _, st, n, _ in spans]))
         with self._span("serve/chunk/build"):
             ids = np.zeros((bb, ct), np.int32)
             lengths = np.ones((bb,), np.int32)
@@ -2427,6 +2504,9 @@ class InferenceEngine:
                     # a group-limited router: the rows whose kept
                     # groups include a group held here
                     counters["group_rows"] = self._moe_counts[2]
+            if self.indexer is not None:
+                counters.update(self._selection_counters(
+                    [(p, 1) for p in poss]))
         with self._span("serve/decode/build"):
             poss_a, temps_a, keys_a = self._decode_arrays(
                 sids, poss, temps, seeds)
@@ -2475,6 +2555,27 @@ class InferenceEngine:
 
         self._issue("serve/decode", counters, program, nxt, arrive)
         return True
+
+    def _selection_counters(self, spans):
+        """What a dispatch's indexer scores and its readers read, as
+        sums over its real rows (``spans``: (first position, tokens) a
+        row): the (query, key) pairs scored (a query at position p
+        scores p + 1 keys: a decode's are its rows' live tokens), the
+        rows its queries select (at most ``topk`` each) and
+        ``dense_rows``, the rows whose whole context is at most
+        ``topk`` (every query of theirs selects everything)."""
+        topk = self.indexer[1]
+        first = np.asarray([s for s, _ in spans], np.int64)
+        n = np.asarray([c for _, c in spans], np.int64)
+        last = first + n
+        # positions first..last-1: sum of (p + 1), and of min(p + 1, topk)
+        tri = lambda m: m * (m + 1) // 2
+        capped = lambda m: tri(np.minimum(m, topk)) \
+            + np.maximum(m - topk, 0) * topk
+        return dict(
+            scored_tokens=int((tri(last) - tri(first)).sum()),
+            selected_tokens=int((capped(last) - capped(first)).sum()),
+            dense_rows=int((last <= topk).sum()))
 
     def _decode_arrays(self, sids, poss, temps, seeds):
         """The decode dispatch's per-row host arrays over the full slot

@@ -74,6 +74,24 @@ over zero lanes adds nothing). :class:`LatentPoolSpec` is built from the
 model's ``latent_geometry``; pages, block tables, the allocator and
 admission are the pair's.
 
+**The indexer leaf (a THIRD leaf of the pair's tree)** — a family
+whose attention reads a learned selection of its tokens
+(``models/keye_vl2.py``) keeps, beside a token's keys and values, ONE
+indexer key a token a layer, TWO tokens a pool row: ``(layers,
+num_pages, page_size / 2, 2 x index_width)``, token ``o`` of a page in
+row ``o // 2`` at lanes ``(o % 2) * index_width`` on (the same bytes as
+``(page_size, index_width)`` row-major; at the published width of 64 a
+row is one whole 128-lane tile, where a 64-lane row is held padded to
+128 and re-laid by the compiler around every program: 28 ms of a 65 ms
+decode step, my chip run, PR 55). Written through the same
+``PagedWriteIndex`` as the pair
+(``ops/attention/indexed.write_index_keys``), mapped by the same block
+tables and freed with the same pages (the allocator moves page ids and
+knows no leaf).
+:class:`PagedKVSpec` carries its width (``index_width``, from the
+model's ``indexer_geometry``; 0: no such leaf) and the tree is
+:class:`IndexedPairCache`.
+
 Writes happen inside the model forwards via
 :func:`deepspeed_tpu.models.gpt2.write_kv_cache` (dense) /
 :func:`deepspeed_tpu.ops.attention.page_pool.write_paged_kv_cache`
@@ -94,7 +112,7 @@ __all__ = ["KVCacheSpec", "cache_spec_for", "init_kv_cache",
            "PageAllocator", "StatePoolSpec", "state_pool_spec_for",
            "init_state_pool", "state_pool_bytes", "PagedStateCache",
            "PagedTailCache", "LatentStateCache", "LatentPoolSpec",
-           "latent_row_lanes"]
+           "latent_row_lanes", "IndexedPairCache"]
 
 
 class KVCacheSpec(NamedTuple):
@@ -182,11 +200,20 @@ class PagedKVSpec(NamedTuple):
     pages_per_seq: int
     dtype: Any = jnp.bfloat16
     quant_block: int = 0  # scale block over head_dim (0 = head_dim)
+    # lanes of the indexer key a token a layer holds beside its keys
+    # and values (module docstring); 0: the tree has no such leaf
+    index_width: int = 0
 
     @property
     def shape(self) -> Tuple[int, int, int, int]:
         return (self.num_layers, self.num_pages, self.page_size,
                 self.kv_heads * self.head_dim)
+
+    @property
+    def index_shape(self) -> Tuple[int, int, int, int]:
+        """Two tokens a row (module docstring)."""
+        return (self.num_layers, self.num_pages, self.page_size // 2,
+                2 * self.index_width)
 
     @property
     def quantized(self) -> bool:
@@ -276,11 +303,19 @@ def paged_spec_for(model_config, num_pages: int, page_size: int,
     # a trunk of mixed layer kinds pages only its softmax layers
     layers = getattr(model_config, "kv_cache_layers", None) or \
         model_config.num_layers
+    indexer = getattr(model_config, "indexer_geometry", None)
+    if indexer is not None and quantized:
+        raise ValueError("the indexer leaf has no int8 form: its keys "
+                         "are scored as they are held")
+    if indexer is not None and page_size % 2:
+        raise ValueError(f"the indexer leaf holds two tokens a row: "
+                         f"page_size ({page_size}) has to be even")
     return PagedKVSpec(num_layers=layers,
                        num_pages=num_pages, page_size=page_size,
                        kv_heads=kv_heads, head_dim=head_dim,
                        pages_per_seq=pages_for(max_len, page_size),
-                       dtype=dtype, quant_block=block)
+                       dtype=dtype, quant_block=block,
+                       index_width=indexer[0] if indexer else 0)
 
 
 def init_paged_kv_cache(spec: PagedKVSpec):
@@ -298,18 +333,25 @@ def init_paged_kv_cache(spec: PagedKVSpec):
         # read unmasked, and quantized writes always store a scale > 0
         pools = pools + (jnp.zeros(spec.scale_shape, jnp.float32),
                          jnp.zeros(spec.scale_shape, jnp.float32))
+    if spec.index_width:
+        return IndexedPairCache(*pools,
+                                jnp.zeros(spec.index_shape, spec.dtype))
     return pools
 
 
 def paged_kv_bytes(spec: PagedKVSpec) -> int:
-    """Total bytes of the paged pool tree — int8 payload + fp32 scales
-    when quantized (the KV lever of ``quant_serving_bytes``); a latent
-    pool's one leaf."""
+    """Total bytes of the paged pool tree, EVERY leaf that
+    :func:`init_paged_kv_cache` builds: int8 payload + fp32 scales when
+    quantized (the KV lever of ``quant_serving_bytes``); a latent
+    pool's one leaf; the indexer leaf beside the pair."""
     if isinstance(spec, LatentPoolSpec):
         return int(np.prod(spec.shape)) * jnp.dtype(spec.dtype).itemsize
     total = _pair_bytes(spec)
     if spec.quantized:
         total += 2 * int(np.prod(spec.scale_shape)) * 4
+    if spec.index_width:
+        total += int(np.prod(spec.index_shape)) * jnp.dtype(
+            spec.dtype).itemsize
     return total
 
 
@@ -397,6 +439,16 @@ class LatentStateCache(NamedTuple):
     pool: Any
     state: Any
     tails: Any
+
+
+class IndexedPairCache(NamedTuple):
+    """The cache tree of a family whose attention reads a learned
+    selection of its tokens (``models/keye_vl2.py``): the page pools of
+    keys and values and, a third leaf over the same pages, ONE indexer
+    key a token a layer (module docstring)."""
+    keys: Any
+    values: Any
+    index_keys: Any
 
 
 def init_state_pool(spec: StatePoolSpec):

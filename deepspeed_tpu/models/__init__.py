@@ -20,3 +20,5 @@ from deepspeed_tpu.models.axk1 import (
     AXK1Config, axk1_forward, init_axk1_params)
 from deepspeed_tpu.models.lfm2 import (
     LFM2Config, init_lfm2_params, lfm2_forward)
+from deepspeed_tpu.models.keye_vl2 import (
+    KeyeVL2Config, init_keye_vl2_params, keye_vl2_forward)

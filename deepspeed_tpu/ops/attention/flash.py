@@ -215,7 +215,8 @@ def _stream_kv_wait(k_ref, v_ref, kbuf, vbuf, ksem, vsem, i, row):
 
 
 def _fwd_kernel(*refs, sm_scale, block_k, causal, seq_k, block_q,
-                has_mask, dropout_rate, stream=False, q_per_kv=1):
+                has_mask, dropout_rate, stream=False, q_per_kv=1,
+                mask_rows=False):
     if stream:
         refs, (kbuf, vbuf, ksem, vsem) = refs[:-4], refs[-4:]
     q_ref, k_ref, v_ref, mask_ref, seed_ref, (o_ref, lse_ref) = \
@@ -261,7 +262,11 @@ def _fwd_kernel(*refs, sm_scale, block_k, causal, seq_k, block_q,
             q, k, (((1,), (0 if stream else 1,)), ((), ())),
             preferred_element_type=jnp.float32)
         s = s * sm_scale
-        if mask_ref is not None:
+        if mask_ref is not None and mask_rows:
+            # a mask a (query, key): this query block's rows of it
+            s += mask_ref[0, :, pl.ds(i * block_k, block_k)].astype(
+                jnp.float32)
+        elif mask_ref is not None:
             s += mask_ref[0, 0, pl.ds(i * block_k, block_k)][None, :]
         if causal or dropout_rate > 0.0:
             q_idx, k_idx = _tile_idx(qb * block_q, i * block_k,
@@ -631,6 +636,10 @@ def _seed_spec():
 
 def _flash_fwd(q, k, v, mask, causal, sm_scale, interpret,
                dropout_rate=0.0, seed=None):
+    """``mask``: None, an additive KEY mask (B, 1, 1, Sk) float32, or an
+    additive mask a (query, key) (B, 1, Sq, Sk) in any float type,
+    shared by the heads (the selected-rows reader of a chunk,
+    ``ops/attention/indexed.py``)."""
     b, h, sq, d = q.shape
     hkv = k.shape[1]
     G = h // hkv       # GQA group size (1 = MHA); validated in the API
@@ -642,11 +651,13 @@ def _flash_fwd(q, k, v, mask, causal, sm_scale, interpret,
     vr = v.reshape(b * hkv, sk, d)
 
     stream = _use_stream(sq, sk)
+    # a row a query: told from a key mask by its shape alone
+    mask_rows = mask is not None and mask.shape[2] == sq and sq > 1
     kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, block_k=bk,
                                causal=causal, seq_k=sk, block_q=bq,
                                has_mask=mask is not None,
                                dropout_rate=dropout_rate, stream=stream,
-                               q_per_kv=G)
+                               q_per_kv=G, mask_rows=mask_rows)
     if stream:
         # streamed operands live unblocked in HBM pre-tiled TRANSPOSED
         # to (row, n_blocks, D, block) so each DMA moves whole trailing
@@ -664,7 +675,11 @@ def _flash_fwd(q, k, v, mask, causal, sm_scale, interpret,
         kv_spec,
     ]
     args = [qr, kr, vr]
-    if mask is not None:
+    if mask_rows:
+        in_specs.append(pl.BlockSpec((1, bq, sk),
+                                     lambda i, j: (i // h, j, 0)))
+        args.append(mask.reshape(b, sq, sk))
+    elif mask is not None:
         # additive key mask (B, 1, 1, Sk) -> (B, 1, Sk); shared across heads
         maskr = mask.reshape(b, 1, sk)
         in_specs.append(pl.BlockSpec((1, 1, sk), lambda i, j: (i // h, 0, 0)))

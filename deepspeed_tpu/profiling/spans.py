@@ -82,6 +82,13 @@ DEVICE_SCOPES = (
     # convolution and the tail; an attention layer's norm a head and
     # rotation of queries and keys, between `attn_proj` and `attn_core`
     "conv_proj", "conv_core", "attn_norm_rope",
+    # attention over a learned selection (models/keye_vl2.py,
+    # ops/attention/indexed.py): the indexer's projections and its
+    # scores of every token a query may see; the exact choice of the
+    # best; the softmax over the chosen rows (decode: read by (page,
+    # offset); a chunk: its own rows under the selection's mask); a
+    # chunk's part over the rows earlier chunks wrote
+    "indexer", "select", "sparse_attn", "sparse_prefix",
 )
 
 # host phase spans (TraceAnnotation), each parent before its children

@@ -1541,6 +1541,97 @@ def test_lfm2_serving_programs_keep_pages_and_tails_in_place(monkeypatch,
           memory.generated_code_size_in_bytes)
 
 
+@pytest.mark.parametrize("program", ["decode", "chunk_1", "chunk_2"])
+def test_keye_vl2_serving_programs_keep_three_leaves_in_place(monkeypatch,
+                                                              program):
+    """The benchmark configuration's three programs at the published
+    widths (decode at 17 rows over the table's 69,632 positions; a chunk
+    of 2,048 at 1 and at 2 rows, any start), weights held in bfloat16,
+    the cache tree donated: keys, values AND the indexer leaf (two
+    tokens a 128-lane row) are aliased through the six layers, and no
+    copy of the indexer leaf is made around the program (a 64-lane row
+    was re-laid twice a layer: 28 ms of a decode step, my chip run, PR
+    55). Decode runs no kernel: a sort a layer and rows read by (page,
+    offset); a chunk runs the flash kernel TWICE a layer (its own rows,
+    and a block of the prefix a loop turn) under a mask a (query, key)
+    and three grouped products an expert layer, and holds no (heads x
+    chunk x table) scores."""
+    import json
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(__file__), "..", "..", "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from families import keye_vl2 as family
+    from deepspeed_tpu.inference.kv_cache import (IndexedPairCache,
+                                                  paged_kv_bytes,
+                                                  paged_spec_for)
+    from deepspeed_tpu.models import keye_vl2 as kv2
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(flash, "_use_pallas", lambda: True)
+    with open(os.path.join(bench, "configs",
+                           "keye-vl-2.0-30b-a3b.json")) as f:
+        config = json.load(f)
+    model = family.serve_model_of(config)
+    inference = config["serve"]["inference"]
+    rows = inference["max_batch_size"] + 1
+    chunk = inference["chunked_prefill"]["chunk_tokens"]
+    pages = paged_spec_for(model, inference["paged_kv"]["num_pages"],
+                           inference["paged_kv"]["page_size"],
+                           inference["max_seq_len"])
+    assert pages.index_shape[2:] == (8, 128)
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: kv2.init_keye_vl2_params(
+            model, jax.random.PRNGKey(0))))
+    cache = IndexedPairCache(_spec(pages.shape), _spec(pages.shape),
+                             _spec(pages.index_shape))
+    ints = lambda *shape: _spec(shape, jnp.int32)
+
+    def decode(params, cache, toks, positions, tables):
+        logits, cache, counts = kv2.keye_vl2_forward(
+            params, model, toks[:, None], kv_cache=cache,
+            cache_position=positions, block_tables=tables,
+            active=tables[:, 0] > 0, with_counts=True)
+        return jnp.argmax(logits[:, 0], -1), counts, cache
+
+    def prefill(params, cache, ids, lengths, positions, tables, slots):
+        logits, cache, counts = kv2.keye_vl2_forward(
+            params, model, ids, kv_cache=cache, cache_position=positions,
+            block_tables=tables, lengths=lengths, slots=slots,
+            with_counts=True)
+        return jnp.argmax(logits[:, 0], -1), counts, cache
+
+    if program == "decode":
+        fn, args = decode, (ints(rows), ints(rows),
+                            ints(rows, pages.pages_per_seq))
+        kernels = 0
+    else:
+        b = int(program[-1])
+        fn, args = prefill, (ints(b, chunk), ints(b), ints(b),
+                             ints(b, pages.pages_per_seq), ints(b))
+        kernels = 6 * (2 + 3)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    memory = compiled.memory_analysis()
+    held = paged_kv_bytes(pages)
+    assert memory.alias_size_in_bytes >= held
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+    # the indexer leaf is never copied whole (nor re-laid)
+    leaf = "bf16[%d,%d,8,128]" % pages.index_shape[:2]
+    assert not re.search(r"= %s\S* copy\(" % re.escape(leaf), text)
+    # no score of a chunk's queries, a head, against the whole table
+    assert not re.search(r"\[\d+,(16|32),%d,69632\]" % chunk, text)
+    # the weights as they are held (659M parameters in bfloat16) and the
+    # three leaves of the pool
+    assert abs(memory.argument_size_in_bytes - held - 2 * 659_190_016) < 5e7
+    assert memory.temp_size_in_bytes < (2.0e9 if program == "decode"
+                                        else 2.2e9 * int(program[-1]))
+    print(program, "temp", memory.temp_size_in_bytes, "code",
+          memory.generated_code_size_in_bytes)
+
+
 def test_smallthinker_train_step_compiles_at_the_cut_widths(monkeypatch):
     """`deepspeed_tpu.initialize` + the ONE compiled `_micro_step`
     (ZeRO-2, bf16, Adam, clipping) of the benchmark's configuration at

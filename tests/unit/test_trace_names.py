@@ -275,6 +275,53 @@ def test_a_short_convolution_hybrids_programs_carry_their_scopes(
     engine.close()
 
 
+@pytest.mark.parametrize("program", ["chunk", "decode"])
+def test_a_learned_selections_programs_carry_their_scopes(monkeypatch,
+                                                           program):
+    """models/keye_vl2.py: both programs carry the indexer's scores, the
+    exact choice and the softmax over the chosen (`indexer`, `select`,
+    `sparse_attn`), a chunk also the prefix's part (`sparse_prefix`);
+    NO `moe_shared` and no reader of another family; neither opens a
+    name outside the registry."""
+    from deepspeed_tpu.models import keye_vl2 as kv2
+    cfg = kv2.KeyeVL2Config(
+        vocab_size=64, hidden_size=32, num_layers=2, num_heads=2,
+        num_kv_heads=1, head_dim=16, moe_intermediate_size=16,
+        num_experts=4, experts_per_token=2, mrope_section=(2, 3, 3),
+        indexer_num_heads=2, indexer_head_dim=8, indexer_topk=6,
+        max_position_embeddings=64)
+    engine = InferenceEngine(
+        cfg, kv2.init_keye_vl2_params(cfg, jax.random.PRNGKey(0)),
+        {"max_batch_size": 2, "prompt_buckets": [16], "batch_buckets": [1],
+         "max_seq_len": 48,
+         "chunked_prefill": {"enabled": True, "chunk_tokens": 16},
+         "paged_kv": {"prefix_cache": False}})
+    rows, pps = engine._rows, engine.paged_spec.pages_per_seq
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)      # noqa: E731
+    keys = lambda n: jnp.zeros((n, 2), jnp.uint32)        # noqa: E731
+    temps = lambda n: jnp.zeros((n,), jnp.float32)        # noqa: E731
+    want = {"attn_proj", "attn_norm_rope", "indexer", "select",
+            "sparse_attn", "kv_write", "moe_route", "moe_dispatch",
+            "moe_experts", "lm_head"}
+    if program == "decode":
+        fn, args = engine._decode_paged_impl, (
+            i32(rows), i32(rows), i32(rows, pps), keys(rows), temps(rows))
+        want |= {"sample"}
+    else:
+        fn, args = engine._prefill_state_impl, (
+            i32(1, 16), i32(1) + 9, i32(1) + 16, i32(1, pps), keys(1),
+            temps(1), i32(1))
+        want |= {"sparse_prefix"}
+    with _opened_scopes(monkeypatch) as opened:
+        text = jax.jit(fn).lower(engine.params, engine._cache,
+                                 *args).as_text(debug_info=True)
+    assert set(opened) <= set(DEVICE_SCOPES)
+    assert want <= _scopes_in(text), want - _scopes_in(text)
+    assert not {"moe_shared", "attn_cached", "attn_core", "kv_gather",
+                "mla_prefix"} & _scopes_in(text)
+    engine.close()
+
+
 def test_scopes_leave_the_program_set_and_recompiles_alone(monkeypatch):
     def programs():
         engine = _serve_engine()
