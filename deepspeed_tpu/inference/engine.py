@@ -146,6 +146,8 @@ from deepspeed_tpu.models.solar_open2 import (SolarOpen2Config,
                                               solar_open2_forward,
                                               solar_open2_param_specs)
 from deepspeed_tpu.ops.attention.flash import NEG_INF
+from deepspeed_tpu.ops.attention.indexed import \
+    block_pages as indexer_block_pages
 from deepspeed_tpu.ops.attention.paged import (block_pages, live_pages,
                                                paged_decode_supported)
 from deepspeed_tpu.parallel.mesh import axis_size, build_mesh
@@ -666,7 +668,7 @@ class InferenceEngine:
                 # the Pallas reader copies a block of consecutive pages
                 # with one descriptor: requests get their pages in runs
                 # of a block (the gather reader takes any order)
-                run_pages = (block_pages(ps, latent=self.latent)
+                run_pages = (self._walk_pages(ps)
                              if self._decode_attn_path == "pallas" else 1)
                 allocator = PageAllocator(num_pages, ps,
                                           prefix_cache=pk["prefix_cache"],
@@ -1067,10 +1069,13 @@ class InferenceEngine:
         requested = pk["attn_kernel"]
         if self.indexer is not None:
             # the family's readers are its own (ops/attention/indexed.py):
-            # no kernel walks the pages in runs, so the allocator owes
-            # it none and the walk's counters read nothing
-            self._decode_attn_path = "gather"
-            self._decode_attn_reason = "selected-rows readers"
+            # the indexer walks each row's live pages of its key leaf in
+            # runs (interpreted off the chip), so the allocator owes it
+            # runs of ITS block and the walk's counters count that walk;
+            # keys and values are read by row. There is no gather form
+            self._decode_attn_path = "pallas"
+            self._decode_attn_reason = (
+                "indexer page walk, selected-rows readers")
         elif requested != "pallas":
             self._decode_attn_path = "gather"
             self._decode_attn_reason = "configured"
@@ -1111,6 +1116,13 @@ class InferenceEngine:
         pps = self.paged_spec.pages_per_seq
         widths = [int(b) for b in pk["decode_page_buckets"] if b < pps]
         self._decode_page_buckets = tuple(widths) + (pps,)
+
+    def _walk_pages(self, page_size: int) -> int:
+        """Pages a loop turn of the family's decode walk copies: the
+        run its allocator hands out and its spans count turns by."""
+        if self.indexer is not None:
+            return indexer_block_pages(page_size)
+        return block_pages(page_size, latent=self.latent)
 
     def _resolve_context_parallel(self):
         """Decide once, at init, whether chunk dispatches for prompts
@@ -2475,7 +2487,7 @@ class InferenceEngine:
                 # the gather reader walks none (it reads the table's
                 # whole width)
                 ps = self.paged_spec.page_size
-                per_turn = block_pages(ps, latent=self.latent)
+                per_turn = self._walk_pages(ps)
                 if self._decode_attn_path == "pallas":
                     walks = live_pages(np.asarray(poss, np.int64), ps)
                     idle = self._rows - len(sids)

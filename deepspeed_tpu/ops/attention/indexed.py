@@ -6,10 +6,14 @@ EXACTLY (ties to the lower position), and the softmax runs over those
 alone. Two readers, one rule:
 
 - :func:`decode_attention`, one query a row: every live indexer key is
-  read in table order and scored, the choice is ``lax.top_k`` over the
-  row's scores (whose ties go to the lower index), and the chosen
-  tokens' keys and values are read BY ROW, ``pool[layer, page,
-  offset]``: ``topk`` rows a pool a layer, however long the context.
+  read and scored by ONE Pallas kernel that walks the row's live pages
+  of the leaf, a block of pages a loop turn and a run of consecutive
+  pool ids one copy (:func:`indexer_decode_keys`; the XLA scorer over
+  the gathered table, :func:`_stripe_scores`, stays as the tests'
+  reference), the choice is ``lax.top_k`` over the row's scores (whose
+  ties go to the lower index), and the chosen tokens' keys and values
+  are read BY ROW, ``pool[layer, page, offset]``: ``topk`` rows a pool
+  a layer, however long the context.
 - :func:`chunk_attention`, a chunk's queries over the rows earlier
   chunks left in the pool and over its own: the scores are kept a block
   of keys at a time as int32 keys that order as the scores do, a
@@ -39,13 +43,23 @@ until a compaction is found that beats it; both forms share
 dense oracle.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+
+try:
+    from jax.experimental.pallas import tpu as pltpu
+except ImportError:  # pragma: no cover
+    pltpu = None
 
 from deepspeed_tpu.ops.attention import flash
 from deepspeed_tpu.ops.attention.flash import NEG_INF
 from deepspeed_tpu.ops.attention.page_pool import prefix_block_rows
+from deepspeed_tpu.ops.attention.paged import (_block_runs,
+                                               _compiler_params, live_pages)
 from deepspeed_tpu.profiling.spans import scope
 
 # what no score's key falls to: a position a query may not see
@@ -101,19 +115,30 @@ def write_index_keys(pool, layer: int, ki, index):
             token_rows, pool)
 
 
+def _standing_twice(qi, wi):
+    """One query a row against pool rows of TWO tokens: ``qi`` (B,
+    heads, d), ``wi`` (B, heads) -> the queries (B, 2 heads, 2 d), the
+    first ``heads`` against a row's first half (zeros over its second)
+    and the others against its second, and their weights (B, 2 heads),
+    so that a row is read once and never re-laid."""
+    d = qi.shape[-1]
+    half = lambda left, right: jnp.pad(qi, ((0, 0), (0, 0), (left, right)))
+    return (jnp.concatenate([half(0, d), half(d, 0)], axis=1),
+            jnp.concatenate([wi, wi], axis=1))
+
+
 def _stripe_scores(qi, wi, rows):
     """:func:`indexer_scores` of ONE query a row against a stripe of
     pool rows as they are held, two tokens a row: ``qi`` (B, heads, d),
     ``wi`` (B, heads), ``rows`` (B, R, 2 d) -> (B, 2 R) float32 in
-    position order. The queries stand twice, against a row's first half
-    and against its second, so that the stripe is read once and never
-    re-laid."""
-    B, H, d = qi.shape
-    half = lambda left, right: jnp.pad(qi, ((0, 0), (0, 0), (left, right)))
-    s = jnp.einsum("bhd,brd->bhr",
-                   jnp.concatenate([half(0, d), half(d, 0)], axis=1), rows,
+    position order (the queries :func:`_standing_twice`). What
+    :func:`indexer_decode_keys` computes a block at a time: its
+    reference in the tests, called by no program."""
+    B, H, _ = qi.shape
+    q, w = _standing_twice(qi, wi)
+    s = jnp.einsum("bhd,brd->bhr", q, rows,
                    preferred_element_type=jnp.float32)
-    s = jax.nn.relu(s) * jnp.concatenate([wi, wi], axis=1)[..., None]
+    s = jax.nn.relu(s) * w[..., None]
     total = jnp.stack([jnp.sum(s[:, :H], axis=1),
                        jnp.sum(s[:, H:], axis=1)], axis=-1)
     return _one_zero(total).reshape(B, -1)
@@ -245,6 +270,207 @@ def chunk_attention(q, k, v, qi, wi, ki, cache_position, prefix_keys,
     return o.astype(q.dtype)
 
 
+# Tokens a loop turn of the decode indexer's walk, whole pages. The
+# leaf's page is small (16 tokens of 128 B: 2 KB) and a turn costs 0.3
+# us before its first byte, so a turn has to hold many pages. Swept
+# where the rows are long (17 rows of a mean 35k live tokens, 6 layers,
+# the tables out of the churned allocator, every turn a run; ms the six
+# calls alone, 0.23 of it the planes' interleave, my chip runs, PR 56):
+# 8 pages a turn 7.80, 32: 2.69, 64: 1.61, 128: 1.13, 256: 0.97,
+# beside 9.30 for the XLA gather scorer; a shuffled table (every block
+# page by page) 7.72 at 128. At 128 a turn is as much its bytes as its
+# own cost (256 KB: 0.31 us of 0.53), 256 buys 0.16 ms of a 28.8 ms
+# step, and an extent of 256 pages is a ninth of a request: 128. One
+# constant, chosen by nothing a user sets, and the allocator's run for
+# the family (``block_pages``).
+_INDEX_BLOCK_TOKENS = 2048
+
+
+def block_pages(page_size: int) -> int:
+    """Pages of ``page_size`` tokens that one loop turn of
+    :func:`indexer_decode_keys` copies and scores: whole pages, at
+    least one. The run an allocator owes the family."""
+    return max(1, _INDEX_BLOCK_TOKENS // page_size)
+
+
+def _indexer_decode_kernel(layer_ref, tables_ref, pos_ref, runs_ref, q_ref,
+                           w_ref, pool_ref, o_ref, buf, sem, base_ref):
+    """One row's program: walk the row's LIVE pages of the indexer leaf,
+    ``buf.shape[1]`` pages a loop turn through a double buffer, and
+    score the landed block as :func:`_stripe_scores` +
+    :func:`score_keys` do, into block ``blk`` of ``o_ref`` ``(1, blocks,
+    2, block rows)``: plane 0 the rows' first halves (the even
+    positions), plane 1 their second. What the walk never reads stays
+    ``_NO_KEY``. The copying rule is ``paged._decode_kernel``'s: a block
+    whose live pages are consecutive pool ids (``runs_ref``) is one
+    copy, a walk's last short block the binary pieces of its count, any
+    other block page by page; the rows' blocks are one stream through
+    the two slots, a walk's last turn issuing the next row's first
+    block. A copy indexes the leaf's LEADING dimensions: a page of 8
+    rows of bfloat16 is one ``T(8,128)(2,1)`` tile of the leaf as XLA
+    lays it and half of Mosaic's 16-row tile, so a view of the leaf as
+    rows would cut tiles at an odd page, and this cuts none."""
+    b = pl.program_id(0)
+    last_seq = pl.num_programs(0) - 1
+    layer = layer_ref[0]
+    pos = pos_ref[b]
+    per_turn, page_rows, lanes = buf.shape[1:]
+    page_size = 2 * page_rows
+    rows = per_turn * page_rows
+    pool_pages = pool_ref.shape[1]
+    heads = q_ref.shape[1] // 2
+
+    def _pages(seq):
+        return live_pages(pos_ref[seq], page_size)
+
+    num_blk = (_pages(b) + per_turn - 1) // per_turn
+
+    def _for_live_pages(seq, blk, slot, fn):
+        """``fn(copy)`` for every copy of the live pages of row
+        ``seq``'s block ``blk``, which lands in ``slot``."""
+        live = jnp.minimum(_pages(seq) - blk * per_turn, per_turn)
+
+        def _by_page():
+            def _page(j, _):
+                # (a live page lies inside the table)
+                page = tables_ref[seq, blk * per_turn + j]
+                fn(pltpu.make_async_copy(pool_ref.at[layer, page],
+                                         buf.at[slot, j], sem.at[slot]))
+                return _
+            jax.lax.fori_loop(0, live, _page, 0)
+        if per_turn == 1:
+            return _by_page()
+
+        def _as_run():
+            first = tables_ref[seq, blk * per_turn]
+
+            def _copy(done, n):
+                fn(pltpu.make_async_copy(
+                    pool_ref.at[layer, pl.ds(first + done, n)],
+                    buf.at[slot, pl.ds(done, n)], sem.at[slot]))
+
+            def _pieces():
+                for bit in reversed(range(
+                        min(per_turn - 1, pool_pages).bit_length())):
+                    pl.when((live & (1 << bit)) != 0)(
+                        lambda bit=bit: _copy(
+                            (live >> (bit + 1)) << (bit + 1), 1 << bit))
+            if per_turn > pool_pages:        # (no pool holds such a run)
+                return _pieces()
+            jax.lax.cond(live == per_turn, lambda: _copy(0, per_turn),
+                         _pieces)
+        jax.lax.cond(runs_ref[seq, blk] != 0, _as_run, _by_page)
+
+    def _start(seq, blk, slot):
+        _for_live_pages(seq, blk, slot, lambda c: c.start())
+
+    @pl.when(b == 0)
+    def _first_walk():
+        base_ref[0] = 0
+        _start(0, 0, 0)
+    base = base_ref[0]
+    base_ref[0] = jax.lax.rem(base + num_blk, 2)
+
+    # everything the walk does not reach
+    o_ref[...] = jnp.full(o_ref.shape, _NO_KEY, jnp.int32)
+    q, w = q_ref[0], w_ref[0]
+    # position of (plane, row) in a block: 2 row + plane
+    at = 2 * jax.lax.broadcasted_iota(jnp.int32, (2, rows), 1) \
+        + jax.lax.broadcasted_iota(jnp.int32, (2, rows), 0)
+
+    def body(blk, _):
+        slot = jax.lax.rem(base + blk, 2)
+        more = blk + 1 < num_blk
+
+        @pl.when(more | (b < last_seq))
+        def _prefetch_next():
+            # this walk's next block, else the next walk's first
+            _start(jnp.where(more, b, b + 1), jnp.where(more, blk + 1, 0),
+                   1 - slot)
+        _for_live_pages(b, blk, slot, lambda c: c.wait())
+        s = jax.lax.dot_general(
+            q, buf[slot].reshape(rows, lanes), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)       # (2 heads, rows)
+        s = jax.nn.relu(s) * w
+        total = _one_zero(jnp.concatenate(
+            [jnp.sum(s[:heads], axis=0, keepdims=True),
+             jnp.sum(s[heads:], axis=0, keepdims=True)], axis=0))
+        # (a short block's unread rows lie past ``pos``)
+        o_ref[0, blk] = score_keys(total, 2 * blk * rows + at <= pos)
+        return _
+
+    jax.lax.fori_loop(0, num_blk, body, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "block_tokens"))
+def _indexer_decode_call(qi, wi, pool, block_tables, cache_position, layer,
+                         interpret, block_tokens):
+    """The ``pallas_call`` of :func:`indexer_decode_keys`: the stacked
+    leaf pinned in HBM and indexed by ``layer`` from SMEM beside the
+    tables, the positions and the blocks that are runs (every layer's
+    call is the same kernel). ``block_tokens`` is the tokens a loop turn
+    copies: whole pages."""
+    B, H, _ = qi.shape
+    page_rows, lanes = pool.shape[2:]
+    ps = 2 * page_rows
+    per_turn = block_tokens // ps
+    blocks = -(-block_tables.shape[1] // per_turn)
+    rows = per_turn * page_rows
+    q, w = _standing_twice(qi, wi)
+    q, w = q.astype(pool.dtype), w[..., None].astype(jnp.float32)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, 2 * H, lanes), lambda b, *_: (b, 0, 0)),
+                  pl.BlockSpec((1, 2 * H, 1), lambda b, *_: (b, 0, 0)),
+                  pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=pl.BlockSpec((1, blocks, 2, rows),
+                               lambda b, *_: (b, 0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, per_turn, page_rows, lanes),
+                                   pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SMEM((1,), jnp.int32)],
+    )
+    planes = pl.pallas_call(
+        _indexer_decode_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, blocks, 2, rows), jnp.int32),
+        interpret=interpret,
+        compiler_params=_compiler_params(interpret),
+    )(layer, block_tables, cache_position,
+      _block_runs(block_tables, cache_position, ps, per_turn,
+                  pool.shape[1]), q, w, pool)
+    # the two planes interleaved into position order: ONE transpose
+    return planes.transpose(0, 1, 3, 2).reshape(B, -1)[
+        :, :block_tables.shape[1] * ps]
+
+
+def indexer_decode_keys(qi, wi, index_pool, layer: int, block_tables,
+                        cache_position, interpret=None):
+    """One query a row scored against the row's LIVE indexer keys, read
+    off the stacked leaf ``(layers, pages, page_size / 2, 2 d)`` by a
+    Pallas walk of the row's ``cache_position // page_size + 1`` pages:
+    ``qi`` (B, heads, d), ``wi`` (B, heads) float32 -> ``(B, table
+    positions) int32`` keys in position order, what
+    ``score_keys(_stripe_scores(...))`` gives over the gathered table,
+    ``_NO_KEY`` past ``cache_position``. Off the chip the kernel runs
+    ``interpret=True``, as every walk does."""
+    assert qi.ndim == 3 and index_pool.ndim == 4 and \
+        index_pool.shape[-1] == 2 * qi.shape[-1], (qi.shape,
+                                                   index_pool.shape)
+    assert block_tables.shape[0] == qi.shape[0] and \
+        cache_position.shape == (qi.shape[0],), (
+            block_tables.shape, cache_position.shape)
+    if interpret is None:
+        interpret = not flash._use_pallas()
+    ps = 2 * index_pool.shape[2]
+    return _indexer_decode_call(qi, wi, index_pool,
+                                block_tables.astype(jnp.int32),
+                                cache_position.astype(jnp.int32),
+                                jnp.full((1,), layer, jnp.int32),
+                                bool(interpret), block_pages(ps) * ps)
+
+
 def decode_attention(q, pools, index_pool, layer: int, block_tables,
                      cache_position, qi, wi, topk: int, sm_scale,
                      probe=None):
@@ -252,9 +478,11 @@ def decode_attention(q, pools, index_pool, layer: int, block_tables,
     (already written at this row's position). ``q`` (B, heads, hd);
     ``pools`` the (keys, values) pair ``(layers, pages, page_size,
     lanes)`` and ``index_pool`` the indexer leaf, two tokens a row
-    (:func:`write_index_keys`); ``qi`` (B, ih, d), ``wi`` (B, ih). The indexer reads every page the table names,
-    in table order; the readers of keys and values read ``min(topk,
-    table positions)`` token rows each, by (page, offset). Returns (B,
+    (:func:`write_index_keys`); ``qi`` (B, ih, d), ``wi`` (B, ih). The
+    indexer walks each row's live pages of its leaf
+    (:func:`indexer_decode_keys`); the readers of keys and values read
+    ``min(topk, table positions)`` token rows each, by (page, offset).
+    Returns (B,
     heads, hd) in ``q``'s dtype. ``probe`` (a list, eager calls only)
     receives (the chosen positions, which of them count)."""
     kpool, vpool = pools
@@ -263,11 +491,8 @@ def decode_attention(q, pools, index_pool, layer: int, block_tables,
     positions = block_tables.shape[1] * ps
     hkv = kpool.shape[-1] // hd
     with scope("indexer"):
-        rows = index_pool[layer, block_tables].reshape(
-            B, positions // 2, index_pool.shape[-1])
-        keys = score_keys(_stripe_scores(qi, wi, rows),
-                          jnp.arange(positions)[None, :]
-                          <= cache_position[:, None])
+        keys = indexer_decode_keys(qi, wi, index_pool, layer, block_tables,
+                                   cache_position)
     with scope("select"):
         # equal keys: the lower position first (``lax.top_k``'s rule)
         best, chosen = jax.lax.top_k(keys, min(topk, positions))

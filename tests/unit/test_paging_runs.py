@@ -330,3 +330,57 @@ def test_the_engine_sizes_the_runs_by_its_readers_block(attn_kernel):
         == want
     assert state["extents_free"] == (state["num_pages"] - 1) // want
     engine.close()
+
+
+def test_an_indexer_familys_engine_hands_out_the_indexer_walks_runs(
+        monkeypatch):
+    """ISSUE 56: a family with an indexer leaf reads it by a page walk
+    of ITS OWN block (``ops/attention/indexed.block_pages``), so the
+    engine gives its allocator runs of that many pages, and the
+    `serve/decode` span's ``read_pages``, ``read_turns``, ``run_turns``
+    and ``block_tokens`` are counted with that block: held here against
+    a count by hand over one request's decode steps (2 pages a turn, so
+    that a context of 40-45 positions is two turns)."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.inference import InferenceEngine
+    from deepspeed_tpu.ops.attention import indexed
+    from tests.unit import test_keye_vl2 as keye
+    monkeypatch.setattr(indexed, "_INDEX_BLOCK_TOKENS", 32)
+    decodes = []
+    plain = InferenceEngine._span
+
+    def recording(self, name, **args):
+        if name == "serve/decode":
+            decodes.append(args)
+        return plain(self, name, **args)
+
+    monkeypatch.setattr(InferenceEngine, "_span", recording)
+    cfg = keye.TINY
+    params = keye.kv2.init_keye_vl2_params(cfg, keye.jax.random.PRNGKey(3),
+                                           jnp.float32)
+    engine = InferenceEngine(cfg, params, keye.INFERENCE,
+                             dtype=jnp.float32)
+    ps = engine.paged_spec.page_size
+    per_turn = indexed.block_pages(ps)
+    assert per_turn == 32 // ps == 2
+    assert engine._decode_attn_path == "pallas"
+    assert "indexer page walk" in engine._decode_attn_reason
+    state = engine.debug_state()["page_pool"]
+    assert state["run_pages"] == engine.scheduler.allocator.run_pages \
+        == per_turn
+    keye._serve(engine, keye._prompts([40]), new=6)
+    engine.close()
+    idle = engine._rows - 1
+    assert len(decodes) >= 5
+    for a in decodes:
+        # the one live row at position p scores p + 1 keys
+        pos = a["scored_tokens"] - 1
+        live = pos // ps + 1
+        assert 40 <= pos <= 45
+        assert a["block_tokens"] == per_turn * ps
+        assert a["read_pages"] == live + idle
+        assert a["read_turns"] == -(-live // per_turn) + idle
+        # a fresh pool lays the request's pages as whole extents: every
+        # turn a run, an inactive row's null page a run of one
+        assert a["run_turns"] == a["read_turns"]
+        assert 0 < a["read_pages"] < engine._rows * a["table_pages"]

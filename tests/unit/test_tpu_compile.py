@@ -441,6 +441,39 @@ def test_a_run_of_pages_is_one_copy_at_the_cells_blocks(arity, block_pages):
     assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
 
 
+@pytest.mark.parametrize("block_tokens", [512, 1024, 2048])
+def test_indexer_walk_compiles_at_the_cells_leaf(block_tokens):
+    """keye-vl-2.0-30b-a3b.serve-repo-saturated's decode indexer (ISSUE
+    56): 17 rows of 16 heads of 64 against the indexer leaf as the cell
+    holds it, 6 layers of 38,913 pages of 8 rows of 128 lanes of
+    bfloat16 (a page is HALF the 16-row tile: every copy indexes the
+    leaf's leading dimensions, a page or a run of pages), a table of
+    4,352 pages, at the shipped block of 2,048 tokens (128 pages) a turn
+    and the two measured beside it. ONE kernel holds both arms (a run as
+    one copy or its binary pieces; page by page): Mosaic takes the
+    dynamic page counts and starts, the leaf stays in HBM as XLA lays it
+    (no copy, no re-lay), and the planes' interleave is all the call
+    holds beside its result."""
+    from deepspeed_tpu.ops.attention import indexed
+    leaf = (6, 38913, 8, 128)
+
+    def keys(qi, wi, pool, tables, positions):
+        return indexed._indexer_decode_call(
+            qi, wi, pool, tables, positions, jnp.full((1,), 3, jnp.int32),
+            False, block_tokens)
+    compiled = _compile(keys, _spec((17, 16, 64)),
+                        _spec((17, 16), jnp.float32), _spec(leaf),
+                        _spec((17, 4352), jnp.int32),
+                        _spec((17,), jnp.int32))
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert not re.search(r"= bf16\[%d,%d,8,128\]\S* (copy|fusion)\("
+                         % leaf[:2], text)
+    assert "s32[17,69632]" in text
+    # far less than a layer of the leaf (79.7 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 17 * 69632 * 4
+
+
 @pytest.mark.parametrize("row_lanes,page_size,kv_heads,head_dim", [
     (640, 16, 1, 640),      # the latent pool's ONE rule: 576 held at 640
     (576, 16, 1, 576),      # the row as the equations give it: refused
@@ -1551,7 +1584,8 @@ def test_keye_vl2_serving_programs_keep_three_leaves_in_place(monkeypatch,
     tokens a 128-lane row) are aliased through the six layers, and no
     copy of the indexer leaf is made around the program (a 64-lane row
     was re-laid twice a layer: 28 ms of a decode step, my chip run, PR
-    55). Decode runs no kernel: a sort a layer and rows read by (page,
+    55). Decode runs ONE kernel a layer, the indexer's walk of the live
+    pages of its leaf (ISSUE 56), a sort a layer and rows read by (page,
     offset); a chunk runs the flash kernel TWICE a layer (its own rows,
     and a block of the prefix a loop turn) under a mask a (query, key)
     and three grouped products an expert layer, and holds no (heads x
@@ -1605,7 +1639,7 @@ def test_keye_vl2_serving_programs_keep_three_leaves_in_place(monkeypatch,
     if program == "decode":
         fn, args = decode, (ints(rows), ints(rows),
                             ints(rows, pages.pages_per_seq))
-        kernels = 0
+        kernels = 6
     else:
         b = int(program[-1])
         fn, args = prefill, (ints(b, chunk), ints(b), ints(b),
